@@ -272,11 +272,11 @@ class EngineConfig:
     # late (overshoot discarded). Cancels and admin ops flush the
     # pipeline; admissions interleave WITHOUT flushing.
     pipeline_decode: bool = False
-    # in-flight decode bursts when pipelined. Depth 2 is what hides a
-    # remote host: burst k's token download (started at dispatch) has a
-    # full burst of device time to land before the host consumes it, so
-    # steady-state cycles track device time, not the d2h RTT. Stops are
-    # detected up to depth*burst tokens late (overshoot discarded).
+    # in-flight decode bursts when pipelined. At depth 2 burst k's token
+    # download (started at dispatch) has a full burst of device time to
+    # land before the host consumes it, so steady-state cycles track
+    # device time, not the d2h RTT. Stops are detected up to depth*burst
+    # tokens late (overshoot discarded).
     pipeline_depth: int = 2
     # admission first tokens sampled on device and materialized a step
     # later (never blocks the step thread on the d2h RTT); off = the
@@ -394,6 +394,54 @@ class EngineConfig:
     @property
     def max_context(self) -> int:
         return self.page_size * self.max_pages_per_seq
+
+    def prefill_shapes(
+        self, spec: ModelSpec, free_bytes: int | None, tp: int = 1
+    ) -> dict[int, int]:
+        """``{bucket: pack width}`` the engine offers: every prefill shape
+        it will compile and dispatch. Derived from ``max_context`` (a
+        bucket past the first one that holds a whole context is never
+        reached) and from what fits ``free_bytes`` of device memory
+        beside the weights and the cache (None = the backend reports no
+        limit, as on the CPU: everything configured is offered).
+
+        Prefill attention materialises f32 scores ``[rows, H/tp, T,
+        max_context]`` per layer (ops/attention.causal_attention); the
+        chip's compiler reports 1.3-1.5x that as the program's temporary
+        memory at Llama-3-8B widths, plus well under 96 KiB per prompt
+        token for the MLP and the rest. A pack width that does not fit
+        halves; a bucket that does not fit even one row is not offered,
+        nor is any above it — longer prompts then go through chunks of
+        the largest bucket that is (``max_prefill_chunk_tokens`` is
+        capped by it). A guard with margin, not a tuner."""
+        top = self.bucket_for(min(
+            self.max_context, self.max_prefill_chunk_tokens,
+            self.prefill_buckets[-1],
+        ))
+        heads = max(1, spec.num_heads // max(1, tp))
+
+        def need(rows: int, bucket: int) -> int:
+            scores = 4 * rows * heads * bucket * self.max_context
+            return scores * 3 // 2 + 96 * 1024 * rows * bucket
+
+        shapes: dict[int, int] = {}
+        for bucket in self.prefill_buckets:
+            if bucket > top:
+                break
+            width = max(1, self.prefill_pack_size)
+            if free_bytes is not None:
+                while width > 1 and need(width, bucket) > free_bytes:
+                    width //= 2
+                if need(width, bucket) > free_bytes:
+                    break
+            shapes[bucket] = width
+        if not shapes:
+            raise ValueError(
+                f"no prefill bucket of {self.prefill_buckets} fits "
+                f"{free_bytes} free device bytes at max_context "
+                f"{self.max_context}: lower max_pages_per_seq or num_pages"
+            )
+        return shapes
 
     def bucket_for(self, length: int) -> int:
         for b in self.prefill_buckets:

@@ -140,8 +140,8 @@ async def launch_engine_worker(
     if precompile:
         # shape warmup BEFORE registration: no request ever eats a
         # compile, and per-shape compile time lands in the startup log
-        # (engine.precompile logs each shape; with DYN_COMPILE_CACHE_DIR
-        # set, a restarted worker mostly replays the disk cache here).
+        # (engine.precompile logs each shape; a restarted worker mostly
+        # replays the persistent compile cache here).
         # Off the event loop: a cold compile pass can take minutes on
         # TPU and must not starve the hub keepalives sharing this loop.
         import asyncio as _aio
@@ -470,12 +470,6 @@ async def _amain(args: argparse.Namespace) -> None:
     rcfg = RuntimeConfig.from_env()
     if args.hub:
         rcfg.override_hub(args.hub)
-    if rcfg.compile_cache_dir:
-        # honor the YAML-layered config too (DYN_CONFIG), not just the
-        # DYN_COMPILE_CACHE_DIR env the engine reads itself
-        from dynamo_tpu.engine.compile_cache import enable_compile_cache
-
-        enable_compile_cache(rcfg.compile_cache_dir)
     drt = DistributedRuntime(await connect_hub(rcfg.hub_target()), rcfg)
     if multihost or args.mirror == "leader":
         import asyncio as _aio
@@ -745,8 +739,8 @@ def main() -> None:
                         "pack widths, decode bursts, sample widths) before "
                         "registering, logging per-shape compile time — no "
                         "request ever eats a compile. Default ON in the "
-                        "serving recipes; pair with DYN_COMPILE_CACHE_DIR "
-                        "so restarts replay the disk cache")
+                        "serving recipes; restarts replay the persistent "
+                        "compile cache (engine/compile_cache.py)")
     p.add_argument("--health-port", type=int, default=-1,
                    help="system status server port (0 = ephemeral, "
                         "-1 = health subsystem off)")

@@ -95,7 +95,8 @@ def init_params(spec: ModelSpec, key: jax.Array) -> Params:
     keys = iter(jax.random.split(key, 8 + spec.num_layers * 12))
 
     def dense(k, shape, scale=None):
-        scale = scale or (1.0 / jnp.sqrt(shape[0]))
+        if scale is None:
+            scale = 1.0 / jnp.sqrt(shape[0])
         return (jax.random.normal(k, shape, jnp.float32) * scale).astype(dtype)
 
     params: Params = {

@@ -29,7 +29,8 @@ def init_moe_layer(spec: ModelSpec, key: jax.Array) -> Params:
     k1, k2, k3, k4 = jax.random.split(key, 4)
 
     def dense(k, shape, scale=None):
-        scale = scale or (1.0 / jnp.sqrt(shape[-2]))
+        if scale is None:
+            scale = 1.0 / jnp.sqrt(shape[-2])
         return (jax.random.normal(k, shape, jnp.float32) * scale).astype(dtype)
 
     out = {

@@ -21,7 +21,6 @@ import os
 import jax
 import jax.numpy as jnp
 
-from dynamo_tpu.ops.shard import shard_map as compat_shard_map
 
 NEG_INF = -1e30
 
@@ -409,7 +408,7 @@ def decode_update_attention(
             # dynalint: disable=DL013 -- array pools only: fused_ok
             # excludes quantized+tp (scale leaves unspecced), and that
             # exclusion is counted (note_fallback quant_tp_shardmap)
-            kernel = compat_shard_map(
+            kernel = jax.shard_map(
                 kernel,
                 mesh=mesh,
                 in_specs=tuple(in_specs),
@@ -589,7 +588,7 @@ def paged_decode_attention_auto(
             # dynalint: disable=DL013 -- array layer slices only: the
             # quantized form is diverted above (v3 kernel, or the
             # counted gather/dequant fallback) before this shard_map
-            kernel = compat_shard_map(
+            kernel = jax.shard_map(
                 kernel,
                 mesh=mesh,
                 in_specs=tuple(in_specs),

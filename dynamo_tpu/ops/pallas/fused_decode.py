@@ -49,9 +49,9 @@ from dynamo_tpu.ops.quant import (
     QuantPool,
     append_scale,
     is_quant,
-    kt_scales_f,
     quant_values,
     rescale_factor,
+    table_col_scales,
 )
 
 
@@ -67,7 +67,7 @@ def _fused_decode_kernel(
     v_new_ref,  # [1, KH, D] VMEM
     k_pages_ref,  # [L, num_pages, KH, page, D] ANY/HBM (aliased out)
     v_pages_ref,
-    *rest,  # [kt_s, vt_s, old_ks, old_vs,] [sinks,] o_ref, k_out_ref,
+    *rest,  # [kc, vc, old_ks, old_vs,] [sinks,] o_ref, k_out_ref,
     # v_out_ref, [nks_ref, nvs_ref,] kv_buf, sems, stage_k, stage_v,
     # rmw_sems
     layer: int,
@@ -80,10 +80,13 @@ def _fused_decode_kernel(
 ):
     i = 0
     if quantized:
-        # host-pregathered bf16 scales: per table page [1, P, KH] and the
-        # destination page's current scales [1, KH] — all indexing the
-        # kernel does on them is static (window chunk / whole block)
-        kt_s_ref, vt_s_ref, old_ks_ref, old_vs_ref = rest[:4]
+        # host-prepared f32 scales: per window COLUMN [1, 1, n_chunks*Nw]
+        # (ops/quant.table_col_scales) and the destination page's
+        # current scales, lane-broadcast to [1, KH, 1, D] — all indexing
+        # the kernel does on them is static (window chunk / whole block)
+        # and heads sit on the LEADING axis, where Mosaic can broadcast
+        # them over a [KH, page, D] page
+        kc_ref, vc_ref, old_ks_ref, old_vs_ref = rest[:4]
         i = 4
     if has_sinks:
         sinks_ref = rest[i]
@@ -92,7 +95,7 @@ def _fused_decode_kernel(
         sinks_ref = None
     o_ref, k_out_ref, v_out_ref = rest[i: i + 3]
     if quantized:
-        nks_ref, nvs_ref = rest[i + 3: i + 5]  # [1, KH] grown scales out
+        nks_ref, nvs_ref = rest[i + 3: i + 5]  # [1, KH, 1, D] grown scales
     kv_buf, sems, stage_k, stage_v, rmw_sems = rest[-5:]
     b = pl.program_id(0)
     nb = pl.num_programs(0)
@@ -195,22 +198,10 @@ def _fused_decode_kernel(
                 issue(nxt, b + 1, 0)
 
         wait(buf, b, c)
-        if quantized:
-            # upcast + dequant in-register BEFORE the flash chunk: the
-            # window's pages crossed HBM at 1 byte/elem; the f32 form
-            # only ever exists in VMEM. Scales index statically by the
-            # window chunk (host pre-gathered them by block table).
-            lo = c * Pw
-            hi = min(P, lo + Pw)
-            sk = kt_scales_f(kt_s_ref, lo, hi, Pw)  # [Pw, KH] f32
-            sv = kt_scales_f(vt_s_ref, lo, hi, Pw)
-            kf = kv_buf[buf, 0].astype(jnp.float32) * sk[:, :, None, None]
-            vf = kv_buf[buf, 1].astype(jnp.float32) * sv[:, :, None, None]
-            kf = kf.reshape(Nw, D)
-            vf = vf.reshape(Nw, D)
-        else:
-            kf = kv_buf[buf, 0].reshape(Nw, D).astype(jnp.float32)
-            vf = kv_buf[buf, 1].reshape(Nw, D).astype(jnp.float32)
+        # fp8 pools upcast in-register: the window's pages crossed HBM at
+        # 1 byte/elem; the f32 form only ever exists in VMEM
+        kf = kv_buf[buf, 0].reshape(Nw, D).astype(jnp.float32)
+        vf = kv_buf[buf, 1].reshape(Nw, D).astype(jnp.float32)
         # the pool does NOT yet hold the new token, so every fetched
         # chunk can be fully masked (seq_len == 1) — sanitize V
         # unconditionally: garbage only ever multiplies 0-probability
@@ -220,6 +211,11 @@ def _fused_decode_kernel(
             qf, kf, (((1,), (1,)), ((), ())),
             preferred_element_type=jnp.float32,
         )
+        if quantized:
+            # dequant on the score side (see paged_attention_v3): the
+            # page/head scale is constant along D, so it factors out of
+            # q.k as one [1, Nw] column scale, statically sliced
+            scores = scores * kc_ref[0, :, c * Nw:(c + 1) * Nw]
         gp = c * Pw + col_page
         pos = gp * page + col_tok
         # pos < seq_len - 1: the new token is NOT in the pool; its
@@ -233,6 +229,8 @@ def _fused_decode_kernel(
         alpha = jnp.exp(m - m_new)
         probs = jnp.exp(scores - m_new)
         l = l * alpha + jnp.sum(probs, axis=-1, keepdims=True)
+        if quantized:
+            probs = probs * vc_ref[0, :, c * Nw:(c + 1) * Nw]
         acc = acc * alpha + jax.lax.dot_general(
             probs, vf, (((1,), (0,)), ((), ())),
             preferred_element_type=jnp.float32,
@@ -278,31 +276,31 @@ def _fused_decode_kernel(
         # new_scale = max(old, amax(row)/FP8_MAX) per head (rounded to
         # the stored bf16), existing fp8 values re-encode by old/new,
         # the new row quantizes under the grown scale, and the page DMAs
-        # back at fp8 width. Grown scales leave via a tiny [1, KH]
+        # back at fp8 width. Grown scales leave via a tiny [1, KH, 1, D]
         # output; the host scatters them into the scale pool (XLA) right
-        # after the pallas_call, inside the same jit.
-        kn = k_new_ref[0].astype(jnp.float32)  # [KH, D]
-        vn_r = v_new_ref[0].astype(jnp.float32)
-        oks = old_ks_ref[0].astype(jnp.float32)  # [KH]
-        ovs = old_vs_ref[0].astype(jnp.float32)
-        nks = append_scale(oks, kn)
-        nvs = append_scale(ovs, vn_r)
-        page_k = stage_k[...].astype(jnp.float32) * rescale_factor(
-            oks, nks
-        )[:, None, None]
-        page_v = stage_v[...].astype(jnp.float32) * rescale_factor(
-            ovs, nvs
-        )[:, None, None]
-        row_k = quant_values(kn, nks[:, None])[:, None, :]
-        row_v = quant_values(vn_r, nvs[:, None])[:, None, :]
+        # after the pallas_call, inside the same jit. Heads stay on the
+        # LEADING axis throughout ([KH, 1, D] rows and scales, every
+        # lane of a scale row equal): Mosaic broadcasts along lanes OR
+        # sublanes in one step, not both, and cannot move a [KH] lane
+        # vector onto the leading axis.
+        kn = k_new_ref[0][:, None, :].astype(jnp.float32)  # [KH, 1, D]
+        vn_r = v_new_ref[0][:, None, :].astype(jnp.float32)
+        oks = old_ks_ref[0]  # [KH, 1, D] f32
+        ovs = old_vs_ref[0]
+        nks = append_scale(oks, kn, keepdims=True)
+        nvs = append_scale(ovs, vn_r, keepdims=True)
+        page_k = stage_k[...].astype(jnp.float32) * rescale_factor(oks, nks)
+        page_v = stage_v[...].astype(jnp.float32) * rescale_factor(ovs, nvs)
+        row_k = quant_values(kn, nks)  # [KH, 1, D]
+        row_v = quant_values(vn_r, nvs)
         stage_k[...] = jnp.clip(
             jnp.where(row, row_k, page_k), -FP8_MAX, FP8_MAX
         ).astype(stage_k.dtype)
         stage_v[...] = jnp.clip(
             jnp.where(row, row_v, page_v), -FP8_MAX, FP8_MAX
         ).astype(stage_v.dtype)
-        nks_ref[0] = nks.astype(nks_ref.dtype)
-        nvs_ref[0] = nvs.astype(nvs_ref.dtype)
+        nks_ref[0] = nks
+        nvs_ref[0] = nvs
     else:
         stage_k[...] = jnp.where(row, k_new_ref[0][:, None, :], stage_k[...])
         stage_v[...] = jnp.where(row, v_new_ref[0][:, None, :], stage_v[...])
@@ -388,8 +386,8 @@ def fused_decode_attention(
         pl.BlockSpec(
             (1, KH, D), lambda b, *_: (b, 0, 0), memory_space=pltpu.VMEM
         ),
-        pl.BlockSpec(memory_space=pltpu.ANY),  # k_pages
-        pl.BlockSpec(memory_space=pltpu.ANY),  # v_pages
+        pl.BlockSpec(memory_space=pl.ANY),  # k_pages
+        pl.BlockSpec(memory_space=pl.ANY),  # v_pages
     ]
     if quantized:
         k_vals, k_scale = k_pages
@@ -401,34 +399,35 @@ def fused_decode_attention(
         # never ratchets into this occupancy (ops/quant.quant_append_rows
         # applies the same reset; the two paths must share the bits)
         held = (dst_off != 0)[:, None]  # [B, 1]
+
+        def dst_scale(scale):  # -> [B, KH, 1, D] f32 (zeroed when fresh)
+            s = jnp.where(held, scale[layer, dst_page], 0)
+            return jnp.broadcast_to(
+                s.astype(jnp.float32)[:, :, None, None], (B, KH, 1, D)
+            )
+
+        # host-expanded scales: dynamic page indexing happens in XLA,
+        # the kernel's own scale indexing is fully static
+        k_cols = table_col_scales(k_scale[layer], block_tables, page_size, Pw)
+        v_cols = table_col_scales(v_scale[layer], block_tables, page_size, Pw)
         inputs = [
             block_tables.astype(jnp.int32), seq_lens.astype(jnp.int32),
             dst_page.astype(jnp.int32), dst_off.astype(jnp.int32),
             q4, k_new, v_new, k_vals, v_vals,
-            # host-gathered scales: dynamic page indexing happens in XLA,
-            # the kernel's own scale indexing is fully static
-            k_scale[layer][block_tables],  # [B, P, KH]
-            v_scale[layer][block_tables],
-            # [B, KH] dst page's current scale (zeroed when fresh)
-            jnp.where(held, k_scale[layer, dst_page], 0),
-            jnp.where(held, v_scale[layer, dst_page], 0),
+            k_cols, v_cols, dst_scale(k_scale), dst_scale(v_scale),
         ]
-        in_specs += [
-            pl.BlockSpec(
-                (1, P, KH), lambda b, *_: (b, 0, 0),
-                memory_space=pltpu.VMEM,
-            ),
-            pl.BlockSpec(
-                (1, P, KH), lambda b, *_: (b, 0, 0),
-                memory_space=pltpu.VMEM,
-            ),
-            pl.BlockSpec(
-                (1, KH), lambda b, *_: (b, 0), memory_space=pltpu.VMEM
-            ),
-            pl.BlockSpec(
-                (1, KH), lambda b, *_: (b, 0), memory_space=pltpu.VMEM
-            ),
-        ]
+        # trailing block dims equal the array dims (Pallas TPU blocks
+        # must, or be multiples of (8, 128)): the per-sequence axis is
+        # the only one blocked
+        col_spec = pl.BlockSpec(
+            (1,) + k_cols.shape[1:], lambda b, *_: (b, 0, 0),
+            memory_space=pltpu.VMEM,
+        )
+        dst_spec = pl.BlockSpec(
+            (1, KH, 1, D), lambda b, *_: (b, 0, 0, 0),
+            memory_space=pltpu.VMEM,
+        )
+        in_specs += [col_spec, col_spec, dst_spec, dst_spec]
         pool_dtype = k_vals.dtype
         k_pages_op, v_pages_op = k_vals, v_vals
     else:
@@ -452,8 +451,8 @@ def fused_decode_attention(
             (1, KH, G, D), lambda b, *_: (b, 0, 0, 0),
             memory_space=pltpu.VMEM,
         ),
-        pl.BlockSpec(memory_space=pltpu.ANY),  # k_pages out
-        pl.BlockSpec(memory_space=pltpu.ANY),  # v_pages out
+        pl.BlockSpec(memory_space=pl.ANY),  # k_pages out
+        pl.BlockSpec(memory_space=pl.ANY),  # v_pages out
     ]
     out_shape = [
         jax.ShapeDtypeStruct((B, KH, G, D), q.dtype),
@@ -461,18 +460,8 @@ def fused_decode_attention(
         jax.ShapeDtypeStruct(v_pages_op.shape, pool_dtype),
     ]
     if quantized:
-        out_specs += [
-            pl.BlockSpec(
-                (1, KH), lambda b, *_: (b, 0), memory_space=pltpu.VMEM
-            ),
-            pl.BlockSpec(
-                (1, KH), lambda b, *_: (b, 0), memory_space=pltpu.VMEM
-            ),
-        ]
-        out_shape += [
-            jax.ShapeDtypeStruct((B, KH), k_scale.dtype),
-            jax.ShapeDtypeStruct((B, KH), v_scale.dtype),
-        ]
+        out_specs += [dst_spec, dst_spec]
+        out_shape += [jax.ShapeDtypeStruct((B, KH, 1, D), jnp.float32)] * 2
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=4,
         grid=(B,),
@@ -499,11 +488,19 @@ def fused_decode_attention(
     )(*inputs)
     if quantized:
         out, k_out, v_out, nks, nvs = results
+        # append_scale already rounded through the stored dtype: the
+        # cast back is exact
         k_pool = QuantPool(
-            k_out, k_scale.at[layer, dst_page].set(nks)
+            k_out,
+            k_scale.at[layer, dst_page].set(
+                nks[:, :, 0, 0].astype(k_scale.dtype)
+            ),
         )
         v_pool = QuantPool(
-            v_out, v_scale.at[layer, dst_page].set(nvs)
+            v_out,
+            v_scale.at[layer, dst_page].set(
+                nvs[:, :, 0, 0].astype(v_scale.dtype)
+            ),
         )
         return out.reshape(B, H, D), k_pool, v_pool
     out, k_out, v_out = results

@@ -33,7 +33,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from dynamo_tpu.ops.shard import shard_map as compat_shard_map
 
 
 def _kv_write_kernel(
@@ -149,12 +148,12 @@ def kv_write_pallas(
                 (1, KH, D), lambda i, *_: (i, 0, 0),
                 memory_space=pltpu.VMEM,
             ),
-            pl.BlockSpec(memory_space=pltpu.ANY),  # k_pages
-            pl.BlockSpec(memory_space=pltpu.ANY),  # v_pages
+            pl.BlockSpec(memory_space=pl.ANY),  # k_pages
+            pl.BlockSpec(memory_space=pl.ANY),  # v_pages
         ],
         out_specs=[
-            pl.BlockSpec(memory_space=pltpu.ANY),
-            pl.BlockSpec(memory_space=pltpu.ANY),
+            pl.BlockSpec(memory_space=pl.ANY),
+            pl.BlockSpec(memory_space=pl.ANY),
         ],
         scratch_shapes=[
             pltpu.VMEM((2, KH, page_size, D), k_pages.dtype),
@@ -234,7 +233,7 @@ def write_new_kv(
             # dynalint: disable=DL013 -- array pools only: the
             # quantized append returned above (quant_append_rows)
             # before this shard_map
-            kernel = compat_shard_map(
+            kernel = jax.shard_map(
                 kernel,
                 mesh=mesh,
                 in_specs=(

@@ -55,22 +55,33 @@ from jax.experimental.pallas import tpu as pltpu
 
 NEG_INF = -1e30
 
-# per-buffer-slot window budget (bytes of K or V, one chunk). Total VMEM
-# ~= 4x this (2 slots x K+V) + the f32 conversions and score matrix of
-# ONE window — ~6x, i.e. <=24MB of v5e's ~128MB.
-_WINDOW_SLOT_BYTES = 4 * 1024 * 1024
+# per-buffer-slot window budget (bytes of K or V, one chunk). The window
+# buffer is four such slots (2 buffers x K+V) = 8 MiB, half of the 16 MiB
+# scoped-VMEM limit the TPU compiler gives a kernel by default; the other
+# half is the f32 working set of ONE window (upcast K/V, the score
+# matrix), the fused kernel's page stage and the pipelined q/out blocks.
+# Compiling for v5e, a 12 MiB buffer is still accepted and 14 MiB is
+# refused, so 8 MiB leaves real headroom without raising the limit.
+_WINDOW_SLOT_BYTES = 2 * 1024 * 1024
+
+# cap on pages per window chunk: every window page owns 4 DMA semaphores
+# (2 buffers x K+V) and the chip's semaphore space holds 512 words, shared
+# with the fused kernel's RMW semaphores and the compiler's own — at 128
+# window pages the compiler refuses the kernel ("memory space sflag")
+_MAX_WINDOW_PAGES = 64
 
 
 def _window_pages(KH: int, page: int, D: int, itemsize: int, P: int) -> int:
-    """Pages per window chunk for the slot budget. DTYPE-AWARE on
-    purpose (ROADMAP #1 tuning note): ``itemsize`` must be the POOL
-    dtype's — an fp8 pool (ops/quant.py) packs twice the pages of bf16
-    into the same VMEM slot, doubling the resident window (and the
-    back-to-back DMA issue burst) instead of wasting half the slot. The
-    f32 working forms are per-CHUNK temporaries already covered by the
-    ~6x headroom above and do not cap the window."""
+    """Pages per window chunk: bounded by the VMEM slot budget AND by the
+    DMA-semaphore space. ``itemsize`` is the POOL dtype's. An 8-bit pool
+    (ops/quant.py) gets a quarter of the slot BYTES, i.e. half the
+    ELEMENTS of a bf16 one: its in-register upcast needs more working
+    set per element — compiling for v5e, 1M fp8 elements per slot ask for
+    16.6 MiB of scoped VMEM where 1M bf16 elements fit, and 768K fp8
+    elements fit."""
+    slot = _WINDOW_SLOT_BYTES // 4 if itemsize == 1 else _WINDOW_SLOT_BYTES
     per_page = KH * page * D * itemsize
-    return max(1, min(P, _WINDOW_SLOT_BYTES // per_page))
+    return max(1, min(P, _MAX_WINDOW_PAGES, slot // per_page))
 
 
 def _decode_kernel_v3(
@@ -81,18 +92,18 @@ def _decode_kernel_v3(
     q_ref,  # [1, KH, G, D] VMEM (this sequence's query heads, pre-scaled)
     k_pages_ref,  # [num_pages, KH, page, D] ANY/HBM
     v_pages_ref,
-    *rest,  # [kt_s_ref, vt_s_ref [1, P, KH] when quantized,]
+    *rest,  # [kc_ref, vc_ref [1, 1, n_chunks*Nw] f32 when quantized,]
     # [sinks_ref [KH*G, 1] f32 VMEM when has_sinks,] o_ref, kv_buf, sems
     page_size: int,
     pages_per_seq: int,
     window_pages: int,
     window: int = 0,  # sliding window in tokens (0 = full attention)
     has_sinks: bool = False,  # per-head sink logits in the softmax denom
-    quantized: bool = False,  # fp8 pages + host-pregathered bf16 scales
+    quantized: bool = False,  # fp8 pages + host-expanded column scales
 ):
     i = 0
     if quantized:
-        kt_s_ref, vt_s_ref = rest[:2]
+        kc_ref, vc_ref = rest[:2]
         i = 2
     if has_sinks:
         sinks_ref = rest[i]
@@ -194,23 +205,8 @@ def _decode_kernel_v3(
                 issue(nxt, b + 1, 0)
 
         wait(buf, b, c)
-        if quantized:
-            # dequant in-register (mirrors fused_decode): per-page/head
-            # scales were host-gathered by block table, so this indexes
-            # statically by the unrolled chunk
-            from dynamo_tpu.ops.quant import kt_scales_f
-
-            lo = c * Pw
-            hi = min(P, lo + Pw)
-            sk = kt_scales_f(kt_s_ref, lo, hi, Pw)  # [Pw, KH] f32
-            sv = kt_scales_f(vt_s_ref, lo, hi, Pw)
-            kf = kv_buf[buf, 0].astype(jnp.float32) * sk[:, :, None, None]
-            vf = kv_buf[buf, 1].astype(jnp.float32) * sv[:, :, None, None]
-            kf = kf.reshape(Nw, D)
-            vf = vf.reshape(Nw, D)
-        else:
-            kf = kv_buf[buf, 0].reshape(Nw, D).astype(jnp.float32)
-            vf = kv_buf[buf, 1].reshape(Nw, D).astype(jnp.float32)
+        kf = kv_buf[buf, 0].reshape(Nw, D).astype(jnp.float32)
+        vf = kv_buf[buf, 1].reshape(Nw, D).astype(jnp.float32)
         if quantized or window or n_chunks > 1:
             # Only these shapes can SKIP fetches (chunk_live) and hence
             # read UNINITIALIZED VMEM: garbage K only feeds masked score
@@ -224,6 +220,13 @@ def _decode_kernel_v3(
             qf, kf, (((1,), (1,)), ((), ())),
             preferred_element_type=jnp.float32,
         )  # [KH*G, Nw]
+        if quantized:
+            # dequant on the SCORE side: a page/head scale is constant
+            # along D, so q.(s*k) == s*(q.k) — one [1, Nw] column scale
+            # (host-expanded, ops/quant.table_col_scales) instead of a
+            # [Pw, KH] -> [Pw, KH, page, D] broadcast Mosaic cannot lay
+            # out; statically sliced per unrolled chunk
+            scores = scores * kc_ref[0, :, c * Nw:(c + 1) * Nw]
         gp = c * Pw + col_page  # global page index
         pos = gp * page + col_tok
         valid = (col_kh == row_kh) & (pos < seq_len) & (gp < P)
@@ -238,6 +241,9 @@ def _decode_kernel_v3(
         alpha = jnp.exp(m - m_new)
         probs = jnp.exp(scores - m_new)  # masked cols underflow to 0
         l = l * alpha + jnp.sum(probs, axis=-1, keepdims=True)
+        if quantized:
+            # V dequant folds into the probabilities the same way
+            probs = probs * vc_ref[0, :, c * Nw:(c + 1) * Nw]
         acc = acc * alpha + jax.lax.dot_general(
             probs, vf, (((1,), (0,)), ((), ())),
             preferred_element_type=jnp.float32,
@@ -316,22 +322,26 @@ def paged_decode_attention_v3(
             (1, KH, G, D), lambda b, *_: (b, 0, 0, 0),
             memory_space=pltpu.VMEM,
         ),
-        pl.BlockSpec(memory_space=pltpu.ANY),
-        pl.BlockSpec(memory_space=pltpu.ANY),
+        pl.BlockSpec(memory_space=pl.ANY),
+        pl.BlockSpec(memory_space=pl.ANY),
     ]
     inputs = [block_tables.astype(jnp.int32), seq_lens.astype(jnp.int32),
               q4, k_pages, v_pages]
     if quantized:
-        # host-gathered per-table-page scales: the kernel's own scale
-        # indexing stays static (same contract as fused_decode)
+        # host-expanded per-column scales: the kernel's own scale
+        # indexing stays a static lane slice (same contract as
+        # fused_decode)
+        from dynamo_tpu.ops.quant import table_col_scales
+
         for sc in (k_scale, v_scale):
+            cols = table_col_scales(sc, block_tables, page_size, Pw)
             in_specs.append(
                 pl.BlockSpec(
-                    (1, P, KH), lambda b, *_: (b, 0, 0),
+                    (1,) + cols.shape[1:], lambda b, *_: (b, 0, 0),
                     memory_space=pltpu.VMEM,
                 )
             )
-            inputs.append(sc[block_tables])
+            inputs.append(cols)
     if has_sinks:
         # already the [KH*G, 1] f32 column the flash merge consumes: an
         # IN-kernel (KH, G) -> (KH*G, 1) reshape is a vector layout cast

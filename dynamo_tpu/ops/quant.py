@@ -128,11 +128,14 @@ def init_quant_pool(vals_shape: tuple[int, ...], scale_ndim: int) -> QuantPool:
 # XLA fallback paths so both produce the same bits.
 
 
-def append_scale(old_scale_f32: jax.Array, rows_f32: jax.Array) -> jax.Array:
+def append_scale(
+    old_scale_f32: jax.Array, rows_f32: jax.Array, keepdims: bool = False
+) -> jax.Array:
     """New per-head scale after appending ``rows`` (amax over the last
-    axis), rounded through the bf16 the pool stores and returned as f32.
-    Monotone: never below the old scale."""
-    amax = jnp.max(jnp.abs(rows_f32), axis=-1)
+    axis, kept as a size-1 axis with ``keepdims``), rounded through the
+    bf16 the pool stores and returned as f32. Monotone: never below the
+    old scale."""
+    amax = jnp.max(jnp.abs(rows_f32), axis=-1, keepdims=keepdims)
     ns = jnp.maximum(old_scale_f32, amax / FP8_MAX)
     return ns.astype(SCALE_DTYPE).astype(jnp.float32)
 
@@ -162,17 +165,27 @@ def dequant(vals: jax.Array, scale_f32: jax.Array) -> jax.Array:
     return vals.astype(jnp.float32) * scale_f32
 
 
-def kt_scales_f(ref, lo: int, hi: int, Pw: int):
-    """One window chunk's [Pw, KH] f32 scales out of a [1, P, KH]
-    per-sequence scale block (Pallas VMEM ref or array). ``lo``/``hi``
-    are STATIC (the kernels' chunk loops are unrolled); the last chunk of
-    a non-divisible table zero-pads — those page slots are beyond ``P``
-    and masked by the validity check. Shared by both decode kernels so
-    their dequant bits agree."""
-    s = ref[0, lo:hi].astype(jnp.float32)
-    if hi - lo < Pw:
-        s = jnp.pad(s, ((0, Pw - (hi - lo)), (0, 0)))
-    return s
+def table_col_scales(
+    scale_l: jax.Array,  # one layer's scales [num_pages, KH]
+    block_tables: jax.Array,  # [B, P] int32
+    page_size: int,
+    window_pages: int,
+) -> jax.Array:
+    """Per-sequence COLUMN scales for the decode kernels: f32
+    ``[B, 1, n_chunks * window_pages * KH * page]`` in the kernels'
+    flattened window order (page, head, token), the table zero-padded to
+    whole window chunks (those page slots are beyond ``P`` and masked by
+    the validity check). The dynamic page gather and the broadcast along
+    the token axis happen here in XLA; the kernels slice one chunk's
+    ``[1, Nw]`` statically and scale scores / probabilities with it.
+    Shared by both decode kernels so their dequant bits agree."""
+    s = scale_l[block_tables].astype(jnp.float32)  # [B, P, KH]
+    B, P, KH = s.shape
+    pad = -P % window_pages
+    if pad:
+        s = jnp.pad(s, ((0, 0), (0, pad), (0, 0)))
+    s = jnp.broadcast_to(s[..., None], (B, P + pad, KH, page_size))
+    return s.reshape(B, 1, -1)
 
 
 def quant_page_tiles(

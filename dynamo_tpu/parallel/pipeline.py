@@ -48,7 +48,6 @@ import jax.numpy as jnp
 
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-from dynamo_tpu.ops.shard import shard_map as compat_shard_map
 
 from dynamo_tpu.engine.config import ModelSpec
 from dynamo_tpu.models.llama import TRASH_PAGE, rms_norm, rope
@@ -313,7 +312,7 @@ def pp_decode_step(
         pp_params["embed"] if spec.tie_embeddings else pp_params["lm_head"]
     )
 
-    shard = compat_shard_map(
+    shard = jax.shard_map(
         partial(body),
         mesh=mesh,
         in_specs=(
@@ -421,7 +420,7 @@ def pp_prefill(
         "w_up": P("pp", None, "tp"),
         "w_down": P("pp", "tp", None),
     }
-    shard = compat_shard_map(
+    shard = jax.shard_map(
         body,
         mesh=mesh,
         in_specs=(

@@ -25,7 +25,6 @@ import jax.numpy as jnp
 
 from jax.sharding import Mesh, PartitionSpec as P
 
-from dynamo_tpu.ops.shard import shard_map as compat_shard_map
 
 NEG_INF = -1e30
 
@@ -109,7 +108,7 @@ def ring_attention(
     tp = mesh.shape.get("tp", 1)
     head_axis = "tp" if tp > 1 and k.shape[1] % tp == 0 else None
     fn = partial(_ring_chunk, sp=sp, axis=axis)
-    return compat_shard_map(
+    return jax.shard_map(
         fn,
         mesh=mesh,
         in_specs=(P(axis, head_axis, None),) * 3,

@@ -89,11 +89,6 @@ class RuntimeConfig:
     # guided/): explicit --guided CLI flags win, empty falls through to
     # the EngineConfig default ("auto")
     guided_mode: str = ""
-    # persistent XLA compilation cache dir (DYN_COMPILE_CACHE_DIR): a
-    # restarted worker reloads its serving programs from disk instead of
-    # paying cold-start TTFT recompiling them; empty = off. Honored by
-    # every engine process (engine/compile_cache.py).
-    compile_cache_dir: str = ""
     # per-tenant fairness quotas for engine workers (DYN_TENANT_QUOTAS;
     # engine/tenancy.py grammar:
     # "tenantA:weight=4,rate=1000,burst=2000;*:rate=200"). Explicit

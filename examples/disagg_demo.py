@@ -4,6 +4,10 @@ prefilled on the prefill worker, its KV pages transferred worker→worker over
 TCP, and decoded on the decode worker.
 
 Run: python examples/disagg_demo.py
+
+A CPU demo: the workers are child processes on the CPU backend. On TPUs a
+chip belongs to one process, so disaggregated prefill/decode needs one chip
+per worker process; `chip_smoke.py` is the single-process path on a chip.
 """
 
 import asyncio

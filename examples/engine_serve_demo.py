@@ -8,6 +8,9 @@ Run: python examples/engine_serve_demo.py          (pure-JAX decode path)
 
 Exercises: real continuous-batching engine (paged KV cache, prefix reuse),
 model-card discovery, greedy determinism, SSE streaming.
+
+A CPU demo: the worker is a child process on the CPU backend (a chip
+belongs to one process; `chip_smoke.py` is the single-process path on one).
 """
 
 import asyncio

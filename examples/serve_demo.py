@@ -4,6 +4,8 @@ all as separate OS processes, driven through the HTTP API.
 Run: python examples/serve_demo.py
 Exercises: model-card discovery, chat + completions (aggregated and SSE),
 KV-aware routing, /v1/models, /health, /metrics.
+
+A CPU demo (mock workers as child processes; no accelerator involved).
 """
 
 import asyncio

@@ -30,11 +30,9 @@ if [ "${SMOKE:-0}" = "1" ]; then
   EP=2 PREFILL_TP=2 PAGE=4 NUM_PAGES=64 SLOTS=2 KVBM_MB=8 BURST=4
   MODEL_ARGS=(--model tiny-deepseek)
   PRECOMPILE=0  # CI smoke: skip the shape warmup
-else
-  # persistent XLA compile cache: worker restarts replay compiled
-  # serving programs from disk (empty DYN_COMPILE_CACHE_DIR disables)
-  export DYN_COMPILE_CACHE_DIR="${DYN_COMPILE_CACHE_DIR-$HOME/.cache/dynamo-tpu/xla-cache}"
 fi
+# persistent XLA compile cache: worker restarts replay compiled serving
+# programs from disk (JAX_COMPILATION_CACHE_DIR, else <checkout>/.jax_cache)
 
 COMMON=("${MODEL_ARGS[@]}" --model-name "${MODEL:-deepseek-r1}"
         --page-size "$PAGE" --num-pages "$NUM_PAGES"

@@ -26,11 +26,9 @@ if [ "${SMOKE:-0}" = "1" ]; then
   EP=2 TP=2 PAGE=4 NUM_PAGES=64 SLOTS=2 BURST=4
   MODEL_ARGS=(--model tiny-gpt-oss)
   PRECOMPILE=0  # CI smoke: skip the shape warmup
-else
-  # persistent XLA compile cache: worker restarts replay compiled
-  # serving programs from disk (empty DYN_COMPILE_CACHE_DIR disables)
-  export DYN_COMPILE_CACHE_DIR="${DYN_COMPILE_CACHE_DIR-$HOME/.cache/dynamo-tpu/xla-cache}"
 fi
+# persistent XLA compile cache: worker restarts replay compiled serving
+# programs from disk (JAX_COMPILATION_CACHE_DIR, else <checkout>/.jax_cache)
 # serving default: compile every shape at startup (PRECOMPILE=0 skips)
 [ "$PRECOMPILE" = "1" ] && MODEL_ARGS+=(--precompile)
 # DYN_KV_DTYPE=fp8: quantized KV cache (throughput mode; default bf16

@@ -6,9 +6,8 @@ set -euo pipefail
 cd "$(dirname "$0")/../.."
 # Persistent XLA compile cache + startup shape warmup (serving default):
 # restarts replay compiled programs from disk, and no request ever eats
-# a compile. DYN_COMPILE_CACHE_DIR= (empty) disables the cache,
-# PRECOMPILE=0 skips the warmup.
-export DYN_COMPILE_CACHE_DIR="${DYN_COMPILE_CACHE_DIR-$HOME/.cache/dynamo-tpu/xla-cache}"
+# a compile. The cache lives where JAX_COMPILATION_CACHE_DIR says, else
+# in <checkout>/.jax_cache; PRECOMPILE=0 skips the warmup.
 ARGS=(run --in http --out engine --port "${PORT:-8000}")
 [ "${PRECOMPILE:-1}" = "1" ] && ARGS+=(--precompile)
 # DYN_KV_DTYPE=fp8: quantized KV cache (throughput mode — ~half the

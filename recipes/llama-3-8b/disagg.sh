@@ -7,9 +7,9 @@ cd "$(dirname "$0")/../.."
 PORT="${PORT:-8000}"
 MODEL_ARGS=(--model "${MODEL:-llama-3-8b}")
 [ -n "${MODEL_PATH:-}" ] && MODEL_ARGS=(--model-path "$MODEL_PATH")
-# compile cache + shape warmup (serving default; see README):
-# DYN_COMPILE_CACHE_DIR= disables the cache, PRECOMPILE=0 the warmup
-export DYN_COMPILE_CACHE_DIR="${DYN_COMPILE_CACHE_DIR-$HOME/.cache/dynamo-tpu/xla-cache}"
+# compile cache + shape warmup (serving default; see README): the cache
+# lives where JAX_COMPILATION_CACHE_DIR says, else in
+# <checkout>/.jax_cache; PRECOMPILE=0 skips the warmup
 [ "${PRECOMPILE:-1}" = "1" ] && MODEL_ARGS+=(--precompile)
 # DYN_KV_DTYPE=fp8: quantized KV cache — BOTH pools must match (packed
 # fp8 payloads cross the transfer plane); default bf16

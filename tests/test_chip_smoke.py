@@ -1,0 +1,86 @@
+"""chip_smoke.py rehearsed on the CPU: its serving phase at a tiny spec,
+its refusal to run without a TPU, and its tp=4 build on four virtual
+devices. The script has no CPU option — the tests call its functions.
+"""
+
+import asyncio
+import dataclasses
+
+import jax
+import pytest
+
+import chip_smoke
+from dynamo_tpu.engine.config import EngineConfig, ModelSpec
+
+pytestmark = pytest.mark.integration
+
+
+def test_serving_phase_on_cpu_at_tiny_spec():
+    """The whole stack in one process — hub, launch_engine_worker with
+    precompile, HTTP frontend — serving the smoke's six requests: every
+    count exact, every SSE chunk an engine delta, two prefill buckets
+    hit, a page boundary crossed, and NO compile after precompile (the
+    burst feed path's glue programs are part of the walk)."""
+    spec = dataclasses.replace(ModelSpec.tiny(), name="tiny-smoke")
+    cfg = EngineConfig(
+        pipeline_decode=True, decode_steps_per_dispatch=4,
+        decode_steps_admit_pending=0, prefill_buckets=(128, 512),
+        prefill_pack_size=4, guided_mode="off",
+    )
+    phase = asyncio.run(chip_smoke.serve_phase(spec, cfg))
+    assert [r["name"] for r in phase["results"]] == [
+        r[0] for r in chip_smoke.REQUESTS
+    ]
+    assert {r["bucket"] for r in phase["results"]} == {128, 512}
+    assert not any("error" in r for r in phase["precompile"].values())
+    assert {"decode[8x1]", "decode[8x4]", "burst_feed"} <= set(
+        phase["precompile"]
+    )
+    for prompt, ids in phase["streams"].items():
+        assert len(ids) >= 32 and len(prompt) >= 29
+    # what only holds on the chip is a separate check — and fails here,
+    # where the XLA fall-through was (rightly) taken and counted
+    assert phase["fallbacks"]
+    with pytest.raises(chip_smoke.SmokeFailure, match="fallbacks"):
+        chip_smoke.check_device_path(phase, guided=False)
+    # the engine object outlives its stack for the numeric checks
+    logits = chip_smoke.engine_prefill_logits(
+        phase["engine"], list(min(phase["streams"], key=len))
+    )
+    assert logits.shape == (spec.vocab_size,)
+
+
+def test_main_fails_without_a_tpu(capsys):
+    """No accelerator: non-zero exit and no result line — never a CPU
+    run under the chip's name."""
+    assert jax.devices()[0].platform == "cpu"
+    assert chip_smoke.main([]) != 0
+    out, err = capsys.readouterr()
+    assert '"ok"' not in out
+    assert "no TPU" in err
+
+
+def test_tp4_build_does_not_put_the_model_on_device_0():
+    """The four-chip phase's build, on four virtual CPU devices: weights
+    and pools are born sharded, so device 0 holds its shard (replicated
+    leaves whole) and never every parameter."""
+    spec = dataclasses.replace(ModelSpec.dryrun(), name="tp4-smoke")
+    cfg = dataclasses.replace(
+        chip_smoke.four_chip_config(4), num_pages=64, max_pages_per_seq=8
+    )
+
+    async def build():
+        stack = await chip_smoke.start_stack(spec, cfg, precompile=False)
+        await chip_smoke.stop_stack(stack)
+        return stack.engine
+
+    engine = asyncio.run(build())
+    dev0 = jax.devices()[0]
+    assert engine.mesh.shape["tp"] == 4
+    want, whole = chip_smoke.shard_report(engine, dev0)
+    assert chip_smoke.resident_bytes(engine, dev0) == want
+    assert want < 0.5 * whole
+    wq = engine.params["layers"][0]["wq"]
+    assert {s.data.shape for s in wq.addressable_shards} == {
+        (spec.hidden_size, spec.num_heads * spec.head_dim // 4)
+    }

@@ -8,6 +8,7 @@ interleaved with decode steps (engine/core.py _advance_partial)."""
 import asyncio
 
 import numpy as np
+import pytest
 
 from dynamo_tpu.engine.config import EngineConfig, ModelSpec
 from dynamo_tpu.engine.core import InferenceEngine
@@ -127,3 +128,77 @@ async def test_chunked_prefill_cancel_mid_flight():
         await asyncio.sleep(0.01)
     assert engine.allocator.active_pages == 0
     await engine.close()
+
+
+# ------------------------------------------ prefill shapes that fit
+
+
+GIB = 2**30
+LLAMA8B_4K = dict(
+    spec=ModelSpec.llama3_8b(),
+    cfg=dict(max_pages_per_seq=256, max_prefill_chunk_tokens=4096),
+)
+
+
+@pytest.mark.parametrize(
+    "free,tp,want",
+    [
+        # no limit reported (the CPU): everything configured is offered
+        (None, 1, {64: 8, 128: 8, 256: 8, 512: 8, 1024: 8, 2048: 8, 4096: 8}),
+        # a 16 GB chip beside 10.5 GiB of weights and cache: f32 scores
+        # [rows, 32, T, 4096] halve the pack as the bucket doubles
+        (int(4.7 * GIB), 1,
+         {64: 8, 128: 8, 256: 8, 512: 8, 1024: 4, 2048: 2, 4096: 1}),
+        # a tight device: 2,048 does not fit even one row, so neither it
+        # nor 4,096 is offered — longer prompts chunk at 1,024
+        (GIB, 1, {64: 8, 128: 8, 256: 4, 512: 2, 1024: 1}),
+        # tp=4 holds a quarter of the heads
+        (GIB, 4, {64: 8, 128: 8, 256: 8, 512: 4, 1024: 2, 2048: 1}),
+    ],
+    ids=["no-limit", "v5e-16gb", "tight", "tight-tp4"],
+)
+def test_prefill_shapes_follow_what_fits(free, tp, want):
+    cfg = EngineConfig(**LLAMA8B_4K["cfg"])
+    assert cfg.prefill_shapes(LLAMA8B_4K["spec"], free, tp=tp) == want
+
+
+def test_prefill_shapes_stop_at_max_context_and_refuse_nothing_fits():
+    spec = ModelSpec.llama3_8b()
+    # 1,024-token tables: no bucket past 1,024 is ever reached, and the
+    # default 512-token chunk caps it further
+    assert max(EngineConfig().prefill_shapes(spec, None)) == 512
+    assert max(
+        EngineConfig(max_prefill_chunk_tokens=4096).prefill_shapes(spec, None)
+    ) == 1024
+    with pytest.raises(ValueError, match="no prefill bucket"):
+        EngineConfig().prefill_shapes(spec, 1024)
+
+
+async def test_engine_on_a_small_device_chunks_at_the_largest_offered_bucket(
+    monkeypatch,
+):
+    """A device too small for the 64 and 128 buckets: the engine offers
+    16 (pack 2) and 32 (single rows), chunks a 100-token prompt at 32,
+    and serves the same greedy tokens as the unconstrained engine."""
+    prompt = list(np.arange(100) % 250 + 16)
+    e1 = InferenceEngine(SPEC, _cfg(chunk=128))
+    await e1.start()
+    want = await _collect(e1, prompt, 6)
+    await e1.close()
+
+    monkeypatch.setattr(
+        InferenceEngine, "_free_device_bytes", lambda self: 4 * 2**20
+    )
+    e2 = InferenceEngine(SPEC, _cfg(chunk=128))
+    assert e2._prefill_shapes == {16: 2, 32: 1}
+    assert e2._prefill_chunk_max() == 32
+    report = e2.precompile()
+    assert "prefill_packed[2x16]" in report and "prefill[32]" in report
+    assert not any(k.startswith("prefill[64]") for k in report)
+    await e2.start()
+    got, short = await asyncio.gather(
+        _collect(e2, prompt, 6), _collect(e2, [5, 9, 13], 4)
+    )
+    assert got == want and len(short) == 4
+    assert e2.allocator.active_pages == 0
+    await e2.close()

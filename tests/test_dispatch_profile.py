@@ -223,27 +223,50 @@ def test_dispatch_overhead_fraction_math():
     assert "eager_readmit" not in READMIT_PHASES
 
 
-def test_compile_cache_env_wiring(tmp_path):
-    """DYN_COMPILE_CACHE_DIR reaches jax config through the engine
-    chokepoint, and RuntimeConfig layers the same knob. Subprocess:
-    jax's cache config is process-global."""
+@pytest.mark.parametrize(
+    "placed,backend,want",
+    [
+        pytest.param(True, "cpu", "{tmp}", id="env"),
+        pytest.param(False, "tpu", "{repo}/.jax_cache", id="default"),
+        pytest.param(False, "cpu", None, id="default-cpu-off"),
+    ],
+)
+def test_compile_cache_placement(tmp_path, placed, backend, want):
+    """Where the persistent compile cache lives is decided OUTSIDE the
+    program: with JAX_COMPILATION_CACHE_DIR set, jax's own handling of
+    the variable is the whole story (the engine chokepoint never touches
+    the directory option); unset, every engine process on an accelerator
+    uses the one fixed <checkout>/.jax_cache, and on the CPU backend the
+    cache stays off. Subprocess: jax's cache config is process-global;
+    the backend the chokepoint asks about is steered here, in the test."""
+    repo = __file__.rsplit("/tests/", 1)[0]
+    if want is not None:
+        want = want.format(tmp=tmp_path, repo=repo)
     code = (
-        "import os, jax\n"
-        "from dynamo_tpu.engine.compile_cache import maybe_enable_compile_cache, active_cache_dir\n"
-        "from dynamo_tpu.runtime.config import RuntimeConfig\n"
-        f"os.environ['DYN_COMPILE_CACHE_DIR'] = {str(tmp_path)!r}\n"
-        "assert maybe_enable_compile_cache()\n"
-        f"assert active_cache_dir() == {str(tmp_path)!r}\n"
-        f"assert jax.config.jax_compilation_cache_dir == {str(tmp_path)!r}\n"
-        "rcfg = RuntimeConfig.from_env()\n"
-        f"assert rcfg.compile_cache_dir == {str(tmp_path)!r}\n"
+        "import jax\n"
+        f"jax.default_backend = lambda: {backend!r}\n"
+        "updates = []\n"
+        "real = jax.config.update\n"
+        "jax.config.update = lambda k, v: (updates.append(k), real(k, v))\n"
+        "from dynamo_tpu.engine import compile_cache as cc\n"
+        f"assert cc.enable_compile_cache() == {want!r}\n"
+        f"assert jax.config.jax_compilation_cache_dir == {want!r}\n"
+        "assert ('jax_compilation_cache_dir' in updates) is "
+        f"{not placed and want is not None}\n"
+        # the zeroed thresholds stay wherever the cache is on
+        "assert (jax.config.jax_persistent_cache_min_compile_time_secs == 0"
+        f") is {want is not None}\n"
+        "assert (jax.config.jax_persistent_cache_min_entry_size_bytes == -1"
+        f") is {want is not None}\n"
         "print('WIRED')\n"
     )
+    env = {"JAX_PLATFORMS": "cpu", "PATH": "/usr/bin:/bin",
+           "PYTHONPATH": "."}
+    if placed:
+        env["JAX_COMPILATION_CACHE_DIR"] = str(tmp_path)
     out = subprocess.run(
         [sys.executable, "-c", code], capture_output=True, text=True,
-        timeout=120, env={"JAX_PLATFORMS": "cpu", "PATH": "/usr/bin:/bin",
-                          "PYTHONPATH": "."},
-        cwd=__file__.rsplit("/tests/", 1)[0],
+        timeout=120, env=env, cwd=repo,
     )
     assert "WIRED" in out.stdout, out.stderr
 

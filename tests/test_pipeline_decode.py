@@ -153,6 +153,7 @@ async def test_async_admission_waves_never_refeed_first_token(monkeypatch):
     token corrupted every later token (caught intermittently by the page
     -pressure test; deterministic here by pinning waves unready so they
     outlive several burst dispatches)."""
+    import jax
     import numpy as _np
 
     class _NeverReady:
@@ -169,6 +170,12 @@ async def test_async_admission_waves_never_refeed_first_token(monkeypatch):
 
         def __array__(self, *a, **kw):
             return _np.asarray(self._dev)
+
+    # the engine hands the wave to its jitted feed: the proxy flattens to
+    # the array it wraps
+    jax.tree_util.register_pytree_node(
+        _NeverReady, lambda p: ((p._dev,), None), lambda _, c: c[0]
+    )
 
     async def run(pipeline, patch):
         cfg = _cfg(pipeline, num_pages=64, slots=2)

@@ -30,9 +30,9 @@ WIRE_PRIMITIVES = frozenset({
     "create_connection", "drain",
 })
 
-# JAX tracing wrappers the jit registry indexes. ``shard_map`` includes the
-# repo's 0.4.x compat shim (ops/shard.py), imported as ``compat_shard_map``
-# at every call site.
+# JAX tracing wrappers the jit registry indexes. The code calls
+# ``jax.shard_map`` directly; ``compat_shard_map`` is the alias the rule
+# fixtures import it under.
 JIT_WRAPPERS = frozenset({"jit", "pjit"})
 SHARD_MAP_WRAPPERS = frozenset({"shard_map", "compat_shard_map"})
 
@@ -329,8 +329,8 @@ class JitInfo:
 
 
 class ShardMapSite:
-    """One ``shard_map``/``compat_shard_map`` call site (incl. the repo's
-    ops/shard.py compat shim) with its declared specs, for DL013."""
+    """One ``shard_map``/``compat_shard_map`` call site with its declared
+    specs, for DL013."""
 
     __slots__ = (
         "path", "context", "line", "col", "node",

@@ -9,7 +9,7 @@ would have no spec at all.
 from jax.sharding import PartitionSpec as P
 
 from dynamo_tpu.ops.quant import is_quant
-from dynamo_tpu.ops.shard import compat_shard_map
+from jax import shard_map as compat_shard_map
 
 
 def _kernel(q, k, v):
