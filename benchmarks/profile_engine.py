@@ -2,7 +2,7 @@
 """Step-thread phase profile of the serving hot loop.
 
 Runs one closed-loop serving rung (same workload as bench.py's ladder:
-ISL=128, OSL=48) with DYNAMO_ENGINE_PROFILE=1 and prints where the step
+ISL=128, OSL=48) with EngineConfig.profile on and prints where the step
 thread's wall time goes: device sync, host bookkeeping, admissions,
 batch building. This is the measurement tool behind the round-5
 serving-efficiency work (VERDICT r4 weak #1: ~40ms/cycle of host-side
@@ -45,11 +45,6 @@ import json
 import os
 import sys
 import time
-
-if __name__ == "__main__":
-    # script mode only: importers (bench.py, tests) must not have the
-    # process-wide profiling env flipped by a mere import
-    os.environ.setdefault("DYNAMO_ENGINE_PROFILE", "1")
 
 import numpy as np
 
@@ -236,6 +231,7 @@ def main() -> None:
         decode_steps_per_dispatch=args.burst,
         pipeline_decode=True,
         spec_mode=args.spec,
+        profile=True,
     )
 
     async def run() -> None:

@@ -354,10 +354,12 @@ class EngineConfig:
     guided_cache_entries: int = 32
     # sampling
     seed: int = 0
-    # step-thread phase profiler (same switch as DYNAMO_ENGINE_PROFILE=1):
-    # per-phase wall seconds + call counts via profile_snapshot(), incl.
-    # the dispatch.* attribution (bench.py turns this on for the serving
-    # ladder so the artifact can carry dispatch_overhead_frac)
+    # step-thread phase profiler, the one switch: per-phase wall seconds +
+    # call counts via profile_snapshot(), incl. the dispatch.* attribution
+    # (bench.py turns this on for the serving ladder so the artifact can
+    # carry dispatch_overhead_frac); every phase, device launch and loop
+    # cycle as an engine.* annotation in a jax.profiler trace; the flight
+    # recorder keeps every finished timeline (docs/OBSERVABILITY.md)
     profile: bool = False
     # scheduler
     step_idle_sleep_s: float = 0.002

@@ -21,7 +21,6 @@ import asyncio
 import collections
 import contextlib
 import logging
-import os
 import queue
 import threading
 import time
@@ -118,10 +117,6 @@ class _Slot:
     # feeds the next decode burst there); the host value materializes one
     # step later without ever blocking the step thread on the d2h RTT
     first_pending: bool = False
-    # re-admission gap attribution (profiling mode): when this request
-    # left the waiting queue / when its prefill+sample dispatch completed
-    admit_t: float = 0.0
-    prefill_done_t: float = 0.0
     # speculative decoding (engine/spec.py): per-slot drafter + adaptive
     # k; None = this slot never speculates (spec off, temperature > 0,
     # logprobs requested)
@@ -144,8 +139,10 @@ class _Waiting:
     request: dict[str, Any]
     context: Context
     out_q: asyncio.Queue
-    enq_t: float = 0.0  # perf_counter at enqueue (admit-wait attribution)
-    admit_t: float = 0.0  # perf_counter when the step thread dequeued it
+    # running launch number (_launch) of the prefill program that took
+    # this request's last prompt tokens: the flight recorder's
+    # prefill_dispatch event carries it, as the profiler trace does
+    prefill_seq: int = 0
     # tenancy routing keys (read by TenantScheduler): priority class
     # picks the lane group, tenant the lane, cost the WFQ vtime advance
     tenant: str = "default"
@@ -163,6 +160,46 @@ class _Waiting:
 
 
 _REQUEUED = object()  # _prefill sentinel: entry went back to the queue
+
+# finished timelines a profiled engine asks the flight recorder to keep:
+# a measured window's worth (a minute at 50 requests a second)
+PROFILE_TIMELINES = 4096
+
+# Timeline.admission_phases() name -> the profile sum it feeds
+READMIT_SUMS = {
+    "admit_wait": "readmit.admit_wait",
+    "prefill_dispatch": "readmit.prefill_dispatch",
+    "first_token": "readmit.first_token",
+}
+
+# what _phase and _launch hand out with profiling off: one shared object
+# whose enter and exit do nothing
+_NO_SPAN = contextlib.nullcontext()
+
+
+class _PhaseSpan:
+    """One step-thread phase of a profiled engine: its wall time goes to
+    the phase sums, and it is an ``engine.<name>`` annotation in a
+    jax.profiler trace (a no-op of the profiler's while none is taken),
+    so the phases share the device events' clock."""
+
+    __slots__ = ("_prof", "_name", "_t0", "_note")
+
+    def __init__(self, prof: dict[str, list[float]], name: str):
+        self._prof = prof
+        self._name = name
+        self._note = jax.profiler.TraceAnnotation("engine." + name)
+
+    def __enter__(self) -> None:
+        self._note.__enter__()
+        self._t0 = time.perf_counter()
+
+    def __exit__(self, *exc) -> None:
+        dt = time.perf_counter() - self._t0
+        self._note.__exit__(*exc)
+        rec = self._prof.setdefault(self._name, [0.0, 0])
+        rec[0] += dt
+        rec[1] += 1
 
 
 @dataclass
@@ -401,14 +438,24 @@ class InferenceEngine:
         self._moe_dropped_dev = None  # device-side running drop count
         self.moe_dropped_slots = 0  # last fetched total (metrics surface)
         self._metrics_publishes = 0
-        # step-thread phase profiler (DYNAMO_ENGINE_PROFILE=1 or
-        # EngineConfig.profile): wall seconds + call counts per phase,
-        # read via profile_snapshot()
-        self._profiling = (
-            self.config.profile
-            or os.environ.get("DYNAMO_ENGINE_PROFILE") == "1"
-        )
+        # step-thread phase profiler (EngineConfig.profile, the one
+        # switch): wall seconds + call counts per phase, read via
+        # profile_snapshot(), and every phase, device launch and loop
+        # cycle as an ``engine.*`` annotation in a jax.profiler trace
+        self._profiling = bool(self.config.profile)
         self._prof: dict[str, list[float]] = {}
+        # per-request sums (readmit.*), added where a stream finishes (the
+        # event loop) from its flight-recorder timeline: kept apart from
+        # _prof, which only the step thread writes
+        self._prof_requests: dict[str, list[float]] = {}
+        # running number of the device programs the step thread has
+        # launched (_launch): always on, one int add a launch
+        self._launch_seq = 0
+        # the flight recorder the step thread records into; profiled, it
+        # keeps every finished timeline of a measured window
+        self.flight = FLIGHT
+        if self._profiling:
+            FLIGHT.retain(PROFILE_TIMELINES)
         # dispatch accounting (always on — one int add per device
         # dispatch): jitted programs issued by the step thread, plus the
         # process-wide compile-event baseline so profile_snapshot can
@@ -454,31 +501,39 @@ class InferenceEngine:
             return None
         return max(0, limit - stats.get("bytes_in_use", 0) - limit // 10)
 
-    def _prof_add(self, name: str, dt: float) -> None:
-        """Accumulate one timed event into the phase profiler (no-op
-        unless DYNAMO_ENGINE_PROFILE=1). Used for the re-admission gap
-        attribution: ``readmit.admit_wait`` / ``readmit.prefill_dispatch``
-        / ``readmit.first_token`` break the finish->next-first-token path
-        into named phases (benchmarks/profile_engine.py)."""
-        if not self._profiling:
-            return
-        rec = self._prof.setdefault(name, [0.0, 0])
-        rec[0] += dt
-        rec[1] += 1
-
-    @contextlib.contextmanager
-    def _phase(self, name: str):
-        if not self._profiling:
-            yield
-            return
-        t0 = time.perf_counter()
-        try:
-            yield
-        finally:
-            dt = time.perf_counter() - t0
-            rec = self._prof.setdefault(name, [0.0, 0])
+    def _prof_requests_add(self, tl) -> None:
+        """The re-admission gap attribution of one finished request, from
+        its flight-recorder timeline (runtime/flight.py
+        ``admission_phases``): ``readmit.admit_wait`` /
+        ``readmit.prefill_dispatch`` / ``readmit.first_token`` break the
+        finish->next-first-token path into named phases
+        (benchmarks/profile_engine.py). Event loop only."""
+        for name, dt in tl.admission_phases():
+            rec = self._prof_requests.setdefault(READMIT_SUMS[name], [0.0, 0])
             rec[0] += dt
             rec[1] += 1
+
+    def _phase(self, name: str):
+        """A step-thread phase. Profiled: its wall time is summed under
+        ``name`` and it is an ``engine.<name>`` annotation on the
+        profiler's clock. Unprofiled: the one shared no-op."""
+        if not self._profiling:
+            return _NO_SPAN
+        return _PhaseSpan(self._prof, name)
+
+    def _launch(self, kind: str, **counts: int):
+        """Around one device program the step thread issues: numbers it
+        and, profiled, wraps the issue in an ``engine.launch`` annotation
+        carrying ``kind``, ``seq`` and ``counts``. The device runs one
+        stream in launch order, so a trace's k-th launch of a kind is its
+        k-th execution of that kind. ``counts`` are host values only: an
+        annotation never reads a device array."""
+        self._launch_seq += 1
+        if not self._profiling:
+            return _NO_SPAN
+        return jax.profiler.TraceAnnotation(
+            "engine.launch", kind=kind, seq=self._launch_seq, **counts
+        )
 
     def profile_snapshot(self) -> dict[str, dict[str, float]]:
         """Per-phase accumulated step-thread wall time (profiling mode),
@@ -498,7 +553,8 @@ class InferenceEngine:
         snap = {
             k: {"secs": round(v[0], 4), "calls": int(v[1])}
             for k, v in sorted(
-                self._prof.items(), key=lambda kv: -kv[1][0]
+                (*self._prof.items(), *self._prof_requests.items()),
+                key=lambda kv: -kv[1][0],
             )
         }
         snap.setdefault("dispatch.d2h_wait", {"secs": 0.0, "calls": 0})
@@ -516,6 +572,7 @@ class InferenceEngine:
         covers only work from this point on (drop warmup/compile noise
         before a measured window — bench.py, profile_engine.py)."""
         self._prof.clear()
+        self._prof_requests.clear()
         self.dispatches = 0
         self._compile_base = compile_snapshot()
 
@@ -1244,7 +1301,7 @@ class InferenceEngine:
         out_q: asyncio.Queue = asyncio.Queue()
         self._waiting.put_nowait(
             _Waiting(
-                request, context, out_q, enq_t=time.perf_counter(),
+                request, context, out_q,
                 tenant=tenant, priority=priority, cost=cost, charged=True,
             )
         )
@@ -1293,7 +1350,11 @@ class InferenceEngine:
                         "shed under overload (outranked while waiting)",
                         retry_after_s=float(item["_shed"]),
                     )
-                n_generated += len(item.get("token_ids") or ())
+                toks = item.get("token_ids")
+                if toks:
+                    if n_generated == 0:
+                        FLIGHT.event(context.id, "first_delta")
+                    n_generated += len(toks)
                 # record BEFORE the yield: downstream operators stop
                 # iterating once they see the finish item, so this
                 # generator may never be resumed past it (it gets a
@@ -1313,6 +1374,8 @@ class InferenceEngine:
             )
             if tl is not None:
                 emit_request_spans(tl)
+                if self._profiling:
+                    self._prof_requests_add(tl)
 
     # -- step loop ---------------------------------------------------------
 
@@ -1335,6 +1398,14 @@ class InferenceEngine:
                     # silently consume limit-based specs (xN) before any
                     # request is in flight.
                     FAULTS.fire_sync("engine.step")
+                if self._profiling:
+                    # once a cycle, the clock FLIGHT and the clients use
+                    # beside the profiler's: a trace reader fits the
+                    # offset from these
+                    with jax.profiler.TraceAnnotation(
+                        "engine.clock", mono_ns=time.monotonic_ns()
+                    ):
+                        pass
                 step_t0 = time.perf_counter()
                 did_work = self._step()
                 if did_work:
@@ -1490,7 +1561,8 @@ class InferenceEngine:
         # runs below, so prefills steal at most a budget's worth of device
         # time per step
         if self._partial is not None:
-            self._advance_partial_safe()
+            with self._phase("advance_partial"):
+                self._advance_partial_safe()
             did = True
             self._publish_metrics()
         else:
@@ -1553,74 +1625,64 @@ class InferenceEngine:
         pending: list[tuple] = []
         preps: list[dict] = []
         reserved: set[int] = set()
-        admit_t0 = time.perf_counter() if self._profiling else 0.0
-        while self._partial is None:
-            free_idx = next(
-                (
-                    i
-                    for i, s in enumerate(self._slots)
-                    if s is None and i not in reserved
-                ),
-                None,
-            )
-            if free_idx is None and not self._waiting.empty():
-                # no free slot for a waiting INTERACTIVE request: pause
-                # an over-quota batch stream instead of making the
-                # interactive user wait out the batch tenant's backlog
-                free_idx = self._preempt_for_admission(reserved)
-            if free_idx is None or self._waiting.empty():
-                break
-            cost = len(
-                self._peek_waiting_tokens() or ()
-            ) or 1
-            cost = min(cost, self._prefill_chunk_max())
-            if admitted and cost > budget and decoding and not warm:
-                break  # first admission always proceeds
-            if not decoding and n_admitted >= cold_cap:
-                break  # stagger the cold wave (convoy breaker)
-            try:
-                waiting = self._waiting.get_nowait()
-            except queue.Empty:
-                # a concurrent shed (event loop) emptied the queue
-                # between the check and the dequeue
-                break
-            FLIGHT.event(waiting.context.id, "admit")
-            if self._profiling:
-                waiting.admit_t = time.perf_counter()
-                if waiting.enq_t:
-                    self._prof_add(
-                        "readmit.admit_wait", waiting.admit_t - waiting.enq_t
-                    )
-            if waiting.context.is_stopped:
-                self._drop_staged_kv(waiting.request)
-                self._post(
-                    waiting.out_q,
-                    {"token_ids": [], "finish_reason": "cancelled"},
+        with self._phase("admit_loop"):
+            while self._partial is None:
+                free_idx = next(
+                    (
+                        i
+                        for i, s in enumerate(self._slots)
+                        if s is None and i not in reserved
+                    ),
+                    None,
                 )
-            else:
-                out = self._prefill_safe(free_idx, waiting)
-                if out is _REQUEUED:
-                    # page backpressure: the entry went back to its
-                    # lane; nothing else can admit this pass either
-                    # (the pool is the shared constraint) — retry next
-                    # step. NOT counted as work: when the whole engine
-                    # is page-stalled the loop must pace on the idle
-                    # wait, not hot-spin OutOfPages retries.
+                if free_idx is None and not self._waiting.empty():
+                    # no free slot for a waiting INTERACTIVE request: pause
+                    # an over-quota batch stream instead of making the
+                    # interactive user wait out the batch tenant's backlog
+                    free_idx = self._preempt_for_admission(reserved)
+                if free_idx is None or self._waiting.empty():
                     break
-                if isinstance(out, dict):
-                    preps.append(out)
-                    reserved.add(free_idx)
-                elif out is not None:
-                    pending.append(out)
-                    reserved.add(free_idx)
-                budget -= cost
-                admitted = True
-                n_admitted += 1
-            did = True
-        if self._profiling and admitted:
-            rec = self._prof.setdefault("admit_loop", [0.0, 0])
-            rec[0] += time.perf_counter() - admit_t0
-            rec[1] += 1
+                cost = len(
+                    self._peek_waiting_tokens() or ()
+                ) or 1
+                cost = min(cost, self._prefill_chunk_max())
+                if admitted and cost > budget and decoding and not warm:
+                    break  # first admission always proceeds
+                if not decoding and n_admitted >= cold_cap:
+                    break  # stagger the cold wave (convoy breaker)
+                try:
+                    waiting = self._waiting.get_nowait()
+                except queue.Empty:
+                    # a concurrent shed (event loop) emptied the queue
+                    # between the check and the dequeue
+                    break
+                FLIGHT.event(waiting.context.id, "admit")
+                if waiting.context.is_stopped:
+                    self._drop_staged_kv(waiting.request)
+                    self._post(
+                        waiting.out_q,
+                        {"token_ids": [], "finish_reason": "cancelled"},
+                    )
+                else:
+                    out = self._prefill_safe(free_idx, waiting)
+                    if out is _REQUEUED:
+                        # page backpressure: the entry went back to its
+                        # lane; nothing else can admit this pass either
+                        # (the pool is the shared constraint) — retry next
+                        # step. NOT counted as work: when the whole engine
+                        # is page-stalled the loop must pace on the idle
+                        # wait, not hot-spin OutOfPages retries.
+                        break
+                    if isinstance(out, dict):
+                        preps.append(out)
+                        reserved.add(free_idx)
+                    elif out is not None:
+                        pending.append(out)
+                        reserved.add(free_idx)
+                    budget -= cost
+                    admitted = True
+                    n_admitted += 1
+                did = True
         # packed prefill: all same-bucket preps in ONE dispatch each
         with self._phase("packed_prefill"):
             pending.extend(self._run_packed_prefills(preps))
@@ -1796,7 +1858,6 @@ class InferenceEngine:
             self._slots[i] = None
             self._waiting.put_nowait(_Waiting(
                 resume, slot.context, slot.out_q,
-                enq_t=time.perf_counter(),
                 tenant=slot.tenant, priority=slot.priority,
                 cost=float(len(resume["token_ids"]) + slot.remaining),
             ))
@@ -2344,7 +2405,6 @@ class InferenceEngine:
             last_token=last_token,
             sample_seed=sample_seed,
             logprobs=logprobs,
-            admit_t=waiting.admit_t,
             spec=slot_spec,
             guided=guided_state,
         )
@@ -2517,6 +2577,7 @@ class InferenceEngine:
             logits = self._run_prefill_chunk(
                 sp, token_ids, start_pos, len(token_ids), mm=mm
             )
+            waiting.prefill_seq = self._launch_seq
             self._seal_prompt_blocks(sp, seq)  # salted hashes: cache-safe
             self._drain_offload()
             return (
@@ -2547,18 +2608,20 @@ class InferenceEngine:
                     {"num_tokens": tail},
                     {"tokens": padded, "block_table": block_table},
                 )
-            logits, self.k_pages, self.v_pages, dropped = (
-                self.fam.prefill_ring(
-                    self.spec,
-                    self.params,
-                    jnp.asarray(padded),
-                    jnp.asarray(block_table),
-                    self.k_pages,
-                    self.v_pages,
-                    jnp.asarray(tail, jnp.int32),
-                    mesh=self.mesh,
+            with self._launch("prefill", tokens=tail, rows=1):
+                logits, self.k_pages, self.v_pages, dropped = (
+                    self.fam.prefill_ring(
+                        self.spec,
+                        self.params,
+                        jnp.asarray(padded),
+                        jnp.asarray(block_table),
+                        self.k_pages,
+                        self.v_pages,
+                        jnp.asarray(tail, jnp.int32),
+                        mesh=self.mesh,
+                    )
                 )
-            )
+            waiting.prefill_seq = self._launch_seq
             self.dispatches += 1
             self._note_moe_dropped(dropped)
             self._seal_prompt_blocks(sp, seq)
@@ -2644,14 +2707,20 @@ class InferenceEngine:
                         {"tokens": tokens, "block_tables": bts,
                          "start": starts, "num_tokens": nts},
                     )
-                logits, self.k_pages, self.v_pages, dropped = (
-                    self.fam.prefill_batch(
-                        self.spec, self.params, jnp.asarray(tokens),
-                        jnp.asarray(bts), jnp.asarray(starts),
-                        self.k_pages, self.v_pages, jnp.asarray(nts),
-                        mesh=self.mesh,
+                with self._launch(
+                    "prefill", tokens=sum(p["tail"] for p in group),
+                    rows=len(group),
+                ):
+                    logits, self.k_pages, self.v_pages, dropped = (
+                        self.fam.prefill_batch(
+                            self.spec, self.params, jnp.asarray(tokens),
+                            jnp.asarray(bts), jnp.asarray(starts),
+                            self.k_pages, self.v_pages, jnp.asarray(nts),
+                            mesh=self.mesh,
+                        )
                     )
-                )
+                for p in group:
+                    p["waiting"].prefill_seq = self._launch_seq
                 self.dispatches += 1
                 self._note_moe_dropped(dropped)
             except Exception as e:  # noqa: BLE001
@@ -2705,11 +2774,12 @@ class InferenceEngine:
         params = [self._sampling_params(w.request) for w in waitings]
         for i, (t, k, p, s) in enumerate(params):
             temps[i], topk[i], topp[i], seeds[i] = t, k, p, s
-        samples = sample_tokens(
-            logits, jnp.asarray(temps), jnp.asarray(topk),
-            jnp.asarray(topp), jnp.asarray(seeds),
-            jnp.zeros((nb,), jnp.int32),  # first token: RNG step 0
-        )
+        with self._launch("sample", rows=len(waitings)):
+            samples = sample_tokens(
+                logits, jnp.asarray(temps), jnp.asarray(topk),
+                jnp.asarray(topp), jnp.asarray(seeds),
+                jnp.zeros((nb,), jnp.int32),  # first token: RNG step 0
+            )
         self.dispatches += 1
         # NO host copy here: the dispatch's samples become one admission
         # wave with a single async copy (_complete_admissions_async), and
@@ -2744,6 +2814,7 @@ class InferenceEngine:
             logits = self._run_prefill_chunk(
                 p["sp"], p["token_ids"], p["start_pos"], len(p["token_ids"])
             )
+            p["waiting"].prefill_seq = self._launch_seq
             self._seal_prompt_blocks(p["sp"], p["seq"])
             pres = self._fused_first_tokens(logits[None, :], [p["waiting"]])
             return (
@@ -2818,19 +2889,21 @@ class InferenceEngine:
             gmask = self._admission_guided_mask(
                 [r[2] for r in recs], stacked.shape[0]
             )
-            if gmask is not None:
-                sampled_dev = sample_tokens_masked(
-                    stacked, jnp.asarray(gmask), *sample_args
-                )
-            else:
-                sampled_dev = sample_tokens(stacked, *sample_args)
+            with self._launch("sample", rows=len(recs)):
+                if gmask is not None:
+                    sampled_dev = sample_tokens_masked(
+                        stacked, jnp.asarray(gmask), *sample_args
+                    )
+                else:
+                    sampled_dev = sample_tokens(stacked, *sample_args)
             self.dispatches += 1
             # logprobs, when any admitted prompt wants them, batch over the
             # same stacked logits: one more fused sync, not one per record
             lp = top_i = top_v = None
             if any(r[2].logprobs is not None for r in recs):
                 n_lp = min(20, self.spec.vocab_size - 1)
-                picked, ti, tv = token_logprobs(stacked, sampled_dev, n_lp)
+                with self._launch("logprobs", rows=len(recs)):
+                    picked, ti, tv = token_logprobs(stacked, sampled_dev, n_lp)
                 self.dispatches += 1
                 # readmit.d2h_wait, NOT dispatch.d2h_wait: this span
                 # nests inside the complete_admissions phase the
@@ -2855,14 +2928,7 @@ class InferenceEngine:
                 )
             return
 
-        if self._profiling:
-            now = time.perf_counter()
-            for _si, _w, slot, _lr, _t, _sp in recs:
-                if slot.admit_t:
-                    self._prof_add(
-                        "readmit.prefill_dispatch", now - slot.admit_t
-                    )
-                slot.prefill_done_t = now
+        self._record_prefill_dispatch(r[1] for r in recs)
         for i, (slot_idx, waiting, slot, _logits_ref, token_ids, sp) in enumerate(recs):
             # per-record isolation: one bad emit (disagg export, handoff)
             # must not strand the step's other admissions
@@ -2905,6 +2971,16 @@ class InferenceEngine:
                         {"token_ids": [], "finish_reason": "error",
                          "error": f"admission failed: {e}"},
                     )
+
+    @staticmethod
+    def _record_prefill_dispatch(waitings) -> None:
+        """The flight recorder's ``prefill_dispatch``: these requests'
+        prefill and first-token sample are on the device's queue."""
+        for waiting in waitings:
+            FLIGHT.event(
+                waiting.context.id, "prefill_dispatch",
+                seq=waiting.prefill_seq,
+            )
 
     def _admission_guided_mask(
         self, slots: list, width: int
@@ -3015,7 +3091,8 @@ class InferenceEngine:
                     [self._logits_row(lr) for _, _, lr in unsampled],
                     on_device=True,
                 )
-                sampled_dev = sample_tokens(stacked, *sample_args)
+                with self._launch("sample", rows=len(unsampled)):
+                    sampled_dev = sample_tokens(stacked, *sample_args)
                 self.dispatches += 1
                 waves[id(sampled_dev)] = {
                     "dev": sampled_dev,
@@ -3045,14 +3122,7 @@ class InferenceEngine:
                      "error": f"prefill failed: {e}"},
                 )
             return
-        if self._profiling:
-            now = time.perf_counter()
-            for _si, slot in recs:
-                slot.prefill_done_t = now
-                if slot.admit_t:
-                    self._prof_add(
-                        "readmit.prefill_dispatch", now - slot.admit_t
-                    )
+        self._record_prefill_dispatch(r[1] for r in pending)
         for slot_idx, slot in recs:
             self._slots[slot_idx] = slot
         self._admit_waves.extend(waves.values())
@@ -3168,12 +3238,6 @@ class InferenceEngine:
     def _land_first_token(self, slot_idx: int, slot: _Slot, tok: int) -> None:
         """Record + stream an async admission's first token (stop
         semantics of _accept_token, with counters pre-advanced)."""
-        if self._profiling and slot.prefill_done_t:
-            self._prof_add(
-                "readmit.first_token",
-                time.perf_counter() - slot.prefill_done_t,
-            )
-            slot.prefill_done_t = 0.0
         FLIGHT.event(slot.context.id, "first_token")
         slot.seq.append(tok)
         slot.last_token = tok
@@ -3242,18 +3306,19 @@ class InferenceEngine:
                 {"start": start, "num_tokens": len(new_tokens)},
                 {"tokens": padded, "block_table": block_table, **mm_arrays},
             )
-        logits, self.k_pages, self.v_pages, dropped = self.fam.prefill(
-            self.spec,
-            self.params,
-            jnp.asarray(padded),
-            jnp.asarray(block_table),
-            jnp.asarray(start, jnp.int32),
-            self.k_pages,
-            self.v_pages,
-            jnp.asarray(len(new_tokens), jnp.int32),
-            mesh=self.mesh,
-            **mm_kwargs,
-        )
+        with self._launch("prefill", tokens=len(new_tokens), rows=1):
+            logits, self.k_pages, self.v_pages, dropped = self.fam.prefill(
+                self.spec,
+                self.params,
+                jnp.asarray(padded),
+                jnp.asarray(block_table),
+                jnp.asarray(start, jnp.int32),
+                self.k_pages,
+                self.v_pages,
+                jnp.asarray(len(new_tokens), jnp.int32),
+                mesh=self.mesh,
+                **mm_kwargs,
+            )
         self.dispatches += 1
         self._note_moe_dropped(dropped)
         return logits
@@ -3287,6 +3352,7 @@ class InferenceEngine:
         end = min(p.done + self._prefill_chunk_max(), len(p.token_ids))
         FLIGHT.event(p.waiting.context.id, "prefill_chunk")
         logits = self._run_prefill_chunk(p.sp, p.token_ids, p.done, end)
+        p.waiting.prefill_seq = self._launch_seq
         p.done = end
         if end == len(p.token_ids):
             self._partial = None
@@ -3616,15 +3682,16 @@ class InferenceEngine:
                 for j in range(min(len(row), len(masks))):
                     allowed[r, j] = masks[j]
         with self._phase("spec.verify"):
-            targets, self.k_pages, self.v_pages, dropped = self.fam.verify(
-                self.spec, self.params, jnp.asarray(tokens),
-                jnp.asarray(bts), jnp.asarray(starts),
-                self.k_pages, self.v_pages, jnp.asarray(nts),
-                mesh=self.mesh,
-                allowed=(
-                    jnp.asarray(allowed) if allowed is not None else None
-                ),
-            )
+            with self._launch("verify", tokens=int(nts.sum()), rows=len(ready)):
+                targets, self.k_pages, self.v_pages, dropped = self.fam.verify(
+                    self.spec, self.params, jnp.asarray(tokens),
+                    jnp.asarray(bts), jnp.asarray(starts),
+                    self.k_pages, self.v_pages, jnp.asarray(nts),
+                    mesh=self.mesh,
+                    allowed=(
+                        jnp.asarray(allowed) if allowed is not None else None
+                    ),
+                )
             self.dispatches += 1
             self._note_moe_dropped(dropped)
             with self._phase("dispatch.d2h_wait"):
@@ -4005,9 +4072,10 @@ class InferenceEngine:
             )
         tokens_in = self._feed_array(batch["tokens"])
         for valid, prev in zip(chain_valids, chain or ()):
-            tokens_in = _chain_feed(
-                jnp.asarray(valid), prev["results"][0], tokens_in
-            )
+            with self._launch("feed"):
+                tokens_in = _chain_feed(
+                    jnp.asarray(valid), prev["results"][0], tokens_in
+                )
         for ap in self._admit_waves:
             # freshly admitted slots: feed their first token from the
             # device-side admission sample (its host copy is still in
@@ -4029,30 +4097,36 @@ class InferenceEngine:
                     idx[slot_idx] = row
                     ap["fed"].add(slot_idx)
             if mask.any():
-                tokens_in = _wave_feed(
-                    jnp.asarray(mask), jnp.asarray(idx), ap["dev"], tokens_in
-                )
+                with self._launch("feed"):
+                    tokens_in = _wave_feed(
+                        jnp.asarray(mask), jnp.asarray(idx), ap["dev"],
+                        tokens_in,
+                    )
         self.dispatches += 1
         allowed = batch.get("allowed")
-        result = self.fam.decode_steps(
-            self.spec,
-            self.params,
-            tokens_in,
-            jnp.asarray(batch["block_tables"]),
-            jnp.asarray(batch["seq_lens"]),
-            self.k_pages,
-            self.v_pages,
-            jnp.asarray(batch["active"]),
-            jnp.asarray(batch["temps"]),
-            jnp.asarray(batch["topk"]),
-            jnp.asarray(batch["topp"]),
-            jnp.asarray(batch["seeds"]),
-            jnp.asarray(batch["steps"]),
-            n_steps=batch["n_burst"],
-            n_logprobs=batch["n_lp"],
-            mesh=self.mesh,
-            allowed=jnp.asarray(allowed) if allowed is not None else None,
-        )
+        with self._launch(
+            "decode", steps=batch["n_burst"],
+            live=len(batch["participants"]), slots=len(self._slots),
+        ):
+            result = self.fam.decode_steps(
+                self.spec,
+                self.params,
+                tokens_in,
+                jnp.asarray(batch["block_tables"]),
+                jnp.asarray(batch["seq_lens"]),
+                self.k_pages,
+                self.v_pages,
+                jnp.asarray(batch["active"]),
+                jnp.asarray(batch["temps"]),
+                jnp.asarray(batch["topk"]),
+                jnp.asarray(batch["topp"]),
+                jnp.asarray(batch["seeds"]),
+                jnp.asarray(batch["steps"]),
+                n_steps=batch["n_burst"],
+                n_logprobs=batch["n_lp"],
+                mesh=self.mesh,
+                allowed=jnp.asarray(allowed) if allowed is not None else None,
+            )
         if batch["n_lp"] > 0:
             sampled, lp, top_i, top_v, self.k_pages, self.v_pages = result
         else:
@@ -4064,7 +4138,8 @@ class InferenceEngine:
         # host copy) materialize from THIS download when the burst
         # processes, keeping the whole cycle at ONE device->host
         # transfer
-        combined = _with_fed_column(tokens_in, sampled)
+        with self._launch("feed"):
+            combined = _with_fed_column(tokens_in, sampled)
         # start the d2h NOW: by processing time (a cycle later) the copy
         # has landed and the host asarray is free — the fresh download
         # RTT rides under the next burst's execution
@@ -4252,14 +4327,6 @@ class InferenceEngine:
         logprob_entry: dict | None = None,
     ) -> None:
         """Record + stream one sampled token; place slot or finish."""
-        if self._profiling and slot.prefill_done_t:
-            # sync-admission first token: sample + d2h ran inline just
-            # before this emit, so the residual here is host bookkeeping
-            self._prof_add(
-                "readmit.first_token",
-                time.perf_counter() - slot.prefill_done_t,
-            )
-            slot.prefill_done_t = 0.0
         FLIGHT.event(slot.context.id, "first_token")
         finish = self._accept_token(slot, tok)
         if finish is not None:

@@ -16,7 +16,13 @@ import jax.numpy as jnp
 NEG_INF = -1e30
 
 
+# the sampler's region of a program (decode bursts inline it): op_name
+# metadata only, as models/llama.py's scopes
+SCOPE_SAMPLER = "sampler"
+
+
 @partial(jax.jit, static_argnames=("n_top",))
+@jax.named_scope(SCOPE_SAMPLER)
 def token_logprobs(
     logits: jax.Array,  # [B, V] f32
     sampled: jax.Array,  # [B] int32
@@ -37,6 +43,7 @@ def token_logprobs(
     return picked, top_ids.astype(jnp.int32), top_vals
 
 
+@jax.named_scope(SCOPE_SAMPLER)
 def _sample_tokens_impl(
     logits: jax.Array,  # [B, V] f32
     temperature: jax.Array,  # [B]

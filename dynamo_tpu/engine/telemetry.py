@@ -76,7 +76,7 @@ _M_REJECTS = REGISTRY.counter(
 _M_OVERHEAD = REGISTRY.gauge(
     "engine_dispatch_overhead_frac",
     "step-thread d2h-blocked fraction of the sample window "
-    "(0 unless DYNAMO_ENGINE_PROFILE=1)", ["engine"],
+    "(0 unless EngineConfig.profile)", ["engine"],
 )
 _M_SPEC_ACCEPT = REGISTRY.gauge(
     "engine_spec_acceptance_rate",

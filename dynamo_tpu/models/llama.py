@@ -316,6 +316,17 @@ def rope_spec(spec: ModelSpec, x: jax.Array, positions: jax.Array) -> jax.Array:
     return rope(x, positions, spec.rope_theta, inv_freq=inv, scale=att)
 
 
+# Stable names for the regions of a layer, in decode and prefill alike:
+# jax.named_scope is metadata on the operations (their op_name in an HLO
+# dump and in a profiler's operation details); it changes no program.
+SCOPE_QKV = "attn_qkv"  # q/k/v projections + rope
+SCOPE_KV = "attn_kv"  # KV write + attention over the paged context
+SCOPE_OUT = "attn_out"  # output projection
+SCOPE_MLP = "mlp"
+SCOPE_HEAD = "head"  # final norm + vocabulary projection
+
+
+@jax.named_scope(SCOPE_QKV)
 def _attn_qkv(spec: ModelSpec, lp: Params, x: jax.Array, positions: jax.Array):
     """x: [T, d] -> q [T, nh, hd], k/v [T, nkv, hd] with rope applied."""
     T = x.shape[0]
@@ -332,6 +343,7 @@ def _attn_qkv(spec: ModelSpec, lp: Params, x: jax.Array, positions: jax.Array):
     return q, k, v
 
 
+@jax.named_scope(SCOPE_OUT)
 def _o_proj(spec: ModelSpec, lp: Params, attn: jax.Array) -> jax.Array:
     out = attn @ lp["wo"]
     return out + lp["bo"] if spec.attn_bias else out
@@ -341,6 +353,7 @@ def _mlp(lp: Params, x: jax.Array) -> jax.Array:
     return (jax.nn.silu(x @ lp["w_gate"]) * (x @ lp["w_up"])) @ lp["w_down"]
 
 
+@jax.named_scope(SCOPE_MLP)
 def _ffn(spec: ModelSpec, lp: Params, x: jax.Array) -> jax.Array:
     """Dense MLP or routed MoE depending on the spec."""
     if spec.num_experts:
@@ -350,6 +363,7 @@ def _ffn(spec: ModelSpec, lp: Params, x: jax.Array) -> jax.Array:
     return _mlp(lp, x)
 
 
+@jax.named_scope(SCOPE_MLP)
 def _ffn_counted(spec: ModelSpec, lp: Params, x: jax.Array):
     """_ffn + dropped-slot count (0 for dense layers)."""
     if spec.num_experts:
@@ -359,6 +373,7 @@ def _ffn_counted(spec: ModelSpec, lp: Params, x: jax.Array):
     return _mlp(lp, x), jnp.zeros((), jnp.int32)
 
 
+@jax.named_scope(SCOPE_HEAD)
 def _logits(spec: ModelSpec, params: Params, x: jax.Array) -> jax.Array:
     x = rms_norm(x, params["final_norm"], spec.rms_eps)
     head = params["embed"].T if spec.tie_embeddings else params["lm_head"]
@@ -419,29 +434,31 @@ def prefill_forward_impl(
     for li, lp in enumerate(params["layers"]):
         h = rms_norm(x, lp["attn_norm"], spec.rms_eps)
         q, k, v = _attn_qkv(spec, lp, h, positions)
-        k_pages = _set_page_tiles(k_pages, li, safe_pg, k, page_size,
-                                  valid_tok)
-        v_pages = _set_page_tiles(v_pages, li, safe_pg, v, page_size,
-                                  valid_tok)
-        # [max_ctx, kvh, D] — sliced back to the model dim when padded,
-        # dequantized when the pool is fp8
-        k_ctx = gather_ctx(k_pages, li, block_table, spec.head_dim)
-        v_ctx = gather_ctx(v_pages, li, block_table, spec.head_dim)
-        if is_quant(k_pages):
-            # overlay the EXACT in-flight rows over the quantized
-            # read-back (the XLA mirror of the fused kernel's analytic
-            # new-token merge): this prefill's own tokens attend to each
-            # other at full precision; only the cached prefix pays fp8
-            k_ctx = k_ctx.at[positions].set(
-                k.astype(k_ctx.dtype), mode="drop"
+        with jax.named_scope(SCOPE_KV):
+            k_pages = _set_page_tiles(k_pages, li, safe_pg, k, page_size,
+                                      valid_tok)
+            v_pages = _set_page_tiles(v_pages, li, safe_pg, v, page_size,
+                                      valid_tok)
+            # [max_ctx, kvh, D] — sliced back to the model dim when
+            # padded, dequantized when the pool is fp8
+            k_ctx = gather_ctx(k_pages, li, block_table, spec.head_dim)
+            v_ctx = gather_ctx(v_pages, li, block_table, spec.head_dim)
+            if is_quant(k_pages):
+                # overlay the EXACT in-flight rows over the quantized
+                # read-back (the XLA mirror of the fused kernel's analytic
+                # new-token merge): this prefill's own tokens attend to
+                # each other at full precision; only the cached prefix
+                # pays fp8
+                k_ctx = k_ctx.at[positions].set(
+                    k.astype(k_ctx.dtype), mode="drop"
+                )
+                v_ctx = v_ctx.at[positions].set(
+                    v.astype(v_ctx.dtype), mode="drop"
+                )
+            attn = causal_attention(
+                q, k_ctx, v_ctx, positions, kv_len,
+                window=spec.attn_window(li), sinks=lp.get("sinks"),
             )
-            v_ctx = v_ctx.at[positions].set(
-                v.astype(v_ctx.dtype), mode="drop"
-            )
-        attn = causal_attention(
-            q, k_ctx, v_ctx, positions, kv_len,
-            window=spec.attn_window(li), sinks=lp.get("sinks"),
-        )
         attn = attn.reshape(T, spec.num_heads * spec.head_dim)
         x = x + _o_proj(spec, lp, attn)
         h = rms_norm(x, lp["mlp_norm"], spec.rms_eps)
@@ -517,20 +534,22 @@ def prefill_forward_batch_impl(
 
     for li, lp in enumerate(params["layers"]):
         h = rms_norm(x, lp["attn_norm"], spec.rms_eps)
-        q = h @ lp["wq"]
-        k = h @ lp["wk"]
-        v = h @ lp["wv"]
-        if spec.attn_bias:
-            q, k, v = q + lp["bq"], k + lp["bk"], v + lp["bv"]
-        q = q.reshape(N, T, spec.num_heads, spec.head_dim)
-        k = k.reshape(N, T, spec.num_kv_heads, spec.head_dim)
-        v = v.reshape(N, T, spec.num_kv_heads, spec.head_dim)
-        q = jax.vmap(lambda a, p: rope_spec(spec, a, p))(q, positions)
-        k = jax.vmap(lambda a, p: rope_spec(spec, a, p))(k, positions)
-        k_pages = _set_page_tiles(k_pages, li, safe_pg, k, page_size,
-                                  valid_tok)
-        v_pages = _set_page_tiles(v_pages, li, safe_pg, v, page_size,
-                                  valid_tok)
+        with jax.named_scope(SCOPE_QKV):
+            q = h @ lp["wq"]
+            k = h @ lp["wk"]
+            v = h @ lp["wv"]
+            if spec.attn_bias:
+                q, k, v = q + lp["bq"], k + lp["bk"], v + lp["bv"]
+            q = q.reshape(N, T, spec.num_heads, spec.head_dim)
+            k = k.reshape(N, T, spec.num_kv_heads, spec.head_dim)
+            v = v.reshape(N, T, spec.num_kv_heads, spec.head_dim)
+            q = jax.vmap(lambda a, p: rope_spec(spec, a, p))(q, positions)
+            k = jax.vmap(lambda a, p: rope_spec(spec, a, p))(k, positions)
+        with jax.named_scope(SCOPE_KV):
+            k_pages = _set_page_tiles(k_pages, li, safe_pg, k, page_size,
+                                      valid_tok)
+            v_pages = _set_page_tiles(v_pages, li, safe_pg, v, page_size,
+                                      valid_tok)
 
         def one_attn(q_i, bt_i, pos_i, kvl_i, k_i, v_i, kp=k_pages,
                      vp=v_pages, li=li, lp=lp):
@@ -550,7 +569,10 @@ def prefill_forward_batch_impl(
                 window=spec.attn_window(li), sinks=lp.get("sinks"),
             )
 
-        attn = jax.vmap(one_attn)(q, block_tables, positions, kv_len, k, v)
+        with jax.named_scope(SCOPE_KV):
+            attn = jax.vmap(one_attn)(
+                q, block_tables, positions, kv_len, k, v
+            )
         x = x + _o_proj(spec, lp, attn.reshape(N, T, -1))
         h = rms_norm(x, lp["mlp_norm"], spec.rms_eps)
         f, d = _ffn_counted(spec, lp, h.reshape(N * T, -1))
@@ -796,25 +818,27 @@ def decode_forward_impl(
     for li, lp in enumerate(params["layers"]):
         h = rms_norm(x, lp["attn_norm"], spec.rms_eps)
         # per-slot single-token qkv: vmap the [T=1] path
-        q = h @ lp["wq"]
-        k = h @ lp["wk"]
-        v = h @ lp["wv"]
-        if spec.attn_bias:
-            q, k, v = q + lp["bq"], k + lp["bk"], v + lp["bv"]
-        q = q.reshape(B, spec.num_heads, spec.head_dim)
-        k = k.reshape(B, spec.num_kv_heads, spec.head_dim)
-        v = v.reshape(B, spec.num_kv_heads, spec.head_dim)
-        q = rope_spec(spec, q, positions)
-        k = rope_spec(spec, k, positions)
+        with jax.named_scope(SCOPE_QKV):
+            q = h @ lp["wq"]
+            k = h @ lp["wk"]
+            v = h @ lp["wv"]
+            if spec.attn_bias:
+                q, k, v = q + lp["bq"], k + lp["bk"], v + lp["bv"]
+            q = q.reshape(B, spec.num_heads, spec.head_dim)
+            k = k.reshape(B, spec.num_kv_heads, spec.head_dim)
+            v = v.reshape(B, spec.num_kv_heads, spec.head_dim)
+            q = rope_spec(spec, q, positions)
+            k = rope_spec(spec, k, positions)
         # KV append + paged attention in ONE kernel per layer on the
         # Pallas path (ops/pallas/fused_decode.py — halves the decode
         # program's kernel-launch count); scatter + gather attention
         # elsewhere (ops/attention.decode_update_attention dispatch)
-        attn, k_pages, v_pages = decode_update_attention(
-            q, k_pages, v_pages, k, v, block_tables, seq_lens,
-            safe_page, offset, layer=li, mesh=mesh,
-            window=spec.attn_window(li), sinks=lp.get("sinks"),
-        )
+        with jax.named_scope(SCOPE_KV):
+            attn, k_pages, v_pages = decode_update_attention(
+                q, k_pages, v_pages, k, v, block_tables, seq_lens,
+                safe_page, offset, layer=li, mesh=mesh,
+                window=spec.attn_window(li), sinks=lp.get("sinks"),
+            )
         attn = attn.reshape(B, spec.num_heads * spec.head_dim)
         x = x + _o_proj(spec, lp, attn)
         h = rms_norm(x, lp["mlp_norm"], spec.rms_eps)

@@ -479,6 +479,10 @@ def fused_decode_attention(
     # 4=q 5=k_new 6=v_new 7=k_pages 8=v_pages [9-12=scales] [then sinks]
     # -> outputs 1, 2 (the value pools; grown scales leave as outputs
     # 3/4 and are scattered into the scale pool below, same jit)
+    # No jax.named_scope here: the profiler names this custom call after
+    # the innermost scope around it, which is this function's own jit
+    # (``fused_decode_attention``, what the benchmark's trace reduction
+    # matches); models/llama.py's attn_kv scope encloses the call.
     results = pl.pallas_call(
         kernel,
         grid_spec=grid_spec,

@@ -2,16 +2,25 @@
 timelines — the "why was THIS request slow" tool.
 
 Every request the engine admits gets a timeline: admission, phase
-transitions (prefill chunks, first token, spec verifies, disagg
-events, fault trips), and the finish reason, each stamped with a
-monotonic offset from enqueue and carrying the request's trace_id.
+transitions (prefill chunks, the prefill dispatch, first token, first
+delta, spec verifies, disagg events, fault trips), and the finish
+reason, each stamped with a monotonic offset from enqueue and carrying
+the request's trace_id. The time-to-first-token chain of a request is
+``admit`` -> ``prefill_dispatch`` (``seq``: the engine's running
+dispatch number, which the ``engine.launch`` annotation in a profiler
+trace carries too) -> ``first_token`` (the host has the value) ->
+``first_delta`` (generate() hands the stream its first tokens); each
+instant is read from the clock once, here.
 The step thread records events with one lock + append (coalescing
 repeats, bounded per timeline), so the hot path stays cheap.
 
 Retention is TAIL-BIASED: besides the most-recent ring, errored
 timelines and the slowest requests survive eviction in their own
 buckets — the interesting requests are exactly the ones a plain ring
-would have rotated out by the time an operator asks.
+would have rotated out by the time an operator asks. A profiled engine
+(``EngineConfig.profile``) widens the ring with :meth:`retain`, and
+:attr:`complete` says whether every timeline finished since is still in
+it: a reader of a whole window takes ``finished()`` only then.
 
 Live queries: worker admin ``{"op": "timeline"}`` (engine/worker.py)
 and the frontend's ``GET /debug/timeline`` fan-out (frontend/http.py).
@@ -84,6 +93,33 @@ class Timeline:
                 return ev
         return None
 
+    def admission_phases(self) -> list[tuple[str, float]]:
+        """(phase, seconds) for every admission of this request, from its
+        events: ``admit_wait`` (enqueue, or the preemption that sent it
+        back, -> ``admit``; a requeue on page pressure coalesces into one
+        ``admit`` whose last instant counts), ``prefill_dispatch``
+        (``admit`` -> ``prefill_dispatch``) and ``first_token``
+        (``prefill_dispatch`` -> ``first_token``). The engine's
+        ``readmit.*`` profile sums are these."""
+        out: list[tuple[str, float]] = []
+        since = 0.0
+        admit = dispatch = None
+        for ev in self.events:
+            name = ev["name"]
+            if name == "preempt":
+                since = ev["t"]
+            elif name == "admit":
+                admit = ev["t_last"]
+                out.append(("admit_wait", admit - since))
+            elif name == "prefill_dispatch" and admit is not None:
+                dispatch = ev["t"]
+                out.append(("prefill_dispatch", dispatch - admit))
+                admit = None
+            elif name == "first_token" and dispatch is not None:
+                out.append(("first_token", ev["t"] - dispatch))
+                dispatch = None
+        return out
+
     def to_dict(self) -> dict[str, Any]:
         return {
             "request_id": self.request_id,
@@ -130,6 +166,9 @@ class FlightRecorder:
         self._slow: list[tuple[float, int, Timeline]] = []
         self._keep_slow = keep_slow
         self._seq = 0
+        # finished timelines the recent ring has dropped since the last
+        # retain()/clear(): 0 = finished() is everything that finished
+        self._rotated = 0
 
     # -- recording (any thread) -------------------------------------------
 
@@ -188,6 +227,7 @@ class FlightRecorder:
             self._recent.append(tl)
             if len(self._recent) > self._capacity:
                 self._recent.pop(0)
+                self._rotated += 1
             if error or reason == "error":
                 self._errors.append(tl)
                 if len(self._errors) > self._keep_errors:
@@ -198,6 +238,30 @@ class FlightRecorder:
             elif item[0] > self._slow[0][0]:
                 heapq.heapreplace(self._slow, item)
             return tl
+
+    # -- whole-window retention (a profiled engine) -----------------------
+
+    def retain(self, capacity: int) -> None:
+        """Widen the recent ring to ``capacity`` finished timelines (never
+        narrows it) and start counting what it drops afresh."""
+        with self._lock:
+            self._capacity = max(self._capacity, int(capacity))
+            self._rotated = 0
+
+    @property
+    def complete(self) -> bool:
+        """True while no finished timeline has left the recent ring since
+        the last retain()/clear(): ``finished()`` is then every request
+        that finished since."""
+        with self._lock:
+            return self._rotated == 0
+
+    def finished(self) -> list[Timeline]:
+        """The recent ring, oldest first. Finished timelines are no longer
+        mutated, so the list is safe to read outside the lock."""
+        with self._lock:
+            race.read("flight.timeline")
+            return list(self._recent)
 
     # -- queries (event loop / admin) -------------------------------------
 
@@ -254,6 +318,7 @@ class FlightRecorder:
             self._recent.clear()
             self._errors.clear()
             self._slow.clear()
+            self._rotated = 0
 
 
 # process-wide recorder: the engine records into it, the worker admin op

@@ -285,20 +285,69 @@ def test_profile_phase_catalog_sync():
         if (
             isinstance(node, ast.Call)
             and isinstance(node.func, ast.Attribute)
-            and node.func.attr in ("_phase", "_prof_add")
+            and node.func.attr == "_phase"
             and node.args
             and isinstance(node.args[0], ast.Constant)
             and isinstance(node.args[0].value, str)
         ):
             used.add(node.args[0].value)
-    # profile_snapshot's synthesized keys + direct _prof accumulators
+    # profile_snapshot's synthesized keys + direct _prof accumulators,
+    # and the per-request sums generate() adds from the timelines
     used.update(re.findall(
         r'(?:snap|self\._prof)(?:\.setdefault\(|\[)"([a-z_.0-9]+)"', src
     ))
+    from dynamo_tpu.engine.core import READMIT_SUMS
+    used.update(READMIT_SUMS.values())
     catalogued = set(catalog.PROFILE_PHASES)
     assert used - catalogued == set(), (
         f"phases missing from catalog.PROFILE_PHASES: {used - catalogued}"
     )
     assert catalogued - used == set(), (
         f"stale catalog phases no code emits: {catalogued - used}"
+    )
+
+
+def _core_source() -> str:
+    return open(InferenceEngine.__module__.replace(".", "/") + ".py").read()
+
+
+def _literal_calls(src: str, attr: str, arg: int) -> set[str]:
+    """String literals passed as positional argument ``arg`` of every
+    ``<x>.<attr>(...)`` call in ``src``."""
+    found: set[str] = set()
+    for node in ast.walk(ast.parse(src)):
+        if (
+            isinstance(node, ast.Call)
+            and isinstance(node.func, ast.Attribute)
+            and node.func.attr == attr
+            and len(node.args) > arg
+            and isinstance(node.args[arg], ast.Constant)
+            and isinstance(node.args[arg].value, str)
+        ):
+            found.add(node.args[arg].value)
+    return found
+
+
+def test_profiler_annotation_catalog_sync():
+    """catalog.PROFILER_ANNOTATIONS <-> the TraceAnnotation names
+    engine/core.py writes, both directions; the per-phase annotation is
+    built from the phase name and nowhere else."""
+    from tools.dynalint import catalog
+
+    src = _core_source()
+    used = _literal_calls(src, "TraceAnnotation", 0)
+    assert '"engine." + name' in src  # _PhaseSpan: engine.<phase>
+    assert used == set(catalog.PROFILER_ANNOTATIONS), (
+        used ^ set(catalog.PROFILER_ANNOTATIONS)
+    )
+
+
+def test_flight_event_catalog_sync():
+    """catalog.FLIGHT_EVENTS <-> the events engine/core.py records, both
+    directions: timeline readers reference these exact strings."""
+    from tools.dynalint import catalog
+
+    used = _literal_calls(_core_source(), "event", 1)
+    assert used == set(catalog.FLIGHT_EVENTS), (
+        used ^ set(catalog.FLIGHT_EVENTS)
     )

@@ -632,15 +632,15 @@ async def test_forced_tool_call_parses_through_tool_parser():
 # ------------------------------------------ observability + artifact
 
 
-async def test_guided_phases_metric_and_snapshot(monkeypatch):
+async def test_guided_phases_metric_and_snapshot():
     """guided.* profile phases accumulate, guided_snapshot carries the
     compiler stats, and the outcome counter lands ok trips."""
     from dynamo_tpu.runtime.metrics import MetricsRegistry
 
-    monkeypatch.setenv("DYNAMO_ENGINE_PROFILE", "1")
     vocab = VOCABS["gqa"]
     engine = InferenceEngine(
-        TINY_GQA, _cfg(spec_mode="ngram", spec_reprobe_tokens=16),
+        TINY_GQA,
+        _cfg(spec_mode="ngram", spec_reprobe_tokens=16, profile=True),
         guided_vocab=vocab,
     )
     await engine.start()

@@ -330,8 +330,8 @@ async def test_eager_readmission_fills_slot_in_same_cycle():
     assert outs2 == outs
 
 
-async def test_readmission_gap_attribution_phases(monkeypatch):
-    """DYNAMO_ENGINE_PROFILE=1 breaks the finish->first-token path into
+async def test_readmission_gap_attribution_phases():
+    """EngineConfig.profile breaks the finish->first-token path into
     the named phases profile_engine.py reports: admit_wait (queue time),
     prefill_dispatch (prompt forward + fused sample), first_token
     (residual sample/d2h materialization)."""
@@ -339,11 +339,10 @@ async def test_readmission_gap_attribution_phases(monkeypatch):
     from dynamo_tpu.engine.core import InferenceEngine
     from dynamo_tpu.runtime.context import Context
 
-    monkeypatch.setenv("DYNAMO_ENGINE_PROFILE", "1")
     cfg = EngineConfig(
         page_size=4, num_pages=64, max_pages_per_seq=16,
         max_decode_slots=2, prefill_buckets=(16, 32),
-        decode_steps_per_dispatch=2, pipeline_decode=True,
+        decode_steps_per_dispatch=2, pipeline_decode=True, profile=True,
     )
     engine = InferenceEngine(TINY, cfg)
     await engine.start()

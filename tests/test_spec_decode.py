@@ -326,15 +326,14 @@ def test_accepted_tokens_per_dispatch_meets_bar():
 # ------------------------------------------------ observability surfaces
 
 
-async def test_spec_phases_metrics_and_snapshot(monkeypatch):
+async def test_spec_phases_metrics_and_snapshot():
     """spec.* profile phases accumulate (profile_engine attribution
     consumes them), spec_snapshot carries the counters, and the
     dynamo_spec_tokens_total counter rides every /metrics exposition."""
     from benchmarks.profile_engine import spec_attribution
     from dynamo_tpu.runtime.metrics import MetricsRegistry
 
-    monkeypatch.setenv("DYNAMO_ENGINE_PROFILE", "1")
-    engine = InferenceEngine(TINY_GQA, _cfg("ngram"))
+    engine = InferenceEngine(TINY_GQA, _cfg("ngram", profile=True))
     await engine.start()
     await _gen(engine, _repetitive(272, 40), 32)
     snap = engine.profile_snapshot()

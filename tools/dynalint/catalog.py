@@ -71,7 +71,9 @@ FAULT_SITES: dict[str, str] = {
 }
 
 # engine step-thread profiler phase names (engine/core.py _phase /
-# _prof_add / profile_snapshot) -> meaning. DL006-style registry for the
+# READMIT_SUMS / profile_snapshot) -> meaning. With
+# EngineConfig.profile every _phase name is also an ``engine.<name>``
+# annotation in a jax.profiler trace (PROFILER_ANNOTATIONS below). DL006-style registry for the
 # SAME reason as METRIC_NAMES: benchmarks/profile_engine.py's
 # attribution sections, bench.py's dispatch_overhead_frac, and the
 # dashboards built on profile snapshots reference these exact strings —
@@ -82,8 +84,11 @@ PROFILE_PHASES: dict[str, str] = {
     "spmd_sync": "rejoining follower state-sync service",
     "materialize": "async admission-wave first-token landings",
     "flush": "pipeline flush before cancels/admin ops",
-    "admit_loop": "admission dequeue + page acquisition",
+    "admit_loop": "admission dequeue + page acquisition (every pass, "
+                  "admitting or not)",
     "packed_prefill": "packed prefill dispatch(es) for the step",
+    "advance_partial": "the next chunk of an in-flight chunked prefill "
+                       "(and its admission, on the last chunk)",
     "complete_admissions": "first-token sample + emit for admissions",
     "eager_readmit": "same-cycle re-admission pass after a burst freed slots",
     "readmit_wait": "bounded wait for a closed-loop resubmission",
@@ -91,9 +96,13 @@ PROFILE_PHASES: dict[str, str] = {
     "dispatch": "decode burst dispatch (host issue time)",
     "process": "burst processing (stop semantics, seal, stream)",
     "process.d2h_sync": "burst token download sync inside process",
-    "readmit.admit_wait": "generate() enqueue -> step-thread dequeue",
-    "readmit.prefill_dispatch": "dequeue -> prefill+sample dispatched",
-    "readmit.first_token": "dispatch complete -> first token streamed",
+    # the three per-request sums come from the flight recorder's timeline
+    # at finish (runtime/flight.py Timeline.admission_phases)
+    "readmit.admit_wait": "enqueue (or preemption) -> admit event",
+    "readmit.prefill_dispatch": "admit -> prefill_dispatch event "
+                                "(prefill+sample dispatched)",
+    "readmit.first_token": "prefill_dispatch -> first_token event (the "
+                           "host has the first token)",
     "dispatch.d2h_wait": "step thread blocked on device->host transfers "
                          "(outside admission phases)",
     "readmit.d2h_wait": "d2h blocks nested inside admission phases "
@@ -112,6 +121,43 @@ PROFILE_PHASES: dict[str, str] = {
                         "verify (per-position masks, no state mutation)",
     "preempt": "priority preemption: pipeline flush + seal/offload + "
                "resume-request rebuild for one paused batch stream",
+}
+
+# jax.profiler.TraceAnnotation names the profiled engine writes into a
+# profiler trace besides ``engine.<phase>`` for every _phase name above
+# (engine/core.py, EngineConfig.profile) -> what they mark and the
+# attributes they carry. perfbench/lib/spans.py reads these exact
+# strings; two-way sync with the code is test-enforced
+# (tests/test_dispatch_profile.py).
+PROFILER_ANNOTATIONS: dict[str, str] = {
+    "engine.launch": "one device program the step thread issues: kind "
+                     "(prefill | decode | verify | sample | logprobs | "
+                     "feed), seq (running launch number), and the host "
+                     "counts it was built from (prefill/verify: tokens, "
+                     "rows; decode: steps, live, slots; sample: rows)",
+    "engine.clock": "once a step-loop cycle: mono_ns = "
+                    "time.monotonic_ns(), to fit the profiler's clock to "
+                    "the flight recorder's and the clients'",
+}
+
+# flight-recorder event names (runtime/flight.py FLIGHT.event) the engine
+# records -> the instant they mark. /debug/timeline consumers and
+# perfbench/lib/spans.py reference these exact strings; two-way sync with
+# engine/core.py is test-enforced (tests/test_dispatch_profile.py).
+FLIGHT_EVENTS: dict[str, str] = {
+    "admit": "the step thread took the request off the waiting queue",
+    "prefill_chunk": "one chunk of a chunked prefill dispatched",
+    "prefill_dispatch": "the prefill that took the last prompt tokens and "
+                        "the first-token sample are dispatched; seq = the "
+                        "engine.launch number of that prefill program",
+    "first_token": "the host has the first token's value (step thread)",
+    "first_delta": "generate() hands the stream its first tokens (event "
+                   "loop): the engine's side of time to first token",
+    "disagg_resume": "decode-side resume from remotely prefilled KV",
+    "spec_verify": "one speculative verify landed (accepted = n)",
+    "preempt": "paused for a higher-priority admission, re-queued",
+    "shed": "bounced from the waiting queue under overload",
+    "fault": "an injected fault fired on this request's path",
 }
 
 # span name (runtime/tracing.py span()/emit_span()) -> what it times.
@@ -280,7 +326,7 @@ METRIC_NAMES: dict[str, str] = {
                                       "deadline) — the 503/504 feeders",
     "engine_dispatch_overhead_frac": "step-thread d2h-blocked fraction "
                                      "of the sample window (0 unless "
-                                     "DYNAMO_ENGINE_PROFILE=1)",
+                                     "EngineConfig.profile)",
     "engine_spec_acceptance_rate": "cumulative speculative-draft "
                                    "acceptance rate",
     # fused-kernel fallback accounting (ops/fallback.py, on every
