@@ -71,12 +71,11 @@ PEAK_HBM = {
 # (gptoss gets more headroom: expert dispatch makes its prefill
 # relatively more expensive, so starving re-admissions costs more).
 # Starting points pending on-chip sweeps; env knobs override:
-# DYNAMO_BENCH_BURST[_<FAM>], DYNAMO_BENCH_DEPTH[_<FAM>],
-# DYNAMO_BENCH_PREFILL_BUDGET[_<FAM>].
+# DYNAMO_BENCH_BURST[_<FAM>], DYNAMO_BENCH_PREFILL_BUDGET[_<FAM>].
 FAMILY_SERVING = {
-    "gqa": {"burst": 24, "depth": 2, "budget_frac": 0.5},
-    "mla": {"burst": 32, "depth": 2, "budget_frac": 0.5},
-    "gptoss": {"burst": 16, "depth": 2, "budget_frac": 0.75},
+    "gqa": {"burst": 24, "budget_frac": 0.5},
+    "mla": {"burst": 32, "budget_frac": 0.5},
+    "gptoss": {"burst": 16, "budget_frac": 0.75},
 }
 
 # on-chip acceptance bars, recorded in the artifact so every BENCH_r*
@@ -334,7 +333,6 @@ def serving_measurement(
         # on v5e: 16 was -14%, 32 was -20%).
         decode_steps_per_dispatch=_fam_env("BURST", family, tuning["burst"]),
         pipeline_decode=True,
-        pipeline_depth=_fam_env("DEPTH", family, tuning["depth"]),
         # steady-state churn at S streams with OSL/burst-length ~2-cycle
         # requests re-admits ~S/2 prompts per cycle — a budget below
         # that equilibrium idles slots (the r4 0.49 ceiling was exactly
@@ -520,7 +518,6 @@ def serving_measurement(
             "warmup_s": warm_s, "window_s": window_s,
             "repeats": repeats,
             "burst": cfg.decode_steps_per_dispatch,
-            "pipeline_depth": cfg.pipeline_depth,
             "prefill_budget": cfg.max_prefill_tokens_per_step,
             "rungs": out_rungs,
             "output_tok_per_s": best["output_tok_per_s"],

@@ -265,19 +265,15 @@ class EngineConfig:
     # scheduling analogue): amortizes host dispatch + token sync; tokens
     # stream in bursts of this size, EOS overshoot is discarded host-side
     decode_steps_per_dispatch: int = 1
-    # pipelined decode bursts: dispatch ahead with fed tokens chained on
-    # device, syncing results pipeline_depth bursts late — dispatch and
-    # d2h transfer latency hide behind device execution. Stops are
-    # detected up to pipeline_depth * decode_steps_per_dispatch tokens
-    # late (overshoot discarded). Cancels and admin ops flush the
-    # pipeline; admissions interleave WITHOUT flushing.
+    # pipelined decode bursts: burst k+1 is dispatched, its fed tokens
+    # chained on device from burst k's samples, BEFORE the host reads
+    # burst k. So one burst is in flight when the read returns (it has
+    # just started) and two just after a dispatch: the step thread's own
+    # work hides behind the running burst, and a prefill launched in that
+    # cycle waits for one burst, not more. Stops are detected one burst late
+    # (overshoot discarded). Cancels, admin ops and an open chunked
+    # prefill flush the pipeline; admissions interleave WITHOUT flushing.
     pipeline_decode: bool = False
-    # in-flight decode bursts when pipelined. At depth 2 burst k's token
-    # download (started at dispatch) has a full burst of device time to
-    # land before the host consumes it, so steady-state cycles track
-    # device time, not the d2h RTT. Stops are detected up to depth*burst
-    # tokens late (overshoot discarded).
-    pipeline_depth: int = 2
     # admission first tokens sampled on device and materialized a step
     # later (never blocks the step thread on the d2h RTT); off = the
     # synchronous sample-and-emit path

@@ -427,8 +427,9 @@ class InferenceEngine:
             )
         self._partial: _PartialPrefill | None = None
         self._clear_cache_requested = False
-        # dispatched-but-unprocessed decode bursts, oldest first (max
-        # length = config.pipeline_depth when pipeline_decode)
+        # dispatched-but-unprocessed decode bursts, oldest first: one
+        # between cycles when pipeline_decode, two from a dispatch until
+        # the older one is read (_decode_step)
         self._pipeline: list[dict] = []
         # async first-token waves, oldest first: each holds a device
         # sample whose host copy is in flight; waves touch disjoint live
@@ -527,7 +528,9 @@ class InferenceEngine:
         carrying ``kind``, ``seq`` and ``counts``. The device runs one
         stream in launch order, so a trace's k-th launch of a kind is its
         k-th execution of that kind. ``counts`` are host values only: an
-        annotation never reads a device array."""
+        annotation never reads a device array. A prefill and a decode
+        burst carry ``ahead``, the decode bursts in flight at the launch:
+        how many bursts the program is queued behind at most."""
         self._launch_seq += 1
         if not self._profiling:
             return _NO_SPAN
@@ -2608,7 +2611,9 @@ class InferenceEngine:
                     {"num_tokens": tail},
                     {"tokens": padded, "block_table": block_table},
                 )
-            with self._launch("prefill", tokens=tail, rows=1):
+            with self._launch(
+                "prefill", tokens=tail, rows=1, ahead=len(self._pipeline)
+            ):
                 logits, self.k_pages, self.v_pages, dropped = (
                     self.fam.prefill_ring(
                         self.spec,
@@ -2709,7 +2714,7 @@ class InferenceEngine:
                     )
                 with self._launch(
                     "prefill", tokens=sum(p["tail"] for p in group),
-                    rows=len(group),
+                    rows=len(group), ahead=len(self._pipeline),
                 ):
                     logits, self.k_pages, self.v_pages, dropped = (
                         self.fam.prefill_batch(
@@ -3306,7 +3311,10 @@ class InferenceEngine:
                 {"start": start, "num_tokens": len(new_tokens)},
                 {"tokens": padded, "block_table": block_table, **mm_arrays},
             )
-        with self._launch("prefill", tokens=len(new_tokens), rows=1):
+        with self._launch(
+            "prefill", tokens=len(new_tokens), rows=1,
+            ahead=len(self._pipeline),
+        ):
             logits, self.k_pages, self.v_pages, dropped = self.fam.prefill(
                 self.spec,
                 self.params,
@@ -3537,7 +3545,7 @@ class InferenceEngine:
         Scheduling contract with the pipeline: a slot is EITHER
         burst-managed or spec-managed in any given cycle. _build_batch
         skips spec-managed slots, so their burst coverage drains within
-        pipeline_depth cycles of the flag flipping, after which every
+        a cycle or two of the flag flipping, after which every
         cycle runs one packed verify (1..k+1 tokens per slot per
         dispatch). A slot whose drafter finds nothing still verifies at
         width 1 — it must emit a token this cycle — and the no-match
@@ -3767,15 +3775,19 @@ class InferenceEngine:
         either on the trash page or in pages released when the slot
         finishes.
 
-        ``pipeline_decode=True`` keeps up to ``pipeline_depth`` bursts in
-        flight: each new burst dispatches with its fed tokens CHAINED ON
-        DEVICE from the in-flight bursts' sampled outputs, and only the
-        OLDEST burst's host copy is processed per step. At depth 2 burst
-        k's token download (started at dispatch) has a full burst of
-        device execution to land before the host reads it — cycles track
-        device time, not the d2h round-trip. Stops are detected up to
-        depth bursts late (discarded garbage, as with mid-burst EOS);
-        cancels and admin ops flush the pipeline first (_step).
+        ``pipeline_decode=True`` keeps ONE burst queued behind the
+        running one: burst k+1 dispatches with its fed tokens CHAINED ON
+        DEVICE from burst k's sampled outputs, and only then is burst k's
+        host copy read. The read returns when k ends, so k+1 has just
+        started and is the only burst in flight while the thread streams
+        k's tokens, re-admits and builds k+2: that host work hides behind
+        k+1, and a prefill launched in it waits in the device's in-order
+        queue for one burst. (A second queued burst buys nothing unless
+        the device-to-host copy takes longer than a burst, and costs every
+        prompt a burst of time to first token: PERF.md, PR 27.)
+        Stops are detected one burst late (discarded garbage, as with
+        mid-burst EOS); cancels and admin ops flush the pipeline first
+        (_step).
 
         Guided slots opt the engine out of pipelining for the cycles
         they are live: a pipelined burst would dispatch with a mask
@@ -3812,7 +3824,7 @@ class InferenceEngine:
                     batch, chain=self._pipeline or None
                 )
             self._pipeline.append({"batch": batch, "results": results})
-            if len(self._pipeline) > max(1, self.config.pipeline_depth):
+            if len(self._pipeline) > 1:
                 before = sum(s is not None for s in self._slots)
                 with self._phase("process"):
                     self._process_burst(self._pipeline.pop(0))
@@ -4107,6 +4119,7 @@ class InferenceEngine:
         with self._launch(
             "decode", steps=batch["n_burst"],
             live=len(batch["participants"]), slots=len(self._slots),
+            ahead=len(self._pipeline),
         ):
             result = self.fam.decode_steps(
                 self.spec,

@@ -395,10 +395,10 @@ async def _amain(args: argparse.Namespace) -> None:
         decode_steps_per_dispatch=args.decode_steps_per_dispatch,
         # serving workers ALWAYS pipeline (even at burst 1 = pure
         # double-buffering): burst N+1 dispatches with device-chained
-        # tokens while burst N's d2h is in flight, so the step thread
-        # never blocks on the device->host RTT (dispatch.d2h_wait ~ 0).
-        # Cost: stops detected up to pipeline_depth bursts late
-        # (overshoot discarded); cancels/admin ops still flush first.
+        # tokens before burst N is read, so the device always has the
+        # next burst queued while the step thread streams N's tokens
+        # and admits. Cost: stops detected one burst late (overshoot
+        # discarded); cancels/admin ops still flush first.
         pipeline_decode=True,
         max_prefill_chunk_tokens=args.max_prefill_chunk_tokens,
         tp=args.tp,
@@ -655,8 +655,8 @@ def main() -> None:
     p.add_argument("--max-pages-per-seq", type=int, default=64)
     p.add_argument("--max-decode-slots", type=int, default=8)
     p.add_argument("--decode-steps-per-dispatch", type=int, default=1,
-                   help=">1 fuses N decode steps per dispatch and enables "
-                        "the pipelined (depth-2) burst schedule")
+                   help="decode steps fused per dispatch; bursts are "
+                        "pipelined, one queued behind the running one")
     p.add_argument("--max-prefill-chunk-tokens", type=int, default=512,
                    help="chunked-prefill dispatch cap; multimodal prompts "
                         "must fit ONE dispatch (a 576-row CLIP-L image "

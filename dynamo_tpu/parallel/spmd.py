@@ -423,11 +423,11 @@ class SpmdFollower:
         self.group = group
         self.engine = engine
         # follower-side pipeline mirror: device results of the last
-        # decode bursts, for chain replay (oldest first). Sized from the
-        # engine's pipeline depth — a mirror shorter than the leader's
-        # chain would misalign every mask
-        depth = int(getattr(engine.config, "pipeline_depth", 2) or 2)
-        self._pending: deque = deque(maxlen=max(8, depth + 2))
+        # decode bursts, for chain replay (oldest first). The leader
+        # chains from at most one in-flight burst (core._decode_step); a
+        # mirror shorter than the leader's chain would misalign every
+        # mask, so it holds a few more
+        self._pending: deque = deque(maxlen=8)
         # rejoin: on stream loss, reconnect with a state-sync join
         # instead of dying. Only valid in MIRROR topologies (local mesh
         # per process); a spanning jax.distributed mesh is not elastic.

@@ -59,7 +59,8 @@ async def test_profile_off_records_nothing_and_creates_no_annotation(
     monkeypatch.setattr(jax.profiler, "TraceAnnotation", boom)
     assert engine._phase("idle") is engine._phase("dispatch") is core._NO_SPAN
     seq = engine._launch_seq
-    assert engine._launch("decode", steps=2, live=1, slots=4) is core._NO_SPAN
+    assert engine._launch(
+        "decode", steps=2, live=1, slots=4, ahead=1) is core._NO_SPAN
     assert engine._launch_seq == seq + 1  # numbered all the same
     await engine.start()
     await _serve(engine, 3, "off")
@@ -208,6 +209,25 @@ def test_launch_numbers_are_dense_and_carry_host_counts(traced):
     for _l, n, a, b, _s in events:
         if n == "engine.launch":
             assert any(c <= a and b <= d for c, d in spans)
+
+
+def test_prefill_and_decode_launches_carry_the_bursts_ahead(traced):
+    """``ahead`` is the decode bursts in flight at the launch: one burst is
+    kept queued behind the running one, so neither a prefill nor a burst is
+    ever launched behind two; the other kinds do not carry it."""
+    events = traced[0]
+    ahead = {"prefill": [], "decode": []}
+    for _l, n, _a, _b, s in events:
+        if n != "engine.launch":
+            continue
+        if s["kind"] in ahead:
+            ahead[s["kind"]].append(int(s["ahead"]))
+        else:
+            assert "ahead" not in s, s
+    assert ahead["prefill"] and set(ahead["prefill"]) <= {0, 1}
+    assert set(ahead["decode"]) == {0, 1}
+    # 6 requests for 4 slots: some prompt was launched behind a burst
+    assert 1 in ahead["prefill"]
 
 
 def test_every_timeline_s_prefill_dispatch_names_a_launch_that_exists(traced):

@@ -1,7 +1,10 @@
 """Pipelined decode bursts (engine/core.py pipeline_decode): dispatch k+1
-device-chained before processing k. Must be invisible to clients — exact
-same tokens as the unpipelined engine, under mixed sampling, mid-burst
-stops, admission churn, cancellation, and page pressure."""
+device-chained before processing k, so ONE burst is queued behind the
+running one. Must be invisible to clients — exact same tokens as the
+unpipelined engine, under mixed sampling, mid-burst stops, admission
+churn, admissions into a half-full batch, a chunked prompt (the flush
+path), cancellation, and page pressure — and no prefill may ever be
+launched behind more than one burst."""
 
 import asyncio
 
@@ -20,17 +23,19 @@ SPEC = ModelSpec(
 )
 
 
-def _cfg(pipeline: bool, *, num_pages=256, slots=3) -> EngineConfig:
+def _cfg(pipeline: bool, *, num_pages=256, slots=3, **kw) -> EngineConfig:
     return EngineConfig(
         page_size=4, num_pages=num_pages, max_pages_per_seq=32,
         max_decode_slots=slots, prefill_buckets=(16, 32, 64),
-        decode_steps_per_dispatch=4, pipeline_decode=pipeline,
+        decode_steps_per_dispatch=4, pipeline_decode=pipeline, **kw,
     )
 
 
 async def _collect(engine, prompt, max_tokens, *, temperature=0.0, seed=None,
-                   ignore_eos=True):
-    out = []
+                   ignore_eos=True, out=None):
+    """The stream's tokens; into ``out`` as they arrive, where one is given
+    for others to watch."""
+    out = [] if out is None else out
     sampling = {"temperature": temperature}
     if seed is not None:
         sampling["seed"] = seed
@@ -45,34 +50,148 @@ async def _collect(engine, prompt, max_tokens, *, temperature=0.0, seed=None,
     return out
 
 
-async def _run_workload(pipeline: bool) -> list[list[int]]:
-    engine = InferenceEngine(SPEC, _cfg(pipeline))
+class _Watch:
+    """What the step thread had in flight, seen from outside: ``ahead`` of
+    every launch, and the bursts left in flight at every read of the
+    oldest one, in the order they happened."""
+
+    def __init__(self, engine):
+        self.log: list[tuple[str, int]] = []
+        launch, process = engine._launch, engine._process_burst
+
+        def watched_launch(kind, **counts):
+            if kind in ("prefill", "decode"):
+                # the count the annotation carries, as the engine gave it
+                self.log.append((kind, counts["ahead"]))
+                assert counts["ahead"] == len(engine._pipeline)
+            return launch(kind, **counts)
+
+        def watched_process(pending):
+            self.log.append(("read", len(engine._pipeline)))
+            return process(pending)
+
+        engine._launch = watched_launch
+        engine._process_burst = watched_process
+
+    def ahead(self, kind: str) -> list[int]:
+        return [n for k, n in self.log if k == kind]
+
+
+async def _after(n: int, outs: list[list[int]], coro):
+    """Start ``coro`` once every stream of ``outs`` has ``n`` tokens."""
+    while any(len(o) < n for o in outs):
+        await asyncio.sleep(0.002)
+    return await coro
+
+
+async def _churn(engine):
+    # more requests than slots -> admission churn + pipeline flushes;
+    # budgets not divisible by the burst -> mid-burst length stops;
+    # mixed greedy + seeded sampling
+    return await asyncio.gather(
+        _collect(engine, [5, 9, 13], 11),
+        _collect(engine, [7, 11], 6, temperature=0.9, seed=42),
+        _collect(engine, [3, 5, 9, 13], 9),
+        _collect(engine, [17, 19], 5, temperature=0.7, seed=7),
+        _collect(engine, [2, 4, 6], 13),
+    )
+
+
+async def _midstream(engine):
+    # the chat cell's regime: two of four slots decode, and prompts
+    # arrive one at a time while bursts are in flight — each prefill is
+    # launched behind them, unflushed, and its first token feeds the next
+    # burst's device chain
+    a, b = [], []
+    return await asyncio.gather(
+        _collect(engine, [5, 9, 13], 61, out=a),
+        _collect(engine, [7, 11, 2, 8], 58, out=b),
+        _after(9, [a, b], _collect(engine, [3, 5, 9, 13, 4], 19)),
+        _after(22, [a, b], _collect(
+            engine, [17, 19], 10, temperature=0.8, seed=11)),
+        _after(30, [a, b], _collect(engine, [2, 4, 6], 7)),
+    )
+
+
+async def _chunked(engine):
+    # a prompt over the chunk opens a partial prefill while two streams
+    # decode: every step it is open lands the in-flight burst first
+    a, b = [], []
+    long_prompt = [3 + (7 * i) % 200 for i in range(27)]
+    return await asyncio.gather(
+        _collect(engine, [5, 9, 13], 41, out=a),
+        _collect(engine, [7, 11, 2, 8], 37, out=b),
+        _after(9, [a, b], _collect(engine, long_prompt, 14)),
+    )
+
+
+WORKLOADS = {
+    # name: (driver, engine options, tokens wanted of each stream)
+    "churn": (_churn, {}, (11, 6, 9, 5, 13)),
+    "midstream": (_midstream, {"slots": 4}, (61, 58, 19, 10, 7)),
+    "chunked": (_chunked, {"slots": 4, "max_prefill_chunk_tokens": 8},
+                (41, 37, 14)),
+}
+
+
+async def _run_workload(name: str, pipeline: bool):
+    drive, opts, _ = WORKLOADS[name]
+    engine = InferenceEngine(SPEC, _cfg(pipeline, **opts))
+    watch = _Watch(engine)
     await engine.start()
     try:
-        # more requests than slots -> admission churn + pipeline flushes;
-        # budgets not divisible by the burst -> mid-burst length stops;
-        # mixed greedy + seeded sampling
-        jobs = [
-            _collect(engine, [5, 9, 13], 11),
-            _collect(engine, [7, 11], 6, temperature=0.9, seed=42),
-            _collect(engine, [3, 5, 9, 13], 9),
-            _collect(engine, [17, 19], 5, temperature=0.7, seed=7),
-            _collect(engine, [2, 4, 6], 13),
-        ]
-        outs = await asyncio.gather(*jobs)
+        outs = await drive(engine)
         assert engine.allocator.active_pages == 0
-        assert not engine._pipeline or True  # drained naturally below
-        return outs
+        return outs, watch
     finally:
         await engine.close()
 
 
-async def test_pipelined_matches_unpipelined_exactly():
-    want = await _run_workload(False)
-    got = await _run_workload(True)
+@pytest.mark.parametrize("name", list(WORKLOADS))
+async def test_pipelined_matches_unpipelined_exactly(name):
+    want, plain = await _run_workload(name, False)
+    got, watch = await _run_workload(name, True)
     assert got == want
-    for o, mt in zip(got, (11, 6, 9, 5, 13)):
-        assert len(o) == mt
+    assert tuple(len(o) for o in got) == WORKLOADS[name][2]
+    assert set(plain.ahead("prefill")) | set(plain.ahead("decode")) == {0}
+    # one burst queued behind the running one, never two: a prompt's
+    # prefill waits for one burst at most
+    assert max(watch.ahead("prefill")) <= 1
+    assert max(watch.ahead("decode")) == 1
+    if name == "midstream":
+        # those prefills really were launched behind a burst in flight
+        assert watch.ahead("prefill").count(1) >= 3
+    if name == "chunked":
+        # 27 tokens at 8 a chunk: the chunks after the first each found
+        # the pipeline flushed
+        assert watch.ahead("prefill").count(0) >= 4
+
+
+async def test_one_burst_in_flight_whenever_the_oldest_is_read():
+    """Steady decode: every burst but the first is dispatched behind one
+    burst, and every read that follows a dispatch leaves one burst in
+    flight (by construction the device is never left without queued work);
+    only the drain at the stream's end reads with nothing behind it."""
+    engine = InferenceEngine(SPEC, _cfg(True, slots=2))
+    watch = _Watch(engine)
+    await engine.start()
+    try:
+        outs = await asyncio.gather(
+            _collect(engine, [5, 9, 13], 49), _collect(engine, [7, 11], 49))
+    finally:
+        await engine.close()
+    assert [len(o) for o in outs] == [49, 49]
+    log = [e for e in watch.log if e[0] != "prefill"]
+    assert watch.ahead("decode")[0] == 0
+    assert set(watch.ahead("decode")[1:]) == {1}
+    after_dispatch = [b for a, b in zip(log, log[1:])
+                      if a[0] == "decode" and b[0] == "read"]
+    assert len(after_dispatch) >= 10
+    assert {n for _k, n in after_dispatch} == {1}
+    drains = [b for a, b in zip(log, log[1:])
+              if a[0] == "read" and b[0] == "read"]
+    assert {n for _k, n in drains} <= {0}
+    assert log[-1] == ("read", 0) and not engine._pipeline
 
 
 async def test_pipelined_eos_stop():

@@ -82,7 +82,7 @@ def test_cpu_smoke_ladder_carries_variance_protocol():
     )
     assert ladder["repeats"] == 2
     assert ladder["family"] == "gqa"
-    for key in ("burst", "pipeline_depth", "prefill_budget", "bars"):
+    for key in ("burst", "prefill_budget", "bars"):
         assert key in ladder
     assert ladder["bars"]["frac_of_raw_decode"] == 0.60
     assert ladder["bars"]["ttft_p99_over_p50_max"] == 2.0
@@ -234,7 +234,7 @@ def test_family_serving_tuning_table():
     """Each north-star family has its own ladder tuning, and the bars
     artifact records the per-family frac targets."""
     for fam in ("gqa", "mla", "gptoss"):
-        assert {"burst", "depth", "budget_frac"} <= set(
+        assert {"burst", "budget_frac"} <= set(
             bench.FAMILY_SERVING[fam]
         )
         assert fam in bench.SERVING_BARS["frac_of_raw_decode"]

@@ -134,7 +134,9 @@ PROFILER_ANNOTATIONS: dict[str, str] = {
                      "(prefill | decode | verify | sample | logprobs | "
                      "feed), seq (running launch number), and the host "
                      "counts it was built from (prefill/verify: tokens, "
-                     "rows; decode: steps, live, slots; sample: rows)",
+                     "rows; decode: steps, live, slots; sample: rows; "
+                     "prefill and decode: ahead, the decode bursts in "
+                     "flight at the launch)",
     "engine.clock": "once a step-loop cycle: mono_ns = "
                     "time.monotonic_ns(), to fit the profiler's clock to "
                     "the flight recorder's and the clients'",
