@@ -69,7 +69,9 @@ PREFILL_VS_REFERENCE_TOL = 0.05
 PALLAS_VS_XLA_TOL = 0.05
 TP4_VS_TP1_TOL = 0.05
 FP8_ATTN_TOL = 0.05  # fused fp8 kernel against the XLA quantized path
-TP_LEADING_TOKENS = 4  # greedy tokens tp=4 and tp=1 must share per stream
+# greedy tokens tp=4 and tp=1 must share per stream, unless they part
+# earlier at a tie of tp=1's own bf16 logits (streams_agree)
+TP_LEADING_TOKENS = 4
 
 _A = "The quick brown fox jumps over the lazy dog. "
 REQUESTS = (
@@ -578,6 +580,35 @@ def fp8_fused_vs_xla(spec, page_size: int, pages_per_seq: int) -> None:
 # ------------------------------------------------------------ four chips
 
 
+def streams_agree(engine, prompt, one, four) -> int:
+    """tp=4's greedy stream ``four`` against tp=1's ``one`` (``engine``:
+    the tp=1 engine, closed). They must share ``TP_LEADING_TOKENS`` tokens.
+    A stream may part sooner only at a tie: where tp=1's own logit of
+    tp=4's token stands no further under its best than one unit in the
+    last place of a bfloat16, which the logits are. An argmax between two
+    such tokens is decided by the order of a sum. Returns the tokens
+    shared."""
+    same = next(
+        (i for i, (a, b) in enumerate(zip(one, four)) if a != b), len(one)
+    )
+    say(f"tp=4 vs tp=1 greedy stream ({len(prompt)}-token prompt): "
+        f"first {same} of {len(one)} tokens agree")
+    if same < min(TP_LEADING_TOKENS, len(one)):
+        logits = engine_prefill_logits(engine, list(prompt) + one[:same])
+        best = float(logits.max())
+        gap = best - float(logits[four[same]])
+        ulp = 2.0 ** (math.floor(math.log2(abs(best))) - 7)
+        say(f"  they part at token {same}: tp=1 gives tp=4's choice "
+            f"{gap:.5f} less than its best, {best:.4f}, where a bfloat16 "
+            f"steps by {ulp:.5f}")
+        check(
+            gap <= ulp,
+            f"streams part after {same} tokens (< {TP_LEADING_TOKENS}) "
+            "and not at a tie",
+        )
+    return same
+
+
 def shard_report(engine, device) -> tuple[int, int]:
     """(bytes ``device`` should hold, bytes of the whole model): weights
     and pools, what param_shardings / cache_shardings imply — replicated
@@ -659,9 +690,12 @@ def four_chip_mode() -> None:
         }
         for d in jax.devices():
             say(f"[tp={tp}] device {d.id}: {memory_line(d)}")
-        # free the chips before the other side builds
-        engine.params = engine.k_pages = engine.v_pages = None
-        del phase, engine
+        if tp > 1:
+            # free the chips before the other side builds; the tp=1
+            # engine stays for the streams' near-tie check
+            engine.params = engine.k_pages = engine.v_pages = None
+            del engine
+        del phase
         gc.collect()
     for prompt, want in sides[1]["logits"].items():
         compare_logits(
@@ -669,16 +703,7 @@ def four_chip_mode() -> None:
             f"tp=4 vs tp=1 prefill logits ({len(prompt)} tokens)",
         )
     for prompt, one in sides[1]["streams"].items():
-        four = sides[4]["streams"][prompt]
-        same = next(
-            (i for i, (a, b) in enumerate(zip(one, four)) if a != b), len(one)
-        )
-        say(f"tp=4 vs tp=1 greedy stream ({len(prompt)}-token prompt): "
-            f"first {same} of {len(one)} tokens agree")
-        check(
-            same >= TP_LEADING_TOKENS,
-            f"streams part after {same} tokens (< {TP_LEADING_TOKENS})",
-        )
+        streams_agree(engine, prompt, one, sides[4]["streams"][prompt])
 
 
 # ------------------------------------------------------------- one chip

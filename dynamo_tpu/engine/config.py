@@ -482,15 +482,26 @@ class EngineConfig:
         beside the weights and the cache (None = the backend reports no
         limit, as on the CPU: everything configured is offered).
 
-        Prefill attention materialises f32 scores ``[rows, H/tp, T,
-        max_context]`` per layer (ops/attention.causal_attention); the
-        chip's compiler reports 1.3-1.5x that as the program's temporary
-        memory at Llama-3-8B widths, plus well under 96 KiB per prompt
-        token for the MLP and the rest. A pack width that does not fit
-        halves; a bucket that does not fit even one row is not offered,
-        nor is any above it — longer prompts then go through chunks of
-        the largest bucket that is (``max_prefill_chunk_tokens`` is
-        capped by it). A guard with margin, not a tuner."""
+        The guard charges a pack float32 scores ``[rows, H/tp, T,
+        max_context]`` x 1.5 a layer plus 96 KiB per prompt token: what
+        the programs held while prefill attention scored the whole table
+        (PR 21's ``memory_analysis()``). Since the walk over pages
+        (ops/attention.paged_prefill_attention) they hold scores ``[rows,
+        H/tp, 128, 256]`` whatever the table, 186 MiB in all for a pack
+        of 8 x 512 at Llama-3-8B widths at 4,096-token and 32,768-token
+        tables alike, where this charges 3.4 and 26 GiB (PERF.md section
+        6, PR 31). So the charge is an UPPER BOUND now, too high by the
+        table's width over a block's: past 4k-token tables it halves
+        packs, and past 8k refuses buckets, that would fit. Bringing it
+        down widens what an engine compiles at start (one
+        ``prefill_pack_size`` serves every bucket, so the top bucket gets
+        its pack back, a program more), which is why it waits on a pack
+        width a bucket in the configuration: ROADMAP.md S3 (b). A pack
+        width that does not fit halves; a bucket that does not fit even
+        one row is not offered, nor is any above it — longer prompts then
+        go through chunks of the largest bucket that is
+        (``max_prefill_chunk_tokens`` is capped by it). A guard with
+        margin, not a tuner."""
         top = self.bucket_for(min(
             self.max_context, self.max_prefill_chunk_tokens,
             self.prefill_buckets[-1],

@@ -469,6 +469,15 @@ class InferenceEngine:
         self._kv_chunk_pages = self._full_table_chunk_pages()
         self.decode_kv = {"pages_live": 0, "pages_fetched": 0,
                           "pages_table": 0}
+        # what the prefill walk visited, in blocks of pages a layer, over
+        # the dispatched prefills and verifies (always on: integer
+        # arithmetic on [rows, tiles] a dispatch), a layer kind apart,
+        # beside what a walk of the table's whole width would have
+        self._prefill_walks = self._prefill_walk_windows()
+        self.prefill_kv = {
+            f"blocks_{what}.{kind}": 0
+            for kind in self._prefill_walks for what in ("visited", "table")
+        }
         # worker telemetry feeds (engine/telemetry.py EngineCollector):
         # the step thread only appends to bounded deques / bumps ints;
         # the collector turns them into /metrics histograms+counters
@@ -560,6 +569,8 @@ class InferenceEngine:
           serving window means a shape escaped the warmup set.
         - ``decode_kv.pages_live`` / ``.pages_fetched`` / ``.pages_table``
           (calls): see ``_count_decode_kv``.
+        - ``prefill_kv.blocks_visited.<kind>`` / ``.blocks_table.<kind>``
+          (calls; kind ``full`` or ``window``): see ``_count_prefill_kv``.
         """
         snap = {
             k: {"secs": round(v[0], 4), "calls": int(v[1])}
@@ -582,6 +593,8 @@ class InferenceEngine:
             snap[f"moe.{name}"] = {"secs": 0.0, "calls": n}
         for name, n in self.decode_kv.items():
             snap[f"decode_kv.{name}"] = {"secs": 0.0, "calls": n}
+        for name, n in self.prefill_kv.items():
+            snap[f"prefill_kv.{name}"] = {"secs": 0.0, "calls": n}
         return snap
 
     def reset_profile_window(self) -> None:
@@ -592,6 +605,7 @@ class InferenceEngine:
         self._prof_requests.clear()
         self.dispatches = 0
         self.decode_kv = dict.fromkeys(self.decode_kv, 0)
+        self.prefill_kv = dict.fromkeys(self.prefill_kv, 0)
         self._compile_base = compile_snapshot()
 
     def _full_table_chunk_pages(self) -> int | None:
@@ -645,6 +659,46 @@ class InferenceEngine:
         kv["pages_table"] += (
             len(batch["active"]) * self.config.max_pages_per_seq * n_burst
         )
+
+    def _prefill_walk_windows(self) -> dict[str, int]:
+        """The kinds of attention layer whose prefill is the walk over
+        pages (``ops/attention.paged_prefill_attention``), each with its
+        window: ``full`` (0) and ``window``, as the model has them; none
+        where the family's prefill reads no such cache."""
+        if self.spec.is_mla:
+            return {}
+        windows = {
+            self.spec.kind(li).window for li in range(self.spec.num_layers)
+        }
+        return {"window" if w else "full": w for w in sorted(windows)}
+
+    def _count_prefill_kv(self, rows: int, pages: int, starts, nts) -> None:
+        """Add a dispatched walk over pages (a prefill, a pack of them or
+        a speculative verify: ``rows`` padded rows a sequence against a
+        table ``pages`` wide as the program was handed it; ``starts``,
+        ``nts``: each sequence's first position and real rows, 0 for a
+        padded member) to ``prefill_kv``: the blocks of pages the walk
+        visits (``blocks_visited``: by the walk's own ``prefill_blocks`` a
+        query tile, a pack running each tile to its longest member) and
+        the blocks a walk of the whole table would (``blocks_table``), one
+        layer's worth a layer kind. Their ratio is how far prefill
+        attention follows the prompts."""
+        from dynamo_tpu.ops.attention import prefill_blocks, prefill_tiling
+
+        page = self.config.page_size
+        starts = np.asarray(starts, np.int32).reshape(-1, 1)
+        nts = np.asarray(nts, np.int32).reshape(-1, 1)
+        for kind, window in self._prefill_walks.items():
+            tq, bp = prefill_tiling(rows, pages, page, window)
+            tiles = np.arange(-(-rows // tq), dtype=np.int32)[None, :]
+            _, count = prefill_blocks(starts, nts, tiles, tq, window, page, bp)
+            kv = self.prefill_kv
+            kv[f"blocks_visited.{kind}"] += (
+                int(count.max(axis=0).sum()) * len(starts)
+            )
+            kv[f"blocks_table.{kind}"] += (
+                len(starts) * tiles.size * -(-pages // bp)
+            )
 
     # -- precompile (startup warmup) ---------------------------------------
 
@@ -2811,6 +2865,7 @@ class InferenceEngine:
                 for p in group:
                     p["waiting"].prefill_seq = self._launch_seq
                 self.dispatches += 1
+                self._count_prefill_kv(bucket, bts.shape[1], starts, nts)
             except Exception as e:  # noqa: BLE001
                 log.exception("packed prefill failed (%d prompts)", len(group))
                 self._spmd_broken(
@@ -3411,6 +3466,9 @@ class InferenceEngine:
                 **mm_kwargs,
             )
         self.dispatches += 1
+        self._count_prefill_kv(
+            bucket, len(block_table), start, len(new_tokens)
+        )
         return logits
 
     def _advance_partial_safe(self) -> None:
@@ -3783,6 +3841,7 @@ class InferenceEngine:
                     ),
                 )
             self.dispatches += 1
+            self._count_prefill_kv(W, bts.shape[1], starts, nts)
             with self._phase("dispatch.d2h_wait"):
                 targets = np.asarray(targets)
         self.spec_verifies += 1

@@ -27,10 +27,8 @@ from dynamo_tpu.engine.config import ModelSpec
 from dynamo_tpu.ops.attention import (
     causal_attention,
     decode_update_attention,
-    gather_ctx,
-    gather_pages,
     page_tiles,
-    window_table,
+    paged_prefill_attention,
 )
 from dynamo_tpu.ops.quant import (
     QuantPool,
@@ -517,34 +515,24 @@ def _ctx_attention(
     spec: ModelSpec, li: int, lp: Params, q, k, v, k_pool, v_pool, lj: int,
     block_table, positions, kv_len,
 ):
-    """Attention of one sequence's new queries (q [T, H, D] at
-    ``positions``; their own k, v already written to the pools) over its
-    paged context. A window layer gathers the pages its window reaches,
-    not the table."""
+    """Attention of one sequence's new queries (q [T, H, D] at the
+    consecutive ``positions``; their own k, v already written to the
+    pools) over its paged context, walked in blocks of pages
+    (``paged_prefill_attention``): what is gathered and scored follows
+    the prompt's length, a query tile's causal edge and the layer's
+    window, not the table's width. Every prefill program and the
+    speculative verify come through here."""
     kd = spec.kind(li)
-    page_size = k_pool.shape[3]
-    table, offset = window_table(
-        block_table, positions[0], q.shape[0], kd.window, page_size
-    )
-    # [ctx, kvh, D] — sliced back to the model dim when padded,
-    # dequantized when the pool is fp8
-    k_ctx = gather_ctx(k_pool, lj, table, spec.head_dim)
-    v_ctx = gather_ctx(v_pool, lj, table, spec.v_dim)
-    if is_quant(k_pool):
-        # overlay the EXACT in-flight rows over the quantized read-back
-        # (the XLA mirror of the fused kernel's analytic new-token
-        # merge): the new tokens attend to each other at full precision;
-        # only the cached prefix pays fp8
-        k_ctx = k_ctx.at[positions - offset].set(
-            k.astype(k_ctx.dtype), mode="drop"
-        )
-        v_ctx = v_ctx.at[positions - offset].set(
-            v.astype(v_ctx.dtype), mode="drop"
-        )
     with _scope(attn_scope(spec, li)):
-        return causal_attention(
-            q, k_ctx, v_ctx, positions, kv_len, window=kd.window,
-            sinks=lp.get("sinks"), kv_offset=offset,
+        return paged_prefill_attention(
+            q, k_pool, v_pool, lj, block_table, positions[0], kv_len,
+            head_dim=spec.head_dim, v_dim=spec.v_dim, window=kd.window,
+            sinks=lp.get("sinks"),
+            # the EXACT in-flight rows over a quantised pool's read-back
+            # (the XLA mirror of the fused kernel's analytic new-token
+            # merge): the new tokens attend to each other at full
+            # precision; only the cached prefix pays fp8
+            new_kv=(k, v) if is_quant(k_pool) else None,
         )
 
 
@@ -573,8 +561,10 @@ def prefill_forward_impl(
 ) -> tuple[jax.Array, jax.Array, jax.Array]:
     """Process one prompt; writes KV pages; returns (last_logits, k, v).
 
-    Attention runs over the gathered paged context (cached prefix + newly
-    written tokens), so prefix-cache hits skip recompute of cached tokens.
+    Attention walks the paged context (cached prefix + newly written
+    tokens) in blocks of pages (_ctx_attention), so prefix-cache hits skip
+    recompute of cached tokens and its cost follows the prompt, not the
+    table.
     ``mm_embeds``/``mm_pos``: encoder rows overwrite the placeholder
     tokens' embeddings (multimodal EPD injection — one masked scatter;
     padded positions >= T drop).
@@ -670,7 +660,7 @@ def prefill_forward_batch_impl(
     A queue of same-bucket prompts lands as one jit call instead of N:
     matmuls batch over [N, T, d] (the MXU sees N*T rows), the per-layer
     KV write is ONE page-tile scatter over all N*T/page pages, and
-    attention runs per prompt over its own table. This is what takes
+    attention runs per prompt over its own pages. This is what takes
     admission TTFT from O(N * dispatch) to O(dispatch): dispatch and
     host<->device round-trips dominate short prefills, especially when
     the host is far from the chip.

@@ -6,13 +6,17 @@ contiguous all-heads block = one DMA descriptor); a sequence's pages are
 listed in its row of ``block_tables [B, max_pages_per_seq]``. This is the
 TPU-first replacement for the reference's engine-internal (vLLM) paged
 attention + its block-copy CUDA kernel (lib/llm/src/kernels/block_copy.cu):
-XLA-friendly gathers/scatters here, and the one place a decode step's
+XLA-friendly gathers/scatters here, the one place a decode step's
 attention is chosen (``decode_update_attention``): the fused Pallas kernel
 (ops/pallas/fused_decode.py) where Pallas runs, the XLA reference
-(``paged_decode_attention``) where it does not.
+(``paged_decode_attention``) where it does not; and the attention of every
+prefill-like program (``paged_prefill_attention``): a walk over a
+sequence's pages in blocks, under a running softmax.
 
-All functions are shape-static and jit-safe. GQA is handled by repeating KV
-heads up to the query head count.
+All functions are shape-static and jit-safe. The reference forms
+(``causal_attention``, ``paged_decode_attention``) handle GQA by repeating KV
+heads up to the query head count; the prefill walk scores a KV head against
+its group of query heads.
 """
 
 from __future__ import annotations
@@ -93,30 +97,32 @@ def repeat_kv(x: jax.Array, n_rep: int) -> jax.Array:
 def gather_pages(
     pages: jax.Array,  # [num_pages, kv_heads, page_size, head_dim]
     block_table: jax.Array,  # [max_pages_per_seq] int32
-    layer: int | None = None,  # ``pages`` is a pool [L, num_pages, ...]
+    layer=None,  # ``pages`` is a pool [L, num_pages, ...]: its layer
 ) -> jax.Array:
-    """Materialize one sequence's KV as [max_ctx, kv_heads, head_dim].
-    With ``layer`` the pages are gathered out of the pool in one indexing
-    step: ``pool[layer][table]`` had the compiler copy the layer's whole
-    slice of the pool first (0.83 ms a call at 600 MB; my chip run, PR 28)."""
+    """Materialize the pages ``block_table`` lists as [len(table) * page,
+    kv_heads, head_dim]: a whole table (the decode reference) or one block
+    of it (the prefill walk). With ``layer`` the pages are gathered out
+    of the pool in one indexing step: ``pool[layer][table]`` had the
+    compiler copy the layer's whole slice of the pool first (0.83 ms a
+    call at 600 MB; my chip run, PR 28)."""
     toks = pages[block_table] if layer is None else pages[layer, block_table]
     P, H, page, D = toks.shape
     return toks.transpose(0, 2, 1, 3).reshape(P * page, H, D)
 
 
-def gather_ctx(pool, li: int, block_table: jax.Array, head_dim: int):
-    """One layer's context for a sequence, pool-form-agnostic: plain
-    arrays gather in the pool dtype; QuantPool (ops/quant.py) gathers
-    fp8 pages and dequantizes with the per-page/head scales. Sliced back
-    to the MODEL head dim when the pool is lane-padded. The single
-    gather used by every XLA attention site (prefill/verify/CPU decode),
-    so the fp8 gather/dequant path can't be missed by one of them."""
+def gather_ctx(pool, li, block_table: jax.Array, head_dim: int):
+    """One layer's rows on the pages ``block_table`` lists (a block of
+    a sequence's table in the prefill walk, a whole table in the tests'
+    oracle), pool-form-agnostic: plain arrays gather in the pool dtype;
+    QuantPool (ops/quant.py) gathers fp8 pages and dequantizes with the
+    per-page/head scales. Sliced back to the MODEL head dim when the
+    pool is lane-padded. The single gather of the prefill walk (prefill,
+    packed prefill, verify), so the fp8 gather/dequant path can't be
+    missed by one of them."""
     from dynamo_tpu.ops.quant import gather_dequant_pages, is_quant
 
     if is_quant(pool):
-        return gather_dequant_pages(pool.layer(li), block_table)[
-            ..., :head_dim
-        ]
+        return gather_dequant_pages(pool, block_table, li)[..., :head_dim]
     return gather_pages(pool, block_table, li)[..., :head_dim]
 
 
@@ -166,6 +172,160 @@ def causal_attention(
         probs = jax.nn.softmax(logits, axis=-1)
     out = jnp.einsum("hts,shd->thd", probs, v.astype(jnp.float32))
     return out.astype(q.dtype)
+
+
+# The prefill walk's shapes (``prefill_tiling``): rows a query tile, tokens
+# a block of pages. Fixed by a probe on the chip at the cells' shapes
+# (PERF.md section 6, PR 31): the scores of a tile against a block,
+# ``[rows, H, 128, 256]`` float32, are 8 MiB a row at MiMo's 64 heads and stay
+# in the chip's fast memory; 32 MiB and more (256 x 512 under a pack of two)
+# cost a third more time a call, 128 MiB (512 x 1,024) three times.
+_TILE_ROWS = 128
+_BLOCK_TOKENS = 256
+
+
+def prefill_tiling(
+    n_queries: int, pages_per_seq: int, page_size: int, window: int = 0
+) -> tuple[int, int]:
+    """``(tq, bp)`` of the prefill walk, from what a program sees at trace
+    time: rows a query tile and pages a KV block. A table, or a window
+    layer's reach from one tile (its rows and the ``window - 1`` tokens
+    before them), no wider than one block IS one block: such a tile's
+    walk is a single step."""
+    tq = min(n_queries, _TILE_ROWS)
+    reach = pages_per_seq
+    if window:
+        reach = min(reach, (tq + window - 2) // page_size + 2)
+    return tq, min(reach, max(1, _BLOCK_TOKENS // page_size))
+
+
+def prefill_blocks(
+    start_pos, num_tokens, tile, tq: int, window: int, page_size: int,
+    bp: int,
+):
+    """``(first page, blocks)`` a query tile of the prefill walk visits:
+    blocks of ``bp`` pages from the page of the first key its mask can
+    reach. The tile's rows ``[tile * tq, (tile + 1) * tq)`` of a call
+    stand at ``start_pos + row``; those under ``num_tokens`` are real, and
+    see the keys ``[max(0, p0 - window + 1), p1]`` between the first row's
+    window and the last real row (``window`` 0: from 0). No block for a
+    tile without a real row. Plain arithmetic on numpy or jax integers
+    alike: the walk's trip count, the engine's ``prefill_kv`` counters and
+    the tests are this one function (as ``live_chunks`` is the decode
+    kernel's)."""
+    p0 = start_pos + tile * tq
+    p1 = (p0 + tq).clip(None, start_pos + num_tokens) - 1
+    live = p1 >= p0
+    first = ((p0 - window + 1).clip(0) if window else p0 * 0) // page_size
+    return first * live, ((p1 // page_size - first) // bp + 1) * live
+
+
+@functools.partial(jax.jit, static_argnames=("head_dim", "v_dim", "window"))
+def paged_prefill_attention(
+    q: jax.Array,  # [T, H, D]: queries at positions start_pos + arange(T)
+    k_pool,  # [L, num_pages, KH, page, >= D] (arrays or a QuantPool)
+    v_pool,  # [L, num_pages, KH, page, >= Dv]
+    layer,  # scalar: the pools' layer. Not static: one trace a layer kind
+    block_table: jax.Array,  # [P] the sequence's pages
+    start_pos: jax.Array,  # scalar: position of q[0]
+    kv_len: jax.Array,  # scalar: start_pos + the real rows of q
+    *,
+    head_dim: int,  # the model's K width (the pool may be lane-padded)
+    v_dim: int,
+    window: int = 0,
+    sinks: jax.Array | None = None,  # [H]
+    new_kv: tuple | None = None,  # (k [T, KH, D], v [T, KH, Dv]) exact rows
+) -> jax.Array:
+    """``causal_attention`` of a call's queries over the sequence's PAGED
+    context, walked in blocks with a running softmax: what is gathered and
+    scored follows the prompt, the tile's causal edge and the window, not
+    the table's width (a 300-token prompt in a 4,608-token table costs 300
+    tokens' worth).
+
+    Queries go in tiles of ``tq`` rows, keys in blocks of ``bp`` pages
+    (``prefill_tiling``); a tile visits the blocks ``prefill_blocks``
+    names, a run-time count (``fori_loop``; under ``vmap`` a pack runs to
+    its longest member). A block's pages are gathered in one indexing
+    step (``gather_ctx``), scored a KV head at a time against the
+    ``H / KH`` query heads that share it (no repeated copy of K and V),
+    masked by position and folded into running ``(m, l, acc)``. A head's
+    sink logit is the walk's first ``(m, l) = (sink, 1)`` with a zero
+    value row: ``causal_attention``'s concatenated column. Precision is
+    ``causal_attention``'s: float32 operands, scores, softmax and
+    accumulation. ``new_kv`` lays the call's exact rows over a quantised
+    pool's read-back, block by block. Plain XLA: the CPU and a tp mesh
+    (heads sharded by GSPMD) run it as it stands. A jit of its own inside
+    the program's, so that the layers of a kind share one trace, under
+    ``vmap`` too: traced a layer it cost a packed program of 7 layers
+    1.8 s of set-up, warm (my chip run, PR 31). Returns [T, H, Dv]."""
+    T, H, D = q.shape
+    KH, page = k_pool.shape[2], k_pool.shape[3]
+    G = H // KH
+    P = block_table.shape[0]
+    tq, bp = prefill_tiling(T, P, page, window)
+    n_tiles = -(-T // tq)
+    span = bp * page
+    scale = 1.0 / jnp.sqrt(jnp.asarray(D, jnp.float32))
+    # [tiles, KH, G * tq, D]: a KV head's G query heads, tile rows within
+    qt = jnp.pad(q.astype(jnp.float32), ((0, n_tiles * tq - T), (0, 0), (0, 0)))
+    qt = qt.reshape(n_tiles, tq, KH, G, D).transpose(0, 2, 3, 1, 4)
+    qt = qt.reshape(n_tiles, KH, G * tq, D)
+    if sinks is None:
+        m0 = jnp.full((KH, G, tq), NEG_INF, jnp.float32)
+    else:
+        m0 = jnp.broadcast_to(
+            sinks.astype(jnp.float32).reshape(KH, G, 1), (KH, G, tq)
+        )
+    l0 = jnp.full((KH, G, tq), 0.0 if sinks is None else 1.0, jnp.float32)
+    acc0 = jnp.zeros((KH, G, tq, v_dim), jnp.float32)
+
+    def tile_attention(i):
+        q_pos = start_pos + i * tq + jnp.arange(tq)
+        first, count = prefill_blocks(
+            start_pos, kv_len - start_pos, i, tq, window, page, bp
+        )
+
+        def block(j, carry):
+            m, l, acc = carry
+            page0 = first + j * bp
+            # past the table's end the ids repeat its last entry: those
+            # positions lie past the sequence's length and are masked
+            ids = block_table[jnp.minimum(page0 + jnp.arange(bp), P - 1)]
+            kb = gather_ctx(k_pool, layer, ids, head_dim)  # [span, KH, D]
+            vb = gather_ctx(v_pool, layer, ids, v_dim)
+            kv_pos = page0 * page + jnp.arange(span)
+            if new_kv is not None:
+                rows = start_pos + jnp.arange(T) - page0 * page
+                rows = jnp.where(rows < 0, span, rows)  # before the block
+                kb = kb.at[rows].set(new_kv[0].astype(kb.dtype), mode="drop")
+                vb = vb.at[rows].set(new_kv[1].astype(vb.dtype), mode="drop")
+            s = jnp.einsum(
+                "kqd,skd->kqs", qt[i], kb.astype(jnp.float32)
+            ).reshape(KH, G, tq, span) * scale
+            mask = (kv_pos[None, :] <= q_pos[:, None]) & (kv_pos < kv_len)
+            if window:
+                mask &= kv_pos[None, :] > q_pos[:, None] - window
+            s = jnp.where(mask, s, NEG_INF)
+            m_new = jnp.maximum(m, s.max(axis=-1))
+            alpha = jnp.exp(m - m_new)
+            # a row that has met no key yet (m_new still NEG_INF) sums
+            # ones here; its first real key's alpha of exactly 0 drops them
+            p = jnp.exp(s - m_new[..., None])
+            pv = jnp.einsum(
+                "kqs,skd->kqd", p.reshape(KH, G * tq, span),
+                vb.astype(jnp.float32),
+            ).reshape(KH, G, tq, v_dim)
+            return (m_new, alpha * l + p.sum(axis=-1),
+                    alpha[..., None] * acc + pv)
+
+        _, l, acc = jax.lax.fori_loop(0, count, block, (m0, l0, acc0))
+        # a tile of padded rows alone visits nothing: l may be 0 there
+        return acc / jnp.where(l == 0.0, 1.0, l)[..., None]
+
+    out = jax.lax.map(tile_attention, jnp.arange(n_tiles))
+    # [tiles, KH, G, tq, Dv] -> [T, H, Dv]
+    out = out.transpose(0, 3, 1, 2, 4).reshape(n_tiles * tq, H, v_dim)
+    return out[:T].astype(q.dtype)
 
 
 def paged_decode_attention(

@@ -274,11 +274,15 @@ def quant_append_rows(
 def gather_dequant_pages(
     pool_l: QuantPool,  # one layer: vals [NP, KH, page, D], scale [NP, KH]
     block_table: jax.Array,  # [P] int32
+    layer=None,  # ``pool_l`` is a whole pool [L, NP, ...]: its layer
 ) -> jax.Array:
     """Quantized counterpart of ops.attention.gather_pages: materialize
-    one sequence's context as f32 ``[P*page, KH, D]`` (dequantized)."""
-    toks = pool_l.vals[block_table]  # [P, KH, page, D]
-    s = pool_l.scale[block_table].astype(jnp.float32)  # [P, KH]
+    the listed pages as f32 ``[P*page, KH, D]`` (dequantized). With
+    ``layer`` (an int or a traced scalar) they are gathered out of the
+    pool in one indexing step, as there."""
+    at = (block_table,) if layer is None else (layer, block_table)
+    toks = pool_l.vals[at]  # [P, KH, page, D]
+    s = pool_l.scale[at].astype(jnp.float32)  # [P, KH]
     toks = toks.astype(jnp.float32) * s[:, :, None, None]
     P, H, page, D = toks.shape
     return toks.transpose(0, 2, 1, 3).reshape(P * page, H, D)

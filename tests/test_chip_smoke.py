@@ -7,6 +7,7 @@ import asyncio
 import dataclasses
 
 import jax
+import numpy as np
 import pytest
 
 import chip_smoke
@@ -15,7 +16,7 @@ from dynamo_tpu.engine.config import EngineConfig, ModelSpec
 pytestmark = pytest.mark.integration
 
 
-def test_serving_phase_on_cpu_at_tiny_spec():
+def test_serving_phase_on_cpu_at_tiny_spec(monkeypatch):
     """The whole stack in one process — hub, launch_engine_worker with
     precompile, HTTP frontend — serving the smoke's six requests: every
     count exact, every SSE chunk an engine delta, two prefill buckets
@@ -48,6 +49,21 @@ def test_serving_phase_on_cpu_at_tiny_spec():
         phase["engine"], list(min(phase["streams"], key=len))
     )
     assert logits.shape == (spec.vocab_size,)
+    # the four-chip phase's stream check: equal streams agree; one that
+    # parts early passes only where tp=1's logits tie to a bfloat16's last
+    # place (0.03125 between 4 and 8)
+    prompt = min(phase["streams"], key=len)
+    one = phase["streams"][prompt]
+    engine = phase["engine"]
+    assert chip_smoke.streams_agree(engine, prompt, one, one) == len(one)
+    tied = np.full_like(logits, -1.0)
+    tied[[5, 6, 7, 8]] = 4.375, 4.375, 4.34375, 4.3125
+    monkeypatch.setattr(chip_smoke, "engine_prefill_logits", lambda e, t: tied)
+    for token in (6, 7):
+        assert chip_smoke.streams_agree(
+            engine, prompt, [5] + one[1:], [token] + one[1:]) == 0
+    with pytest.raises(chip_smoke.SmokeFailure, match="not at a tie"):
+        chip_smoke.streams_agree(engine, prompt, [5] + one[1:], [8] + one[1:])
 
 
 def test_main_fails_without_a_tpu(capsys):

@@ -330,6 +330,10 @@ AUDIT: dict = {
         "_draw": "read-only",  # a key and a scale in, a fresh matrix out
         "insert_kv_pages": "donates",
         "embed_forward": "read-only",
+        # ops/attention's walk, imported: a jit of its own only so that a
+        # kind's layers share one trace; it reads the pools inside the
+        # prefill programs' jits, which are the ones that donate them
+        "paged_prefill_attention": "read-only",
     },
     mla: {
         "prefill_forward": "donates",

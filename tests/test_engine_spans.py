@@ -70,7 +70,9 @@ async def test_profile_off_records_nothing_and_creates_no_annotation(
     assert set(snap) == {"dispatch.d2h_wait", "readmit.d2h_wait",
                          "dispatch.dispatches", "dispatch.compile",
                          "decode_kv.pages_live", "decode_kv.pages_fetched",
-                         "decode_kv.pages_table"}
+                         "decode_kv.pages_table",
+                         "prefill_kv.blocks_visited.full",
+                         "prefill_kv.blocks_table.full"}
     # three bursts' worth at least, always on: pages moved for real contexts
     kv = {k.removeprefix("decode_kv."): v["calls"]
           for k, v in snap.items() if k.startswith("decode_kv.")}
@@ -197,7 +199,8 @@ def test_phase_annotations_match_the_profile_sums(traced):
     for phase, rec in snap.items():
         if (
             phase in synthesized
-            or phase.startswith("decode_kv.")  # counts, not phases
+            # counts, not phases
+            or phase.startswith(("decode_kv.", "prefill_kv."))
             or phase.startswith("readmit.") and phase != "readmit.d2h_wait"
         ):
             continue
