@@ -96,6 +96,11 @@ class ModelSpec:
     # keeps all its outputs and assignments to absent experts add nothing
     # here (their chips add them). Empty = all experts held.
     held_experts: tuple[int, ...] = ()
+    # multi-token-prediction layers the published checkpoint carries
+    # behind its ``num_layers`` decoder layers (``num_nextn_predict_
+    # layers``). The next-token forward pass has no use for them: the
+    # loader drops their tensors as expected, not as strays
+    nextn_predict_layers: int = 0
 
     def __post_init__(self) -> None:
         # a spec read from JSON brings lists and dicts; the spec is a
@@ -501,7 +506,9 @@ class EngineConfig:
         one row is not offered, nor is any above it — longer prompts then
         go through chunks of the largest bucket that is
         (``max_prefill_chunk_tokens`` is capped by it). A guard with
-        margin, not a tuner."""
+        margin, not a tuner. The latent family (``spec.is_mla``) is
+        charged what ITS programs hold (``need_latent``): their walk
+        never had a whole-table form to stay compatible with."""
         top = self.bucket_for(min(
             self.max_context, self.max_prefill_chunk_tokens,
             self.prefill_buckets[-1],
@@ -509,8 +516,26 @@ class EngineConfig:
         heads = max(1, spec.num_heads // max(1, tp))
 
         def need(rows: int, bucket: int) -> int:
+            if spec.is_mla:
+                return need_latent(rows, bucket)
             scores = 4 * rows * heads * bucket * self.max_context
             return scores * 3 // 2 + 96 * 1024 * rows * bucket
+
+        def need_latent(rows: int, bucket: int) -> int:
+            # what the latent walk holds (ops/attention.
+            # latent_prefill_attention), whatever the table: float32
+            # scores of all a call's rows against ONE block (scores,
+            # probabilities and their rounded copy: x 3), the running
+            # accumulator in and out of the loop, and the same 96 KiB a
+            # prompt token for the rest of the program
+            from dynamo_tpu.ops.attention import latent_prefill_tiling
+
+            _, bp = latent_prefill_tiling(
+                bucket, self.max_pages_per_seq, self.page_size
+            )
+            scores = 4 * rows * heads * bucket * bp * self.page_size
+            acc = 4 * rows * heads * bucket * spec.v_head_dim
+            return scores * 3 + acc * 2 + 96 * 1024 * rows * bucket
 
         shapes: dict[int, int] = {}
         for bucket in self.prefill_buckets:

@@ -432,7 +432,8 @@ class InferenceEngine:
         # slots (slot-identity guards handle reuse), so they materialize
         # independently as their copies land
         self._admit_waves: list[dict] = []
-        # the expert layers' counters (models/llama.py: KindPools.counts,
+        # the expert layers' counters (``fam.moe_counts``: KindPools.counts
+        # of a GQA model with layer kinds, the latent family's v slot;
         # added to on the device by the programs): the last host copy,
         # and the device copy on its way (_refresh_moe_counts)
         self.moe_counts: np.ndarray | None = None
@@ -570,7 +571,8 @@ class InferenceEngine:
         - ``decode_kv.pages_live`` / ``.pages_fetched`` / ``.pages_table``
           (calls): see ``_count_decode_kv``.
         - ``prefill_kv.blocks_visited.<kind>`` / ``.blocks_table.<kind>``
-          (calls; kind ``full`` or ``window``): see ``_count_prefill_kv``.
+          (calls; kind ``full`` or ``window``, or ``latent`` for the
+          latent family's walk): see ``_count_prefill_kv``.
         """
         snap = {
             k: {"secs": round(v[0], 4), "calls": int(v[1])}
@@ -609,13 +611,20 @@ class InferenceEngine:
         self._compile_base = compile_snapshot()
 
     def _full_table_chunk_pages(self) -> int | None:
-        """Pages a chunk of the fused decode kernel holds on this cache's
-        full-attention layers (``ops/pallas/fused_decode.chunk_pages``,
-        from the pools' own shapes); None where the cache is not one that
-        kernel reads."""
+        """Pages a chunk of the decode kernel holds on this cache's
+        full-attention layers, from the pools' own shapes
+        (``ops/pallas/fused_decode.chunk_pages``; the latent family's
+        kernel, ``latent_decode.latent_chunk_pages``); None where the
+        cache is not one that a kernel reads."""
         from dynamo_tpu.ops.pallas.fused_decode import pool_chunk_pages
+        from dynamo_tpu.ops.pallas.latent_decode import latent_chunk_pages
+        from dynamo_tpu.ops.quant import is_quant
 
         k, v = self.k_pages, self.v_pages
+        if self.spec.is_mla:
+            if is_quant(k):
+                return None  # an fp8 latent pool keeps the XLA walk
+            return latent_chunk_pages(k, self.config.max_pages_per_seq)
         if hasattr(k, "pools"):  # a pool a layer kind (llama.KindPools)
             full = next(
                 (i for i, kd in enumerate(self.spec.layer_kinds)
@@ -661,12 +670,13 @@ class InferenceEngine:
         )
 
     def _prefill_walk_windows(self) -> dict[str, int]:
-        """The kinds of attention layer whose prefill is the walk over
-        pages (``ops/attention.paged_prefill_attention``), each with its
-        window: ``full`` (0) and ``window``, as the model has them; none
-        where the family's prefill reads no such cache."""
+        """The kinds of attention layer whose prefill is a walk over
+        pages, each with its window: ``full`` (0) and ``window``, as a
+        GQA model has them (``ops/attention.paged_prefill_attention``);
+        ``latent`` for the latent family's
+        (``latent_prefill_attention``)."""
         if self.spec.is_mla:
-            return {}
+            return {"latent": 0}
         windows = {
             self.spec.kind(li).window for li in range(self.spec.num_layers)
         }
@@ -683,13 +693,18 @@ class InferenceEngine:
         the blocks a walk of the whole table would (``blocks_table``), one
         layer's worth a layer kind. Their ratio is how far prefill
         attention follows the prompts."""
-        from dynamo_tpu.ops.attention import prefill_blocks, prefill_tiling
+        from dynamo_tpu.ops.attention import (
+            latent_prefill_tiling, prefill_blocks, prefill_tiling,
+        )
 
         page = self.config.page_size
         starts = np.asarray(starts, np.int32).reshape(-1, 1)
         nts = np.asarray(nts, np.int32).reshape(-1, 1)
         for kind, window in self._prefill_walks.items():
-            tq, bp = prefill_tiling(rows, pages, page, window)
+            if kind == "latent":  # one tile of all the call's rows
+                tq, bp = latent_prefill_tiling(rows, pages, page)
+            else:
+                tq, bp = prefill_tiling(rows, pages, page, window)
             tiles = np.arange(-(-rows // tq), dtype=np.int32)[None, :]
             _, count = prefill_blocks(starts, nts, tiles, tq, window, page, bp)
             kv = self.prefill_kv
@@ -986,7 +1001,7 @@ class InferenceEngine:
         The copy is a program of its own behind whatever is queued, so
         it reads the counters as of this call; the pools' own leaf would
         be donated away by the next launch."""
-        counts = getattr(self.k_pages, "counts", None)
+        counts = self.fam.moe_counts(self.k_pages, self.v_pages)
         if counts is None or not counts.shape[-1]:
             return
         if self._metrics_publishes % 16 != 1:

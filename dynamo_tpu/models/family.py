@@ -13,14 +13,14 @@ core. Two families today:
   differ in KV heads, ``llama.KindPools``: a pool a kind over one
   page-id space, K ``head_dim`` wide and V ``v_dim``. Every leaf leads
   with a layer axis.
-- ``MlaFamily``: DeepSeek-V2/V3/R1 (models/mla.py) — ONE latent cache
-  array. The engine's (k_pages, v_pages) plumbing carries the latent
-  cache as ``k_pages`` and a tiny inert placeholder as ``v_pages`` so
-  page bookkeeping, KVBM tier blocks, and transfer metadata flow
-  unchanged. Supports meshes (tp over heads, ep over experts,
-  replicated latent cache), packed prefill, logprobs, and embeddings;
-  ring prefill (long MLA prompts chunk instead) and multimodal stay
-  gated off.
+- ``MlaFamily``: latent attention, DeepSeek-V2/V3/R1 and JoyAI-LLM-Flash
+  (models/mla.py): ONE latent cache, which rides the ``k_pages`` slot;
+  the ``v_pages`` slot carries the expert layers' counters (it was an
+  inert ``[1]`` placeholder), so page bookkeeping, KVBM tier blocks and
+  transfer metadata flow unchanged. Supports meshes (tp over heads, ep
+  over experts, replicated latent cache), packed prefill, logprobs, and
+  embeddings; ring prefill (long MLA prompts chunk instead) and
+  multimodal stay gated off.
 
 Ref: the reference delegates this dispatch to its engines (vLLM model
 registry); here it is explicit and small.
@@ -107,6 +107,12 @@ class GqaFamily:
             mesh=mesh, allowed=allowed,
         )
 
+    def moe_counts(self, k, v):
+        """The expert layers' device-side counters in this family's
+        cache, or None: a model with layer kinds keeps them on the K
+        side (``llama.KindPools.counts``)."""
+        return getattr(k, "counts", None)
+
     def extract_pages(self, k, v, page_ids):
         return self.m.extract_kv_pages(k, v, page_ids)
 
@@ -118,15 +124,21 @@ class GqaFamily:
 
 
 class MlaFamily:
-    """DeepSeek MLA adapter: latent cache rides the k_pages slot; the
-    v_pages slot carries an inert [1] placeholder everywhere.
+    """Latent-attention (MLA) adapter over models/mla.py. The engine's
+    ``(k_pages, v_pages)`` pair carries the family's two device-side
+    states: ``k_pages`` the ONE latent cache ``[L, pages, page, D]`` (an
+    array, or a ``QuantPool`` for fp8) and ``v_pages`` the expert layers'
+    counters ``[L, 2, n_held + 3]`` int32 (``[L, 2, 0]`` without
+    experts), which every program adds to as it runs. Both lead with a
+    layer axis, as every leaf of the pair does; page bookkeeping, KVBM
+    tier blocks and transfer metadata see the latent cache alone
+    (``extract_pages`` ships an inert v block).
 
-    Mesh story (deepseek-r1-class serving): per-head work shards over
-    "tp", experts over "ep" (mla.param_shardings), and the latent cache
-    replicates — it has no head axis and is ~14x smaller than GQA KV, so
-    every rank decodes against a local copy with no gather collective.
-    Ref topology: recipes/deepseek-r1/sglang-wideep/
-    tep16p-dep16d-disagg.yaml:63 (--ep-size 16)."""
+    Under a mesh per-head work shards over "tp", experts over "ep"
+    (mla.param_shardings), and the latent cache replicates: it has no
+    head axis, so every rank decodes against a local copy with no gather
+    collective (ref topology: recipes/deepseek-r1/sglang-wideep/
+    tep16p-dep16d-disagg.yaml:63, --ep-size 16)."""
 
     supports_packed_prefill = True
     supports_ring_prefill = False  # long MLA prompts take the chunked path
@@ -139,63 +151,70 @@ class MlaFamily:
     def __init__(self):
         from dynamo_tpu.models import mla
 
-        self.m = mla
+        self.mla = mla
+        # what a caller reaches for the family's one-step decode program
+        # in the pair's signature (``fam.m.decode_forward``)
+        self.m = self
 
     def init_params(self, spec, key):
-        return self.m.init_params(spec, key)
+        return self.mla.init_params(spec, key)
 
     def param_shardings(self, spec, mesh):
-        return self.m.param_shardings(spec, mesh)
+        return self.mla.param_shardings(spec, mesh)
 
     def cache_shardings(self, mesh, kv_dtype="bf16", spec=None):
-        s = self.m.cache_shardings(mesh, kv_dtype)
         from jax.sharding import NamedSharding, PartitionSpec as P
 
-        # placeholder v_pages is a single replicated leaf either way
-        return s, NamedSharding(mesh, P())
+        # the counters are a single replicated leaf either way
+        return self.mla.cache_shardings(mesh, kv_dtype), NamedSharding(mesh, P())
 
     def init_cache(self, spec, num_pages, page_size, kv_dtype="bf16"):
-        cache = self.m.init_cache(
+        cache = self.mla.init_cache(
             spec, num_pages, page_size, kv_dtype=kv_dtype
         )
-        return cache, jnp.zeros((1,), jnp.int8)  # inert v_pages placeholder
+        return cache, self.mla.init_counts(spec)
 
     def prefill(self, spec, params, tokens, bt, start, k, v, n, mesh=None):
-        logits, cache = self.m.prefill_forward(
-            spec, params, tokens, bt, start, k, n, mesh=mesh
+        logits, cache, counts = self.mla.prefill_forward(
+            spec, params, tokens, bt, start, k, n, mesh=mesh, counts=v
         )
         # engine contract: four values, the last a constant zero
         # (llama._no_drops)
-        return logits, cache, v, jnp.zeros((), jnp.int32)
+        return logits, cache, counts, jnp.zeros((), jnp.int32)
 
     def prefill_batch(self, spec, params, tokens, bts, starts, k, v, ns,
                       mesh=None):
-        logits, cache = self.m.prefill_forward_batch(
-            spec, params, tokens, bts, starts, k, ns, mesh=mesh
+        logits, cache, counts = self.mla.prefill_forward_batch(
+            spec, params, tokens, bts, starts, k, ns, mesh=mesh, counts=v
         )
-        return logits, cache, v, jnp.zeros((), jnp.int32)
+        return logits, cache, counts, jnp.zeros((), jnp.int32)
 
     def verify(self, spec, params, tokens, bts, starts, k, v, ns,
                mesh=None, allowed=None):
-        targets, cache = self.m.verify_forward(
+        targets, cache, counts = self.mla.verify_forward(
             spec, params, tokens, bts, starts, k, ns, mesh=mesh,
-            allowed=allowed,
+            allowed=allowed, counts=v,
         )
-        return targets, cache, v, jnp.zeros((), jnp.int32)
+        return targets, cache, counts, jnp.zeros((), jnp.int32)
+
+    def decode_forward(self, spec, params, tokens, bts, lens, k, v, active,
+                       mesh=None):
+        return self.mla.decode_forward(
+            spec, params, tokens, bts, lens, k, active, mesh=mesh, counts=v
+        )
 
     def decode_steps(self, spec, params, tokens, bts, lens, k, v, active,
                      temps, topk, topp, seeds, steps, *, n_steps, n_logprobs,
                      mesh=None, allowed=None):
-        result = self.m.decode_steps(
+        # (out[, logprobs, top ids, top values], cache, counts)
+        return self.mla.decode_steps(
             spec, params, tokens, bts, lens, k, active, temps, topk, topp,
             seeds, steps, n_steps=n_steps, n_logprobs=n_logprobs, mesh=mesh,
-            allowed=allowed,
+            allowed=allowed, counts=v,
         )
-        if n_logprobs > 0:
-            out, lp, ti, tv, cache = result
-            return out, lp, ti, tv, cache, v
-        out, cache = result
-        return out, cache, v
+
+    def moe_counts(self, k, v):
+        return v
 
     def extract_pages(self, k, v, page_ids):
         # latent blocks [L, n, page, D]; the v slot stays inert (kept in
@@ -208,7 +227,7 @@ class MlaFamily:
         return _insert_latent(k, page_ids, kb), v
 
     def embed_forward(self, spec, params, tokens, num_tokens):
-        return self.m.embed_forward(spec, params, tokens, num_tokens)
+        return self.mla.embed_forward(spec, params, tokens, num_tokens)
 
 
 @jax.jit

@@ -99,7 +99,7 @@ def spec_from_hf_config(cfg: dict, name: str | None = None) -> ModelSpec:
             swiglu_limit=float(cfg.get("swiglu_limit") or 7.0),
             swiglu_alpha=1.702,
         )
-    if model_type in ("deepseek_v2", "deepseek_v3"):
+    if model_type in ("deepseek_v2", "deepseek_v3", "joyai_llm_flash"):
         # DeepSeek MLA checkpoints store rope dims pair-interleaved
         # (HF DeepseekV3Config.rope_interleave defaults True)
         extras["rope_interleave"] = bool(cfg.get("rope_interleave", True))
@@ -158,6 +158,7 @@ def spec_from_hf_config(cfg: dict, name: str | None = None) -> ModelSpec:
         qk_rope_head_dim=int(cfg.get("qk_rope_head_dim") or 0),
         v_head_dim=int(cfg.get("v_head_dim") or 0),
         q_lora_rank=int(cfg.get("q_lora_rank") or 0),
+        nextn_predict_layers=int(cfg.get("num_nextn_predict_layers") or 0),
         **moe,
         **extras,
     )
@@ -470,6 +471,17 @@ def load_params(
         with safe_open(path_file, framework="numpy") as f:
             for name in f.keys():
                 if name not in dest:
+                    part = name.split(".")
+                    if (len(part) > 2 and part[1] == "layers"
+                            and part[2].isdigit()
+                            and int(part[2]) >= spec.num_layers):
+                        # behind the decoder: the multi-token-prediction
+                        # layers are dropped, as the published inference
+                        # code drops them; anything deeper is a stray
+                        if int(part[2]) >= (spec.num_layers
+                                            + spec.nextn_predict_layers):
+                            skipped_extras.append(name)
+                        continue
                     if spec.kv_lora_rank and name.endswith(
                         "self_attn.kv_b_proj.weight"
                     ):

@@ -1,36 +1,55 @@
-"""Multi-head Latent Attention (MLA): the DeepSeek-V2/V3/R1 attention.
+"""Multi-head Latent Attention (MLA): the DeepSeek-V2/V3/R1 family's
+attention (JoyAI-LLM-Flash is the configuration the benchmark serves).
 
-The reference serves DeepSeek-R1 through engine configs
-(recipes/deepseek-r1/sglang-wideep/tep16p-dep16d-disagg.yaml) and leaves
-MLA to the engine; here the engine is ours, so MLA is implemented
-TPU-natively. What makes MLA special for serving:
+What makes it a family of its own for serving:
 
-- The KV cache stores ONE latent vector per token — ``kv_lora_rank``
-  compressed dims plus a small decoupled-RoPE key (``qk_rope_head_dim``)
-  SHARED across heads — instead of per-head K and V. For R1
-  (128 heads, d_c=512, d_r=64) that is ~14x less KV memory than GQA at
-  the same head count, which is why wide-EP decode fits at all.
-- Decode runs in the ABSORBED form: q_nope folds through W_uk so scores
-  are taken directly against cached latents, and the attention output is
-  re-expanded through W_uv afterwards — per step the cache traffic is
-  the latent stream, never materialized per-head K/V.
+- The cache stores ONE latent row a token a layer, ``[c | k_r]``:
+  ``kv_lora_rank`` compressed dims plus a small roped key
+  (``qk_rope_head_dim``) SHARED by the heads, instead of per-head K and V:
+  576 values (1,152 B in bfloat16) where 32 heads of 192 + 128 would be
+  20,480 B.
+- DECODE runs ABSORBED: ``q_nope`` folds through ``W_uk`` so scores are
+  taken against the cached rows themselves, values are the same rows'
+  leading ``kv_lora_rank`` lanes, and the output is re-expanded through
+  ``W_uv``. A step moves the latent stream, once:
+  ``ops/attention.latent_decode_update_attention`` chooses the Mosaic
+  kernel (ops/pallas/latent_decode.py: one pool, a page fetched once and
+  used as key and as value, a sequence's own live chunks, the new row
+  written in the call) where Pallas runs and the XLA walk elsewhere.
+- PREFILL (single, packed, the speculative verify) runs NOT absorbed over
+  the walk of ``ops/attention.latent_prefill_attention``: a block of
+  cached rows is up-projected to per-head keys and values once and scored
+  by the call's queries at ``(192 + 128) x 2`` FLOP a pair where the
+  absorbed form pays ``(576 + 512) x 2``; a chunk at ``start_pos > 0``
+  reads the earlier chunks' rows from their pages. No program gathers a
+  whole table or holds a ``[.., max_ctx]`` score.
 
-Paged cache layout: ``[L, num_pages, page_size, d_c + d_r]`` — no head
-axis (the latent is shared), page-major like the GQA pool, and
-compatible with the engine's page/block bookkeeping. Rows gather by
-block table with plain XLA ops; MLA decode is far less gather-bound
-than GQA (one row per token, not KH) so the Pallas treatment is not the
-first bottleneck here.
+Paged cache: ``[L, num_pages, page_size, D]`` with ``D`` the row's 576
+values rounded up to the lane tile where the kernel runs compiled
+(``ops/attention.pool_head_dim``); no head axis, page-major like the GQA
+pool and under the same page / block bookkeeping. ``kv_dtype="fp8"`` keeps
+a ``QuantPool`` with a scale a ROW and the XLA paths (counted:
+``latent_fp8_xla``).
 
-The DeepSeek block composes MLA with the MoE FFN (models/moe.py) plus
-``n_shared_experts`` always-on dense experts; the first
-``first_k_dense`` layers use a plain dense MLP (DeepSeek's
-first_k_dense_replace). RoPE is the standard half-split form, with YaRN
-frequency correction when the spec configures it (DeepSeek-R1 ships
-factor 40 / mscale 1 — llama.yarn_freqs, HF-parity semantics).
+The block composes MLA with the expert layer (models/moe.py: sigmoid
+``noaux_tc`` routing, ``held_experts`` of an ep deployment, the layer's
+counters) plus ``n_shared_experts`` always-on experts that every chip
+computes whole; the first ``first_k_dense`` layers use a dense MLP. RoPE is
+computed half-split; a model whose published weights rotate INTERLEAVED
+pairs (``rope_interleave``) has the rope columns of ``wq_b`` / ``w_kv_a``
+permuted where the weights are made (``init_params``) or loaded
+(models/loader.py): exact, since both sides of every rope dot product get
+the same permutation. YaRN when the spec configures it.
+
+Every program takes the latent cache and, as ``counts``, the expert
+layers' device-side counters (``[L, 2, n_held + 3]`` int32, the layout of
+``llama.KindPools.counts``); ``models/family.MlaFamily`` carries the two as
+the engine's ``(k_pages, v_pages)`` pair.
 
 Parity contract: ``reference_forward`` computes the plain non-absorbed
-attention; the paged prefill/decode must match it (tests/test_mla.py).
+attention over whole sequences; the paged programs must match it
+(tests/test_mla.py), and perfbench/references/latent_moe.py, which shares
+nothing with this file, must match both (tests/test_joyai.py).
 """
 
 from __future__ import annotations
@@ -45,11 +64,15 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from dynamo_tpu.engine.config import ModelSpec
 from dynamo_tpu.models.llama import (
-    TRASH_PAGE, _logits, _replicate, rms_norm, rope_spec,
+    COUNT_DECODE, COUNT_PREFILL, TRASH_PAGE, _draw, _replicate, rms_norm,
+    rope_spec,
+)
+from dynamo_tpu.ops.attention import (
+    latent_decode_schedule, latent_decode_update_attention,
+    latent_prefill_attention, pad_heads, pool_head_dim,
 )
 from dynamo_tpu.ops.quant import (
     QuantPool,
-    gather_dequant_rows,
     init_quant_pool,
     is_quant,
     quant_append_rows,
@@ -84,61 +107,94 @@ def softmax_scale(spec: ModelSpec) -> float:
 # ---------------------------------------------------------------- init
 
 
+def _half_split(spec: ModelSpec, w: jax.Array, lead: int) -> jax.Array:
+    """Columns ``[..., lead + dr]`` of a projection whose trailing ``dr``
+    rope columns are published pair-interleaved, brought to the half-split
+    order ``rope_spec`` rotates (models/loader._deinterleave_rope_cols is
+    the same permutation at load)."""
+    if not spec.rope_interleave:
+        return w
+    dr = spec.qk_rope_head_dim
+    perm = jnp.concatenate([jnp.arange(0, dr, 2), jnp.arange(1, dr, 2)])
+    return jnp.concatenate([w[..., :lead], w[..., lead:][..., perm]], axis=-1)
+
+
 def init_params(spec: ModelSpec, key: jax.Array) -> Params:
-    """Random-init DeepSeek-family params (MLA + MoE/dense FFN)."""
+    """Random weights in a STATED order, so that a reference that shares
+    no code can draw the same (perfbench/references/latent_moe.py): the key
+    split in ``4 + 8 x layers``; embedding, head, then a layer ``wq_a``
+    (``wq`` without a query rank), ``wq_b``, ``w_kv_a``, ``w_kv_b``, ``wo``
+    and its MLP (dense: gate, up, down; experts: one key for
+    ``moe.init_moe_layer``, one split in three for the shared expert's
+    gate, up, down). ``N(0, 1 / fan_in)``, embedding ``N(0, 0.02^2)``, norm
+    gains 1, rounded to the model's dtype. Matrices are drawn in the
+    PUBLISHED layout (``w_kv_b`` one ``[dc, H x (dn + dv)]`` matrix, rope
+    columns as ``rope_interleave`` says) and then brought to the program's:
+    ``w_uk`` / ``w_uv`` a head, rope columns half-split."""
     assert spec.kv_lora_rank > 0, "not an MLA spec"
     dtype = jnp.dtype(spec.dtype)
     d = spec.hidden_size
     H = spec.num_heads
     dn, dr, dv = spec.qk_nope_head_dim, spec.qk_rope_head_dim, spec.v_head_dim
     dc = spec.kv_lora_rank
-    keys = iter(jax.random.split(key, 8 + spec.num_layers * 12))
+    keys = iter(jax.random.split(key, 4 + spec.num_layers * 8))
 
     def dense(k, shape, scale=None):
         if scale is None:
             scale = 1.0 / jnp.sqrt(shape[0])
-        return (jax.random.normal(k, shape, jnp.float32) * scale).astype(dtype)
+        return _draw(k, scale, shape=shape, dtype=dtype)
 
     params: Params = {
         "embed": dense(next(keys), (spec.vocab_size, d), scale=0.02),
         "final_norm": jnp.ones((d,), dtype),
         "layers": [],
     }
+    head_key = next(keys)
     if not spec.tie_embeddings:
-        params["lm_head"] = dense(next(keys), (d, spec.vocab_size))
+        params["lm_head"] = dense(head_key, (d, spec.vocab_size))
     for li in range(spec.num_layers):
+        k_qa, k_qb, k_kva, k_kvb, k_o, k1, k2, k3 = (
+            next(keys) for _ in range(8)
+        )
+        kv_b = dense(k_kvb, (dc, H * (dn + dv))).reshape(dc, H, dn + dv)
         layer: Params = {
             "attn_norm": jnp.ones((d,), dtype),
             "mlp_norm": jnp.ones((d,), dtype),
-            "w_kv_a": dense(next(keys), (d, dc + dr)),
+            "w_kv_a": _half_split(spec, dense(k_kva, (d, dc + dr)), dc),
             "kv_norm": jnp.ones((dc,), dtype),
-            "w_uk": dense(next(keys), (H, dc, dn), scale=1.0 / jnp.sqrt(dc)),
-            "w_uv": dense(next(keys), (H, dc, dv), scale=1.0 / jnp.sqrt(dc)),
-            "wo": dense(next(keys), (H * dv, d)),
+            "w_uk": kv_b[..., :dn].transpose(1, 0, 2),  # [H, dc, dn]
+            "w_uv": kv_b[..., dn:].transpose(1, 0, 2),  # [H, dc, dv]
+            "wo": dense(k_o, (H * dv, d)),
         }
+        q_in = spec.q_lora_rank or d
+        wq = _half_split(
+            spec,
+            dense(k_qb if spec.q_lora_rank else k_qa,
+                  (q_in, H * (dn + dr))).reshape(q_in, H, dn + dr),
+            dn,
+        ).reshape(q_in, H * (dn + dr))
         if spec.q_lora_rank:
-            layer["wq_a"] = dense(next(keys), (d, spec.q_lora_rank))
+            layer["wq_a"] = dense(k_qa, (d, spec.q_lora_rank))
             layer["q_norm"] = jnp.ones((spec.q_lora_rank,), dtype)
-            layer["wq_b"] = dense(
-                next(keys), (spec.q_lora_rank, H * (dn + dr))
-            )
+            layer["wq_b"] = wq
         else:
-            layer["wq"] = dense(next(keys), (d, H * (dn + dr)))
+            layer["wq"] = wq
         if spec.num_experts and li >= spec.first_k_dense:
             from dynamo_tpu.models import moe
 
-            layer["moe"] = moe.init_moe_layer(spec, next(keys))
+            layer["moe"] = moe.init_moe_layer(spec, k1)
             if spec.n_shared_experts:
                 f = spec.moe_intermediate_size * spec.n_shared_experts
+                kg, ku, kd = jax.random.split(k2, 3)
                 layer["shared"] = {
-                    "w_gate": dense(next(keys), (d, f)),
-                    "w_up": dense(next(keys), (d, f)),
-                    "w_down": dense(next(keys), (f, d)),
+                    "w_gate": dense(kg, (d, f)),
+                    "w_up": dense(ku, (d, f)),
+                    "w_down": dense(kd, (f, d)),
                 }
         else:
-            layer["w_gate"] = dense(next(keys), (d, spec.intermediate_size))
-            layer["w_up"] = dense(next(keys), (d, spec.intermediate_size))
-            layer["w_down"] = dense(next(keys), (spec.intermediate_size, d))
+            layer["w_gate"] = dense(k1, (d, spec.intermediate_size))
+            layer["w_up"] = dense(k2, (d, spec.intermediate_size))
+            layer["w_down"] = dense(k3, (spec.intermediate_size, d))
         params["layers"].append(layer)
     return params
 
@@ -147,19 +203,31 @@ def init_cache(
     spec: ModelSpec, num_pages: int, page_size: int, dtype=None,
     kv_dtype: str = "bf16",
 ) -> jax.Array:
-    """Latent cache [L, num_pages, page_size, d_c + d_r] (page 0 = trash).
-    ONE array — MLA has no separate K and V pools. ``kv_dtype="fp8"``
-    allocates a QuantPool (ops/quant.py) with one bf16 scale per
-    (layer, page, ROW): with no head axis the row is the natural scale
-    unit, appends never requantize their neighbors, and the finer
-    granularity keeps the absorbed-attention drift inside the tolerance
-    goldens (a single per-page scale measured ~2x the greedy-token
-    disagreement on CPU)."""
+    """Latent cache [L, num_pages, page_size, D] (page 0 = trash). ONE
+    array: MLA has no separate K and V pools. ``D`` is the row's ``d_c +
+    d_r`` values, rounded up to the lane tile where the decode kernel runs
+    compiled (``pool_head_dim``: writers pad with zeros, which add 0 to
+    every score; the value lanes are the leading ``d_c``).
+    ``kv_dtype="fp8"`` allocates a QuantPool (ops/quant.py), unpadded (the
+    kernel does not read it), with one bf16 scale per (layer, page, ROW):
+    with no head axis the row is the natural scale unit, appends never
+    requantize their neighbors, and the finer granularity keeps the
+    attention drift inside the tolerance goldens (a single per-page scale
+    measured ~2x the greedy-token disagreement on CPU)."""
     dtype = dtype or jnp.dtype(spec.dtype)
-    shape = (spec.num_layers, num_pages, page_size, latent_dim(spec))
+    lead = (spec.num_layers, num_pages, page_size)
     if kv_dtype == "fp8":
-        return init_quant_pool(shape, 3)
-    return jnp.zeros(shape, dtype)
+        return init_quant_pool(lead + (latent_dim(spec),), 3)
+    return jnp.zeros(lead + (pool_head_dim(latent_dim(spec)),), dtype)
+
+
+def init_counts(spec: ModelSpec) -> jax.Array:
+    """The expert layers' counters, zeroed: ``[L, 2, n_held + 3]`` int32
+    by layer and phase (``llama.COUNT_PREFILL`` / ``COUNT_DECODE``), as
+    ``llama.KindPools.counts``; ``[L, 2, 0]`` for a model without
+    experts."""
+    n = spec.experts_here[0] + 3 if spec.num_experts else 0
+    return jnp.zeros((spec.num_layers, 2, n), jnp.int32)
 
 
 def _set_latent_tiles(
@@ -175,14 +243,8 @@ def _set_latent_tiles(
             cache.vals.at[li, safe_pg].set(vals),
             cache.scale.at[li, safe_pg].set(s),
         )
+    tiles = pad_heads(tiles, cache.shape[-1])
     return cache.at[li, safe_pg].set(tiles.astype(cache.dtype))
-
-
-def _gather_rows_any(cache, li: int, block_table: jax.Array) -> jax.Array:
-    """[num_pages, page, D] + [P] -> [P*page, D], dequantized when fp8."""
-    if is_quant(cache):
-        return gather_dequant_rows(cache.layer(li), block_table)
-    return _gather_rows(cache[li], block_table)
 
 
 def param_shardings(spec: ModelSpec, mesh: Mesh) -> Params:
@@ -256,69 +318,143 @@ def cache_shardings(mesh: Mesh, kv_dtype: str = "bf16"):
 
 
 def _q_heads(spec: ModelSpec, lp: Params, h: jax.Array, positions) -> tuple:
-    """-> (q_nope [T, H, dn], q_rope [T, H, dr]) with RoPE applied."""
-    T = h.shape[0]
+    """h [..., d] at ``positions`` [...] -> (q_nope [..., H, dn], q_rope
+    [..., H, dr]) with RoPE applied."""
     H, dn, dr = spec.num_heads, spec.qk_nope_head_dim, spec.qk_rope_head_dim
     if spec.q_lora_rank:
         q = rms_norm(h @ lp["wq_a"], lp["q_norm"], spec.rms_eps) @ lp["wq_b"]
     else:
         q = h @ lp["wq"]
-    q = q.reshape(T, H, dn + dr)
+    q = q.reshape(*h.shape[:-1], H, dn + dr)
     q_nope, q_rope = q[..., :dn], q[..., dn:]
     return q_nope, rope_spec(spec, q_rope, positions)
 
 
 def _latent_row(spec: ModelSpec, lp: Params, h: jax.Array, positions):
-    """-> cache rows [T, d_c + d_r]: normalized latent + roped shared key."""
+    """-> cache rows [..., d_c + d_r]: normalized latent + roped shared
+    key."""
     dc = spec.kv_lora_rank
     kv_a = h @ lp["w_kv_a"]
-    c = rms_norm(kv_a[:, :dc], lp["kv_norm"], spec.rms_eps)
-    k_r = rope_spec(spec, kv_a[:, None, dc:], positions)[:, 0]
+    c = rms_norm(kv_a[..., :dc], lp["kv_norm"], spec.rms_eps)
+    k_r = rope_spec(spec, kv_a[..., None, dc:], positions)[..., 0, :]
     return jnp.concatenate([c, k_r], axis=-1)
 
 
-def _absorbed_attention(
+# A layer's two halves as jits of their own inside the programs: the
+# layers of a kind then share ONE trace and one lowered function (the
+# expert layer's three grouped-product kernels are traced once a program,
+# not once a layer), where plain Python traced each layer anew: seconds
+# of every process's set-up, warm or cold (PERF.md section 6, PR 32).
+_ATTN_IN = ("wq", "wq_a", "q_norm", "wq_b", "w_kv_a", "kv_norm")
+_MLP = ("moe", "shared", "w_gate", "w_up", "w_down")
+
+
+@partial(jax.jit, static_argnums=(0,))
+def _attn_inputs_jit(spec: ModelSpec, ap: Params, h, positions):
+    q_nope, q_rope = _q_heads(spec, ap, h, positions)
+    return q_nope, q_rope, _latent_row(spec, ap, h, positions)
+
+
+def _attn_inputs(spec: ModelSpec, lp: Params, h: jax.Array, positions):
+    """h [..., d] -> (q_nope, q_rope, the tokens' new latent rows)."""
+    return _attn_inputs_jit(
+        spec, {k: lp[k] for k in _ATTN_IN if k in lp}, h, positions
+    )
+
+
+def _dense_attention(
     spec: ModelSpec,
     lp: Params,
     q_nope: jax.Array,  # [T, H, dn]
     q_rope: jax.Array,  # [T, H, dr]
-    rows: jax.Array,  # [S, d_c + d_r] cached latents (+ self rows)
+    rows: jax.Array,  # [S, d_c + d_r] the sequence's own latent rows
     mask: jax.Array,  # [T, S] bool
 ) -> jax.Array:
-    """Latent-space attention -> per-head outputs [T, H, dv]."""
+    """Plain NON-absorbed attention over a whole sequence's rows, per-head
+    K and V materialized, float32 -> [T, H, dv]. The ground truth's
+    attention (``reference_forward``) and the cacheless embedding pass's;
+    no paged program comes through here."""
     dc = spec.kv_lora_rank
-    scale = jnp.asarray(softmax_scale(spec), jnp.float32)
-    c, k_r = rows[:, :dc], rows[:, dc:]
-    # absorb W_uk: q_lat[t,h,:] = q_nope[t,h,:] @ w_uk[h].T  -> [T, H, dc]
-    q_lat = jnp.einsum("thn,hcn->thc", q_nope.astype(jnp.float32),
-                       lp["w_uk"].astype(jnp.float32))
+    f32 = jnp.float32
+    c, k_r = rows[:, :dc].astype(f32), rows[:, dc:].astype(f32)
+    k_nope = jnp.einsum("sc,hcn->shn", c, lp["w_uk"].astype(f32))
+    v = jnp.einsum("sc,hcv->shv", c, lp["w_uv"].astype(f32))
     scores = (
-        jnp.einsum("thc,sc->ths", q_lat, c.astype(jnp.float32))
-        + jnp.einsum("thr,sr->ths", q_rope.astype(jnp.float32),
-                     k_r.astype(jnp.float32))
-    ) * scale
+        jnp.einsum("thn,shn->ths", q_nope.astype(f32), k_nope)
+        + jnp.einsum("thr,sr->ths", q_rope.astype(f32), k_r)
+    ) * jnp.asarray(softmax_scale(spec), f32)
     scores = jnp.where(mask[:, None, :], scores, NEG_INF)
-    probs = jax.nn.softmax(scores, axis=-1)
-    o_lat = jnp.einsum("ths,sc->thc", probs, c.astype(jnp.float32))
-    return jnp.einsum("thc,hcv->thv", o_lat,
-                      lp["w_uv"].astype(jnp.float32))
+    return jnp.einsum("ths,shv->thv", jax.nn.softmax(scores, axis=-1), v)
 
 
 def _ffn(
     spec: ModelSpec, li: int, lp: Params, x: jax.Array,
-    mesh: Mesh | None = None,
-) -> jax.Array:
-    if "moe" in lp:
-        from dynamo_tpu.models import moe
+    mesh: Mesh | None = None, counted: jax.Array | None = None,
+):
+    """The layer's MLP over [T, d] rows: dense, or the routed experts held
+    here plus the shared expert, which every chip of an ep deployment
+    computes whole. With ``counted`` ([T] bool: the real tokens) an expert
+    layer returns (y, its counters' row): see moe.moe_mlp."""
+    if "moe" not in lp:
+        return (jax.nn.silu(x @ lp["w_gate"]) * (x @ lp["w_up"])) @ lp["w_down"]
+    from dynamo_tpu.models import moe
 
-        out = moe.moe_mlp(spec, lp["moe"], x, mesh=mesh)
-        if "shared" in lp:
-            sh = lp["shared"]
-            out = out + (
-                jax.nn.silu(x @ sh["w_gate"]) * (x @ sh["w_up"])
-            ) @ sh["w_down"]
-        return out
-    return (jax.nn.silu(x @ lp["w_gate"]) * (x @ lp["w_up"])) @ lp["w_down"]
+    out = moe.moe_mlp(spec, lp["moe"], x, mesh=mesh, counted=counted)
+    out, row = out if counted is not None else (out, None)
+    if "shared" in lp:
+        sh = lp["shared"]
+        out = out + (
+            jax.nn.silu(x @ sh["w_gate"]) * (x @ sh["w_up"])
+        ) @ sh["w_down"]
+    return out if counted is None else (out, row)
+
+
+@partial(jax.jit, static_argnums=(0,),
+         static_argnames=("phase", "mesh", "counting"))
+def _ffn_counting_jit(spec, li, mp, x, counts, counted, *, phase, mesh,
+                      counting):
+    if not counting:
+        return _ffn(spec, li, mp, x, mesh), counts
+    y, row = _ffn(spec, li, mp, x, mesh, counted=counted)
+    step = jnp.ones((1,), jnp.int32)
+    return y, counts.at[li, phase].add(jnp.concatenate([row, step]))
+
+
+def _ffn_counting(
+    spec: ModelSpec, li: int, lp: Params, x: jax.Array, counts,
+    phase: int, counted: jax.Array, mesh: Mesh | None,
+):
+    """``_ffn`` over [T, d] rows, adding an expert layer's counters to
+    ``counts`` where the caller keeps them. Returns (y, counts)."""
+    counting = (
+        counts is not None and "moe" in lp and counts.shape[-1] > 0
+    )
+    return _ffn_counting_jit(
+        spec, li, {k: lp[k] for k in _MLP if k in lp}, x, counts, counted,
+        phase=phase, mesh=mesh, counting=counting,
+    )
+
+
+def _ctx_attention(
+    spec: ModelSpec, li: int, lp: Params, q_nope, q_rope, new_rows, cache,
+    block_table, start_pos, kv_len,
+):
+    """Attention of one sequence's new queries (at ``start_pos +
+    arange(T)``; their own rows already written to ``cache``) over its
+    paged latents: every prefill program and the verify come through
+    here. A quantized pool gets the call's exact rows laid over its
+    read-back (the XLA mirror of the decode kernel's analytic merge)."""
+    return latent_prefill_attention(
+        q_nope, q_rope, cache, li, lp["w_uk"], lp["w_uv"], block_table,
+        start_pos, kv_len, scale=softmax_scale(spec),
+        new_rows=new_rows if is_quant(cache) else None,
+    )
+
+
+def _with_counts(out: tuple, counts):
+    """A program's results, the counters appended where the caller passed
+    them."""
+    return out if counts is None else out + (counts,)
 
 
 # ------------------------------------------------------------- reference
@@ -327,31 +463,18 @@ def _ffn(
 def reference_forward(
     spec: ModelSpec, params: Params, tokens: jax.Array
 ) -> jax.Array:
-    """Plain NON-absorbed MLA forward (per-head K/V materialized) — the
-    numerical ground truth the paged/absorbed paths must match."""
+    """Plain NON-absorbed MLA forward over a whole sequence, no cache: the
+    numerical ground truth the paged programs must match. Shares
+    ``_q_heads``, ``_latent_row`` and ``_ffn`` with them."""
     T = tokens.shape[0]
     positions = jnp.arange(T)
     x = params["embed"][tokens]
-    dn = spec.qk_nope_head_dim
-    scale = jnp.asarray(softmax_scale(spec), jnp.float32)
     mask = positions[:, None] >= positions[None, :]
     for li, lp in enumerate(params["layers"]):
         h = rms_norm(x, lp["attn_norm"], spec.rms_eps)
         q_nope, q_rope = _q_heads(spec, lp, h, positions)
         rows = _latent_row(spec, lp, h, positions)
-        c, k_r = rows[:, : spec.kv_lora_rank], rows[:, spec.kv_lora_rank:]
-        k_nope = jnp.einsum("sc,hcn->shn", c.astype(jnp.float32),
-                            lp["w_uk"].astype(jnp.float32))
-        v = jnp.einsum("sc,hcv->shv", c.astype(jnp.float32),
-                       lp["w_uv"].astype(jnp.float32))
-        scores = (
-            jnp.einsum("thn,shn->ths", q_nope.astype(jnp.float32), k_nope)
-            + jnp.einsum("thr,sr->ths", q_rope.astype(jnp.float32),
-                         k_r.astype(jnp.float32))
-        ) * scale
-        scores = jnp.where(mask[:, None, :], scores, NEG_INF)
-        probs = jax.nn.softmax(scores, axis=-1)
-        attn = jnp.einsum("ths,shv->thv", probs, v)
+        attn = _dense_attention(spec, lp, q_nope, q_rope, rows, mask)
         x = x + attn.reshape(T, -1).astype(x.dtype) @ lp["wo"]
         hh = rms_norm(x, lp["mlp_norm"], spec.rms_eps)
         x = x + _ffn(spec, li, lp, hh)
@@ -367,13 +490,6 @@ def _logits_all(spec, params, x):
 # ----------------------------------------------------------------- paged
 
 
-def _gather_rows(cache_l: jax.Array, block_table: jax.Array) -> jax.Array:
-    """[num_pages, page, D] + [P] -> [P*page, D]."""
-    rows = cache_l[block_table]  # [P, page, D]
-    P, page, D = rows.shape
-    return rows.reshape(P * page, D)
-
-
 def prefill_forward_impl(
     spec: ModelSpec,
     params: Params,
@@ -383,9 +499,11 @@ def prefill_forward_impl(
     cache: jax.Array,  # [L, pages, page, D] (donated)
     num_tokens: jax.Array,  # scalar
     mesh: Mesh | None = None,  # static: replicate logits across the mesh
-) -> tuple[jax.Array, jax.Array]:
-    """One prompt; writes latent rows page-granularly; returns
-    (last_logits, cache). Mirrors llama.prefill_forward_impl."""
+    counts: jax.Array | None = None,  # the expert counters (donated)
+):
+    """One prompt, or one chunk of it at ``start_pos``; writes latent rows
+    page-granularly; returns (last_logits, cache[, counts]). Mirrors
+    llama.prefill_forward_impl."""
     T = tokens.shape[0]
     idx = jnp.arange(T)
     positions = start_pos + idx
@@ -396,41 +514,53 @@ def prefill_forward_impl(
     safe_pg = jnp.where(
         page_starts < start_pos + num_tokens, pg_idx, TRASH_PAGE
     )
-    valid_tok = (idx < num_tokens).reshape(n_pg, page_size)
+    real = idx < num_tokens
     x = params["embed"][tokens]
     kv_len = start_pos + num_tokens
-    max_ctx = block_table.shape[0] * page_size
-    ctx_pos = jnp.arange(max_ctx)
     for li, lp in enumerate(params["layers"]):
         h = rms_norm(x, lp["attn_norm"], spec.rms_eps)
-        q_nope, q_rope = _q_heads(spec, lp, h, positions)
-        new_rows = _latent_row(spec, lp, h, positions)
+        q_nope, q_rope, new_rows = _attn_inputs(spec, lp, h, positions)
         cache = _set_latent_tiles(
             cache, li, safe_pg,
-            new_rows.reshape(n_pg, page_size, -1), valid_tok,
+            new_rows.reshape(n_pg, page_size, -1),
+            real.reshape(n_pg, page_size),
         )
-        rows = _gather_rows_any(cache, li, block_table)  # [max_ctx, D]
-        if is_quant(cache):
-            # exact in-flight rows over the quantized read-back (the XLA
-            # mirror of the fused GQA kernel's analytic new-token merge)
-            rows = rows.at[positions].set(
-                new_rows.astype(rows.dtype), mode="drop"
-            )
-        mask = (ctx_pos[None, :] <= positions[:, None]) & (
-            ctx_pos[None, :] < kv_len
+        attn = _ctx_attention(
+            spec, li, lp, q_nope, q_rope, new_rows, cache, block_table,
+            start_pos, kv_len,
         )
-        attn = _absorbed_attention(spec, lp, q_nope, q_rope, rows, mask)
         x = x + attn.reshape(T, -1).astype(x.dtype) @ lp["wo"]
         hh = rms_norm(x, lp["mlp_norm"], spec.rms_eps)
-        x = x + _ffn(spec, li, lp, hh, mesh)
+        y, counts = _ffn_counting(
+            spec, li, lp, hh, counts, COUNT_PREFILL, real, mesh
+        )
+        x = x + y
     last = jnp.clip(num_tokens - 1, 0, T - 1)
-    return _replicate(_logits_all(spec, params, x)[last], mesh), cache
+    logits = _replicate(_logits_all(spec, params, x)[last], mesh)
+    return _with_counts((logits, cache), counts)
 
 
 prefill_forward = jax.jit(
     prefill_forward_impl, static_argnums=(0,),
-    static_argnames=("mesh",), donate_argnums=(5,)
+    static_argnames=("mesh",), donate_argnums=(5,),
+    donate_argnames=("counts",),
 )
+
+
+def _seq_attention(
+    spec: ModelSpec, li: int, lp: Params, q_nope, q_rope, new_rows, cache,
+    block_tables, start_pos, kv_len,
+):
+    """``_ctx_attention`` of N sequences, each over its own table (a
+    pack's walk runs to its longest member) -> [N, T, H * dv]."""
+    N, T = q_nope.shape[:2]
+    return jax.vmap(
+        lambda qn, qr, nr, bt, sp, kvl: _ctx_attention(
+            spec, li, lp, qn, qr, nr, cache, bt, sp, kvl
+        )
+    )(q_nope, q_rope, new_rows, block_tables, start_pos, kv_len).reshape(
+        N, T, -1
+    )
 
 
 def prefill_forward_batch_impl(
@@ -442,12 +572,13 @@ def prefill_forward_batch_impl(
     cache: jax.Array,  # donated
     num_tokens: jax.Array,  # [N]
     mesh: Mesh | None = None,  # static
-) -> tuple[jax.Array, jax.Array]:
-    """N prompts in ONE dispatch — MLA's packed-prefill admission path
+    counts: jax.Array | None = None,
+):
+    """N prompts in ONE dispatch: MLA's packed-prefill admission path
     (mirrors llama.prefill_forward_batch_impl: matmuls batch over
-    [N, T, d], the latent write is one page-tile scatter, absorbed
-    attention runs per prompt over its own table). Returns
-    (last_logits [N, V], cache)."""
+    [N, T, d], the latent write is one page-tile scatter, the walk runs a
+    prompt over its own table). Returns (last_logits [N, V], cache[,
+    counts])."""
     N, T = tokens.shape
     page_size = cache.shape[2]
     idx = jnp.arange(T)
@@ -461,53 +592,40 @@ def prefill_forward_batch_impl(
     )
     valid_pg = page_starts < (start_pos + num_tokens)[:, None]
     safe_pg = jnp.where(valid_pg, pg_idx_raw, TRASH_PAGE).reshape(N * n_pg)
+    real = idx[None, :] < num_tokens[:, None]  # [N, T]
 
     x = params["embed"][tokens]  # [N, T, d]
     kv_len = start_pos + num_tokens  # [N]
-    max_ctx = block_tables.shape[1] * page_size
-    ctx_pos = jnp.arange(max_ctx)
     for li, lp in enumerate(params["layers"]):
         h = rms_norm(x, lp["attn_norm"], spec.rms_eps)
-        q_nope, q_rope = jax.vmap(
-            lambda hh, pos: _q_heads(spec, lp, hh, pos)
-        )(h, positions)  # [N, T, H, dn] / [N, T, H, dr]
-        new_rows = jax.vmap(
-            lambda hh, pos: _latent_row(spec, lp, hh, pos)
-        )(h, positions)  # [N, T, D]
+        q_nope, q_rope, new_rows = _attn_inputs(spec, lp, h, positions)
         cache = _set_latent_tiles(
             cache, li, safe_pg,
             new_rows.reshape(N * n_pg, page_size, -1),
-            (idx[None, :] < num_tokens[:, None]).reshape(
-                N * n_pg, page_size
-            ),
+            real.reshape(N * n_pg, page_size),
         )
-
-        def one_attn(qn, qr, bt, pos, kvl, nr, cache=cache, li=li, lp=lp):
-            rows = _gather_rows_any(cache, li, bt)  # [max_ctx, D]
-            if is_quant(cache):
-                rows = rows.at[pos].set(nr.astype(rows.dtype), mode="drop")
-            mask = (ctx_pos[None, :] <= pos[:, None]) & (
-                ctx_pos[None, :] < kvl
-            )
-            return _absorbed_attention(spec, lp, qn, qr, rows, mask)
-
-        attn = jax.vmap(one_attn)(
-            q_nope, q_rope, block_tables, positions, kv_len, new_rows
-        )  # [N, T, H, dv]
-        x = x + attn.reshape(N, T, -1).astype(x.dtype) @ lp["wo"]
+        attn = _seq_attention(
+            spec, li, lp, q_nope, q_rope, new_rows, cache, block_tables,
+            start_pos, kv_len,
+        )
+        x = x + attn.astype(x.dtype) @ lp["wo"]
         hh = rms_norm(x, lp["mlp_norm"], spec.rms_eps)
-        x = x + _ffn(
-            spec, li, lp, hh.reshape(N * T, -1), mesh
-        ).reshape(N, T, -1)
+        y, counts = _ffn_counting(
+            spec, li, lp, hh.reshape(N * T, -1), counts, COUNT_PREFILL,
+            real.reshape(N * T), mesh,
+        )
+        x = x + y.reshape(N, T, -1)
 
     last = jnp.clip(num_tokens - 1, 0, T - 1)  # [N]
     x_last = jnp.take_along_axis(x, last[:, None, None], axis=1)[:, 0]
-    return _replicate(_logits_all(spec, params, x_last), mesh), cache
+    logits = _replicate(_logits_all(spec, params, x_last), mesh)
+    return _with_counts((logits, cache), counts)
 
 
 prefill_forward_batch = jax.jit(
     prefill_forward_batch_impl, static_argnums=(0,),
-    static_argnames=("mesh",), donate_argnums=(5,)
+    static_argnames=("mesh",), donate_argnums=(5,),
+    donate_argnames=("counts",),
 )
 
 
@@ -521,12 +639,14 @@ def verify_forward_impl(
     num_tokens: jax.Array,  # [N] valid tokens per row (0 = padded row)
     mesh: Mesh | None = None,  # static
     allowed: jax.Array | None = None,  # [N, W, V] bool: guided masks
-) -> tuple[jax.Array, jax.Array]:
+    counts: jax.Array | None = None,
+):
     """Speculative-verify forward for MLA (mirrors llama.verify_forward):
-    token-granular latent writes — a verify starts mid-page, so the
-    page-tile invariant of prefill does not hold — and the target's
-    greedy argmax at all W positions, returned as [N, W] int32 so only
-    token ids cross to the host. Returns (targets, cache)."""
+    token-granular latent writes (a verify starts mid-page, so the
+    page-tile invariant of prefill does not hold), the walk of the
+    prefill programs over each row's table, and the target's greedy
+    argmax at all W positions, returned as [N, W] int32 so only token ids
+    cross to the host. Returns (targets, cache[, counts])."""
     N, W = tokens.shape
     page_size = cache.shape[2]
     idx = jnp.arange(W)
@@ -540,46 +660,30 @@ def verify_forward_impl(
 
     x = params["embed"][tokens]  # [N, W, d]
     kv_len = start_pos + num_tokens  # [N]
-    max_ctx = block_tables.shape[1] * page_size
-    ctx_pos = jnp.arange(max_ctx)
     for li, lp in enumerate(params["layers"]):
         h = rms_norm(x, lp["attn_norm"], spec.rms_eps)
-        q_nope, q_rope = jax.vmap(
-            lambda hh, pos: _q_heads(spec, lp, hh, pos)
-        )(h, positions)  # [N, W, H, dn] / [N, W, H, dr]
-        new_rows = jax.vmap(
-            lambda hh, pos: _latent_row(spec, lp, hh, pos)
-        )(h, positions)  # [N, W, D]
+        q_nope, q_rope, new_rows = _attn_inputs(spec, lp, h, positions)
+        flat = new_rows.reshape(N * W, -1)
         if is_quant(cache):
             # per-row scales make this a plain scatter: every (page,
             # offset) slot owns its scale, so same-page siblings never
             # clash (unlike the GQA page RMW)
-            cache = quant_append_rows(
-                cache, new_rows.reshape(N * W, -1), safe_pg, offs, li
-            )
+            cache = quant_append_rows(cache, flat, safe_pg, offs, li)
         else:
             cache = cache.at[li, safe_pg, offs].set(
-                new_rows.reshape(N * W, -1).astype(cache.dtype)
+                pad_heads(flat, cache.shape[-1]).astype(cache.dtype)
             )
-
-        def one_attn(qn, qr, bt, pos, kvl, nr, cache=cache, li=li, lp=lp):
-            rows = _gather_rows_any(cache, li, bt)  # [max_ctx, D]
-            if is_quant(cache):
-                # exact verify-window rows (llama mirror)
-                rows = rows.at[pos].set(nr.astype(rows.dtype), mode="drop")
-            mask = (ctx_pos[None, :] <= pos[:, None]) & (
-                ctx_pos[None, :] < kvl
-            )
-            return _absorbed_attention(spec, lp, qn, qr, rows, mask)
-
-        attn = jax.vmap(one_attn)(
-            q_nope, q_rope, block_tables, positions, kv_len, new_rows
-        )  # [N, W, H, dv]
-        x = x + attn.reshape(N, W, -1).astype(x.dtype) @ lp["wo"]
+        attn = _seq_attention(
+            spec, li, lp, q_nope, q_rope, new_rows, cache, block_tables,
+            start_pos, kv_len,
+        )
+        x = x + attn.astype(x.dtype) @ lp["wo"]
         hh = rms_norm(x, lp["mlp_norm"], spec.rms_eps)
-        x = x + _ffn(
-            spec, li, lp, hh.reshape(N * W, -1), mesh
-        ).reshape(N, W, -1)
+        y, counts = _ffn_counting(
+            spec, li, lp, hh.reshape(N * W, -1), counts, COUNT_PREFILL,
+            valid.reshape(N * W), mesh,
+        )
+        x = x + y.reshape(N, W, -1)
 
     logits = _logits_all(spec, params, x)  # [N, W, V]
     if allowed is not None:
@@ -587,12 +691,13 @@ def verify_forward_impl(
         # on-grammar even when every draft is rejected (llama mirror)
         logits = jnp.where(allowed, logits, NEG_INF)
     targets = jnp.argmax(logits, axis=-1).astype(jnp.int32)
-    return _replicate(targets, mesh), cache
+    return _with_counts((_replicate(targets, mesh), cache), counts)
 
 
 verify_forward = jax.jit(
     verify_forward_impl, static_argnums=(0,),
-    static_argnames=("mesh",), donate_argnums=(5,)
+    static_argnames=("mesh",), donate_argnums=(5,),
+    donate_argnames=("counts",),
 )
 
 
@@ -605,8 +710,10 @@ def decode_forward_impl(
     cache: jax.Array,  # donated
     active: jax.Array,  # [B] bool
     mesh: Mesh | None = None,  # static
-) -> tuple[jax.Array, jax.Array]:
-    """One decode step (absorbed latent attention); returns (logits, cache)."""
+    counts: jax.Array | None = None,
+):
+    """One decode step (absorbed latent attention over the sequences' live
+    pages); returns (logits, cache[, counts])."""
     B = tokens.shape[0]
     page_size = cache.shape[2]
     positions = seq_lens - 1
@@ -615,46 +722,41 @@ def decode_forward_impl(
     )[:, 0]
     safe_page = jnp.where(active, page_idx, TRASH_PAGE)
     offset = positions % page_size
-    max_ctx = block_tables.shape[1] * page_size
-    ctx_pos = jnp.arange(max_ctx)
+    scale = softmax_scale(spec)
+    # the kernel's schedule follows the lengths alone: once a step
+    schedule = latent_decode_schedule(cache, block_tables, seq_lens, mesh)
     x = params["embed"][tokens]
     for li, lp in enumerate(params["layers"]):
         h = rms_norm(x, lp["attn_norm"], spec.rms_eps)
-        q_nope, q_rope = _q_heads(spec, lp, h, positions)
-        new_rows = _latent_row(spec, lp, h, positions)  # [B, D]
-        if is_quant(cache):
-            cache = quant_append_rows(
-                cache, new_rows, safe_page, offset, li
-            )
-        else:
-            cache = cache.at[li, safe_page, offset].set(
-                new_rows.astype(cache.dtype)
-            )
-        rows = jax.vmap(
-            lambda bt, cache=cache, li=li: _gather_rows_any(cache, li, bt)
-        )(block_tables)  # [B, max_ctx, D]
-        if is_quant(cache):
-            # exact new-token overlay: the decode query's own latent row
-            # (its strongest attention target) never pays fp8 error
-            max_ctx_i = rows.shape[1]
-            rows = rows.at[
-                jnp.arange(B), jnp.clip(positions, 0, max_ctx_i - 1)
-            ].set(new_rows.astype(rows.dtype))
-        mask = ctx_pos[None, :] < seq_lens[:, None]  # [B, max_ctx]
-        attn = jax.vmap(
-            lambda qn, qr, r, m: _absorbed_attention(
-                spec, lp, qn[None], qr[None], r, m[None]
-            )[0]
-        )(q_nope, q_rope, rows, mask)
+        q_nope, q_rope, new_rows = _attn_inputs(spec, lp, h, positions)
+        # absorb W_uk: q_lat[b, h] = q_nope[b, h] W_uk[h]^T
+        q_lat = jnp.einsum(
+            "bhn,hcn->bhc", q_nope, lp["w_uk"],
+            preferred_element_type=jnp.float32,
+        )
+        o_lat, cache = latent_decode_update_attention(
+            q_lat, q_rope, cache, new_rows, block_tables, seq_lens,
+            safe_page, offset, layer=li, scale=scale, mesh=mesh,
+            schedule=schedule,
+        )
+        attn = jnp.einsum(
+            "bhc,hcv->bhv", o_lat.astype(lp["w_uv"].dtype), lp["w_uv"],
+            preferred_element_type=jnp.float32,
+        )
         x = x + attn.reshape(B, -1).astype(x.dtype) @ lp["wo"]
         hh = rms_norm(x, lp["mlp_norm"], spec.rms_eps)
-        x = x + _ffn(spec, li, lp, hh, mesh)
-    return _replicate(_logits_all(spec, params, x), mesh), cache
+        y, counts = _ffn_counting(
+            spec, li, lp, hh, counts, COUNT_DECODE, active, mesh
+        )
+        x = x + y
+    logits = _replicate(_logits_all(spec, params, x), mesh)
+    return _with_counts((logits, cache), counts)
 
 
 decode_forward = jax.jit(
     decode_forward_impl, static_argnums=(0,),
-    static_argnames=("mesh",), donate_argnums=(5,)
+    static_argnames=("mesh",), donate_argnums=(5,),
+    donate_argnames=("counts",),
 )
 
 
@@ -675,10 +777,12 @@ def decode_steps_impl(
     n_logprobs: int = 0,  # static: 0=off, N=sampled+top-N logprobs
     mesh: Mesh | None = None,  # static
     allowed: jax.Array | None = None,  # [B, V] bool: guided token masks
+    counts: jax.Array | None = None,
 ):
     """Fused multi-step MLA decode + on-device sampling (the serving hot
     loop; mirrors llama.decode_steps for the GQA family, including the
-    logprob surface)."""
+    logprob surface). Returns (out[, logprobs, top ids, top values],
+    cache[, counts])."""
     from dynamo_tpu.engine.sampling import sample_tokens, token_logprobs
 
     B = tokens.shape[0]
@@ -688,11 +792,12 @@ def decode_steps_impl(
     tv0 = jnp.zeros((B, n_steps, max(n_logprobs, 1)), jnp.float32)
 
     def body(i, carry):
-        toks, lens, cache, out, lp, ti, tv = carry
-        logits, cache = decode_forward_impl(
+        toks, lens, cache, counts, out, lp, ti, tv = carry
+        logits, cache, *rest = decode_forward_impl(
             spec, params, toks, block_tables, lens, cache, active,
-            mesh=mesh,
+            mesh=mesh, counts=counts,
         )
+        counts = rest[0] if rest else None
         if allowed is not None:
             logits = jnp.where(allowed, logits, NEG_INF)
         nxt = sample_tokens(logits, temperature, top_k, top_p, seeds,
@@ -704,17 +809,19 @@ def decode_steps_impl(
             lp = lp.at[:, i].set(picked)
             ti = ti.at[:, i].set(top_i)
             tv = tv.at[:, i].set(top_v)
-        return (nxt, lens + active.astype(jnp.int32), cache, out, lp, ti, tv)
+        return (nxt, lens + active.astype(jnp.int32), cache, counts, out,
+                lp, ti, tv)
 
-    _t, _l, cache, out, lp, ti, tv = jax.lax.fori_loop(
+    _t, _l, cache, counts, out, lp, ti, tv = jax.lax.fori_loop(
         0, n_steps, body,
-        (tokens, seq_lens, cache, out0, lp0, ti0, tv0),
+        (tokens, seq_lens, cache, counts, out0, lp0, ti0, tv0),
     )
     out = _replicate(out, mesh)
     if n_logprobs > 0:
-        return (out, _replicate(lp, mesh), _replicate(ti, mesh),
-                _replicate(tv, mesh), cache)
-    return out, cache
+        return _with_counts(
+            (out, _replicate(lp, mesh), _replicate(ti, mesh),
+             _replicate(tv, mesh), cache), counts)
+    return _with_counts((out, cache), counts)
 
 
 decode_steps = jax.jit(
@@ -723,7 +830,7 @@ decode_steps = jax.jit(
     # donate the latent cache: without this every MLA decode burst
     # COPIED the whole cache for its in-place page writes (the donation
     # audit in tests/test_donation.py caught exactly this)
-    donate_argnums=(5,),
+    donate_argnums=(5,), donate_argnames=("counts",),
 )
 
 
@@ -749,7 +856,7 @@ def embed_forward_impl(
         h = rms_norm(x, lp["attn_norm"], spec.rms_eps)
         q_nope, q_rope = _q_heads(spec, lp, h, positions)
         rows = _latent_row(spec, lp, h, positions)
-        attn = _absorbed_attention(spec, lp, q_nope, q_rope, rows, mask2d)
+        attn = _dense_attention(spec, lp, q_nope, q_rope, rows, mask2d)
         x = x + attn.reshape(T, -1).astype(x.dtype) @ lp["wo"]
         hh = rms_norm(x, lp["mlp_norm"], spec.rms_eps)
         x = x + _ffn(spec, li, lp, hh)

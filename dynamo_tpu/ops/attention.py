@@ -583,3 +583,262 @@ def window_table(
     # lie past the sequence's length and are masked
     ids = jnp.minimum(first + jnp.arange(n), P_ - 1)
     return block_table[ids], first * page_size
+
+
+# ------------------------------------------------------- latent attention
+# Latent attention (MLA, models/mla.py) keeps ONE pool ``[L, num_pages,
+# page, D]``: a row a token, ``[c | k_r | lane padding]``, shared by the
+# heads. Decode is absorbed (scores and values against the rows
+# themselves); prefill is not (a block of rows is up-projected to per-head
+# keys and values once and scored by the whole call's queries).
+
+SCOPE_ATTN_LATENT = "attn_latent"  # names the decode kernel in a trace
+
+
+def latent_rows(pool, layer, ids: jax.Array) -> jax.Array:
+    """The rows on pages ``ids`` [..., n] of layer ``layer`` of a latent
+    pool, ``[..., n * page, D]``: in the pool's dtype, or float32 for a
+    ``QuantPool`` (one scale a row). One indexing step out of the pool, as
+    ``gather_pages``."""
+    from dynamo_tpu.ops.quant import is_quant
+
+    if is_quant(pool):
+        rows = pool.vals[layer, ids].astype(jnp.float32) * pool.scale[
+            layer, ids
+        ].astype(jnp.float32)[..., None]
+    else:
+        rows = pool[layer, ids]
+    return rows.reshape(*ids.shape[:-1], -1, rows.shape[-1])
+
+
+def _flash_merge(carry, s, v, valid):
+    """One block folded into a running softmax. carry ``(m, l, acc)``
+    float32 with ``acc [..., q, dv]``; s ``[..., q, n]`` float32 scores,
+    masked here by ``valid`` (broadcastable to s); v ``[..., n, dv]``."""
+    m, l, acc = carry
+    s = jnp.where(valid, s, NEG_INF)
+    m_new = jnp.maximum(m, s.max(axis=-1))
+    alpha = jnp.exp(m - m_new)
+    # a row that has met no key yet has m_new == NEG_INF and exp(0) here
+    p = jnp.where(valid, jnp.exp(s - m_new[..., None]), 0.0)
+    pv = jnp.einsum(
+        "...qn,...nd->...qd", p.astype(v.dtype), v,
+        preferred_element_type=jnp.float32,
+    )
+    return m_new, alpha * l + p.sum(axis=-1), alpha[..., None] * acc + pv
+
+
+def paged_latent_decode_attention(
+    q: jax.Array,  # [B, H, D]: [q_lat | q_rope | 0], pre-scaled
+    pool,  # [L, num_pages, page, D], holding positions < seq_len - 1
+    layer,
+    new_rows: jax.Array,  # [B, D] the step's own rows, exact
+    block_tables: jax.Array,  # [B, P]
+    seq_lens: jax.Array,  # [B] INCLUDING the new token
+    *,
+    dc: int,
+) -> jax.Array:
+    """The XLA reference of ``ops/pallas/latent_decode.py``: absorbed
+    decode attention over a latent pool, float32, walked in blocks of
+    pages up to the batch's LONGEST live context (a run-time count), the
+    new token merged analytically as the kernel does. Returns ``o_lat
+    [B, H, dc]`` float32."""
+    B, H, D = q.shape
+    page, P = pool.shape[2], block_tables.shape[1]
+    bp = min(P, max(1, _BLOCK_TOKENS // page))
+    span = bp * page
+    end = seq_lens - 1
+    qf = q.astype(jnp.float32)
+
+    def block(j, carry):
+        ids = block_tables[:, jnp.minimum(j * bp + jnp.arange(bp), P - 1)]
+        rows = latent_rows(pool, layer, ids).astype(jnp.float32)
+        valid = (j * span + jnp.arange(span))[None, :] < end[:, None]
+        rows = jnp.where(valid[..., None], rows, 0.0)
+        s = jnp.einsum("bhd,bsd->bhs", qf, rows)
+        return _flash_merge(carry, s, rows[..., :dc], valid[:, None, :])
+
+    carry = jax.lax.fori_loop(
+        0, (jnp.max(end) + span - 1) // span, block,
+        (
+            jnp.full((B, H), NEG_INF, jnp.float32),
+            jnp.zeros((B, H), jnp.float32),
+            jnp.zeros((B, H, dc), jnp.float32),
+        ),
+    )
+    new = new_rows.astype(jnp.float32)[:, None, :]  # [B, 1, D]
+    s_new = jnp.einsum("bhd,bsd->bhs", qf, new)
+    _, l, acc = _flash_merge(carry, s_new, new[..., :dc], True)
+    return acc / l[..., None]
+
+
+def _latent_kernel_serves(pool, mesh) -> bool:
+    """Whether the Mosaic latent kernel serves this pool: Pallas active, a
+    plain array, one shard of heads."""
+    from dynamo_tpu.ops.quant import is_quant
+
+    tp = mesh is not None and mesh.shape.get("tp", 1) > 1
+    return use_pallas() and not is_quant(pool) and not tp
+
+
+def latent_decode_schedule(pool, block_tables, seq_lens, mesh=None):
+    """What a decode STEP can make once for all its layers' calls of
+    ``latent_decode_update_attention``: the kernel's schedule (each
+    sequence's live chunks, buffers, successors: a dozen small operations
+    that depend on the lengths alone), or None where the XLA walk
+    serves."""
+    if not _latent_kernel_serves(pool, mesh):
+        return None
+    from dynamo_tpu.ops.pallas.latent_decode import latent_schedule
+
+    return latent_schedule(pool, block_tables, seq_lens)
+
+
+def latent_decode_update_attention(
+    q_lat: jax.Array,  # [B, H, dc]: q_nope through W_uk
+    q_rope: jax.Array,  # [B, H, dr]
+    pool,  # [L, num_pages, page, D >= dc + dr] (or a QuantPool)
+    new_rows: jax.Array,  # [B, dc + dr] the step's rows
+    block_tables: jax.Array,
+    seq_lens: jax.Array,  # [B] INCLUDING the new token
+    dst_page: jax.Array,  # [B] (0 = trash)
+    dst_off: jax.Array,
+    *,
+    layer,
+    scale: float,
+    mesh=None,
+    schedule: tuple | None = None,  # ``latent_decode_schedule``'s
+):
+    """A latent layer's decode step, append + absorbed attention, and the
+    place its implementation is chosen (``decode_update_attention``'s
+    twin): the Mosaic kernel (ops/pallas/latent_decode.py) wherever Pallas
+    is active and the pool is a plain array on one shard of heads; else
+    the XLA walk (``paged_latent_decode_attention``), counted: an fp8 pool
+    as ``latent_fp8_xla`` and a tp mesh as ``latent_tp_xla`` (the kernel
+    has neither a dequantising chunk nor a ``shard_map`` yet; no cell
+    reads either), the CPU and ``DYNAMO_PALLAS=0`` as
+    ``no_pallas_backend``. Returns ``(o_lat [B, H, dc], pool)``."""
+    from dynamo_tpu.ops.fallback import note_fallback
+    from dynamo_tpu.ops.quant import is_quant, quant_append_rows
+
+    dc, D = q_lat.shape[-1], pool.shape[-1]
+    quantized = is_quant(pool)
+    q = jnp.concatenate(
+        [q_lat.astype(jnp.float32), q_rope.astype(jnp.float32)], axis=-1
+    ) * scale
+    q, new_rows = pad_heads(q, D), pad_heads(new_rows, D)
+    if _latent_kernel_serves(pool, mesh):
+        from dynamo_tpu.ops.pallas.latent_decode import latent_decode_attention
+
+        out, pool = latent_decode_attention(
+            q.astype(pool.dtype), pool, new_rows, block_tables, seq_lens,
+            dst_page, dst_off, layer=layer, dc=dc,
+            interpret=jax.default_backend() != "tpu",
+            scope=SCOPE_ATTN_LATENT, schedule=schedule,
+        )
+        return out, pool
+    if not use_pallas():
+        note_fallback("no_pallas_backend", expected=True,
+                      detail="latent_decode_update_attention: XLA walk")
+    elif quantized:
+        note_fallback("latent_fp8_xla",
+                      detail="latent decode: the kernel reads bf16 pools")
+    else:
+        note_fallback("latent_tp_xla",
+                      detail="latent decode: no shard_map over heads yet")
+    out = paged_latent_decode_attention(
+        q, pool, layer, new_rows, block_tables, seq_lens, dc=dc
+    )
+    if quantized:
+        pool = quant_append_rows(pool, new_rows, dst_page, dst_off, layer)
+    else:
+        pool = pool.at[layer, dst_page, dst_off].set(
+            new_rows.astype(pool.dtype)
+        )
+    return out.astype(q_lat.dtype), pool
+
+
+def latent_prefill_tiling(
+    n_queries: int, pages_per_seq: int, page_size: int
+) -> tuple[int, int]:
+    """``(tq, bp)`` of the latent prefill walk: ONE tile of all the call's
+    rows (a block's up-projection is paid once a block, so every query
+    scores it while it is there) against blocks of ``prefill_tiling``'s
+    size. With ``prefill_blocks`` it is the walk's trip count and the
+    engine's ``prefill_kv.*.latent`` counters alike."""
+    return n_queries, prefill_tiling(n_queries, pages_per_seq, page_size)[1]
+
+
+@functools.partial(jax.jit, static_argnames=("scale",))
+def latent_prefill_attention(
+    q_nope: jax.Array,  # [T, H, dn]: queries at start_pos + arange(T)
+    q_rope: jax.Array,  # [T, H, dr]
+    pool,  # [L, num_pages, page, D] (the call's rows already written)
+    layer,  # scalar, not static: the layers share one trace
+    w_uk: jax.Array,  # [H, dc, dn]
+    w_uv: jax.Array,  # [H, dc, dv]
+    block_table: jax.Array,  # [P]
+    start_pos: jax.Array,
+    kv_len: jax.Array,  # start_pos + the real rows
+    *,
+    scale: float,
+    new_rows: jax.Array | None = None,  # [T, dc + dr] exact (quant pools)
+) -> jax.Array:
+    """Causal attention of a call's queries over the sequence's PAGED
+    latents, NOT absorbed: the walk of ``paged_prefill_attention`` with
+    the loop over blocks outermost. A block of ``bp`` pages is gathered
+    (``latent_rows``), up-projected ONCE to per-head keys and values
+    (``c W_uk``, ``c W_uv``; the roped key is shared by the heads) and
+    scored by all ``T`` query rows at ``(dn + dr + dv) x 2`` FLOP a pair
+    where the absorbed form pays ``(2 dc + dr) x 2``; blocks run from the
+    table's first page to the last real row's (``prefill_blocks`` with one
+    tile: a run-time count, under ``vmap`` a pack's longest member), so a
+    chunk at ``start_pos > 0`` reads the earlier chunks' latents from
+    their pages and nothing follows the table's width. Operands in the
+    model's dtype, float32 accumulation, scores and softmax. Returns
+    ``[T, H, dv]``."""
+    T, H, _ = q_nope.shape
+    dc, dv = w_uk.shape[1], w_uv.shape[2]
+    dr = q_rope.shape[-1]
+    page, P = pool.shape[2], block_table.shape[0]
+    tq, bp = latent_prefill_tiling(T, P, page)
+    span = bp * page
+    q_pos = start_pos + jnp.arange(T)
+    _, count = prefill_blocks(start_pos, kv_len - start_pos, 0, tq, 0, page, bp)
+    dt = q_nope.dtype
+
+    def block(j, carry):
+        ids = block_table[jnp.minimum(j * bp + jnp.arange(bp), P - 1)]
+        rows = latent_rows(pool, layer, ids)
+        kv_pos = j * span + jnp.arange(span)
+        if new_rows is not None:
+            at = q_pos - j * span
+            at = jnp.where(at < 0, span, at)  # before the block
+            rows = rows.at[at, : dc + dr].set(
+                new_rows.astype(rows.dtype), mode="drop")
+        rows = jnp.where((kv_pos < kv_len)[:, None], rows, 0).astype(dt)
+        c, k_r = rows[:, :dc], rows[:, dc: dc + dr]
+        k_n = jnp.einsum("sc,hcn->hsn", c, w_uk,
+                         preferred_element_type=jnp.float32).astype(dt)
+        v = jnp.einsum("sc,hcv->hsv", c, w_uv,
+                       preferred_element_type=jnp.float32).astype(dt)
+        s = (
+            jnp.einsum("thn,hsn->hts", q_nope, k_n,
+                       preferred_element_type=jnp.float32)
+            + jnp.einsum("thr,sr->hts", q_rope, k_r,
+                         preferred_element_type=jnp.float32)
+        ) * scale
+        valid = (kv_pos[None, :] <= q_pos[:, None]) & (kv_pos < kv_len)
+        return _flash_merge(carry, s, v, valid)
+
+    _, l, acc = jax.lax.fori_loop(
+        0, count, block,
+        (
+            jnp.full((H, T), NEG_INF, jnp.float32),
+            jnp.zeros((H, T), jnp.float32),
+            jnp.zeros((H, T, dv), jnp.float32),
+        ),
+    )
+    # a padded member of a pack visits nothing: l is 0 there
+    out = acc / jnp.where(l == 0.0, 1.0, l)[..., None]
+    return out.transpose(1, 0, 2).astype(dt)

@@ -151,3 +151,31 @@ def test_kv_write_compiles_for_v5e(v5e, dk):
         _rows(v5e, B, dtype=jnp.int32), _rows(v5e, B, dtype=jnp.int32),
         layer=1,
     ).compile()
+
+
+@pytest.mark.parametrize(
+    "pages_per_seq,dtype",
+    [
+        # JoyAI-LLM-Flash's cell: 128 slots, 32 heads against rows of 576
+        # values laid out as 640 lanes, 10,240-token tables in 40 chunks
+        pytest.param(640, jnp.bfloat16, id="joyai-640"),
+        # a table that its chunks do not divide, and a float32 pool
+        pytest.param(100, jnp.bfloat16, id="bf16-100"),
+        pytest.param(64, jnp.float32, id="f32-64"),
+    ],
+)
+def test_latent_decode_compiles_for_v5e(v5e, pages_per_seq, dtype):
+    from dynamo_tpu.ops.pallas.latent_decode import latent_decode_attention
+
+    slots, heads, lanes, dc = 128, 32, 640, 512
+    pool = jax.ShapeDtypeStruct((L, 1025, 16, lanes), dtype, sharding=v5e)
+    i32 = jnp.int32
+    compiled = latent_decode_attention.lower(
+        _rows(v5e, slots, heads, lanes, dtype=dtype), pool,
+        _rows(v5e, slots, lanes, dtype=dtype),
+        _rows(v5e, slots, pages_per_seq, dtype=i32),
+        _rows(v5e, slots, dtype=i32), _rows(v5e, slots, dtype=i32),
+        _rows(v5e, slots, dtype=i32), layer=1, dc=dc, scope="attn_latent",
+    ).compile()
+    # the kernel is named after the scope: the trace's readers match it
+    assert "%attn_latent" in compiled.as_text()

@@ -342,6 +342,12 @@ AUDIT: dict = {
         "decode_forward": "donates",
         "decode_steps": "donates",
         "embed_forward": "read-only",
+        # jits inside the programs above (one trace for the layers of a
+        # kind): their buffers are the outer program's, which donates
+        "_attn_inputs_jit": "read-only",
+        "_ffn_counting_jit": "read-only",
+        "latent_prefill_attention": "read-only",
+        "_draw": "read-only",
     },
     family: {
         "_extract_latent": "read-only",

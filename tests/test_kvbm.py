@@ -270,7 +270,11 @@ async def test_fp8_engine_offload_onboard_and_corrupt_scale_miss():
         SPEC, small_config(kv_dtype="fp8"), kvbm=kvbm
     )
     assert engine.kv_dtype == "fp8"
-    prompt = list(range(30, 30 + 13))
+    # a prompt whose greedy stream does not hang on a near-tie: the second
+    # run reads its prefix back in fp8 where the first scored exact rows,
+    # and on two prompts of four a tie of the tiny model's logits then
+    # falls the other way (a bf16 pool gives equal streams on all four)
+    prompt = list(range(40, 40 + 13))
     want = await run(engine, prompt)
     engine.offload.flush()
     assert kvbm.stats.offloaded >= 3
@@ -321,7 +325,11 @@ async def test_fp8_mla_engine_onboard_not_rejected():
     engine = InferenceEngine(
         ModelSpec.tiny_deepseek(), small_config(kv_dtype="fp8"), kvbm=kvbm
     )
-    prompt = list(range(30, 30 + 13))
+    # a prompt whose greedy stream does not hang on a near-tie: the second
+    # run reads its prefix back in fp8 where the first scored exact rows,
+    # and on two prompts of four a tie of the tiny model's logits then
+    # falls the other way (a bf16 pool gives equal streams on all four)
+    prompt = list(range(40, 40 + 13))
     want = await run(engine, prompt)
     engine.offload.flush()
     assert kvbm.stats.offloaded >= 3

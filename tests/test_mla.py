@@ -4,6 +4,7 @@ dense non-absorbed reference (models/mla.py)."""
 import asyncio
 
 import numpy as np
+import pytest
 
 import jax
 import jax.numpy as jnp
@@ -261,10 +262,15 @@ async def test_deepseek_serves_through_engine_on_mesh():
     assert len(got3) == 6
 
 
-def test_deepseek_checkpoint_loads(tmp_path):
+@pytest.mark.parametrize("mtp", [False, True], ids=["plain", "mtp-layer"])
+def test_deepseek_checkpoint_loads(tmp_path, mtp):
     """DeepSeek-named safetensors (q-LoRA, kv_a_proj_with_mqa, fused
     kv_b_proj, routed+shared experts, first-k-dense) -> mla params with
-    forward parity vs the source tree."""
+    forward parity vs the source tree. ``mtp``: the checkpoint carries a
+    multi-token-prediction layer behind its decoder layers
+    (``num_nextn_predict_layers``), which the loader drops as the
+    published inference code does; its fused kv_b_proj used to be split
+    into a layer that does not exist."""
     import json as _json
     import os
 
@@ -331,10 +337,15 @@ def test_deepseek_checkpoint_loads(tmp_path):
                              ("down_proj", "w_down")):
                 t[p + f"mlp.{hf}.weight"] = np.ascontiguousarray(
                     np.asarray(lp[ours]).T)
+    if mtp:
+        behind = f"model.layers.{SPEC.num_layers}."
+        for name in [n for n in t if n.startswith("model.layers.0.")]:
+            t[name.replace("model.layers.0.", behind)] = t[name]
     save_file(t, os.path.join(str(tmp_path), "model.safetensors"))
     with open(os.path.join(str(tmp_path), "config.json"), "w") as f:
         _json.dump({
             "model_type": "deepseek_v3",
+            "num_nextn_predict_layers": int(mtp),
             "vocab_size": SPEC.vocab_size, "hidden_size": SPEC.hidden_size,
             "intermediate_size": SPEC.intermediate_size,
             "moe_intermediate_size": SPEC.moe_intermediate_size,
@@ -363,6 +374,8 @@ def test_deepseek_checkpoint_loads(tmp_path):
         }, f)
     spec2, params2 = load_model_dir(str(tmp_path), dtype="float32")
     assert spec2.is_mla and spec2.kv_lora_rank == SPEC.kv_lora_rank
+    assert spec2.nextn_predict_layers == int(mtp)
+    assert len(params2["layers"]) == SPEC.num_layers
     tokens = jnp.asarray(np.arange(9) % SPEC.vocab_size, jnp.int32)
     want = mla.reference_forward(SPEC, params, tokens)
     got = mla.reference_forward(spec2, params2, tokens)
@@ -511,14 +524,25 @@ async def test_deepseek_serves_through_engine():
     got = await run(prompt)  # warm prefix: latent pages reused
     assert got == want
 
-    # paged-engine output == the dense reference greedy chain
+    # paged-engine output == the dense reference greedy chain. ONE
+    # compiled reference at one padded length (attention is causal: what
+    # follows a position cannot reach it). Run eagerly at six growing
+    # lengths, as this test did, every primitive compiled anew for every
+    # length, 50 of the 60 seconds an async test is given when it ran
+    # alone and more than that beside five busy workers: that, not
+    # anything it shared with its neighbours, made it unsteady
     params = engine.params
     seq = list(prompt)
     for _ in range(6):
-        lg = mla.reference_forward(SPEC, params, jnp.asarray(seq, jnp.int32))
-        seq.append(int(np.argmax(np.asarray(lg[-1]))))
+        padded = np.zeros((24,), np.int32)
+        padded[: len(seq)] = seq
+        lg = _reference_jit(SPEC, params, jnp.asarray(padded))
+        seq.append(int(np.argmax(np.asarray(lg[len(seq) - 1]))))
     assert want == seq[len(prompt):]
     await engine.close()
+
+
+_reference_jit = jax.jit(mla.reference_forward, static_argnums=0)
 
 
 async def test_deepseek_serves_through_frontend():
