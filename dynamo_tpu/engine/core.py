@@ -172,6 +172,11 @@ READMIT_SUMS = {
     "first_token": "readmit.first_token",
 }
 
+# the engine's always-on host counters, each a dict of ints under the
+# attribute of its name: profile_snapshot() carries them as
+# ``<family>.<name>`` and reset_profile_window() zeroes them
+_COUNTER_FAMILIES = ("decode_kv", "prefill_kv", "chunked_prefill")
+
 # what _phase and _launch hand out with profiling off: one shared object
 # whose enter and exit do nothing
 _NO_SPAN = contextlib.nullcontext()
@@ -422,6 +427,9 @@ class InferenceEngine:
                 cache_entries=self.config.guided_cache_entries,
             )
         self._partial: _PartialPrefill | None = None
+        # this step-loop cycle ran a chunk of a partial that was open when
+        # it began (_step): such a cycle admits nothing else
+        self._chunk_cycle = False
         self._clear_cache_requested = False
         # dispatched-but-unprocessed decode bursts, oldest first: one
         # between cycles when pipeline_decode, two from a dispatch until
@@ -479,6 +487,11 @@ class InferenceEngine:
             f"blocks_{what}.{kind}": 0
             for kind in self._prefill_walks for what in ("visited", "table")
         }
+        # how a chunked prefill's chunks met the decode pipeline (always
+        # on: two int adds a chunk): every chunk launched, the first
+        # included, and those launched with a burst in flight, i.e. queued
+        # behind device work and not after a drained device
+        self.chunked_prefill = {"chunks": 0, "chunks_behind_burst": 0}
         # worker telemetry feeds (engine/telemetry.py EngineCollector):
         # the step thread only appends to bounded deques / bumps ints;
         # the collector turns them into /metrics histograms+counters
@@ -573,6 +586,12 @@ class InferenceEngine:
         - ``prefill_kv.blocks_visited.<kind>`` / ``.blocks_table.<kind>``
           (calls; kind ``full`` or ``window``, or ``latent`` for the
           latent family's walk): see ``_count_prefill_kv``.
+        - ``chunked_prefill.chunks`` / ``.chunks_behind_burst`` (calls):
+          the chunks of chunked prefills launched, and those of them
+          launched with a decode burst in flight (``ahead`` >= 1 on their
+          ``engine.launch``). Their ratio is how often a long prompt's
+          next chunk rode the decode pipeline instead of finding the
+          device drained; near 1 on a saturated engine.
         """
         snap = {
             k: {"secs": round(v[0], 4), "calls": int(v[1])}
@@ -593,10 +612,9 @@ class InferenceEngine:
         # refresh (moe_counters): counts, so ``calls``
         for name, n in self.moe_counters().items():
             snap[f"moe.{name}"] = {"secs": 0.0, "calls": n}
-        for name, n in self.decode_kv.items():
-            snap[f"decode_kv.{name}"] = {"secs": 0.0, "calls": n}
-        for name, n in self.prefill_kv.items():
-            snap[f"prefill_kv.{name}"] = {"secs": 0.0, "calls": n}
+        for family in _COUNTER_FAMILIES:
+            for name, n in getattr(self, family).items():
+                snap[f"{family}.{name}"] = {"secs": 0.0, "calls": n}
         return snap
 
     def reset_profile_window(self) -> None:
@@ -606,8 +624,8 @@ class InferenceEngine:
         self._prof.clear()
         self._prof_requests.clear()
         self.dispatches = 0
-        self.decode_kv = dict.fromkeys(self.decode_kv, 0)
-        self.prefill_kv = dict.fromkeys(self.prefill_kv, 0)
+        for family in _COUNTER_FAMILIES:
+            setattr(self, family, dict.fromkeys(getattr(self, family), 0))
         self._compile_base = compile_snapshot()
 
     def _full_table_chunk_pages(self) -> int | None:
@@ -1692,16 +1710,30 @@ class InferenceEngine:
             # and _build_batch/_process_burst guard by active mask +
             # request id — so admitting without a flush keeps the decode
             # pipeline deep instead of paying a host sync per admission
-            # wave. Chunked-prefill advance keeps the flush (its slot
-            # bookkeeping interleaves with the partial's reserved slot).
+            # wave. An open chunked prefill does not either: its next
+            # chunk is launched behind the running burst like any other
+            # admission's prefill, and the same four facts cover it.
+            # (1) Nothing can take its slot: _admit_phase and
+            # _eager_readmit admit nothing while _partial is set, so the
+            # index stays None, and a slot that a finishing stream frees
+            # meanwhile stays free until the partial closes. (2) Its
+            # pages are referenced from _acquire_prompt_pages until a
+            # slot or a release takes them over, so nothing evicts them
+            # under a running burst. (3) A cancel of the chunked request
+            # (_advance_partial) releases those pages with bursts in
+            # flight: no burst's block table names them, the chunks that
+            # wrote them were launched earlier, and whoever is handed
+            # them next writes them by a later program of the one stream.
+            # (4) The slot installed at the last chunk is absent from the
+            # in-flight burst's active mask (the index was None when that
+            # burst was built) and _process_burst guards by request id;
+            # its first token reaches the next burst through _wave_feed.
+            # A failed step and close() drop _partial and the pipeline
+            # together (_thread_loop).
             stopped = any(
                 s is not None and s.context.is_stopped for s in self._slots
             )
-            if (
-                self._partial is not None
-                or stopped
-                or self._clear_cache_requested
-            ):
+            if stopped or self._clear_cache_requested:
                 with self._phase("flush"):
                     self._flush_pipeline()
                 did = True
@@ -1711,13 +1743,19 @@ class InferenceEngine:
             log.info("admin clear_kv_blocks: evicted %d cached pages", n)
             self._publish_metrics()
             did = True
-        # 1) advance an in-flight chunked prefill, or admit waiting requests
+        # 1) advance an in-flight chunked prefill (one chunk a cycle,
+        # queued behind the burst in flight), or admit waiting requests
         # up to a per-step token budget (ref: vLLM max_num_batched_tokens
         # scheduling — many short prompts enter in ONE step instead of
         # serializing one admission behind every decode step); decode still
         # runs below, so prefills steal at most a budget's worth of device
         # time per step
-        if self._partial is not None:
+        # A cycle that advances a partial admits nothing beside its chunk,
+        # the last chunk's cycle included (_eager_readmit): one chunk a
+        # cycle and admissions in the cycles between partials, the cadence
+        # the closed loops' occupancy and time per token rest on.
+        self._chunk_cycle = self._partial is not None
+        if self._chunk_cycle:
             with self._phase("advance_partial"):
                 self._advance_partial_safe()
             did = True
@@ -1871,6 +1909,10 @@ class InferenceEngine:
             not cfg.eager_readmit
             or freed <= 0
             or self._partial is not None
+            # the cycle that closed a partial: its burst is read here now
+            # that no flush lands it first, and an admission pass behind
+            # the last chunk would put two cycles' prefill into one
+            or self._chunk_cycle
             or self._closed
         ):
             return
@@ -2802,8 +2844,7 @@ class InferenceEngine:
         # long prompt: remaining chunks advance on subsequent steps,
         # interleaved with decode (_step)
         end = start_pos + chunk_max
-        FLIGHT.event(waiting.context.id, "prefill_chunk")
-        logits = self._run_prefill_chunk(sp, token_ids, start_pos, end)
+        self._run_partial_chunk(waiting, sp, token_ids, start_pos, end)
         self._partial = _PartialPrefill(
             slot_idx, waiting, seq, sp, token_ids, end, max_tokens
         )
@@ -3215,7 +3256,7 @@ class InferenceEngine:
         dispatch, its width that dispatch's pack width (a bounded set: the
         wave feed compiles once per width; waves cover disjoint slots and
         land independently). Records without a presample (multimodal,
-        ring, chunked completions) batch into one extra stacked sample."""
+        ring) batch into one extra stacked sample."""
         recs: list[tuple] = []
         waves: dict[int, dict] = {}
         unsampled: list[tuple] = []
@@ -3500,8 +3541,27 @@ class InferenceEngine:
                  "error": f"prefill failed: {e}"},
             )
 
+    def _run_partial_chunk(
+        self, waiting: _Waiting, sp: SeqPages, token_ids: list[int],
+        start: int, end: int,
+    ) -> jax.Array:
+        """One chunk of a chunked prefill, counted by whether a decode
+        burst was in flight at its launch (the ``ahead`` its
+        ``engine.launch`` carries): ``chunked_prefill`` in
+        profile_snapshot()."""
+        FLIGHT.event(waiting.context.id, "prefill_chunk")
+        self.chunked_prefill["chunks"] += 1
+        if self._pipeline:
+            self.chunked_prefill["chunks_behind_burst"] += 1
+        return self._run_prefill_chunk(sp, token_ids, start, end)
+
     def _advance_partial(self) -> None:
-        """Run the next chunk of the in-flight chunked prefill."""
+        """Run the next chunk of the in-flight chunked prefill: behind
+        the burst in flight when there is one (_step's comment says why
+        that is sound). The last chunk completes as a single-prompt
+        prefill does (_single_prefill_record): its row sampled at width
+        1 off the chunk's own logits, so the slot joins the next burst
+        through the wave feed like every other admission."""
         p = self._partial
         assert p is not None
         if p.waiting.context.is_stopped:
@@ -3513,17 +3573,19 @@ class InferenceEngine:
             self._publish_metrics()
             return
         end = min(p.done + self._prefill_chunk_max(), len(p.token_ids))
-        FLIGHT.event(p.waiting.context.id, "prefill_chunk")
-        logits = self._run_prefill_chunk(p.sp, p.token_ids, p.done, end)
+        logits = self._run_partial_chunk(
+            p.waiting, p.sp, p.token_ids, p.done, end
+        )
         p.waiting.prefill_seq = self._launch_seq
         p.done = end
         if end == len(p.token_ids):
             self._partial = None
             self._seal_prompt_blocks(p.sp, p.seq)
             self._drain_offload()
+            pres = self._fused_first_tokens(logits[None, :], [p.waiting])
             self._complete_admissions([
                 (p.slot_idx, p.waiting, p.seq, p.sp, p.token_ids,
-                 p.max_tokens, (logits, None), None)
+                 p.max_tokens, (logits, None), pres[0] if pres else None)
             ])
 
     def _export_and_finish(

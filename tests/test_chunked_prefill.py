@@ -3,7 +3,12 @@
 The reference passes max_num_batched_tokens through to its engines; our
 engine owns the step loop, so the chunking is explicit: a prompt whose
 uncached tail exceeds max_prefill_chunk_tokens runs as N chunk steps
-interleaved with decode steps (engine/core.py _advance_partial)."""
+interleaved with decode steps (engine/core.py _advance_partial). With
+``pipeline_decode`` a chunk is launched behind the burst in flight: the
+flush that used to land every burst first is gone, and what it made
+trivially true is asserted here for both schedules (the running stream
+keeps streaming between chunks, a cancel between chunks hands every page
+back, the chunked prompt's tokens are the single shot's)."""
 
 import asyncio
 
@@ -21,12 +26,17 @@ SPEC = ModelSpec(
 )
 
 
-def _cfg(chunk: int) -> EngineConfig:
+def _cfg(chunk: int, pipeline: bool = False) -> EngineConfig:
     return EngineConfig(
         page_size=4, num_pages=128, max_pages_per_seq=32,
         max_decode_slots=2, prefill_buckets=(16, 32, 64, 128),
-        max_prefill_chunk_tokens=chunk,
+        max_prefill_chunk_tokens=chunk, pipeline_decode=pipeline,
+        decode_steps_per_dispatch=2 if pipeline else 1,
     )
+
+
+SCHEDULES = pytest.mark.parametrize(
+    "pipeline", [False, True], ids=["unpipelined", "pipelined"])
 
 
 async def _collect(engine, prompt, max_tokens, sink=None, tag=None):
@@ -43,7 +53,8 @@ async def _collect(engine, prompt, max_tokens, sink=None, tag=None):
     return out
 
 
-async def test_chunked_matches_single_shot():
+@SCHEDULES
+async def test_chunked_matches_single_shot(pipeline):
     """Greedy output identical whether the prompt prefills in 1 shot or in
     4 chunks (and the prefix cache sees identical sealed blocks)."""
     prompt = list(np.arange(60) % 250 + 16)
@@ -53,10 +64,12 @@ async def test_chunked_matches_single_shot():
     want = await _collect(e1, prompt, 6)
     await e1.close()
 
-    e2 = InferenceEngine(SPEC, _cfg(chunk=16))
+    e2 = InferenceEngine(SPEC, _cfg(chunk=16, pipeline=pipeline))
     await e2.start()
     got = await _collect(e2, prompt, 6)
     assert got == want
+    # an idle engine: nothing in flight, so no chunk is behind a burst
+    assert e2.chunked_prefill == {"chunks": 4, "chunks_behind_burst": 0}
     # run it again: the chunked prompt's sealed pages must serve as prefix
     got2 = await _collect(e2, prompt, 6)
     assert got2 == want
@@ -64,11 +77,14 @@ async def test_chunked_matches_single_shot():
     await e2.close()
 
 
-async def test_decode_progress_during_long_prefill():
+@SCHEDULES
+async def test_decode_progress_during_long_prefill(pipeline):
     """While a 64-token prompt prefills in 16-token chunks, an already-
     decoding stream keeps emitting (bounded ITL) instead of stalling for
-    the whole admission."""
-    engine = InferenceEngine(SPEC, _cfg(chunk=16))
+    the whole admission. Pipelined, each chunk waits behind the burst in
+    flight and that burst is read in the same cycle, so the stream's
+    tokens still land between the chunks."""
+    engine = InferenceEngine(SPEC, _cfg(chunk=16, pipeline=pipeline))
     await engine.start()
     order: list[str] = []
 
@@ -93,14 +109,22 @@ async def test_decode_progress_during_long_prefill():
     # at least 2 A-tokens must land in the 6 positions before B's first
     window = order[max(0, first_b - 6) : first_b]
     assert window.count("A") >= 2, order
+    if pipeline:
+        # the three chunks after the first each found A's burst in flight
+        assert engine.chunked_prefill["chunks"] == 4
+        assert engine.chunked_prefill["chunks_behind_burst"] >= 3
     await engine.close()
 
 
-async def test_chunked_prefill_cancel_mid_flight():
+@SCHEDULES
+async def test_chunked_prefill_cancel_mid_flight(pipeline):
     """Cancelling during chunked prefill releases pages and reports
-    cancelled."""
-    engine = InferenceEngine(SPEC, _cfg(chunk=16))
+    cancelled; a neighbour decoding meanwhile (pipelined: with a burst in
+    flight when the pages go back) streams its own tokens to the end."""
+    engine = InferenceEngine(SPEC, _cfg(chunk=16, pipeline=pipeline))
     await engine.start()
+    alone = await _collect(engine, [5, 9, 13], 30)
+    neighbour = asyncio.create_task(_collect(engine, [5, 9, 13], 30))
     ctx = Context()
     long_prompt = list(np.arange(96) % 250 + 16)
 
@@ -119,6 +143,7 @@ async def test_chunked_prefill_cancel_mid_flight():
     ctx.stop_generating()
     items = await task
     assert items[-1]["finish_reason"] in ("cancelled", "stop", "length")
+    assert await neighbour == alone
     # all pages back (cache may retain sealed prefix pages; active = 0).
     # The step THREAD may be a beat behind the client-visible stream end
     # under load, so poll briefly instead of asserting instantaneously.

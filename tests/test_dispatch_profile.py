@@ -26,6 +26,11 @@ from dynamo_tpu.runtime.faults import FAULTS
 
 pytestmark = pytest.mark.integration
 
+_TINY_F32 = ModelSpec(
+    name="tiny-f32", vocab_size=272, hidden_size=32, intermediate_size=64,
+    num_layers=2, num_heads=4, num_kv_heads=2, head_dim=8, dtype="float32",
+)
+
 
 def _cfg(**kw) -> EngineConfig:
     base = dict(
@@ -305,6 +310,77 @@ def test_profile_phase_catalog_sync():
     assert catalogued - used == set(), (
         f"stale catalog phases no code emits: {catalogued - used}"
     )
+
+
+def test_profile_counter_catalog_sync():
+    """catalog.PROFILE_COUNTERS <-> the always-on counters engines report
+    beside their phases, both directions, over the three families (a
+    prefill walk's kinds differ by model): a renamed counter silently
+    zeroes whoever reads the snapshot."""
+    from tools.dynalint import catalog
+
+    reported: set[str] = set()
+    for spec in (ModelSpec.tiny(), ModelSpec.tiny_deepseek(),
+                 ModelSpec.tiny_gpt_oss()):
+        engine = InferenceEngine(spec, _cfg(profile=False))
+        reported |= {
+            k for k in engine.profile_snapshot()
+            if k not in catalog.PROFILE_PHASES and not k.startswith("moe.")
+        }
+    assert reported == set(catalog.PROFILE_COUNTERS), (
+        reported ^ set(catalog.PROFILE_COUNTERS)
+    )
+
+
+async def test_chunked_prefill_counter_in_the_snapshot_and_reset():
+    """``chunked_prefill.chunks`` / ``.chunks_behind_burst`` from
+    profile_snapshot(): a 70-token prompt in chunks of 32 on an idle
+    engine is three chunks, none behind a burst; the same prompt beside a
+    stream decoding in pipelined bursts has the chunks after its first
+    behind one; reset_profile_window() clears both."""
+    engine = InferenceEngine(_TINY_F32, _cfg(
+        profile=False, async_admissions=True, pipeline_decode=True,
+        decode_steps_per_dispatch=2, max_pages_per_seq=32))
+    await engine.start()
+
+    def counts():
+        snap = engine.profile_snapshot()
+        return (snap["chunked_prefill.chunks"],
+                snap["chunked_prefill.chunks_behind_burst"])
+
+    assert counts() == ({"secs": 0.0, "calls": 0},) * 2
+    await _serve(engine, [70], "idle")
+    assert [c["calls"] for c in counts()] == [3, 0]
+    engine.reset_profile_window()
+    assert [c["calls"] for c in counts()] == [0, 0]
+
+    got: list[int] = []
+
+    async def stream():
+        async for item in engine.generate(
+            {"token_ids": [5, 9, 13],
+             "stop_conditions": {"max_tokens": 60, "ignore_eos": True},
+             "sampling": {"temperature": 0.0}},
+            Context("load"),
+        ):
+            got.extend(item["token_ids"])
+
+    async def later():
+        while len(got) < 6:
+            await asyncio.sleep(0.002)
+        # other tokens than the idle prompt's: nothing of it is cached
+        async for _ in engine.generate(
+            {"token_ids": [200 - j for j in range(70)],
+             "stop_conditions": {"max_tokens": 4, "ignore_eos": True},
+             "sampling": {"temperature": 0.0}},
+            Context("loaded"),
+        ):
+            pass
+
+    await asyncio.gather(stream(), later())
+    chunks, behind = (c["calls"] for c in counts())
+    assert chunks == 3 and 2 <= behind <= 3
+    await engine.close()
 
 
 def _core_source() -> str:

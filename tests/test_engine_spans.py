@@ -72,7 +72,9 @@ async def test_profile_off_records_nothing_and_creates_no_annotation(
                          "decode_kv.pages_live", "decode_kv.pages_fetched",
                          "decode_kv.pages_table",
                          "prefill_kv.blocks_visited.full",
-                         "prefill_kv.blocks_table.full"}
+                         "prefill_kv.blocks_table.full",
+                         "chunked_prefill.chunks",
+                         "chunked_prefill.chunks_behind_burst"}
     # three bursts' worth at least, always on: pages moved for real contexts
     kv = {k.removeprefix("decode_kv."): v["calls"]
           for k, v in snap.items() if k.startswith("decode_kv.")}
@@ -200,7 +202,8 @@ def test_phase_annotations_match_the_profile_sums(traced):
         if (
             phase in synthesized
             # counts, not phases
-            or phase.startswith(("decode_kv.", "prefill_kv."))
+            or phase.startswith(
+                ("decode_kv.", "prefill_kv.", "chunked_prefill."))
             or phase.startswith("readmit.") and phase != "readmit.d2h_wait"
         ):
             continue

@@ -6,6 +6,7 @@ latent cache, the decode kernel interpreted and the XLA walk) against the
 benchmark's plain reference (``perfbench/references/latent_moe.py``), which
 shares no code with it."""
 
+import asyncio
 import importlib.util
 import os
 
@@ -352,6 +353,63 @@ async def test_serves_through_the_engine_and_counts(monkeypatch):
     assert c["decode.assignments"] >= 2 * 5 * 4
     assert sum(c[f"decode.expert.{i}"] for i in range(4)) <= c[
         "decode.assignments"]
+
+
+async def _greedy(engine, prompt, n, out=None):
+    out = [] if out is None else out
+    async for item in engine.generate(
+        {"token_ids": list(prompt), "sampling": {"temperature": 0.0},
+         "stop_conditions": {"max_tokens": n, "ignore_eos": True}},
+        Context(),
+    ):
+        assert item.get("finish_reason") != "error", item
+        out.extend(item.get("token_ids") or [])
+    return out
+
+
+async def test_a_chunked_prompt_behind_running_bursts(monkeypatch):
+    """Chunked under load: two streams decode in pipelined bursts through
+    the kernel while a 37-token prompt prefills in chunks of 16. The
+    chunks at ``start_pos`` 16 and 32 walk latents an earlier chunk wrote,
+    each launched behind the burst in flight (no flush lands it first),
+    and the prompt's tokens are those it gets alone and unchunked."""
+    monkeypatch.setenv("DYNAMO_PALLAS", "1")
+    prompt = [int(t) for t in np.arange(5, 5 + 37) * 7 % 96]
+
+    def build(**kw):
+        return InferenceEngine(SPEC, EngineConfig(
+            page_size=PAGE, num_pages=64, max_pages_per_seq=PAGES_PER_SEQ,
+            max_decode_slots=3, decode_steps_per_dispatch=4, seed=SEED, **kw))
+
+    alone = build(prefill_buckets=(64,), max_prefill_chunk_tokens=64)
+    want = await _greedy(alone, prompt, 6)
+    assert alone.chunked_prefill["chunks"] == 0
+    await alone.close()
+
+    engine = build(prefill_buckets=(16,), max_prefill_chunk_tokens=16,
+                   pipeline_decode=True)
+    chunks, run_chunk = [], engine._run_partial_chunk
+
+    def watched(waiting, sp, token_ids, start, end):
+        chunks.append((start, len(engine._pipeline)))
+        return run_chunk(waiting, sp, token_ids, start, end)
+
+    engine._run_partial_chunk = watched
+    a, b = [], []
+
+    async def later():
+        while min(len(a), len(b)) < 4:
+            await asyncio.sleep(0.002)
+        return await _greedy(engine, prompt, 6)
+
+    outs = await asyncio.gather(
+        _greedy(engine, [3, 9, 27], 40, out=a),
+        _greedy(engine, [8, 64, 32, 5], 40, out=b), later())
+    assert outs[2] == want and [len(o) for o in outs[:2]] == [40, 40]
+    assert chunks == [(0, 1), (16, 1), (32, 1)]
+    assert engine.chunked_prefill == {"chunks": 3, "chunks_behind_burst": 3}
+    assert engine.allocator.active_pages == 0
+    await engine.close()
 
 
 _jit_reference = jax.jit(mla.reference_forward, static_argnums=0)

@@ -5,6 +5,7 @@ and held experts behind a router over all of them. Against the benchmark's
 plain reference (``perfbench/references/hybrid_swa_moe.py``), which shares
 no code with the program."""
 
+import asyncio
 import importlib.util
 import os
 
@@ -180,6 +181,63 @@ async def test_the_engine_serves_it_chunked_and_counts(model):
     assert snap["moe.prefill.steps"]["calls"] == counters["prefill.steps"]
     assert 0 < counters["prefill.experts_touched"] <= (
         3 * 8 * counters["prefill.steps"])
+    await engine.close()
+
+
+async def _greedy(engine, prompt, n, out=None):
+    out = [] if out is None else out
+    req = {"token_ids": [int(t) for t in prompt],
+           "stop_conditions": {"max_tokens": n, "ignore_eos": True},
+           "sampling": {"temperature": 0.0}}
+    async for item in engine.generate(req, Context()):
+        assert item.get("finish_reason") != "error", item
+        out.extend(item.get("token_ids", []))
+    return out
+
+
+async def test_a_chunked_prompt_behind_running_bursts(model):
+    """Chunked under load: two streams decode in pipelined bursts while a
+    30-token prompt prefills in chunks of 8, a window's length, through
+    the window pool and the full pool. Every chunk after the first is
+    launched behind the burst in flight (no flush lands it first); the
+    first token is the reference's and all six are those the prompt gets
+    alone and unchunked."""
+    _, toks, want = model
+
+    def build(**kw):
+        return InferenceEngine(SPEC, EngineConfig(
+            page_size=PAGE, num_pages=64, max_pages_per_seq=PAGES_PER_SEQ,
+            max_decode_slots=3, decode_steps_per_dispatch=4, seed=SEED,
+            guided_mode="off", **kw))
+
+    alone = build(prefill_buckets=(32,), max_prefill_chunk_tokens=32)
+    unchunked = await _greedy(alone, toks[0, :30], 6)
+    assert unchunked[0] == int(want[0, 29].argmax())
+    await alone.close()
+
+    engine = build(prefill_buckets=(8,), max_prefill_chunk_tokens=8,
+                   pipeline_decode=True)
+    chunks, run_chunk = [], engine._run_partial_chunk
+
+    def watched(waiting, sp, token_ids, start, end):
+        chunks.append((start, len(engine._pipeline)))
+        return run_chunk(waiting, sp, token_ids, start, end)
+
+    engine._run_partial_chunk = watched
+    a, b = [], []
+
+    async def later():
+        while min(len(a), len(b)) < 4:
+            await asyncio.sleep(0.002)
+        return await _greedy(engine, toks[0, :30], 6)
+
+    outs = await asyncio.gather(
+        _greedy(engine, toks[1, :5], 36, out=a),
+        _greedy(engine, toks[1, 7:11], 36, out=b), later())
+    assert outs[2] == unchunked and [len(o) for o in outs[:2]] == [36, 36]
+    assert chunks == [(0, 1), (8, 1), (16, 1), (24, 1)]
+    assert engine.chunked_prefill == {"chunks": 4, "chunks_behind_burst": 4}
+    assert engine.allocator.active_pages == 0
     await engine.close()
 
 

@@ -2,9 +2,11 @@
 device-chained before processing k, so ONE burst is queued behind the
 running one. Must be invisible to clients — exact same tokens as the
 unpipelined engine, under mixed sampling, mid-burst stops, admission
-churn, admissions into a half-full batch, a chunked prompt (the flush
-path), cancellation, and page pressure — and no prefill may ever be
-launched behind more than one burst."""
+churn, admissions into a half-full batch, chunked prompts (each chunk
+launched behind the burst in flight, as any admission's prefill is: under
+load, beside a finishing stream, cancelled between chunks, on an idle
+engine, two in a row), cancellation, and page pressure — and no prefill
+may ever be launched behind more than one burst."""
 
 import asyncio
 
@@ -32,7 +34,7 @@ def _cfg(pipeline: bool, *, num_pages=256, slots=3, **kw) -> EngineConfig:
 
 
 async def _collect(engine, prompt, max_tokens, *, temperature=0.0, seed=None,
-                   ignore_eos=True, out=None):
+                   ignore_eos=True, out=None, ctx=None):
     """The stream's tokens; into ``out`` as they arrive, where one is given
     for others to watch."""
     out = [] if out is None else out
@@ -44,7 +46,7 @@ async def _collect(engine, prompt, max_tokens, *, temperature=0.0, seed=None,
          "stop_conditions": {"max_tokens": max_tokens,
                              "ignore_eos": ignore_eos},
          "sampling": sampling},
-        Context(),
+        ctx or Context(),
     ):
         out.extend(item["token_ids"])
     return out
@@ -52,12 +54,18 @@ async def _collect(engine, prompt, max_tokens, *, temperature=0.0, seed=None,
 
 class _Watch:
     """What the step thread had in flight, seen from outside: ``ahead`` of
-    every launch, and the bursts left in flight at every read of the
-    oldest one, in the order they happened."""
+    every launch, the bursts in flight at every chunk of a chunked prefill
+    (``chunk``), at a chunked request's cancellation (``cancel``) and at a
+    stream's end beside an open partial (``finish``), and the bursts left
+    in flight at every read of the oldest one, in the order they
+    happened."""
 
     def __init__(self, engine):
+        self.engine = engine
         self.log: list[tuple[str, int]] = []
         launch, process = engine._launch, engine._process_burst
+        chunk, advance = engine._run_partial_chunk, engine._advance_partial
+        finish = engine._finish
 
         def watched_launch(kind, **counts):
             if kind in ("prefill", "decode"):
@@ -70,8 +78,25 @@ class _Watch:
             self.log.append(("read", len(engine._pipeline)))
             return process(pending)
 
+        def watched_chunk(*args):
+            self.log.append(("chunk", len(engine._pipeline)))
+            return chunk(*args)
+
+        def watched_advance():
+            if engine._partial.waiting.context.is_stopped:
+                self.log.append(("cancel", len(engine._pipeline)))
+            return advance()
+
+        def watched_finish(*args, **kw):
+            if engine._partial is not None:
+                self.log.append(("finish", len(engine._pipeline)))
+            return finish(*args, **kw)
+
         engine._launch = watched_launch
         engine._process_burst = watched_process
+        engine._run_partial_chunk = watched_chunk
+        engine._advance_partial = watched_advance
+        engine._finish = watched_finish
 
     def ahead(self, kind: str) -> list[int]:
         return [n for k, n in self.log if k == kind]
@@ -113,25 +138,106 @@ async def _midstream(engine):
     )
 
 
+def _long(n: int, salt: int = 7) -> list[int]:
+    return [3 + (salt * i) % 200 for i in range(n)]
+
+
 async def _chunked(engine):
     # a prompt over the chunk opens a partial prefill while two streams
-    # decode: every step it is open lands the in-flight burst first
+    # decode: every chunk is launched behind the burst in flight, which
+    # is read as in any other cycle
     a, b = [], []
-    long_prompt = [3 + (7 * i) % 200 for i in range(27)]
     return await asyncio.gather(
         _collect(engine, [5, 9, 13], 41, out=a),
         _collect(engine, [7, 11, 2, 8], 37, out=b),
-        _after(9, [a, b], _collect(engine, long_prompt, 14)),
+        _after(9, [a, b], _collect(engine, _long(27), 14)),
     )
 
 
+async def _chunked_finish(engine):
+    # all three at once: the cold wave admits the two short prompts, the
+    # long one opens its partial in the next cycle, and the first stream
+    # runs out of budget while six of its eight chunks are still to come.
+    # The slot it frees stays free until the partial closes.
+    return await asyncio.gather(
+        _collect(engine, [5, 9, 13], 10),
+        _collect(engine, [7, 11, 2, 8], 57),
+        _collect(engine, _long(59), 14),
+    )
+
+
+async def _chunked_cancel(engine):
+    # the chunked request is cancelled from the step thread itself, right
+    # after its third chunk is launched: the next cycle's advance finds it
+    # stopped and hands its pages back with a burst in flight
+    ctx = Context()
+    run_chunk, n = engine._run_partial_chunk, [0]
+
+    def third_chunk_cancels(*args):
+        out = run_chunk(*args)
+        n[0] += 1
+        if n[0] == 3:
+            ctx.stop_generating()
+        return out
+
+    engine._run_partial_chunk = third_chunk_cancels
+    a, b = [], []
+    return await asyncio.gather(
+        _collect(engine, [5, 9, 13], 41, out=a),
+        _collect(engine, [7, 11, 2, 8], 37, out=b),
+        _after(9, [a, b], _collect(engine, _long(59), 14, ctx=ctx)),
+        # one admitted after the cancel: its pages may be the freed ones
+        _after(30, [a, b], _collect(engine, _long(7, salt=11), 9)),
+    )
+
+
+async def _chunked_idle(engine):
+    # nothing decodes: the chunks find the pipeline empty, one a cycle
+    return [await _collect(engine, _long(27), 14)]
+
+
+async def _chunked_twice(engine):
+    # two long prompts queue behind each other: the second's partial
+    # opens in the cycle after the first closes
+    a, b = [], []
+    return await asyncio.gather(
+        _collect(engine, [5, 9, 13], 61, out=a),
+        _collect(engine, [7, 11, 2, 8], 58, out=b),
+        _after(9, [a, b], _collect(engine, _long(27), 14)),
+        _after(9, [a, b], _collect(
+            engine, _long(30, salt=13), 11, temperature=0.8, seed=5)),
+    )
+
+
+async def _chunked_closing(engine):
+    # a stream runs out of budget in every cycle the first partial can
+    # close in, so the burst read in that cycle frees a slot with the
+    # second long prompt waiting: the cycle still admits nothing beside
+    # its chunk, and the second partial opens in the next one
+    return await asyncio.gather(
+        *(_collect(engine, [5 + i, 9, 13], n)
+          for i, n in enumerate((9, 13, 17, 21, 25, 29))),
+        _collect(engine, _long(27), 14),
+        _collect(engine, _long(30, salt=13), 11),
+    )
+
+
+CHUNKED = {"slots": 4, "max_prefill_chunk_tokens": 8}
 WORKLOADS = {
     # name: (driver, engine options, tokens wanted of each stream)
     "churn": (_churn, {}, (11, 6, 9, 5, 13)),
     "midstream": (_midstream, {"slots": 4}, (61, 58, 19, 10, 7)),
-    "chunked": (_chunked, {"slots": 4, "max_prefill_chunk_tokens": 8},
-                (41, 37, 14)),
+    "chunked": (_chunked, CHUNKED, (41, 37, 14)),
+    "chunked_finish": (_chunked_finish, CHUNKED, (10, 57, 14)),
+    "chunked_cancel": (_chunked_cancel, CHUNKED, (41, 37, 0, 9)),
+    "chunked_idle": (_chunked_idle, CHUNKED, (14,)),
+    "chunked_twice": (_chunked_twice, CHUNKED, (61, 58, 14, 11)),
+    "chunked_closing": (_chunked_closing, {**CHUNKED, "slots": 8},
+                        (9, 13, 17, 21, 25, 29, 14, 11)),
 }
+# chunks of 8: the chunks each case's long prompts take
+CHUNKS = {"chunked": 4, "chunked_finish": 8, "chunked_cancel": 3,
+          "chunked_idle": 4, "chunked_twice": 8, "chunked_closing": 8}
 
 
 async def _run_workload(name: str, pipeline: bool):
@@ -161,10 +267,36 @@ async def test_pipelined_matches_unpipelined_exactly(name):
     if name == "midstream":
         # those prefills really were launched behind a burst in flight
         assert watch.ahead("prefill").count(1) >= 3
-    if name == "chunked":
-        # 27 tokens at 8 a chunk: the chunks after the first each found
-        # the pipeline flushed
-        assert watch.ahead("prefill").count(0) >= 4
+    if name not in CHUNKS:
+        return
+    # a chunked prefill rides the pipeline: no flush for it, so under load
+    # every chunk finds the one burst in flight, and the engine's counter
+    # says what the launches carried
+    chunks = watch.ahead("chunk")
+    assert len(chunks) == len(plain.ahead("chunk")) == CHUNKS[name]
+    assert set(plain.ahead("chunk")) == {0}
+    assert watch.engine.chunked_prefill == {
+        "chunks": len(chunks), "chunks_behind_burst": chunks.count(1),
+    }
+    if name == "chunked_idle":
+        # nothing in flight and none invented: the prompt's chunks and
+        # the first burst after them find the device drained
+        assert set(chunks) == {0} and set(watch.ahead("prefill")) == {0}
+        assert watch.ahead("decode")[0] == 0
+    else:
+        assert set(chunks) == {1}
+        # one chunk a cycle and no admission beside it, the cycle that
+        # closes a partial included: a burst is dispatched between any two
+        # chunks (an admission pass behind the last chunk would open the
+        # next partial there and run its second chunk with none between)
+        beats = [k for k, _n in watch.log if k in ("chunk", "decode")]
+        assert ("chunk", "chunk") not in set(zip(beats, beats[1:]))
+    if name == "chunked_finish":
+        # a stream ended beside the open partial, in both engines
+        assert watch.ahead("finish") and plain.ahead("finish")
+    if name == "chunked_cancel":
+        # its pages went back under a burst in flight
+        assert watch.ahead("cancel") == [1] and plain.ahead("cancel") == [0]
 
 
 async def test_one_burst_in_flight_whenever_the_oldest_is_read():

@@ -190,6 +190,59 @@ async def test_mixed_spec_and_nonspec_slots_one_engine():
     assert greedy_out == ref
 
 
+async def test_chunked_prompt_beside_verifies_and_unread_bursts():
+    """A partial open beside a spec-managed slot and a burst-managed one.
+    The flush that landed every burst before a chunk used to guarantee
+    that a verify never ran with an unread burst AND a chunk queued ahead
+    of it on the device; now it does, every cycle the partial is open,
+    and the greedy streams (the verified one and the chunked prompt's
+    own) stay the spec-off engine's."""
+    greedy_prompt = _repetitive(272, 40)
+    long_prompt = _repetitive(272, 48, seed=9)  # three chunks of 16
+    kw = dict(max_decode_slots=3, max_prefill_chunk_tokens=16)
+
+    off = InferenceEngine(TINY_GQA, _cfg("off", **kw))
+    await off.start()
+    want_greedy, _ = await _gen(off, greedy_prompt, 60)
+    want_long, _ = await _gen(off, long_prompt, 12)
+    await off.close()
+
+    engine = InferenceEngine(TINY_GQA, _cfg("ngram", **kw))
+    seen: list[tuple[str, bool, int]] = []
+    launch = engine._launch
+
+    def watched(kind, **counts):
+        if kind in ("verify", "prefill"):
+            seen.append(
+                (kind, engine._partial is not None, len(engine._pipeline)))
+        return launch(kind, **counts)
+
+    engine._launch = watched
+    await engine.start()
+
+    async def later():
+        while engine.spec_verifies < 2:
+            await asyncio.sleep(0.002)
+        return await _gen(engine, long_prompt, 12)
+
+    (greedy, _), (sampled, _), (long_out, _) = await asyncio.gather(
+        _gen(engine, greedy_prompt, 60),
+        _gen(engine, _repetitive(272, 24, seed=5), 90, temperature=0.8),
+        later(),
+    )
+    assert greedy == want_greedy and long_out == want_long
+    assert len(sampled) == 90
+    # the chunks after the first each found a burst in flight, and a
+    # verify ran behind such a chunk with that burst still unread
+    assert [n for k, _p, n in seen if k == "prefill"][-2:] == [1, 1]
+    assert ("verify", True, 1) in seen
+    # (the two shorter prompts chunk too, on a cold engine: 3 + 2 + 3)
+    assert engine.chunked_prefill["chunks"] == 8
+    assert engine.chunked_prefill["chunks_behind_burst"] >= 3
+    assert engine.allocator.active_pages == 0
+    await engine.close()
+
+
 # ------------------------------------------------- boundaries + fallback
 
 

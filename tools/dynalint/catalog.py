@@ -123,6 +123,37 @@ PROFILE_PHASES: dict[str, str] = {
                "resume-request rebuild for one paused batch stream",
 }
 
+# always-on host counters profile_snapshot() carries beside the phases
+# (counts in ``calls``, zeroed by reset_profile_window()) -> what they
+# count. Readers (perfbench/readers/latent.py, a builder's traced pair in
+# PERF.md) reference these exact strings; two-way sync with what engines
+# of the three families report is test-enforced
+# (tests/test_dispatch_profile.py). ``moe.*`` are device-side and named
+# in docs/OBSERVABILITY.md.
+PROFILE_COUNTERS: dict[str, str] = {
+    "decode_kv.pages_live": "pages holding a live slot's context, over "
+                            "the dispatched bursts' slots and steps",
+    "decode_kv.pages_fetched": "pages of the chunks the decode kernel "
+                               "fetched and scored for them",
+    "decode_kv.pages_table": "slots x table width x steps: what a kernel "
+                             "that followed the table would move",
+    "prefill_kv.blocks_visited.full": "block steps the prefill walk ran "
+                                      "on full-attention layers",
+    "prefill_kv.blocks_table.full": "block steps a walk of the whole "
+                                    "table would run there",
+    "prefill_kv.blocks_visited.window": "the same on window layers",
+    "prefill_kv.blocks_table.window": "and a whole-table walk's there",
+    "prefill_kv.blocks_visited.latent": "the same for the latent "
+                                        "family's walk",
+    "prefill_kv.blocks_table.latent": "and a whole-table walk's there",
+    "chunked_prefill.chunks": "chunks of chunked prefills launched (a "
+                              "long prompt's first chunk included)",
+    "chunked_prefill.chunks_behind_burst": "those launched with a decode "
+                                           "burst in flight (ahead >= 1): "
+                                           "queued behind device work, "
+                                           "not after a drained device",
+}
+
 # jax.profiler.TraceAnnotation names the profiled engine writes into a
 # profiler trace besides ``engine.<phase>`` for every _phase name above
 # (engine/core.py, EngineConfig.profile) -> what they mark and the
