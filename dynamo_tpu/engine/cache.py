@@ -65,6 +65,8 @@ class PageAllocator:
         *,
         on_store: Callable[[int, int], None] | None = None,
         on_evict: Callable[[list[int]], None] | None = None,
+        on_release: Callable[[list[int]], None] | None = None,
+        prefix_cache: bool = True,
     ):
         # page 0 is the trash page; usable pages are 1..num_pages-1
         self.page_size = page_size
@@ -77,6 +79,13 @@ class PageAllocator:
         self._inactive: OrderedDict[int, float] = OrderedDict()  # page -> ts (LRU)
         self._on_store = on_store or (lambda sh, parent: None)
         self._on_evict = on_evict or (lambda shs: None)
+        # told of every page a request lets go of (a model that keeps
+        # state beside its pages frees it then: engine/core.py)
+        self._on_release = on_release
+        # False = no page is ever reused under another sequence's prefix:
+        # nothing matches and nothing seals (a model whose pages do not
+        # hold all of a sequence's state at a block boundary)
+        self.prefix_cache = prefix_cache
 
     # -- observers ---------------------------------------------------------
 
@@ -112,6 +121,8 @@ class PageAllocator:
         """Longest consecutive run of cached pages for this hash chain.
         Returns the page ids (does NOT take references - call take_prefix)."""
         pages = []
+        if not self.prefix_cache:
+            return pages
         for sh in sequence_hashes:
             page = self._hash_page.get(sh)
             if page is None:
@@ -156,7 +167,7 @@ class PageAllocator:
         If the hash is already cached on another page, the existing entry
         wins (dedup) but this page keeps serving its request.
         """
-        if sequence_hash in self._hash_page:
+        if sequence_hash in self._hash_page or not self.prefix_cache:
             return
         self._hash_page[sequence_hash] = page
         self._page_hash[page] = sequence_hash
@@ -168,6 +179,8 @@ class PageAllocator:
         """Drop one reference per page; unreferenced pages with a hash stay
         cached (inactive LRU); unhashed pages (partial blocks) free up."""
         now = time.monotonic()
+        if self._on_release is not None and pages:
+            self._on_release(list(pages))
         for page in pages:
             refs = self._ref.get(page, 0) - 1
             if refs > 0:

@@ -40,16 +40,25 @@ _lock = threading.Lock()
 _listener_installed = False
 _cache_dir: str | None = None
 _decided = False
-# [count, total_secs] — mutated only under the GIL by the jax listeners
+# [count, total_secs] — mutated by the jax listeners under _events_lock
+# (two threads compile during precompile)
 _events: list = [0, 0.0]
+_events_lock = threading.Lock()
+# the same by compiling thread (the listener runs in the thread that
+# compiled): precompile warms the samplers beside the model's programs
+_thread_events: dict[int, list] = {}
 # [persistent-cache lookups, hits]
 _cache_events: list = [0, 0]
 
 
 def _on_event_duration(name: str, secs: float, **_kw) -> None:
     if "backend_compile" in name:
-        _events[0] += 1
-        _events[1] += secs
+        with _events_lock:
+            _events[0] += 1
+            _events[1] += secs
+            mine = _thread_events.setdefault(threading.get_ident(), [0, 0.0])
+            mine[0] += 1
+            mine[1] += secs
 
 
 def _on_event(name: str, **_kw) -> None:
@@ -80,6 +89,13 @@ def compile_snapshot() -> tuple[int, float]:
     taken before any jit activity are complete."""
     ensure_compile_listener()
     return _events[0], _events[1]
+
+
+def thread_compile_snapshot() -> tuple[int, float]:
+    """``compile_snapshot()`` of the calling thread's own compiles."""
+    ensure_compile_listener()
+    count, secs = _thread_events.get(threading.get_ident(), (0, 0.0))
+    return count, secs
 
 
 def cache_snapshot() -> tuple[int, int]:

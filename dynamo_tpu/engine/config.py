@@ -21,6 +21,16 @@ class LayerKind:
     rope_theta: float
     window: int = 0  # sliding window in tokens; 0 = full attention
     sinks: bool = False  # one learned logit a query head in the softmax
+    # what mixes tokens: "softmax" attention over the sequence's pages, or
+    # "kda" (Kimi Delta Attention, arXiv:2510.26692): a gated delta rule
+    # over a fixed-size state a sequence, which owns no page in this layer
+    # (``ModelSpec.kda_*`` give its sizes; the three fields above are
+    # unread for it)
+    mixer: str = "softmax"
+
+    @property
+    def recurrent(self) -> bool:
+        return self.mixer != "softmax"
 
 
 @dataclass(frozen=True)
@@ -101,6 +111,21 @@ class ModelSpec:
     # layers``). The next-token forward pass has no use for them: the
     # loader drops their tensors as expected, not as strays
     nextn_predict_layers: int = 0
+    # softmax layers without a positional term (NoPE): q and k are not
+    # rotated and ``rope_theta`` is unread
+    use_rope: bool = True
+    # softmax layers gate their attention output by element before the
+    # output projection: ``wo (a * sigmoid(x @ w_gate_attn))``
+    attn_gate: bool = False
+    # KDA layers (``LayerKind.mixer == "kda"``): heads of ``kda_head_dim``
+    # keys and values (no KV grouping), a causal depthwise convolution of
+    # ``kda_conv`` taps on q, k and v, decay and output gate through a
+    # rank of ``kda_head_dim``, and beta in (0, 2) where
+    # ``kda_neg_eigval`` (else (0, 1))
+    kda_heads: int = 0
+    kda_head_dim: int = 0
+    kda_conv: int = 4
+    kda_neg_eigval: bool = False
 
     def __post_init__(self) -> None:
         # a spec read from JSON brings lists and dicts; the spec is a
@@ -120,6 +145,9 @@ class ModelSpec:
                 f"layer_pattern names {len(self.layer_pattern)} layers, "
                 f"the model has {self.num_layers}"
             )
+        if self.layer_kinds and self.layer_kinds[0].recurrent:
+            # the cache's first leaf is a page pool (llama.page_size_of)
+            raise ValueError("layer_kinds must list a paged kind first")
         if self.held_experts:
             n, first = self.held_experts
             if not 0 < n <= self.num_experts - first:
@@ -129,7 +157,9 @@ class ModelSpec:
                 )
 
     def kind(self, li: int) -> LayerKind:
-        """What kind of attention layer ``li`` is."""
+        """What kind of layer ``li`` is: THE place the ``sliding_window``
+        / ``layer_types`` / ``attn_sinks`` shorthand (two kinds that share
+        a pool) resolves into a ``LayerKind``."""
         if self.layer_kinds:
             return self.layer_kinds[self.layer_pattern[li]]
         window = self.sliding_window
@@ -140,6 +170,19 @@ class ModelSpec:
         return LayerKind(
             self.num_kv_heads, self.rope_theta, window, self.attn_sinks
         )
+
+    @property
+    def kinds(self) -> tuple[LayerKind, ...]:
+        """Every kind of layer the model has, listed or resolved from the
+        shorthand."""
+        return self.layer_kinds or tuple(dict.fromkeys(
+            self.kind(li) for li in range(self.num_layers)
+        ))
+
+    @property
+    def has_recurrent(self) -> bool:
+        """Some layer keeps a recurrent state a sequence beside the pages."""
+        return any(k.recurrent for k in self.layer_kinds)
 
     def pool_slot(self, li: int) -> tuple[int, int]:
         """(kind, index among that kind's layers) of layer ``li``: where
@@ -162,10 +205,7 @@ class ModelSpec:
 
     @property
     def has_attn_extras(self) -> bool:
-        kinds = self.layer_kinds or (
-            self.kind(li) for li in range(self.num_layers)
-        )
-        return any(k.window or k.sinks for k in kinds)
+        return any(k.window or k.sinks for k in self.kinds)
 
     @classmethod
     def llama3_8b(cls) -> "ModelSpec":
@@ -297,6 +337,30 @@ class ModelSpec:
             v_head_dim=16, q_lora_rank=24,
         )
 
+    @classmethod
+    def tiny_solar(cls, **kw) -> "ModelSpec":
+        """Toy Solar-Open2 architecture: one gated NoPE GQA layer to three
+        KDA layers, sigmoid-routed experts beside a shared expert in every
+        layer."""
+        base = dict(
+            name="tiny-solar", vocab_size=96, hidden_size=64,
+            intermediate_size=64, num_layers=4, num_heads=4,
+            num_kv_heads=2, head_dim=16, dtype="float32",
+            rope_theta=10000.0, tie_embeddings=False, use_rope=False,
+            attn_gate=True,
+            layer_kinds=(
+                LayerKind(2, 10000.0),
+                LayerKind(0, 0.0, mixer="kda"),
+            ),
+            layer_pattern=(0, 1, 1, 1),
+            kda_heads=4, kda_head_dim=16, kda_neg_eigval=True,
+            num_experts=8, num_experts_per_token=2,
+            moe_intermediate_size=32, moe_scoring="sigmoid",
+            n_shared_experts=1,
+        )
+        base.update(kw)
+        return cls(**base)
+
     @property
     def is_mla(self) -> bool:
         return self.kv_lora_rank > 0
@@ -308,6 +372,7 @@ class ModelSpec:
             "tiny-moe": cls.tiny_moe,
             "tiny-deepseek": cls.tiny_deepseek,
             "tiny-gpt-oss": cls.tiny_gpt_oss,
+            "tiny-solar": cls.tiny_solar,
             "llama-3-8b": cls.llama3_8b,
             "llama-3-70b": cls.llama3_70b,
             "mixtral-8x7b": cls.mixtral_8x7b,
@@ -508,7 +573,11 @@ class EngineConfig:
         (``max_prefill_chunk_tokens`` is capped by it). A guard with
         margin, not a tuner. The latent family (``spec.is_mla``) is
         charged what ITS programs hold (``need_latent``): their walk
-        never had a whole-table form to stay compatible with."""
+        never had a whole-table form to stay compatible with; so is a model
+        with recurrent layers (``need_recurrent``): its softmax layers the
+        walk's true tiles, its KDA layers the chunkwise form's float32
+        operands. The state rows themselves are part of the pools, so
+        ``free_bytes`` already lacks them."""
         top = self.bucket_for(min(
             self.max_context, self.max_prefill_chunk_tokens,
             self.prefill_buckets[-1],
@@ -518,6 +587,8 @@ class EngineConfig:
         def need(rows: int, bucket: int) -> int:
             if spec.is_mla:
                 return need_latent(rows, bucket)
+            if spec.has_recurrent:
+                return need_recurrent(rows, bucket)
             scores = 4 * rows * heads * bucket * self.max_context
             return scores * 3 // 2 + 96 * 1024 * rows * bucket
 
@@ -536,6 +607,23 @@ class EngineConfig:
             scores = 4 * rows * heads * bucket * bp * self.page_size
             acc = 4 * rows * heads * bucket * spec.v_head_dim
             return scores * 3 + acc * 2 + 96 * 1024 * rows * bucket
+
+        def need_recurrent(rows: int, bucket: int) -> int:
+            # the softmax layers' walk holds float32 scores of a tile of
+            # queries against one block of pages, three copies, and its
+            # accumulator (ops/attention.paged_prefill_attention); a KDA
+            # layer's chunkwise form a score of float32 arrays [rows,
+            # bucket, heads x head_dim] (ops/attention.kda_chunk_prefill:
+            # its operands by block and what they are made from); 96 KiB a
+            # prompt token for the rest
+            from dynamo_tpu.ops.attention import prefill_tiling
+
+            tq, bp = prefill_tiling(
+                bucket, self.max_pages_per_seq, self.page_size, 0
+            )
+            scores = 4 * rows * heads * tq * bp * self.page_size
+            kda = 4 * 20 * rows * bucket * spec.kda_heads * spec.kda_head_dim
+            return scores * 3 + kda + 96 * 1024 * rows * bucket
 
         shapes: dict[int, int] = {}
         for bucket in self.prefill_buckets:

@@ -25,6 +25,7 @@ import queue
 import threading
 import time
 from dataclasses import dataclass, field
+from functools import partial
 from typing import Any, AsyncIterator
 
 import jax
@@ -35,6 +36,7 @@ from dynamo_tpu.engine.cache import OutOfPages, PageAllocator, SeqPages
 from dynamo_tpu.engine.compile_cache import (
     compile_snapshot,
     enable_compile_cache,
+    thread_compile_snapshot,
 )
 from dynamo_tpu.engine.config import EngineConfig, ModelSpec
 from dynamo_tpu.engine.sampling import (
@@ -90,6 +92,13 @@ def _with_fed_column(tokens_in, sampled):
     """[B, 1 + n]: the fed tokens ride along as column 0 of the burst's
     samples, so one download carries both."""
     return jnp.concatenate([tokens_in[:, None], sampled], axis=1)
+
+
+@partial(jax.jit, static_argnums=0)
+def _init_params(spec: ModelSpec, key):
+    """Random weights as ONE program: tensor by tensor a cold start
+    compiles a draw a shape (13 s of a 50 s init on a v5e, PR 34)."""
+    return get_family(spec).init_params(spec, key)
 
 
 @dataclass
@@ -175,7 +184,7 @@ READMIT_SUMS = {
 # the engine's always-on host counters, each a dict of ints under the
 # attribute of its name: profile_snapshot() carries them as
 # ``<family>.<name>`` and reset_profile_window() zeroes them
-_COUNTER_FAMILIES = ("decode_kv", "prefill_kv", "chunked_prefill")
+_COUNTER_FAMILIES = ("decode_kv", "prefill_kv", "chunked_prefill", "kda")
 
 # what _phase and _launch hand out with profiling off: one shared object
 # whose enter and exit do nothing
@@ -269,6 +278,18 @@ class InferenceEngine:
         self.metrics = metrics_publisher
 
         self.fam = get_family(spec)
+        if self.fam.recurrent:
+            # a sequence's pages hold none of its recurrent state, so what
+            # moves or reuses pages is off for this model; what was asked
+            # for anyway is counted (_note_recurrent_gate)
+            if self.kvbm is not None:
+                self._note_recurrent_gate("page_offload")
+                self.offload.close()
+                self.kvbm = self.offload = None
+            if self.config.spec_mode != "off":
+                self._note_recurrent_gate("spec_decode")
+            if self.config.sp > 1:
+                self._note_recurrent_gate("ring_prefill")
         if mesh is not None and not self.fam.supports_mesh:
             raise ValueError(
                 f"{type(self.fam).__name__} does not support meshes yet; "
@@ -295,7 +316,7 @@ class InferenceEngine:
                     params = self.fam.init_params(spec, key)
                 params = jax.tree.map(jax.device_put, params, shardings)
         elif params is None:
-            params = self.fam.init_params(spec, key)
+            params = _init_params(spec, key)
         self.params = params
 
         # KV storage dtype (ops/quant.py): fp8 pools halve decode HBM
@@ -312,9 +333,14 @@ class InferenceEngine:
         # +1 page: index 0 is the trash page
 
         def init_cache():
+            # a live sequence owns one state row, so one a decode slot
+            rows = (
+                {"state_rows": self.config.max_decode_slots}
+                if self.fam.recurrent else {}
+            )
             return self.fam.init_cache(
                 spec, self.config.num_pages + 1, self.config.page_size,
-                kv_dtype=self.kv_dtype,
+                kv_dtype=self.kv_dtype, **rows,
             )
 
         if born_sharded:
@@ -348,11 +374,18 @@ class InferenceEngine:
             tp=mesh.shape.get("tp", 1) if mesh is not None else 1,
         )
 
+        # pages let go of since the last prefill, whose state rows the
+        # next one frees before it claims its own (_flush_state_releases)
+        self._state_released: collections.deque = collections.deque()
         self.allocator = PageAllocator(
             self.config.num_pages + 1,
             self.config.page_size,
             on_store=self._on_store,
             on_evict=self._on_evict,
+            on_release=(
+                self._state_released.extend if self.fam.recurrent else None
+            ),
+            prefix_cache=self.fam.supports_prefix_reuse,
         )
         self._slots: list[_Slot | None] = [None] * self.config.max_decode_slots
         # the decode burst lengths this engine dispatches — the full
@@ -492,6 +525,18 @@ class InferenceEngine:
         # included, and those launched with a burst in flight, i.e. queued
         # behind device work and not after a drained device
         self.chunked_prefill = {"chunks": 0, "chunks_behind_burst": 0}
+        # what the KDA kernels were asked to do, a layer's worth (always
+        # on, a model with recurrent layers only): state rows a kda_step
+        # call updated, over the dispatched bursts' steps; blocks of
+        # tokens kda_chunk carried a state through, over the prefills
+        self.kda = (
+            {"decode_rows": 0, "prefill_blocks": 0}
+            if self.fam.recurrent else {}
+        )
+        # the state directory's device-side counters [clock, claims, rows
+        # missing]: the last host copy and the one on its way
+        self.state_stats: np.ndarray | None = None
+        self._state_stats_dev = None
         # worker telemetry feeds (engine/telemetry.py EngineCollector):
         # the step thread only appends to bounded deques / bumps ints;
         # the collector turns them into /metrics histograms+counters
@@ -612,6 +657,8 @@ class InferenceEngine:
         # refresh (moe_counters): counts, so ``calls``
         for name, n in self.moe_counters().items():
             snap[f"moe.{name}"] = {"secs": 0.0, "calls": n}
+        for name, n in self.state_counters().items():
+            snap[f"recurrent_state.{name}"] = {"secs": 0.0, "calls": n}
         for family in _COUNTER_FAMILIES:
             for name, n in getattr(self, family).items():
                 snap[f"{family}.{name}"] = {"secs": 0.0, "calls": n}
@@ -646,7 +693,7 @@ class InferenceEngine:
         if hasattr(k, "pools"):  # a pool a layer kind (llama.KindPools)
             full = next(
                 (i for i, kd in enumerate(self.spec.layer_kinds)
-                 if not kd.window), None,
+                 if not kd.window and not kd.recurrent), None,
             )
             if full is None:
                 return None
@@ -668,6 +715,10 @@ class InferenceEngine:
         pages_fetched`` is what the chunk's size wastes."""
         from dynamo_tpu.ops.pallas.fused_decode import live_chunks
 
+        if self.kda:
+            self.kda["decode_rows"] += (
+                int(batch["active"].sum()) * batch["n_burst"]
+            )
         chunk = self._kv_chunk_pages
         if chunk is None:
             return
@@ -718,6 +769,10 @@ class InferenceEngine:
         page = self.config.page_size
         starts = np.asarray(starts, np.int32).reshape(-1, 1)
         nts = np.asarray(nts, np.int32).reshape(-1, 1)
+        if self.kda:
+            from dynamo_tpu.ops.attention import kda_prefill_blocks
+
+            self.kda["prefill_blocks"] += kda_prefill_blocks(nts)
         for kind, window in self._prefill_walks.items():
             if kind == "latent":  # one tile of all the call's rows
                 tq, bp = latent_prefill_tiling(rows, pages, page)
@@ -761,7 +816,7 @@ class InferenceEngine:
         report: dict[str, dict] = {}
 
         def timed(name: str, fn) -> None:
-            c0, s0 = compile_snapshot()
+            c0, s0 = thread_compile_snapshot()
             t0 = time.perf_counter()
             try:
                 if FAULTS.enabled:
@@ -777,12 +832,12 @@ class InferenceEngine:
                             "pays this compile instead", name, e)
                 report[name] = {
                     "secs": round(time.perf_counter() - t0, 3),
-                    "compiles": compile_snapshot()[0] - c0,
+                    "compiles": thread_compile_snapshot()[0] - c0,
                     "error": str(e),
                 }
                 return
             dt = time.perf_counter() - t0
-            c1, s1 = compile_snapshot()
+            c1, s1 = thread_compile_snapshot()
             report[name] = {"secs": round(dt, 3), "compiles": c1 - c0}
             log.info(
                 "precompile %s: %.0f ms (%d compiles, %.0f ms in XLA)",
@@ -797,9 +852,38 @@ class InferenceEngine:
         first_logits: dict[int, jax.Array] = {}  # sample width -> [w, V]
         burst_out: dict[int, jax.Array] = {}  # burst length -> [B, n]
 
+        # first-token sample widths: packed-dispatch fused samples (the
+        # offered pack widths), the single-prompt program (1), and the
+        # stacked admission batch (max_decode_slots) — here on host-built
+        # logits, as the sync admission path feeds them; feed() below
+        # warms the device-fed forms (one program on a single device).
+        # They touch no pool, and a sampler compiles for 6-14 s a width on
+        # a v5e (PR 34): a thread of their own warms them while this one
+        # compiles the model's programs.
+        B = cfg.max_decode_slots
+
+        def sample_widths():
+            for w in sorted({1, B, *self._prefill_shapes.values()}):
+                def sample(w=w):
+                    out = sample_tokens(
+                        jnp.zeros((w, self.spec.vocab_size), jnp.float32),
+                        jnp.zeros((w,), jnp.float32),
+                        jnp.zeros((w,), jnp.int32),
+                        jnp.ones((w,), jnp.float32),
+                        jnp.zeros((w,), jnp.uint32),
+                        jnp.zeros((w,), jnp.int32),
+                    )
+                    jax.block_until_ready(out)
+
+                timed(f"sample[{w}]", sample)
+
+        samplers = threading.Thread(
+            target=sample_widths, name="precompile-samplers"
+        )
+        samplers.start()
+
         # every prefill shape the engine offers (chunked prefill
         # re-enters through the same bucketed shapes)
-        B = cfg.max_decode_slots
         bt1 = jnp.zeros((cfg.max_pages_per_seq,), jnp.int32)
         for bucket, nb in self._prefill_shapes.items():
             def one_prefill(bucket=bucket):
@@ -913,24 +997,7 @@ class InferenceEngine:
 
                     timed(f"verify_masked[{nrows}x{W}]", verify_masked)
 
-        # first-token sample widths: packed-dispatch fused samples (the
-        # offered pack widths), the single-prompt program (1), and the
-        # stacked admission batch (max_decode_slots) — here on host-built
-        # logits, as the sync admission path feeds them; feed() below
-        # warms the device-fed forms (one program on a single device)
-        for w in sorted({1, B, *self._prefill_shapes.values()}):
-            def sample(w=w):
-                out = sample_tokens(
-                    jnp.zeros((w, self.spec.vocab_size), jnp.float32),
-                    jnp.zeros((w,), jnp.float32),
-                    jnp.zeros((w,), jnp.int32),
-                    jnp.ones((w,), jnp.float32),
-                    jnp.zeros((w,), jnp.uint32),
-                    jnp.zeros((w,), jnp.int32),
-                )
-                jax.block_until_ready(out)
-
-            timed(f"sample[{w}]", sample)
+        samplers.join()
 
         # the burst feed path, on the real device results: the first-token
         # sampler on prefill logits, then the feed glue — chain feed and
@@ -989,6 +1056,14 @@ class InferenceEngine:
 
             timed(f"decode_masked[{B}x1]", masked_burst)
 
+        if self.fam.recurrent:
+            def release():
+                self._state_released.append(-1)
+                self._flush_state_releases()
+                jax.block_until_ready(self.k_pages)
+
+            timed("release_state_rows", release)
+
         total = sum(r["secs"] for r in report.values())
         compiles = sum(r["compiles"] for r in report.values())
         misses = sum(1 for r in report.values() if "error" in r)
@@ -1028,6 +1103,76 @@ class InferenceEngine:
             self.moe_counts = np.asarray(self._moe_counts_dev)
         self._moe_counts_dev = jnp.copy(counts)
 
+    def _note_recurrent_gate(self, what: str) -> None:
+        """Count a feature that was asked of a model with recurrent layers
+        and is off for it (``page_offload``, ``page_transfer``,
+        ``spec_decode``, ``ring_prefill``): its pages hold none of the
+        state, so they cannot be moved, reused or rolled back alone."""
+        from dynamo_tpu.ops.fallback import note_fallback
+
+        note_fallback(
+            f"recurrent_no_{what}",
+            detail="a model with recurrent layers keeps state no page holds",
+        )
+
+    def _flush_state_releases(self) -> None:
+        """Free the state rows of the pages released since the last call
+        (a model with recurrent layers): a program of its own, queued
+        behind the bursts that still name those rows and before the
+        prefill that is about to claim one. One shape: the table's width
+        rounded up to a power of two, padded with -1."""
+        if not self._state_released:
+            return
+        width = 1 << max(6, (self.config.max_pages_per_seq - 1).bit_length())
+        pages = []
+        with contextlib.suppress(IndexError):
+            while True:
+                pages.append(self._state_released.popleft())
+        for at in range(0, len(pages), width):
+            chunk = np.full((width,), -1, np.int32)
+            chunk[: len(pages[at: at + width])] = pages[at: at + width]
+            self.k_pages, self.v_pages = self.fam.release_state_rows(
+                self.k_pages, self.v_pages, jnp.asarray(chunk)
+            )
+
+    def _refresh_state_stats(self) -> None:
+        """The state directory's counters to the host, as
+        ``_refresh_moe_counts`` brings the experts': without waiting on
+        the device. A row that went missing makes the run a degraded one:
+        it joins the fallback series."""
+        if not self.fam.recurrent or self._metrics_publishes % 16 != 1:
+            return
+        stats = self.fam.state_stats(self.k_pages, self.v_pages)
+        if self._state_stats_dev is not None:
+            before = self.state_stats
+            self.state_stats = np.asarray(self._state_stats_dev)
+            if self.state_stats[2] > (0 if before is None else before[2]):
+                from dynamo_tpu.ops.fallback import note_fallback
+
+                note_fallback(
+                    "recurrent_state_row_missing",
+                    detail="a sequence's state row was not where its "
+                           "block table says: its output is wrong",
+                )
+        self._state_stats_dev = jnp.copy(stats)
+
+    def state_counters(self) -> dict[str, int]:
+        """A model with recurrent layers: the state rows held, those a
+        live sequence owns now (a decode slot or the open chunked
+        prefill), and the directory's device-side counters as of their
+        last refresh (rows claimed, rows that were missing). Empty
+        otherwise."""
+        if not self.fam.recurrent:
+            return {}
+        stats = self.state_stats if self.state_stats is not None else (0, 0, 0)
+        return {
+            "rows": self.config.max_decode_slots,
+            "rows_live": sum(s is not None for s in self._slots)
+            + (self._partial is not None),
+            "claims": int(stats[1]),
+            "row_missing": int(stats[2]),
+        }
+
     def moe_counters(self) -> dict[str, int]:
         """The counters as of the last refresh, summed over the expert
         layers: by phase (``prefill``, ``decode``) the assignments of
@@ -1051,6 +1196,7 @@ class InferenceEngine:
     def _publish_metrics(self) -> None:
         self._metrics_publishes += 1
         self._refresh_moe_counts()
+        self._refresh_state_stats()
         if self.metrics is not None:
             self.metrics.publish(
                 ForwardPassMetrics(
@@ -1359,6 +1505,11 @@ class InferenceEngine:
                 # classic "why was THIS request slow" suspect, so its
                 # duration (and failure) joins the request's trace
                 with tracing.span("disagg.pull", request_id=context.id):
+                    if not self.fam.supports_page_transfer:
+                        self._note_recurrent_gate("page_transfer")
+                        raise RuntimeError(
+                            "transferred pages carry no recurrent state"
+                        )
                     disagg["_staged_kv"] = await asyncio.to_thread(
                         lambda: pull_kv_blocks(kvp, mesh=self.mesh)
                     )
@@ -2906,6 +3057,7 @@ class InferenceEngine:
                         {"tokens": tokens, "block_tables": bts,
                          "start": starts, "num_tokens": nts},
                     )
+                self._flush_state_releases()
                 with self._launch(
                     "prefill", tokens=sum(p["tail"] for p in group),
                     rows=len(group), ahead=len(self._pipeline),
@@ -3145,10 +3297,13 @@ class InferenceEngine:
                         ],
                     }
                 disagg = waiting.request.get("disagg") or {}
-                if (
-                    (disagg.get("kv_transfer") or {}).get("do_remote_decode")
-                    and self.transfer_source is not None
-                ):
+                remote = (disagg.get("kv_transfer") or {}).get(
+                    "do_remote_decode") and self.transfer_source is not None
+                if remote and not self.fam.supports_page_transfer:
+                    # exported pages would carry no recurrent state: the
+                    # stream is decoded here instead, and that is counted
+                    self._note_recurrent_gate("page_transfer")
+                elif remote:
                     # disagg prefill: stage KV to host, hand off, free pages
                     self._export_and_finish(slot, sp, token_ids, tok, entry)
                     continue
@@ -3505,6 +3660,7 @@ class InferenceEngine:
                 {"start": start, "num_tokens": len(new_tokens)},
                 {"tokens": padded, "block_table": block_table, **mm_arrays},
             )
+        self._flush_state_releases()
         with self._launch(
             "prefill", tokens=len(new_tokens), rows=1,
             ahead=len(self._pipeline),

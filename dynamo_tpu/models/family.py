@@ -5,8 +5,11 @@ small adapter surface — params/cache init, prefill, fused decode, page
 extract/insert — and the adapter maps it onto the family's functional
 core. Two families today:
 
-- ``GqaFamily``: llama/mistral/mixtral/qwen/gpt-oss/MiMo (models/llama.py)
-  — paged K and V pools, GQA attention, the full feature matrix (packed
+- ``GqaFamily``: llama/mistral/mixtral/qwen/gpt-oss/MiMo/Solar-Open2
+  (models/llama.py) — paged K and V pools, GQA attention, and among its
+  layer kinds KDA layers over a recurrent state a sequence
+  (``llama.KindPools``: the state pool and its directory ride the same
+  pair), the full feature matrix (packed
   prefill, ring prefill, meshes, logprobs, embeddings). ``k_pages`` and
   ``v_pages`` are each ONE pytree: an array ``[L, pages, kv_heads, page,
   D]``, a ``QuantPool`` of such (fp8), or, for a model whose layer kinds
@@ -49,6 +52,15 @@ class GqaFamily:
     supports_embeddings = True
     supports_multimodal = True  # prefill embedding injection (EPD)
     supports_spec_decode = True  # prompt-lookup verify (engine/spec.py)
+    # what the pages of a sequence are good for beyond serving it: reuse
+    # under another sequence's prefix, offload to the KVBM tiers, transfer
+    # to another engine (disaggregation, migration pulls, SPMD rejoin). A
+    # model with recurrent layers keeps state that no page holds, so all
+    # three are off for it (``recurrent``), each counted where it is
+    # asked for (engine/core.py: _note_recurrent_gate)
+    supports_prefix_reuse = True
+    supports_page_transfer = True
+    recurrent = False
 
     def __init__(self, spec: Any | None = None):
         from dynamo_tpu.models import llama
@@ -58,6 +70,14 @@ class GqaFamily:
         # specs fall back to chunked prefill for long prompts
         if spec is not None and spec.has_attn_extras:
             self.supports_ring_prefill = False
+        if spec is not None and spec.has_recurrent:
+            self.recurrent = True
+            self.supports_ring_prefill = False  # no state across shards
+            self.supports_spec_decode = False  # no roll-back of a state
+            self.supports_mesh = False  # the state has no sharding yet
+            self.supports_multimodal = False
+            self.supports_prefix_reuse = False
+            self.supports_page_transfer = False
 
     def init_params(self, spec, key):
         return self.m.init_params(spec, key)
@@ -68,10 +88,22 @@ class GqaFamily:
     def cache_shardings(self, mesh, kv_dtype="bf16", spec=None):
         return self.m.cache_shardings(mesh, kv_dtype, spec)
 
-    def init_cache(self, spec, num_pages, page_size, kv_dtype="bf16"):
+    def init_cache(self, spec, num_pages, page_size, kv_dtype="bf16",
+                   state_rows=0):
         return self.m.init_cache(
-            spec, num_pages, page_size, kv_dtype=kv_dtype
+            spec, num_pages, page_size, kv_dtype=kv_dtype,
+            state_rows=state_rows,
         )
+
+    def state_stats(self, k, v):
+        """The state directory's device-side counters ``[clock, claims,
+        rows missing]`` (llama.StateRows; a recurrent model's alone)."""
+        return k.rows.stats[0]
+
+    def release_state_rows(self, k, v, pages):
+        """The cache with the state rows freed whose owner is among
+        ``pages`` (see llama.release_state_rows)."""
+        return self.m.release_state_rows(k, pages), v
 
     def prefill(self, spec, params, tokens, bt, start, k, v, n, mesh=None,
                 mm_embeds=None, mm_pos=None):
@@ -147,6 +179,9 @@ class MlaFamily:
     supports_embeddings = True
     supports_multimodal = False
     supports_spec_decode = True  # prompt-lookup verify (engine/spec.py)
+    supports_prefix_reuse = True
+    supports_page_transfer = True
+    recurrent = False
 
     def __init__(self):
         from dynamo_tpu.models import mla

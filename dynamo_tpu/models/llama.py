@@ -27,6 +27,8 @@ from dynamo_tpu.engine.config import ModelSpec
 from dynamo_tpu.ops.attention import (
     causal_attention,
     decode_update_attention,
+    kda_chunk_prefill,
+    kda_decode_step,
     page_tiles,
     paged_prefill_attention,
 )
@@ -76,14 +78,22 @@ def init_params(spec: ModelSpec, key: jax.Array) -> Params:
     for li in range(spec.num_layers):
         kd = spec.kind(li)
         nkv = kd.num_kv_heads
-        layer = {
-            "attn_norm": jnp.ones((d,), dtype),
-            "wq": dense(next(keys), (d, nh * hd)),
-            "wk": dense(next(keys), (d, nkv * hd)),
-            "wv": dense(next(keys), (d, nkv * vd)),
-            "wo": dense(next(keys), (nh * vd, d)),
-            "mlp_norm": jnp.ones((d,), dtype),
-        }
+        # what a model's newer layers add is drawn on keys of their own
+        # (the matrices' keys stay where they were for every older model)
+        extra = jax.random.split(jax.random.fold_in(key, 2000 + li), 9)
+        if kd.recurrent:
+            layer = _init_kda_layer(spec, dense, keys, extra)
+        else:
+            layer = {
+                "attn_norm": jnp.ones((d,), dtype),
+                "wq": dense(next(keys), (d, nh * hd)),
+                "wk": dense(next(keys), (d, nkv * hd)),
+                "wv": dense(next(keys), (d, nkv * vd)),
+                "wo": dense(next(keys), (nh * vd, d)),
+                "mlp_norm": jnp.ones((d,), dtype),
+            }
+            if spec.attn_gate:
+                layer["w_gate_attn"] = dense(extra[0], (d, nh * vd))
         if spec.attn_bias:
             layer.update(
                 bq=jnp.zeros((nh * hd,), dtype),
@@ -103,6 +113,14 @@ def init_params(spec: ModelSpec, key: jax.Array) -> Params:
             from dynamo_tpu.models import moe
 
             layer["moe"] = moe.init_moe_layer(spec, next(keys))
+            if spec.n_shared_experts:
+                f = spec.moe_intermediate_size * spec.n_shared_experts
+                kg, ku, kdn = jax.random.split(next(keys), 3)
+                layer["shared"] = {
+                    "w_gate": dense(kg, (d, f)),
+                    "w_up": dense(ku, (d, f)),
+                    "w_down": dense(kdn, (f, d)),
+                }
         else:
             layer.update(
                 w_gate=dense(next(keys), (d, spec.intermediate_size)),
@@ -111,6 +129,44 @@ def init_params(spec: ModelSpec, key: jax.Array) -> Params:
             )
         params["layers"].append(layer)
     return params
+
+
+def _init_kda_layer(spec: ModelSpec, dense, keys, extra) -> Params:
+    """A KDA layer's mixer weights (its MLP is drawn by the caller): the
+    four big matrices on the layer's own keys like an attention layer's,
+    the rest on ``extra``. ``a_log`` and ``dt_bias`` are drawn so that the
+    decay a token ``alpha = exp(-exp(a_log) softplus(dt_bias))`` spans
+    (0.9, 0.9999) log-uniformly over the channels before the input's own
+    term ``(x w_f_down) w_f_up`` moves it."""
+    dtype = jnp.dtype(spec.dtype)
+    d, H, D = spec.hidden_size, spec.kda_heads, spec.kda_head_dim
+    r = D  # the decay's and the gate's pairs go through rank head_dim
+    f32 = jnp.float32
+    ka, kt = jax.random.split(extra[5])
+    a = jax.random.uniform(ka, (H,), f32, 0.02, 0.1)
+    tau = jnp.exp(jax.random.uniform(
+        kt, (H, D), f32, jnp.log(1e-4), jnp.log(-jnp.log(0.9))))
+    return {
+        "attn_norm": jnp.ones((d,), dtype),
+        "wq": dense(next(keys), (d, H * D)),
+        "wk": dense(next(keys), (d, H * D)),
+        "wv": dense(next(keys), (d, H * D)),
+        "wo": dense(next(keys), (H * D, d)),
+        "mlp_norm": jnp.ones((d,), dtype),
+        # the short convolutions' taps, [taps, channels] a projection:
+        # N(0, 1 / taps)
+        "conv_q": dense(extra[0], (spec.kda_conv, H * D)),
+        "conv_k": dense(extra[1], (spec.kda_conv, H * D)),
+        "conv_v": dense(extra[2], (spec.kda_conv, H * D)),
+        "w_f_down": dense(extra[3], (d, r)),
+        "w_f_up": dense(extra[4], (r, H * D)),
+        "a_log": jnp.log(a),
+        "dt_bias": jnp.log(jnp.expm1(tau / a[:, None])).reshape(H * D),
+        "w_g_down": dense(extra[6], (d, r)),
+        "w_g_up": dense(extra[7], (r, H * D)),
+        "w_beta": dense(extra[8], (d, H)),
+        "o_norm": jnp.ones((D,), dtype),
+    }
 
 
 def param_shardings(spec: ModelSpec, mesh: Mesh) -> Params:
@@ -166,10 +222,109 @@ class KindPools(NamedTuple):
     held experts a call touched (at least one assignment: it read their
     weights), and the phase's steps (prefill programs; decode model
     steps). Empty
-    (``[L, 2, 0]``) on the V side and where the model has no experts."""
+    (``[L, 2, 0]``) on the V side and where the model has no experts.
+
+    A RECURRENT kind's entry in ``pools`` is not pages: on the K side its
+    layers' states ``[layers of the kind, rows + 1, H, dk, dv]`` float32,
+    on the V side the tails of their short convolutions ``[layers, rows +
+    1, taps - 1, 3 (q, k, v), H D]``, a row a live sequence and a trash
+    row last; ``rows`` (K side) is the directory that finds a sequence's
+    row from its block table (``StateRows``)."""
 
     pools: tuple
     counts: jax.Array
+    rows: Any = None
+
+
+class StateRows(NamedTuple):
+    """Who owns each row of the recurrent kinds' state pools. A sequence
+    is known to a program by its block table and by nothing else, so a
+    row's ``owner`` is the sequence's FIRST PAGE (column 0 of its table; 0
+    = nobody) and the programs find, claim and touch rows on the device:
+
+    - a prefill at ``start_pos`` 0 takes the row that its owner holds
+      already, else a free row, else the least recently used (by
+      ``stamp``, the ``clock`` of a row's last use), and starts from a
+      zero state;
+    - a chunk at ``start_pos`` > 0 and a decode step take the row that
+      matches. One that should match and does not runs on the trash row
+      and is counted (``stats`` missing): its output is wrong;
+    - an empty row of a pack, a table whose first page is the trash page
+      (warm-up) and an inactive slot own nothing and touch nothing.
+
+    The engine frees the rows of the pages it releases
+    (``release_state_rows``), so a claim there always finds a free row;
+    the take-over of the least recently used serves a caller that builds
+    its own tables and releases nothing, for whom every live row is the
+    latest touched. Every leaf leads with an axis of 1, as every leaf of
+    the cache leads with a layer axis."""
+
+    owner: jax.Array  # [1, rows + 1] int32
+    stamp: jax.Array  # [1, rows + 1] int32
+    stats: jax.Array  # [1, 3] int32: clock, claims, rows missing
+
+
+STAT_CLOCK, STAT_CLAIMS, STAT_MISSING = 0, 1, 2
+
+
+def _claim_state_rows(rows: StateRows, owners, starts, live):
+    """Rows for a prefill program's sequences. owners, starts: [N] int32;
+    live: [N] bool (the row has tokens). Returns ``(idx [N], fresh [N],
+    rows)``: each sequence's row (the trash row where it owns none),
+    whether it starts from a zero state, the directory updated. One
+    sequence at a time: two of a pack must not claim one row."""
+    owner, stamp, stats = rows.owner[0], rows.stamp[0], rows.stats[0]
+    R = owner.shape[0] - 1
+    clock = stats[STAT_CLOCK] + 1
+    idx, fresh = [], []
+    for i in range(owners.shape[0]):
+        use = live[i] & (owners[i] > 0)
+        match = owner[:R] == owners[i]
+        found = jnp.any(match)
+        first = starts[i] == 0
+        # free rows first, then the oldest
+        lru = jnp.argmin(jnp.where(owner[:R] == 0, -1, stamp[:R]))
+        ok = use & (found | first)
+        at = jnp.where(ok, jnp.where(found, jnp.argmax(match), lru), R)
+        owner = owner.at[at].set(jnp.where(ok, owners[i], owner[at]))
+        stamp = stamp.at[at].set(jnp.where(ok, clock, stamp[at]))
+        stats = stats.at[STAT_CLAIMS].add((use & first & ~found).astype(jnp.int32))
+        stats = stats.at[STAT_MISSING].add((use & ~ok).astype(jnp.int32))
+        idx.append(at)
+        fresh.append(use & first)
+    stats = stats.at[STAT_CLOCK].set(clock)
+    return (jnp.stack(idx).astype(jnp.int32), jnp.stack(fresh),
+            StateRows(owner[None], stamp[None], stats[None]))
+
+
+def _find_state_rows(rows: StateRows, owners, active):
+    """Rows of a decode program's slots (owners: [B] int32, active: [B]
+    bool), touched; the trash row for a slot that owns none. Returns (idx
+    [B], rows)."""
+    owner, stamp, stats = rows.owner[0], rows.stamp[0], rows.stats[0]
+    R = owner.shape[0] - 1
+    clock = stats[STAT_CLOCK] + 1
+    use = active & (owners > 0)
+    match = owner[None, :R] == owners[:, None]  # [B, R]
+    ok = use & jnp.any(match, axis=1)
+    idx = jnp.where(ok, jnp.argmax(match, axis=1), R).astype(jnp.int32)
+    stamp = stamp.at[idx].set(jnp.where(ok, clock, stamp[idx]))
+    stats = stats.at[STAT_CLOCK].set(clock).at[STAT_MISSING].add(
+        jnp.sum(use & ~ok).astype(jnp.int32))
+    return idx, StateRows(owner[None], stamp[None], stats[None])
+
+
+@partial(jax.jit, donate_argnums=(0,))
+def release_state_rows(k_pages: KindPools, pages: jax.Array) -> KindPools:
+    """Free the state rows whose owner is among ``pages`` ([n] int32, pad
+    with -1): the engine calls it with the pages it released, before the
+    next prefill claims a row. A page that no row's owner is changes
+    nothing, so tail pages and whole sequences go through alike."""
+    rows = k_pages.rows
+    gone = jnp.any(rows.owner[0][:, None] == pages[None, :], axis=1)
+    return k_pages._replace(rows=rows._replace(
+        owner=jnp.where(gone, 0, rows.owner[0])[None]
+    ))
 
 
 COUNT_PREFILL, COUNT_DECODE = 0, 1
@@ -184,6 +339,9 @@ def cache_shardings(
     scales co-located per shard."""
     s = NamedSharding(mesh, P(None, None, "tp", None, None))
     if spec is not None and spec.layer_kinds:
+        if spec.has_recurrent:
+            raise ValueError("a model with recurrent layers runs on one "
+                             "device: its state has no sharding yet")
         side = KindPools(
             tuple(s for _ in spec.layer_kinds), NamedSharding(mesh, P())
         )
@@ -196,7 +354,7 @@ def cache_shardings(
 
 def init_cache(
     spec: ModelSpec, num_pages: int, page_size: int, dtype=None,
-    kv_dtype: str = "bf16",
+    kv_dtype: str = "bf16", state_rows: int = 0,
 ) -> tuple[jax.Array, jax.Array]:
     """K and V page arrays [L, num_pages, kv_heads, page_size, head_dim].
 
@@ -210,7 +368,9 @@ def init_cache(
 
     K pools are ``head_dim`` wide and V pools ``v_dim``, each rounded up
     to the 128-lane tile on the chip (pool_head_dim). A model with layer
-    kinds gets a ``KindPools`` a side: a pool a kind.
+    kinds gets a ``KindPools`` a side: a pool a kind; a recurrent kind's
+    "pool" is ``state_rows`` rows of state (and a trash row), see
+    ``KindPools``.
 
     ``kv_dtype="fp8"`` allocates QuantPools instead (ops/quant.py): fp8
     values + bf16 per-page/head scales — half the HBM footprint and half
@@ -252,15 +412,34 @@ def init_cache(
         raise ValueError("kv_dtype=fp8 has no pools by layer kind yet")
     n_layers = [spec.layer_pattern.count(i) for i in range(len(spec.layer_kinds))]
     n_counts = spec.experts_here[0] + 3 if spec.num_experts else 0
+    R1 = state_rows + 1  # the last row is the trash row
+    H, D = spec.kda_heads, spec.kda_head_dim
+
+    def k_side(n, kd):
+        if kd.recurrent:
+            return jnp.zeros((n, R1, H, D, D), jnp.float32)
+        return side(n, kd.num_kv_heads, spec.head_dim)
+
+    def v_side(n, kd):
+        if kd.recurrent:
+            return jnp.zeros((n, R1, spec.kda_conv - 1, 3, H * D), dtype)
+        return side(n, kd.num_kv_heads, spec.v_dim)
+
+    rows = None
+    if spec.has_recurrent:
+        if state_rows < 1:
+            raise ValueError("a model with recurrent layers needs state_rows")
+        rows = StateRows(
+            jnp.zeros((1, R1), jnp.int32), jnp.zeros((1, R1), jnp.int32),
+            jnp.zeros((1, 3), jnp.int32),
+        )
     return (
         KindPools(
-            tuple(side(n, kd.num_kv_heads, spec.head_dim)
-                  for n, kd in zip(n_layers, spec.layer_kinds)),
-            jnp.zeros((spec.num_layers, 2, n_counts), jnp.int32),
+            tuple(k_side(n, kd) for n, kd in zip(n_layers, spec.layer_kinds)),
+            jnp.zeros((spec.num_layers, 2, n_counts), jnp.int32), rows,
         ),
         KindPools(
-            tuple(side(n, kd.num_kv_heads, spec.v_dim)
-                  for n, kd in zip(n_layers, spec.layer_kinds)),
+            tuple(v_side(n, kd) for n, kd in zip(n_layers, spec.layer_kinds)),
             jnp.zeros((spec.num_layers, 2, 0), jnp.int32),
         ),
     )
@@ -441,7 +620,7 @@ def _scope(name: str | None):
 
 
 def attn_scope(spec: ModelSpec, li: int) -> str | None:
-    if not spec.has_attn_extras:
+    if not (spec.has_attn_extras or spec.has_recurrent):
         return None
     return SCOPE_ATTN_WINDOW if spec.kind(li).window else SCOPE_ATTN_FULL
 
@@ -465,13 +644,24 @@ def _attn_qkv(
     v = v.reshape(*lead, kd.num_kv_heads, spec.v_dim)
     if spec.value_scale != 1.0:
         v = v * jnp.asarray(spec.value_scale, v.dtype)
-    q = rope_spec(spec, q, positions, kd.rope_theta)
-    k = rope_spec(spec, k, positions, kd.rope_theta)
+    if spec.use_rope:
+        q = rope_spec(spec, q, positions, kd.rope_theta)
+        k = rope_spec(spec, k, positions, kd.rope_theta)
     return q, k, v
 
 
 @jax.named_scope(SCOPE_OUT)
-def _o_proj(spec: ModelSpec, lp: Params, attn: jax.Array) -> jax.Array:
+def _o_proj(
+    spec: ModelSpec, lp: Params, attn: jax.Array,
+    h: jax.Array | None = None,
+) -> jax.Array:
+    """The output projection; where the layer gates its attention output
+    (``w_gate_attn``), by element with a sigmoid of the layer's normed
+    input ``h`` first."""
+    if "w_gate_attn" in lp:
+        attn = attn * jax.nn.sigmoid(
+            (h @ lp["w_gate_attn"]).astype(jnp.float32)
+        ).astype(attn.dtype)
     out = attn @ lp["wo"]
     return out + lp["bo"] if spec.attn_bias else out
 
@@ -491,7 +681,13 @@ def _ffn(
     if "moe" in lp:
         from dynamo_tpu.models import moe
 
-        return moe.moe_mlp(spec, lp["moe"], x, mesh=mesh, counted=counted)
+        out = moe.moe_mlp(spec, lp["moe"], x, mesh=mesh, counted=counted)
+        if "shared" in lp:
+            # the shared expert, whole: every chip of an expert-parallel
+            # deployment computes it for its own tokens
+            y = _mlp(lp["shared"], x)
+            out = out + y if counted is None else (out[0] + y, out[1])
+        return out
     return _mlp(lp, x)
 
 
@@ -541,6 +737,130 @@ def _logits(spec: ModelSpec, params: Params, x: jax.Array) -> jax.Array:
     x = rms_norm(x, params["final_norm"], spec.rms_eps)
     head = params["embed"].T if spec.tie_embeddings else params["lm_head"]
     return (x @ head).astype(jnp.float32)
+
+
+# ------------------------------------------------------------------- KDA
+
+
+def _kda_inputs(spec: ModelSpec, lp: Params, h: jax.Array, tail: jax.Array):
+    """A KDA layer's operands from its normed input. h: [N, T, d]; tail:
+    [N, taps - 1, 3 H D], the q | k | v projections of the ``taps - 1``
+    tokens before (zeros at a sequence's start). Returns (q, k, v, g [N,
+    T, H, D] float32, beta [N, T, H] float32, ext [N, taps - 1 + T, 3 H
+    D]: the projections with the tail in front, of which the caller keeps
+    the new tail)."""
+    f32 = jnp.float32
+    N, T, _ = h.shape
+    H, D = spec.kda_heads, spec.kda_head_dim
+    x = jnp.concatenate([h @ lp["wq"], h @ lp["wk"], h @ lp["wv"]], axis=-1)
+    ext = jnp.concatenate([tail.astype(x.dtype), x], axis=1)
+    taps = jnp.concatenate(
+        [lp["conv_q"], lp["conv_k"], lp["conv_v"]], axis=1
+    ).astype(f32)
+    conv = sum(
+        taps[i] * ext[:, i:i + T].astype(f32) for i in range(spec.kda_conv)
+    )
+    q, k, v = (
+        y.reshape(N, T, H, D)
+        for y in jnp.split(jax.nn.silu(conv), 3, axis=-1)
+    )
+    q = q * jax.lax.rsqrt(jnp.sum(q * q, -1, keepdims=True) + 1e-6) * D ** -0.5
+    k = k * jax.lax.rsqrt(jnp.sum(k * k, -1, keepdims=True) + 1e-6)
+    f = ((h @ lp["w_f_down"]) @ lp["w_f_up"]).astype(f32) + lp["dt_bias"]
+    g = -jnp.exp(lp["a_log"])[:, None] * jax.nn.softplus(f).reshape(N, T, H, D)
+    beta = jax.nn.sigmoid((h @ lp["w_beta"]).astype(f32))
+    if spec.kda_neg_eigval:
+        beta = 2.0 * beta
+    return q, k, v, g, beta, ext
+
+
+@jax.named_scope(SCOPE_OUT)
+def _kda_out(spec: ModelSpec, lp: Params, o: jax.Array, h: jax.Array):
+    """o: [..., H, D] float32 -> the layer's output [..., d]: RMSNorm a
+    head, the sigmoid gate of the layer's input, the output projection."""
+    var = jnp.mean(o * o, axis=-1, keepdims=True)
+    o = (o * jax.lax.rsqrt(var + spec.rms_eps)).astype(h.dtype) * lp["o_norm"]
+    gate = jax.nn.sigmoid(
+        ((h @ lp["w_g_down"]) @ lp["w_g_up"]).astype(jnp.float32)
+    ).astype(h.dtype)
+    return (o.reshape(*h.shape[:-1], -1) * gate) @ lp["wo"]
+
+
+def ext_width(c_pool) -> int:
+    """Channels of a convolution tail, q | k | v side by side: the tails'
+    pool keeps them ``[..., taps - 1, 3, H D]``, a head block's channels of
+    each apart, so that the decode kernel writes a block a program."""
+    return c_pool.shape[-2] * c_pool.shape[-1]
+
+
+def _kda_prefill(
+    spec: ModelSpec, lp: Params, h: jax.Array, s_pool, c_pool, lj: int,
+    idx: jax.Array, fresh: jax.Array, num_tokens: jax.Array,
+):
+    """A KDA layer over N sequences' new tokens, from and to their state
+    rows. h: [N, T, d]; idx, fresh, num_tokens: [N]. Returns (out [N, T,
+    d], s_pool, c_pool)."""
+    N, T, _ = h.shape
+    tail = jnp.where(
+        fresh[:, None, None], 0, c_pool[lj, idx].reshape(N, -1, ext_width(c_pool))
+    )
+    with jax.named_scope(SCOPE_QKV):
+        q, k, v, g, beta, ext = _kda_inputs(spec, lp, h, tail)
+    # a padded token leaves the state as it was
+    real = jnp.arange(T)[None, :] < num_tokens[:, None]
+    g = jnp.where(real[..., None, None], g, 0.0)
+    beta = jnp.where(real[..., None], beta, 0.0)
+    with jax.named_scope(SCOPE_KV):
+        o, s_pool = kda_chunk_prefill(
+            q, k, v, g, beta, s_pool, idx, fresh, layer=lj
+        )
+        # the new tail: the projections of the last taps - 1 REAL tokens
+        new_tail = jax.vmap(
+            lambda e, n: jax.lax.dynamic_slice_in_dim(
+                e, n, spec.kda_conv - 1, axis=0)
+        )(ext, num_tokens)
+        c_pool = c_pool.at[lj, idx].set(
+            new_tail.reshape(N, *c_pool.shape[2:]).astype(c_pool.dtype)
+        )
+    return _kda_out(spec, lp, o, h), s_pool, c_pool
+
+
+def _kda_decode(
+    spec: ModelSpec, lp: Params, h: jax.Array, s_pool, c_pool, lj: int,
+    idx: jax.Array,
+):
+    """A KDA layer's decode step over the slots' state rows. h: [B, d];
+    idx: [B] (the trash row for a slot that owns none). Returns (out [B,
+    d], s_pool, c_pool)."""
+    B = h.shape[0]
+    with jax.named_scope(SCOPE_QKV):
+        q, k, v, g, beta, ext = _kda_inputs(
+            spec, lp, h[:, None], c_pool[lj, idx].reshape(B, -1, ext_width(c_pool))
+        )
+    with jax.named_scope(SCOPE_KV):
+        o, s_pool, c_pool = kda_decode_step(
+            s_pool, c_pool, idx, q[:, 0], k[:, 0], v[:, 0], g[:, 0],
+            beta[:, 0], ext[:, 1:].reshape(B, *c_pool.shape[2:]), layer=lj,
+        )
+    return _kda_out(spec, lp, o, h), s_pool, c_pool
+
+
+def _kda_whole(spec: ModelSpec, lp: Params, h: jax.Array) -> jax.Array:
+    """A KDA layer over one whole sequence from an empty state, keeping
+    none (embeddings, ``reference_forward``). h: [T, d] -> [T, d]."""
+    H, D = spec.kda_heads, spec.kda_head_dim
+    tail = jnp.zeros((1, spec.kda_conv - 1, 3 * H * D), h.dtype)
+    q, k, v, g, beta, _ = _kda_inputs(spec, lp, h[None], tail)
+    o, _ = kda_chunk_prefill(
+        q, k, v, g, beta, jnp.zeros((1, 2, H, D, D), jnp.float32),
+        jnp.zeros((1,), jnp.int32), jnp.ones((1,), bool), layer=0,
+    )
+    return _kda_out(spec, lp, o[0], h)
+
+
+def _state_owner(block_tables: jax.Array) -> jax.Array:
+    """The id a sequence's state row is kept under: its first page."""
+    return block_tables[..., 0].astype(jnp.int32)
 
 
 # ---------------------------------------------------------------- prefill
@@ -595,20 +915,33 @@ def prefill_forward_impl(
     if mm_embeds is not None:
         x = x.at[mm_pos].set(mm_embeds.astype(x.dtype), mode="drop")
     kv_len = start_pos + num_tokens
+    if spec.has_recurrent:
+        idx, fresh, rows = _claim_state_rows(
+            k_pages.rows, _state_owner(block_table)[None], start_pos[None],
+            (num_tokens > 0)[None],
+        )
+        k_pages = k_pages._replace(rows=rows)
 
     for li, lp in enumerate(params["layers"]):
         h = rms_norm(x, lp["attn_norm"], spec.rms_eps)
-        q, k, v = _attn_qkv(spec, li, lp, h, positions)
         kp, vp, lj = _layer_pools(spec, k_pages, v_pages, li)
-        with jax.named_scope(SCOPE_KV):
-            kp = _set_page_tiles(kp, lj, safe_pg, k, page_size, valid_tok)
-            vp = _set_page_tiles(vp, lj, safe_pg, v, page_size, valid_tok)
-            attn = _ctx_attention(
-                spec, li, lp, q, k, v, kp, vp, lj, block_table, positions,
-                kv_len,
+        if spec.kind(li).recurrent:
+            mix, kp, vp = _kda_prefill(
+                spec, lp, h[None], kp, vp, lj, idx, fresh, num_tokens[None]
             )
+            mix = mix[0]
+        else:
+            q, k, v = _attn_qkv(spec, li, lp, h, positions)
+            with jax.named_scope(SCOPE_KV):
+                kp = _set_page_tiles(kp, lj, safe_pg, k, page_size, valid_tok)
+                vp = _set_page_tiles(vp, lj, safe_pg, v, page_size, valid_tok)
+                attn = _ctx_attention(
+                    spec, li, lp, q, k, v, kp, vp, lj, block_table,
+                    positions, kv_len,
+                )
+            mix = _o_proj(spec, lp, attn.reshape(T, -1), h)
         k_pages, v_pages = _put_pools(spec, k_pages, v_pages, li, kp, vp)
-        x = x + _o_proj(spec, lp, attn.reshape(T, -1))
+        x = x + mix
         h = rms_norm(x, lp["mlp_norm"], spec.rms_eps)
         f, k_pages = _ffn_counting(
             spec, li, lp, h, k_pages, COUNT_PREFILL, real, mesh
@@ -619,6 +952,17 @@ def prefill_forward_impl(
     logits = _logits(spec, params, x[last])  # [V]
     logits = _replicate(logits, mesh)
     return logits, k_pages, v_pages, _no_drops(mesh)
+
+
+def _no_recurrent(spec: ModelSpec, what: str) -> None:
+    """Programs that have no recurrent form: a state cannot be split
+    across sequence shards, nor rolled back past rejected drafts. The
+    engine never reaches them for such a model (family.GqaFamily's
+    ``supports_*``); a direct caller is told."""
+    if spec.has_recurrent:
+        raise NotImplementedError(
+            f"{what}: no form for a model with recurrent (KDA) layers"
+        )
 
 
 def _no_drops(mesh: Mesh | None) -> jax.Array:
@@ -685,23 +1029,35 @@ def prefill_forward_batch_impl(
 
     x = params["embed"][tokens]  # [N, T, d]
     kv_len = start_pos + num_tokens  # [N]
+    if spec.has_recurrent:
+        idx, fresh, rows = _claim_state_rows(
+            k_pages.rows, _state_owner(block_tables), start_pos,
+            num_tokens > 0,
+        )
+        k_pages = k_pages._replace(rows=rows)
 
     for li, lp in enumerate(params["layers"]):
         h = rms_norm(x, lp["attn_norm"], spec.rms_eps)
-        q, k, v = _attn_qkv(spec, li, lp, h, positions)
         kp, vp, lj = _layer_pools(spec, k_pages, v_pages, li)
-        with jax.named_scope(SCOPE_KV):
-            kp = _set_page_tiles(kp, lj, safe_pg, k, page_size, valid_tok)
-            vp = _set_page_tiles(vp, lj, safe_pg, v, page_size, valid_tok)
-            attn = jax.vmap(
-                lambda q_i, k_i, v_i, bt_i, pos_i, kvl_i, kp=kp, vp=vp,
-                li=li, lp=lp, lj=lj: _ctx_attention(
-                    spec, li, lp, q_i, k_i, v_i, kp, vp, lj, bt_i, pos_i,
-                    kvl_i,
-                )
-            )(q, k, v, block_tables, positions, kv_len)
+        if spec.kind(li).recurrent:
+            mix, kp, vp = _kda_prefill(
+                spec, lp, h, kp, vp, lj, idx, fresh, num_tokens
+            )
+        else:
+            q, k, v = _attn_qkv(spec, li, lp, h, positions)
+            with jax.named_scope(SCOPE_KV):
+                kp = _set_page_tiles(kp, lj, safe_pg, k, page_size, valid_tok)
+                vp = _set_page_tiles(vp, lj, safe_pg, v, page_size, valid_tok)
+                attn = jax.vmap(
+                    lambda q_i, k_i, v_i, bt_i, pos_i, kvl_i, kp=kp, vp=vp,
+                    li=li, lp=lp, lj=lj: _ctx_attention(
+                        spec, li, lp, q_i, k_i, v_i, kp, vp, lj, bt_i, pos_i,
+                        kvl_i,
+                    )
+                )(q, k, v, block_tables, positions, kv_len)
+            mix = _o_proj(spec, lp, attn.reshape(N, T, -1), h)
         k_pages, v_pages = _put_pools(spec, k_pages, v_pages, li, kp, vp)
-        x = x + _o_proj(spec, lp, attn.reshape(N, T, -1))
+        x = x + mix
         h = rms_norm(x, lp["mlp_norm"], spec.rms_eps)
         f, k_pages = _ffn_counting(
             spec, li, lp, h.reshape(N * T, -1), k_pages, COUNT_PREFILL,
@@ -743,6 +1099,7 @@ def prefill_forward_ring_impl(
     """
     from dynamo_tpu.parallel.ring import ring_attention
 
+    _no_recurrent(spec, "ring prefill")
     T = tokens.shape[0]
     idx = jnp.arange(T)
     page_size = page_size_of(k_pages)
@@ -766,7 +1123,7 @@ def prefill_forward_ring_impl(
         vp = _set_page_tiles(vp, lj, safe_pg, v, page_size, valid_tok)
         k_pages, v_pages = _put_pools(spec, k_pages, v_pages, li, kp, vp)
         attn = ring_attention(q, k, v, mesh=mesh)
-        x = x + _o_proj(spec, lp, attn.reshape(T, -1))
+        x = x + _o_proj(spec, lp, attn.reshape(T, -1), h)
         h = rms_norm(x, lp["mlp_norm"], spec.rms_eps)
         x = x + _ffn(spec, lp, h, mesh=mesh)
         x = jax.lax.with_sharding_constraint(x, sp_spec)
@@ -821,6 +1178,7 @@ def verify_forward_impl(
     """
     from dynamo_tpu.ops.pallas.kv_write import write_new_kv
 
+    _no_recurrent(spec, "speculative verify")
     N, W = tokens.shape
     page_size = page_size_of(k_pages)
     idx = jnp.arange(W)
@@ -867,7 +1225,7 @@ def verify_forward_impl(
             )
         )(q, k, v, block_tables, positions, kv_len)
         k_pages, v_pages = _put_pools(spec, k_pages, v_pages, li, kp, vp)
-        x = x + _o_proj(spec, lp, attn.reshape(N, W, -1))
+        x = x + _o_proj(spec, lp, attn.reshape(N, W, -1), h)
         h = rms_norm(x, lp["mlp_norm"], spec.rms_eps)
         x = x + _ffn(
             spec, lp, h.reshape(N * W, -1), mesh=mesh
@@ -902,9 +1260,16 @@ def decode_forward_impl(
     v_pages: jax.Array,
     active: jax.Array,  # [B] bool: slot has a live request
     mesh: Mesh | None = None,  # static: routes attention through shard_map
+    state_idx: jax.Array | None = None,  # [B]: the slots' state rows, where
+    # the caller found them already (a burst finds them once)
 ) -> tuple[jax.Array, jax.Array, jax.Array]:
     """One decode step for the whole slot batch; returns (logits[B,V], k, v)."""
     B = tokens.shape[0]
+    if spec.has_recurrent and state_idx is None:
+        state_idx, rows = _find_state_rows(
+            k_pages.rows, _state_owner(block_tables), active
+        )
+        k_pages = k_pages._replace(rows=rows)
     page_size = page_size_of(k_pages)
     positions = seq_lens - 1  # position of the new token
 
@@ -918,21 +1283,25 @@ def decode_forward_impl(
 
     for li, lp in enumerate(params["layers"]):
         h = rms_norm(x, lp["attn_norm"], spec.rms_eps)
-        q, k, v = _attn_qkv(spec, li, lp, h, positions)
         kp, vp, lj = _layer_pools(spec, k_pages, v_pages, li)
-        # KV append + paged attention in ONE kernel per layer on the
-        # Pallas path (ops/pallas/fused_decode.py — halves the decode
-        # program's kernel-launch count); scatter + gather attention
-        # elsewhere (ops/attention.decode_update_attention dispatch)
-        with jax.named_scope(SCOPE_KV):
-            attn, kp, vp = decode_update_attention(
-                q, kp, vp, k, v, block_tables, seq_lens,
-                safe_page, offset, layer=lj, mesh=mesh,
-                window=spec.kind(li).window, sinks=lp.get("sinks"),
-                scope=attn_scope(spec, li),
-            )
+        if spec.kind(li).recurrent:
+            mix, kp, vp = _kda_decode(spec, lp, h, kp, vp, lj, state_idx)
+        else:
+            q, k, v = _attn_qkv(spec, li, lp, h, positions)
+            # KV append + paged attention in ONE kernel per layer on the
+            # Pallas path (ops/pallas/fused_decode.py — halves the decode
+            # program's kernel-launch count); scatter + gather attention
+            # elsewhere (ops/attention.decode_update_attention dispatch)
+            with jax.named_scope(SCOPE_KV):
+                attn, kp, vp = decode_update_attention(
+                    q, kp, vp, k, v, block_tables, seq_lens,
+                    safe_page, offset, layer=lj, mesh=mesh,
+                    window=spec.kind(li).window, sinks=lp.get("sinks"),
+                    scope=attn_scope(spec, li),
+                )
+            mix = _o_proj(spec, lp, attn.reshape(B, -1), h)
         k_pages, v_pages = _put_pools(spec, k_pages, v_pages, li, kp, vp)
-        x = x + _o_proj(spec, lp, attn.reshape(B, -1))
+        x = x + mix
         h = rms_norm(x, lp["mlp_norm"], spec.rms_eps)
         f, k_pages = _ffn_counting(
             spec, li, lp, h, k_pages, COUNT_DECODE, active, mesh
@@ -994,11 +1363,20 @@ def decode_steps_impl(
     lp0 = jnp.zeros((B, n_steps), jnp.float32)
     ti0 = jnp.zeros((B, n_steps, max(n_logprobs, 1)), jnp.int32)
     tv0 = jnp.zeros((B, n_steps, max(n_logprobs, 1)), jnp.float32)
+    state_idx = None
+    if spec.has_recurrent:
+        # a burst's tables do not change: its slots' state rows are found
+        # (and touched) once
+        state_idx, rows = _find_state_rows(
+            k_pages.rows, _state_owner(block_tables), active
+        )
+        k_pages = k_pages._replace(rows=rows)
 
     def body(i, carry):
         toks, lens, kp, vp, out, lp, ti, tv = carry
         logits, kp, vp = decode_forward_impl(
-            spec, params, toks, block_tables, lens, kp, vp, active, mesh=mesh
+            spec, params, toks, block_tables, lens, kp, vp, active, mesh=mesh,
+            state_idx=state_idx,
         )
         if allowed is not None:
             logits = jnp.where(allowed, logits, -1e30)
@@ -1100,6 +1478,20 @@ insert_kv_pages = jax.jit(_insert_kv_pages_impl, donate_argnums=(0, 1))
 # ------------------------------------------------------------- embeddings
 
 
+def _whole_mixer(spec: ModelSpec, li: int, lp: Params, h, positions, n):
+    """Layer ``li``'s mixer over one whole sequence with no cache (h: [T,
+    d]; ``n`` real tokens): plain causal attention, or KDA from an empty
+    state (a padded tail cannot reach a real token either way)."""
+    if spec.kind(li).recurrent:
+        return _kda_whole(spec, lp, h)
+    q, k, v = _attn_qkv(spec, li, lp, h, positions)
+    attn = causal_attention(
+        q, k, v, positions, n,
+        window=spec.kind(li).window, sinks=lp.get("sinks"),
+    )
+    return _o_proj(spec, lp, attn.reshape(h.shape[0], -1), h)
+
+
 def embed_forward_impl(
     spec: ModelSpec,
     params: Params,
@@ -1116,12 +1508,7 @@ def embed_forward_impl(
     x = params["embed"][tokens]
     for li, lp in enumerate(params["layers"]):
         h = rms_norm(x, lp["attn_norm"], spec.rms_eps)
-        q, k, v = _attn_qkv(spec, li, lp, h, positions)
-        attn = causal_attention(
-            q, k, v, positions, num_tokens,
-            window=spec.kind(li).window, sinks=lp.get("sinks"),
-        )
-        x = x + _o_proj(spec, lp, attn.reshape(T, -1))
+        x = x + _whole_mixer(spec, li, lp, h, positions, num_tokens)
         h = rms_norm(x, lp["mlp_norm"], spec.rms_eps)
         x = x + _ffn(spec, lp, h)
     xn = rms_norm(x, params["final_norm"], spec.rms_eps).astype(jnp.float32)
@@ -1146,12 +1533,7 @@ def reference_forward(
     x = params["embed"][tokens]
     for li, lp in enumerate(params["layers"]):
         h = rms_norm(x, lp["attn_norm"], spec.rms_eps)
-        q, k, v = _attn_qkv(spec, li, lp, h, positions)
-        attn = causal_attention(
-            q, k, v, positions, jnp.asarray(T),
-            window=spec.kind(li).window, sinks=lp.get("sinks"),
-        )
-        x = x + _o_proj(spec, lp, attn.reshape(T, -1))
+        x = x + _whole_mixer(spec, li, lp, h, positions, jnp.asarray(T))
         h = rms_norm(x, lp["mlp_norm"], spec.rms_eps)
         x = x + _ffn(spec, lp, h)
     xn = rms_norm(x, params["final_norm"], spec.rms_eps)

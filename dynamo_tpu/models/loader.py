@@ -116,6 +116,35 @@ def spec_from_hf_config(cfg: dict, name: str | None = None) -> ModelSpec:
                 ),
                 norm_topk_prob=bool(cfg.get("norm_topk_prob", True)),
             )
+    if model_type == "solar_open2":
+        # KDA layers among gated NoPE GQA layers (``gqa_layers`` lists the
+        # softmax ones), sigmoid routing with a correction bias in every
+        # layer, a shared expert
+        from dynamo_tpu.engine.config import LayerKind
+
+        lin = cfg["linear_attn_config"]
+        gqa = set(cfg["gqa_layers"])
+        if cfg.get("kda_use_full_proj"):
+            raise NotImplementedError("kda_use_full_proj: low rank only")
+        extras.update(
+            layer_kinds=(
+                LayerKind(int(cfg["num_key_value_heads"]),
+                          float(cfg.get("rope_theta", 10000.0))),
+                LayerKind(0, 0.0, mixer="kda"),
+            ),
+            layer_pattern=tuple(
+                0 if i in gqa else 1
+                for i in range(int(cfg["num_hidden_layers"]))
+            ),
+            use_rope=bool(cfg.get("use_rope", False)),
+            attn_gate=bool(cfg.get("use_gqa_gate", False)),
+            kda_heads=int(lin["num_heads"]), kda_head_dim=int(lin["head_dim"]),
+            kda_conv=int(lin["short_conv_kernel_size"]),
+            kda_neg_eigval=bool(cfg.get("kda_allow_neg_eigval", False)),
+            moe_scoring="sigmoid",
+            routed_scaling_factor=float(cfg.get("routed_scaling_factor") or 1.0),
+            norm_topk_prob=bool(cfg.get("norm_topk_prob", True)),
+        )
     # YaRN rope scaling (gpt-oss, DeepSeek-R1)
     rs = cfg.get("rope_scaling") or {}
     if (rs.get("rope_type") or rs.get("type")) == "yarn":
@@ -170,6 +199,8 @@ def hf_config_from_spec(spec: ModelSpec) -> dict:
     checkpoint silently loses features on reload."""
     if spec.kv_lora_rank:
         model_type = "deepseek_v3"
+    elif spec.has_recurrent:
+        model_type = "solar_open2"
     elif spec.attn_sinks:
         model_type = "gpt_oss"
     elif spec.num_experts:
@@ -199,6 +230,27 @@ def hf_config_from_spec(spec: ModelSpec) -> dict:
         cfg["num_local_experts"] = spec.num_experts
         cfg["num_experts_per_tok"] = spec.num_experts_per_token
         cfg["moe_intermediate_size"] = spec.moe_intermediate_size
+    if model_type == "solar_open2":
+        cfg.update(
+            intermediate_size=spec.intermediate_size,
+            n_routed_experts=spec.num_experts,
+            n_shared_experts=spec.n_shared_experts,
+            first_k_dense_replace=spec.first_k_dense,
+            routed_scaling_factor=spec.routed_scaling_factor,
+            norm_topk_prob=spec.norm_topk_prob,
+            use_rope=spec.use_rope, use_gqa_gate=spec.attn_gate,
+            gqa_layers=[
+                i for i in range(spec.num_layers)
+                if not spec.kind(i).recurrent
+            ],
+            linear_attn_config={
+                "short_conv_kernel_size": spec.kda_conv,
+                "head_dim": spec.kda_head_dim, "num_heads": spec.kda_heads,
+                "num_kv_heads": None,
+            },
+            kda_use_full_proj=False, kda_allow_neg_eigval=spec.kda_neg_eigval,
+        )
+        del cfg["num_local_experts"]
     if model_type == "gpt_oss":
         cfg.update(
             sliding_window=spec.sliding_window,
@@ -349,6 +401,28 @@ def _dest_map(
         for hf, ours in (("q_proj", "wq"), ("k_proj", "wk"),
                          ("v_proj", "wv"), ("o_proj", "wo")):
             m[p + f"self_attn.{hf}.weight"] = (li + (ours,), True, None)
+        if spec.kind(i).recurrent:
+            # a KDA layer's own tensors (the names of the public
+            # flash-linear-attention KDA module). The taps are stored
+            # [channels, 1, taps]: load_params folds the middle axis
+            a = p + "self_attn."
+            for hf, ours, tr, dt in (
+                ("q_conv1d.weight", "conv_q", True, None),
+                ("k_conv1d.weight", "conv_k", True, None),
+                ("v_conv1d.weight", "conv_v", True, None),
+                ("f_a_proj.weight", "w_f_down", True, None),
+                ("f_b_proj.weight", "w_f_up", True, None),
+                ("g_a_proj.weight", "w_g_down", True, None),
+                ("g_b_proj.weight", "w_g_up", True, None),
+                ("b_proj.weight", "w_beta", True, None),
+                ("A_log", "a_log", False, "float32"),
+                ("dt_bias", "dt_bias", False, "float32"),
+                ("o_norm.weight", "o_norm", False, None),
+            ):
+                m[a + hf] = (li + (ours,), tr, dt)
+        elif spec.attn_gate:
+            m[p + "self_attn.g_proj.weight"] = (
+                li + ("w_gate_attn",), True, None)
         if spec.attn_bias:
             for hf, ours in (("q_proj", "bq"), ("k_proj", "bk"),
                              ("v_proj", "bv"), ("o_proj", "bo")):
@@ -372,6 +446,14 @@ def _dest_map(
                     m[ep + "gate_proj.weight"] = (li + ("moe", "w_gate", e), True, None)
                     m[ep + "up_proj.weight"] = (li + ("moe", "w_up", e), True, None)
                     m[ep + "down_proj.weight"] = (li + ("moe", "w_down", e), True, None)
+                if spec.moe_scoring == "sigmoid":
+                    m[mp + "gate.e_score_correction_bias"] = (
+                        li + ("moe", "score_bias"), False, "float32")
+                for hf, ours in (("gate_proj", "w_gate"), ("up_proj", "w_up"),
+                                 ("down_proj", "w_down")):
+                    if spec.n_shared_experts:
+                        m[mp + f"shared_experts.{hf}.weight"] = (
+                            li + ("shared", ours), True, None)
             else:  # gpt_oss: router here; fused experts in load_params
                 m[p + "mlp.router.weight"] = (li + ("moe", "router"), True, "float32")
                 if spec.moe_bias:
@@ -529,6 +611,8 @@ def load_params(
                     continue
                 path, transpose, dt_override = dest[name]
                 arr = f.get_tensor(name)
+                if arr.ndim == 3 and str(path[-1]).startswith("conv_"):
+                    arr = arr.reshape(arr.shape[0], -1)  # [C, 1, taps]
                 if transpose:
                     arr = np.ascontiguousarray(arr.T)
                 if spec.kv_lora_rank and spec.rope_interleave:
@@ -635,6 +719,8 @@ def save_params(
         dest = _dest_map(
             spec, names={"model.layers.0.mlp.experts.gate_up_proj"}
         )
+    elif spec.has_recurrent:
+        dest = _dest_map(spec, names={"model.layers.0.mlp.experts.0."})
     else:
         dest = _dest_map(spec)
     tensors: dict[str, np.ndarray] = {}
@@ -645,6 +731,8 @@ def save_params(
             arr = np.asarray(_tree_get(params, path))
         if transpose:
             arr = np.ascontiguousarray(arr.T)
+        if str(path[-1]).startswith("conv_"):
+            arr = arr[:, None, :]  # the published [channels, 1, taps]
         tensors[name] = arr
     if spec.moe_bias and not spec.kv_lora_rank:
         # gpt-oss fused expert tensors: re-interleave gate/up (weights

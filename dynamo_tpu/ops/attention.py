@@ -842,3 +842,208 @@ def latent_prefill_attention(
     # a padded member of a pack visits nothing: l is 0 there
     out = acc / jnp.where(l == 0.0, 1.0, l)[..., None]
     return out.transpose(1, 0, 2).astype(dt)
+
+
+# ------------------------------------------------------------------- KDA
+# Kimi Delta Attention (arXiv:2510.26692): the gated delta rule with a
+# decay a channel, over a state S [dk, dv] a head a sequence, float32:
+#
+#     S' = diag(exp(g_t)) S_{t-1}
+#     S_t = S' + beta_t k_t (v_t - S'^T k_t)^T
+#     o_t = S_t^T q_t
+#
+# Appended at the file's end: no softmax line moved.
+
+SCOPE_KDA_STEP = "kda_step"  # name the kernels in a trace
+SCOPE_KDA_CHUNK = "kda_chunk"
+KDA_BLOCK = 64  # tokens a block of the chunkwise form
+KDA_SUB = 16  # and a sub-block inside it: the span an inverse decay covers
+_HI = jax.lax.Precision.HIGHEST
+
+
+def kda_recurrence(q, k, v, g, beta, s0):
+    """The recurrence a token at a time: what the chunkwise form must
+    equal (tests; the plain form of ``reference_forward``). q, k, g: [T,
+    H, dk]; v: [T, H, dv]; beta: [T, H]; s0: [H, dk, dv]. Returns (o [T,
+    H, dv], s)."""
+
+    def step(s, x):
+        q_t, k_t, v_t, g_t, b_t = x
+        sd = jnp.exp(g_t)[..., None] * s
+        r = jnp.einsum("hkv,hk->hv", sd, k_t, precision=_HI)
+        s = sd + k_t[..., None] * (b_t[..., None] * (v_t - r))[:, None, :]
+        return s, jnp.einsum("hkv,hk->hv", s, q_t, precision=_HI)
+
+    f32 = jnp.float32
+    s, o = jax.lax.scan(step, s0.astype(f32), (
+        q.astype(f32), k.astype(f32), v.astype(f32), g.astype(f32),
+        beta.astype(f32),
+    ))
+    return o, s
+
+
+def kda_chunk_operands(q, k, v, g, beta):
+    """What a block of ``C`` tokens contributes that does not depend on
+    the state it starts from. q, k, g: [..., C, dk]; v: [..., C, dv];
+    beta: [..., C]; all float32, g <= 0 the log decay a channel. With
+    ``G_t`` the decay summed from the block's start to token t, the
+    weight of token s in token t is ``exp(G_t - G_s)`` a channel (s <=
+    t). Returns ``(ut, w, qd, b, kend, gamma)``:
+
+        U = ut - w S0;  O = qd S0 + b U;  S1 = gamma * S0 + kend^T U
+
+    ``ut``, ``w``: ``T^-1 (beta v)`` and ``T^-1 (beta k exp(G))`` with ``T
+    = I + beta * tril(A, -1)``, ``A[t, s] = sum_c k_t k_s exp(G_t - G_s)``
+    (a unit lower triangular solve: forward substitution, as the
+    recurrence orders it); ``b`` is A's twin for the queries with its
+    diagonal. Every exponent is taken of a non-positive number, except
+    inside a sub-block of 16 tokens, where keys are brought back to the
+    sub-block's first token (FLA's own bound: a decay past e^-80 over 16
+    tokens, -5 a token, is beyond it)."""
+    C, dk = q.shape[-2:]
+    sub = min(KDA_SUB, C)
+    ns = C // sub
+    G = jnp.cumsum(g, axis=-2)
+    # each sub-block's reference: the decay up to the token before it
+    ends = G.reshape(*G.shape[:-2], ns, sub, dk)[..., :-1, -1, :]
+    R = jnp.concatenate([jnp.zeros_like(G[..., :1, :]), ends], axis=-2)
+    Rt = jnp.repeat(R, sub, axis=-2)  # [..., C, dk]
+    e_in = jnp.exp(G - Rt)  # <= 1
+    kt, qt = k * e_in, q * e_in
+    kh = k * jnp.exp(jnp.minimum(Rt - G, 80.0))
+
+    def mm(a, b_):
+        return jnp.einsum("...td,...sd->...ts", a, b_, precision=_HI)
+
+    a_diag, b_diag = mm(kt, kh), mm(qt, kh)
+    # a row of sub-block i against the keys before it, decayed to i's
+    # reference (both factors <= 1)
+    zero = jnp.zeros((*q.shape[:-2], sub, C), q.dtype)
+    a_off, b_off = [zero], [zero]
+    for i in range(1, ns):
+        ko = k * jnp.exp(jnp.minimum(R[..., i:i + 1, :] - G, 0.0))
+        rows = slice(i * sub, (i + 1) * sub)
+        a_off.append(mm(kt[..., rows, :], ko))
+        b_off.append(mm(qt[..., rows, :], ko))
+    t = jnp.arange(C)
+    same = (t[:, None] // sub) == (t[None, :] // sub)
+    before = (t[None, :] // sub) < (t[:, None] // sub)
+    strict, upto = t[None, :] < t[:, None], t[None, :] <= t[:, None]
+    a = jnp.where(same & strict, a_diag,
+                  jnp.where(before, jnp.concatenate(a_off, axis=-2), 0.0))
+    b = jnp.where(same & upto, b_diag,
+                  jnp.where(before, jnp.concatenate(b_off, axis=-2), 0.0))
+    tri = jnp.eye(C, dtype=q.dtype) + beta[..., None] * a
+    eg = jnp.exp(G)
+    rhs = beta[..., None] * jnp.concatenate([v, k * eg], axis=-1)
+    sol = jax.lax.linalg.triangular_solve(
+        tri, rhs, left_side=True, lower=True, unit_diagonal=True
+    )
+    dv = v.shape[-1]
+    last = G[..., -1:, :]
+    return (sol[..., :dv], sol[..., dv:], q * eg, b,
+            k * jnp.exp(last - G), jnp.exp(last[..., 0, :]))
+
+
+def kda_prefill_blocks(num_tokens) -> int:
+    """Blocks of ``KDA_BLOCK`` tokens that hold a real token, a call's
+    rows summed: what ``kda_chunk`` must carry a state through (the
+    engine's ``kda.prefill_blocks`` counter; numpy or python integers)."""
+    import numpy as np
+
+    return int((-(-np.asarray(num_tokens) // KDA_BLOCK)).sum())
+
+
+def kda_chunk_prefill(q, k, v, g, beta, pool, rows, fresh, *, layer: int):
+    """The chunkwise form over whole rows, from and to the sequences' rows
+    of the state pool ``[L, rows + 1, H, dk, dv]`` float32, and the place
+    its sequential part is chosen: the ``kda_chunk`` kernel
+    (ops/pallas/kda.py) wherever Pallas is active, which reads a row once
+    and writes it once in place; a ``lax.scan`` a BLOCK between a gather
+    and a scatter elsewhere (counted ``no_pallas_backend``). q, k, g: [N,
+    T, H, dk]; v: [N, T, H, dv]; beta: [N, T, H]; rows: [N] int32 (the
+    pool's last row = trash); fresh: [N] bool, start from a zero state. A
+    padded token carries g = 0 and beta = 0: it leaves the state as it
+    was. Returns (o [N, T, H, dv] float32, pool)."""
+    from dynamo_tpu.ops.fallback import note_fallback
+
+    f32 = jnp.float32
+    N, T, H, dk = q.shape
+    C = min(KDA_BLOCK, -(-T // KDA_SUB) * KDA_SUB)
+    pad = -T % C
+    nb = (T + pad) // C
+
+    def blocks(x):  # [N, T, H, d] -> [N, H, nb, C, d]
+        x = jnp.pad(x.astype(f32), ((0, 0), (0, pad), (0, 0), (0, 0)))
+        return x.reshape(N, nb, C, H, -1).transpose(0, 3, 1, 2, 4)
+
+    ut, w, qd, b, kend, gamma = kda_chunk_operands(
+        blocks(q), blocks(k), blocks(v), blocks(g),
+        blocks(beta[..., None])[..., 0],
+    )
+    if use_pallas():
+        from dynamo_tpu.ops.pallas.kda import kda_chunk_scan
+
+        kx = jnp.concatenate([
+            jnp.swapaxes(kend, -1, -2),
+            jnp.broadcast_to(gamma[..., None], (N, H, nb, dk, C)),
+        ], axis=-1)
+        o, pool = kda_chunk_scan(
+            ut, w, qd, b, kx, pool, rows, fresh, layer=layer,
+            interpret=jax.default_backend() != "tpu", scope=SCOPE_KDA_CHUNK,
+        )
+    else:
+        note_fallback("no_pallas_backend", expected=True,
+                      detail="kda_chunk_prefill: lax.scan over blocks")
+
+        def step(s, x):
+            ut_, w_, qd_, b_, kend_, gamma_ = x  # [N, H, ...] of one block
+            u = ut_ - jnp.einsum("nhck,nhkv->nhcv", w_, s, precision=_HI)
+            o_ = (jnp.einsum("nhck,nhkv->nhcv", qd_, s, precision=_HI)
+                  + jnp.einsum("nhcs,nhsv->nhcv", b_, u, precision=_HI))
+            s = gamma_[..., None] * s + jnp.einsum(
+                "nhck,nhcv->nhkv", kend_, u, precision=_HI)
+            return s, o_
+
+        s0 = jnp.where(fresh[:, None, None, None], 0.0, pool[layer, rows])
+        s, o = jax.lax.scan(step, s0, tuple(
+            jnp.moveaxis(x, 2, 0) for x in (ut, w, qd, b, kend, gamma)
+        ))
+        o = jnp.moveaxis(o, 0, 2)
+        pool = pool.at[layer, rows].set(s)
+    o = o.transpose(0, 2, 3, 1, 4).reshape(N, nb * C, H, -1)
+    return o[:, :T], pool
+
+
+def kda_decode_step(pool, conv, rows, q, k, v, g, beta, tail, *, layer: int):
+    """One decode step of every slot over layer ``layer`` of the state
+    pool ``[L, rows + 1, H, dk, dv]`` float32, the slots' new convolution
+    tails ``tail [B, taps - 1, 3, H dk]`` put into ``conv [L, rows + 1,
+    taps - 1, 3, H dk]``, and the place the implementation is chosen: the
+    ``kda_step`` kernel wherever Pallas is active (each live slot's row
+    read once and written once, in place, the tails in the same call),
+    else a gather of the slots' rows, the step in XLA and scatters back
+    (counted ``no_pallas_backend``). ``rows`` [B]: each slot's row, the
+    trash row (the pools' last) for a slot that owns none. q, k, g: [B, H,
+    dk]; v: [B, H, dv]; beta: [B, H]. Returns (o [B, H, dv] float32, pool,
+    conv)."""
+    from dynamo_tpu.ops.fallback import note_fallback
+
+    f32 = jnp.float32
+    q, k, v, beta = (x.astype(f32) for x in (q, k, v, beta))
+    alpha = jnp.exp(g.astype(f32))
+    if use_pallas():
+        from dynamo_tpu.ops.pallas.kda import kda_step
+
+        return kda_step(
+            pool, conv, rows, q, k, v, alpha, beta, tail, layer=layer,
+            interpret=jax.default_backend() != "tpu", scope=SCOPE_KDA_STEP,
+        )
+    note_fallback("no_pallas_backend", expected=True,
+                  detail="kda_decode_step: gather, step, scatter")
+    sd = alpha[..., None] * pool[layer, rows]  # [B, H, dk, dv]
+    r = jnp.einsum("bhkv,bhk->bhv", sd, k, precision=_HI)
+    s = sd + k[..., None] * (beta[..., None] * (v - r))[:, :, None, :]
+    o = jnp.einsum("bhkv,bhk->bhv", s, q, precision=_HI)
+    return (o, pool.at[layer, rows].set(s),
+            conv.at[layer, rows].set(tail.astype(conv.dtype)))

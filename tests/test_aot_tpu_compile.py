@@ -179,3 +179,54 @@ def test_latent_decode_compiles_for_v5e(v5e, pages_per_seq, dtype):
     ).compile()
     # the kernel is named after the scope: the trace's readers match it
     assert "%attn_latent" in compiled.as_text()
+
+
+def test_kda_step_compiles_for_v5e(v5e):
+    """Solar-Open2's cell: 128 slots on 129 state rows, 64 heads of 128 x
+    128 float32 and the convolution tails, both pools aliased in place, a
+    row found through the scalar-prefetched ``rows``."""
+    from dynamo_tpu.ops.pallas.kda import kda_step
+
+    slots, heads, d, rows = 128, 64, 128, 128
+    f32 = jnp.float32
+    compiled = jax.jit(
+        lambda pool, conv, at, q, k, v, a, b, tail: kda_step(
+            pool, conv, at, q, k, v, a, b, tail, layer=1, scope="kda_step"),
+        donate_argnums=(0, 1),
+    ).lower(
+        _rows(v5e, 3, rows + 1, heads, d, d, dtype=f32),
+        _rows(v5e, 3, rows + 1, 3, 3, heads * d),
+        _rows(v5e, slots, dtype=jnp.int32),
+        _rows(v5e, slots, heads, d, dtype=f32),
+        _rows(v5e, slots, heads, d, dtype=f32),
+        _rows(v5e, slots, heads, d, dtype=f32),
+        _rows(v5e, slots, heads, d, dtype=f32),
+        _rows(v5e, slots, heads, dtype=f32),
+        _rows(v5e, slots, 3, 3, heads * d),
+    ).compile()
+    # the kernel is named after the scope: the trace's readers match it
+    assert "%kda_step" in compiled.as_text()
+
+
+@pytest.mark.parametrize("rows,blocks", [(2, 16), (1, 16)],
+                         ids=["pack-of-2", "single"])
+def test_kda_chunk_compiles_for_v5e(v5e, rows, blocks):
+    """The chunkwise form's sequential part at the cell's prefill shapes:
+    1,024 tokens a row in 16 blocks of 64, 64 heads, float32 products, the
+    state from and to the pool's rows in place."""
+    from dynamo_tpu.ops.pallas.kda import kda_chunk_scan
+
+    heads, c, d = 64, 64, 128
+    f32 = jnp.float32
+    lead = (rows, heads, blocks)
+    compiled = jax.jit(
+        lambda *a: kda_chunk_scan(*a, layer=2, scope="kda_chunk"),
+        donate_argnums=(5,),
+    ).lower(
+        _rows(v5e, *lead, c, d, dtype=f32), _rows(v5e, *lead, c, d, dtype=f32),
+        _rows(v5e, *lead, c, d, dtype=f32), _rows(v5e, *lead, c, c, dtype=f32),
+        _rows(v5e, *lead, d, 2 * c, dtype=f32),
+        _rows(v5e, 3, 129, heads, d, d, dtype=f32),
+        _rows(v5e, rows, dtype=jnp.int32), _rows(v5e, rows, dtype=jnp.bool_),
+    ).compile()
+    assert "%kda_chunk" in compiled.as_text()

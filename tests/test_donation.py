@@ -135,6 +135,25 @@ def test_gqa_insert_donates_extract_does_not():
     assert _deleted([k, v]) == [True, True]
 
 
+def test_state_rows_release_and_recurrent_programs_donate_every_leaf():
+    """A model with recurrent layers: the state pool, the convolution
+    tails and the directory ride the donated pair through the programs,
+    and the engine's release of state rows updates the K side in place."""
+    spec = ModelSpec.tiny_solar()
+    params = llama.init_params(spec, jax.random.PRNGKey(0))
+    k, v = llama.init_cache(spec, NUM_PAGES, PAGE, state_rows=2)
+    bt = jnp.arange(1, 1 + PPS, dtype=jnp.int32)
+    _, k2, v2, _ = llama.prefill_forward(
+        spec, params, jnp.zeros((8,), jnp.int32), bt,
+        jnp.asarray(0, jnp.int32), k, v, jnp.asarray(8, jnp.int32),
+    )
+    assert all(_deleted([k, v]))
+    assert int(k2.rows.owner[0, 0]) == 1
+    k3 = llama.release_state_rows(k2, jnp.asarray([1, -1], jnp.int32))
+    assert all(_deleted([k2])) and not any(_deleted([v2]))
+    assert int(k3.rows.owner[0, 0]) == 0
+
+
 def test_kv_write_kernel_donates_pools():
     _params, k, v, _bt = _gqa_args()
     kn = jnp.zeros((B, SPEC.num_kv_heads, SPEC.head_dim), jnp.float32)
@@ -334,6 +353,9 @@ AUDIT: dict = {
         # kind's layers share one trace; it reads the pools inside the
         # prefill programs' jits, which are the ones that donate them
         "paged_prefill_attention": "read-only",
+        # the engine's release of a recurrent model's state rows: the K
+        # side's directory is updated in place
+        "release_state_rows": "donates",
     },
     mla: {
         "prefill_forward": "donates",

@@ -38,6 +38,21 @@ def small_config(**kw):
 # ------------------------------------------------------ numerics: vs reference
 
 
+@pytest.mark.parametrize("spec", [
+    SPEC, ModelSpec.tiny_moe(), ModelSpec.tiny_solar(),
+], ids=["dense", "moe", "recurrent"])
+def test_the_engines_weights_are_the_familys_draw(spec):
+    """The engine draws random weights as one program; tensor for tensor
+    they are what ``init_params`` gives op by op (the benchmark's plain
+    references draw their own copy from the same recipe)."""
+    engine = InferenceEngine(spec, small_config(seed=5))
+    want = llama.init_params(spec, jax.random.PRNGKey(5))
+    same = jax.tree.map(
+        lambda a, b: a.dtype == b.dtype and bool(jnp.array_equal(a, b)),
+        engine.params, want)
+    assert all(jax.tree.leaves(same))
+
+
 def test_prefill_matches_reference_forward():
     """Paged prefill logits == plain full-attention forward logits."""
     key = jax.random.PRNGKey(0)

@@ -152,6 +152,20 @@ PROFILE_COUNTERS: dict[str, str] = {
                                            "burst in flight (ahead >= 1): "
                                            "queued behind device work, "
                                            "not after a drained device",
+    # a model with recurrent (KDA) layers only
+    "kda.decode_rows": "state rows a kda_step call updated (live slots), "
+                       "over the dispatched bursts' steps; a layer's worth",
+    "kda.prefill_blocks": "blocks of 64 tokens with a real token that "
+                          "kda_chunk carried a state through, over the "
+                          "dispatched prefills; a layer's worth",
+    "recurrent_state.rows": "state rows the engine holds (a gauge)",
+    "recurrent_state.rows_live": "rows a live sequence owns now: decode "
+                                 "slots and the open chunked prefill",
+    "recurrent_state.claims": "rows claimed by a prefill at position 0, "
+                              "by the device-side directory's own count",
+    "recurrent_state.row_missing": "sequences whose row was not where "
+                                   "their block table says (their output "
+                                   "is wrong): must read 0",
 }
 
 # jax.profiler.TraceAnnotation names the profiled engine writes into a
@@ -365,7 +379,12 @@ METRIC_NAMES: dict[str, str] = {
     # /metrics surface via the module registry)
     "fused_fallback_total": "fused/quantized fast-path downgrades by "
                             "reason (quant_tp_shardmap | "
-                            "no_pallas_backend) "
+                            "no_pallas_backend | latent_fp8_xla | "
+                            "latent_tp_xla | recurrent_no_page_offload | "
+                            "recurrent_no_page_transfer | "
+                            "recurrent_no_spec_decode | "
+                            "recurrent_no_ring_prefill | "
+                            "recurrent_state_row_missing) "
                             "— counted at TRACE time, so each compiled "
                             "specialization bumps it once, not once per "
                             "step; nonzero quant_tp_shardmap on a TP>1 "
