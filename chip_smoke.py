@@ -20,7 +20,10 @@ weights beside the default 2,048-page cache; the weights are
    report, a guided vocabulary of 128,256 entries built),
 3. compares the engine's prefill logits with the plain ``reference_forward``
    on one prompt, and one decode step through the fused Pallas kernel with
-   the same step through the XLA gather path, bf16 and fp8 pools.
+   the same step through the XLA gather path, bf16 and fp8 pools,
+4. runs the latent family's prefill attention at JoyAI-LLM-Flash's widths
+   through its Mosaic kernel and through the XLA walk, same operands, and
+   prints each one's time a call.
 
 Every timing it prints is a smoke timing, not a metric. The last line of
 standard output is ``{"ok": true, "device": {...}}`` on success; any failure
@@ -34,6 +37,7 @@ import argparse
 import asyncio
 import contextlib
 import dataclasses
+import functools
 import gc
 import json
 import math
@@ -577,6 +581,101 @@ def fp8_fused_vs_xla(spec, page_size: int, pages_per_seq: int) -> None:
         check(worst <= 0.125, f"fp8 {name}: written pages differ by > 1 step")
 
 
+# ------------------------------------------------- latent prefill kernel
+
+# JoyAI-LLM-Flash's attention widths as its cell runs them (perfbench/
+# configs/joyai-llm-flash.json): 32 heads, K 128 + 64 roped, V 128, latent
+# 512, rows of 576 values in 640 lanes, pages of 64 tokens, 160-page tables
+LATENT = dict(H=32, dn=128, dr=64, dv=128, dc=512, lanes=640, page=64,
+              pages_per_seq=160, rows=1024)
+LATENT_KERNEL_VS_TWIN_TOL = 0.02  # same types; reduction order differs
+# (start_pos, real rows) a member: a fresh chunk, chunks resumed at 1,024
+# and 7,168, a pack of two of unequal length
+LATENT_CASES = (((0, 1024),), ((1024, 1024),), ((7168, 1024),),
+                ((0, 1024), (0, 600)))
+
+
+def latent_prefill_inputs(members, seed: int = 5):
+    """Random operands of one ``latent_prefill_attention`` call at the
+    published widths: each member's context written to pages of its own
+    (a shuffled table), the rest of the pool left as drawn."""
+    w = LATENT
+    N, T, P, page = len(members), w["rows"], w["pages_per_seq"], w["page"]
+    keys = jax.random.split(jax.random.PRNGKey(seed), 6)
+    bf16 = jnp.bfloat16
+    pool = jax.random.normal(
+        keys[0], (2, 1 + N * P, page, w["dc"] + w["dr"]), bf16)
+    pool = jnp.pad(pool, [(0, 0)] * 3 + [(0, w["lanes"] - pool.shape[-1])])
+    tables = 1 + jax.random.permutation(keys[1], N * P).reshape(N, P)
+    H, dc = w["H"], w["dc"]
+    return (
+        jax.random.normal(keys[2], (N, T, H, w["dn"]), bf16),
+        jax.random.normal(keys[3], (N, T, H, w["dr"]), bf16),
+        pool, jnp.asarray(1, jnp.int32),
+        (jax.random.normal(keys[4], (H, dc, w["dn"])) * dc ** -0.5).astype(bf16),
+        (jax.random.normal(keys[5], (H, dc, w["dv"])) * dc ** -0.5).astype(bf16),
+        tables.astype(jnp.int32),
+        jnp.asarray([m[0] for m in members], jnp.int32),
+        jnp.asarray([m[0] + m[1] for m in members], jnp.int32),
+    )
+
+
+LATENT_CHAIN = 8  # attention calls a timed program holds
+
+
+def ms_a_call(attend, q_nope, *rest, repeats: int = 5):
+    """(milliseconds an attention call, one call's result). A call alone
+    is as long as its dispatch from Python, so the timed program chains
+    ``LATENT_CHAIN`` calls as a model's layers do (each one's first query
+    takes a zero from the result before it), compiled and run once, then
+    ``repeats`` programs queued and the last waited for."""
+
+    @jax.jit
+    def chain(q_nope, *rest):
+        def body(_, q):
+            out = attend(q, *rest)
+            return q.at[0, 0, 0, 0].add(out[0, 0, 0, 0] * 0)
+
+        return jax.lax.fori_loop(0, LATENT_CHAIN, body, q_nope)
+
+    jax.block_until_ready(chain(q_nope, *rest))
+    t0 = time.perf_counter()
+    for _ in range(repeats):
+        out = chain(q_nope, *rest)
+    jax.block_until_ready(out)
+    ms = (time.perf_counter() - t0) * 1e3 / (repeats * LATENT_CHAIN)
+    return ms, attend(q_nope, *rest)
+
+
+def latent_prefill_kernel_vs_twin() -> None:
+    """``ops/attention.latent_prefill_attention`` at JoyAI-LLM-Flash's
+    widths: the Mosaic kernel against the XLA walk (its twin: the same
+    types), same operands, with each one's time a call. Smoke timings of
+    two attention calls alone, not a cell's metric."""
+    from dynamo_tpu.ops.attention import latent_prefill_attention as attend
+
+    scale = (LATENT["dn"] + LATENT["dr"]) ** -0.5
+    call = functools.partial(attend, scale=scale)
+    for members in LATENT_CASES:
+        args = latent_prefill_inputs(members)
+        attend.clear_cache()  # DYNAMO_PALLAS is read when a path is traced
+        kernel_ms, got = ms_a_call(call, *args)
+        with _xla_attention():
+            attend.clear_cache()
+            twin_ms, want = ms_a_call(call, *args)
+        attend.clear_cache()
+        got, want = (np.asarray(x, np.float32) for x in (got, want))
+        what = " + ".join(f"{nt} rows at {sp}" for sp, nt in members)
+        for n, (_, nt) in enumerate(members):  # padded rows are not held
+            compare_logits(
+                got[n, :nt], want[n, :nt], LATENT_KERNEL_VS_TWIN_TOL,
+                f"latent prefill attention, kernel vs XLA walk ({what}; "
+                f"member {n})",
+            )
+        say(f"latent prefill attention ({what}): kernel {kernel_ms:.3f} ms "
+            f"a call, XLA walk {twin_ms:.3f} ms")
+
+
 # ------------------------------------------------------------ four chips
 
 
@@ -739,6 +838,9 @@ def one_chip_mode() -> None:
     gc.collect()
     pallas_vs_xla_decode(engine)
     fp8_fused_vs_xla(spec, cfg.page_size, cfg.max_pages_per_seq)
+    engine.params = None  # 9 GB of weights make room for a latent pool
+    gc.collect()
+    latent_prefill_kernel_vs_twin()
     say(f"device memory at exit: {memory_line(jax.devices()[0])}")
 
 

@@ -572,8 +572,9 @@ class EngineConfig:
         go through chunks of the largest bucket that is
         (``max_prefill_chunk_tokens`` is capped by it). A guard with
         margin, not a tuner. The latent family (``spec.is_mla``) is
-        charged what ITS programs hold (``need_latent``): their walk
-        never had a whole-table form to stay compatible with; so is a model
+        charged what ITS XLA walk holds (``need_latent``: it never had a
+        whole-table form to stay compatible with; an upper bound where
+        its kernel serves); so is a model
         with recurrent layers (``need_recurrent``): its softmax layers the
         walk's true tiles, its KDA layers the chunkwise form's float32
         operands. The state rows themselves are part of the pools, so
@@ -593,12 +594,17 @@ class EngineConfig:
             return scores * 3 // 2 + 96 * 1024 * rows * bucket
 
         def need_latent(rows: int, bucket: int) -> int:
-            # what the latent walk holds (ops/attention.
-            # latent_prefill_attention), whatever the table: float32
+            # what the latent XLA walk holds (ops/attention.
+            # latent_prefill_walk), whatever the table: float32
             # scores of all a call's rows against ONE block (scores,
             # probabilities and their rounded copy: x 3), the running
             # accumulator in and out of the loop, and the same 96 KiB a
-            # prompt token for the rest of the program
+            # prompt token for the rest of the program. An UPPER BOUND
+            # too since PR 36: where the Mosaic kernel serves
+            # (ops/pallas/latent_prefill.py) the scores never leave VMEM
+            # and a program holds the padded queries and the output, a
+            # tenth of this. The charge stays the walk's so that the set
+            # of compiled prefill programs does (ROADMAP.md S3 (b)).
             from dynamo_tpu.ops.attention import latent_prefill_tiling
 
             _, bp = latent_prefill_tiling(

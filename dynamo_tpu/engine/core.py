@@ -515,10 +515,15 @@ class InferenceEngine:
         # the dispatched prefills and verifies (always on: integer
         # arithmetic on [rows, tiles] a dispatch), a layer kind apart,
         # beside what a walk of the table's whole width would have
+        # the latent family also counts its dispatches and those its
+        # Mosaic kernel served (``kernel_calls / dispatches``: the hit
+        # share of ops/attention.latent_prefill_attention's choice)
         self._prefill_walks = self._prefill_walk_windows()
         self.prefill_kv = {
-            f"blocks_{what}.{kind}": 0
-            for kind in self._prefill_walks for what in ("visited", "table")
+            f"{what}.{kind}": 0
+            for kind in self._prefill_walks
+            for what in ("blocks_visited", "blocks_table") + (
+                ("dispatches", "kernel_calls") if kind == "latent" else ())
         }
         # how a chunked prefill's chunks met the decode pipeline (always
         # on: two int adds a chunk): every chunk launched, the first
@@ -630,7 +635,10 @@ class InferenceEngine:
           (calls): see ``_count_decode_kv``.
         - ``prefill_kv.blocks_visited.<kind>`` / ``.blocks_table.<kind>``
           (calls; kind ``full`` or ``window``, or ``latent`` for the
-          latent family's walk): see ``_count_prefill_kv``.
+          latent family's walk, which also reports
+          ``prefill_kv.dispatches.latent`` and ``.kernel_calls.latent``:
+          its dispatches and those its Mosaic kernel served): see
+          ``_count_prefill_kv``.
         - ``chunked_prefill.chunks`` / ``.chunks_behind_burst`` (calls):
           the chunks of chunked prefills launched, and those of them
           launched with a decode burst in flight (``ahead`` >= 1 on their
@@ -761,9 +769,13 @@ class InferenceEngine:
         query tile, a pack running each tile to its longest member) and
         the blocks a walk of the whole table would (``blocks_table``), one
         layer's worth a layer kind. Their ratio is how far prefill
-        attention follows the prompts."""
+        attention follows the prompts. The latent family's tiling is that
+        of the implementation the dispatch got: its kernel's tiles, each
+        member its own blocks (a grid axis, not a ``vmap``), or the XLA
+        walk's one tile of all the rows."""
         from dynamo_tpu.ops.attention import (
-            latent_prefill_tiling, prefill_blocks, prefill_tiling,
+            latent_kernel_serves, latent_prefill_tiling, prefill_blocks,
+            prefill_tiling,
         )
 
         page = self.config.page_size
@@ -773,15 +785,19 @@ class InferenceEngine:
             from dynamo_tpu.ops.attention import kda_prefill_blocks
 
             self.kda["prefill_blocks"] += kda_prefill_blocks(nts)
+        kv = self.prefill_kv
         for kind, window in self._prefill_walks.items():
-            if kind == "latent":  # one tile of all the call's rows
-                tq, bp = latent_prefill_tiling(rows, pages, page)
+            kernel = kind == "latent" and latent_kernel_serves(
+                self.k_pages, self.mesh)
+            if kind == "latent":
+                tq, bp = latent_prefill_tiling(rows, pages, page, kernel)
+                kv["dispatches.latent"] += 1
+                kv["kernel_calls.latent"] += kernel
             else:
                 tq, bp = prefill_tiling(rows, pages, page, window)
             tiles = np.arange(-(-rows // tq), dtype=np.int32)[None, :]
             _, count = prefill_blocks(starts, nts, tiles, tq, window, page, bp)
-            kv = self.prefill_kv
-            kv[f"blocks_visited.{kind}"] += (
+            kv[f"blocks_visited.{kind}"] += int(count.sum()) if kernel else (
                 int(count.max(axis=0).sum()) * len(starts)
             )
             kv[f"blocks_table.{kind}"] += (

@@ -16,20 +16,23 @@ What makes it a family of its own for serving:
   kernel (ops/pallas/latent_decode.py: one pool, a page fetched once and
   used as key and as value, a sequence's own live chunks, the new row
   written in the call) where Pallas runs and the XLA walk elsewhere.
-- PREFILL (single, packed, the speculative verify) runs NOT absorbed over
-  the walk of ``ops/attention.latent_prefill_attention``: a block of
-  cached rows is up-projected to per-head keys and values once and scored
-  by the call's queries at ``(192 + 128) x 2`` FLOP a pair where the
-  absorbed form pays ``(576 + 512) x 2``; a chunk at ``start_pos > 0``
-  reads the earlier chunks' rows from their pages. No program gathers a
-  whole table or holds a ``[.., max_ctx]`` score.
+- PREFILL (single, packed, the speculative verify) runs NOT absorbed
+  through ``ops/attention.latent_prefill_attention``: a block of cached
+  rows is up-projected to per-head keys and values once and scored by the
+  call's queries at ``(192 + 128) x 2`` FLOP a pair where the absorbed
+  form pays ``(576 + 512) x 2``; a chunk at ``start_pos > 0`` reads the
+  earlier chunks' rows from their pages. It chooses the Mosaic kernel
+  (ops/pallas/latent_prefill.py: a pack's members a grid axis, a
+  tile-by-block score never out of VMEM, a query tile stopping at its
+  causal edge) where Pallas runs and the XLA walk elsewhere. No program
+  gathers a whole table or holds a ``[.., max_ctx]`` score.
 
 Paged cache: ``[L, num_pages, page_size, D]`` with ``D`` the row's 576
 values rounded up to the lane tile where the kernel runs compiled
 (``ops/attention.pool_head_dim``); no head axis, page-major like the GQA
 pool and under the same page / block bookkeeping. ``kv_dtype="fp8"`` keeps
 a ``QuantPool`` with a scale a ROW and the XLA paths (counted:
-``latent_fp8_xla``).
+``latent_fp8_xla``, ``latent_prefill_fp8_xla``).
 
 The block composes MLA with the expert layer (models/moe.py: sigmoid
 ``noaux_tc`` routing, ``held_experts`` of an ep deployment, the layer's
@@ -435,20 +438,24 @@ def _ffn_counting(
     )
 
 
-def _ctx_attention(
+def _seq_attention(
     spec: ModelSpec, li: int, lp: Params, q_nope, q_rope, new_rows, cache,
-    block_table, start_pos, kv_len,
+    block_tables, start_pos, kv_len, mesh: Mesh | None = None,
 ):
-    """Attention of one sequence's new queries (at ``start_pos +
-    arange(T)``; their own rows already written to ``cache``) over its
-    paged latents: every prefill program and the verify come through
-    here. A quantized pool gets the call's exact rows laid over its
-    read-back (the XLA mirror of the decode kernel's analytic merge)."""
+    """Attention of N sequences' new queries (member n's at ``start_pos[n]
+    + arange(T)``; their own rows already written to ``cache``) over
+    their paged latents, each over its own table -> [N, T, H * dv]: every
+    prefill program and the verify come through here, a single prompt or
+    chunk as a pack of one. ``ops/attention.latent_prefill_attention``
+    chooses the kernel or the XLA walk; a quantized pool gets the call's
+    exact rows laid over its read-back (the XLA mirror of the decode
+    kernel's analytic merge)."""
+    N, T = q_nope.shape[:2]
     return latent_prefill_attention(
-        q_nope, q_rope, cache, li, lp["w_uk"], lp["w_uv"], block_table,
+        q_nope, q_rope, cache, li, lp["w_uk"], lp["w_uv"], block_tables,
         start_pos, kv_len, scale=softmax_scale(spec),
-        new_rows=new_rows if is_quant(cache) else None,
-    )
+        new_rows=new_rows if is_quant(cache) else None, mesh=mesh,
+    ).reshape(N, T, -1)
 
 
 def _with_counts(out: tuple, counts):
@@ -525,11 +532,11 @@ def prefill_forward_impl(
             new_rows.reshape(n_pg, page_size, -1),
             real.reshape(n_pg, page_size),
         )
-        attn = _ctx_attention(
-            spec, li, lp, q_nope, q_rope, new_rows, cache, block_table,
-            start_pos, kv_len,
-        )
-        x = x + attn.reshape(T, -1).astype(x.dtype) @ lp["wo"]
+        attn = _seq_attention(
+            spec, li, lp, q_nope[None], q_rope[None], new_rows[None], cache,
+            block_table[None], start_pos[None], kv_len[None], mesh,
+        )[0]
+        x = x + attn.astype(x.dtype) @ lp["wo"]
         hh = rms_norm(x, lp["mlp_norm"], spec.rms_eps)
         y, counts = _ffn_counting(
             spec, li, lp, hh, counts, COUNT_PREFILL, real, mesh
@@ -545,22 +552,6 @@ prefill_forward = jax.jit(
     static_argnames=("mesh",), donate_argnums=(5,),
     donate_argnames=("counts",),
 )
-
-
-def _seq_attention(
-    spec: ModelSpec, li: int, lp: Params, q_nope, q_rope, new_rows, cache,
-    block_tables, start_pos, kv_len,
-):
-    """``_ctx_attention`` of N sequences, each over its own table (a
-    pack's walk runs to its longest member) -> [N, T, H * dv]."""
-    N, T = q_nope.shape[:2]
-    return jax.vmap(
-        lambda qn, qr, nr, bt, sp, kvl: _ctx_attention(
-            spec, li, lp, qn, qr, nr, cache, bt, sp, kvl
-        )
-    )(q_nope, q_rope, new_rows, block_tables, start_pos, kv_len).reshape(
-        N, T, -1
-    )
 
 
 def prefill_forward_batch_impl(
@@ -606,7 +597,7 @@ def prefill_forward_batch_impl(
         )
         attn = _seq_attention(
             spec, li, lp, q_nope, q_rope, new_rows, cache, block_tables,
-            start_pos, kv_len,
+            start_pos, kv_len, mesh,
         )
         x = x + attn.astype(x.dtype) @ lp["wo"]
         hh = rms_norm(x, lp["mlp_norm"], spec.rms_eps)
@@ -675,7 +666,7 @@ def verify_forward_impl(
             )
         attn = _seq_attention(
             spec, li, lp, q_nope, q_rope, new_rows, cache, block_tables,
-            start_pos, kv_len,
+            start_pos, kv_len, mesh,
         )
         x = x + attn.astype(x.dtype) @ lp["wo"]
         hh = rms_norm(x, lp["mlp_norm"], spec.rms_eps)

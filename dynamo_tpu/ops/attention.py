@@ -593,6 +593,9 @@ def window_table(
 # keys and values once and scored by the whole call's queries).
 
 SCOPE_ATTN_LATENT = "attn_latent"  # names the decode kernel in a trace
+# the prefill kernel's name is one no configuration's ``trace_names``
+# holds: the decode kernel's roofline share divides by ``attn_latent``'s time
+SCOPE_PREFILL_LATENT = "prefill_latent"
 
 
 def latent_rows(pool, layer, ids: jax.Array) -> jax.Array:
@@ -672,9 +675,11 @@ def paged_latent_decode_attention(
     return acc / l[..., None]
 
 
-def _latent_kernel_serves(pool, mesh) -> bool:
-    """Whether the Mosaic latent kernel serves this pool: Pallas active, a
-    plain array, one shard of heads."""
+def latent_kernel_serves(pool, mesh=None) -> bool:
+    """Whether the Mosaic latent kernels (decode and prefill alike) serve
+    this pool: Pallas active, a plain array, one shard of heads. Public
+    for the engine, whose ``prefill_kv`` counters ask the tiling of the
+    implementation its dispatches get."""
     from dynamo_tpu.ops.quant import is_quant
 
     tp = mesh is not None and mesh.shape.get("tp", 1) > 1
@@ -687,7 +692,7 @@ def latent_decode_schedule(pool, block_tables, seq_lens, mesh=None):
     sequence's live chunks, buffers, successors: a dozen small operations
     that depend on the lengths alone), or None where the XLA walk
     serves."""
-    if not _latent_kernel_serves(pool, mesh):
+    if not latent_kernel_serves(pool, mesh):
         return None
     from dynamo_tpu.ops.pallas.latent_decode import latent_schedule
 
@@ -727,7 +732,7 @@ def latent_decode_update_attention(
         [q_lat.astype(jnp.float32), q_rope.astype(jnp.float32)], axis=-1
     ) * scale
     q, new_rows = pad_heads(q, D), pad_heads(new_rows, D)
-    if _latent_kernel_serves(pool, mesh):
+    if latent_kernel_serves(pool, mesh):
         from dynamo_tpu.ops.pallas.latent_decode import latent_decode_attention
 
         out, pool = latent_decode_attention(
@@ -758,23 +763,34 @@ def latent_decode_update_attention(
     return out.astype(q_lat.dtype), pool
 
 
+# Rows a query tile of the latent prefill KERNEL holds (PERF.md section 6,
+# PR 36: the chip's timings of 128 / 256 / 512); a shorter call is one tile
+# of whole lane tiles, since the kernel keeps a tile's rows on the lanes.
+_LATENT_TILE_ROWS = 256
+
+
 def latent_prefill_tiling(
-    n_queries: int, pages_per_seq: int, page_size: int
+    n_queries: int, pages_per_seq: int, page_size: int, kernel: bool = False
 ) -> tuple[int, int]:
-    """``(tq, bp)`` of the latent prefill walk: ONE tile of all the call's
-    rows (a block's up-projection is paid once a block, so every query
-    scores it while it is there) against blocks of ``prefill_tiling``'s
-    size. With ``prefill_blocks`` it is the walk's trip count and the
-    engine's ``prefill_kv.*.latent`` counters alike."""
-    return n_queries, prefill_tiling(n_queries, pages_per_seq, page_size)[1]
+    """``(tq, bp)`` of prefill attention over latents, asked of whichever
+    implementation a dispatch gets (``latent_kernel_serves``): the
+    kernel's tiles of ``_LATENT_TILE_ROWS`` rows, each visiting the blocks
+    up to its own last real row, or the XLA walk's ONE tile of all the
+    call's rows (its block's up-projection is paid once a block, so every
+    query scores it while it is there); blocks of ``prefill_tiling``'s
+    size either way. With ``prefill_blocks`` it is the trip counts of
+    both and the engine's ``prefill_kv.*.latent`` counters alike."""
+    bp = prefill_tiling(n_queries, pages_per_seq, page_size)[1]
+    if not kernel:
+        return n_queries, bp
+    return min(_LATENT_TILE_ROWS, -(-n_queries // 128) * 128), bp
 
 
-@functools.partial(jax.jit, static_argnames=("scale",))
-def latent_prefill_attention(
+def latent_prefill_walk(
     q_nope: jax.Array,  # [T, H, dn]: queries at start_pos + arange(T)
     q_rope: jax.Array,  # [T, H, dr]
     pool,  # [L, num_pages, page, D] (the call's rows already written)
-    layer,  # scalar, not static: the layers share one trace
+    layer,
     w_uk: jax.Array,  # [H, dc, dn]
     w_uv: jax.Array,  # [H, dc, dv]
     block_table: jax.Array,  # [P]
@@ -784,19 +800,16 @@ def latent_prefill_attention(
     scale: float,
     new_rows: jax.Array | None = None,  # [T, dc + dr] exact (quant pools)
 ) -> jax.Array:
-    """Causal attention of a call's queries over the sequence's PAGED
-    latents, NOT absorbed: the walk of ``paged_prefill_attention`` with
-    the loop over blocks outermost. A block of ``bp`` pages is gathered
-    (``latent_rows``), up-projected ONCE to per-head keys and values
-    (``c W_uk``, ``c W_uv``; the roped key is shared by the heads) and
-    scored by all ``T`` query rows at ``(dn + dr + dv) x 2`` FLOP a pair
-    where the absorbed form pays ``(2 dc + dr) x 2``; blocks run from the
-    table's first page to the last real row's (``prefill_blocks`` with one
-    tile: a run-time count, under ``vmap`` a pack's longest member), so a
-    chunk at ``start_pos > 0`` reads the earlier chunks' latents from
-    their pages and nothing follows the table's width. Operands in the
-    model's dtype, float32 accumulation, scores and softmax. Returns
-    ``[T, H, dv]``."""
+    """The XLA twin of ``ops/pallas/latent_prefill.py``, one sequence:
+    the walk of ``paged_prefill_attention`` with the loop over blocks
+    outermost. A block of ``bp`` pages is gathered (``latent_rows``),
+    up-projected ONCE to per-head keys and values and scored by all ``T``
+    query rows; blocks run from the table's first page to the last real
+    row's (``prefill_blocks`` with one tile: a run-time count, under
+    ``vmap`` a pack's longest member). Its ``[H, T, block]`` float32
+    scores go through HBM, which is what the kernel is for; it serves
+    where the kernel does not (the CPU, fp8 pools with their exact
+    ``new_rows`` overlay, a tp mesh by GSPMD). Returns ``[T, H, dv]``."""
     T, H, _ = q_nope.shape
     dc, dv = w_uk.shape[1], w_uv.shape[2]
     dr = q_rope.shape[-1]
@@ -842,6 +855,78 @@ def latent_prefill_attention(
     # a padded member of a pack visits nothing: l is 0 there
     out = acc / jnp.where(l == 0.0, 1.0, l)[..., None]
     return out.transpose(1, 0, 2).astype(dt)
+
+
+@functools.partial(jax.jit, static_argnames=("scale", "mesh"))
+def latent_prefill_attention(
+    q_nope: jax.Array,  # [N, T, H, dn]: member n's rows at start_pos[n] + t
+    q_rope: jax.Array,  # [N, T, H, dr]
+    pool,  # [L, num_pages, page, D] (the call's rows already written)
+    layer,  # scalar, not static: the layers share one trace
+    w_uk: jax.Array,  # [H, dc, dn]
+    w_uv: jax.Array,  # [H, dc, dv]
+    block_tables: jax.Array,  # [N, P]
+    start_pos: jax.Array,  # [N]
+    kv_len: jax.Array,  # [N]: start_pos + the real rows
+    *,
+    scale: float,
+    new_rows: jax.Array | None = None,  # [N, T, dc + dr] exact (quant pools)
+    mesh=None,
+) -> jax.Array:
+    """Causal attention of ``N`` sequences' new queries over their PAGED
+    latents, NOT absorbed (``(dn + dr + dv) x 2`` FLOP a pair where the
+    absorbed form pays ``(2 dc + dr) x 2``), and the place its
+    implementation is chosen (``latent_decode_update_attention``'s twin on
+    the prefill side): every prefill program and the verify come through
+    here, a single prompt or chunk as a pack of one. The Mosaic kernel
+    (ops/pallas/latent_prefill.py: the pack's members a grid axis, a
+    tile-by-block score never out of VMEM, a block up-projected once for
+    every query tile, a tile stopping at its causal edge) wherever
+    ``latent_kernel_serves``; else the XLA walk a member under ``vmap``,
+    counted: an fp8 pool as ``latent_prefill_fp8_xla`` (the kernel has no
+    dequantising block and no exact ``new_rows`` overlay), a tp mesh as
+    ``latent_prefill_tp_xla`` (no ``shard_map`` over heads yet), the CPU
+    and ``DYNAMO_PALLAS=0`` as ``no_pallas_backend``. Either way what is
+    fetched and scored follows the prompts, not the table's width.
+    Operands in the model's dtype, float32 accumulation, scores and
+    softmax. Returns ``[N, T, H, dv]``."""
+    from dynamo_tpu.ops.fallback import note_fallback
+    from dynamo_tpu.ops.quant import is_quant
+
+    # dynalint: disable=DL011 -- a probe of the pool's pytree form
+    # (is_quant) and the static mesh, not of traced data
+    if latent_kernel_serves(pool, mesh):
+        from dynamo_tpu.ops.pallas.latent_prefill import latent_prefill_kernel
+
+        T = q_nope.shape[1]
+        page, P = pool.shape[2], block_tables.shape[1]
+        tq, bp = latent_prefill_tiling(T, P, page, kernel=True)
+        tiles = jnp.arange(-(-T // tq))[None, :]
+        _, counts = prefill_blocks(
+            start_pos[:, None], (kv_len - start_pos)[:, None], tiles, tq, 0,
+            page, bp,
+        )
+        return latent_prefill_kernel(
+            q_nope, pad_heads(q_rope, pool.shape[-1] - w_uk.shape[1]), pool,
+            w_uk, w_uv, block_tables, start_pos, kv_len, counts,
+            layer=layer, scale=scale, tq=tq, bp=bp,
+            interpret=jax.default_backend() != "tpu",
+            scope=SCOPE_PREFILL_LATENT,
+        )
+    if not use_pallas():
+        note_fallback("no_pallas_backend", expected=True,
+                      detail="latent_prefill_attention: XLA walk")
+    elif is_quant(pool):
+        note_fallback("latent_prefill_fp8_xla",
+                      detail="latent prefill: the kernel reads bf16 pools")
+    else:
+        note_fallback("latent_prefill_tp_xla",
+                      detail="latent prefill: no shard_map over heads yet")
+    return jax.vmap(
+        lambda qn, qr, bt, sp, kvl, nr: latent_prefill_walk(
+            qn, qr, pool, layer, w_uk, w_uv, bt, sp, kvl, scale=scale,
+            new_rows=nr)
+    )(q_nope, q_rope, block_tables, start_pos, kv_len, new_rows)
 
 
 # ------------------------------------------------------------------- KDA

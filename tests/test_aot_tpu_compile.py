@@ -181,6 +181,54 @@ def test_latent_decode_compiles_for_v5e(v5e, pages_per_seq, dtype):
     assert "%attn_latent" in compiled.as_text()
 
 
+# heads, K nope, V, latent, a row's lanes, page, pages a table
+_JOYAI = (32, 128, 128, 512, 640, 64, 160)
+
+
+@pytest.mark.parametrize(
+    "members,rows,dtype,widths",
+    [
+        # JoyAI-LLM-Flash's cell: the packed program's two members of
+        # 1,024 rows, four tiles of 256 rows against blocks of 4 pages
+        pytest.param(2, 1024, jnp.bfloat16, _JOYAI, id="joyai-2x1024"),
+        # its single-prompt program, which is also a resumed chunk's
+        pytest.param(1, 1024, jnp.bfloat16, _JOYAI, id="joyai-1024"),
+        # a verify's few rows: one tile, padded to the lanes
+        pytest.param(2, 9, jnp.bfloat16, _JOYAI, id="verify-9"),
+        # rows that tiles of 256 do not divide, and float32
+        pytest.param(1, 384, jnp.float32, _JOYAI, id="f32-384"),
+        # heads of 64 + 32 and 64 (MiniCPM3's): padded to the lanes here
+        pytest.param(2, 128, jnp.bfloat16, (40, 64, 64, 256, 384, 16, 64),
+                     id="heads-of-64"),
+    ],
+)
+def test_latent_prefill_compiles_for_v5e(v5e, members, rows, dtype, widths):
+    from dynamo_tpu.ops.attention import (
+        SCOPE_PREFILL_LATENT, latent_prefill_tiling,
+    )
+    from dynamo_tpu.ops.pallas.latent_prefill import latent_prefill_kernel
+
+    heads, dn, dv, dc, lanes, page, pages = widths
+    tq, bp = latent_prefill_tiling(rows, pages, page, kernel=True)
+    i32 = jnp.int32
+    compiled = latent_prefill_kernel.lower(
+        _rows(v5e, members, rows, heads, dn, dtype=dtype),
+        _rows(v5e, members, rows, heads, lanes - dc, dtype=dtype),
+        jax.ShapeDtypeStruct((L, 1025, page, lanes), dtype, sharding=v5e),
+        _rows(v5e, heads, dc, dn, dtype=dtype),
+        _rows(v5e, heads, dc, dv, dtype=dtype),
+        _rows(v5e, members, pages, dtype=i32), _rows(v5e, members, dtype=i32),
+        _rows(v5e, members, dtype=i32),
+        _rows(v5e, members, -(-rows // tq), dtype=i32),
+        layer=_rows(v5e, dtype=i32), scale=0.07, tq=tq, bp=bp,
+        scope=SCOPE_PREFILL_LATENT,
+    ).compile()
+    # named after its own scope, which no configuration's trace_names
+    # holds: the decode kernel's roofline share reads ``attn_latent``
+    text = compiled.as_text()
+    assert "%prefill_latent" in text and "attn_latent" not in text
+
+
 def test_kda_step_compiles_for_v5e(v5e):
     """Solar-Open2's cell: 128 slots on 129 state rows, 64 heads of 128 x
     128 float32 and the convolution tails, both pools aliased in place, a

@@ -66,6 +66,22 @@ def test_serving_phase_on_cpu_at_tiny_spec(monkeypatch):
         chip_smoke.streams_agree(engine, prompt, [5] + one[1:], [8] + one[1:])
 
 
+def test_latent_prefill_reading_on_cpu_at_toy_widths(monkeypatch, capsys):
+    """The smoke's fourth reading, the latent prefill kernel against its
+    XLA twin, rehearsed at toy widths with the kernel interpreted: the
+    four cases run, agree, and print both times a call."""
+    monkeypatch.setenv("DYNAMO_PALLAS", "1")
+    monkeypatch.setattr(chip_smoke, "LATENT", dict(
+        H=4, dn=16, dr=8, dv=12, dc=24, lanes=40, page=4, pages_per_seq=16,
+        rows=16))
+    monkeypatch.setattr(chip_smoke, "LATENT_CASES", (
+        ((0, 16),), ((16, 16),), ((48, 16),), ((0, 16), (0, 9))))
+    chip_smoke.latent_prefill_kernel_vs_twin()
+    out = capsys.readouterr().out
+    assert out.count("kernel vs XLA walk") == 5  # a line a member
+    assert out.count("ms a call, XLA walk") == 4
+
+
 def test_main_fails_without_a_tpu(capsys):
     """No accelerator: non-zero exit and no result line — never a CPU
     run under the chip's name."""

@@ -146,6 +146,11 @@ PROFILE_COUNTERS: dict[str, str] = {
     "prefill_kv.blocks_visited.latent": "the same for the latent "
                                         "family's walk",
     "prefill_kv.blocks_table.latent": "and a whole-table walk's there",
+    "prefill_kv.dispatches.latent": "prefills, packs and verifies "
+                                    "dispatched on the latent family",
+    "prefill_kv.kernel_calls.latent": "those whose attention the Mosaic "
+                                      "kernel served (latent_prefill.py), "
+                                      "not the XLA walk",
     "chunked_prefill.chunks": "chunks of chunked prefills launched (a "
                               "long prompt's first chunk included)",
     "chunked_prefill.chunks_behind_burst": "those launched with a decode "
@@ -380,7 +385,9 @@ METRIC_NAMES: dict[str, str] = {
     "fused_fallback_total": "fused/quantized fast-path downgrades by "
                             "reason (quant_tp_shardmap | "
                             "no_pallas_backend | latent_fp8_xla | "
-                            "latent_tp_xla | recurrent_no_page_offload | "
+                            "latent_tp_xla | latent_prefill_fp8_xla | "
+                            "latent_prefill_tp_xla | "
+                            "recurrent_no_page_offload | "
                             "recurrent_no_page_transfer | "
                             "recurrent_no_spec_decode | "
                             "recurrent_no_ring_prefill | "
