@@ -50,6 +50,7 @@ from dynamo_tpu.guided.runtime import GUIDED_REQUESTS
 from dynamo_tpu.kv_router.protocols import ForwardPassMetrics
 from dynamo_tpu.models import llama
 from dynamo_tpu.models.family import get_family
+from dynamo_tpu.models.regions import SCOPE_FEED
 from dynamo_tpu.runtime.context import (
     Context,
     DeadlineExceeded,
@@ -74,6 +75,7 @@ log = logging.getLogger("dynamo.engine")
 
 
 @jax.jit
+@jax.named_scope(SCOPE_FEED)
 def _chain_feed(valid, prev_combined, tokens_in):
     """Rows still live in an in-flight burst take its last sampled token
     (``prev_combined`` is that burst's [B, 1 + n] fed-column + samples)."""
@@ -81,6 +83,7 @@ def _chain_feed(valid, prev_combined, tokens_in):
 
 
 @jax.jit
+@jax.named_scope(SCOPE_FEED)
 def _wave_feed(mask, idx, wave, tokens_in):
     """Freshly admitted rows take their first token from the admission
     wave's device-side sample ``wave[idx]``."""
@@ -88,6 +91,7 @@ def _wave_feed(mask, idx, wave, tokens_in):
 
 
 @jax.jit
+@jax.named_scope(SCOPE_FEED)
 def _with_fed_column(tokens_in, sampled):
     """[B, 1 + n]: the fed tokens ride along as column 0 of the burst's
     samples, so one download carries both."""
@@ -639,6 +643,9 @@ class InferenceEngine:
           ``prefill_kv.dispatches.latent`` and ``.kernel_calls.latent``:
           its dispatches and those its Mosaic kernel served): see
           ``_count_prefill_kv``.
+        - ``window.at``: the instant this snapshot was taken (secs =
+          ``time.monotonic()``), so that the difference of two snapshots
+          is divided by the time that really lay between them.
         - ``chunked_prefill.chunks`` / ``.chunks_behind_burst`` (calls):
           the chunks of chunked prefills launched, and those of them
           launched with a decode burst in flight (``ahead`` >= 1 on their
@@ -656,6 +663,7 @@ class InferenceEngine:
         snap.setdefault("dispatch.d2h_wait", {"secs": 0.0, "calls": 0})
         snap.setdefault("readmit.d2h_wait", {"secs": 0.0, "calls": 0})
         snap["dispatch.dispatches"] = {"secs": 0.0, "calls": self.dispatches}
+        snap["window.at"] = {"secs": time.monotonic(), "calls": 0}
         c, s = compile_snapshot()
         snap["dispatch.compile"] = {
             "secs": round(s - self._compile_base[1], 4),

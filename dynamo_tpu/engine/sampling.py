@@ -13,12 +13,11 @@ from functools import partial
 import jax
 import jax.numpy as jnp
 
-NEG_INF = -1e30
-
-
 # the sampler's region of a program (decode bursts inline it): op_name
 # metadata only, as models/llama.py's scopes
-SCOPE_SAMPLER = "sampler"
+from dynamo_tpu.models.regions import SCOPE_SAMPLER
+
+NEG_INF = -1e30
 
 
 @partial(jax.jit, static_argnames=("n_top",))

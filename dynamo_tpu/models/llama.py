@@ -24,6 +24,27 @@ import jax.numpy as jnp
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from dynamo_tpu.engine.config import ModelSpec
+from dynamo_tpu.models.regions import (
+    SCOPE_ATTN_FULL,
+    SCOPE_ATTN_WINDOW,
+    SCOPE_BURST,
+    SCOPE_EMBED,
+    SCOPE_HEAD,
+    SCOPE_INDEX,
+    SCOPE_KDA_CONV,
+    SCOPE_KDA_GATES,
+    SCOPE_KDA_PROJ,
+    SCOPE_KV,
+    SCOPE_MLP,
+    SCOPE_MOE_COUNT,
+    SCOPE_MOE_SHARED,
+    SCOPE_NORM,
+    SCOPE_OUT,
+    SCOPE_QKV,
+    SCOPE_RESIDUAL,
+    SCOPE_SAMPLER,
+    SCOPE_STATE_ROWS,
+)
 from dynamo_tpu.ops.attention import (
     causal_attention,
     decode_update_attention,
@@ -267,6 +288,7 @@ class StateRows(NamedTuple):
 STAT_CLOCK, STAT_CLAIMS, STAT_MISSING = 0, 1, 2
 
 
+@jax.named_scope(SCOPE_STATE_ROWS)
 def _claim_state_rows(rows: StateRows, owners, starts, live):
     """Rows for a prefill program's sequences. owners, starts: [N] int32;
     live: [N] bool (the row has tokens). Returns ``(idx [N], fresh [N],
@@ -297,6 +319,7 @@ def _claim_state_rows(rows: StateRows, owners, starts, live):
             StateRows(owner[None], stamp[None], stats[None]))
 
 
+@jax.named_scope(SCOPE_STATE_ROWS)
 def _find_state_rows(rows: StateRows, owners, active):
     """Rows of a decode program's slots (owners: [B] int32, active: [B]
     bool), touched; the trash row for a slot that owns none. Returns (idx
@@ -601,22 +624,32 @@ def rope_spec(
 
 # Stable names for the regions of a layer, in decode and prefill alike:
 # jax.named_scope is metadata on the operations (their op_name in an HLO
-# dump and in a profiler's operation details); it changes no program.
-SCOPE_QKV = "attn_qkv"  # q/k/v projections + rope
-SCOPE_KV = "attn_kv"  # KV write + attention over the paged context
-SCOPE_OUT = "attn_out"  # output projection
-SCOPE_MLP = "mlp"
-SCOPE_HEAD = "head"  # final norm + vocabulary projection
-# inside attn_kv, where a model mixes kinds of attention layer: a Mosaic
+# dump and in a profiler's operation details); it changes no program. The
+# names and their groups are models/regions.py's. Inside attn_kv, where a
+# model mixes kinds of attention layer, attn_window / attn_full: a Mosaic
 # call is named after its innermost scope, so the two kinds' decode
 # kernels read apart in a trace. A model of one kind opens neither, and
 # its kernel keeps its own name (``fused_decode_attention``).
-SCOPE_ATTN_WINDOW = "attn_window"
-SCOPE_ATTN_FULL = "attn_full"
 
 
 def _scope(name: str | None):
     return jax.named_scope(name) if name else contextlib.nullcontext()
+
+
+@jax.named_scope(SCOPE_NORM)
+def _norm(x: jax.Array, w: jax.Array, eps: float) -> jax.Array:
+    """A layer's input norm, under its region's name."""
+    return rms_norm(x, w, eps)
+
+
+@jax.named_scope(SCOPE_RESIDUAL)
+def _add(x: jax.Array, y: jax.Array) -> jax.Array:
+    return x + y
+
+
+@jax.named_scope(SCOPE_EMBED)
+def _embed(params: Params, tokens: jax.Array) -> jax.Array:
+    return params["embed"][tokens]
 
 
 def attn_scope(spec: ModelSpec, li: int) -> str | None:
@@ -652,12 +685,13 @@ def _attn_qkv(
 
 @jax.named_scope(SCOPE_OUT)
 def _o_proj(
-    spec: ModelSpec, lp: Params, attn: jax.Array,
-    h: jax.Array | None = None,
+    spec: ModelSpec, lp: Params, attn: jax.Array, h: jax.Array,
 ) -> jax.Array:
-    """The output projection; where the layer gates its attention output
-    (``w_gate_attn``), by element with a sigmoid of the layer's normed
-    input ``h`` first."""
+    """The output projection of the heads' outputs ``attn`` [..., H, dv]
+    (or already flat), ``h`` [..., d] the layer's normed input; where the
+    layer gates its attention output (``w_gate_attn``), by element with a
+    sigmoid of ``h`` first."""
+    attn = attn.reshape(*h.shape[:-1], -1)
     if "w_gate_attn" in lp:
         attn = attn * jax.nn.sigmoid(
             (h @ lp["w_gate_attn"]).astype(jnp.float32)
@@ -685,8 +719,9 @@ def _ffn(
         if "shared" in lp:
             # the shared expert, whole: every chip of an expert-parallel
             # deployment computes it for its own tokens
-            y = _mlp(lp["shared"], x)
-            out = out + y if counted is None else (out[0] + y, out[1])
+            with jax.named_scope(SCOPE_MOE_SHARED):
+                y = _mlp(lp["shared"], x)
+                out = out + y if counted is None else (out[0] + y, out[1])
         return out
     return _mlp(lp, x)
 
@@ -700,10 +735,12 @@ def _ffn_counting(
     keeps = isinstance(k_pages, KindPools) and k_pages.counts.shape[-1] > 0
     if "moe" in lp and keeps:
         y, row = _ffn(spec, lp, x, mesh=mesh, counted=counted)
-        step = jnp.ones((1,), jnp.int32)
-        return y, k_pages._replace(counts=k_pages.counts.at[li, phase].add(
-            jnp.concatenate([row, step])
-        ))
+        with jax.named_scope(SCOPE_MOE_COUNT):
+            step = jnp.ones((1,), jnp.int32)
+            counts = k_pages.counts.at[li, phase].add(
+                jnp.concatenate([row, step])
+            )
+        return y, k_pages._replace(counts=counts)
     return _ffn(spec, lp, x, mesh=mesh), k_pages
 
 
@@ -752,25 +789,33 @@ def _kda_inputs(spec: ModelSpec, lp: Params, h: jax.Array, tail: jax.Array):
     f32 = jnp.float32
     N, T, _ = h.shape
     H, D = spec.kda_heads, spec.kda_head_dim
-    x = jnp.concatenate([h @ lp["wq"], h @ lp["wk"], h @ lp["wv"]], axis=-1)
-    ext = jnp.concatenate([tail.astype(x.dtype), x], axis=1)
-    taps = jnp.concatenate(
-        [lp["conv_q"], lp["conv_k"], lp["conv_v"]], axis=1
-    ).astype(f32)
-    conv = sum(
-        taps[i] * ext[:, i:i + T].astype(f32) for i in range(spec.kda_conv)
-    )
-    q, k, v = (
-        y.reshape(N, T, H, D)
-        for y in jnp.split(jax.nn.silu(conv), 3, axis=-1)
-    )
-    q = q * jax.lax.rsqrt(jnp.sum(q * q, -1, keepdims=True) + 1e-6) * D ** -0.5
-    k = k * jax.lax.rsqrt(jnp.sum(k * k, -1, keepdims=True) + 1e-6)
-    f = ((h @ lp["w_f_down"]) @ lp["w_f_up"]).astype(f32) + lp["dt_bias"]
-    g = -jnp.exp(lp["a_log"])[:, None] * jax.nn.softplus(f).reshape(N, T, H, D)
-    beta = jax.nn.sigmoid((h @ lp["w_beta"]).astype(f32))
-    if spec.kda_neg_eigval:
-        beta = 2.0 * beta
+    with jax.named_scope(SCOPE_KDA_PROJ):
+        x = jnp.concatenate(
+            [h @ lp["wq"], h @ lp["wk"], h @ lp["wv"]], axis=-1
+        )
+    with jax.named_scope(SCOPE_KDA_CONV):
+        ext = jnp.concatenate([tail.astype(x.dtype), x], axis=1)
+        taps = jnp.concatenate(
+            [lp["conv_q"], lp["conv_k"], lp["conv_v"]], axis=1
+        ).astype(f32)
+        conv = sum(
+            taps[i] * ext[:, i:i + T].astype(f32)
+            for i in range(spec.kda_conv)
+        )
+        q, k, v = (
+            y.reshape(N, T, H, D)
+            for y in jnp.split(jax.nn.silu(conv), 3, axis=-1)
+        )
+        q = q * jax.lax.rsqrt(
+            jnp.sum(q * q, -1, keepdims=True) + 1e-6) * D ** -0.5
+        k = k * jax.lax.rsqrt(jnp.sum(k * k, -1, keepdims=True) + 1e-6)
+    with jax.named_scope(SCOPE_KDA_GATES):
+        f = ((h @ lp["w_f_down"]) @ lp["w_f_up"]).astype(f32) + lp["dt_bias"]
+        g = -jnp.exp(lp["a_log"])[:, None] * jax.nn.softplus(f).reshape(
+            N, T, H, D)
+        beta = jax.nn.sigmoid((h @ lp["w_beta"]).astype(f32))
+        if spec.kda_neg_eigval:
+            beta = 2.0 * beta
     return q, k, v, g, beta, ext
 
 
@@ -801,15 +846,18 @@ def _kda_prefill(
     rows. h: [N, T, d]; idx, fresh, num_tokens: [N]. Returns (out [N, T,
     d], s_pool, c_pool)."""
     N, T, _ = h.shape
-    tail = jnp.where(
-        fresh[:, None, None], 0, c_pool[lj, idx].reshape(N, -1, ext_width(c_pool))
-    )
     with jax.named_scope(SCOPE_QKV):
+        with jax.named_scope(SCOPE_KDA_CONV):
+            tail = jnp.where(
+                fresh[:, None, None], 0,
+                c_pool[lj, idx].reshape(N, -1, ext_width(c_pool)),
+            )
         q, k, v, g, beta, ext = _kda_inputs(spec, lp, h, tail)
-    # a padded token leaves the state as it was
-    real = jnp.arange(T)[None, :] < num_tokens[:, None]
-    g = jnp.where(real[..., None, None], g, 0.0)
-    beta = jnp.where(real[..., None], beta, 0.0)
+        with jax.named_scope(SCOPE_KDA_GATES):
+            # a padded token leaves the state as it was
+            real = jnp.arange(T)[None, :] < num_tokens[:, None]
+            g = jnp.where(real[..., None, None], g, 0.0)
+            beta = jnp.where(real[..., None], beta, 0.0)
     with jax.named_scope(SCOPE_KV):
         o, s_pool = kda_chunk_prefill(
             q, k, v, g, beta, s_pool, idx, fresh, layer=lj
@@ -890,8 +938,6 @@ def prefill_forward_impl(
     padded positions >= T drop).
     """
     T = tokens.shape[0]
-    idx = jnp.arange(T)
-    positions = start_pos + idx  # absolute positions of new tokens
     page_size = page_size_of(k_pages)
 
     # Page-granular KV write: prefix-cache hits and chunk boundaries are
@@ -903,18 +949,22 @@ def prefill_forward_impl(
     # masked in attention, overwritten as decode appends. Fully-padded
     # pages go to the trash page (duplicate trash indices are fine).
     n_pg = T // page_size
-    page_starts = start_pos + jnp.arange(n_pg) * page_size
-    pg_idx_raw = block_table[page_starts // page_size]
-    safe_pg = jnp.where(
-        page_starts < start_pos + num_tokens, pg_idx_raw, TRASH_PAGE
-    )
-    real = idx < num_tokens
-    valid_tok = real.reshape(n_pg, page_size)
+    with jax.named_scope(SCOPE_INDEX):
+        idx = jnp.arange(T)
+        positions = start_pos + idx  # absolute positions of new tokens
+        page_starts = start_pos + jnp.arange(n_pg) * page_size
+        pg_idx_raw = block_table[page_starts // page_size]
+        safe_pg = jnp.where(
+            page_starts < start_pos + num_tokens, pg_idx_raw, TRASH_PAGE
+        )
+        real = idx < num_tokens
+        valid_tok = real.reshape(n_pg, page_size)
 
-    x = params["embed"][tokens]  # [T, d]
+    x = _embed(params, tokens)  # [T, d]
     if mm_embeds is not None:
         x = x.at[mm_pos].set(mm_embeds.astype(x.dtype), mode="drop")
-    kv_len = start_pos + num_tokens
+    with jax.named_scope(SCOPE_INDEX):
+        kv_len = start_pos + num_tokens
     if spec.has_recurrent:
         idx, fresh, rows = _claim_state_rows(
             k_pages.rows, _state_owner(block_table)[None], start_pos[None],
@@ -923,7 +973,7 @@ def prefill_forward_impl(
         k_pages = k_pages._replace(rows=rows)
 
     for li, lp in enumerate(params["layers"]):
-        h = rms_norm(x, lp["attn_norm"], spec.rms_eps)
+        h = _norm(x, lp["attn_norm"], spec.rms_eps)
         kp, vp, lj = _layer_pools(spec, k_pages, v_pages, li)
         if spec.kind(li).recurrent:
             mix, kp, vp = _kda_prefill(
@@ -939,17 +989,18 @@ def prefill_forward_impl(
                     spec, li, lp, q, k, v, kp, vp, lj, block_table,
                     positions, kv_len,
                 )
-            mix = _o_proj(spec, lp, attn.reshape(T, -1), h)
+            mix = _o_proj(spec, lp, attn, h)
         k_pages, v_pages = _put_pools(spec, k_pages, v_pages, li, kp, vp)
-        x = x + mix
-        h = rms_norm(x, lp["mlp_norm"], spec.rms_eps)
+        x = _add(x, mix)
+        h = _norm(x, lp["mlp_norm"], spec.rms_eps)
         f, k_pages = _ffn_counting(
             spec, li, lp, h, k_pages, COUNT_PREFILL, real, mesh
         )
-        x = x + f
+        x = _add(x, f)
 
-    last = jnp.clip(num_tokens - 1, 0, T - 1)
-    logits = _logits(spec, params, x[last])  # [V]
+    with jax.named_scope(SCOPE_HEAD):
+        last = jnp.clip(num_tokens - 1, 0, T - 1)
+        logits = _logits(spec, params, x[last])  # [V]
     logits = _replicate(logits, mesh)
     return logits, k_pages, v_pages, _no_drops(mesh)
 
@@ -1013,22 +1064,25 @@ def prefill_forward_batch_impl(
     """
     N, T = tokens.shape
     page_size = page_size_of(k_pages)
-    idx = jnp.arange(T)
-    positions = start_pos[:, None] + idx[None, :]  # [N, T]
     n_pg = T // page_size
-    page_starts = start_pos[:, None] + (
-        jnp.arange(n_pg) * page_size
-    )[None, :]  # [N, n_pg]
-    pg_idx_raw = jnp.take_along_axis(
-        block_tables, page_starts // page_size, axis=1
-    )
-    valid_pg = page_starts < (start_pos + num_tokens)[:, None]
-    safe_pg = jnp.where(valid_pg, pg_idx_raw, TRASH_PAGE).reshape(N * n_pg)
-    real = idx[None, :] < num_tokens[:, None]  # [N, T]
-    valid_tok = real.reshape(N * n_pg, page_size)
+    with jax.named_scope(SCOPE_INDEX):
+        idx = jnp.arange(T)
+        positions = start_pos[:, None] + idx[None, :]  # [N, T]
+        page_starts = start_pos[:, None] + (
+            jnp.arange(n_pg) * page_size
+        )[None, :]  # [N, n_pg]
+        pg_idx_raw = jnp.take_along_axis(
+            block_tables, page_starts // page_size, axis=1
+        )
+        valid_pg = page_starts < (start_pos + num_tokens)[:, None]
+        safe_pg = jnp.where(
+            valid_pg, pg_idx_raw, TRASH_PAGE).reshape(N * n_pg)
+        real = idx[None, :] < num_tokens[:, None]  # [N, T]
+        valid_tok = real.reshape(N * n_pg, page_size)
 
-    x = params["embed"][tokens]  # [N, T, d]
-    kv_len = start_pos + num_tokens  # [N]
+    x = _embed(params, tokens)  # [N, T, d]
+    with jax.named_scope(SCOPE_INDEX):
+        kv_len = start_pos + num_tokens  # [N]
     if spec.has_recurrent:
         idx, fresh, rows = _claim_state_rows(
             k_pages.rows, _state_owner(block_tables), start_pos,
@@ -1037,7 +1091,7 @@ def prefill_forward_batch_impl(
         k_pages = k_pages._replace(rows=rows)
 
     for li, lp in enumerate(params["layers"]):
-        h = rms_norm(x, lp["attn_norm"], spec.rms_eps)
+        h = _norm(x, lp["attn_norm"], spec.rms_eps)
         kp, vp, lj = _layer_pools(spec, k_pages, v_pages, li)
         if spec.kind(li).recurrent:
             mix, kp, vp = _kda_prefill(
@@ -1055,19 +1109,20 @@ def prefill_forward_batch_impl(
                         kvl_i,
                     )
                 )(q, k, v, block_tables, positions, kv_len)
-            mix = _o_proj(spec, lp, attn.reshape(N, T, -1), h)
+            mix = _o_proj(spec, lp, attn, h)
         k_pages, v_pages = _put_pools(spec, k_pages, v_pages, li, kp, vp)
-        x = x + mix
-        h = rms_norm(x, lp["mlp_norm"], spec.rms_eps)
+        x = _add(x, mix)
+        h = _norm(x, lp["mlp_norm"], spec.rms_eps)
         f, k_pages = _ffn_counting(
             spec, li, lp, h.reshape(N * T, -1), k_pages, COUNT_PREFILL,
             real.reshape(N * T), mesh,
         )
-        x = x + f.reshape(N, T, -1)
+        x = _add(x, f.reshape(N, T, -1))
 
-    last = jnp.clip(num_tokens - 1, 0, T - 1)  # [N]
-    x_last = jnp.take_along_axis(x, last[:, None, None], axis=1)[:, 0]
-    logits = _logits(spec, params, x_last)  # [N, V]
+    with jax.named_scope(SCOPE_HEAD):
+        last = jnp.clip(num_tokens - 1, 0, T - 1)  # [N]
+        x_last = jnp.take_along_axis(x, last[:, None, None], axis=1)[:, 0]
+        logits = _logits(spec, params, x_last)  # [N, V]
     logits = _replicate(logits, mesh)
     return logits, k_pages, v_pages, _no_drops(mesh)
 
@@ -1112,20 +1167,20 @@ def prefill_forward_ring_impl(
     valid_tok = (idx < num_tokens).reshape(n_pg, page_size)
 
     sp_spec = NamedSharding(mesh, P("sp", None))
-    x = params["embed"][tokens]
+    x = _embed(params, tokens)
     x = jax.lax.with_sharding_constraint(x, sp_spec)
 
     for li, lp in enumerate(params["layers"]):
-        h = rms_norm(x, lp["attn_norm"], spec.rms_eps)
+        h = _norm(x, lp["attn_norm"], spec.rms_eps)
         q, k, v = _attn_qkv(spec, li, lp, h, idx)
         kp, vp, lj = _layer_pools(spec, k_pages, v_pages, li)
         kp = _set_page_tiles(kp, lj, safe_pg, k, page_size, valid_tok)
         vp = _set_page_tiles(vp, lj, safe_pg, v, page_size, valid_tok)
         k_pages, v_pages = _put_pools(spec, k_pages, v_pages, li, kp, vp)
         attn = ring_attention(q, k, v, mesh=mesh)
-        x = x + _o_proj(spec, lp, attn.reshape(T, -1), h)
-        h = rms_norm(x, lp["mlp_norm"], spec.rms_eps)
-        x = x + _ffn(spec, lp, h, mesh=mesh)
+        x = _add(x, _o_proj(spec, lp, attn, h))
+        h = _norm(x, lp["mlp_norm"], spec.rms_eps)
+        x = _add(x, _ffn(spec, lp, h, mesh=mesh))
         x = jax.lax.with_sharding_constraint(x, sp_spec)
 
     last = jnp.clip(num_tokens - 1, 0, T - 1)
@@ -1192,11 +1247,11 @@ def verify_forward_impl(
     safe_pg = safe_pg2.reshape(N * W)
     offs = offs2.reshape(N * W)
 
-    x = params["embed"][tokens]  # [N, W, d]
+    x = _embed(params, tokens)  # [N, W, d]
     kv_len = start_pos + num_tokens  # [N]
 
     for li, lp in enumerate(params["layers"]):
-        h = rms_norm(x, lp["attn_norm"], spec.rms_eps)
+        h = _norm(x, lp["attn_norm"], spec.rms_eps)
         q, k, v = _attn_qkv(spec, li, lp, h, positions)
         kp, vp, lj = _layer_pools(spec, k_pages, v_pages, li)
         if is_quant(kp):
@@ -1225,11 +1280,11 @@ def verify_forward_impl(
             )
         )(q, k, v, block_tables, positions, kv_len)
         k_pages, v_pages = _put_pools(spec, k_pages, v_pages, li, kp, vp)
-        x = x + _o_proj(spec, lp, attn.reshape(N, W, -1), h)
-        h = rms_norm(x, lp["mlp_norm"], spec.rms_eps)
-        x = x + _ffn(
+        x = _add(x, _o_proj(spec, lp, attn, h))
+        h = _norm(x, lp["mlp_norm"], spec.rms_eps)
+        x = _add(x, _ffn(
             spec, lp, h.reshape(N * W, -1), mesh=mesh
-        ).reshape(N, W, -1)
+        ).reshape(N, W, -1))
 
     logits = _logits(spec, params, x)  # [N, W, V]
     if allowed is not None:
@@ -1271,18 +1326,18 @@ def decode_forward_impl(
         )
         k_pages = k_pages._replace(rows=rows)
     page_size = page_size_of(k_pages)
-    positions = seq_lens - 1  # position of the new token
+    with jax.named_scope(SCOPE_INDEX):
+        positions = seq_lens - 1  # position of the new token
+        page_idx_raw = jnp.take_along_axis(
+            block_tables, (positions // page_size)[:, None], axis=1
+        )[:, 0]
+        safe_page = jnp.where(active, page_idx_raw, TRASH_PAGE)
+        offset = positions % page_size
 
-    page_idx_raw = jnp.take_along_axis(
-        block_tables, (positions // page_size)[:, None], axis=1
-    )[:, 0]
-    safe_page = jnp.where(active, page_idx_raw, TRASH_PAGE)
-    offset = positions % page_size
-
-    x = params["embed"][tokens]  # [B, d]
+    x = _embed(params, tokens)  # [B, d]
 
     for li, lp in enumerate(params["layers"]):
-        h = rms_norm(x, lp["attn_norm"], spec.rms_eps)
+        h = _norm(x, lp["attn_norm"], spec.rms_eps)
         kp, vp, lj = _layer_pools(spec, k_pages, v_pages, li)
         if spec.kind(li).recurrent:
             mix, kp, vp = _kda_decode(spec, lp, h, kp, vp, lj, state_idx)
@@ -1299,14 +1354,14 @@ def decode_forward_impl(
                     window=spec.kind(li).window, sinks=lp.get("sinks"),
                     scope=attn_scope(spec, li),
                 )
-            mix = _o_proj(spec, lp, attn.reshape(B, -1), h)
+            mix = _o_proj(spec, lp, attn, h)
         k_pages, v_pages = _put_pools(spec, k_pages, v_pages, li, kp, vp)
-        x = x + mix
-        h = rms_norm(x, lp["mlp_norm"], spec.rms_eps)
+        x = _add(x, mix)
+        h = _norm(x, lp["mlp_norm"], spec.rms_eps)
         f, k_pages = _ffn_counting(
             spec, li, lp, h, k_pages, COUNT_DECODE, active, mesh
         )
-        x = x + f
+        x = _add(x, f)
 
     logits = _logits(spec, params, x)  # [B, V]
     return logits, k_pages, v_pages
@@ -1378,19 +1433,24 @@ def decode_steps_impl(
             spec, params, toks, block_tables, lens, kp, vp, active, mesh=mesh,
             state_idx=state_idx,
         )
-        if allowed is not None:
-            logits = jnp.where(allowed, logits, -1e30)
-        nxt = sample_tokens(
-            logits, temperature, top_k, top_p, seeds, steps + i
-        )
-        nxt = jnp.where(active, nxt, toks)
-        out = out.at[:, i].set(nxt)
-        if n_logprobs > 0:
-            picked, top_i, top_v = token_logprobs(logits, nxt, n_logprobs)
-            lp = lp.at[:, i].set(picked)
-            ti = ti.at[:, i].set(top_i)
-            tv = tv.at[:, i].set(top_v)
-        return nxt, lens + active.astype(jnp.int32), kp, vp, out, lp, ti, tv
+        # the carry between steps is the burst's own region; the sampler's
+        # functions open theirs beneath it (the innermost name is the region)
+        with jax.named_scope(SCOPE_BURST):
+            if allowed is not None:
+                with jax.named_scope(SCOPE_SAMPLER):
+                    logits = jnp.where(allowed, logits, -1e30)
+            nxt = sample_tokens(
+                logits, temperature, top_k, top_p, seeds, steps + i
+            )
+            nxt = jnp.where(active, nxt, toks)
+            out = out.at[:, i].set(nxt)
+            if n_logprobs > 0:
+                picked, top_i, top_v = token_logprobs(logits, nxt, n_logprobs)
+                lp = lp.at[:, i].set(picked)
+                ti = ti.at[:, i].set(top_i)
+                tv = tv.at[:, i].set(top_v)
+            lens = lens + active.astype(jnp.int32)
+        return nxt, lens, kp, vp, out, lp, ti, tv
 
     _toks, _lens, k_pages, v_pages, out, lp, ti, tv = jax.lax.fori_loop(
         0, n_steps, body,
@@ -1489,7 +1549,7 @@ def _whole_mixer(spec: ModelSpec, li: int, lp: Params, h, positions, n):
         q, k, v, positions, n,
         window=spec.kind(li).window, sinks=lp.get("sinks"),
     )
-    return _o_proj(spec, lp, attn.reshape(h.shape[0], -1), h)
+    return _o_proj(spec, lp, attn, h)
 
 
 def embed_forward_impl(
@@ -1505,12 +1565,12 @@ def embed_forward_impl(
     Returns [hidden_size] float32."""
     T = tokens.shape[0]
     positions = jnp.arange(T)
-    x = params["embed"][tokens]
+    x = _embed(params, tokens)
     for li, lp in enumerate(params["layers"]):
-        h = rms_norm(x, lp["attn_norm"], spec.rms_eps)
-        x = x + _whole_mixer(spec, li, lp, h, positions, num_tokens)
-        h = rms_norm(x, lp["mlp_norm"], spec.rms_eps)
-        x = x + _ffn(spec, lp, h)
+        h = _norm(x, lp["attn_norm"], spec.rms_eps)
+        x = _add(x, _whole_mixer(spec, li, lp, h, positions, num_tokens))
+        h = _norm(x, lp["mlp_norm"], spec.rms_eps)
+        x = _add(x, _ffn(spec, lp, h))
     xn = rms_norm(x, params["final_norm"], spec.rms_eps).astype(jnp.float32)
     mask = (positions < num_tokens)[:, None].astype(jnp.float32)
     pooled = (xn * mask).sum(axis=0) / jnp.maximum(mask.sum(), 1.0)
@@ -1530,12 +1590,12 @@ def reference_forward(
     tests. tokens: [T] -> logits [T, V]."""
     T = tokens.shape[0]
     positions = jnp.arange(T)
-    x = params["embed"][tokens]
+    x = _embed(params, tokens)
     for li, lp in enumerate(params["layers"]):
-        h = rms_norm(x, lp["attn_norm"], spec.rms_eps)
-        x = x + _whole_mixer(spec, li, lp, h, positions, jnp.asarray(T))
-        h = rms_norm(x, lp["mlp_norm"], spec.rms_eps)
-        x = x + _ffn(spec, lp, h)
+        h = _norm(x, lp["attn_norm"], spec.rms_eps)
+        x = _add(x, _whole_mixer(spec, li, lp, h, positions, jnp.asarray(T)))
+        h = _norm(x, lp["mlp_norm"], spec.rms_eps)
+        x = _add(x, _ffn(spec, lp, h))
     xn = rms_norm(x, params["final_norm"], spec.rms_eps)
     head = params["embed"].T if spec.tie_embeddings else params["lm_head"]
     return (xn @ head).astype(jnp.float32)

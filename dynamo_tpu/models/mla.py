@@ -67,8 +67,14 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from dynamo_tpu.engine.config import ModelSpec
 from dynamo_tpu.models.llama import (
-    COUNT_DECODE, COUNT_PREFILL, TRASH_PAGE, _draw, _replicate, rms_norm,
-    rope_spec,
+    COUNT_DECODE, COUNT_PREFILL, TRASH_PAGE, _add, _draw, _embed, _norm,
+    _replicate, rms_norm, rope_spec,
+)
+# the regions this family opens (jax.named_scope: metadata only)
+from dynamo_tpu.models.regions import (
+    SCOPE_BURST, SCOPE_HEAD, SCOPE_INDEX, SCOPE_KV, SCOPE_LATENT_ABSORB,
+    SCOPE_LATENT_KV, SCOPE_LATENT_Q, SCOPE_MLP, SCOPE_MOE_COUNT,
+    SCOPE_MOE_SHARED, SCOPE_OUT, SCOPE_SAMPLER,
 )
 from dynamo_tpu.ops.attention import (
     latent_decode_schedule, latent_decode_update_attention,
@@ -320,6 +326,7 @@ def cache_shardings(mesh: Mesh, kv_dtype: str = "bf16"):
 # --------------------------------------------------------------- pieces
 
 
+@jax.named_scope(SCOPE_LATENT_Q)
 def _q_heads(spec: ModelSpec, lp: Params, h: jax.Array, positions) -> tuple:
     """h [..., d] at ``positions`` [...] -> (q_nope [..., H, dn], q_rope
     [..., H, dr]) with RoPE applied."""
@@ -333,6 +340,7 @@ def _q_heads(spec: ModelSpec, lp: Params, h: jax.Array, positions) -> tuple:
     return q_nope, rope_spec(spec, q_rope, positions)
 
 
+@jax.named_scope(SCOPE_LATENT_KV)
 def _latent_row(spec: ModelSpec, lp: Params, h: jax.Array, positions):
     """-> cache rows [..., d_c + d_r]: normalized latent + roped shared
     key."""
@@ -341,6 +349,13 @@ def _latent_row(spec: ModelSpec, lp: Params, h: jax.Array, positions):
     c = rms_norm(kv_a[..., :dc], lp["kv_norm"], spec.rms_eps)
     k_r = rope_spec(spec, kv_a[..., None, dc:], positions)[..., 0, :]
     return jnp.concatenate([c, k_r], axis=-1)
+
+
+@jax.named_scope(SCOPE_OUT)
+def _o_proj(lp: Params, attn: jax.Array, x: jax.Array) -> jax.Array:
+    """The heads' outputs ``attn`` [..., H, dv] (or already flat) through
+    the output projection, in the residual stream ``x``'s type."""
+    return attn.reshape(*x.shape[:-1], -1).astype(x.dtype) @ lp["wo"]
 
 
 # A layer's two halves as jits of their own inside the programs: the
@@ -390,6 +405,7 @@ def _dense_attention(
     return jnp.einsum("ths,shv->thv", jax.nn.softmax(scores, axis=-1), v)
 
 
+@jax.named_scope(SCOPE_MLP)
 def _ffn(
     spec: ModelSpec, li: int, lp: Params, x: jax.Array,
     mesh: Mesh | None = None, counted: jax.Array | None = None,
@@ -406,9 +422,10 @@ def _ffn(
     out, row = out if counted is not None else (out, None)
     if "shared" in lp:
         sh = lp["shared"]
-        out = out + (
-            jax.nn.silu(x @ sh["w_gate"]) * (x @ sh["w_up"])
-        ) @ sh["w_down"]
+        with jax.named_scope(SCOPE_MOE_SHARED):
+            out = out + (
+                jax.nn.silu(x @ sh["w_gate"]) * (x @ sh["w_up"])
+            ) @ sh["w_down"]
     return out if counted is None else (out, row)
 
 
@@ -419,8 +436,9 @@ def _ffn_counting_jit(spec, li, mp, x, counts, counted, *, phase, mesh,
     if not counting:
         return _ffn(spec, li, mp, x, mesh), counts
     y, row = _ffn(spec, li, mp, x, mesh, counted=counted)
-    step = jnp.ones((1,), jnp.int32)
-    return y, counts.at[li, phase].add(jnp.concatenate([row, step]))
+    with jax.named_scope(SCOPE_MOE_COUNT):
+        step = jnp.ones((1,), jnp.int32)
+        return y, counts.at[li, phase].add(jnp.concatenate([row, step]))
 
 
 def _ffn_counting(
@@ -438,6 +456,7 @@ def _ffn_counting(
     )
 
 
+@jax.named_scope(SCOPE_KV)
 def _seq_attention(
     spec: ModelSpec, li: int, lp: Params, q_nope, q_rope, new_rows, cache,
     block_tables, start_pos, kv_len, mesh: Mesh | None = None,
@@ -475,19 +494,20 @@ def reference_forward(
     ``_q_heads``, ``_latent_row`` and ``_ffn`` with them."""
     T = tokens.shape[0]
     positions = jnp.arange(T)
-    x = params["embed"][tokens]
+    x = _embed(params, tokens)
     mask = positions[:, None] >= positions[None, :]
     for li, lp in enumerate(params["layers"]):
-        h = rms_norm(x, lp["attn_norm"], spec.rms_eps)
+        h = _norm(x, lp["attn_norm"], spec.rms_eps)
         q_nope, q_rope = _q_heads(spec, lp, h, positions)
         rows = _latent_row(spec, lp, h, positions)
         attn = _dense_attention(spec, lp, q_nope, q_rope, rows, mask)
-        x = x + attn.reshape(T, -1).astype(x.dtype) @ lp["wo"]
-        hh = rms_norm(x, lp["mlp_norm"], spec.rms_eps)
-        x = x + _ffn(spec, li, lp, hh)
+        x = _add(x, _o_proj(lp, attn, x))
+        hh = _norm(x, lp["mlp_norm"], spec.rms_eps)
+        x = _add(x, _ffn(spec, li, lp, hh))
     return _logits_all(spec, params, x)
 
 
+@jax.named_scope(SCOPE_HEAD)
 def _logits_all(spec, params, x):
     xn = rms_norm(x, params["final_norm"], spec.rms_eps)
     head = params["embed"].T if spec.tie_embeddings else params["lm_head"]
@@ -512,38 +532,43 @@ def prefill_forward_impl(
     page-granularly; returns (last_logits, cache[, counts]). Mirrors
     llama.prefill_forward_impl."""
     T = tokens.shape[0]
-    idx = jnp.arange(T)
-    positions = start_pos + idx
     page_size = cache.shape[2]
     n_pg = T // page_size
-    page_starts = start_pos + jnp.arange(n_pg) * page_size
-    pg_idx = block_table[page_starts // page_size]
-    safe_pg = jnp.where(
-        page_starts < start_pos + num_tokens, pg_idx, TRASH_PAGE
-    )
-    real = idx < num_tokens
-    x = params["embed"][tokens]
-    kv_len = start_pos + num_tokens
-    for li, lp in enumerate(params["layers"]):
-        h = rms_norm(x, lp["attn_norm"], spec.rms_eps)
-        q_nope, q_rope, new_rows = _attn_inputs(spec, lp, h, positions)
-        cache = _set_latent_tiles(
-            cache, li, safe_pg,
-            new_rows.reshape(n_pg, page_size, -1),
-            real.reshape(n_pg, page_size),
+    with jax.named_scope(SCOPE_INDEX):
+        idx = jnp.arange(T)
+        positions = start_pos + idx
+        page_starts = start_pos + jnp.arange(n_pg) * page_size
+        pg_idx = block_table[page_starts // page_size]
+        safe_pg = jnp.where(
+            page_starts < start_pos + num_tokens, pg_idx, TRASH_PAGE
         )
+        real = idx < num_tokens
+    x = _embed(params, tokens)
+    with jax.named_scope(SCOPE_INDEX):
+        kv_len = start_pos + num_tokens
+    for li, lp in enumerate(params["layers"]):
+        h = _norm(x, lp["attn_norm"], spec.rms_eps)
+        q_nope, q_rope, new_rows = _attn_inputs(spec, lp, h, positions)
+        with jax.named_scope(SCOPE_KV):
+            cache = _set_latent_tiles(
+                cache, li, safe_pg,
+                new_rows.reshape(n_pg, page_size, -1),
+                real.reshape(n_pg, page_size),
+            )
         attn = _seq_attention(
             spec, li, lp, q_nope[None], q_rope[None], new_rows[None], cache,
             block_table[None], start_pos[None], kv_len[None], mesh,
         )[0]
-        x = x + attn.astype(x.dtype) @ lp["wo"]
-        hh = rms_norm(x, lp["mlp_norm"], spec.rms_eps)
+        x = _add(x, _o_proj(lp, attn, x))
+        hh = _norm(x, lp["mlp_norm"], spec.rms_eps)
         y, counts = _ffn_counting(
             spec, li, lp, hh, counts, COUNT_PREFILL, real, mesh
         )
-        x = x + y
-    last = jnp.clip(num_tokens - 1, 0, T - 1)
-    logits = _replicate(_logits_all(spec, params, x)[last], mesh)
+        x = _add(x, y)
+    with jax.named_scope(SCOPE_HEAD):
+        last = jnp.clip(num_tokens - 1, 0, T - 1)
+        logits = _logits_all(spec, params, x)[last]
+    logits = _replicate(logits, mesh)
     return _with_counts((logits, cache), counts)
 
 
@@ -572,44 +597,50 @@ def prefill_forward_batch_impl(
     counts])."""
     N, T = tokens.shape
     page_size = cache.shape[2]
-    idx = jnp.arange(T)
-    positions = start_pos[:, None] + idx[None, :]  # [N, T]
     n_pg = T // page_size
-    page_starts = start_pos[:, None] + (
-        jnp.arange(n_pg) * page_size
-    )[None, :]  # [N, n_pg]
-    pg_idx_raw = jnp.take_along_axis(
-        block_tables, page_starts // page_size, axis=1
-    )
-    valid_pg = page_starts < (start_pos + num_tokens)[:, None]
-    safe_pg = jnp.where(valid_pg, pg_idx_raw, TRASH_PAGE).reshape(N * n_pg)
-    real = idx[None, :] < num_tokens[:, None]  # [N, T]
-
-    x = params["embed"][tokens]  # [N, T, d]
-    kv_len = start_pos + num_tokens  # [N]
-    for li, lp in enumerate(params["layers"]):
-        h = rms_norm(x, lp["attn_norm"], spec.rms_eps)
-        q_nope, q_rope, new_rows = _attn_inputs(spec, lp, h, positions)
-        cache = _set_latent_tiles(
-            cache, li, safe_pg,
-            new_rows.reshape(N * n_pg, page_size, -1),
-            real.reshape(N * n_pg, page_size),
+    with jax.named_scope(SCOPE_INDEX):
+        idx = jnp.arange(T)
+        positions = start_pos[:, None] + idx[None, :]  # [N, T]
+        page_starts = start_pos[:, None] + (
+            jnp.arange(n_pg) * page_size
+        )[None, :]  # [N, n_pg]
+        pg_idx_raw = jnp.take_along_axis(
+            block_tables, page_starts // page_size, axis=1
         )
+        valid_pg = page_starts < (start_pos + num_tokens)[:, None]
+        safe_pg = jnp.where(
+            valid_pg, pg_idx_raw, TRASH_PAGE).reshape(N * n_pg)
+        real = idx[None, :] < num_tokens[:, None]  # [N, T]
+
+    x = _embed(params, tokens)  # [N, T, d]
+    with jax.named_scope(SCOPE_INDEX):
+        kv_len = start_pos + num_tokens  # [N]
+    for li, lp in enumerate(params["layers"]):
+        h = _norm(x, lp["attn_norm"], spec.rms_eps)
+        q_nope, q_rope, new_rows = _attn_inputs(spec, lp, h, positions)
+        with jax.named_scope(SCOPE_KV):
+            cache = _set_latent_tiles(
+                cache, li, safe_pg,
+                new_rows.reshape(N * n_pg, page_size, -1),
+                real.reshape(N * n_pg, page_size),
+            )
         attn = _seq_attention(
             spec, li, lp, q_nope, q_rope, new_rows, cache, block_tables,
             start_pos, kv_len, mesh,
         )
-        x = x + attn.astype(x.dtype) @ lp["wo"]
-        hh = rms_norm(x, lp["mlp_norm"], spec.rms_eps)
+        x = _add(x, _o_proj(lp, attn, x))
+        hh = _norm(x, lp["mlp_norm"], spec.rms_eps)
         y, counts = _ffn_counting(
             spec, li, lp, hh.reshape(N * T, -1), counts, COUNT_PREFILL,
             real.reshape(N * T), mesh,
         )
-        x = x + y.reshape(N, T, -1)
+        x = _add(x, y.reshape(N, T, -1))
 
-    last = jnp.clip(num_tokens - 1, 0, T - 1)  # [N]
-    x_last = jnp.take_along_axis(x, last[:, None, None], axis=1)[:, 0]
-    logits = _replicate(_logits_all(spec, params, x_last), mesh)
+    with jax.named_scope(SCOPE_HEAD):
+        last = jnp.clip(num_tokens - 1, 0, T - 1)  # [N]
+        x_last = jnp.take_along_axis(x, last[:, None, None], axis=1)[:, 0]
+        logits = _logits_all(spec, params, x_last)
+    logits = _replicate(logits, mesh)
     return _with_counts((logits, cache), counts)
 
 
@@ -649,32 +680,33 @@ def verify_forward_impl(
     safe_pg = jnp.where(valid, pg_idx_raw, TRASH_PAGE).reshape(N * W)
     offs = (positions % page_size).reshape(N * W)
 
-    x = params["embed"][tokens]  # [N, W, d]
+    x = _embed(params, tokens)  # [N, W, d]
     kv_len = start_pos + num_tokens  # [N]
     for li, lp in enumerate(params["layers"]):
-        h = rms_norm(x, lp["attn_norm"], spec.rms_eps)
+        h = _norm(x, lp["attn_norm"], spec.rms_eps)
         q_nope, q_rope, new_rows = _attn_inputs(spec, lp, h, positions)
         flat = new_rows.reshape(N * W, -1)
-        if is_quant(cache):
-            # per-row scales make this a plain scatter: every (page,
-            # offset) slot owns its scale, so same-page siblings never
-            # clash (unlike the GQA page RMW)
-            cache = quant_append_rows(cache, flat, safe_pg, offs, li)
-        else:
-            cache = cache.at[li, safe_pg, offs].set(
-                pad_heads(flat, cache.shape[-1]).astype(cache.dtype)
-            )
+        with jax.named_scope(SCOPE_KV):
+            if is_quant(cache):
+                # per-row scales make this a plain scatter: every (page,
+                # offset) slot owns its scale, so same-page siblings never
+                # clash (unlike the GQA page RMW)
+                cache = quant_append_rows(cache, flat, safe_pg, offs, li)
+            else:
+                cache = cache.at[li, safe_pg, offs].set(
+                    pad_heads(flat, cache.shape[-1]).astype(cache.dtype)
+                )
         attn = _seq_attention(
             spec, li, lp, q_nope, q_rope, new_rows, cache, block_tables,
             start_pos, kv_len, mesh,
         )
-        x = x + attn.astype(x.dtype) @ lp["wo"]
-        hh = rms_norm(x, lp["mlp_norm"], spec.rms_eps)
+        x = _add(x, _o_proj(lp, attn, x))
+        hh = _norm(x, lp["mlp_norm"], spec.rms_eps)
         y, counts = _ffn_counting(
             spec, li, lp, hh.reshape(N * W, -1), counts, COUNT_PREFILL,
             valid.reshape(N * W), mesh,
         )
-        x = x + y.reshape(N, W, -1)
+        x = _add(x, y.reshape(N, W, -1))
 
     logits = _logits_all(spec, params, x)  # [N, W, V]
     if allowed is not None:
@@ -707,39 +739,43 @@ def decode_forward_impl(
     pages); returns (logits, cache[, counts])."""
     B = tokens.shape[0]
     page_size = cache.shape[2]
-    positions = seq_lens - 1
-    page_idx = jnp.take_along_axis(
-        block_tables, (positions // page_size)[:, None], axis=1
-    )[:, 0]
-    safe_page = jnp.where(active, page_idx, TRASH_PAGE)
-    offset = positions % page_size
+    with jax.named_scope(SCOPE_INDEX):
+        positions = seq_lens - 1
+        page_idx = jnp.take_along_axis(
+            block_tables, (positions // page_size)[:, None], axis=1
+        )[:, 0]
+        safe_page = jnp.where(active, page_idx, TRASH_PAGE)
+        offset = positions % page_size
     scale = softmax_scale(spec)
     # the kernel's schedule follows the lengths alone: once a step
     schedule = latent_decode_schedule(cache, block_tables, seq_lens, mesh)
-    x = params["embed"][tokens]
+    x = _embed(params, tokens)
     for li, lp in enumerate(params["layers"]):
-        h = rms_norm(x, lp["attn_norm"], spec.rms_eps)
+        h = _norm(x, lp["attn_norm"], spec.rms_eps)
         q_nope, q_rope, new_rows = _attn_inputs(spec, lp, h, positions)
         # absorb W_uk: q_lat[b, h] = q_nope[b, h] W_uk[h]^T
-        q_lat = jnp.einsum(
-            "bhn,hcn->bhc", q_nope, lp["w_uk"],
-            preferred_element_type=jnp.float32,
-        )
-        o_lat, cache = latent_decode_update_attention(
-            q_lat, q_rope, cache, new_rows, block_tables, seq_lens,
-            safe_page, offset, layer=li, scale=scale, mesh=mesh,
-            schedule=schedule,
-        )
-        attn = jnp.einsum(
-            "bhc,hcv->bhv", o_lat.astype(lp["w_uv"].dtype), lp["w_uv"],
-            preferred_element_type=jnp.float32,
-        )
-        x = x + attn.reshape(B, -1).astype(x.dtype) @ lp["wo"]
-        hh = rms_norm(x, lp["mlp_norm"], spec.rms_eps)
+        with jax.named_scope(SCOPE_LATENT_ABSORB):
+            q_lat = jnp.einsum(
+                "bhn,hcn->bhc", q_nope, lp["w_uk"],
+                preferred_element_type=jnp.float32,
+            )
+        with jax.named_scope(SCOPE_KV):
+            o_lat, cache = latent_decode_update_attention(
+                q_lat, q_rope, cache, new_rows, block_tables, seq_lens,
+                safe_page, offset, layer=li, scale=scale, mesh=mesh,
+                schedule=schedule,
+            )
+        with jax.named_scope(SCOPE_LATENT_ABSORB):
+            attn = jnp.einsum(
+                "bhc,hcv->bhv", o_lat.astype(lp["w_uv"].dtype), lp["w_uv"],
+                preferred_element_type=jnp.float32,
+            )
+        x = _add(x, _o_proj(lp, attn, x))
+        hh = _norm(x, lp["mlp_norm"], spec.rms_eps)
         y, counts = _ffn_counting(
             spec, li, lp, hh, counts, COUNT_DECODE, active, mesh
         )
-        x = x + y
+        x = _add(x, y)
     logits = _replicate(_logits_all(spec, params, x), mesh)
     return _with_counts((logits, cache), counts)
 
@@ -789,19 +825,23 @@ def decode_steps_impl(
             mesh=mesh, counts=counts,
         )
         counts = rest[0] if rest else None
-        if allowed is not None:
-            logits = jnp.where(allowed, logits, NEG_INF)
-        nxt = sample_tokens(logits, temperature, top_k, top_p, seeds,
-                            steps + i)
-        nxt = jnp.where(active, nxt, toks)
-        out = out.at[:, i].set(nxt)
-        if n_logprobs > 0:
-            picked, top_i, top_v = token_logprobs(logits, nxt, n_logprobs)
-            lp = lp.at[:, i].set(picked)
-            ti = ti.at[:, i].set(top_i)
-            tv = tv.at[:, i].set(top_v)
-        return (nxt, lens + active.astype(jnp.int32), cache, counts, out,
-                lp, ti, tv)
+        # as llama.decode_steps_impl: the carry is the burst's region, the
+        # sampler's functions open theirs beneath it
+        with jax.named_scope(SCOPE_BURST):
+            if allowed is not None:
+                with jax.named_scope(SCOPE_SAMPLER):
+                    logits = jnp.where(allowed, logits, NEG_INF)
+            nxt = sample_tokens(logits, temperature, top_k, top_p, seeds,
+                                steps + i)
+            nxt = jnp.where(active, nxt, toks)
+            out = out.at[:, i].set(nxt)
+            if n_logprobs > 0:
+                picked, top_i, top_v = token_logprobs(logits, nxt, n_logprobs)
+                lp = lp.at[:, i].set(picked)
+                ti = ti.at[:, i].set(top_i)
+                tv = tv.at[:, i].set(top_v)
+            lens = lens + active.astype(jnp.int32)
+        return nxt, lens, cache, counts, out, lp, ti, tv
 
     _t, _l, cache, counts, out, lp, ti, tv = jax.lax.fori_loop(
         0, n_steps, body,
@@ -839,18 +879,18 @@ def embed_forward_impl(
     llama.embed_forward_impl — the /v1/embeddings surface)."""
     T = tokens.shape[0]
     positions = jnp.arange(T)
-    x = params["embed"][tokens]
+    x = _embed(params, tokens)
     mask2d = (positions[:, None] >= positions[None, :]) & (
         positions[None, :] < num_tokens
     )
     for li, lp in enumerate(params["layers"]):
-        h = rms_norm(x, lp["attn_norm"], spec.rms_eps)
+        h = _norm(x, lp["attn_norm"], spec.rms_eps)
         q_nope, q_rope = _q_heads(spec, lp, h, positions)
         rows = _latent_row(spec, lp, h, positions)
         attn = _dense_attention(spec, lp, q_nope, q_rope, rows, mask2d)
-        x = x + attn.reshape(T, -1).astype(x.dtype) @ lp["wo"]
-        hh = rms_norm(x, lp["mlp_norm"], spec.rms_eps)
-        x = x + _ffn(spec, li, lp, hh)
+        x = _add(x, _o_proj(lp, attn, x))
+        hh = _norm(x, lp["mlp_norm"], spec.rms_eps)
+        x = _add(x, _ffn(spec, li, lp, hh))
     xn = rms_norm(x, params["final_norm"], spec.rms_eps).astype(jnp.float32)
     valid = (positions < num_tokens)[:, None].astype(jnp.float32)
     pooled = (xn * valid).sum(axis=0) / jnp.maximum(valid.sum(), 1.0)

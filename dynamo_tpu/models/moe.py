@@ -23,12 +23,17 @@ import jax.numpy as jnp
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from dynamo_tpu.engine.config import ModelSpec
+# the regions of the expert layer (jax.named_scope: metadata only)
+from dynamo_tpu.models.regions import (
+    SCOPE_EXPERTS,
+    SCOPE_MOE_COMBINE,
+    SCOPE_MOE_COUNT,
+    SCOPE_MOE_DISPATCH,
+    SCOPE_MOE_GROUPED,
+    SCOPE_ROUTE,
+)
 
 Params = dict
-
-# the regions of the expert layer (jax.named_scope: metadata only)
-SCOPE_ROUTE = "moe_route"
-SCOPE_EXPERTS = "moe_experts"
 
 
 def init_moe_layer(spec: ModelSpec, key: jax.Array) -> Params:
@@ -147,32 +152,39 @@ def _held_experts(
     this shard is the one that adds the down projection's bias."""
     T, k = topi.shape
     n = lp["w_gate"].shape[0]
-    slot = _slots(topi, first, n)  # absent: n, sorted behind every group
-    order = jnp.argsort(slot, stable=True)
-    sizes = jnp.bincount(slot, length=n + 1)[:n].astype(jnp.int32)
-    eid = jnp.minimum(slot[order], n - 1)
-    rows = x[order // k]  # [T*k, d]: the held assignments lead, by expert
+    # the layer's three steps, each a region of its own beneath
+    # moe_experts: sort the assignments by expert and gather their rows,
+    # the grouped products, weight and sum back into the tokens
+    with jax.named_scope(SCOPE_MOE_DISPATCH):
+        slot = _slots(topi, first, n)  # absent: n, sorted behind every group
+        order = jnp.argsort(slot, stable=True)
+        sizes = jnp.bincount(slot, length=n + 1)[:n].astype(jnp.int32)
+        eid = jnp.minimum(slot[order], n - 1)
+        rows = x[order // k]  # [T*k, d]: the held assignments lead, by expert
     # rows past sum(sizes) belong to no group; what the grouped products
     # leave there is weighted by zero below
-    g = _grouped_matmul(rows, lp["w_gate"], sizes)
-    u = _grouped_matmul(rows, lp["w_up"], sizes)
-    if "b_gate" in lp:
-        g = g + lp["b_gate"][eid]
-        u = u + lp["b_up"][eid]
-    if spec.swiglu_limit:
-        # gpt-oss clamped swiglu (HF GptOssExperts.forward): gate capped
-        # above, linear clamped both ways, swish slope alpha, (up + 1)
-        g = jnp.minimum(g, spec.swiglu_limit)
-        u = jnp.clip(u, -spec.swiglu_limit, spec.swiglu_limit)
-        h = g * jax.nn.sigmoid(spec.swiglu_alpha * g) * (u + 1.0)
-    else:
-        h = jax.nn.silu(g) * u
-    out = _grouped_matmul(h.astype(x.dtype), lp["w_down"], sizes)
-    if "b_down" in lp:
-        out = out + jnp.where(down_bias, lp["b_down"][eid], 0)
-    w = jnp.where(slot < n, topv.reshape(T * k), 0.0)[order]
-    out = jnp.where(w[:, None] != 0, out.astype(jnp.float32) * w[:, None], 0.0)
-    return jnp.zeros((T, x.shape[1]), jnp.float32).at[order // k].add(out)
+    with jax.named_scope(SCOPE_MOE_GROUPED):
+        g = _grouped_matmul(rows, lp["w_gate"], sizes)
+        u = _grouped_matmul(rows, lp["w_up"], sizes)
+        if "b_gate" in lp:
+            g = g + lp["b_gate"][eid]
+            u = u + lp["b_up"][eid]
+        if spec.swiglu_limit:
+            # gpt-oss clamped swiglu (HF GptOssExperts.forward): gate capped
+            # above, linear clamped both ways, swish slope alpha, (up + 1)
+            g = jnp.minimum(g, spec.swiglu_limit)
+            u = jnp.clip(u, -spec.swiglu_limit, spec.swiglu_limit)
+            h = g * jax.nn.sigmoid(spec.swiglu_alpha * g) * (u + 1.0)
+        else:
+            h = jax.nn.silu(g) * u
+        out = _grouped_matmul(h.astype(x.dtype), lp["w_down"], sizes)
+        if "b_down" in lp:
+            out = out + jnp.where(down_bias, lp["b_down"][eid], 0)
+    with jax.named_scope(SCOPE_MOE_COMBINE):
+        w = jnp.where(slot < n, topv.reshape(T * k), 0.0)[order]
+        out = jnp.where(
+            w[:, None] != 0, out.astype(jnp.float32) * w[:, None], 0.0)
+        return jnp.zeros((T, x.shape[1]), jnp.float32).at[order // k].add(out)
 
 
 # rows a tile of the grouped product; its tiles of the contracted and
@@ -263,10 +275,11 @@ def moe_mlp(
     if counted is None:
         return y
     # a row that is not counted routes to no expert for the count
-    sizes = _count(jnp.where(counted[:, None], topi, -1), first, n_held)
-    total = (jnp.sum(counted) * topi.shape[1]).astype(jnp.int32)
-    touched = jnp.sum(sizes > 0).astype(jnp.int32)
-    return y, jnp.concatenate([sizes, total[None], touched[None]])
+    with jax.named_scope(SCOPE_MOE_COUNT):
+        sizes = _count(jnp.where(counted[:, None], topi, -1), first, n_held)
+        total = (jnp.sum(counted) * topi.shape[1]).astype(jnp.int32)
+        touched = jnp.sum(sizes > 0).astype(jnp.int32)
+        return y, jnp.concatenate([sizes, total[None], touched[None]])
 
 
 def _slots(topi: jax.Array, first, n: int) -> jax.Array:

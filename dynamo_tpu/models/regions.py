@@ -1,0 +1,137 @@
+"""The regions of the device programs: the one list of names the program
+may open with ``jax.named_scope``, each with the group it reports under.
+
+A scope is metadata: it becomes a component of the ``op_name`` of every
+operation traced under it (``jit(decode_steps_impl)/.../attn_qkv/
+dot_general``) and changes no program. The models, the kernels' callers,
+the sampler and the engine's feed glue import their ``SCOPE_*`` strings
+from here; a reader of a profiler trace imports the same table
+(``perfbench/lib/regions.py`` loads this file by path), so the vocabulary
+of a breakdown is the program's own. Nothing is imported here: the file
+loads without JAX.
+
+A Mosaic call is named in a trace after the innermost scope (or jit)
+around it, and the benchmark's ``kernels.*`` metrics find it by that name
+(a configuration's ``trace_names``): no scope below may be opened directly
+around a kernel that already has one of ``KERNEL_SCOPES``.
+"""
+
+from __future__ import annotations
+
+import re
+
+# the groups, as PERF.md section 3's rows
+ATTN_PROJ = "attn_proj"  # what turns a layer's input into queries, keys,
+# values (or latents, or the KDA mixer's operands), and its output back
+ATTN_CTX = "attn_ctx"  # cache write + attention over the cached context,
+# or the recurrent state's update
+FFN = "ffn"
+HEAD = "head"
+REST = "rest"
+GROUPS = (ATTN_PROJ, ATTN_CTX, FFN, HEAD, REST)
+
+# -- attn_proj
+SCOPE_QKV = "attn_qkv"  # q/k/v projections + rope (GQA); the KDA mixer's
+# operands, whose parts are named beneath it
+SCOPE_OUT = "attn_out"  # output projection (and its gate)
+SCOPE_LATENT_Q = "latent_q"  # MLA: query down- and up-projection + rope
+SCOPE_LATENT_KV = "latent_kv"  # MLA: the new latent row (down-projection,
+# norm, roped shared key)
+SCOPE_LATENT_ABSORB = "latent_absorb"  # MLA decode: W_uk into the queries,
+# W_uv out of the latent output
+SCOPE_KDA_PROJ = "kda_proj"  # KDA: the q | k | v projections
+SCOPE_KDA_CONV = "kda_conv"  # KDA: tails, the causal convolutions, norms
+SCOPE_KDA_GATES = "kda_gates"  # KDA: decay and beta
+# -- attn_ctx
+SCOPE_KV = "attn_kv"  # KV write + attention over the paged context
+SCOPE_ATTN_WINDOW = "attn_window"
+SCOPE_ATTN_FULL = "attn_full"
+SCOPE_FUSED_DECODE = "fused_decode_attention"  # the kernel's own name,
+# where a model of one kind of layer opens no scope around it
+SCOPE_ATTN_LATENT = "attn_latent"
+SCOPE_PREFILL_LATENT = "prefill_latent"
+SCOPE_LATENT_SCHEDULE = "latent_schedule"  # the decode kernel's live
+# chunks, once a step
+SCOPE_KDA_STEP = "kda_step"
+SCOPE_KDA_CHUNK = "kda_chunk"
+SCOPE_KDA_CHUNK_OPERANDS = "kda_chunk_operands"  # the chunkwise form's
+# batched XLA half: decays, triangular solves, re-layouts
+SCOPE_STATE_ROWS = "state_rows"  # the recurrent state's directory
+# -- ffn
+SCOPE_MLP = "mlp"
+SCOPE_ROUTE = "moe_route"
+SCOPE_EXPERTS = "moe_experts"
+SCOPE_MOE_DISPATCH = "moe_dispatch"  # sort the assignments by expert,
+# gather their rows
+SCOPE_MOE_GROUPED = "moe_grouped"  # the three grouped products + swiglu
+SCOPE_GMM = "gmm"  # megablox's own jit: the Mosaic grouped product
+SCOPE_MOE_COMBINE = "moe_combine"  # weight and scatter-add back to tokens
+SCOPE_MOE_SHARED = "moe_shared"  # the shared expert
+SCOPE_MOE_COUNT = "moe_count"  # the layer's device-side counters
+# -- head
+SCOPE_HEAD = "head"  # final norm + vocabulary projection
+SCOPE_SAMPLER = "sampler"
+# -- rest
+SCOPE_NORM = "norm"  # a layer's two input norms
+SCOPE_RESIDUAL = "residual"
+SCOPE_EMBED = "embed"
+SCOPE_INDEX = "page_index"  # positions, page ids and masks a program
+# derives from its tables
+SCOPE_BURST = "burst_glue"  # a decode burst's carry between its steps
+SCOPE_FEED = "feed"  # the engine's feed glue between programs
+
+REGIONS: dict[str, str] = {
+    SCOPE_QKV: ATTN_PROJ, SCOPE_OUT: ATTN_PROJ, SCOPE_LATENT_Q: ATTN_PROJ,
+    SCOPE_LATENT_KV: ATTN_PROJ, SCOPE_LATENT_ABSORB: ATTN_PROJ,
+    SCOPE_KDA_PROJ: ATTN_PROJ, SCOPE_KDA_CONV: ATTN_PROJ,
+    SCOPE_KDA_GATES: ATTN_PROJ,
+    SCOPE_KV: ATTN_CTX, SCOPE_ATTN_WINDOW: ATTN_CTX,
+    SCOPE_ATTN_FULL: ATTN_CTX, SCOPE_FUSED_DECODE: ATTN_CTX,
+    SCOPE_ATTN_LATENT: ATTN_CTX, SCOPE_PREFILL_LATENT: ATTN_CTX,
+    SCOPE_LATENT_SCHEDULE: ATTN_CTX, SCOPE_KDA_STEP: ATTN_CTX,
+    SCOPE_KDA_CHUNK: ATTN_CTX, SCOPE_KDA_CHUNK_OPERANDS: ATTN_CTX,
+    SCOPE_STATE_ROWS: ATTN_CTX,
+    SCOPE_MLP: FFN, SCOPE_ROUTE: FFN, SCOPE_EXPERTS: FFN,
+    SCOPE_MOE_DISPATCH: FFN, SCOPE_MOE_GROUPED: FFN, SCOPE_GMM: FFN,
+    SCOPE_MOE_COMBINE: FFN, SCOPE_MOE_SHARED: FFN, SCOPE_MOE_COUNT: FFN,
+    SCOPE_HEAD: HEAD, SCOPE_SAMPLER: HEAD,
+    SCOPE_NORM: REST, SCOPE_RESIDUAL: REST, SCOPE_EMBED: REST,
+    SCOPE_INDEX: REST, SCOPE_BURST: REST, SCOPE_FEED: REST,
+}
+
+# the names a trace gives the Mosaic calls, which the benchmark's
+# ``kernels.*`` metrics read by: each stays the innermost name around its
+# kernel
+KERNEL_SCOPES = (
+    SCOPE_FUSED_DECODE, SCOPE_ATTN_WINDOW, SCOPE_ATTN_FULL,
+    SCOPE_ATTN_LATENT, SCOPE_PREFILL_LATENT, SCOPE_GMM, SCOPE_KDA_STEP,
+    SCOPE_KDA_CHUNK,
+)
+
+_WRAPPED = re.compile(r"\(([^()]*)\)")
+
+
+def _bare(component: str) -> str:
+    """A component of an ``op_name`` without the transforms around it:
+    ``jit(gmm)`` -> ``gmm``, ``vmap(jit(attn_full))`` -> ``attn_full``."""
+    m = _WRAPPED.search(component)
+    return m.group(1) if m else component
+
+
+def resolve(op_name: str) -> tuple[str | None, str]:
+    """(region, leaf) of an operation from its ``op_name``: the innermost
+    component that is a name of ``REGIONS`` (None where the path holds
+    none), and the path's last component, the primitive."""
+    parts = [p for p in op_name.split("/") if p]
+    if not parts:
+        return None, ""
+    for part in reversed(parts):
+        name = _bare(part)
+        if name in REGIONS:
+            return name, parts[-1]
+    return None, parts[-1]
+
+
+def group_of(region: str | None) -> str:
+    """The group a region reports under; ``rest`` for none."""
+    return REGIONS.get(region, REST)

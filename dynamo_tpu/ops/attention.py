@@ -27,6 +27,19 @@ import os
 import jax
 import jax.numpy as jnp
 
+# the names of the kernels' regions in a trace are models/regions.py's:
+# ``attn_latent`` names the latent decode kernel (its roofline share
+# divides by that operation's time); ``prefill_latent`` is in no
+# configuration's ``trace_names``; ``kda_step`` / ``kda_chunk`` name the
+# recurrent layers' kernels
+from dynamo_tpu.models.regions import (
+    SCOPE_ATTN_LATENT,
+    SCOPE_KDA_CHUNK,
+    SCOPE_KDA_CHUNK_OPERANDS,
+    SCOPE_KDA_STEP,
+    SCOPE_LATENT_SCHEDULE,
+    SCOPE_PREFILL_LATENT,
+)
 
 NEG_INF = -1e30
 
@@ -592,11 +605,6 @@ def window_table(
 # themselves); prefill is not (a block of rows is up-projected to per-head
 # keys and values once and scored by the whole call's queries).
 
-SCOPE_ATTN_LATENT = "attn_latent"  # names the decode kernel in a trace
-# the prefill kernel's name is one no configuration's ``trace_names``
-# holds: the decode kernel's roofline share divides by ``attn_latent``'s time
-SCOPE_PREFILL_LATENT = "prefill_latent"
-
 
 def latent_rows(pool, layer, ids: jax.Array) -> jax.Array:
     """The rows on pages ``ids`` [..., n] of layer ``layer`` of a latent
@@ -696,7 +704,8 @@ def latent_decode_schedule(pool, block_tables, seq_lens, mesh=None):
         return None
     from dynamo_tpu.ops.pallas.latent_decode import latent_schedule
 
-    return latent_schedule(pool, block_tables, seq_lens)
+    with jax.named_scope(SCOPE_LATENT_SCHEDULE):
+        return latent_schedule(pool, block_tables, seq_lens)
 
 
 def latent_decode_update_attention(
@@ -939,8 +948,6 @@ def latent_prefill_attention(
 #
 # Appended at the file's end: no softmax line moved.
 
-SCOPE_KDA_STEP = "kda_step"  # name the kernels in a trace
-SCOPE_KDA_CHUNK = "kda_chunk"
 KDA_BLOCK = 64  # tokens a block of the chunkwise form
 KDA_SUB = 16  # and a sub-block inside it: the span an inverse decay covers
 _HI = jax.lax.Precision.HIGHEST
@@ -1062,17 +1069,21 @@ def kda_chunk_prefill(q, k, v, g, beta, pool, rows, fresh, *, layer: int):
         x = jnp.pad(x.astype(f32), ((0, 0), (0, pad), (0, 0), (0, 0)))
         return x.reshape(N, nb, C, H, -1).transpose(0, 3, 1, 2, 4)
 
-    ut, w, qd, b, kend, gamma = kda_chunk_operands(
-        blocks(q), blocks(k), blocks(v), blocks(g),
-        blocks(beta[..., None])[..., 0],
-    )
+    # the batched half of the chunkwise form is a region of its own: XLA
+    # fusions, triangular solves and re-layouts beside the kernel's scan
+    with jax.named_scope(SCOPE_KDA_CHUNK_OPERANDS):
+        ut, w, qd, b, kend, gamma = kda_chunk_operands(
+            blocks(q), blocks(k), blocks(v), blocks(g),
+            blocks(beta[..., None])[..., 0],
+        )
     if use_pallas():
         from dynamo_tpu.ops.pallas.kda import kda_chunk_scan
 
-        kx = jnp.concatenate([
-            jnp.swapaxes(kend, -1, -2),
-            jnp.broadcast_to(gamma[..., None], (N, H, nb, dk, C)),
-        ], axis=-1)
+        with jax.named_scope(SCOPE_KDA_CHUNK_OPERANDS):
+            kx = jnp.concatenate([
+                jnp.swapaxes(kend, -1, -2),
+                jnp.broadcast_to(gamma[..., None], (N, H, nb, dk, C)),
+            ], axis=-1)
         o, pool = kda_chunk_scan(
             ut, w, qd, b, kx, pool, rows, fresh, layer=layer,
             interpret=jax.default_backend() != "tpu", scope=SCOPE_KDA_CHUNK,
@@ -1096,8 +1107,9 @@ def kda_chunk_prefill(q, k, v, g, beta, pool, rows, fresh, *, layer: int):
         ))
         o = jnp.moveaxis(o, 0, 2)
         pool = pool.at[layer, rows].set(s)
-    o = o.transpose(0, 2, 3, 1, 4).reshape(N, nb * C, H, -1)
-    return o[:, :T], pool
+    with jax.named_scope(SCOPE_KDA_CHUNK_OPERANDS):
+        o = o.transpose(0, 2, 3, 1, 4).reshape(N, nb * C, H, -1)
+        return o[:, :T], pool
 
 
 def kda_decode_step(pool, conv, rows, q, k, v, g, beta, tail, *, layer: int):

@@ -69,6 +69,7 @@ async def test_profile_off_records_nothing_and_creates_no_annotation(
     assert engine._prof == {} and engine._prof_requests == {}
     assert set(snap) == {"dispatch.d2h_wait", "readmit.d2h_wait",
                          "dispatch.dispatches", "dispatch.compile",
+                         "window.at",
                          "decode_kv.pages_live", "decode_kv.pages_fetched",
                          "decode_kv.pages_table",
                          "prefill_kv.blocks_visited.full",

@@ -111,6 +111,9 @@ PROFILE_PHASES: dict[str, str] = {
                         "phase sums",
     "dispatch.dispatches": "jitted device programs issued (count)",
     "dispatch.compile": "backend compile events since engine build",
+    "window.at": "the instant the snapshot was taken (secs = "
+                 "time.monotonic()): two snapshots say how far apart "
+                 "they are, whatever the caller meant the window to be",
     "spec.draft": "prompt-lookup drafting over spec-managed slots",
     "spec.verify": "packed speculative-verify dispatch + target sync",
     "spec.rollback": "page release of rejected draft tails (and the "
