@@ -6,8 +6,11 @@ deepseek-r1 wide-EP — engine_configs/deepseek_r1/wide_ep/wide_ep_agg.yaml
 token's top-k assignments are computed, none is dropped: the router scores
 all ``num_experts``, the assignments that land on the experts HELD here
 are sorted by expert, and one grouped matrix product a projection
-(``_grouped_matmul``) runs over the sorted rows. Assignments to experts held elsewhere add
-nothing here; their holders add them.
+(``_grouped_matmul``) runs over the sorted rows. The products' rows go
+back to their tokens by the inverse of that sort: each token gathers its
+k rows and adds them, weighted, in float32 and in the order of its top-k
+(``_combine``; no floating-point scatter). Assignments to experts held
+elsewhere have weight 0 and add nothing here; their holders add them.
 
 Which experts are held is stated from outside, two ways through one code
 path (``_held_experts``): ``ModelSpec.held_experts`` for a process that is
@@ -153,11 +156,17 @@ def _held_experts(
     T, k = topi.shape
     n = lp["w_gate"].shape[0]
     # the layer's three steps, each a region of its own beneath
-    # moe_experts: sort the assignments by expert and gather their rows,
-    # the grouped products, weight and sum back into the tokens
+    # moe_experts: sort the assignments by expert and gather their rows
+    # (keeping where each assignment went), the grouped products, then
+    # un-permute: each token gathers its k rows back and sums them,
+    # weighted, in the order of its top-k
     with jax.named_scope(SCOPE_MOE_DISPATCH):
         slot = _slots(topi, first, n)  # absent: n, sorted behind every group
         order = jnp.argsort(slot, stable=True)
+        # inv[a]: where assignment a (token a // k, choice a % k) sorted
+        # to. A second sort of 8,192 integers is 7 us on a v5e where the
+        # scatter of an iota is 39 (16,384: 9 and 77; my chip run, PR 38)
+        inv = jnp.argsort(order)
         sizes = jnp.bincount(slot, length=n + 1)[:n].astype(jnp.int32)
         eid = jnp.minimum(slot[order], n - 1)
         rows = x[order // k]  # [T*k, d]: the held assignments lead, by expert
@@ -181,10 +190,34 @@ def _held_experts(
         if "b_down" in lp:
             out = out + jnp.where(down_bias, lp["b_down"][eid], 0)
     with jax.named_scope(SCOPE_MOE_COMBINE):
-        w = jnp.where(slot < n, topv.reshape(T * k), 0.0)[order]
-        out = jnp.where(
-            w[:, None] != 0, out.astype(jnp.float32) * w[:, None], 0.0)
-        return jnp.zeros((T, x.shape[1]), jnp.float32).at[order // k].add(out)
+        return _combine(out, inv.reshape(T, k),
+                        jnp.where(slot.reshape(T, k) < n, topv, 0.0))
+
+
+def _combine(out: jax.Array, inv: jax.Array, w: jax.Array) -> jax.Array:
+    """y[t] = sum over j of w[t, j] * out[inv[t, j]], float32 [T, d]: the
+    un-permute of the sorted rows, as one gather in token order. ``out``
+    [T*k, d] in sorted order; ``inv`` [T, k] the sorted position of each
+    of a token's k assignments (a permutation of 0 .. T*k); ``w`` [T, k]
+    float32, 0 where the expert is held elsewhere. A weight of 0 adds
+    exactly 0 whatever its row holds (a row no group reached is
+    undefined). The rows are gathered choice-major ([k, T, d]), so a
+    choice is a contiguous slab and the k terms are added slab by slab in
+    the order of the top-k: the result does not depend on how the sort
+    laid the rows out."""
+    T, k = inv.shape
+    rows = out.at[inv.T.reshape(T * k)].get(
+        mode="promise_in_bounds", unique_indices=True).reshape(k, T, -1)
+    wt = w.T
+    # the mask made once, [k, T]: compared slab by slab the chip's
+    # compiler makes k small fusions of the compares beside the sum
+    keep = wt != 0
+    y = None
+    for j in range(k):
+        term = jnp.where(keep[j][:, None],
+                         rows[j].astype(jnp.float32) * wt[j][:, None], 0.0)
+        y = term if y is None else y + term
+    return y
 
 
 # rows a tile of the grouped product; its tiles of the contracted and

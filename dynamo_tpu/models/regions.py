@@ -61,11 +61,12 @@ SCOPE_STATE_ROWS = "state_rows"  # the recurrent state's directory
 SCOPE_MLP = "mlp"
 SCOPE_ROUTE = "moe_route"
 SCOPE_EXPERTS = "moe_experts"
-SCOPE_MOE_DISPATCH = "moe_dispatch"  # sort the assignments by expert,
-# gather their rows
+SCOPE_MOE_DISPATCH = "moe_dispatch"  # sort the assignments by expert
+# (and invert the sort for the combine), gather their rows
 SCOPE_MOE_GROUPED = "moe_grouped"  # the three grouped products + swiglu
 SCOPE_GMM = "gmm"  # megablox's own jit: the Mosaic grouped product
-SCOPE_MOE_COMBINE = "moe_combine"  # weight and scatter-add back to tokens
+SCOPE_MOE_COMBINE = "moe_combine"  # un-permute: a token gathers its k rows
+# back and adds them, weighted, in float32
 SCOPE_MOE_SHARED = "moe_shared"  # the shared expert
 SCOPE_MOE_COUNT = "moe_count"  # the layer's device-side counters
 # -- head

@@ -234,6 +234,14 @@ def test_every_instruction_resolves_and_the_unnamed_are_bookkeeping(
     assert named > 0 and len(named_computing) >= 0.9 * len(computing), (
         len(named_computing), len(computing))
     found = {table["ops"][i["name"]][0] for i in executed} - {None}
+    # a region whose operations the CPU's compiler fused into a neighbour's
+    # fusion (the expert layer's gather-and-add into the residual's; the
+    # chip's compiler keeps that gather a fusion of its own) is found
+    # inside the fusion: the table marks such a fusion ``mixed``
+    found |= {
+        regions.resolve(j["op_name"])[0]
+        for i in executed if i["opcode"] == "fusion"
+        for c in i["calls"] for j in comps[c]} - {None}
     want = {"embed", "norm", "head", "page_index", "attn_out"}
     want |= {"sampler", "burst_glue"} if program == "decode" else set()
     want |= {
@@ -252,22 +260,34 @@ def test_every_instruction_resolves_and_the_unnamed_are_bookkeeping(
     assert want <= found, want - found
 
 
-def test_moe_experts_holds_the_scatter_add():
-    """The expert layer's sum back into its tokens reads ``moe_experts /
-    ... / scatter-add`` from the table, under the layer's third step."""
-    _table_, comps, _entry, _text = _table("mimo", "prefill")
-    adds = [
-        i["op_name"] for instrs in comps.values() for i in instrs
-        if i["op_name"].endswith("scatter-add")
-        and "moe_experts" in i["op_name"].split("/")
-    ]
-    # the sum of the experts' outputs is the combine's; the dispatch's own
-    # scatter-add is its bincount of the assignments
-    assert {regions.resolve(n) for n in adds} == {
-        ("moe_combine", "scatter-add"), ("moe_dispatch", "scatter-add")}
-    path = next(n for n in adds if "moe_combine" in n).split("/")
-    assert path.index("mlp") < path.index("moe_experts") < path.index(
-        "moe_combine")
+def test_moe_experts_sums_back_by_a_gather_and_scatters_integers_only():
+    """Beneath ``moe_experts`` no floating-point scatter is left: the one
+    scatter is the dispatch's bincount of the assignments, over integers
+    (the inverse of the sort is a second sort), and the sum back into the
+    tokens reads ``moe_experts / moe_combine / gather`` and ``/ add`` from
+    the table, under the layer's third step."""
+    for program in ("prefill", "decode"):
+        _table_, comps, _entry, text = _table("mimo", program)
+        beneath = [
+            i for instrs in comps.values() for i in instrs
+            if "moe_experts" in i["op_name"].split("/")
+        ]
+        scatters = [i for i in beneath if i["opcode"] == "scatter"]
+        assert {regions.resolve(i["op_name"]) for i in scatters} == {
+            ("moe_dispatch", "scatter-add")}
+        shapes = [
+            re.search(rf"%?{re.escape(i['name'])} = (\S+) scatter\(",
+                      text).group(1) for i in scatters]
+        assert all(re.match(r"\(?[su]\d+\[", s) for s in shapes), shapes
+        combine = {
+            regions.resolve(i["op_name"]): i["op_name"] for i in beneath
+            if regions.resolve(i["op_name"])[0] == "moe_combine"}
+        leaves = {leaf for _region, leaf in combine}
+        assert "gather" in leaves and "add" in leaves, leaves
+        assert not any(leaf.startswith("scatter") for leaf in leaves)
+        path = combine[("moe_combine", "gather")].split("/")
+        assert path.index("mlp") < path.index("moe_experts") < path.index(
+            "moe_combine")
 
 
 def test_a_fusion_of_two_regions_is_marked_mixed():

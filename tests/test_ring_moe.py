@@ -292,3 +292,91 @@ def test_moe_under_an_ep_mesh_is_the_same_layer():
         sharded, x
     )
     np.testing.assert_allclose(np.asarray(got), want, rtol=2e-4, atol=2e-4)
+
+
+# ------------------------------------------- the combine: a gather, not a scatter
+
+
+def _scatter_add_experts(spec, lp, x, topi, topv, first):
+    """``moe._held_experts`` as it summed before the un-permute: every
+    sorted row weighted in float32 and scatter-added into its token. Kept
+    here as the yardstick of the gather form."""
+    T, k = topi.shape
+    n = lp["w_gate"].shape[0]
+    slot = moe._slots(topi, first, n)
+    order = jnp.argsort(slot, stable=True)
+    sizes = jnp.bincount(slot, length=n + 1)[:n].astype(jnp.int32)
+    rows = x[order // k]
+    g = jax.lax.ragged_dot(rows, lp["w_gate"], sizes)
+    u = jax.lax.ragged_dot(rows, lp["w_up"], sizes)
+    h = jax.nn.silu(g) * u
+    out = jax.lax.ragged_dot(h.astype(x.dtype), lp["w_down"], sizes)
+    w = jnp.where(slot < n, topv.reshape(T * k), 0.0)[order]
+    out = jnp.where(w[:, None] != 0, out.astype(jnp.float32) * w[:, None], 0.0)
+    return jnp.zeros((T, x.shape[1]), jnp.float32).at[order // k].add(out)
+
+
+_HELD, _FIRST, _EXPERTS = 4, 4, 16  # experts 4..7 of 16 are held
+
+
+def _combine_case(case, T, k):
+    """(spec, lp, x, topi, topv) of a case: a share of 4 of 16 experts."""
+    import dataclasses
+
+    spec = dataclasses.replace(
+        MOE_SPEC, num_experts=_EXPERTS, num_experts_per_token=k,
+        held_experts=(_HELD, _FIRST))
+    lp = moe.init_moe_layer(spec, jax.random.PRNGKey(3))
+    x = jax.random.normal(
+        jax.random.PRNGKey(T * 8 + k), (T, spec.hidden_size), jnp.float32)
+    topi, topv = moe.route(spec, lp, x)
+    if case == "one_held_expert":  # every assignment of every token
+        topi = jnp.full((T, k), _FIRST + 1, jnp.int32)
+    elif case == "none_held":
+        topi = (topi % _FIRST).astype(jnp.int32)  # experts 0..3: elsewhere
+    return spec, lp, x, topi, topv
+
+
+@pytest.mark.parametrize("k", [1, 4, 8])
+@pytest.mark.parametrize("T", [1, 5, 128, 1024, 2048])
+@pytest.mark.parametrize("case", [
+    "routed", "one_held_expert", "none_held", "undefined_rows", "ep_mesh"])
+def test_moe_combine_by_gather_is_the_scatter_add(case, T, k, monkeypatch):
+    """The un-permute (each token gathers its k rows and adds them in the
+    order of its top-k) gives what the float32 scatter-add over every
+    assignment gave, to float32 rounding: over a chunk's and a pack's
+    rows, with all assignments on one held expert, with none held
+    (exactly 0), with NaN and inf in the rows no group reached, and under
+    the "ep" mesh."""
+    spec, lp, x, topi, topv = _combine_case(case, T, k)
+    want = np.asarray(_scatter_add_experts(spec, lp, x, topi, topv, _FIRST))
+    tol = dict(rtol=1e-6, atol=1e-6 * max(1.0, float(np.abs(want).max())))
+    if case == "ep_mesh":
+        # the whole layer through the shard_map: two shards of two experts
+        # each, their shares met in a psum (one more float32 addition)
+        mesh = make_mesh(ep=2)
+        sharded = jax.tree.map(
+            jax.device_put, lp, moe.moe_layer_shardings(mesh, spec))
+        got = jax.jit(lambda lp_, x_: moe.moe_mlp(spec, lp_, x_, mesh=mesh))(
+            sharded, x)
+        np.testing.assert_allclose(np.asarray(got), want, **tol)
+        return
+    if case == "undefined_rows":
+        real = moe._grouped_matmul
+
+        def poisoned(a, w, sizes):
+            out = real(a, w, sizes)
+            past = jnp.arange(a.shape[0])[:, None] >= jnp.sum(sizes)
+            junk = jnp.where(
+                jnp.arange(a.shape[0])[:, None] % 2 == 0, jnp.nan, jnp.inf)
+            return jnp.where(past, junk, out)
+
+        monkeypatch.setattr(moe, "_grouped_matmul", poisoned)
+    got = np.asarray(moe._held_experts(spec, lp, x, topi, topv, _FIRST))
+    assert got.dtype == np.float32 and got.shape == x.shape
+    assert np.isfinite(got).all()
+    if case == "none_held":
+        assert not got.any() and not want.any()
+    elif case == "one_held_expert":
+        assert np.abs(got).max() > 0
+    np.testing.assert_allclose(got, want, **tol)
