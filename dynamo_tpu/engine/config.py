@@ -21,16 +21,25 @@ class LayerKind:
     rope_theta: float
     window: int = 0  # sliding window in tokens; 0 = full attention
     sinks: bool = False  # one learned logit a query head in the softmax
-    # what mixes tokens: "softmax" attention over the sequence's pages, or
+    # what mixes tokens: "softmax" attention over the sequence's pages;
     # "kda" (Kimi Delta Attention, arXiv:2510.26692): a gated delta rule
     # over a fixed-size state a sequence, which owns no page in this layer
     # (``ModelSpec.kda_*`` give its sizes; the three fields above are
-    # unread for it)
+    # unread for it); or "ssd" (Mamba-2, arXiv:2405.21060): a scalar-decay
+    # state-space mixer over a state row (``ModelSpec.ssm_*``) IN PARALLEL
+    # with softmax attention over pages off the same norm (Falcon-H1), so
+    # the kind keeps both: what a kind keeps is ``paged`` and ``recurrent``
     mixer: str = "softmax"
 
     @property
     def recurrent(self) -> bool:
+        """The kind keeps a state row a sequence."""
         return self.mixer != "softmax"
+
+    @property
+    def paged(self) -> bool:
+        """The kind keeps pages: it has softmax attention (KV heads)."""
+        return self.num_kv_heads > 0
 
 
 @dataclass(frozen=True)
@@ -126,6 +135,37 @@ class ModelSpec:
     kda_head_dim: int = 0
     kda_conv: int = 4
     kda_neg_eigval: bool = False
+    # SSD layers (``LayerKind.mixer == "ssd"``, Mamba-2): ``ssm_heads``
+    # heads of ``ssm_head_dim`` channels over a state ``[head_dim,
+    # ssm_state]`` a head, B and C shared by the heads of one of
+    # ``ssm_groups`` groups, a causal depthwise convolution of ``ssm_conv``
+    # taps (with bias) on x | B | C, the chunkwise form at ``ssm_chunk``
+    ssm_heads: int = 0
+    ssm_head_dim: int = 0
+    ssm_state: int = 0
+    ssm_groups: int = 1
+    ssm_conv: int = 4
+    ssm_chunk: int = 128
+    # the Falcon-H1 family's fixed scalar multipliers (muP); 1 = absent.
+    # ``ssm_multipliers`` scale the z | x | B | C | dt segments of the SSM
+    # input projection's output, ``mlp_multipliers`` the gate projection
+    # and the down projection's output
+    embedding_multiplier: float = 1.0
+    lm_head_multiplier: float = 1.0
+    key_multiplier: float = 1.0
+    attention_in_multiplier: float = 1.0
+    attention_out_multiplier: float = 1.0
+    ssm_in_multiplier: float = 1.0
+    ssm_out_multiplier: float = 1.0
+    ssm_multipliers: tuple[float, ...] = ()
+    mlp_multipliers: tuple[float, ...] = ()
+    # blocks along the vocabulary that ``init_params`` DRAWS the embedding
+    # and the head in, block b on its key folded with b (random weights
+    # only; a checkpoint's tables load as they are). 1 = whole, on the key
+    # itself. A table whose float32 normals would not fit beside the model
+    # sets more: 261,120 x 5,120 is 5.35 GB whole, a third of a chip (the
+    # largest drawn whole, 131,072 x 5,120, is 2.68 GB)
+    vocab_draw_blocks: int = 1
 
     def __post_init__(self) -> None:
         # a spec read from JSON brings lists and dicts; the spec is a
@@ -136,6 +176,8 @@ class ModelSpec:
         fix("layer_types", tuple(self.layer_types))
         fix("layer_pattern", tuple(self.layer_pattern))
         fix("held_experts", tuple(self.held_experts))
+        fix("ssm_multipliers", tuple(float(m) for m in self.ssm_multipliers))
+        fix("mlp_multipliers", tuple(float(m) for m in self.mlp_multipliers))
         fix("layer_kinds", tuple(
             k if isinstance(k, LayerKind) else LayerKind(**k)
             for k in self.layer_kinds
@@ -145,7 +187,7 @@ class ModelSpec:
                 f"layer_pattern names {len(self.layer_pattern)} layers, "
                 f"the model has {self.num_layers}"
             )
-        if self.layer_kinds and self.layer_kinds[0].recurrent:
+        if self.layer_kinds and not self.layer_kinds[0].paged:
             # the cache's first leaf is a page pool (llama.page_size_of)
             raise ValueError("layer_kinds must list a paged kind first")
         if self.held_experts:
@@ -183,6 +225,11 @@ class ModelSpec:
     def has_recurrent(self) -> bool:
         """Some layer keeps a recurrent state a sequence beside the pages."""
         return any(k.recurrent for k in self.layer_kinds)
+
+    @property
+    def mixers(self) -> frozenset[str]:
+        """The ``LayerKind.mixer`` of every listed kind."""
+        return frozenset(k.mixer for k in self.layer_kinds)
 
     def pool_slot(self, li: int) -> tuple[int, int]:
         """(kind, index among that kind's layers) of layer ``li``: where
@@ -361,6 +408,36 @@ class ModelSpec:
         base.update(kw)
         return cls(**base)
 
+    @classmethod
+    def tiny_falcon_h1(cls, **kw) -> "ModelSpec":
+        """Toy Falcon-H1 architecture: every layer a Mamba-2 (SSD) mixer
+        and GQA attention in parallel off one norm, a dense MLP, the
+        family's multipliers all away from 1."""
+        base = dict(
+            name="tiny-falcon-h1", vocab_size=96, hidden_size=64,
+            intermediate_size=96, num_layers=3, num_heads=4,
+            num_kv_heads=2, head_dim=16, dtype="float32",
+            rope_theta=1e11, tie_embeddings=False,
+            layer_kinds=(LayerKind(2, 1e11, mixer="ssd"),),
+            layer_pattern=(0, 0, 0),
+            ssm_heads=4, ssm_head_dim=8, ssm_state=16, ssm_groups=2,
+            ssm_conv=4, ssm_chunk=16,
+            embedding_multiplier=5.0, lm_head_multiplier=0.125,
+            key_multiplier=0.3, attention_in_multiplier=0.9,
+            attention_out_multiplier=0.6, ssm_in_multiplier=0.5,
+            ssm_out_multiplier=0.7,
+            ssm_multipliers=(0.35, 0.5, 0.7, 0.8, 0.6),
+            mlp_multipliers=(0.7, 0.4), vocab_draw_blocks=8,
+        )
+        base.update(kw)
+        return cls(**base)
+
+    @property
+    def ssm_conv_dim(self) -> int:
+        """Channels the SSD convolution runs over: x | B | C."""
+        return (self.ssm_heads * self.ssm_head_dim
+                + 2 * self.ssm_groups * self.ssm_state)
+
     @property
     def is_mla(self) -> bool:
         return self.kv_lora_rank > 0
@@ -373,6 +450,7 @@ class ModelSpec:
             "tiny-deepseek": cls.tiny_deepseek,
             "tiny-gpt-oss": cls.tiny_gpt_oss,
             "tiny-solar": cls.tiny_solar,
+            "tiny-falcon-h1": cls.tiny_falcon_h1,
             "llama-3-8b": cls.llama3_8b,
             "llama-3-70b": cls.llama3_70b,
             "mixtral-8x7b": cls.mixtral_8x7b,
@@ -577,7 +655,8 @@ class EngineConfig:
         its kernel serves); so is a model
         with recurrent layers (``need_recurrent``): its softmax layers the
         walk's true tiles, its KDA layers the chunkwise form's float32
-        operands. The state rows themselves are part of the pools, so
+        operands, its SSD layers the chunk form's (decay matrices a head
+        a chunk, the carried states). The state rows themselves are part of the pools, so
         ``free_bytes`` already lacks them."""
         top = self.bucket_for(min(
             self.max_context, self.max_prefill_chunk_tokens,
@@ -629,7 +708,18 @@ class EngineConfig:
             )
             scores = 4 * rows * heads * tq * bp * self.page_size
             kda = 4 * 20 * rows * bucket * spec.kda_heads * spec.kda_head_dim
-            return scores * 3 + kda + 96 * 1024 * rows * bucket
+            # an SSD layer's chunk form (ops/attention.ssd_chunk_prefill):
+            # per head the chunk's decay matrix and masked C B^T [chunk,
+            # chunk] float32 (two copies and a rounded one), x, y and dt x
+            # [head_dim] a token in float32, B and C a group, and the
+            # carried states [chunks, heads, head_dim, state]
+            Hs, Q = spec.ssm_heads, spec.ssm_chunk
+            ssd = 4 * rows * bucket * (
+                3 * Hs * Q + 4 * Hs * spec.ssm_head_dim
+                + 4 * spec.ssm_groups * spec.ssm_state
+            ) + 4 * 2 * rows * -(-bucket // max(1, Q)) * (
+                Hs * spec.ssm_head_dim * spec.ssm_state)
+            return scores * 3 + kda + ssd + 96 * 1024 * rows * bucket
 
         shapes: dict[int, int] = {}
         for bucket in self.prefill_buckets:

@@ -188,7 +188,9 @@ READMIT_SUMS = {
 # the engine's always-on host counters, each a dict of ints under the
 # attribute of its name: profile_snapshot() carries them as
 # ``<family>.<name>`` and reset_profile_window() zeroes them
-_COUNTER_FAMILIES = ("decode_kv", "prefill_kv", "chunked_prefill", "kda")
+_COUNTER_FAMILIES = (
+    "decode_kv", "prefill_kv", "chunked_prefill", "kda", "ssd",
+)
 
 # what _phase and _launch hand out with profiling off: one shared object
 # whose enter and exit do nothing
@@ -540,7 +542,15 @@ class InferenceEngine:
         # tokens kda_chunk carried a state through, over the prefills
         self.kda = (
             {"decode_rows": 0, "prefill_blocks": 0}
-            if self.fam.recurrent else {}
+            if "kda" in spec.mixers else {}
+        )
+        # and the SSD mixer's, a layer's worth (a model with SSD layers
+        # only): state rows an ssd_step call updated, over the dispatched
+        # bursts' steps; chunks of tokens the chunk form carried a state
+        # through and rows it resumed (start_pos > 0), over the prefills
+        self.ssd = (
+            {"decode_rows": 0, "prefill_chunks": 0, "rows_resumed": 0}
+            if "ssd" in spec.mixers else {}
         )
         # the state directory's device-side counters [clock, claims, rows
         # missing]: the last host copy and the one on its way
@@ -707,13 +717,16 @@ class InferenceEngine:
                 return None  # an fp8 latent pool keeps the XLA walk
             return latent_chunk_pages(k, self.config.max_pages_per_seq)
         if hasattr(k, "pools"):  # a pool a layer kind (llama.KindPools)
+            from dynamo_tpu.models.llama import kind_pages
+
             full = next(
                 (i for i, kd in enumerate(self.spec.layer_kinds)
-                 if not kd.window and not kd.recurrent), None,
+                 if not kd.window and kd.paged), None,
             )
             if full is None:
                 return None
-            k, v = k.pools[full], v.pools[full]
+            k = kind_pages(self.spec, k, full)
+            v = kind_pages(self.spec, v, full)
         if len(k.shape) != 5:
             return None
         return pool_chunk_pages(k, v, self.config.max_pages_per_seq)
@@ -731,10 +744,11 @@ class InferenceEngine:
         pages_fetched`` is what the chunk's size wastes."""
         from dynamo_tpu.ops.pallas.fused_decode import live_chunks
 
-        if self.kda:
-            self.kda["decode_rows"] += (
-                int(batch["active"].sum()) * batch["n_burst"]
-            )
+        for counters in (self.kda, self.ssd):
+            if counters:
+                counters["decode_rows"] += (
+                    int(batch["active"].sum()) * batch["n_burst"]
+                )
         chunk = self._kv_chunk_pages
         if chunk is None:
             return
@@ -793,6 +807,12 @@ class InferenceEngine:
             from dynamo_tpu.ops.attention import kda_prefill_blocks
 
             self.kda["prefill_blocks"] += kda_prefill_blocks(nts)
+        if self.ssd:
+            from dynamo_tpu.ops.attention import ssd_prefill_chunks
+
+            self.ssd["prefill_chunks"] += ssd_prefill_chunks(
+                nts, self.spec.ssm_chunk)
+            self.ssd["rows_resumed"] += int(((starts > 0) & (nts > 0)).sum())
         kv = self.prefill_kv
         for kind, window in self._prefill_walks.items():
             kernel = kind == "latent" and latent_kernel_serves(

@@ -43,6 +43,9 @@ from dynamo_tpu.models.regions import (
     SCOPE_QKV,
     SCOPE_RESIDUAL,
     SCOPE_SAMPLER,
+    SCOPE_SSM_CONV,
+    SCOPE_SSM_GATES,
+    SCOPE_SSM_PROJ,
     SCOPE_STATE_ROWS,
 )
 from dynamo_tpu.ops.attention import (
@@ -52,6 +55,8 @@ from dynamo_tpu.ops.attention import (
     kda_decode_step,
     page_tiles,
     paged_prefill_attention,
+    ssd_chunk_prefill,
+    ssd_decode_step,
 )
 from dynamo_tpu.ops.quant import (
     QuantPool,
@@ -89,20 +94,27 @@ def init_params(spec: ModelSpec, key: jax.Array) -> Params:
             scale = 1.0 / jnp.sqrt(shape[0])
         return _draw(k, scale, shape=shape, dtype=dtype)
 
+    def table(k, shape, axis, scale=None):
+        if spec.vocab_draw_blocks > 1:
+            return _draw_blocks(
+                k, shape[0] ** -0.5 if scale is None else scale, shape, axis,
+                dtype, spec.vocab_draw_blocks)
+        return dense(k, shape, scale=scale)
+
     params: Params = {
-        "embed": dense(next(keys), (spec.vocab_size, d), scale=0.02),
+        "embed": table(next(keys), (spec.vocab_size, d), 0, scale=0.02),
         "final_norm": jnp.ones((d,), dtype),
         "layers": [],
     }
     if not spec.tie_embeddings:
-        params["lm_head"] = dense(next(keys), (d, spec.vocab_size))
+        params["lm_head"] = table(next(keys), (d, spec.vocab_size), 1)
     for li in range(spec.num_layers):
         kd = spec.kind(li)
         nkv = kd.num_kv_heads
         # what a model's newer layers add is drawn on keys of their own
         # (the matrices' keys stay where they were for every older model)
         extra = jax.random.split(jax.random.fold_in(key, 2000 + li), 9)
-        if kd.recurrent:
+        if not kd.paged:
             layer = _init_kda_layer(spec, dense, keys, extra)
         else:
             layer = {
@@ -115,6 +127,8 @@ def init_params(spec: ModelSpec, key: jax.Array) -> Params:
             }
             if spec.attn_gate:
                 layer["w_gate_attn"] = dense(extra[0], (d, nh * vd))
+            if kd.mixer == "ssd":
+                layer.update(_init_ssd_mixer(spec, dense, extra))
         if spec.attn_bias:
             layer.update(
                 bq=jnp.zeros((nh * hd,), dtype),
@@ -150,6 +164,52 @@ def init_params(spec: ModelSpec, key: jax.Array) -> Params:
             )
         params["layers"].append(layer)
     return params
+
+
+def _draw_blocks(key, scale, shape, axis: int, dtype, blocks: int):
+    """``_draw`` of a vocabulary-sized table in ``blocks`` blocks along
+    ``axis`` (``ModelSpec.vocab_draw_blocks``), block ``b`` on
+    ``fold_in(key, b)``, one after the other into place: the float32
+    normals of 261,120 x 5,120 values are 5.35 GB, a third of a chip, and
+    a block's are an eighth of that."""
+    n = shape[axis] // blocks
+    if n * blocks != shape[axis]:
+        raise ValueError(f"a table of {shape} does not cut in {blocks}")
+    block = tuple(n if i == axis else v for i, v in enumerate(shape))
+
+    def put(b, table):
+        part = _draw(
+            jax.random.fold_in(key, b), scale, shape=block, dtype=dtype)
+        return jax.lax.dynamic_update_slice_in_dim(table, part, b * n, axis)
+
+    return jax.lax.fori_loop(0, blocks, put, jnp.zeros(shape, dtype))
+
+
+def _init_ssd_mixer(spec: ModelSpec, dense, extra) -> Params:
+    """An SSD (Mamba-2) mixer's weights beside the layer's attention, on
+    ``extra``: the input projection ``[d, z | x | B | C | dt]``, the taps
+    ``[taps, channels]`` N(0, 1 / taps) and their bias N(0, 0.1^2), ``A =
+    exp(a_log)`` uniform in (1, 16) a head, ``dt_bias`` the inverse
+    softplus of a step log-uniform in (1e-3, 1e-1) (so a token's decay
+    ``exp(-dt A)`` spans (0.2, 0.999) before the input's own term moves
+    it), ``D`` uniform in (0.5, 1.5), the gated norm's gain 1, the output
+    projection. ``a_log``, ``dt_bias`` and ``D`` stay float32."""
+    dtype = jnp.dtype(spec.dtype)
+    f32 = jnp.float32
+    d, H = spec.hidden_size, spec.ssm_heads
+    d_ssm, ch = H * spec.ssm_head_dim, spec.ssm_conv_dim
+    step = jnp.exp(jax.random.uniform(
+        extra[4], (H,), f32, jnp.log(1e-3), jnp.log(1e-1)))
+    return {
+        "ssm_in": dense(extra[0], (d, d_ssm + ch + H)),
+        "ssm_conv": dense(extra[1], (spec.ssm_conv, ch)),
+        "ssm_conv_bias": dense(extra[2], (ch,), scale=0.1),
+        "ssm_a_log": jnp.log(jax.random.uniform(extra[3], (H,), f32, 1.0, 16.0)),
+        "ssm_dt_bias": jnp.log(jnp.expm1(step)),
+        "ssm_d": jax.random.uniform(extra[5], (H,), f32, 0.5, 1.5),
+        "ssm_norm": jnp.ones((d_ssm,), dtype),
+        "ssm_out": dense(extra[6], (d_ssm, d)),
+    }
 
 
 def _init_kda_layer(spec: ModelSpec, dense, keys, extra) -> Params:
@@ -255,6 +315,45 @@ class KindPools(NamedTuple):
     pools: tuple
     counts: jax.Array
     rows: Any = None
+
+
+class PagesAndState(NamedTuple):
+    """The entry in ``KindPools.pools`` of a kind that keeps BOTH (an SSD
+    mixer in parallel with softmax attention, ``LayerKind.mixer ==
+    "ssd"``): its page pool as a paged kind's and, beside it, on the K
+    side its layers' states ``[layers of the kind, rows + 1, H, P, N]``
+    float32, on the V side their convolution tails ``[layers, rows + 1,
+    taps - 1, channels]``. One block table, one page-id space and one
+    ``StateRows`` directory serve both. A kind that keeps one of the two
+    keeps the bare array: the page transfer programs (``_extract_kv_pages_impl``,
+    ``_insert_kv_pages_impl``) index every entry of a paged model as an
+    array, and the benchmark's accepted tests read ``pools[ki].shape`` of
+    a pages-only and of a state-only kind (``tests/perfbench/
+    test_perfbench_mimo.py``, ``test_perfbench_solar.py``); only
+    ``_entry_parts`` / ``_entry_of`` know the three shapes."""
+
+    pages: Any
+    state: jax.Array
+
+
+def _entry_parts(kd, entry):
+    """(pages, state) of a kind's entry in ``KindPools.pools`` (or of the
+    one pool of a model without kinds): None for what the kind lacks."""
+    if kd.paged and kd.recurrent:
+        return entry.pages, entry.state
+    return (None, entry) if kd.recurrent else (entry, None)
+
+
+def _entry_of(kd, pages, state):
+    if kd.paged and kd.recurrent:
+        return PagesAndState(pages, state)
+    return state if kd.recurrent else pages
+
+
+def kind_pages(spec: ModelSpec, side, ki: int):
+    """The page pool of kind ``ki`` on a cache side (None where the kind
+    keeps none)."""
+    return _entry_parts(spec.layer_kinds[ki], side.pools[ki])[0]
 
 
 class StateRows(NamedTuple):
@@ -393,7 +492,7 @@ def init_cache(
     to the 128-lane tile on the chip (pool_head_dim). A model with layer
     kinds gets a ``KindPools`` a side: a pool a kind; a recurrent kind's
     "pool" is ``state_rows`` rows of state (and a trash row), see
-    ``KindPools``.
+    ``KindPools``; a kind that keeps both gets ``PagesAndState``.
 
     ``kv_dtype="fp8"`` allocates QuantPools instead (ops/quant.py): fp8
     values + bf16 per-page/head scales — half the HBM footprint and half
@@ -438,15 +537,32 @@ def init_cache(
     R1 = state_rows + 1  # the last row is the trash row
     H, D = spec.kda_heads, spec.kda_head_dim
 
+    def state(n, kd):
+        if kd.mixer == "ssd":
+            return jnp.zeros(
+                (n, R1, spec.ssm_heads, spec.ssm_head_dim, spec.ssm_state),
+                jnp.float32)
+        return jnp.zeros((n, R1, H, D, D), jnp.float32)
+
+    def tails(n, kd):
+        if kd.mixer == "ssd":
+            return jnp.zeros(
+                (n, R1, spec.ssm_conv - 1, spec.ssm_conv_dim), dtype)
+        return jnp.zeros((n, R1, spec.kda_conv - 1, 3, H * D), dtype)
+
     def k_side(n, kd):
-        if kd.recurrent:
-            return jnp.zeros((n, R1, H, D, D), jnp.float32)
-        return side(n, kd.num_kv_heads, spec.head_dim)
+        return _entry_of(
+            kd,
+            side(n, kd.num_kv_heads, spec.head_dim) if kd.paged else None,
+            state(n, kd) if kd.recurrent else None,
+        )
 
     def v_side(n, kd):
-        if kd.recurrent:
-            return jnp.zeros((n, R1, spec.kda_conv - 1, 3, H * D), dtype)
-        return side(n, kd.num_kv_heads, spec.v_dim)
+        return _entry_of(
+            kd,
+            side(n, kd.num_kv_heads, spec.v_dim) if kd.paged else None,
+            tails(n, kd) if kd.recurrent else None,
+        )
 
     rows = None
     if spec.has_recurrent:
@@ -647,9 +763,18 @@ def _add(x: jax.Array, y: jax.Array) -> jax.Array:
     return x + y
 
 
+def _times(x: jax.Array, m: float) -> jax.Array:
+    """x scaled by one of a family's fixed multipliers; nothing where the
+    model has none (1)."""
+    return x if m == 1.0 else x * jnp.asarray(m, x.dtype)
+
+
 @jax.named_scope(SCOPE_EMBED)
-def _embed(params: Params, tokens: jax.Array) -> jax.Array:
-    return params["embed"][tokens]
+def _embed(
+    params: Params, tokens: jax.Array, spec: ModelSpec | None = None
+) -> jax.Array:
+    x = params["embed"][tokens]
+    return x if spec is None else _times(x, spec.embedding_multiplier)
 
 
 def attn_scope(spec: ModelSpec, li: int) -> str | None:
@@ -667,8 +792,9 @@ def _attn_qkv(
     the rope base are the layer kind's."""
     kd = spec.kind(li)
     lead = x.shape[:-1]
+    x = _times(x, spec.attention_in_multiplier)
     q = x @ lp["wq"]
-    k = x @ lp["wk"]
+    k = _times(x @ lp["wk"], spec.key_multiplier)
     v = x @ lp["wv"]
     if spec.attn_bias:
         q, k, v = q + lp["bq"], k + lp["bk"], v + lp["bv"]
@@ -696,12 +822,16 @@ def _o_proj(
         attn = attn * jax.nn.sigmoid(
             (h @ lp["w_gate_attn"]).astype(jnp.float32)
         ).astype(attn.dtype)
-    out = attn @ lp["wo"]
+    out = _times(attn @ lp["wo"], spec.attention_out_multiplier)
     return out + lp["bo"] if spec.attn_bias else out
 
 
-def _mlp(lp: Params, x: jax.Array) -> jax.Array:
-    return (jax.nn.silu(x @ lp["w_gate"]) * (x @ lp["w_up"])) @ lp["w_down"]
+def _mlp(lp: Params, x: jax.Array, mults: tuple = ()) -> jax.Array:
+    """SwiGLU; ``mults`` (gate, down) where the family scales the gate
+    projection and the down projection's output."""
+    g_mul, d_mul = mults or (1.0, 1.0)
+    gate = jax.nn.silu(_times(x @ lp["w_gate"], g_mul))
+    return _times((gate * (x @ lp["w_up"])) @ lp["w_down"], d_mul)
 
 
 @jax.named_scope(SCOPE_MLP)
@@ -723,7 +853,7 @@ def _ffn(
                 y = _mlp(lp["shared"], x)
                 out = out + y if counted is None else (out[0] + y, out[1])
         return out
-    return _mlp(lp, x)
+    return _mlp(lp, x, spec.mlp_multipliers)
 
 
 def _ffn_counting(
@@ -773,7 +903,7 @@ def _ctx_attention(
 def _logits(spec: ModelSpec, params: Params, x: jax.Array) -> jax.Array:
     x = rms_norm(x, params["final_norm"], spec.rms_eps)
     head = params["embed"].T if spec.tie_embeddings else params["lm_head"]
-    return (x @ head).astype(jnp.float32)
+    return _times((x @ head).astype(jnp.float32), spec.lm_head_multiplier)
 
 
 # ------------------------------------------------------------------- KDA
@@ -906,6 +1036,154 @@ def _kda_whole(spec: ModelSpec, lp: Params, h: jax.Array) -> jax.Array:
     return _kda_out(spec, lp, o[0], h)
 
 
+# ------------------------------------------------------------------- SSD
+
+
+def _ssd_inputs(spec: ModelSpec, lp: Params, h: jax.Array, tail: jax.Array):
+    """An SSD mixer's operands from the layer's normed input. h: [N, T,
+    d]; tail: [N, taps - 1, channels], the x | B | C projections of the
+    ``taps - 1`` tokens before (zeros at a sequence's start). Returns (z
+    [N, T, H P], x [N, T, H, P], B, C [N, T, G, S], dt [N, T, H] float32
+    with the softplus applied, ext [N, taps - 1 + T, channels]: the
+    projections with the tail in front, of which the caller keeps the new
+    tail)."""
+    import numpy as np
+
+    f32 = jnp.float32
+    N, T, _ = h.shape
+    H, P, G, S = (spec.ssm_heads, spec.ssm_head_dim, spec.ssm_groups,
+                  spec.ssm_state)
+    d_ssm, ch = H * P, spec.ssm_conv_dim
+    with jax.named_scope(SCOPE_SSM_PROJ):
+        zxbcdt = _times(h, spec.ssm_in_multiplier) @ lp["ssm_in"]
+        if spec.ssm_multipliers:
+            mup = np.repeat(
+                np.asarray(spec.ssm_multipliers, np.float32),
+                (d_ssm, d_ssm, G * S, G * S, H))
+            zxbcdt = zxbcdt * jnp.asarray(mup, zxbcdt.dtype)
+        z, xbc, dt = jnp.split(zxbcdt, (d_ssm, d_ssm + ch), axis=-1)
+    with jax.named_scope(SCOPE_SSM_CONV):
+        ext = jnp.concatenate([tail.astype(xbc.dtype), xbc], axis=1)
+        taps = lp["ssm_conv"].astype(f32)
+        conv = sum(
+            taps[i] * ext[:, i:i + T].astype(f32)
+            for i in range(spec.ssm_conv)
+        ) + lp["ssm_conv_bias"].astype(f32)
+        x, B, C = jnp.split(
+            jax.nn.silu(conv).astype(h.dtype), (d_ssm, d_ssm + G * S), axis=-1)
+    with jax.named_scope(SCOPE_SSM_GATES):
+        dt = jax.nn.softplus(dt.astype(f32) + lp["ssm_dt_bias"])
+    return (z, x.reshape(N, T, H, P), B.reshape(N, T, G, S),
+            C.reshape(N, T, G, S), dt, ext)
+
+
+def _ssd_out(spec: ModelSpec, lp: Params, y: jax.Array, z: jax.Array):
+    """y: [..., H, P] float32, z: [..., H P] -> the mixer's output [...,
+    d]: the gate ``silu(z)``, then RMSNorm a group of channels
+    (``norm_before_gate`` false), the output projection, its multiplier."""
+    G = spec.ssm_groups
+    with jax.named_scope(SCOPE_SSM_GATES):
+        g = y.reshape(*z.shape) * jax.nn.silu(z.astype(jnp.float32))
+        g = g.reshape(*z.shape[:-1], G, -1)
+        var = jnp.mean(g * g, axis=-1, keepdims=True)
+        g = (g * jax.lax.rsqrt(var + spec.rms_eps)).reshape(*z.shape)
+        g = g.astype(z.dtype) * lp["ssm_norm"]
+    with jax.named_scope(SCOPE_SSM_PROJ):
+        return _times(g @ lp["ssm_out"], spec.ssm_out_multiplier)
+
+
+def _ssd_prefill(
+    spec: ModelSpec, lp: Params, h: jax.Array, s_pool, c_pool, lj: int,
+    idx: jax.Array, fresh: jax.Array, num_tokens: jax.Array,
+):
+    """An SSD mixer over N sequences' new tokens, from and to their state
+    rows. h: [N, T, d]; idx, fresh, num_tokens: [N]. Returns (out [N, T,
+    d], s_pool, c_pool)."""
+    N, T, _ = h.shape
+    with jax.named_scope(SCOPE_QKV):
+        with jax.named_scope(SCOPE_SSM_CONV):
+            tail = jnp.where(fresh[:, None, None], 0, c_pool[lj, idx])
+        z, x, B, C, dt, ext = _ssd_inputs(spec, lp, h, tail)
+        with jax.named_scope(SCOPE_SSM_GATES):
+            # a padded token leaves the state as it was
+            real = jnp.arange(T)[None, :] < num_tokens[:, None]
+            dt = jnp.where(real[..., None], dt, 0.0)
+    with jax.named_scope(SCOPE_KV):
+        y, s_pool = ssd_chunk_prefill(
+            x, dt, -jnp.exp(lp["ssm_a_log"]), B, C, lp["ssm_d"], s_pool,
+            idx, fresh, layer=lj, chunk=spec.ssm_chunk,
+        )
+        # the new tail: the projections of the last taps - 1 REAL tokens
+        new_tail = jax.vmap(
+            lambda e, n: jax.lax.dynamic_slice_in_dim(
+                e, n, spec.ssm_conv - 1, axis=0)
+        )(ext, num_tokens)
+        c_pool = c_pool.at[lj, idx].set(new_tail.astype(c_pool.dtype))
+    with jax.named_scope(SCOPE_OUT):
+        return _ssd_out(spec, lp, y, z), s_pool, c_pool
+
+
+def _ssd_decode(
+    spec: ModelSpec, lp: Params, h: jax.Array, s_pool, c_pool, lj: int,
+    idx: jax.Array,
+):
+    """An SSD mixer's decode step over the slots' state rows. h: [B, d];
+    idx: [B] (the trash row for a slot that owns none). Returns (out [B,
+    d], s_pool, c_pool)."""
+    with jax.named_scope(SCOPE_QKV):
+        z, x, B, C, dt, ext = _ssd_inputs(
+            spec, lp, h[:, None], c_pool[lj, idx])
+    with jax.named_scope(SCOPE_KV):
+        y, s_pool, c_pool = ssd_decode_step(
+            s_pool, c_pool, idx, x[:, 0], dt[:, 0],
+            -jnp.exp(lp["ssm_a_log"]), B[:, 0], C[:, 0], lp["ssm_d"],
+            ext[:, 1:], layer=lj,
+        )
+    with jax.named_scope(SCOPE_OUT):
+        return _ssd_out(spec, lp, y, z[:, 0]), s_pool, c_pool
+
+
+def _ssd_whole(spec: ModelSpec, lp: Params, h: jax.Array) -> jax.Array:
+    """An SSD mixer over one whole sequence from an empty state, keeping
+    none (embeddings, ``reference_forward``). h: [T, d] -> [T, d]."""
+    H, P, S = spec.ssm_heads, spec.ssm_head_dim, spec.ssm_state
+    tail = jnp.zeros((1, spec.ssm_conv - 1, spec.ssm_conv_dim), h.dtype)
+    z, x, B, C, dt, _ = _ssd_inputs(spec, lp, h[None], tail)
+    y, _ = ssd_chunk_prefill(
+        x, dt, -jnp.exp(lp["ssm_a_log"]), B, C, lp["ssm_d"],
+        jnp.zeros((1, 2, H, P, S), jnp.float32), jnp.zeros((1,), jnp.int32),
+        jnp.ones((1,), bool), layer=0, chunk=spec.ssm_chunk,
+    )
+    return _ssd_out(spec, lp, y[0], z[0])
+
+
+# what a recurrent mixer is called with, by ``LayerKind.mixer``: (prefill
+# over [N, T, d] rows, decode step over [B, d] slots, a whole sequence)
+_RECURRENT = {
+    "kda": (_kda_prefill, _kda_decode, _kda_whole),
+    "ssd": (_ssd_prefill, _ssd_decode, _ssd_whole),
+}
+
+
+def _mixers(spec: ModelSpec, li: int, kp, vp, attend, recur):
+    """Layer ``li``'s token mixers over its normed input, ONE body for
+    every kind: softmax attention over the kind's pages where it has KV
+    heads (``attend(k_pool, v_pool) -> (out, k_pool, v_pool)``), the
+    recurrent mixer over its state rows where it has one (``recur(fn,
+    states, tails) -> (out, states, tails)``, ``fn`` the mixer's prefill
+    and decode forms), their outputs summed where it has both. kp, vp: the
+    kind's entries of the cache's two sides. Returns (mix, kp, vp)."""
+    kd = spec.kind(li)
+    (k_pg, s_pool), (v_pg, c_pool) = _entry_parts(kd, kp), _entry_parts(kd, vp)
+    mix = None
+    if kd.paged:
+        mix, k_pg, v_pg = attend(k_pg, v_pg)
+    if kd.recurrent:
+        rec, s_pool, c_pool = recur(_RECURRENT[kd.mixer], s_pool, c_pool)
+        mix = rec if mix is None else mix + rec
+    return mix, _entry_of(kd, k_pg, s_pool), _entry_of(kd, v_pg, c_pool)
+
+
 def _state_owner(block_tables: jax.Array) -> jax.Array:
     """The id a sequence's state row is kept under: its first page."""
     return block_tables[..., 0].astype(jnp.int32)
@@ -960,7 +1238,7 @@ def prefill_forward_impl(
         real = idx < num_tokens
         valid_tok = real.reshape(n_pg, page_size)
 
-    x = _embed(params, tokens)  # [T, d]
+    x = _embed(params, tokens, spec)  # [T, d]
     if mm_embeds is not None:
         x = x.at[mm_pos].set(mm_embeds.astype(x.dtype), mode="drop")
     with jax.named_scope(SCOPE_INDEX):
@@ -975,12 +1253,8 @@ def prefill_forward_impl(
     for li, lp in enumerate(params["layers"]):
         h = _norm(x, lp["attn_norm"], spec.rms_eps)
         kp, vp, lj = _layer_pools(spec, k_pages, v_pages, li)
-        if spec.kind(li).recurrent:
-            mix, kp, vp = _kda_prefill(
-                spec, lp, h[None], kp, vp, lj, idx, fresh, num_tokens[None]
-            )
-            mix = mix[0]
-        else:
+
+        def attend(kp, vp, li=li, lp=lp, lj=lj, h=h):
             q, k, v = _attn_qkv(spec, li, lp, h, positions)
             with jax.named_scope(SCOPE_KV):
                 kp = _set_page_tiles(kp, lj, safe_pg, k, page_size, valid_tok)
@@ -989,7 +1263,15 @@ def prefill_forward_impl(
                     spec, li, lp, q, k, v, kp, vp, lj, block_table,
                     positions, kv_len,
                 )
-            mix = _o_proj(spec, lp, attn, h)
+            return _o_proj(spec, lp, attn, h), kp, vp
+
+        def recur(fn, sp, cp, lp=lp, lj=lj, h=h):
+            mix, sp, cp = fn[0](
+                spec, lp, h[None], sp, cp, lj, idx, fresh, num_tokens[None]
+            )
+            return mix[0], sp, cp
+
+        mix, kp, vp = _mixers(spec, li, kp, vp, attend, recur)
         k_pages, v_pages = _put_pools(spec, k_pages, v_pages, li, kp, vp)
         x = _add(x, mix)
         h = _norm(x, lp["mlp_norm"], spec.rms_eps)
@@ -1012,7 +1294,7 @@ def _no_recurrent(spec: ModelSpec, what: str) -> None:
     ``supports_*``); a direct caller is told."""
     if spec.has_recurrent:
         raise NotImplementedError(
-            f"{what}: no form for a model with recurrent (KDA) layers"
+            f"{what}: no form for a model with recurrent layers"
         )
 
 
@@ -1080,7 +1362,7 @@ def prefill_forward_batch_impl(
         real = idx[None, :] < num_tokens[:, None]  # [N, T]
         valid_tok = real.reshape(N * n_pg, page_size)
 
-    x = _embed(params, tokens)  # [N, T, d]
+    x = _embed(params, tokens, spec)  # [N, T, d]
     with jax.named_scope(SCOPE_INDEX):
         kv_len = start_pos + num_tokens  # [N]
     if spec.has_recurrent:
@@ -1093,11 +1375,8 @@ def prefill_forward_batch_impl(
     for li, lp in enumerate(params["layers"]):
         h = _norm(x, lp["attn_norm"], spec.rms_eps)
         kp, vp, lj = _layer_pools(spec, k_pages, v_pages, li)
-        if spec.kind(li).recurrent:
-            mix, kp, vp = _kda_prefill(
-                spec, lp, h, kp, vp, lj, idx, fresh, num_tokens
-            )
-        else:
+
+        def attend(kp, vp, li=li, lp=lp, lj=lj, h=h):
             q, k, v = _attn_qkv(spec, li, lp, h, positions)
             with jax.named_scope(SCOPE_KV):
                 kp = _set_page_tiles(kp, lj, safe_pg, k, page_size, valid_tok)
@@ -1109,7 +1388,12 @@ def prefill_forward_batch_impl(
                         kvl_i,
                     )
                 )(q, k, v, block_tables, positions, kv_len)
-            mix = _o_proj(spec, lp, attn, h)
+            return _o_proj(spec, lp, attn, h), kp, vp
+
+        def recur(fn, sp, cp, lp=lp, lj=lj, h=h):
+            return fn[0](spec, lp, h, sp, cp, lj, idx, fresh, num_tokens)
+
+        mix, kp, vp = _mixers(spec, li, kp, vp, attend, recur)
         k_pages, v_pages = _put_pools(spec, k_pages, v_pages, li, kp, vp)
         x = _add(x, mix)
         h = _norm(x, lp["mlp_norm"], spec.rms_eps)
@@ -1167,7 +1451,7 @@ def prefill_forward_ring_impl(
     valid_tok = (idx < num_tokens).reshape(n_pg, page_size)
 
     sp_spec = NamedSharding(mesh, P("sp", None))
-    x = _embed(params, tokens)
+    x = _embed(params, tokens, spec)
     x = jax.lax.with_sharding_constraint(x, sp_spec)
 
     for li, lp in enumerate(params["layers"]):
@@ -1247,7 +1531,7 @@ def verify_forward_impl(
     safe_pg = safe_pg2.reshape(N * W)
     offs = offs2.reshape(N * W)
 
-    x = _embed(params, tokens)  # [N, W, d]
+    x = _embed(params, tokens, spec)  # [N, W, d]
     kv_len = start_pos + num_tokens  # [N]
 
     for li, lp in enumerate(params["layers"]):
@@ -1334,14 +1618,13 @@ def decode_forward_impl(
         safe_page = jnp.where(active, page_idx_raw, TRASH_PAGE)
         offset = positions % page_size
 
-    x = _embed(params, tokens)  # [B, d]
+    x = _embed(params, tokens, spec)  # [B, d]
 
     for li, lp in enumerate(params["layers"]):
         h = _norm(x, lp["attn_norm"], spec.rms_eps)
         kp, vp, lj = _layer_pools(spec, k_pages, v_pages, li)
-        if spec.kind(li).recurrent:
-            mix, kp, vp = _kda_decode(spec, lp, h, kp, vp, lj, state_idx)
-        else:
+
+        def attend(kp, vp, li=li, lp=lp, lj=lj, h=h):
             q, k, v = _attn_qkv(spec, li, lp, h, positions)
             # KV append + paged attention in ONE kernel per layer on the
             # Pallas path (ops/pallas/fused_decode.py — halves the decode
@@ -1354,7 +1637,12 @@ def decode_forward_impl(
                     window=spec.kind(li).window, sinks=lp.get("sinks"),
                     scope=attn_scope(spec, li),
                 )
-            mix = _o_proj(spec, lp, attn, h)
+            return _o_proj(spec, lp, attn, h), kp, vp
+
+        def recur(fn, sp, cp, lp=lp, lj=lj, h=h):
+            return fn[1](spec, lp, h, sp, cp, lj, state_idx)
+
+        mix, kp, vp = _mixers(spec, li, kp, vp, attend, recur)
         k_pages, v_pages = _put_pools(spec, k_pages, v_pages, li, kp, vp)
         x = _add(x, mix)
         h = _norm(x, lp["mlp_norm"], spec.rms_eps)
@@ -1540,16 +1828,21 @@ insert_kv_pages = jax.jit(_insert_kv_pages_impl, donate_argnums=(0, 1))
 
 def _whole_mixer(spec: ModelSpec, li: int, lp: Params, h, positions, n):
     """Layer ``li``'s mixer over one whole sequence with no cache (h: [T,
-    d]; ``n`` real tokens): plain causal attention, or KDA from an empty
-    state (a padded tail cannot reach a real token either way)."""
-    if spec.kind(li).recurrent:
-        return _kda_whole(spec, lp, h)
-    q, k, v = _attn_qkv(spec, li, lp, h, positions)
-    attn = causal_attention(
-        q, k, v, positions, n,
-        window=spec.kind(li).window, sinks=lp.get("sinks"),
-    )
-    return _o_proj(spec, lp, attn, h)
+    d]; ``n`` real tokens): plain causal attention, a recurrent mixer
+    from an empty state, or both summed, as the kind has them (a padded
+    tail cannot reach a real token either way)."""
+    kd = spec.kind(li)
+    mix = None
+    if kd.paged:
+        q, k, v = _attn_qkv(spec, li, lp, h, positions)
+        attn = causal_attention(
+            q, k, v, positions, n, window=kd.window, sinks=lp.get("sinks"),
+        )
+        mix = _o_proj(spec, lp, attn, h)
+    if kd.recurrent:
+        rec = _RECURRENT[kd.mixer][2](spec, lp, h)
+        mix = rec if mix is None else mix + rec
+    return mix
 
 
 def embed_forward_impl(
@@ -1565,7 +1858,7 @@ def embed_forward_impl(
     Returns [hidden_size] float32."""
     T = tokens.shape[0]
     positions = jnp.arange(T)
-    x = _embed(params, tokens)
+    x = _embed(params, tokens, spec)
     for li, lp in enumerate(params["layers"]):
         h = _norm(x, lp["attn_norm"], spec.rms_eps)
         x = _add(x, _whole_mixer(spec, li, lp, h, positions, num_tokens))
@@ -1590,7 +1883,7 @@ def reference_forward(
     tests. tokens: [T] -> logits [T, V]."""
     T = tokens.shape[0]
     positions = jnp.arange(T)
-    x = _embed(params, tokens)
+    x = _embed(params, tokens, spec)
     for li, lp in enumerate(params["layers"]):
         h = _norm(x, lp["attn_norm"], spec.rms_eps)
         x = _add(x, _whole_mixer(spec, li, lp, h, positions, jnp.asarray(T)))
@@ -1598,4 +1891,4 @@ def reference_forward(
         x = _add(x, _ffn(spec, lp, h))
     xn = rms_norm(x, params["final_norm"], spec.rms_eps)
     head = params["embed"].T if spec.tie_embeddings else params["lm_head"]
-    return (xn @ head).astype(jnp.float32)
+    return _times((xn @ head).astype(jnp.float32), spec.lm_head_multiplier)

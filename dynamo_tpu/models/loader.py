@@ -58,6 +58,15 @@ __all__ = [
 # ------------------------------------------------------------- spec <-> config
 
 
+# the falcon_h1 family's scalar multipliers: config.json and ModelSpec give
+# them the same names
+_FALCON_SCALARS = (
+    "embedding_multiplier", "lm_head_multiplier", "key_multiplier",
+    "attention_in_multiplier", "attention_out_multiplier",
+    "ssm_in_multiplier", "ssm_out_multiplier",
+)
+
+
 def spec_from_hf_config(cfg: dict, name: str | None = None) -> ModelSpec:
     """Map an HF ``config.json`` dict to a ModelSpec (llama/mixtral family)."""
     model_type = cfg.get("model_type", "llama")
@@ -145,6 +154,37 @@ def spec_from_hf_config(cfg: dict, name: str | None = None) -> ModelSpec:
             routed_scaling_factor=float(cfg.get("routed_scaling_factor") or 1.0),
             norm_topk_prob=bool(cfg.get("norm_topk_prob", True)),
         )
+    if model_type == "falcon_h1":
+        # every layer a Mamba-2 (SSD) mixer and GQA attention in parallel
+        # off one norm, a dense MLP, the family's fixed multipliers
+        from dynamo_tpu.engine.config import LayerKind
+
+        for key in ("attention_bias", "mlp_bias", "projectors_bias",
+                    "mamba_proj_bias"):
+            if cfg.get(key):
+                raise NotImplementedError(f"falcon_h1: {key} true")
+        if not cfg.get("mamba_rms_norm", True) or cfg.get(
+                "mamba_norm_before_gate"):
+            raise NotImplementedError(
+                "falcon_h1: the gated norm after the gate only")
+        if not cfg.get("mamba_conv_bias", True):
+            raise NotImplementedError("falcon_h1: the taps carry a bias")
+        n_layers = int(cfg["num_hidden_layers"])
+        extras.update(
+            layer_kinds=(LayerKind(
+                int(cfg["num_key_value_heads"]),
+                float(cfg.get("rope_theta", 1e11)), mixer="ssd"),),
+            layer_pattern=(0,) * n_layers,
+            ssm_heads=int(cfg["mamba_n_heads"]),
+            ssm_head_dim=int(cfg["mamba_d_head"]),
+            ssm_state=int(cfg["mamba_d_state"]),
+            ssm_groups=int(cfg["mamba_n_groups"]),
+            ssm_conv=int(cfg["mamba_d_conv"]),
+            ssm_chunk=int(cfg.get("mamba_chunk_size") or 128),
+            ssm_multipliers=tuple(cfg.get("ssm_multipliers") or ()),
+            mlp_multipliers=tuple(cfg.get("mlp_multipliers") or ()),
+            **{key: float(cfg.get(key, 1.0)) for key in _FALCON_SCALARS},
+        )
     # YaRN rope scaling (gpt-oss, DeepSeek-R1)
     rs = cfg.get("rope_scaling") or {}
     if (rs.get("rope_type") or rs.get("type")) == "yarn":
@@ -199,7 +239,9 @@ def hf_config_from_spec(spec: ModelSpec) -> dict:
     checkpoint silently loses features on reload."""
     if spec.kv_lora_rank:
         model_type = "deepseek_v3"
-    elif spec.has_recurrent:
+    elif "ssd" in spec.mixers:
+        model_type = "falcon_h1"
+    elif "kda" in spec.mixers:
         model_type = "solar_open2"
     elif spec.attn_sinks:
         model_type = "gpt_oss"
@@ -251,6 +293,19 @@ def hf_config_from_spec(spec: ModelSpec) -> dict:
             kda_use_full_proj=False, kda_allow_neg_eigval=spec.kda_neg_eigval,
         )
         del cfg["num_local_experts"]
+    if model_type == "falcon_h1":
+        cfg.update(
+            mamba_n_heads=spec.ssm_heads, mamba_d_head=spec.ssm_head_dim,
+            mamba_d_ssm=spec.ssm_heads * spec.ssm_head_dim,
+            mamba_d_state=spec.ssm_state, mamba_n_groups=spec.ssm_groups,
+            mamba_d_conv=spec.ssm_conv, mamba_chunk_size=spec.ssm_chunk,
+            mamba_conv_bias=True, mamba_proj_bias=False,
+            mamba_rms_norm=True, mamba_norm_before_gate=False,
+            attention_bias=False, mlp_bias=False, projectors_bias=False,
+            ssm_multipliers=list(spec.ssm_multipliers),
+            mlp_multipliers=list(spec.mlp_multipliers),
+            **{key: getattr(spec, key) for key in _FALCON_SCALARS},
+        )
     if model_type == "gpt_oss":
         cfg.update(
             sliding_window=spec.sliding_window,
@@ -386,9 +441,11 @@ def _dest_map(
     express). gpt-oss attention sinks, projection biases, and router
     bias map here when the spec enables them.
     """
+    falcon = "ssd" in spec.mixers  # the published falcon_h1 names
     m: dict[str, tuple[tuple, bool, str | None]] = {
         "model.embed_tokens.weight": (("embed",), False, None),
-        "model.norm.weight": (("final_norm",), False, None),
+        ("model.final_layernorm.weight" if falcon else "model.norm.weight"):
+            (("final_norm",), False, None),
     }
     if not spec.tie_embeddings:
         m["lm_head.weight"] = (("lm_head",), True, None)
@@ -397,11 +454,29 @@ def _dest_map(
         p = f"model.layers.{i}."
         li = ("layers", i)
         m[p + "input_layernorm.weight"] = (li + ("attn_norm",), False, None)
-        m[p + "post_attention_layernorm.weight"] = (li + ("mlp_norm",), False, None)
+        m[p + ("pre_ff_layernorm.weight" if falcon
+               else "post_attention_layernorm.weight")] = (
+            li + ("mlp_norm",), False, None)
         for hf, ours in (("q_proj", "wq"), ("k_proj", "wk"),
                          ("v_proj", "wv"), ("o_proj", "wo")):
             m[p + f"self_attn.{hf}.weight"] = (li + (ours,), True, None)
-        if spec.kind(i).recurrent:
+        if spec.kind(i).mixer == "ssd":
+            # the Mamba-2 mixer's own tensors beside the attention's. The
+            # taps are stored [channels, 1, taps]: load_params folds the
+            # middle axis
+            a = p + "mamba."
+            for hf, ours, tr, dt in (
+                ("in_proj.weight", "ssm_in", True, None),
+                ("conv1d.weight", "ssm_conv", True, None),
+                ("conv1d.bias", "ssm_conv_bias", False, None),
+                ("A_log", "ssm_a_log", False, "float32"),
+                ("dt_bias", "ssm_dt_bias", False, "float32"),
+                ("D", "ssm_d", False, "float32"),
+                ("norm.weight", "ssm_norm", False, None),
+                ("out_proj.weight", "ssm_out", True, None),
+            ):
+                m[a + hf] = (li + (ours,), tr, dt)
+        elif spec.kind(i).recurrent:
             # a KDA layer's own tensors (the names of the public
             # flash-linear-attention KDA module). The taps are stored
             # [channels, 1, taps]: load_params folds the middle axis
@@ -461,10 +536,16 @@ def _dest_map(
                         li + ("moe", "router_bias"), False, "float32"
                     )
         else:
+            mlp = "feed_forward." if falcon else "mlp."
             for hf, ours in (("gate_proj", "w_gate"), ("up_proj", "w_up"),
                              ("down_proj", "w_down")):
-                m[p + f"mlp.{hf}.weight"] = (li + (ours,), True, None)
+                m[p + mlp + f"{hf}.weight"] = (li + (ours,), True, None)
     return m
+
+
+def _is_taps(path: tuple) -> bool:
+    """A short convolution's taps, published ``[channels, 1, taps]``."""
+    return str(path[-1]).startswith("conv_") or path[-1] == "ssm_conv"
 
 
 def _tree_set(tree: Params, path: tuple, value) -> None:
@@ -611,7 +692,7 @@ def load_params(
                     continue
                 path, transpose, dt_override = dest[name]
                 arr = f.get_tensor(name)
-                if arr.ndim == 3 and str(path[-1]).startswith("conv_"):
+                if arr.ndim == 3 and _is_taps(path):
                     arr = arr.reshape(arr.shape[0], -1)  # [C, 1, taps]
                 if transpose:
                     arr = np.ascontiguousarray(arr.T)
@@ -719,7 +800,8 @@ def save_params(
         dest = _dest_map(
             spec, names={"model.layers.0.mlp.experts.gate_up_proj"}
         )
-    elif spec.has_recurrent:
+    elif "kda" in spec.mixers:
+        # solar_open2's experts are named one by one
         dest = _dest_map(spec, names={"model.layers.0.mlp.experts.0."})
     else:
         dest = _dest_map(spec)
@@ -731,7 +813,7 @@ def save_params(
             arr = np.asarray(_tree_get(params, path))
         if transpose:
             arr = np.ascontiguousarray(arr.T)
-        if str(path[-1]).startswith("conv_"):
+        if _is_taps(path):
             arr = arr[:, None, :]  # the published [channels, 1, taps]
         tensors[name] = arr
     if spec.moe_bias and not spec.kv_lora_rank:

@@ -42,6 +42,9 @@ SCOPE_LATENT_ABSORB = "latent_absorb"  # MLA decode: W_uk into the queries,
 SCOPE_KDA_PROJ = "kda_proj"  # KDA: the q | k | v projections
 SCOPE_KDA_CONV = "kda_conv"  # KDA: tails, the causal convolutions, norms
 SCOPE_KDA_GATES = "kda_gates"  # KDA: decay and beta
+SCOPE_SSM_PROJ = "ssm_proj"  # SSD: the input and output projections, mup
+SCOPE_SSM_CONV = "ssm_conv"  # SSD: tails, the causal convolution on x|B|C
+SCOPE_SSM_GATES = "ssm_gates"  # SSD: dt, the decays, the gated group norm
 # -- attn_ctx
 SCOPE_KV = "attn_kv"  # KV write + attention over the paged context
 SCOPE_ATTN_WINDOW = "attn_window"
@@ -56,6 +59,8 @@ SCOPE_KDA_STEP = "kda_step"
 SCOPE_KDA_CHUNK = "kda_chunk"
 SCOPE_KDA_CHUNK_OPERANDS = "kda_chunk_operands"  # the chunkwise form's
 # batched XLA half: decays, triangular solves, re-layouts
+SCOPE_SSD_STEP = "ssd_step"  # SSD decode: the state rows' update
+SCOPE_SSD_CHUNK = "ssd_chunk"  # SSD prefill: the chunk form, all of it
 SCOPE_STATE_ROWS = "state_rows"  # the recurrent state's directory
 # -- ffn
 SCOPE_MLP = "mlp"
@@ -85,12 +90,14 @@ REGIONS: dict[str, str] = {
     SCOPE_QKV: ATTN_PROJ, SCOPE_OUT: ATTN_PROJ, SCOPE_LATENT_Q: ATTN_PROJ,
     SCOPE_LATENT_KV: ATTN_PROJ, SCOPE_LATENT_ABSORB: ATTN_PROJ,
     SCOPE_KDA_PROJ: ATTN_PROJ, SCOPE_KDA_CONV: ATTN_PROJ,
-    SCOPE_KDA_GATES: ATTN_PROJ,
+    SCOPE_KDA_GATES: ATTN_PROJ, SCOPE_SSM_PROJ: ATTN_PROJ,
+    SCOPE_SSM_CONV: ATTN_PROJ, SCOPE_SSM_GATES: ATTN_PROJ,
     SCOPE_KV: ATTN_CTX, SCOPE_ATTN_WINDOW: ATTN_CTX,
     SCOPE_ATTN_FULL: ATTN_CTX, SCOPE_FUSED_DECODE: ATTN_CTX,
     SCOPE_ATTN_LATENT: ATTN_CTX, SCOPE_PREFILL_LATENT: ATTN_CTX,
     SCOPE_LATENT_SCHEDULE: ATTN_CTX, SCOPE_KDA_STEP: ATTN_CTX,
     SCOPE_KDA_CHUNK: ATTN_CTX, SCOPE_KDA_CHUNK_OPERANDS: ATTN_CTX,
+    SCOPE_SSD_STEP: ATTN_CTX, SCOPE_SSD_CHUNK: ATTN_CTX,
     SCOPE_STATE_ROWS: ATTN_CTX,
     SCOPE_MLP: FFN, SCOPE_ROUTE: FFN, SCOPE_EXPERTS: FFN,
     SCOPE_MOE_DISPATCH: FFN, SCOPE_MOE_GROUPED: FFN, SCOPE_GMM: FFN,
@@ -106,7 +113,7 @@ REGIONS: dict[str, str] = {
 KERNEL_SCOPES = (
     SCOPE_FUSED_DECODE, SCOPE_ATTN_WINDOW, SCOPE_ATTN_FULL,
     SCOPE_ATTN_LATENT, SCOPE_PREFILL_LATENT, SCOPE_GMM, SCOPE_KDA_STEP,
-    SCOPE_KDA_CHUNK,
+    SCOPE_KDA_CHUNK, SCOPE_SSD_STEP, SCOPE_SSD_CHUNK,
 )
 
 _WRAPPED = re.compile(r"\(([^()]*)\)")

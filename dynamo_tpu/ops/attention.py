@@ -31,7 +31,7 @@ import jax.numpy as jnp
 # ``attn_latent`` names the latent decode kernel (its roofline share
 # divides by that operation's time); ``prefill_latent`` is in no
 # configuration's ``trace_names``; ``kda_step`` / ``kda_chunk`` name the
-# recurrent layers' kernels
+# recurrent layers' kernels, ``ssd_step`` / ``ssd_chunk`` the SSD mixer's
 from dynamo_tpu.models.regions import (
     SCOPE_ATTN_LATENT,
     SCOPE_KDA_CHUNK,
@@ -39,6 +39,8 @@ from dynamo_tpu.models.regions import (
     SCOPE_KDA_STEP,
     SCOPE_LATENT_SCHEDULE,
     SCOPE_PREFILL_LATENT,
+    SCOPE_SSD_CHUNK,
+    SCOPE_SSD_STEP,
 )
 
 NEG_INF = -1e30
@@ -1144,3 +1146,165 @@ def kda_decode_step(pool, conv, rows, q, k, v, g, beta, tail, *, layer: int):
     o = jnp.einsum("bhkv,bhk->bhv", s, q, precision=_HI)
     return (o, pool.at[layer, rows].set(s),
             conv.at[layer, rows].set(tail.astype(conv.dtype)))
+
+
+# ------------------------------------------------------------------- SSD
+# Mamba-2's state-space duality mixer (arXiv:2405.21060): a state H [P, N]
+# a head a sequence, float32, under a SCALAR decay a head a token, with B
+# and C shared by the heads of a group:
+#
+#     H_t = exp(dt_t A) H_{t-1} + dt_t x_t (outer) B_t
+#     y_t = H_t C_t + D x_t
+#
+# Appended at the file's end: no softmax or KDA line moved.
+
+
+def ssd_recurrence(x, dt, A, B, C, D, h0):
+    """The recurrence a token at a time: what the chunk form must equal
+    (tests). x: [T, H, P]; dt: [T, H]; A, D: [H]; B, C: [T, G, N]; h0: [H,
+    P, N]. Returns (y [T, H, P] float32, h)."""
+    f32 = jnp.float32
+    H = x.shape[1]
+    rep = H // B.shape[1]
+
+    def step(h, at):
+        x_t, dt_t, b_t, c_t = at
+        b_t, c_t = (jnp.repeat(y, rep, axis=0) for y in (b_t, c_t))
+        h = jnp.exp(dt_t * A)[:, None, None] * h + (
+            (dt_t[:, None] * x_t)[:, :, None] * b_t[:, None, :])
+        y = jnp.einsum("hpn,hn->hp", h, c_t, precision=_HI)
+        return h, y + D[:, None] * x_t
+
+    h, y = jax.lax.scan(step, h0.astype(f32), (
+        x.astype(f32), dt.astype(f32), B.astype(f32), C.astype(f32)))
+    return y, h
+
+
+def ssd_prefill_chunks(num_tokens, chunk: int) -> int:
+    """Chunks of ``chunk`` tokens that hold a real token, a call's rows
+    summed: what the chunk form carries a state through (the engine's
+    ``ssd.prefill_chunks`` counter; numpy or python integers)."""
+    import numpy as np
+
+    return int((-(-np.asarray(num_tokens) // chunk)).sum())
+
+
+@jax.named_scope(SCOPE_SSD_CHUNK)
+def ssd_chunk_prefill(x, dt, A, B, C, D, pool, rows, fresh, *, layer: int,
+                      chunk: int):
+    """The chunkwise (SSD) form over whole rows, from and to the
+    sequences' rows of the state pool ``[L, rows + 1, H, P, N]`` float32:
+    inside a chunk of ``chunk`` tokens the masked ``(C B^T) (.) L`` product
+    with ``L[t, s] = exp(sum of the log decays after s up to t)``, between
+    chunks the carried state, read from the row once before the first
+    chunk (or zero at a sequence's start) and written once after the
+    last. Plain batched XLA, all of it: its arithmetic is a hundredth of
+    the projections' beside it. x: [N, T, H, P]; dt: [N, T, H] float32,
+    softplus applied, 0 at a padded token (which then leaves the state as
+    it was and adds nothing); A, D: [H] float32; B, C: [N, T, G, N];
+    rows: [N] int32 (the pool's last row = trash); fresh: [N] bool. The
+    products inside a chunk run in x's dtype (float32 accumulation), what
+    touches the carried state in float32 at ``HIGHEST``. Every exponent
+    is of a non-positive number. Returns (y [N, T, H, P] float32,
+    pool)."""
+    f32 = jnp.float32
+    N, T, H, P = x.shape
+    G, S = B.shape[-2:]
+    hg = H // G
+    Q = min(chunk, T)
+    pad = -T % Q
+    nc = (T + pad) // Q
+    cd = x.dtype
+
+    def chunks(a):  # [N, T, h, ...] -> [N, h, nc, Q, ...]
+        a = jnp.pad(a, ((0, 0), (0, pad)) + ((0, 0),) * (a.ndim - 2))
+        a = a.reshape(N, nc, Q, *a.shape[2:])
+        return jnp.moveaxis(a, 3, 1)
+
+    xc = chunks(x)  # [N, H, nc, Q, P]
+    dtc = chunks(dt.astype(f32))  # [N, H, nc, Q]
+    Bc, Cc = chunks(B), chunks(C)  # [N, G, nc, Q, S]
+    cum = jnp.cumsum(dtc * A[None, :, None, None], axis=-1)  # <= 0
+    t = jnp.arange(Q)
+    seen = t[:, None] >= t[None, :]
+    decay = jnp.exp(jnp.where(
+        seen, cum[..., :, None] - cum[..., None, :], -jnp.inf))
+    cb = jnp.einsum("ngctk,ngcsk->ngcts", Cc, Bc, preferred_element_type=f32)
+    m = (decay.reshape(N, G, hg, nc, Q, Q) * cb[:, :, None]).reshape(
+        N, H, nc, Q, Q)
+    dx = dtc[..., None] * xc.astype(f32)  # [N, H, nc, Q, P]
+    y = jnp.einsum("nhcts,nhcsp->nhctp", m.astype(cd), dx.astype(cd),
+                   preferred_element_type=f32)
+    # what a chunk adds to the state, decayed to its end, and its decay
+    to_end = jnp.exp(cum[..., -1:] - cum)  # [N, H, nc, Q]
+    adds = jnp.einsum(
+        "ngjcsp,ngcsk->ngjcpk",
+        (to_end[..., None] * dx).reshape(N, G, hg, nc, Q, P),
+        Bc.astype(f32), precision=_HI,
+    ).reshape(N, H, nc, P, S)
+    gamma = jnp.exp(cum[..., -1])  # [N, H, nc]
+
+    def carry(h, at):
+        add, g = at
+        return g[..., None, None] * h + add, h  # emits a chunk's START
+
+    # a row at a time, by dynamic slices: a gather from (and a scatter
+    # into) the pool makes the chip's compiler copy the pool, 0.5 GB a
+    # layer at the published widths
+    row = (1, 1) + pool.shape[2:]
+    h0 = jnp.concatenate([
+        jax.lax.dynamic_slice(pool, (layer, rows[i], 0, 0, 0), row)[0]
+        for i in range(N)
+    ])
+    h0 = jnp.where(fresh[:, None, None, None], 0.0, h0)
+    h, starts = jax.lax.scan(
+        carry, h0, (jnp.moveaxis(adds, 2, 0), jnp.moveaxis(gamma, 2, 0)))
+    y = y + jnp.exp(cum)[..., None] * jnp.einsum(
+        "cngjpk,ngctk->ngjctp", starts.reshape(nc, N, G, hg, P, S),
+        Cc.astype(f32), precision=_HI,
+    ).reshape(N, H, nc, Q, P)
+    y = jnp.moveaxis(y, 1, 3).reshape(N, nc * Q, H, P)[:, :T]
+    y = y + D[:, None] * x.astype(f32)
+    for i in range(N):  # in order: two members on the trash row are fine
+        pool = jax.lax.dynamic_update_slice(
+            pool, h[i][None, None], (layer, rows[i], 0, 0, 0))
+    return y, pool
+
+
+def ssd_decode_step(pool, conv, rows, x, dt, A, B, C, D, tail, *, layer: int):
+    """One decode step of every slot over layer ``layer`` of the state
+    pool ``[L, rows + 1, H, P, N]`` float32, the slots' new convolution
+    tails ``tail [B, taps - 1, channels]`` put into ``conv [L, rows + 1,
+    taps - 1, channels]``, and the place the implementation is chosen: the
+    ``ssd_step`` kernel (ops/pallas/ssd.py) wherever Pallas is active
+    (each live slot's row read once and written once, in place, the tails
+    in the same call), else a gather of the slots' rows, the step in XLA
+    and scatters back (counted ``no_pallas_backend``). ``rows`` [B]: each
+    slot's row, the trash row (the pools' last) for a slot that owns
+    none. x: [B, H, P]; dt: [B, H] float32, softplus applied; A, D: [H];
+    B, C: [B, G, N]. Returns (y [B, H, P] float32, pool, conv)."""
+    from dynamo_tpu.ops.fallback import note_fallback
+
+    f32 = jnp.float32
+    x, dt, B, C = (a.astype(f32) for a in (x, dt, B, C))
+    decay = jnp.exp(dt * A)  # [B, H]
+    dx = dt[..., None] * x
+    if use_pallas():
+        from dynamo_tpu.ops.pallas.ssd import ssd_step
+
+        y, pool, conv = ssd_step(
+            pool, conv, rows, dx, decay, B, C, tail, layer=layer,
+            interpret=jax.default_backend() != "tpu", scope=SCOPE_SSD_STEP,
+        )
+    else:
+        note_fallback("no_pallas_backend", expected=True,
+                      detail="ssd_decode_step: gather, step, scatter")
+        with jax.named_scope(SCOPE_SSD_STEP):
+            rep = x.shape[1] // B.shape[1]
+            Bh, Ch = (jnp.repeat(a, rep, axis=1) for a in (B, C))
+            h = decay[..., None, None] * pool[layer, rows] + (
+                dx[..., None] * Bh[:, :, None, :])
+            y = jnp.einsum("bhpn,bhn->bhp", h, Ch, precision=_HI)
+            pool = pool.at[layer, rows].set(h)
+            conv = conv.at[layer, rows].set(tail.astype(conv.dtype))
+    return y + D[:, None] * x, pool, conv

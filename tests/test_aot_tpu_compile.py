@@ -278,3 +278,116 @@ def test_kda_chunk_compiles_for_v5e(v5e, rows, blocks):
         _rows(v5e, rows, dtype=jnp.int32), _rows(v5e, rows, dtype=jnp.bool_),
     ).compile()
     assert "%kda_chunk" in compiled.as_text()
+
+
+def test_ssd_step_compiles_for_v5e(v5e):
+    """Falcon-H1's cell: 128 slots on 129 state rows, 32 heads of 128 x
+    256 float32 in 2 groups and the convolution tails of 5,120 channels,
+    both pools aliased in place, a row found through the scalar-prefetched
+    ``rows``."""
+    from dynamo_tpu.ops.pallas.ssd import ssd_step
+
+    slots, heads, p, n, groups, rows, ch = 128, 32, 128, 256, 2, 128, 5120
+    f32 = jnp.float32
+    compiled = jax.jit(
+        lambda pool, conv, at, dx, decay, b, c, tail: ssd_step(
+            pool, conv, at, dx, decay, b, c, tail, layer=1, scope="ssd_step"),
+        donate_argnums=(0, 1),
+    ).lower(
+        _rows(v5e, 4, rows + 1, heads, p, n, dtype=f32),
+        _rows(v5e, 4, rows + 1, 3, ch),
+        _rows(v5e, slots, dtype=jnp.int32),
+        _rows(v5e, slots, heads, p, dtype=f32),
+        _rows(v5e, slots, heads, dtype=f32),
+        _rows(v5e, slots, groups, n, dtype=f32),
+        _rows(v5e, slots, groups, n, dtype=f32),
+        _rows(v5e, slots, 3, ch),
+    ).compile()
+    # the kernel is named after the scope: the trace's readers match it
+    assert "%ssd_step" in compiled.as_text()
+
+
+def _falcon_layer(v5e, vocab=2048):
+    """One layer of Falcon-H1 at the published widths, described: spec,
+    weights and the cache of the cell's engine (128 slots, pages of 64)."""
+    import dataclasses
+
+    from dynamo_tpu.engine.config import LayerKind, ModelSpec
+    from dynamo_tpu.models import llama
+
+    spec = dataclasses.replace(
+        ModelSpec.tiny_falcon_h1(), vocab_size=vocab, hidden_size=5120,
+        intermediate_size=21504, num_layers=1, num_heads=20, num_kv_heads=4,
+        head_dim=128, dtype="bfloat16", layer_pattern=(0,),
+        layer_kinds=(LayerKind(4, 1e11, mixer="ssd"),),
+        ssm_heads=32, ssm_head_dim=128, ssm_state=256, ssm_chunk=128)
+
+    def described(tree):
+        return jax.tree.map(
+            lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=v5e),
+            tree)
+
+    params = described(jax.eval_shape(
+        lambda: llama.init_params(spec, jax.random.PRNGKey(0))))
+    k, v = described(jax.eval_shape(
+        lambda: llama.init_cache(spec, 257, 64, state_rows=128)))
+    return spec, params, k, v
+
+
+def _as_on_the_chip(monkeypatch):
+    """The programs choose their kernels, and whether to interpret them,
+    by the default backend: for a described device it is the CPU, so the
+    choice is told what the chip would say."""
+    monkeypatch.setenv("DYNAMO_PALLAS", "1")
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+
+
+@pytest.mark.parametrize("rows", [2, 1], ids=["pack-of-2", "single"])
+def test_falcon_prefill_program_compiles_for_v5e(v5e, monkeypatch, rows):
+    """The prefill programs of the cell, a layer of them at the published
+    widths: 1,024 tokens a row through the page write, the walk and the
+    SSD chunk form (8 chunks of 128), state and tails from and to their
+    rows; every leaf of the cache donated."""
+    from dynamo_tpu.models import llama
+
+    _as_on_the_chip(monkeypatch)
+    spec, params, k, v = _falcon_layer(v5e)
+    i32 = jnp.int32
+    if rows == 1:
+        lowered = jax.jit(
+            llama.prefill_forward_impl, static_argnums=(0,),
+            donate_argnums=(5, 6),
+        ).lower(spec, params, _rows(v5e, 1024, dtype=i32),
+                _rows(v5e, 160, dtype=i32), _rows(v5e, dtype=i32), k, v,
+                _rows(v5e, dtype=i32))
+    else:
+        lowered = jax.jit(
+            llama.prefill_forward_batch_impl, static_argnums=(0,),
+            donate_argnums=(5, 6),
+        ).lower(spec, params, _rows(v5e, rows, 1024, dtype=i32),
+                _rows(v5e, rows, 160, dtype=i32), _rows(v5e, rows, dtype=i32),
+                k, v, _rows(v5e, rows, dtype=i32))
+    text = lowered.compile().as_text()
+    assert "ssd_chunk" in text
+
+
+def test_falcon_decode_program_compiles_for_v5e(v5e, monkeypatch):
+    """The decode burst of the cell, a layer of it at the published
+    widths: 128 slots through the fused attention kernel (``attn_full``)
+    AND ``ssd_step`` in one layer, 8 steps, the sampler on the device."""
+    from dynamo_tpu.models import llama
+
+    _as_on_the_chip(monkeypatch)
+    spec, params, k, v = _falcon_layer(v5e)
+    B_, i32, f32 = 128, jnp.int32, jnp.float32
+    text = jax.jit(
+        llama.decode_steps_impl, static_argnums=(0,),
+        static_argnames=("n_steps", "n_logprobs"), donate_argnums=(5, 6),
+    ).lower(
+        spec, params, _rows(v5e, B_, dtype=i32), _rows(v5e, B_, 160, dtype=i32),
+        _rows(v5e, B_, dtype=i32), k, v, _rows(v5e, B_, dtype=jnp.bool_),
+        _rows(v5e, B_, dtype=f32), _rows(v5e, B_, dtype=i32),
+        _rows(v5e, B_, dtype=f32), _rows(v5e, B_, dtype=jnp.uint32),
+        _rows(v5e, B_, dtype=i32), n_steps=8, n_logprobs=0,
+    ).compile().as_text()
+    assert "%ssd_step" in text and "%attn_full" in text

@@ -40,7 +40,8 @@ def small_config(**kw):
 
 @pytest.mark.parametrize("spec", [
     SPEC, ModelSpec.tiny_moe(), ModelSpec.tiny_solar(),
-], ids=["dense", "moe", "recurrent"])
+    ModelSpec.tiny_falcon_h1(),
+], ids=["dense", "moe", "recurrent", "parallel-ssm"])
 def test_the_engines_weights_are_the_familys_draw(spec):
     """The engine draws random weights as one program; tensor for tensor
     they are what ``init_params`` gives op by op (the benchmark's plain

@@ -166,6 +166,15 @@ PROFILE_COUNTERS: dict[str, str] = {
     "kda.prefill_blocks": "blocks of 64 tokens with a real token that "
                           "kda_chunk carried a state through, over the "
                           "dispatched prefills; a layer's worth",
+    # a model with SSD (Mamba-2) layers only
+    "ssd.decode_rows": "state rows an ssd_step call updated (live slots), "
+                       "over the dispatched bursts' steps; a layer's worth",
+    "ssd.prefill_chunks": "chunks of mamba_chunk_size tokens with a real "
+                          "token that the SSD chunk form carried a state "
+                          "through, over the dispatched prefills; a "
+                          "layer's worth",
+    "ssd.rows_resumed": "rows of prefill programs that continued a state "
+                        "(start_pos > 0): a chunk behind a prompt's first",
     "recurrent_state.rows": "state rows the engine holds (a gauge)",
     "recurrent_state.rows_live": "rows a live sequence owns now: decode "
                                  "slots and the open chunked prefill",
