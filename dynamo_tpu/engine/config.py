@@ -699,7 +699,9 @@ class EngineConfig:
             # accumulator (ops/attention.paged_prefill_attention); a KDA
             # layer's chunkwise form a score of float32 arrays [rows,
             # bucket, heads x head_dim] (ops/attention.kda_chunk_prefill:
-            # its operands by block and what they are made from); 96 KiB a
+            # its operands by block and what they are made from, where
+            # XLA forms them; an upper bound where the kernel does, kept
+            # so that the shapes are the same either way); 96 KiB a
             # prompt token for the rest
             from dynamo_tpu.ops.attention import prefill_tiling
 

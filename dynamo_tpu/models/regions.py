@@ -57,8 +57,10 @@ SCOPE_LATENT_SCHEDULE = "latent_schedule"  # the decode kernel's live
 # chunks, once a step
 SCOPE_KDA_STEP = "kda_step"
 SCOPE_KDA_CHUNK = "kda_chunk"
-SCOPE_KDA_CHUNK_OPERANDS = "kda_chunk_operands"  # the chunkwise form's
-# batched XLA half: decays, triangular solves, re-layouts
+SCOPE_KDA_CHUNK_OPERANDS = "kda_chunk_operands"  # what XLA does of the
+# chunkwise form beside ``kda_chunk``: the pad of the token axis where the
+# kernel forms a block's operands itself; off the chip the batched half
+# (decays, triangular solves, re-layouts)
 SCOPE_SSD_STEP = "ssd_step"  # SSD decode: the state rows' update
 SCOPE_SSD_CHUNK = "ssd_chunk"  # SSD prefill: the chunk form, all of it
 SCOPE_STATE_ROWS = "state_rows"  # the recurrent state's directory

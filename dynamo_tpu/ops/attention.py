@@ -1051,58 +1051,66 @@ def kda_prefill_blocks(num_tokens) -> int:
 def kda_chunk_prefill(q, k, v, g, beta, pool, rows, fresh, *, layer: int):
     """The chunkwise form over whole rows, from and to the sequences' rows
     of the state pool ``[L, rows + 1, H, dk, dv]`` float32, and the place
-    its sequential part is chosen: the ``kda_chunk`` kernel
-    (ops/pallas/kda.py) wherever Pallas is active, which reads a row once
-    and writes it once in place; a ``lax.scan`` a BLOCK between a gather
-    and a scatter elsewhere (counted ``no_pallas_backend``). q, k, g: [N,
-    T, H, dk]; v: [N, T, H, dv]; beta: [N, T, H]; rows: [N] int32 (the
-    pool's last row = trash); fresh: [N] bool, start from a zero state. A
-    padded token carries g = 0 and beta = 0: it leaves the state as it
-    was. Returns (o [N, T, H, dv] float32, pool)."""
+    its implementation is chosen: the ``kda_chunk`` kernel
+    (ops/pallas/kda.py) wherever Pallas is active, which takes the layer's
+    operands as they are (``[N, T, H d]``, a free reshape), forms a block's
+    half that does not depend on the state (``kda_chunk_operands``' terms)
+    in VMEM at the step that carries the state through it, reads a row
+    once and writes it once in place; elsewhere ``kda_chunk_operands`` over
+    all blocks at once in XLA and a ``lax.scan`` a BLOCK between a gather
+    and a scatter (counted ``no_pallas_backend``). q, k, g: [N, T, H, dk];
+    v: [N, T, H, dv]; beta: [N, T, H]; rows: [N] int32 (the pool's last
+    row = trash); fresh: [N] bool, start from a zero state. A padded token
+    carries g = 0 and beta = 0: it leaves the state as it was. Returns (o
+    [N, T, H, dv] float32, pool)."""
     from dynamo_tpu.ops.fallback import note_fallback
 
     f32 = jnp.float32
-    N, T, H, dk = q.shape
+    N, T, H, _ = q.shape
     C = min(KDA_BLOCK, -(-T // KDA_SUB) * KDA_SUB)
     pad = -T % C
     nb = (T + pad) // C
+    if use_pallas():
+        from dynamo_tpu.ops.pallas.kda import kda_chunk
+
+        def flat(x):  # [N, T, ...] -> [N, T + pad, H d]: a head a lane tile
+            x = x.astype(f32).reshape(N, T, -1)
+            return jnp.pad(x, ((0, 0), (0, pad), (0, 0))) if pad else x
+
+        # what XLA is left of the batched half: the pad of the token axis
+        with jax.named_scope(SCOPE_KDA_CHUNK_OPERANDS):
+            args = [flat(x) for x in (q, k, v, g, beta)]
+        o, pool = kda_chunk(
+            *args, pool, rows, fresh, layer=layer, block=C,
+            sub=min(KDA_SUB, C), interpret=jax.default_backend() != "tpu",
+            scope=SCOPE_KDA_CHUNK,
+        )
+        return o[:, :T].reshape(N, T, H, -1), pool
+    note_fallback("no_pallas_backend", expected=True,
+                  detail="kda_chunk_prefill: XLA operands, lax.scan over blocks")
 
     def blocks(x):  # [N, T, H, d] -> [N, H, nb, C, d]
         x = jnp.pad(x.astype(f32), ((0, 0), (0, pad), (0, 0), (0, 0)))
         return x.reshape(N, nb, C, H, -1).transpose(0, 3, 1, 2, 4)
 
     # the batched half of the chunkwise form is a region of its own: XLA
-    # fusions, triangular solves and re-layouts beside the kernel's scan
+    # fusions, triangular solves and re-layouts beside the scan
     with jax.named_scope(SCOPE_KDA_CHUNK_OPERANDS):
         ut, w, qd, b, kend, gamma = kda_chunk_operands(
             blocks(q), blocks(k), blocks(v), blocks(g),
             blocks(beta[..., None])[..., 0],
         )
-    if use_pallas():
-        from dynamo_tpu.ops.pallas.kda import kda_chunk_scan
 
-        with jax.named_scope(SCOPE_KDA_CHUNK_OPERANDS):
-            kx = jnp.concatenate([
-                jnp.swapaxes(kend, -1, -2),
-                jnp.broadcast_to(gamma[..., None], (N, H, nb, dk, C)),
-            ], axis=-1)
-        o, pool = kda_chunk_scan(
-            ut, w, qd, b, kx, pool, rows, fresh, layer=layer,
-            interpret=jax.default_backend() != "tpu", scope=SCOPE_KDA_CHUNK,
-        )
-    else:
-        note_fallback("no_pallas_backend", expected=True,
-                      detail="kda_chunk_prefill: lax.scan over blocks")
+    def step(s, x):
+        ut_, w_, qd_, b_, kend_, gamma_ = x  # [N, H, ...] of one block
+        u = ut_ - jnp.einsum("nhck,nhkv->nhcv", w_, s, precision=_HI)
+        o_ = (jnp.einsum("nhck,nhkv->nhcv", qd_, s, precision=_HI)
+              + jnp.einsum("nhcs,nhsv->nhcv", b_, u, precision=_HI))
+        s = gamma_[..., None] * s + jnp.einsum(
+            "nhck,nhcv->nhkv", kend_, u, precision=_HI)
+        return s, o_
 
-        def step(s, x):
-            ut_, w_, qd_, b_, kend_, gamma_ = x  # [N, H, ...] of one block
-            u = ut_ - jnp.einsum("nhck,nhkv->nhcv", w_, s, precision=_HI)
-            o_ = (jnp.einsum("nhck,nhkv->nhcv", qd_, s, precision=_HI)
-                  + jnp.einsum("nhcs,nhsv->nhcv", b_, u, precision=_HI))
-            s = gamma_[..., None] * s + jnp.einsum(
-                "nhck,nhcv->nhkv", kend_, u, precision=_HI)
-            return s, o_
-
+    with jax.named_scope(SCOPE_KDA_CHUNK):
         s0 = jnp.where(fresh[:, None, None, None], 0.0, pool[layer, rows])
         s, o = jax.lax.scan(step, s0, tuple(
             jnp.moveaxis(x, 2, 0) for x in (ut, w, qd, b, kend, gamma)

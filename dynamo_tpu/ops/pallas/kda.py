@@ -21,15 +21,15 @@ Two programs, each chosen beside its XLA twin in ``ops/attention.py``:
   the quantities a value channel (v, the output) as rows. The slots' new
   convolution tails are written to their rows of the tails' pool in the
   same call, a head block's channels a program.
-- ``kda_chunk`` (prefill, packed prefill, chunks): the chunkwise form's
-  SEQUENTIAL part. What a block of 64 tokens needs that does not depend on
-  the state (the decayed keys and queries, the triangular systems: ``ops/
-  attention.kda_chunk_operands``) is plain batched XLA over all blocks at
-  once; this kernel carries the state through a sequence's blocks in VMEM:
-  ``U = U~ - W S; O = Q' S + B U; S = diag(gamma) S + K'^T U``, four matrix
-  products a head a block, the state read from its row of the pool once
-  before the first block (or zero at a sequence's start) and written back
-  once after the last. No scan a token anywhere.
+- ``kda_chunk`` (prefill, packed prefill, chunks): the chunkwise form
+  WHOLE, a grid step a (sequence, block of heads, block of 64 tokens). What
+  a block needs that does not depend on the state (the decayed keys and
+  queries, the causal products, the triangular system: the terms of ``ops/
+  attention.kda_chunk_operands``, its XLA twin) is formed in VMEM from the
+  layer's operands as they are; then the state is carried through the
+  block, read from its row of the pool once before a sequence's first
+  block (or zero at its start) and written back once after the last.
+  Nothing of a block's operands crosses HBM. No scan a token anywhere.
 
 Matrix products are float32 at ``HIGHEST``: ``U`` is a difference of
 values and the state's read-out of them, which cancels.
@@ -57,7 +57,7 @@ def head_block(heads: int, most: int) -> int:
 # heads a program of kda_step holds: 16 x 64 KiB of state in and out, two
 # buffers each, is 4 MiB of VMEM
 STEP_HEADS = 16
-# and of kda_chunk: 8 heads' operands of one block and their state
+# and of kda_chunk: 8 heads' q, k, v, g, o of one block and their state
 CHUNK_HEADS = 8
 
 
@@ -167,54 +167,156 @@ def kda_step(
     return o, pool, conv
 
 
-def _chunk_kernel(rows_ref, fresh_ref, ut_ref, w_ref, qd_ref, b_ref, kx_ref,
-                  s0_ref, o_ref, s_ref, *, hb: int, block: int):
+_LANES = 128
+
+
+def _dot(a, b):
+    return jnp.dot(a, b, preferred_element_type=jnp.float32, precision=_HI)
+
+
+def _dot_nt(a, b):  # a b^T
+    return jax.lax.dot_general(
+        a, b, (((1,), (1,)), ((), ())),
+        preferred_element_type=jnp.float32, precision=_HI)
+
+
+def _head_operands(q, k, v, g, beta, *, sub: int):
+    """The state-independent half of one head's block, in VMEM:
+    ``ops/attention.kda_chunk_operands`` term for term. q, k, g: [C, dk];
+    v: [C, dv]; beta: [C, 1]. Returns ``(ut [C, dv], w [C, dk], qd [C,
+    dk], b [C, C], kend [C, dk], G [C, dk])``."""
+    C, dk = q.shape
+    dv = v.shape[1]
+    row = jax.lax.broadcasted_iota(jnp.int32, (C, C), 0)
+    col = jax.lax.broadcasted_iota(jnp.int32, (C, C), 1)
+    tok = jax.lax.broadcasted_iota(jnp.int32, (C, 1), 0)
+    # the decay summed from the block's start: shifted sums down the
+    # tokens, doubling the shift (float32 additions, no product)
+    G, shift = g, 1
+    while shift < C:
+        G = G + jnp.where(tok >= shift, pltpu.roll(G, shift, 0), 0.0)
+        shift *= 2
+    eg = jnp.exp(G)
+    a_rows, b_rows = [], []
+    for r0 in range(0, C, sub):
+        # the sub-block's reference: the decay up to the token before it
+        ref = G[r0 - 1:r0] if r0 else jnp.zeros((1, dk), jnp.float32)
+        e_in = jnp.exp(G[r0:r0 + sub] - ref)  # <= 1
+        lhs = jnp.concatenate(
+            [k[r0:r0 + sub] * e_in, q[r0:r0 + sub] * e_in], axis=0)
+        # the keys up to the sub-block's end, brought to the reference:
+        # those of the sub-block itself back (FLA's e^80 bound), those
+        # before it forward (<= 1)
+        n = r0 + sub
+        cap = jnp.where(tok[:n] >= r0, 80.0, 0.0)
+        both = _dot_nt(lhs, k[:n] * jnp.exp(jnp.minimum(ref - G[:n], cap)))
+        if n < C:
+            both = jnp.concatenate(
+                [both, jnp.zeros((2 * sub, C - n), jnp.float32)], axis=1)
+        a_rows.append(both[:sub])
+        b_rows.append(both[sub:])
+    a = jnp.where(col < row, jnp.concatenate(a_rows, axis=0), 0.0)
+    b = jnp.where(col <= row, jnp.concatenate(b_rows, axis=0), 0.0)
+    # (I + beta a)^-1 [beta v | beta k e^G] by forward substitution in the
+    # recurrence's order: a sub-block's unit lower system a token at a
+    # time on the vector unit, the sub-blocks beneath it by a product
+    tm = beta * a
+    rhs = beta * jnp.concatenate([v, k * eg], axis=1)
+    done = []
+    for r0 in range(0, C, sub):
+        x = rhs[r0:r0 + sub]
+        if r0:
+            solved = jnp.concatenate(
+                done + [jnp.zeros((C - r0, dv + dk), jnp.float32)], axis=0)
+            x = x - _dot(tm[r0:r0 + sub], solved)
+        for s in range(sub - 1):
+            # row s is final: take it out of the rows beneath (tm is
+            # strictly lower, so the rows above get nothing)
+            x = x - tm[r0:r0 + sub, r0 + s:r0 + s + 1] * x[s:s + 1]
+        done.append(x)
+    sol = jnp.concatenate(done, axis=0)
+    return (sol[:, :dv], sol[:, dv:], q * eg, b,
+            k * jnp.exp(G[C - 1:C] - G), G)
+
+
+def _chunk_kernel(rows_ref, fresh_ref, q_ref, k_ref, v_ref, g_ref, beta_ref,
+                  s0_ref, o_ref, s_ref, *, sub: int):
     del rows_ref
+    hb, dk, dv = s_ref.shape
     keep = (fresh_ref[pl.program_id(0)] == 0).astype(jnp.float32)
 
     @pl.when(pl.program_id(2) == 0)
     def _():
         s_ref[...] = s0_ref[...] * keep
 
-    def dot(a, b):
-        return jnp.dot(a, b, preferred_element_type=jnp.float32,
-                       precision=_HI)
-
+    C = q_ref.shape[0]
+    betas = beta_ref[...]  # [C, H]: every head's
+    lane = jax.lax.broadcasted_iota(jnp.int32, betas.shape, 1)
+    first = pl.program_id(1) * hb
+    # the heads' chains are independent: unrolled, the scheduler overlaps
+    # one's substitution steps with another's products
     for j in range(hb):
+        at_k, at_v = slice(j * dk, (j + 1) * dk), slice(j * dv, (j + 1) * dv)
+        beta = jnp.sum(
+            jnp.where(lane == first + j, betas, 0.0), axis=1, keepdims=True)
+        ut, w, qd, b, kend, G = _head_operands(
+            q_ref[:, at_k], k_ref[:, at_k], v_ref[:, at_v], g_ref[:, at_k],
+            beta, sub=sub)
         s = s_ref[j]  # [dk, dv]
-        u = ut_ref[j] - dot(w_ref[j], s)  # [C, dv]
-        o_ref[j] = dot(qd_ref[j], s) + dot(b_ref[j], u)
-        kx = kx_ref[j]  # [dk, 2C]: K'^T, then gamma on every lane
-        s_ref[j] = kx[:, block:block + 1] * s + dot(kx[:, :block], u)
+        ws = _dot(jnp.concatenate([w, qd], axis=0), s)
+        u = ut - ws[:C]  # [C, dv]
+        o_ref[:, at_v] = ws[C:] + _dot(b, u)
+        # one transpose (of a whole lane tile of rows) gives K'^T and,
+        # from G's last row, the block's whole decay a key channel as a
+        # column
+        stack = [kend, G]
+        if 2 * C < _LANES:
+            stack.append(jnp.zeros((_LANES - 2 * C, dk), jnp.float32))
+        kg = jnp.concatenate(stack, axis=0).T
+        s_ref[j] = (jnp.exp(kg[:, 2 * C - 1:2 * C]) * s
+                    + _dot(kg[:, :C], u))
 
 
-def kda_chunk_scan(
-    ut: jax.Array,  # [N, H, nb, C, dv] float32: T^-1 (beta v)
-    w: jax.Array,  # [N, H, nb, C, dk]: T^-1 (beta k decayed from the start)
-    qd: jax.Array,  # [N, H, nb, C, dk]: q decayed from the block's start
-    b: jax.Array,  # [N, H, nb, C, C]: decayed q . k, causal
-    kx: jax.Array,  # [N, H, nb, dk, 2C]: (k decayed to the block's end)^T,
-    # then the block's whole decay gamma [dk] repeated C times
+def kda_chunk(
+    q: jax.Array,  # [N, T, H dk] float32: normalised and scaled
+    k: jax.Array,  # [N, T, H dk] float32: normalised
+    v: jax.Array,  # [N, T, H dv] float32
+    g: jax.Array,  # [N, T, H dk] float32: the log decay a channel, <= 0
+    beta: jax.Array,  # [N, T, H] float32
     pool: jax.Array,  # [L, rows + 1, H, dk, dv] float32 (aliased in place)
     rows: jax.Array,  # [N] int32: each sequence's row (the last = trash)
     fresh: jax.Array,  # [N] bool: start from a zero state
     *,
     layer: int,
+    block: int,
+    sub: int,
     interpret: bool = False,
     scope: str | None = None,
 ) -> tuple[jax.Array, jax.Array]:
-    """The state carried through each sequence's blocks, from and to its
-    row of the pool: read once before the first block (or zero), written
-    once after the last. Returns ``(o [N, H, nb, C, dv], pool)``. A state
-    that is not finite times zero would not be zero: a pool never holds
-    one (a row is written by this kernel and ``kda_step`` alone)."""
-    N, H, nb, C, dv = ut.shape
-    dk = w.shape[-1]
+    """The chunkwise form whole, from and to each sequence's row of the
+    pool: read once before the first block (or zero), written once after
+    the last. It takes the layer's operands as they are (a head is a lane
+    tile of a block) and forms a block's state-independent half in VMEM at
+    the grid step that consumes it, term for term as ``ops/attention.
+    kda_chunk_operands`` does: the decay summed down the block (float32
+    additions), keys and queries brought to their sub-block's reference
+    (every exponent non-positive but inside a sub-block of ``sub``), the
+    causal products A and B, ``T^-1 [beta v | beta k e^G]`` by forward
+    substitution in the recurrence's order. Then ``U = U~ - W S; O = Q' S
+    + B U; S = diag(gamma) S + K'^T U``. No solver's custom call, no
+    re-layout: 8 heads' operands of a block are 256 KiB each in VMEM (two
+    buffers), their state 512 KiB in and out. T is a multiple of
+    ``block``, ``block`` of ``sub``. Returns ``(o [N, T, H dv] float32,
+    pool)``. A state that is not finite times zero would not be zero: a
+    pool never holds one (a row is written by this kernel and ``kda_step``
+    alone)."""
+    _, _, H, dk, dv = pool.shape
+    N, T, _ = q.shape
     hb = head_block(H, CHUNK_HEADS)
 
-    def blocked(last2):
+    def blocked(width):
         return pl.BlockSpec(
-            (None, hb, None) + last2, lambda n, h, i, *_: (n, h, i, 0, 0))
+            (None, block, width), lambda n, h, i, *_: (n, i, h))
 
     state_spec = pl.BlockSpec(
         (None, None, hb, dk, dv),
@@ -222,20 +324,22 @@ def kda_chunk_scan(
     )
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,
-        grid=(N, H // hb, nb),
+        grid=(N, H // hb, T // block),
         in_specs=[
-            blocked((C, dv)), blocked((C, dk)), blocked((C, dk)),
-            blocked((C, C)), blocked((dk, 2 * C)), state_spec,
+            blocked(hb * dk), blocked(hb * dk), blocked(hb * dv),
+            blocked(hb * dk),
+            pl.BlockSpec((None, block, H), lambda n, h, i, *_: (n, i, 0)),
+            state_spec,
         ],
-        out_specs=[blocked((C, dv)), state_spec],
+        out_specs=[blocked(hb * dv), state_spec],
     )
     # operands count the two scalar-prefetch arguments: 7 = the pool
     with jax.named_scope(scope) if scope else contextlib.nullcontext():
         o, pool = pl.pallas_call(
-            functools.partial(_chunk_kernel, hb=hb, block=C),
+            functools.partial(_chunk_kernel, sub=sub),
             grid_spec=grid_spec,
             out_shape=[
-                jax.ShapeDtypeStruct((N, H, nb, C, dv), jnp.float32),
+                jax.ShapeDtypeStruct((N, T, H * dv), jnp.float32),
                 jax.ShapeDtypeStruct(pool.shape, pool.dtype),
             ],
             input_output_aliases={7: 1},
@@ -243,6 +347,6 @@ def kda_chunk_scan(
                 dimension_semantics=("arbitrary", "arbitrary", "arbitrary"),
             ),
             interpret=interpret,
-        )(rows.astype(jnp.int32), fresh.astype(jnp.int32), ut, w, qd, b, kx,
+        )(rows.astype(jnp.int32), fresh.astype(jnp.int32), q, k, v, g, beta,
           pool)
     return o, pool

@@ -259,25 +259,35 @@ def test_kda_step_compiles_for_v5e(v5e):
 @pytest.mark.parametrize("rows,blocks", [(2, 16), (1, 16)],
                          ids=["pack-of-2", "single"])
 def test_kda_chunk_compiles_for_v5e(v5e, rows, blocks):
-    """The chunkwise form's sequential part at the cell's prefill shapes:
-    1,024 tokens a row in 16 blocks of 64, 64 heads, float32 products, the
-    state from and to the pool's rows in place."""
-    from dynamo_tpu.ops.pallas.kda import kda_chunk_scan
+    """The chunkwise form whole at the cell's prefill shapes: 1,024 tokens
+    a row in 16 blocks of 64, 64 heads, the layer's operands as they are
+    (``[N, T, H d]``), a block's half that does not depend on the state
+    formed in the kernel (so no triangular solve beside it), float32
+    products, the state from and to the pool's rows in place."""
+    from dynamo_tpu.ops.pallas.kda import kda_chunk
 
     heads, c, d = 64, 64, 128
     f32 = jnp.float32
-    lead = (rows, heads, blocks)
+    lead = (rows, blocks * c)
     compiled = jax.jit(
-        lambda *a: kda_chunk_scan(*a, layer=2, scope="kda_chunk"),
+        lambda *a: kda_chunk(
+            *a, layer=2, block=c, sub=16, scope="kda_chunk"),
         donate_argnums=(5,),
     ).lower(
-        _rows(v5e, *lead, c, d, dtype=f32), _rows(v5e, *lead, c, d, dtype=f32),
-        _rows(v5e, *lead, c, d, dtype=f32), _rows(v5e, *lead, c, c, dtype=f32),
-        _rows(v5e, *lead, d, 2 * c, dtype=f32),
+        _rows(v5e, *lead, heads * d, dtype=f32),
+        _rows(v5e, *lead, heads * d, dtype=f32),
+        _rows(v5e, *lead, heads * d, dtype=f32),
+        _rows(v5e, *lead, heads * d, dtype=f32),
+        _rows(v5e, *lead, heads, dtype=f32),
         _rows(v5e, 3, 129, heads, d, d, dtype=f32),
         _rows(v5e, rows, dtype=jnp.int32), _rows(v5e, rows, dtype=jnp.bool_),
     ).compile()
-    assert "%kda_chunk" in compiled.as_text()
+    text = compiled.as_text()
+    assert "%kda_chunk" in text
+    # the one custom call is the kernel: no solver beside it
+    assert "triangular" not in text.lower()
+    assert text.count("custom_call_target=") == 1
+    assert 'custom_call_target="tpu_custom_call"' in text
 
 
 def test_ssd_step_compiles_for_v5e(v5e):

@@ -255,9 +255,43 @@ def test_every_instruction_resolves_and_the_unnamed_are_bookkeeping(
     if family == "latent" and program == "decode":
         want |= {"latent_absorb", "attn_latent"}
     if family == "kda":
-        want |= ({"kda_chunk_operands", "kda_chunk"}
-                 if program == "prefill" else {"kda_step"})
+        # the kernel forms a block's operands itself: ``kda_chunk`` alone
+        # (``kda_chunk_operands`` is the pad of a ragged row, and XLA's
+        # half off the chip: the test below)
+        want |= {"kda_chunk"} if program == "prefill" else {"kda_step"}
     assert want <= found, want - found
+
+
+@pytest.mark.parametrize("route", ("kernel", "xla"))
+def test_the_kda_chunk_form_names_what_each_route_runs(monkeypatch, route):
+    """``kda_chunk`` on both routes; ``kda_chunk_operands`` is the pad of a
+    ragged row where the kernel forms a block's operands itself (no
+    triangular solve in the program) and XLA's batched half where it does
+    not."""
+    import jax.numpy as jnp
+
+    from dynamo_tpu.ops.attention import kda_chunk_prefill
+
+    monkeypatch.setenv("DYNAMO_PALLAS", "1" if route == "kernel" else "0")
+    N, T_, H, D = 1, 70, 2, 16
+    x = jnp.ones((N, T_, H, D), jnp.float32)
+    text = jax.jit(
+        lambda q, b, pool: kda_chunk_prefill(
+            q, q, q, -q, b, pool, jnp.zeros((N,), jnp.int32),
+            jnp.ones((N,), bool), layer=0)
+    ).lower(x, x[..., 0], jnp.zeros((1, 2, H, D, D), jnp.float32)
+            ).compile().as_text()
+    names = set(re.findall(r'op_name="([^"]+)"', text))
+    found = {regions.resolve(n)[0] for n in names} - {None}
+    assert {"kda_chunk", "kda_chunk_operands"} <= found, found
+    solves = [n for n in names if "triangular_solve" in n]
+    if route == "kernel":
+        assert not solves
+        assert {n.split("/")[-1] for n in names
+                if "kda_chunk_operands" in n.split("/")} <= {
+            "pad", "reshape", "convert_element_type"}
+    else:
+        assert solves and all("kda_chunk_operands" in n for n in solves)
 
 
 def test_moe_experts_sums_back_by_a_gather_and_scatters_integers_only():

@@ -218,44 +218,111 @@ def _kda_case(T_, beta_scale=2.0, seed=3, N=2, H=4, D=16):
     return q, k, v, g, beta, s0
 
 
-def _chunk(q, k, v, g, beta, s0):
-    """``kda_chunk_prefill`` from and to rows 1.. of a pool whose row 0 is
-    fresh (it must come back as from a zero state) and last row trash."""
+# the chunkwise form's cases: tokens a row, and what is odd about the pack
+KDA_CASES = {
+    "one-block": 64, "ragged": 150, "sub-block": 16, "long": 1024,
+    "fresh-beside-resumed": 150, "trash-member": 150, "padded": 150,
+    "parallel-keys": 128, "edge-decay": 128,
+}
+# what the chunkwise form itself loses against the recurrence where it is
+# worst conditioned (the XLA twin reads the same): the inverse decay at the
+# edge of its bound, T's inverse alternating +-2
+KDA_LOOSE = {"edge-decay": 1e-4, "parallel-keys": 5e-5}
+
+
+def _kda_named(name):
+    """A pack of 2 for ``kda_chunk_prefill``: (q, k, v, g, beta, s0, fresh
+    [2], trash [2], real [2]). ``fresh-beside-resumed``: member 0 starts
+    from zero whatever its row holds; ``trash-member``: member 1 owns no
+    row; ``padded``: tokens past ``real`` carry g = 0 and beta = 0;
+    ``parallel-keys``: beta = 2 on keys within 2% of one direction (T's
+    inverse alternates +-2: a doubling product would lose it);
+    ``edge-decay``: -4.9 a token, the edge of the sub-block's e^-80."""
+    T_ = KDA_CASES[name]
+    q, k, v, g, beta, s0 = _kda_case(T_, seed=3 + len(name))
+    fresh, trash, real = np.zeros(2, bool), np.zeros(2, bool), [T_, T_]
+    if name == "fresh-beside-resumed":
+        fresh[0] = True
+    elif name == "trash-member":
+        trash[1] = True
+    elif name == "padded":
+        real = [100, 37]
+        live = jnp.arange(T_)[None, :] < jnp.asarray(real)[:, None]
+        g = jnp.where(live[..., None, None], g, 0.0)
+        beta = jnp.where(live[..., None], beta, 0.0)
+    elif name == "parallel-keys":
+        k = k[:, :1] + 0.02 * k
+        k = k / jnp.linalg.norm(k, axis=-1, keepdims=True)
+        beta = jnp.full_like(beta, 2.0)
+    elif name == "edge-decay":
+        g = jnp.full_like(g, -4.9)
+    return q, k, v, g, beta, s0, fresh, trash, real
+
+
+def _chunk(q, k, v, g, beta, s0, fresh=None, trash=None):
+    """``kda_chunk_prefill`` from and to rows 1.. of a pool whose row 0
+    must come back as it was and whose last row is trash (a member of
+    ``trash`` is sent there). Returns (o, the members' rows, the pool)."""
     N = q.shape[0]
     pool = jnp.concatenate([s0[:1] * 0 + 7.0, s0, s0[:1]])[None]
-    o, pool = attn_ops.kda_chunk_prefill(
-        q, k, v, g, beta, pool, jnp.arange(1, N + 1),
-        jnp.zeros((N,), bool), layer=0)
-    return o, pool[0, 1: N + 1]
+    rows = np.arange(1, N + 1)
+    if trash is not None:
+        rows = np.where(trash, N + 1, rows)
+    o, new = attn_ops.kda_chunk_prefill(
+        q, k, v, g, beta, pool, jnp.asarray(rows, jnp.int32),
+        jnp.asarray(np.zeros(N, bool) if fresh is None else fresh), layer=0)
+    np.testing.assert_array_equal(np.asarray(new[0, 0]), np.asarray(pool[0, 0]))
+    return o, new[0, 1: N + 1], new
 
 
 @pytest.mark.parametrize("pallas", ["0", "1"], ids=["xla", "kernel"])
-@pytest.mark.parametrize("T_", [64, 150], ids=["one-block", "ragged"])
-def test_kda_chunk_equals_the_token_recurrence(monkeypatch, pallas, T_):
-    """The chunkwise form over blocks of 64 tokens, from a non-zero state,
-    with beta over 1 in the draw (negative eigenvalues) and decays down
-    to e^-3.3 a token, is the recurrence a token at a time: outputs and
-    the state it leaves."""
+@pytest.mark.parametrize("case", list(KDA_CASES))
+def test_kda_chunk_equals_the_token_recurrence(monkeypatch, pallas, case):
+    """The chunkwise form over blocks of 64 tokens (one of 16 for a short
+    row), from a non-zero state, with beta over 1 in the draw (negative
+    eigenvalues) and decays down to e^-3.3 a token, is the recurrence a
+    token at a time over each member's real tokens: outputs and the state
+    it leaves. A member on the trash row leaves its own row as it was."""
     monkeypatch.setenv("DYNAMO_PALLAS", pallas)
-    q, k, v, g, beta, s0 = _kda_case(T_)
+    q, k, v, g, beta, s0, fresh, trash, real = _kda_named(case)
     assert float(beta.max()) > 1.5
-    o, s = _chunk(q, k, v, g, beta, s0)
+    o, s, _ = _chunk(q, k, v, g, beta, s0, fresh, trash)
     for n in range(q.shape[0]):
+        r = real[n]
         want_o, want_s = attn_ops.kda_recurrence(
-            q[n], k[n], v[n], g[n], beta[n], s0[n])
-        _close(o[n], np.asarray(want_o), tol=2e-5)
-        _close(s[n], np.asarray(want_s), tol=2e-5)
+            q[n, :r], k[n, :r], v[n, :r], g[n, :r], beta[n, :r],
+            s0[n] * (0.0 if fresh[n] or trash[n] else 1.0))
+        if trash[n]:
+            np.testing.assert_array_equal(np.asarray(s[n]), np.asarray(s0[n]))
+            continue
+        _close(o[n, :r], np.asarray(want_o), tol=KDA_LOOSE.get(case, 2e-5))
+        _close(s[n], np.asarray(want_s), tol=KDA_LOOSE.get(case, 2e-5))
 
 
-def test_kda_kernels_equal_their_xla_twins(monkeypatch):
+@pytest.mark.parametrize("case", ["both-kernels"] + list(KDA_CASES))
+def test_kda_kernels_equal_their_xla_twins(monkeypatch, case):
     """Both kernels, interpreted, against the XLA forms that serve off the
-    chip: the chunk scan, and the decode step over a pool with a trash row
-    (two slots on it), whose other rows stay as they were."""
+    chip: the chunk form whole (its operands formed in the kernel against
+    ``kda_chunk_operands`` and the scan), and (``both-kernels``) the decode
+    step over a pool with a trash row (two slots on it), whose other rows
+    stay as they were."""
+    if case != "both-kernels":
+        q, k, v, g, beta, s0, fresh, trash, real = _kda_named(case)
+        outs = {}
+        for pallas in ("0", "1"):
+            monkeypatch.setenv("DYNAMO_PALLAS", pallas)
+            outs[pallas] = _chunk(q, k, v, g, beta, s0, fresh, trash)
+        tol = KDA_LOOSE.get(case, 1e-5)
+        for n, r in enumerate(real):
+            _close(outs["1"][0][n, :r], np.asarray(outs["0"][0][n, :r]), tol=tol)
+        # every row but the trash row
+        _close(outs["1"][2][:, :-1], np.asarray(outs["0"][2][:, :-1]), tol=tol)
+        return
     q, k, v, g, beta, s0 = _kda_case(128, seed=5)
     outs = {}
     for pallas in ("0", "1"):
         monkeypatch.setenv("DYNAMO_PALLAS", pallas)
-        outs[pallas] = _chunk(q, k, v, g, beta, s0)
+        outs[pallas] = _chunk(q, k, v, g, beta, s0)[:2]
         # a fresh row starts from zero whatever the pool held
         fresh = attn_ops.kda_chunk_prefill(
             q[:1], k[:1], v[:1], g[:1], beta[:1], s0[None, :2],
