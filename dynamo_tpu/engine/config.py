@@ -28,18 +28,39 @@ class LayerKind:
     # unread for it); or "ssd" (Mamba-2, arXiv:2405.21060): a scalar-decay
     # state-space mixer over a state row (``ModelSpec.ssm_*``) IN PARALLEL
     # with softmax attention over pages off the same norm (Falcon-H1), so
-    # the kind keeps both: what a kind keeps is ``paged`` and ``recurrent``
+    # the kind keeps both: what a kind keeps is ``paged`` and ``recurrent``;
+    # or "latent" (MLA, models/mla.py): softmax attention over ONE pool of
+    # latent rows a layer, ``kv_lora_rank + qk_rope_head_dim`` wide and
+    # shared by the heads (``ModelSpec``'s MLA sizes and rope base;
+    # ``num_kv_heads`` and ``window`` are unread for it), and no V pool
     mixer: str = "softmax"
+    # a KDA kind's forms. ``gate_bound`` < 0: the decay a channel is
+    # bounded, ``gate_bound * sigmoid(exp(a_log) * (f + dt_bias))`` in
+    # (gate_bound, 0); 0: ``-exp(a_log) * softplus(f + dt_bias)``.
+    # ``full_rank``: the decay's and the output gate's projections are one
+    # matrix each ``d -> H D``, not a pair through rank ``kda_head_dim``
+    gate_bound: float = 0.0
+    full_rank: bool = False
+    # the attention output is gated by HEAD before the output projection:
+    # ``wo (a_h * sigmoid(x @ w_gate_head)_h)`` (``ModelSpec.attn_gate``
+    # is the gate by element)
+    head_gate: bool = False
 
     @property
     def recurrent(self) -> bool:
         """The kind keeps a state row a sequence."""
-        return self.mixer != "softmax"
+        return self.mixer in ("kda", "ssd")
+
+    @property
+    def latent(self) -> bool:
+        """The kind's pages are latent rows: one pool, no V side."""
+        return self.mixer == "latent"
 
     @property
     def paged(self) -> bool:
-        """The kind keeps pages: it has softmax attention (KV heads)."""
-        return self.num_kv_heads > 0
+        """The kind keeps pages: it has softmax attention (KV heads, or
+        the one latent row the heads share)."""
+        return self.num_kv_heads > 0 or self.latent
 
 
 @dataclass(frozen=True)
@@ -85,6 +106,11 @@ class ModelSpec:
     moe_bias: bool = False  # router + expert (gate_up/down) biases
     swiglu_limit: float = 0.0  # clamped swiglu bound (gpt-oss 7.0); 0 = off
     swiglu_alpha: float = 0.0  # swish slope inside clamp (gpt-oss 1.702)
+    # a plain-SiLU clamp A LAYER (0 = none; empty = no layer has one):
+    # ``silu(min(gate, L)) * clip(up, -L, L)``, no alpha and no + 1, the
+    # routed experts' and the shared expert's apart
+    expert_clamp: tuple[float, ...] = ()
+    shared_clamp: tuple[float, ...] = ()
     # YaRN rope scaling (gpt-oss, DeepSeek-R1; HF _compute_yarn_parameters)
     rope_scaling_factor: float = 0.0  # 0 = no scaling
     rope_orig_max_pos: int = 0
@@ -176,6 +202,8 @@ class ModelSpec:
         fix("layer_types", tuple(self.layer_types))
         fix("layer_pattern", tuple(self.layer_pattern))
         fix("held_experts", tuple(self.held_experts))
+        fix("expert_clamp", tuple(float(c) for c in self.expert_clamp))
+        fix("shared_clamp", tuple(float(c) for c in self.shared_clamp))
         fix("ssm_multipliers", tuple(float(m) for m in self.ssm_multipliers))
         fix("mlp_multipliers", tuple(float(m) for m in self.mlp_multipliers))
         fix("layer_kinds", tuple(
@@ -225,6 +253,17 @@ class ModelSpec:
     def has_recurrent(self) -> bool:
         """Some layer keeps a recurrent state a sequence beside the pages."""
         return any(k.recurrent for k in self.layer_kinds)
+
+    @property
+    def has_latent(self) -> bool:
+        """Some kind of layer keeps latent pages beside the other kinds."""
+        return any(k.latent for k in self.layer_kinds)
+
+    def clamps(self, li: int) -> tuple[float, float]:
+        """Layer ``li``'s plain-SiLU clamps (routed experts, shared
+        expert); 0 = none."""
+        return (self.expert_clamp[li] if self.expert_clamp else 0.0,
+                self.shared_clamp[li] if self.shared_clamp else 0.0)
 
     @property
     def mixers(self) -> frozenset[str]:
@@ -432,6 +471,35 @@ class ModelSpec:
         base.update(kw)
         return cls(**base)
 
+    @classmethod
+    def tiny_ling3(cls, **kw) -> "ModelSpec":
+        """Toy Ling-3.0 architecture: KDA layers (full-rank bounded decay)
+        to one latent (MLA) layer gated by head, a leading dense layer,
+        group-limited sigmoid routing beside a shared expert, a clamp a
+        layer."""
+        base = dict(
+            name="tiny-ling3", vocab_size=96, hidden_size=64,
+            intermediate_size=96, num_layers=3, num_heads=4,
+            num_kv_heads=4, head_dim=16, dtype="float32", rms_eps=1e-6,
+            rope_theta=6e6, tie_embeddings=False,
+            layer_kinds=(
+                LayerKind(0, 6e6, mixer="latent", head_gate=True),
+                LayerKind(0, 0.0, mixer="kda", gate_bound=-5.0,
+                          full_rank=True),
+            ),
+            layer_pattern=(1, 1, 0),
+            kv_lora_rank=32, qk_nope_head_dim=16, qk_rope_head_dim=8,
+            v_head_dim=16, rotary_dim=8,
+            kda_heads=4, kda_head_dim=16,
+            num_experts=16, num_experts_per_token=4,
+            moe_intermediate_size=32, moe_scoring="sigmoid",
+            n_group=4, topk_group=2, routed_scaling_factor=2.5,
+            n_shared_experts=1, first_k_dense=1,
+            expert_clamp=(0.0, 0.5, 0.75), shared_clamp=(0.0, 0.6, 0.4),
+        )
+        base.update(kw)
+        return cls(**base)
+
     @property
     def ssm_conv_dim(self) -> int:
         """Channels the SSD convolution runs over: x | B | C."""
@@ -440,7 +508,10 @@ class ModelSpec:
 
     @property
     def is_mla(self) -> bool:
-        return self.kv_lora_rank > 0
+        """EVERY layer is latent attention (models/mla.py's own programs).
+        A model that lists its kinds keeps latent layers as a kind
+        (``LayerKind.latent``) beside the others in models/llama.py's."""
+        return self.kv_lora_rank > 0 and not self.layer_kinds
 
     @classmethod
     def preset(cls, name: str) -> "ModelSpec":
@@ -451,6 +522,7 @@ class ModelSpec:
             "tiny-gpt-oss": cls.tiny_gpt_oss,
             "tiny-solar": cls.tiny_solar,
             "tiny-falcon-h1": cls.tiny_falcon_h1,
+            "tiny-ling3": cls.tiny_ling3,
             "llama-3-8b": cls.llama3_8b,
             "llama-3-70b": cls.llama3_70b,
             "mixtral-8x7b": cls.mixtral_8x7b,
@@ -656,8 +728,9 @@ class EngineConfig:
         with recurrent layers (``need_recurrent``): its softmax layers the
         walk's true tiles, its KDA layers the chunkwise form's float32
         operands, its SSD layers the chunk form's (decay matrices a head
-        a chunk, the carried states). The state rows themselves are part of the pools, so
-        ``free_bytes`` already lacks them."""
+        a chunk, the carried states), and where latent layers are a kind
+        beside them, ``need_latent`` on top. The state rows themselves are
+        part of the pools, so ``free_bytes`` already lacks them."""
         top = self.bucket_for(min(
             self.max_context, self.max_prefill_chunk_tokens,
             self.prefill_buckets[-1],
@@ -667,8 +740,14 @@ class EngineConfig:
         def need(rows: int, bucket: int) -> int:
             if spec.is_mla:
                 return need_latent(rows, bucket)
-            if spec.has_recurrent:
-                return need_recurrent(rows, bucket)
+            if spec.has_recurrent or spec.has_latent:
+                # a model with both kinds is charged both: the layers of
+                # one program run in turn, so the sum is an upper bound
+                # (the rest of the program counted once)
+                need = need_recurrent(rows, bucket)
+                if spec.has_latent:
+                    need += need_latent(rows, bucket) - 96 * 1024 * rows * bucket
+                return need
             scores = 4 * rows * heads * bucket * self.max_context
             return scores * 3 // 2 + 96 * 1024 * rows * bucket
 

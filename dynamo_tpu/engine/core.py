@@ -539,9 +539,10 @@ class InferenceEngine:
         # what the KDA kernels were asked to do, a layer's worth (always
         # on, a model with recurrent layers only): state rows a kda_step
         # call updated, over the dispatched bursts' steps; blocks of
-        # tokens kda_chunk carried a state through, over the prefills
+        # tokens kda_chunk carried a state through and rows it resumed
+        # (start_pos > 0), over the prefills
         self.kda = (
-            {"decode_rows": 0, "prefill_blocks": 0}
+            {"decode_rows": 0, "prefill_blocks": 0, "rows_resumed": 0}
             if "kda" in spec.mixers else {}
         )
         # and the SSD mixer's, a layer's worth (a model with SSD layers
@@ -705,8 +706,9 @@ class InferenceEngine:
         """Pages a chunk of the decode kernel holds on this cache's
         full-attention layers, from the pools' own shapes
         (``ops/pallas/fused_decode.chunk_pages``; the latent family's
-        kernel, ``latent_decode.latent_chunk_pages``); None where the
-        cache is not one that a kernel reads."""
+        kernel, ``latent_decode.latent_chunk_pages``, which also reads a
+        latent KIND's pool); None where the cache is not one that a kernel
+        reads."""
         from dynamo_tpu.ops.pallas.fused_decode import pool_chunk_pages
         from dynamo_tpu.ops.pallas.latent_decode import latent_chunk_pages
         from dynamo_tpu.ops.quant import is_quant
@@ -726,6 +728,8 @@ class InferenceEngine:
             if full is None:
                 return None
             k = kind_pages(self.spec, k, full)
+            if self.spec.layer_kinds[full].latent:
+                return latent_chunk_pages(k, self.config.max_pages_per_seq)
             v = kind_pages(self.spec, v, full)
         if len(k.shape) != 5:
             return None
@@ -772,14 +776,19 @@ class InferenceEngine:
         """The kinds of attention layer whose prefill is a walk over
         pages, each with its window: ``full`` (0) and ``window``, as a
         GQA model has them (``ops/attention.paged_prefill_attention``);
-        ``latent`` for the latent family's
-        (``latent_prefill_attention``)."""
+        ``latent`` for the latent family's and a latent kind's
+        (``latent_prefill_attention``). A kind that keeps no pages walks
+        none."""
         if self.spec.is_mla:
             return {"latent": 0}
-        windows = {
-            self.spec.kind(li).window for li in range(self.spec.num_layers)
+        kinds = [kd for kd in self.spec.kinds if kd.paged]
+        walks = {
+            "window" if w else "full": w
+            for w in sorted({kd.window for kd in kinds if not kd.latent})
         }
-        return {"window" if w else "full": w for w in sorted(windows)}
+        if any(kd.latent for kd in kinds):
+            walks["latent"] = 0
+        return walks
 
     def _count_prefill_kv(self, rows: int, pages: int, starts, nts) -> None:
         """Add a dispatched walk over pages (a prefill, a pack of them or
@@ -807,6 +816,7 @@ class InferenceEngine:
             from dynamo_tpu.ops.attention import kda_prefill_blocks
 
             self.kda["prefill_blocks"] += kda_prefill_blocks(nts)
+            self.kda["rows_resumed"] += int(((starts > 0) & (nts > 0)).sum())
         if self.ssd:
             from dynamo_tpu.ops.attention import ssd_prefill_chunks
 
@@ -816,7 +826,7 @@ class InferenceEngine:
         kv = self.prefill_kv
         for kind, window in self._prefill_walks.items():
             kernel = kind == "latent" and latent_kernel_serves(
-                self.k_pages, self.mesh)
+                self._latent_pool(), self.mesh)
             if kind == "latent":
                 tq, bp = latent_prefill_tiling(rows, pages, page, kernel)
                 kv["dispatches.latent"] += 1
@@ -831,6 +841,15 @@ class InferenceEngine:
             kv[f"blocks_table.{kind}"] += (
                 len(starts) * tiles.size * -(-pages // bp)
             )
+
+    def _latent_pool(self):
+        """The pool of latent rows: the latent family's cache, or a latent
+        kind's pool among the kinds'."""
+        if self.spec.is_mla:
+            return self.k_pages
+        from dynamo_tpu.models.llama import latent_pool
+
+        return latent_pool(self.spec, self.k_pages)
 
     # -- precompile (startup warmup) ---------------------------------------
 
@@ -1221,7 +1240,10 @@ class InferenceEngine:
         """The counters as of the last refresh, summed over the expert
         layers: by phase (``prefill``, ``decode``) the assignments of
         real tokens to each held expert (``<phase>.expert.<i>``), their
-        assignments in all (``<phase>.assignments``), the held experts
+        assignments in all (``<phase>.assignments``) and those that
+        landed on an expert held here (``<phase>.assignments_held``: under
+        group-limited routing, the tokens routed to this chip's group),
+        the held experts
         touched, a layer a step (``<phase>.experts_touched``) and the
         phase's steps (``<phase>.steps``: prefill programs, decode model
         steps). Empty where the cache keeps none."""
@@ -1233,6 +1255,7 @@ class InferenceEngine:
             out[f"{name}.steps"] = int(c[:, phase, -1].max())
             out[f"{name}.experts_touched"] = int(c[:, phase, -2].sum())
             out[f"{name}.assignments"] = int(c[:, phase, -3].sum())
+            out[f"{name}.assignments_held"] = int(c[:, phase, :-3].sum())
             for i, n in enumerate(c[:, phase, :-3].sum(axis=0)):
                 out[f"{name}.expert.{i}"] = int(n)
         return out

@@ -5,19 +5,23 @@ small adapter surface — params/cache init, prefill, fused decode, page
 extract/insert — and the adapter maps it onto the family's functional
 core. Two families today:
 
-- ``GqaFamily``: llama/mistral/mixtral/qwen/gpt-oss/MiMo/Solar-Open2
-  (models/llama.py) — paged K and V pools, GQA attention, and among its
-  layer kinds KDA layers over a recurrent state a sequence
-  (``llama.KindPools``: the state pool and its directory ride the same
-  pair), the full feature matrix (packed
+- ``GqaFamily``: llama/mistral/mixtral/qwen/gpt-oss/MiMo/Solar-Open2/
+  Falcon-H1/Ling-3.0 (models/llama.py) — paged K and V pools, GQA
+  attention, and among its layer kinds KDA and SSD layers over a
+  recurrent state a sequence (``llama.KindPools``: the state pool and its
+  directory ride the same pair) and latent (MLA) layers over one pool of
+  latent rows a layer (``LayerKind.latent``: models/mla.py's layer under
+  the kinds' block table), the full feature matrix (packed
   prefill, ring prefill, meshes, logprobs, embeddings). ``k_pages`` and
   ``v_pages`` are each ONE pytree: an array ``[L, pages, kv_heads, page,
   D]``, a ``QuantPool`` of such (fp8), or, for a model whose layer kinds
   differ in KV heads, ``llama.KindPools``: a pool a kind over one
   page-id space, K ``head_dim`` wide and V ``v_dim``. Every leaf leads
   with a layer axis.
-- ``MlaFamily``: latent attention, DeepSeek-V2/V3/R1 and JoyAI-LLM-Flash
-  (models/mla.py): ONE latent cache, which rides the ``k_pages`` slot;
+- ``MlaFamily``: a model whose EVERY layer is latent attention,
+  DeepSeek-V2/V3/R1 and JoyAI-LLM-Flash (models/mla.py's own loops; to
+  fold into a one-kind model, ROADMAP.md D15): ONE latent cache, which
+  rides the ``k_pages`` slot;
   the ``v_pages`` slot carries the expert layers' counters (it was an
   inert ``[1]`` placeholder), so page bookkeeping, KVBM tier blocks and
   transfer metadata flow unchanged. Supports meshes (tp over heads, ep
@@ -77,6 +81,16 @@ class GqaFamily:
             self.supports_mesh = False  # the state has no sharding yet
             self.supports_multimodal = False
             self.supports_prefix_reuse = False
+            self.supports_page_transfer = False
+        if spec is not None and spec.has_latent:
+            # a latent kind beside the others: one pool of latent rows in
+            # the kinds' page-id space (llama.KindPools), its layer from
+            # models/mla.py. No V side, so the page movers, the verify's
+            # token-granular write, the ring and a mesh have no form yet
+            self.supports_ring_prefill = False
+            self.supports_spec_decode = False
+            self.supports_mesh = False
+            self.supports_multimodal = False
             self.supports_page_transfer = False
 
     def init_params(self, spec, key):

@@ -16,6 +16,7 @@ there, so static-shape prefill never corrupts live pages.
 from __future__ import annotations
 
 import contextlib
+import math
 from functools import partial
 from typing import Any, NamedTuple
 
@@ -78,8 +79,14 @@ Params = dict[str, Any]
 @partial(jax.jit, static_argnames=("shape", "dtype"))
 def _draw(key, scale, *, shape, dtype):
     """N(0, scale^2) of ``shape`` rounded to ``dtype``: one program a
-    shape (drawn eagerly, op by op, every shape compiled three)."""
-    return (jax.random.normal(key, shape, jnp.float32) * scale).astype(dtype)
+    shape (drawn eagerly, op by op, every shape compiled three). A stack
+    of matrices is drawn as one matrix of their rows and reshaped: the
+    same bits (the generator counts elements, not axes), and the chip's
+    compiler takes 3 s over ``[64 x 2560, 768]`` where it takes 9 over
+    ``[64, 2560, 768]`` (PERF.md section 6, PR 41)."""
+    flat = shape if len(shape) < 3 else (math.prod(shape[:-1]), shape[-1])
+    draw = jax.random.normal(key, flat, jnp.float32).reshape(shape)
+    return (draw * scale).astype(dtype)
 
 
 def init_params(spec: ModelSpec, key: jax.Array) -> Params:
@@ -114,8 +121,22 @@ def init_params(spec: ModelSpec, key: jax.Array) -> Params:
         # what a model's newer layers add is drawn on keys of their own
         # (the matrices' keys stay where they were for every older model)
         extra = jax.random.split(jax.random.fold_in(key, 2000 + li), 9)
-        if not kd.paged:
-            layer = _init_kda_layer(spec, dense, keys, extra)
+        if kd.latent:
+            from dynamo_tpu.models import mla
+
+            # the layer's four keys as its siblings use theirs: queries,
+            # the latent's down- and up-projection, the output
+            k_q, k_kva, k_kvb, k_o = (next(keys) for _ in range(4))
+            layer = {
+                "attn_norm": jnp.ones((d,), dtype),
+                "mlp_norm": jnp.ones((d,), dtype),
+                **mla.init_latent_mixer(
+                    spec, dense, k_q, k_q, k_kva, k_kvb, k_o,
+                    k_gate=extra[0] if kd.head_gate else None,
+                ),
+            }
+        elif not kd.paged:
+            layer = _init_kda_layer(spec, kd, dense, keys, extra)
         else:
             layer = {
                 "attn_norm": jnp.ones((d,), dtype),
@@ -212,21 +233,41 @@ def _init_ssd_mixer(spec: ModelSpec, dense, extra) -> Params:
     }
 
 
-def _init_kda_layer(spec: ModelSpec, dense, keys, extra) -> Params:
+def _init_kda_layer(spec: ModelSpec, kd, dense, keys, extra) -> Params:
     """A KDA layer's mixer weights (its MLP is drawn by the caller): the
     four big matrices on the layer's own keys like an attention layer's,
     the rest on ``extra``. ``a_log`` and ``dt_bias`` are drawn so that the
     decay a token ``alpha = exp(-exp(a_log) softplus(dt_bias))`` spans
     (0.9, 0.9999) log-uniformly over the channels before the input's own
-    term ``(x w_f_down) w_f_up`` moves it."""
+    term ``(x w_f_down) w_f_up`` moves it. A kind with ``full_rank`` draws
+    ``w_f`` and ``w_g`` ``[d, H D]`` on the pairs' first keys; one with a
+    ``gate_bound`` draws ``exp(a_log)`` uniform in (0.5, 2) and ``dt_bias``
+    so that the same band of decays holds under its bounded form, ``-ln
+    alpha = -gate_bound sigmoid(exp(a_log) dt_bias)``."""
     dtype = jnp.dtype(spec.dtype)
     d, H, D = spec.hidden_size, spec.kda_heads, spec.kda_head_dim
     r = D  # the decay's and the gate's pairs go through rank head_dim
     f32 = jnp.float32
     ka, kt = jax.random.split(extra[5])
-    a = jax.random.uniform(ka, (H,), f32, 0.02, 0.1)
     tau = jnp.exp(jax.random.uniform(
         kt, (H, D), f32, jnp.log(1e-4), jnp.log(-jnp.log(0.9))))
+    if kd.gate_bound:
+        a = jax.random.uniform(ka, (H,), f32, 0.5, 2.0)
+        share = tau / -kd.gate_bound  # the sigmoid's value at rest
+        dt_bias = (jnp.log(share) - jnp.log1p(-share)) / a[:, None]
+    else:
+        a = jax.random.uniform(ka, (H,), f32, 0.02, 0.1)
+        dt_bias = jnp.log(jnp.expm1(tau / a[:, None]))
+    if kd.full_rank:
+        pairs = {"w_f": dense(extra[3], (d, H * D)),
+                 "w_g": dense(extra[6], (d, H * D))}
+    else:
+        pairs = {
+            "w_f_down": dense(extra[3], (d, r)),
+            "w_f_up": dense(extra[4], (r, H * D)),
+            "w_g_down": dense(extra[6], (d, r)),
+            "w_g_up": dense(extra[7], (r, H * D)),
+        }
     return {
         "attn_norm": jnp.ones((d,), dtype),
         "wq": dense(next(keys), (d, H * D)),
@@ -239,12 +280,9 @@ def _init_kda_layer(spec: ModelSpec, dense, keys, extra) -> Params:
         "conv_q": dense(extra[0], (spec.kda_conv, H * D)),
         "conv_k": dense(extra[1], (spec.kda_conv, H * D)),
         "conv_v": dense(extra[2], (spec.kda_conv, H * D)),
-        "w_f_down": dense(extra[3], (d, r)),
-        "w_f_up": dense(extra[4], (r, H * D)),
+        **pairs,
         "a_log": jnp.log(a),
-        "dt_bias": jnp.log(jnp.expm1(tau / a[:, None])).reshape(H * D),
-        "w_g_down": dense(extra[6], (d, r)),
-        "w_g_up": dense(extra[7], (r, H * D)),
+        "dt_bias": dt_bias.reshape(H * D),
         "w_beta": dense(extra[8], (d, H)),
         "o_norm": jnp.ones((D,), dtype),
     }
@@ -310,7 +348,14 @@ class KindPools(NamedTuple):
     on the V side the tails of their short convolutions ``[layers, rows +
     1, taps - 1, 3 (q, k, v), H D]``, a row a live sequence and a trash
     row last; ``rows`` (K side) is the directory that finds a sequence's
-    row from its block table (``StateRows``)."""
+    row from its block table (``StateRows``).
+
+    A LATENT kind's entry (``LayerKind.latent``) is ONE pool of latent
+    rows on the K side, ``[layers of the kind, num_pages, page_size, D]``
+    as models/mla.py keeps its own (no head axis; ``D`` the row's
+    ``kv_lora_rank + qk_rope_head_dim`` values rounded up to the lane tile
+    where the kernels run compiled), over the same page ids and block
+    table; its V side entry is None."""
 
     pools: tuple
     counts: jax.Array
@@ -354,6 +399,15 @@ def kind_pages(spec: ModelSpec, side, ki: int):
     """The page pool of kind ``ki`` on a cache side (None where the kind
     keeps none)."""
     return _entry_parts(spec.layer_kinds[ki], side.pools[ki])[0]
+
+
+def latent_pool(spec: ModelSpec, k_pages):
+    """The pool of the model's latent kind (``LayerKind.latent``) on the
+    cache's K side."""
+    return next(
+        kind_pages(spec, k_pages, ki)
+        for ki, kd in enumerate(spec.layer_kinds) if kd.latent
+    )
 
 
 class StateRows(NamedTuple):
@@ -550,7 +604,16 @@ def init_cache(
                 (n, R1, spec.ssm_conv - 1, spec.ssm_conv_dim), dtype)
         return jnp.zeros((n, R1, spec.kda_conv - 1, 3, H * D), dtype)
 
+    def latent(n):
+        from dynamo_tpu.models import mla
+
+        return jnp.zeros(
+            (n, num_pages, page_size, pool_head_dim(mla.latent_dim(spec))),
+            dtype)
+
     def k_side(n, kd):
+        if kd.latent:
+            return latent(n)
         return _entry_of(
             kd,
             side(n, kd.num_kv_heads, spec.head_dim) if kd.paged else None,
@@ -558,6 +621,8 @@ def init_cache(
         )
 
     def v_side(n, kd):
+        if kd.latent:
+            return None
         return _entry_of(
             kd,
             side(n, kd.num_kv_heads, spec.v_dim) if kd.paged else None,
@@ -585,8 +650,10 @@ def init_cache(
 
 
 def page_size_of(pages) -> int:
-    """Tokens a page, of a cache side in any form."""
-    return jax.tree.leaves(pages)[0].shape[3]
+    """Tokens a page, of a cache side in any form: a page pool's last
+    axes are ``[page_size, D]``, under a head axis or (latent rows)
+    without one."""
+    return jax.tree.leaves(pages)[0].shape[-2]
 
 
 def _layer_pools(spec: ModelSpec, k_pages, v_pages, li: int):
@@ -826,31 +893,42 @@ def _o_proj(
     return out + lp["bo"] if spec.attn_bias else out
 
 
-def _mlp(lp: Params, x: jax.Array, mults: tuple = ()) -> jax.Array:
+def _mlp(
+    lp: Params, x: jax.Array, mults: tuple = (), clamp: float = 0.0,
+) -> jax.Array:
     """SwiGLU; ``mults`` (gate, down) where the family scales the gate
-    projection and the down projection's output."""
+    projection and the down projection's output; ``clamp`` where the
+    layer bounds its two halves: ``silu(min(gate, L)) * clip(up, -L, L)``."""
     g_mul, d_mul = mults or (1.0, 1.0)
-    gate = jax.nn.silu(_times(x @ lp["w_gate"], g_mul))
-    return _times((gate * (x @ lp["w_up"])) @ lp["w_down"], d_mul)
+    gate = _times(x @ lp["w_gate"], g_mul)
+    gate = jax.nn.silu(jnp.minimum(gate, clamp) if clamp else gate)
+    up = x @ lp["w_up"]
+    if clamp:
+        up = jnp.clip(up, -clamp, clamp)
+    return _times((gate * up) @ lp["w_down"], d_mul)
 
 
 @jax.named_scope(SCOPE_MLP)
 def _ffn(
     spec: ModelSpec, lp: Params, x: jax.Array, *, mesh: Mesh | None = None,
-    counted: jax.Array | None = None,
+    counted: jax.Array | None = None, li: int = 0,
 ):
     """Dense MLP or the routed experts, as the layer's weights say. x:
     [T, d]. With ``counted`` ([T] bool: the real tokens) an expert layer
-    returns (y, its counters' row) — see moe.moe_mlp."""
+    returns (y, its counters' row) — see moe.moe_mlp. ``li``: the layer,
+    for a model that clamps its experts a layer (``ModelSpec.clamps``: a
+    static of the program)."""
     if "moe" in lp:
         from dynamo_tpu.models import moe
 
-        out = moe.moe_mlp(spec, lp["moe"], x, mesh=mesh, counted=counted)
+        clamp, shared_clamp = spec.clamps(li)
+        out = moe.moe_mlp(
+            spec, lp["moe"], x, mesh=mesh, counted=counted, clamp=clamp)
         if "shared" in lp:
             # the shared expert, whole: every chip of an expert-parallel
             # deployment computes it for its own tokens
             with jax.named_scope(SCOPE_MOE_SHARED):
-                y = _mlp(lp["shared"], x)
+                y = _mlp(lp["shared"], x, clamp=shared_clamp)
                 out = out + y if counted is None else (out[0] + y, out[1])
         return out
     return _mlp(lp, x, spec.mlp_multipliers)
@@ -864,14 +942,14 @@ def _ffn_counting(
     cache's K side where it keeps them. Returns (y, k_pages)."""
     keeps = isinstance(k_pages, KindPools) and k_pages.counts.shape[-1] > 0
     if "moe" in lp and keeps:
-        y, row = _ffn(spec, lp, x, mesh=mesh, counted=counted)
+        y, row = _ffn(spec, lp, x, mesh=mesh, counted=counted, li=li)
         with jax.named_scope(SCOPE_MOE_COUNT):
             step = jnp.ones((1,), jnp.int32)
             counts = k_pages.counts.at[li, phase].add(
                 jnp.concatenate([row, step])
             )
         return y, k_pages._replace(counts=counts)
-    return _ffn(spec, lp, x, mesh=mesh), k_pages
+    return _ffn(spec, lp, x, mesh=mesh, li=li), k_pages
 
 
 def _ctx_attention(
@@ -909,8 +987,11 @@ def _logits(spec: ModelSpec, params: Params, x: jax.Array) -> jax.Array:
 # ------------------------------------------------------------------- KDA
 
 
-def _kda_inputs(spec: ModelSpec, lp: Params, h: jax.Array, tail: jax.Array):
-    """A KDA layer's operands from its normed input. h: [N, T, d]; tail:
+def _kda_inputs(
+    spec: ModelSpec, kd, lp: Params, h: jax.Array, tail: jax.Array,
+):
+    """A KDA layer's operands from its normed input, in the forms its kind
+    ``kd`` selects (``LayerKind.full_rank``, ``gate_bound``). h: [N, T, d]; tail:
     [N, taps - 1, 3 H D], the q | k | v projections of the ``taps - 1``
     tokens before (zeros at a sequence's start). Returns (q, k, v, g [N,
     T, H, D] float32, beta [N, T, H] float32, ext [N, taps - 1 + T, 3 H
@@ -940,9 +1021,18 @@ def _kda_inputs(spec: ModelSpec, lp: Params, h: jax.Array, tail: jax.Array):
             jnp.sum(q * q, -1, keepdims=True) + 1e-6) * D ** -0.5
         k = k * jax.lax.rsqrt(jnp.sum(k * k, -1, keepdims=True) + 1e-6)
     with jax.named_scope(SCOPE_KDA_GATES):
-        f = ((h @ lp["w_f_down"]) @ lp["w_f_up"]).astype(f32) + lp["dt_bias"]
-        g = -jnp.exp(lp["a_log"])[:, None] * jax.nn.softplus(f).reshape(
-            N, T, H, D)
+        f = h @ lp["w_f"] if kd.full_rank else (
+            (h @ lp["w_f_down"]) @ lp["w_f_up"])
+        f = f.astype(f32) + lp["dt_bias"]
+        if kd.gate_bound:
+            # the bounded ("safe") gate: a token's log decay in
+            # (gate_bound, 0), inside what kda_chunk's sub-block inverse
+            # decay holds at -5
+            g = kd.gate_bound * jax.nn.sigmoid(
+                jnp.exp(lp["a_log"])[:, None] * f.reshape(N, T, H, D))
+        else:
+            g = -jnp.exp(lp["a_log"])[:, None] * jax.nn.softplus(f).reshape(
+                N, T, H, D)
         beta = jax.nn.sigmoid((h @ lp["w_beta"]).astype(f32))
         if spec.kda_neg_eigval:
             beta = 2.0 * beta
@@ -950,14 +1040,14 @@ def _kda_inputs(spec: ModelSpec, lp: Params, h: jax.Array, tail: jax.Array):
 
 
 @jax.named_scope(SCOPE_OUT)
-def _kda_out(spec: ModelSpec, lp: Params, o: jax.Array, h: jax.Array):
+def _kda_out(spec: ModelSpec, kd, lp: Params, o: jax.Array, h: jax.Array):
     """o: [..., H, D] float32 -> the layer's output [..., d]: RMSNorm a
     head, the sigmoid gate of the layer's input, the output projection."""
     var = jnp.mean(o * o, axis=-1, keepdims=True)
     o = (o * jax.lax.rsqrt(var + spec.rms_eps)).astype(h.dtype) * lp["o_norm"]
-    gate = jax.nn.sigmoid(
-        ((h @ lp["w_g_down"]) @ lp["w_g_up"]).astype(jnp.float32)
-    ).astype(h.dtype)
+    gate = h @ lp["w_g"] if kd.full_rank else (
+        (h @ lp["w_g_down"]) @ lp["w_g_up"])
+    gate = jax.nn.sigmoid(gate.astype(jnp.float32)).astype(h.dtype)
     return (o.reshape(*h.shape[:-1], -1) * gate) @ lp["wo"]
 
 
@@ -969,7 +1059,7 @@ def ext_width(c_pool) -> int:
 
 
 def _kda_prefill(
-    spec: ModelSpec, lp: Params, h: jax.Array, s_pool, c_pool, lj: int,
+    spec: ModelSpec, kd, lp: Params, h: jax.Array, s_pool, c_pool, lj: int,
     idx: jax.Array, fresh: jax.Array, num_tokens: jax.Array,
 ):
     """A KDA layer over N sequences' new tokens, from and to their state
@@ -982,7 +1072,7 @@ def _kda_prefill(
                 fresh[:, None, None], 0,
                 c_pool[lj, idx].reshape(N, -1, ext_width(c_pool)),
             )
-        q, k, v, g, beta, ext = _kda_inputs(spec, lp, h, tail)
+        q, k, v, g, beta, ext = _kda_inputs(spec, kd, lp, h, tail)
         with jax.named_scope(SCOPE_KDA_GATES):
             # a padded token leaves the state as it was
             real = jnp.arange(T)[None, :] < num_tokens[:, None]
@@ -1000,11 +1090,11 @@ def _kda_prefill(
         c_pool = c_pool.at[lj, idx].set(
             new_tail.reshape(N, *c_pool.shape[2:]).astype(c_pool.dtype)
         )
-    return _kda_out(spec, lp, o, h), s_pool, c_pool
+    return _kda_out(spec, kd, lp, o, h), s_pool, c_pool
 
 
 def _kda_decode(
-    spec: ModelSpec, lp: Params, h: jax.Array, s_pool, c_pool, lj: int,
+    spec: ModelSpec, kd, lp: Params, h: jax.Array, s_pool, c_pool, lj: int,
     idx: jax.Array,
 ):
     """A KDA layer's decode step over the slots' state rows. h: [B, d];
@@ -1013,27 +1103,28 @@ def _kda_decode(
     B = h.shape[0]
     with jax.named_scope(SCOPE_QKV):
         q, k, v, g, beta, ext = _kda_inputs(
-            spec, lp, h[:, None], c_pool[lj, idx].reshape(B, -1, ext_width(c_pool))
+            spec, kd, lp, h[:, None],
+            c_pool[lj, idx].reshape(B, -1, ext_width(c_pool)),
         )
     with jax.named_scope(SCOPE_KV):
         o, s_pool, c_pool = kda_decode_step(
             s_pool, c_pool, idx, q[:, 0], k[:, 0], v[:, 0], g[:, 0],
             beta[:, 0], ext[:, 1:].reshape(B, *c_pool.shape[2:]), layer=lj,
         )
-    return _kda_out(spec, lp, o, h), s_pool, c_pool
+    return _kda_out(spec, kd, lp, o, h), s_pool, c_pool
 
 
-def _kda_whole(spec: ModelSpec, lp: Params, h: jax.Array) -> jax.Array:
+def _kda_whole(spec: ModelSpec, kd, lp: Params, h: jax.Array) -> jax.Array:
     """A KDA layer over one whole sequence from an empty state, keeping
     none (embeddings, ``reference_forward``). h: [T, d] -> [T, d]."""
     H, D = spec.kda_heads, spec.kda_head_dim
     tail = jnp.zeros((1, spec.kda_conv - 1, 3 * H * D), h.dtype)
-    q, k, v, g, beta, _ = _kda_inputs(spec, lp, h[None], tail)
+    q, k, v, g, beta, _ = _kda_inputs(spec, kd, lp, h[None], tail)
     o, _ = kda_chunk_prefill(
         q, k, v, g, beta, jnp.zeros((1, 2, H, D, D), jnp.float32),
         jnp.zeros((1,), jnp.int32), jnp.ones((1,), bool), layer=0,
     )
-    return _kda_out(spec, lp, o[0], h)
+    return _kda_out(spec, kd, lp, o[0], h)
 
 
 # ------------------------------------------------------------------- SSD
@@ -1093,7 +1184,7 @@ def _ssd_out(spec: ModelSpec, lp: Params, y: jax.Array, z: jax.Array):
 
 
 def _ssd_prefill(
-    spec: ModelSpec, lp: Params, h: jax.Array, s_pool, c_pool, lj: int,
+    spec: ModelSpec, kd, lp: Params, h: jax.Array, s_pool, c_pool, lj: int,
     idx: jax.Array, fresh: jax.Array, num_tokens: jax.Array,
 ):
     """An SSD mixer over N sequences' new tokens, from and to their state
@@ -1124,7 +1215,7 @@ def _ssd_prefill(
 
 
 def _ssd_decode(
-    spec: ModelSpec, lp: Params, h: jax.Array, s_pool, c_pool, lj: int,
+    spec: ModelSpec, kd, lp: Params, h: jax.Array, s_pool, c_pool, lj: int,
     idx: jax.Array,
 ):
     """An SSD mixer's decode step over the slots' state rows. h: [B, d];
@@ -1143,7 +1234,7 @@ def _ssd_decode(
         return _ssd_out(spec, lp, y, z[:, 0]), s_pool, c_pool
 
 
-def _ssd_whole(spec: ModelSpec, lp: Params, h: jax.Array) -> jax.Array:
+def _ssd_whole(spec: ModelSpec, kd, lp: Params, h: jax.Array) -> jax.Array:
     """An SSD mixer over one whole sequence from an empty state, keeping
     none (embeddings, ``reference_forward``). h: [T, d] -> [T, d]."""
     H, P, S = spec.ssm_heads, spec.ssm_head_dim, spec.ssm_state
@@ -1158,28 +1249,34 @@ def _ssd_whole(spec: ModelSpec, lp: Params, h: jax.Array) -> jax.Array:
 
 
 # what a recurrent mixer is called with, by ``LayerKind.mixer``: (prefill
-# over [N, T, d] rows, decode step over [B, d] slots, a whole sequence)
+# over [N, T, d] rows, decode step over [B, d] slots, a whole sequence),
+# each ``(spec, kind, layer weights, ...)``
 _RECURRENT = {
     "kda": (_kda_prefill, _kda_decode, _kda_whole),
     "ssd": (_ssd_prefill, _ssd_decode, _ssd_whole),
 }
 
 
-def _mixers(spec: ModelSpec, li: int, kp, vp, attend, recur):
+def _mixers(spec: ModelSpec, li: int, kp, vp, attend, recur, latent=None):
     """Layer ``li``'s token mixers over its normed input, ONE body for
     every kind: softmax attention over the kind's pages where it has KV
-    heads (``attend(k_pool, v_pool) -> (out, k_pool, v_pool)``), the
-    recurrent mixer over its state rows where it has one (``recur(fn,
-    states, tails) -> (out, states, tails)``, ``fn`` the mixer's prefill
-    and decode forms), their outputs summed where it has both. kp, vp: the
-    kind's entries of the cache's two sides. Returns (mix, kp, vp)."""
+    heads (``attend(k_pool, v_pool) -> (out, k_pool, v_pool)``), latent
+    attention over its one pool of latent rows where that is what its
+    pages hold (``latent(pool) -> (out, pool)``: models/mla.py's layer),
+    the recurrent mixer over its state rows where it has one (``recur(fn,
+    kind, states, tails) -> (out, states, tails)``, ``fn`` the mixer's
+    prefill and decode forms), their outputs summed where it has both. kp,
+    vp: the kind's entries of the cache's two sides. Returns (mix, kp,
+    vp)."""
     kd = spec.kind(li)
     (k_pg, s_pool), (v_pg, c_pool) = _entry_parts(kd, kp), _entry_parts(kd, vp)
     mix = None
-    if kd.paged:
+    if kd.latent:
+        mix, k_pg = latent(k_pg)
+    elif kd.paged:
         mix, k_pg, v_pg = attend(k_pg, v_pg)
     if kd.recurrent:
-        rec, s_pool, c_pool = recur(_RECURRENT[kd.mixer], s_pool, c_pool)
+        rec, s_pool, c_pool = recur(_RECURRENT[kd.mixer], kd, s_pool, c_pool)
         mix = rec if mix is None else mix + rec
     return mix, _entry_of(kd, k_pg, s_pool), _entry_of(kd, v_pg, c_pool)
 
@@ -1265,13 +1362,24 @@ def prefill_forward_impl(
                 )
             return _o_proj(spec, lp, attn, h), kp, vp
 
-        def recur(fn, sp, cp, lp=lp, lj=lj, h=h):
+        def recur(fn, kd, sp, cp, lp=lp, lj=lj, h=h):
             mix, sp, cp = fn[0](
-                spec, lp, h[None], sp, cp, lj, idx, fresh, num_tokens[None]
+                spec, kd, lp, h[None], sp, cp, lj, idx, fresh,
+                num_tokens[None],
             )
             return mix[0], sp, cp
 
-        mix, kp, vp = _mixers(spec, li, kp, vp, attend, recur)
+        def latent(pool, lp=lp, lj=lj, h=h):
+            from dynamo_tpu.models import mla
+
+            mix, pool = mla.prefill_layer(
+                spec, lj, lp, h[None], positions[None], pool, safe_pg,
+                valid_tok, block_table[None], start_pos[None], kv_len[None],
+                mesh,
+            )
+            return mix[0], pool
+
+        mix, kp, vp = _mixers(spec, li, kp, vp, attend, recur, latent)
         k_pages, v_pages = _put_pools(spec, k_pages, v_pages, li, kp, vp)
         x = _add(x, mix)
         h = _norm(x, lp["mlp_norm"], spec.rms_eps)
@@ -1292,9 +1400,9 @@ def _no_recurrent(spec: ModelSpec, what: str) -> None:
     across sequence shards, nor rolled back past rejected drafts. The
     engine never reaches them for such a model (family.GqaFamily's
     ``supports_*``); a direct caller is told."""
-    if spec.has_recurrent:
+    if spec.has_recurrent or spec.has_latent:
         raise NotImplementedError(
-            f"{what}: no form for a model with recurrent layers"
+            f"{what}: no form for a model with recurrent or latent kinds"
         )
 
 
@@ -1390,10 +1498,18 @@ def prefill_forward_batch_impl(
                 )(q, k, v, block_tables, positions, kv_len)
             return _o_proj(spec, lp, attn, h), kp, vp
 
-        def recur(fn, sp, cp, lp=lp, lj=lj, h=h):
-            return fn[0](spec, lp, h, sp, cp, lj, idx, fresh, num_tokens)
+        def recur(fn, kd, sp, cp, lp=lp, lj=lj, h=h):
+            return fn[0](spec, kd, lp, h, sp, cp, lj, idx, fresh, num_tokens)
 
-        mix, kp, vp = _mixers(spec, li, kp, vp, attend, recur)
+        def latent(pool, lp=lp, lj=lj, h=h):
+            from dynamo_tpu.models import mla
+
+            return mla.prefill_layer(
+                spec, lj, lp, h, positions, pool, safe_pg, valid_tok,
+                block_tables, start_pos, kv_len, mesh,
+            )
+
+        mix, kp, vp = _mixers(spec, li, kp, vp, attend, recur, latent)
         k_pages, v_pages = _put_pools(spec, k_pages, v_pages, li, kp, vp)
         x = _add(x, mix)
         h = _norm(x, lp["mlp_norm"], spec.rms_eps)
@@ -1464,7 +1580,7 @@ def prefill_forward_ring_impl(
         attn = ring_attention(q, k, v, mesh=mesh)
         x = _add(x, _o_proj(spec, lp, attn, h))
         h = _norm(x, lp["mlp_norm"], spec.rms_eps)
-        x = _add(x, _ffn(spec, lp, h, mesh=mesh))
+        x = _add(x, _ffn(spec, lp, h, mesh=mesh, li=li))
         x = jax.lax.with_sharding_constraint(x, sp_spec)
 
     last = jnp.clip(num_tokens - 1, 0, T - 1)
@@ -1567,7 +1683,7 @@ def verify_forward_impl(
         x = _add(x, _o_proj(spec, lp, attn, h))
         h = _norm(x, lp["mlp_norm"], spec.rms_eps)
         x = _add(x, _ffn(
-            spec, lp, h.reshape(N * W, -1), mesh=mesh
+            spec, lp, h.reshape(N * W, -1), mesh=mesh, li=li
         ).reshape(N, W, -1))
 
     logits = _logits(spec, params, x)  # [N, W, V]
@@ -1618,6 +1734,14 @@ def decode_forward_impl(
         safe_page = jnp.where(active, page_idx_raw, TRASH_PAGE)
         offset = positions % page_size
 
+    schedule = None
+    if spec.has_latent:
+        # the latent kernel's schedule follows the lengths alone: once a
+        # step for the kind's layers (the kinds share one block table)
+        from dynamo_tpu.ops.attention import latent_decode_schedule
+
+        schedule = latent_decode_schedule(
+            latent_pool(spec, k_pages), block_tables, seq_lens, mesh)
     x = _embed(params, tokens, spec)  # [B, d]
 
     for li, lp in enumerate(params["layers"]):
@@ -1639,10 +1763,18 @@ def decode_forward_impl(
                 )
             return _o_proj(spec, lp, attn, h), kp, vp
 
-        def recur(fn, sp, cp, lp=lp, lj=lj, h=h):
-            return fn[1](spec, lp, h, sp, cp, lj, state_idx)
+        def recur(fn, kd, sp, cp, lp=lp, lj=lj, h=h):
+            return fn[1](spec, kd, lp, h, sp, cp, lj, state_idx)
 
-        mix, kp, vp = _mixers(spec, li, kp, vp, attend, recur)
+        def latent(pool, lp=lp, lj=lj, h=h):
+            from dynamo_tpu.models import mla
+
+            return mla.decode_layer(
+                spec, lj, lp, h, positions, pool, block_tables, seq_lens,
+                safe_page, offset, schedule, mesh,
+            )
+
+        mix, kp, vp = _mixers(spec, li, kp, vp, attend, recur, latent)
         k_pages, v_pages = _put_pools(spec, k_pages, v_pages, li, kp, vp)
         x = _add(x, mix)
         h = _norm(x, lp["mlp_norm"], spec.rms_eps)
@@ -1833,14 +1965,22 @@ def _whole_mixer(spec: ModelSpec, li: int, lp: Params, h, positions, n):
     tail cannot reach a real token either way)."""
     kd = spec.kind(li)
     mix = None
-    if kd.paged:
+    if kd.latent:
+        from dynamo_tpu.models import mla
+
+        mix = mla.whole_layer(
+            spec, lp, h, positions,
+            (positions[:, None] >= positions[None, :])
+            & (positions[None, :] < n),
+        )
+    elif kd.paged:
         q, k, v = _attn_qkv(spec, li, lp, h, positions)
         attn = causal_attention(
             q, k, v, positions, n, window=kd.window, sinks=lp.get("sinks"),
         )
         mix = _o_proj(spec, lp, attn, h)
     if kd.recurrent:
-        rec = _RECURRENT[kd.mixer][2](spec, lp, h)
+        rec = _RECURRENT[kd.mixer][2](spec, kd, lp, h)
         mix = rec if mix is None else mix + rec
     return mix
 
@@ -1863,7 +2003,7 @@ def embed_forward_impl(
         h = _norm(x, lp["attn_norm"], spec.rms_eps)
         x = _add(x, _whole_mixer(spec, li, lp, h, positions, num_tokens))
         h = _norm(x, lp["mlp_norm"], spec.rms_eps)
-        x = _add(x, _ffn(spec, lp, h))
+        x = _add(x, _ffn(spec, lp, h, li=li))
     xn = rms_norm(x, params["final_norm"], spec.rms_eps).astype(jnp.float32)
     mask = (positions < num_tokens)[:, None].astype(jnp.float32)
     pooled = (xn * mask).sum(axis=0) / jnp.maximum(mask.sum(), 1.0)
@@ -1888,7 +2028,7 @@ def reference_forward(
         h = _norm(x, lp["attn_norm"], spec.rms_eps)
         x = _add(x, _whole_mixer(spec, li, lp, h, positions, jnp.asarray(T)))
         h = _norm(x, lp["mlp_norm"], spec.rms_eps)
-        x = _add(x, _ffn(spec, lp, h))
+        x = _add(x, _ffn(spec, lp, h, li=li))
     xn = rms_norm(x, params["final_norm"], spec.rms_eps)
     head = params["embed"].T if spec.tie_embeddings else params["lm_head"]
     return _times((xn @ head).astype(jnp.float32), spec.lm_head_multiplier)

@@ -233,10 +233,22 @@ def spec_from_hf_config(cfg: dict, name: str | None = None) -> ModelSpec:
     )
 
 
+def _no_latent_kind(spec: ModelSpec) -> None:
+    """A model that keeps latent layers as a KIND beside others (Ling-3.0)
+    runs on drawn weights only: the catalog it was added from gives its
+    config and no tensor names, and none is invented here."""
+    if spec.has_latent:
+        raise NotImplementedError(
+            "a latent kind beside other kinds has no published tensor "
+            "names in this loader: drawn weights only"
+        )
+
+
 def hf_config_from_spec(spec: ModelSpec) -> dict:
     """Inverse of spec_from_hf_config (save_params / re-export): every
     architecture field the loader reads must round-trip, or an exported
     checkpoint silently loses features on reload."""
+    _no_latent_kind(spec)
     if spec.kv_lora_rank:
         model_type = "deepseek_v3"
     elif "ssd" in spec.mixers:
@@ -586,6 +598,7 @@ def load_params(
     """
     from safetensors import safe_open
 
+    _no_latent_kind(spec)
     dtype = dtype or spec.dtype
     files = sorted(
         os.path.join(model_dir, f)
@@ -790,6 +803,7 @@ def save_params(
     and checkpoint re-export). Large trees split into multiple shard files."""
     from safetensors.numpy import save_file
 
+    _no_latent_kind(spec)
     os.makedirs(model_dir, exist_ok=True)
     if spec.kv_lora_rank:
         dest = _dest_map_mla(spec)

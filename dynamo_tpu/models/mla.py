@@ -143,9 +143,6 @@ def init_params(spec: ModelSpec, key: jax.Array) -> Params:
     assert spec.kv_lora_rank > 0, "not an MLA spec"
     dtype = jnp.dtype(spec.dtype)
     d = spec.hidden_size
-    H = spec.num_heads
-    dn, dr, dv = spec.qk_nope_head_dim, spec.qk_rope_head_dim, spec.v_head_dim
-    dc = spec.kv_lora_rank
     keys = iter(jax.random.split(key, 4 + spec.num_layers * 8))
 
     def dense(k, shape, scale=None):
@@ -165,29 +162,11 @@ def init_params(spec: ModelSpec, key: jax.Array) -> Params:
         k_qa, k_qb, k_kva, k_kvb, k_o, k1, k2, k3 = (
             next(keys) for _ in range(8)
         )
-        kv_b = dense(k_kvb, (dc, H * (dn + dv))).reshape(dc, H, dn + dv)
         layer: Params = {
             "attn_norm": jnp.ones((d,), dtype),
             "mlp_norm": jnp.ones((d,), dtype),
-            "w_kv_a": _half_split(spec, dense(k_kva, (d, dc + dr)), dc),
-            "kv_norm": jnp.ones((dc,), dtype),
-            "w_uk": kv_b[..., :dn].transpose(1, 0, 2),  # [H, dc, dn]
-            "w_uv": kv_b[..., dn:].transpose(1, 0, 2),  # [H, dc, dv]
-            "wo": dense(k_o, (H * dv, d)),
+            **init_latent_mixer(spec, dense, k_qa, k_qb, k_kva, k_kvb, k_o),
         }
-        q_in = spec.q_lora_rank or d
-        wq = _half_split(
-            spec,
-            dense(k_qb if spec.q_lora_rank else k_qa,
-                  (q_in, H * (dn + dr))).reshape(q_in, H, dn + dr),
-            dn,
-        ).reshape(q_in, H * (dn + dr))
-        if spec.q_lora_rank:
-            layer["wq_a"] = dense(k_qa, (d, spec.q_lora_rank))
-            layer["q_norm"] = jnp.ones((spec.q_lora_rank,), dtype)
-            layer["wq_b"] = wq
-        else:
-            layer["wq"] = wq
         if spec.num_experts and li >= spec.first_k_dense:
             from dynamo_tpu.models import moe
 
@@ -206,6 +185,45 @@ def init_params(spec: ModelSpec, key: jax.Array) -> Params:
             layer["w_down"] = dense(k3, (spec.intermediate_size, d))
         params["layers"].append(layer)
     return params
+
+
+def init_latent_mixer(
+    spec: ModelSpec, dense, k_qa, k_qb, k_kva, k_kvb, k_o, k_gate=None,
+) -> Params:
+    """A latent layer's mixer weights on the given keys, drawn in the
+    published layout and brought to the program's (``init_params``): what
+    this family's every layer holds, and a latent KIND's layer of a model
+    that lists its kinds (llama.init_params). ``k_gate``: the key of the
+    gate by head ``[d, H]`` (``LayerKind.head_gate``), where the kind has
+    one."""
+    dtype = jnp.dtype(spec.dtype)
+    d, H = spec.hidden_size, spec.num_heads
+    dn, dr, dv = spec.qk_nope_head_dim, spec.qk_rope_head_dim, spec.v_head_dim
+    dc = spec.kv_lora_rank
+    kv_b = dense(k_kvb, (dc, H * (dn + dv))).reshape(dc, H, dn + dv)
+    layer: Params = {
+        "w_kv_a": _half_split(spec, dense(k_kva, (d, dc + dr)), dc),
+        "kv_norm": jnp.ones((dc,), dtype),
+        "w_uk": kv_b[..., :dn].transpose(1, 0, 2),  # [H, dc, dn]
+        "w_uv": kv_b[..., dn:].transpose(1, 0, 2),  # [H, dc, dv]
+        "wo": dense(k_o, (H * dv, d)),
+    }
+    q_in = spec.q_lora_rank or d
+    wq = _half_split(
+        spec,
+        dense(k_qb if spec.q_lora_rank else k_qa,
+              (q_in, H * (dn + dr))).reshape(q_in, H, dn + dr),
+        dn,
+    ).reshape(q_in, H * (dn + dr))
+    if spec.q_lora_rank:
+        layer["wq_a"] = dense(k_qa, (d, spec.q_lora_rank))
+        layer["q_norm"] = jnp.ones((spec.q_lora_rank,), dtype)
+        layer["wq_b"] = wq
+    else:
+        layer["wq"] = wq
+    if k_gate is not None:
+        layer["w_gate_head"] = dense(k_gate, (d, H))
+    return layer
 
 
 def init_cache(
@@ -352,10 +370,19 @@ def _latent_row(spec: ModelSpec, lp: Params, h: jax.Array, positions):
 
 
 @jax.named_scope(SCOPE_OUT)
-def _o_proj(lp: Params, attn: jax.Array, x: jax.Array) -> jax.Array:
+def _o_proj(lp: Params, attn: jax.Array, h: jax.Array) -> jax.Array:
     """The heads' outputs ``attn`` [..., H, dv] (or already flat) through
-    the output projection, in the residual stream ``x``'s type."""
-    return attn.reshape(*x.shape[:-1], -1).astype(x.dtype) @ lp["wo"]
+    the output projection, in the type of the layer's normed input ``h``
+    [..., d] (the residual stream's); where the layer gates its attention
+    output by HEAD (``w_gate_head`` [d, H]), with a sigmoid of ``h``
+    first."""
+    attn = attn.reshape(*h.shape[:-1], -1).astype(h.dtype)
+    if "w_gate_head" in lp:
+        gate = jax.nn.sigmoid((h @ lp["w_gate_head"]).astype(jnp.float32))
+        attn = (
+            attn.reshape(*gate.shape, -1) * gate.astype(h.dtype)[..., None]
+        ).reshape(attn.shape)
+    return attn @ lp["wo"]
 
 
 # A layer's two halves as jits of their own inside the programs: the
@@ -477,6 +504,71 @@ def _seq_attention(
     ).reshape(N, T, -1)
 
 
+def prefill_layer(
+    spec: ModelSpec, li, lp: Params, h: jax.Array, positions, cache,
+    safe_pg, valid_tok, block_tables, start_pos, kv_len,
+    mesh: Mesh | None = None,
+):
+    """A latent layer's mixer over N sequences' new tokens (h [N, T, d] at
+    ``positions`` [N, T]; ``safe_pg`` [N * T / page] the pages their rows
+    land on, ``valid_tok`` [N * T / page, page] the real ones): the rows
+    written to layer ``li`` of ``cache``, attention over each sequence's
+    paged latents, the output projection. ``cache`` is this family's, or
+    the pool of a latent KIND (llama.KindPools) with ``li`` the layer's
+    index inside it. Returns (out [N, T, d], cache)."""
+    q_nope, q_rope, new_rows = _attn_inputs(spec, lp, h, positions)
+    with jax.named_scope(SCOPE_KV):
+        cache = _set_latent_tiles(
+            cache, li, safe_pg, new_rows.reshape(*valid_tok.shape, -1),
+            valid_tok,
+        )
+    attn = _seq_attention(
+        spec, li, lp, q_nope, q_rope, new_rows, cache, block_tables,
+        start_pos, kv_len, mesh,
+    )
+    return _o_proj(lp, attn, h), cache
+
+
+def decode_layer(
+    spec: ModelSpec, li, lp: Params, h: jax.Array, positions, cache,
+    block_tables, seq_lens, safe_page, offset, schedule,
+    mesh: Mesh | None = None,
+):
+    """A latent layer's decode step over the slots (h [B, d]): the new
+    rows appended to layer ``li`` of ``cache`` (this family's, or a latent
+    kind's pool) and absorbed attention over the live pages in one call.
+    ``schedule``: ``latent_decode_schedule``'s, made once a step. Returns
+    (out [B, d], cache)."""
+    q_nope, q_rope, new_rows = _attn_inputs(spec, lp, h, positions)
+    # absorb W_uk: q_lat[b, h] = q_nope[b, h] W_uk[h]^T
+    with jax.named_scope(SCOPE_LATENT_ABSORB):
+        q_lat = jnp.einsum(
+            "bhn,hcn->bhc", q_nope, lp["w_uk"],
+            preferred_element_type=jnp.float32,
+        )
+    with jax.named_scope(SCOPE_KV):
+        o_lat, cache = latent_decode_update_attention(
+            q_lat, q_rope, cache, new_rows, block_tables, seq_lens,
+            safe_page, offset, layer=li, scale=softmax_scale(spec),
+            mesh=mesh, schedule=schedule,
+        )
+    with jax.named_scope(SCOPE_LATENT_ABSORB):
+        attn = jnp.einsum(
+            "bhc,hcv->bhv", o_lat.astype(lp["w_uv"].dtype), lp["w_uv"],
+            preferred_element_type=jnp.float32,
+        )
+    return _o_proj(lp, attn, h), cache
+
+
+def whole_layer(spec: ModelSpec, lp: Params, h: jax.Array, positions, mask):
+    """A latent layer's mixer over one whole sequence with no cache (h [T,
+    d]; ``mask`` [T, T] bool): plain non-absorbed attention."""
+    q_nope, q_rope = _q_heads(spec, lp, h, positions)
+    rows = _latent_row(spec, lp, h, positions)
+    attn = _dense_attention(spec, lp, q_nope, q_rope, rows, mask)
+    return _o_proj(lp, attn, h)
+
+
 def _with_counts(out: tuple, counts):
     """A program's results, the counters appended where the caller passed
     them."""
@@ -498,10 +590,7 @@ def reference_forward(
     mask = positions[:, None] >= positions[None, :]
     for li, lp in enumerate(params["layers"]):
         h = _norm(x, lp["attn_norm"], spec.rms_eps)
-        q_nope, q_rope = _q_heads(spec, lp, h, positions)
-        rows = _latent_row(spec, lp, h, positions)
-        attn = _dense_attention(spec, lp, q_nope, q_rope, rows, mask)
-        x = _add(x, _o_proj(lp, attn, x))
+        x = _add(x, whole_layer(spec, lp, h, positions, mask))
         hh = _norm(x, lp["mlp_norm"], spec.rms_eps)
         x = _add(x, _ffn(spec, li, lp, hh))
     return _logits_all(spec, params, x)
@@ -559,7 +648,7 @@ def prefill_forward_impl(
             spec, li, lp, q_nope[None], q_rope[None], new_rows[None], cache,
             block_table[None], start_pos[None], kv_len[None], mesh,
         )[0]
-        x = _add(x, _o_proj(lp, attn, x))
+        x = _add(x, _o_proj(lp, attn, h))
         hh = _norm(x, lp["mlp_norm"], spec.rms_eps)
         y, counts = _ffn_counting(
             spec, li, lp, hh, counts, COUNT_PREFILL, real, mesh
@@ -617,18 +706,12 @@ def prefill_forward_batch_impl(
         kv_len = start_pos + num_tokens  # [N]
     for li, lp in enumerate(params["layers"]):
         h = _norm(x, lp["attn_norm"], spec.rms_eps)
-        q_nope, q_rope, new_rows = _attn_inputs(spec, lp, h, positions)
-        with jax.named_scope(SCOPE_KV):
-            cache = _set_latent_tiles(
-                cache, li, safe_pg,
-                new_rows.reshape(N * n_pg, page_size, -1),
-                real.reshape(N * n_pg, page_size),
-            )
-        attn = _seq_attention(
-            spec, li, lp, q_nope, q_rope, new_rows, cache, block_tables,
-            start_pos, kv_len, mesh,
+        mix, cache = prefill_layer(
+            spec, li, lp, h, positions, cache, safe_pg,
+            real.reshape(N * n_pg, page_size), block_tables, start_pos,
+            kv_len, mesh,
         )
-        x = _add(x, _o_proj(lp, attn, x))
+        x = _add(x, mix)
         hh = _norm(x, lp["mlp_norm"], spec.rms_eps)
         y, counts = _ffn_counting(
             spec, li, lp, hh.reshape(N * T, -1), counts, COUNT_PREFILL,
@@ -700,7 +783,7 @@ def verify_forward_impl(
             spec, li, lp, q_nope, q_rope, new_rows, cache, block_tables,
             start_pos, kv_len, mesh,
         )
-        x = _add(x, _o_proj(lp, attn, x))
+        x = _add(x, _o_proj(lp, attn, h))
         hh = _norm(x, lp["mlp_norm"], spec.rms_eps)
         y, counts = _ffn_counting(
             spec, li, lp, hh.reshape(N * W, -1), counts, COUNT_PREFILL,
@@ -746,31 +829,16 @@ def decode_forward_impl(
         )[:, 0]
         safe_page = jnp.where(active, page_idx, TRASH_PAGE)
         offset = positions % page_size
-    scale = softmax_scale(spec)
     # the kernel's schedule follows the lengths alone: once a step
     schedule = latent_decode_schedule(cache, block_tables, seq_lens, mesh)
     x = _embed(params, tokens)
     for li, lp in enumerate(params["layers"]):
         h = _norm(x, lp["attn_norm"], spec.rms_eps)
-        q_nope, q_rope, new_rows = _attn_inputs(spec, lp, h, positions)
-        # absorb W_uk: q_lat[b, h] = q_nope[b, h] W_uk[h]^T
-        with jax.named_scope(SCOPE_LATENT_ABSORB):
-            q_lat = jnp.einsum(
-                "bhn,hcn->bhc", q_nope, lp["w_uk"],
-                preferred_element_type=jnp.float32,
-            )
-        with jax.named_scope(SCOPE_KV):
-            o_lat, cache = latent_decode_update_attention(
-                q_lat, q_rope, cache, new_rows, block_tables, seq_lens,
-                safe_page, offset, layer=li, scale=scale, mesh=mesh,
-                schedule=schedule,
-            )
-        with jax.named_scope(SCOPE_LATENT_ABSORB):
-            attn = jnp.einsum(
-                "bhc,hcv->bhv", o_lat.astype(lp["w_uv"].dtype), lp["w_uv"],
-                preferred_element_type=jnp.float32,
-            )
-        x = _add(x, _o_proj(lp, attn, x))
+        mix, cache = decode_layer(
+            spec, li, lp, h, positions, cache, block_tables, seq_lens,
+            safe_page, offset, schedule, mesh,
+        )
+        x = _add(x, mix)
         hh = _norm(x, lp["mlp_norm"], spec.rms_eps)
         y, counts = _ffn_counting(
             spec, li, lp, hh, counts, COUNT_DECODE, active, mesh
@@ -885,10 +953,7 @@ def embed_forward_impl(
     )
     for li, lp in enumerate(params["layers"]):
         h = _norm(x, lp["attn_norm"], spec.rms_eps)
-        q_nope, q_rope = _q_heads(spec, lp, h, positions)
-        rows = _latent_row(spec, lp, h, positions)
-        attn = _dense_attention(spec, lp, q_nope, q_rope, rows, mask2d)
-        x = _add(x, _o_proj(lp, attn, x))
+        x = _add(x, whole_layer(spec, lp, h, positions, mask2d))
         hh = _norm(x, lp["mlp_norm"], spec.rms_eps)
         x = _add(x, _ffn(spec, li, lp, hh))
     xn = rms_norm(x, params["final_norm"], spec.rms_eps).astype(jnp.float32)

@@ -147,12 +147,14 @@ def route(spec: ModelSpec, lp: Params, x: jax.Array):
 @jax.named_scope(SCOPE_EXPERTS)
 def _held_experts(
     spec: ModelSpec, lp: Params, x: jax.Array, topi: jax.Array,
-    topv: jax.Array, first, *, down_bias=True,
+    topv: jax.Array, first, *, down_bias=True, clamp: float = 0.0,
 ) -> jax.Array:
     """The share of the layer's output that the experts in ``lp`` make:
     experts ``first .. first + n`` of the model, n = ``lp["w_gate"]``'s
     leading axis. x: [T, d] -> [T, d] float32. ``down_bias``: whether
-    this shard is the one that adds the down projection's bias."""
+    this shard is the one that adds the down projection's bias.
+    ``clamp``: the layer's plain-SiLU bound on an expert's two halves
+    (``ModelSpec.expert_clamp``; a static of the program), 0 = none."""
     T, k = topi.shape
     n = lp["w_gate"].shape[0]
     # the layer's three steps, each a region of its own beneath
@@ -184,6 +186,8 @@ def _held_experts(
             g = jnp.minimum(g, spec.swiglu_limit)
             u = jnp.clip(u, -spec.swiglu_limit, spec.swiglu_limit)
             h = g * jax.nn.sigmoid(spec.swiglu_alpha * g) * (u + 1.0)
+        elif clamp:
+            h = jax.nn.silu(jnp.minimum(g, clamp)) * jnp.clip(u, -clamp, clamp)
         else:
             h = jax.nn.silu(g) * u
         out = _grouped_matmul(h.astype(x.dtype), lp["w_down"], sizes)
@@ -260,9 +264,10 @@ def _grouped_matmul(a: jax.Array, w: jax.Array, sizes: jax.Array):
 
 def moe_mlp(
     spec: ModelSpec, lp: Params, x: jax.Array, *, mesh: Mesh | None = None,
-    counted: jax.Array | None = None,
+    counted: jax.Array | None = None, clamp: float = 0.0,
 ):
-    """x: [T, d] -> [T, d] through the top-k routed experts held here.
+    """x: [T, d] -> [T, d] through the top-k routed experts held here
+    (``clamp``: see ``_held_experts``).
 
     ``counted`` ([T] bool) asks for the layer's counters beside the
     output: (y, counts [n_held + 2] int32) = assignments of the counted
@@ -280,7 +285,7 @@ def moe_mlp(
         if mesh is not None and mesh.shape.get(a, 1) > 1
     )
     if not axes:
-        y = _held_experts(spec, lp, x, topi, topv, first)
+        y = _held_experts(spec, lp, x, topi, topv, first, clamp=clamp)
     else:
         ep = mesh.shape.get("ep", 1)
         experts = {k: v for k, v in lp.items() if k in _EXPERT_SPECS}
@@ -293,7 +298,8 @@ def moe_mlp(
             # the whole down bias: the first adds it
             first_tp = "tp" not in axes or jax.lax.axis_index("tp") == 0
             return jax.lax.psum(_held_experts(
-                spec, experts_, x_, topi_, topv_, at, down_bias=first_tp
+                spec, experts_, x_, topi_, topv_, at, down_bias=first_tp,
+                clamp=clamp,
             ), axes)
 
         y = jax.shard_map(

@@ -51,6 +51,10 @@ SCOPE_ATTN_WINDOW = "attn_window"
 SCOPE_ATTN_FULL = "attn_full"
 SCOPE_FUSED_DECODE = "fused_decode_attention"  # the kernel's own name,
 # where a model of one kind of layer opens no scope around it
+# the latent kernels keep these names whichever loops call them: a model
+# whose every layer is latent (models/mla.py's own) and a latent KIND
+# beside KDA layers in models/llama.py's, where one decode program holds
+# ``kda_step`` and ``attn_latent`` both
 SCOPE_ATTN_LATENT = "attn_latent"
 SCOPE_PREFILL_LATENT = "prefill_latent"
 SCOPE_LATENT_SCHEDULE = "latent_schedule"  # the decode kernel's live

@@ -401,3 +401,98 @@ def test_falcon_decode_program_compiles_for_v5e(v5e, monkeypatch):
         _rows(v5e, B_, dtype=i32), n_steps=8, n_logprobs=0,
     ).compile().as_text()
     assert "%ssd_step" in text and "%attn_full" in text
+
+
+def _ling_layers(v5e, vocab=2048):
+    """A KDA expert layer and the latent (MLA) expert layer of Ling-3.0-
+    flash at the published widths, described: spec, weights and the cache
+    of the cell's engine (128 slots, pages of 64; 64 of 512 experts held,
+    one of the router's 8 groups; both layers clamped)."""
+    import dataclasses
+
+    from dynamo_tpu.engine.config import LayerKind, ModelSpec
+    from dynamo_tpu.models import llama
+
+    spec = dataclasses.replace(
+        ModelSpec.tiny_ling3(), vocab_size=vocab, hidden_size=2560,
+        intermediate_size=6144, num_layers=2, num_heads=32, num_kv_heads=32,
+        head_dim=128, dtype="bfloat16", layer_pattern=(1, 0),
+        layer_kinds=(
+            LayerKind(0, 6e6, mixer="latent", head_gate=True),
+            LayerKind(0, 0.0, mixer="kda", gate_bound=-5.0, full_rank=True),
+        ),
+        kv_lora_rank=512, qk_nope_head_dim=128, qk_rope_head_dim=64,
+        v_head_dim=128, rotary_dim=64, kda_heads=32, kda_head_dim=128,
+        num_experts=512, held_experts=(64, 0), num_experts_per_token=8,
+        moe_intermediate_size=768, n_group=8, topk_group=4,
+        first_k_dense=0, expert_clamp=(4.0, 4.0), shared_clamp=(5.0, 7.0))
+
+    def described(tree):
+        return jax.tree.map(
+            lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=v5e),
+            tree)
+
+    params = described(jax.eval_shape(
+        lambda: llama.init_params(spec, jax.random.PRNGKey(0))))
+    k, v = described(jax.eval_shape(
+        lambda: llama.init_cache(spec, 257, 64, state_rows=128)))
+    return spec, params, k, v
+
+
+@pytest.mark.parametrize("rows", [2, 1], ids=["pack-of-2", "single"])
+def test_ling_prefill_program_compiles_for_v5e(v5e, monkeypatch, rows):
+    """The prefill programs of the cell, a layer of each kind at the
+    published widths: 1,024 tokens a row through ``kda_chunk`` at 32 heads
+    from and to the state rows AND the latent page write and
+    ``prefill_latent`` over the kind's pool, under one block table; the
+    group-limited router over 512 experts; every leaf donated."""
+    from dynamo_tpu.models import llama
+
+    _as_on_the_chip(monkeypatch)
+    spec, params, k, v = _ling_layers(v5e)
+    i32 = jnp.int32
+    if rows == 1:
+        lowered = jax.jit(
+            llama.prefill_forward_impl, static_argnums=(0,),
+            donate_argnums=(5, 6),
+        ).lower(spec, params, _rows(v5e, 1024, dtype=i32),
+                _rows(v5e, 160, dtype=i32), _rows(v5e, dtype=i32), k, v,
+                _rows(v5e, dtype=i32))
+    else:
+        lowered = jax.jit(
+            llama.prefill_forward_batch_impl, static_argnums=(0,),
+            donate_argnums=(5, 6),
+        ).lower(spec, params, _rows(v5e, rows, 1024, dtype=i32),
+                _rows(v5e, rows, 160, dtype=i32), _rows(v5e, rows, dtype=i32),
+                k, v, _rows(v5e, rows, dtype=i32))
+    text = lowered.compile().as_text()
+    assert "%kda_chunk" in text and "%prefill_latent" in text
+
+
+def test_ling_decode_program_compiles_for_v5e(v5e, monkeypatch):
+    """The decode burst of the cell, a layer of each kind at the published
+    widths: 128 slots through ``kda_step`` at 32 heads AND ``attn_latent``
+    on the kind's pool (its schedule made once a step) in one program, 8
+    steps, the sampler on the device."""
+    from dynamo_tpu.models import llama
+
+    _as_on_the_chip(monkeypatch)
+    spec, params, k, v = _ling_layers(v5e)
+    B_, i32, f32 = 128, jnp.int32, jnp.float32
+    compiled = jax.jit(
+        llama.decode_steps_impl, static_argnums=(0,),
+        static_argnames=("n_steps", "n_logprobs"), donate_argnums=(5, 6),
+    ).lower(
+        spec, params, _rows(v5e, B_, dtype=i32), _rows(v5e, B_, 160, dtype=i32),
+        _rows(v5e, B_, dtype=i32), k, v, _rows(v5e, B_, dtype=jnp.bool_),
+        _rows(v5e, B_, dtype=f32), _rows(v5e, B_, dtype=i32),
+        _rows(v5e, B_, dtype=f32), _rows(v5e, B_, dtype=jnp.uint32),
+        _rows(v5e, B_, dtype=i32), n_steps=8, n_logprobs=0,
+    ).compile()
+    text = compiled.as_text()
+    assert "%kda_step" in text and "%attn_latent" in text
+    # the kernels update the pools in place: what the program holds beside
+    # its arguments is less than the state pool (258 MiB a KDA layer)
+    state = k.pools[1]
+    assert compiled.memory_analysis().temp_size_in_bytes < (
+        state.size * state.dtype.itemsize // 2)

@@ -193,6 +193,49 @@ def test_pages_states_and_tails_of_one_kind_are_donated(monkeypatch, pallas):
     assert int(k4.rows.owner[0, 0]) == 1
 
 
+@pytest.mark.parametrize("pallas", ["0", "1"], ids=["xla", "kernel"])
+def test_state_tails_and_the_latent_pool_of_one_model_are_donated(
+        monkeypatch, pallas):
+    """KDA and latent kinds in one model (Ling-3.0): the states, the
+    tails, the ONE latent pool (no V side), the counters and the
+    directory ride the donated pair through prefill, packed prefill and a
+    decode burst; every leaf is given up and none is copied out."""
+    monkeypatch.setenv("DYNAMO_PALLAS", pallas)
+    spec = ModelSpec.tiny_ling3()
+    params = llama.init_params(spec, jax.random.PRNGKey(0))
+    k, v = llama.init_cache(spec, NUM_PAGES, PAGE, state_rows=2)
+    leaves = len(jax.tree.leaves((k, v)))
+    # latent pool, states, counts, rows (3) | tails, the V side's counts
+    assert leaves == 1 + 1 + 1 + 3 + 1 + 1
+    bt = jnp.arange(1, 1 + PPS, dtype=jnp.int32)
+    i32 = jnp.int32
+    pf = jax.jit(llama.prefill_forward_impl, static_argnums=(0,),
+                 donate_argnums=(5, 6))
+    _, k2, v2, _ = pf(
+        spec, params, jnp.zeros((8,), i32), bt, jnp.asarray(0, i32), k, v,
+        jnp.asarray(8, i32))
+    assert all(_deleted([k, v]))
+    pb = jax.jit(llama.prefill_forward_batch_impl, static_argnums=(0,),
+                 donate_argnums=(5, 6))
+    _, k3, v3, _ = pb(
+        spec, params, jnp.zeros((2, 8), i32), jnp.stack([bt, bt * 0]),
+        jnp.zeros((2,), i32), k2, v2, jnp.asarray([8, 0], i32))
+    assert all(_deleted([k2, v2]))
+    ds = jax.jit(llama.decode_steps_impl, static_argnums=(0,),
+                 static_argnames=("n_steps", "n_logprobs"),
+                 donate_argnums=(5, 6))
+    z = jnp.zeros((2,), i32)
+    _, k4, v4 = ds(
+        spec, params, z, jnp.stack([bt, bt * 0]), jnp.asarray([9, 1], i32),
+        k3, v3, jnp.asarray([True, False]), jnp.zeros((2,)), z,
+        jnp.ones((2,)), jnp.zeros((2,), jnp.uint32), z, n_steps=2,
+        n_logprobs=0)
+    assert all(_deleted([k3, v3]))
+    assert len(jax.tree.leaves((k4, v4))) == leaves
+    assert int(k4.rows.owner[0, 0]) == 1
+    assert v4.pools[0] is None and k4.pools[0].ndim == 4
+
+
 def test_kv_write_kernel_donates_pools():
     _params, k, v, _bt = _gqa_args()
     kn = jnp.zeros((B, SPEC.num_kv_heads, SPEC.head_dim), jnp.float32)

@@ -1,0 +1,609 @@
+"""Ling-3.0-flash at toy widths on the CPU, against the benchmark's own
+plain reference (``perfbench/references/linear_latent_moe.py``, loaded by
+path: the same module the chip is held to, not a copy): KDA layers (full
+rank, the bounded decay) over state rows AND a latent (MLA) layer gated by
+head over latent pages in ONE model, one block table and one state
+directory, under group-limited sigmoid routing with a clamp a layer.
+Programs, kernels (interpreted) on a kind's pool, the share of an ep
+deployment, and the engine around a sequence that owns both.
+"""
+
+import asyncio
+import dataclasses
+import importlib.util
+import os
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+from dynamo_tpu.engine.config import EngineConfig, LayerKind, ModelSpec
+from dynamo_tpu.engine.core import InferenceEngine
+from dynamo_tpu.models import llama
+from dynamo_tpu.models.family import GqaFamily, get_family
+from dynamo_tpu.ops import attention as attn_ops
+from dynamo_tpu.runtime.context import Context
+
+REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+
+# the reference reads the published keys; the program reads SPEC. Three
+# layers: a dense KDA layer, an expert KDA layer, an expert MLA layer
+CONFIG = {
+    "hidden_size": 64, "num_attention_heads": 4, "num_key_value_heads": 4,
+    "head_dim": 16, "num_hidden_layers": 3, "layers_kept": [0, 1, 2],
+    "layer_group_size": 3, "first_k_dense_replace": 1,
+    "intermediate_size": 96, "moe_intermediate_size": 32,
+    "moe_shared_expert_intermediate_size": 32,
+    "q_lora_rank": None, "kv_lora_rank": 32, "qk_nope_head_dim": 16,
+    "qk_rope_head_dim": 8, "v_head_dim": 16, "rope_theta": 6000000,
+    "rotary_dim": 8, "short_conv_kernel_size": 4,
+    "no_kda_lora": True, "use_kda_lora": False, "kda_safe_gate": True,
+    "kda_lower_bound": -5,
+    "gated_attention_proj_granularity_type": "head_wise",
+    "score_function": "sigmoid", "moe_router_enable_expert_bias": True,
+    "num_experts": 4, "num_experts_per_tok": 4, "n_group": 4,
+    "topk_group": 2, "norm_topk_prob": True, "routed_scaling_factor": 2.5,
+    "expert_swiglu_limit_list": [0, 0.5, 0.75],
+    "share_expert_swiglu_limit_list": [0, 0.6, 0.4],
+    "rms_norm_eps": 1e-6, "vocab_size": 96, "torch_dtype": "float32",
+    "experts": {"published": 16, "held": 4, "first": 4},
+}
+SPEC = ModelSpec.tiny_ling3(held_experts=(4, 4))
+LATENT, KDA_KIND = 0, 1  # the kinds' places in SPEC.layer_kinds
+PAGE, PAGES_PER_SEQ, T, ROWS = 4, 16, 40, 3
+SEED = 13
+
+
+@pytest.fixture(scope="module")
+def ref():
+    spec = importlib.util.spec_from_file_location(
+        "linear_latent_moe",
+        os.path.join(REPO, "perfbench/references/linear_latent_moe.py"))
+    mod = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(mod)
+    return mod
+
+
+@pytest.fixture(scope="module")
+def model(ref):
+    params = llama.init_params(SPEC, jax.random.PRNGKey(SEED))
+    toks = np.asarray(
+        jax.random.randint(jax.random.PRNGKey(1), (3, T), 0, 96))
+    want = np.asarray(ref.forward(
+        CONFIG, SEED, toks, np.tile(np.arange(T), (3, 1))))
+    return params, toks, want
+
+
+def _cache(rows=ROWS, spec=SPEC):
+    return llama.init_cache(
+        spec, 1 + 3 * PAGES_PER_SEQ, PAGE, state_rows=rows)
+
+
+def _table(row):
+    return jnp.arange(PAGES_PER_SEQ, dtype=jnp.int32) + 1 + row * PAGES_PER_SEQ
+
+
+def _close(got, want, tol=3e-4):
+    np.testing.assert_allclose(np.asarray(got), want, rtol=tol, atol=tol)
+
+
+# fresh jits a test: the kernel/XLA choice is read at trace time
+def _programs():
+    return (jax.jit(llama.prefill_forward_impl, static_argnums=(0,)),
+            jax.jit(llama.prefill_forward_batch_impl, static_argnums=(0,)),
+            jax.jit(llama.decode_forward_impl, static_argnums=(0,)),
+            jax.jit(llama.decode_steps_impl, static_argnums=(0,),
+                    static_argnames=("n_steps", "n_logprobs")))
+
+
+def _prefill(pf, params, toks, row, start, n, k, v, bucket=16, spec=SPEC):
+    padded = np.zeros((bucket,), np.int32)
+    padded[:n] = toks[row, start: start + n]
+    logits, k, v, _ = pf(
+        spec, params, jnp.asarray(padded), _table(row),
+        jnp.asarray(start, jnp.int32), k, v, jnp.asarray(n, jnp.int32),
+    )
+    return logits, k, v
+
+
+def test_one_family_one_table_one_directory():
+    """The model is served by the kinds' family: latent pages are a pool
+    of their own beside the state rows, in one page-id space (no V side),
+    under one directory; the latent family is for a model whose every
+    layer is latent."""
+    fam = get_family(SPEC)
+    assert isinstance(fam, GqaFamily) and fam.recurrent
+    assert not SPEC.is_mla and SPEC.has_latent and SPEC.has_recurrent
+    assert ModelSpec.tiny_deepseek().is_mla
+    k, v = _cache()
+    D = SPEC.kv_lora_rank + SPEC.qk_rope_head_dim
+    assert k.pools[LATENT].shape == (1, 1 + 3 * PAGES_PER_SEQ, PAGE, D)
+    assert v.pools[LATENT] is None
+    assert k.pools[KDA_KIND].shape == (2, ROWS + 1, 4, 16, 16)
+    assert k.pools[KDA_KIND].dtype == jnp.float32
+    assert v.pools[KDA_KIND].shape == (2, ROWS + 1, 3, 3, 64)
+    assert llama.page_size_of(k) == PAGE
+    assert k.rows.owner.shape == (1, ROWS + 1)
+    assert SPEC.clamps(0) == (0.0, 0.0) and SPEC.clamps(2) == (0.75, 0.4)
+
+
+@pytest.mark.parametrize("pallas", ["0", "1"], ids=["xla", "kernels"])
+def test_prefill_then_decode_through_state_and_latent_pages(
+        model, monkeypatch, pallas):
+    """A prompt through the prefill program, then teacher-forced decode
+    steps through the state rows of the KDA layers and the latent pages
+    of the MLA layer: every position's logits are the reference's whole
+    forward pass. The other slots are empty or inactive."""
+    monkeypatch.setenv("DYNAMO_PALLAS", pallas)
+    params, toks, want = model
+    pf, _, df, _ = _programs()
+    k, v = _cache()
+    n = 21
+    logits, k, v = _prefill(pf, params, toks, 1, 0, n, k, v, bucket=32)
+    _close(logits, want[1, n - 1])
+    bts = np.zeros((3, PAGES_PER_SEQ), np.int32)
+    bts[2] = np.asarray(_table(1))
+    active = np.array([False, False, True])
+    for j in range(6):
+        fed = np.zeros((3,), np.int32)
+        seq = np.ones((3,), np.int32)
+        fed[2], seq[2] = toks[1, n + j], n + j + 1
+        lg, k, v = df(SPEC, params, jnp.asarray(fed), jnp.asarray(bts),
+                      jnp.asarray(seq), k, v, jnp.asarray(active))
+        _close(lg[2], want[1, n + j])
+    stats = np.asarray(k.rows.stats[0])
+    assert stats[llama.STAT_CLAIMS] == 1 and stats[llama.STAT_MISSING] == 0
+
+
+@pytest.mark.parametrize("chunks", [
+    [(0, 37)], [(0, 16), (16, 16), (32, 5)],
+], ids=["one-shot", "three-chunks"])
+@pytest.mark.parametrize("pallas", ["0", "1"], ids=["xla", "kernels"])
+def test_a_chunked_prompt_resumes_state_and_appends_latents(
+        model, monkeypatch, chunks, pallas):
+    """Chunks at ``start_pos`` > 0 resume the chunkwise form from the
+    state and the convolution tail the chunk before left in the row, and
+    attend over the latent pages the chunks before wrote: the last
+    chunk's logits are the one-shot prefill's and the reference's."""
+    monkeypatch.setenv("DYNAMO_PALLAS", pallas)
+    params, toks, want = model
+    pf = _programs()[0]
+    k, v = _cache()
+    for start, n in chunks:
+        logits, k, v = _prefill(
+            pf, params, toks, 0, start, n, k, v,
+            bucket=64 if n > 16 else 16)
+    _close(logits, want[0, 36])
+    assert int(k.rows.stats[0, llama.STAT_MISSING]) == 0
+
+
+def test_a_pack_of_two_with_an_empty_member(model, monkeypatch):
+    """Rows of different lengths and an empty row in packed calls, one of
+    them a pack of two RESUMED chunks: each row's logits are the
+    reference's, the empty row claims no state and writes no page."""
+    monkeypatch.setenv("DYNAMO_PALLAS", "1")
+    params, toks, want = model
+    pb = _programs()[1]
+    k, v = _cache()
+
+    def pack(members, bucket=16):
+        nonlocal k, v
+        padded = np.zeros((2, bucket), np.int32)
+        bts = np.zeros((2, PAGES_PER_SEQ), np.int32)
+        starts, lens = np.zeros(2, np.int32), np.zeros(2, np.int32)
+        for i, (row, start, n) in enumerate(members):
+            padded[i, :n] = toks[row, start: start + n]
+            if n:
+                bts[i], starts[i], lens[i] = np.asarray(_table(row)), start, n
+        logits, k, v, _ = pb(
+            SPEC, params, jnp.asarray(padded), jnp.asarray(bts),
+            jnp.asarray(starts), k, v, jnp.asarray(lens))
+        return logits
+
+    logits = pack([(0, 0, 13), (0, 0, 0)])
+    _close(logits[0], want[0, 12])
+    owner = np.asarray(k.rows.owner[0])
+    assert sorted(owner[:ROWS]) == [0, 0, 1] and owner[ROWS] == 0
+    # the empty member's table is the trash page's: no other page moved
+    assert not np.asarray(k.pools[LATENT][:, 1 + PAGES_PER_SEQ:]).any()
+    logits = pack([(1, 0, 16), (2, 0, 16)])
+    _close(logits[0], want[1, 15])
+    _close(logits[1], want[2, 15])
+    logits = pack([(1, 16, 9), (2, 16, 16)])  # two resumed chunks
+    _close(logits[0], want[1, 24])
+    _close(logits[1], want[2, 31])
+    stats = np.asarray(k.rows.stats[0])
+    assert stats[llama.STAT_CLAIMS] == 3 and stats[llama.STAT_MISSING] == 0
+
+
+def test_bursts_of_one_and_eight_agree(model, monkeypatch):
+    """Eight greedy steps as one burst and as eight bursts of one: the
+    same tokens, the same state and the same latent pages afterwards (the
+    burst finds its rows once; the latent schedule is made a step)."""
+    monkeypatch.setenv("DYNAMO_PALLAS", "1")
+    params, toks, _ = model
+    pf, _, _, ds = _programs()
+    B = 3
+    bts = np.zeros((B, PAGES_PER_SEQ), np.int32)
+    bts[0], bts[1] = np.asarray(_table(0)), np.asarray(_table(1))
+    active = jnp.asarray([True, True, False])
+    z = jnp.zeros((B,), jnp.int32)
+
+    def run(bursts):
+        k, v = _cache()
+        for row, n in ((0, 9), (1, 14)):
+            _, k, v = _prefill(pf, params, toks, row, 0, n, k, v)
+        fed = np.array([toks[0, 9], toks[1, 14], 0], np.int32)
+        seq = np.array([10, 15, 1], np.int32)
+        out = []
+        for n_steps in bursts:
+            o, k, v = ds(
+                SPEC, params, jnp.asarray(fed), jnp.asarray(bts),
+                jnp.asarray(seq), k, v, active, jnp.zeros((B,)), z,
+                jnp.ones((B,)), jnp.zeros((B,), jnp.uint32), z,
+                n_steps=n_steps, n_logprobs=0)
+            o = np.asarray(o)
+            out.append(o[:2])
+            fed[:2], seq[:2] = o[:2, -1], seq[:2] + n_steps
+        return np.concatenate(out, axis=1), k
+
+    one, k1 = run([1] * 8)
+    eight, k8 = run([8])
+    np.testing.assert_array_equal(one, eight)
+    _close(k8.pools[KDA_KIND][:, :2], np.asarray(k1.pools[KDA_KIND][:, :2]),
+           tol=1e-5)
+    _close(k8.pools[LATENT][:, 1:], np.asarray(k1.pools[LATENT][:, 1:]),
+           tol=1e-5)
+
+
+@pytest.mark.parametrize("pallas", ["0", "1"], ids=["xla", "kernels"])
+def test_an_inactive_slot_and_a_released_row_touch_nothing(
+        model, monkeypatch, pallas):
+    """A decode step with one live slot: the other sequence's row (its
+    slot inactive) and a row whose owner was released keep their state
+    and tail to the bit; so do the latent pages of both."""
+    monkeypatch.setenv("DYNAMO_PALLAS", pallas)
+    params, toks, _ = model
+    pf, _, df, _ = _programs()
+    k, v = _cache()
+    for row, n in ((0, 9), (1, 14), (2, 11)):
+        _, k, v = _prefill(pf, params, toks, row, 0, n, k, v)
+    k = llama.release_state_rows(k, jnp.asarray(
+        [int(_table(2)[0]), -1], jnp.int32))
+    assert list(np.asarray(k.rows.owner[0])) == [1, 1 + PAGES_PER_SEQ, 0, 0]
+    before = jax.tree.map(np.asarray, (k, v))
+    bts = np.stack([np.asarray(_table(r)) for r in range(3)])
+    lg, k, v = df(
+        SPEC, params, jnp.asarray(toks[:, 20]), jnp.asarray(bts),
+        jnp.asarray([10, 15, 12], jnp.int32), k, v,
+        jnp.asarray([True, False, False]))
+    for side, was in zip((k, v), before):
+        now = np.asarray(side.pools[KDA_KIND])
+        assert not np.array_equal(now[:, 0], was.pools[KDA_KIND][:, 0])
+        np.testing.assert_array_equal(now[:, 1:3], was.pools[KDA_KIND][:, 1:3])
+    pages, old = np.asarray(k.pools[LATENT]), before[0].pools[LATENT]
+    assert not np.array_equal(
+        pages[:, 1: 1 + PAGES_PER_SEQ], old[:, 1: 1 + PAGES_PER_SEQ])
+    np.testing.assert_array_equal(
+        pages[:, 1 + PAGES_PER_SEQ:], old[:, 1 + PAGES_PER_SEQ:])
+    assert int(k.rows.stats[0, llama.STAT_MISSING]) == 0
+
+
+@pytest.mark.parametrize("pallas", ["0", "1"], ids=["xla", "kernel"])
+@pytest.mark.parametrize("T_", [16, 150], ids=["one-block", "ragged"])
+def test_the_chunk_form_is_the_recurrence_at_both_ends_of_the_bound(
+        monkeypatch, pallas, T_):
+    """The bounded gate puts a token's log decay in (-5, 0). Channels at
+    both ends of it in one call (-4.99 beside -1e-5 a token, what
+    ``kda_lower_bound * sigmoid`` gives saturated either way): the
+    chunkwise form, whose sub-block inverse decay holds e^80 over 16
+    tokens, is the recurrence a token at a time, outputs and state."""
+    monkeypatch.setenv("DYNAMO_PALLAS", pallas)
+    N, H, D = 2, 4, 16
+    ks = jax.random.split(jax.random.PRNGKey(T_), 6)
+    q = jax.random.normal(ks[0], (N, T_, H, D))
+    k = jax.random.normal(ks[1], (N, T_, H, D))
+    q = q / jnp.linalg.norm(q, axis=-1, keepdims=True) * D ** -0.5
+    k = k / jnp.linalg.norm(k, axis=-1, keepdims=True)
+    v = jax.random.normal(ks[2], (N, T_, H, D))
+    z = 12.0 * jax.random.normal(ks[3], (N, T_, H, D))
+    g = -5.0 * jax.nn.sigmoid(z)
+    assert float(g.min()) < -4.99 and float(g.max()) > -1e-4
+    beta = jax.nn.sigmoid(2 * jax.random.normal(ks[4], (N, T_, H)))
+    s0 = jax.random.normal(ks[5], (N, H, D, D))
+    pool = jnp.concatenate([s0, s0[:1]])[None]
+    o, new = attn_ops.kda_chunk_prefill(
+        q, k, v, g, beta, pool, jnp.arange(N, dtype=jnp.int32),
+        jnp.zeros((N,), bool), layer=0)
+    for n in range(N):
+        want_o, want_s = attn_ops.kda_recurrence(
+            q[n], k[n], v[n], g[n], beta[n], s0[n])
+        _close(o[n], np.asarray(want_o), tol=1e-4)
+        _close(new[0, n], np.asarray(want_s), tol=1e-4)
+
+
+def test_kernels_equal_their_xla_twins_on_a_kinds_pool(monkeypatch):
+    """Both latent kernels and both KDA kernels, interpreted, against the
+    XLA forms that serve off the chip, through the programs of a model
+    with TWO latent layers: each reads and writes its own layer of the
+    kind's pool (``layer=lj``, not the model's layer index) under the
+    kinds' block table. Logits, the latent pool and the states agree."""
+    spec = dataclasses.replace(
+        SPEC, num_layers=4, layer_pattern=(1, 0, 1, 0),
+        expert_clamp=(0.0, 0.5, 0.0, 0.75), shared_clamp=(0.0, 0.6, 0.0, 0.4))
+    params = llama.init_params(spec, jax.random.PRNGKey(SEED))
+    toks = np.asarray(jax.random.randint(jax.random.PRNGKey(2), (3, T), 0, 96))
+    outs = {}
+    for pallas in ("0", "1"):
+        monkeypatch.setenv("DYNAMO_PALLAS", pallas)
+        pf, _, df, _ = _programs()
+        k, v = _cache(spec=spec)
+        assert k.pools[LATENT].shape[0] == 2
+        got = []
+        for start, n in ((0, 16), (16, 11)):
+            lg, k, v = _prefill(
+                pf, params, toks, 1, start, n, k, v, spec=spec)
+            got.append(np.asarray(lg))
+        bts = np.zeros((3, PAGES_PER_SEQ), np.int32)
+        bts[0] = np.asarray(_table(1))
+        for j in range(3):
+            lg, k, v = df(
+                spec, params, jnp.asarray([toks[1, 27 + j], 0, 0]),
+                jnp.asarray(bts), jnp.asarray([28 + j, 1, 1], jnp.int32),
+                k, v, jnp.asarray([True, False, False]))
+            got.append(np.asarray(lg[0]))
+        outs[pallas] = (got, np.asarray(k.pools[LATENT][:, 1:]),
+                        np.asarray(k.pools[KDA_KIND][:, :1]))
+    for a, b in zip(outs["1"][0], outs["0"][0]):
+        _close(a, b, tol=2e-4)
+    _close(outs["1"][1], outs["0"][1], tol=1e-5)
+    _close(outs["1"][2], outs["0"][2], tol=1e-4)
+    # both layers of the kind's pool hold rows, and they differ
+    pool = outs["1"][1]
+    assert np.abs(pool[0]).max() > 0 and np.abs(pool[1]).max() > 0
+    assert not np.allclose(pool[0], pool[1])
+    want = llama.reference_forward(spec, params, jnp.asarray(toks[1, :30]))
+    _close(outs["1"][0][-1], np.asarray(want[29]))
+
+
+def _kind(spec, ki, **kw):
+    kinds = list(spec.layer_kinds)
+    kinds[ki] = dataclasses.replace(kinds[ki], **kw)
+    return dataclasses.replace(spec, layer_kinds=tuple(kinds))
+
+
+def _low_rank(w, r):
+    u, s, vt = np.linalg.svd(np.asarray(w, np.float64), full_matrices=False)
+    return jnp.asarray((u[:, :r] * s[:r]) @ vt[:r], w.dtype)
+
+
+def _with_layer(params, li, **kw):
+    layers = list(params["layers"])
+    layers[li] = {**layers[li], **kw}
+    return dict(params, layers=layers)
+
+
+# what the published keys select, each changed alone: (spec, params) of a
+# program that differs from the reference in that one thing
+MECHANISMS = {
+    "gate-bound": lambda s, p: (_kind(s, KDA_KIND, gate_bound=-4.0), p),
+    "full-rank-decay": lambda s, p: (
+        s, _with_layer(p, 1, w_f=_low_rank(p["layers"][1]["w_f"], 16))),
+    "head-wise-gate": lambda s, p: (s, _with_layer(
+        p, 2, w_gate_head=jnp.zeros_like(p["layers"][2]["w_gate_head"]))),
+    "expert-clamp": lambda s, p: (
+        dataclasses.replace(s, expert_clamp=(0.0, 0.5, 0.0)), p),
+    "shared-clamp": lambda s, p: (
+        dataclasses.replace(s, shared_clamp=(0.0, 0.0, 0.4)), p),
+    "topk-group": lambda s, p: (dataclasses.replace(s, topk_group=3), p),
+    "routed-scaling-factor": lambda s, p: (
+        dataclasses.replace(s, routed_scaling_factor=1.0), p),
+}
+
+
+@pytest.mark.parametrize("name", list(MECHANISMS))
+def test_every_published_mechanism_moves_the_logits(model, name):
+    """The program as the published keys select it is the reference's to
+    3e-4; with any one mechanism changed (the decay's bound, a rank-16
+    decay projection, a constant gate a head, a layer's expert clamp or
+    its shared expert's lifted, one more routing group, the routed
+    scale) it is not, by a hundred times that: the comparison sees each."""
+    params, toks, want = model
+    got = llama.reference_forward(SPEC, params, jnp.asarray(toks[0]))
+    _close(got, want[0])
+    spec, changed = MECHANISMS[name](SPEC, params)
+    off = llama.reference_forward(spec, changed, jnp.asarray(toks[0]))
+    assert float(np.abs(np.asarray(off) - want[0]).max()) > 3e-2, name
+
+
+def test_the_shares_add_up(ref):
+    """Four chips, a routing group of four experts each, the shared expert
+    counted once, make the uncut expert layer: the program's shares
+    (``held_experts`` = group g of the router's four, its own clamp) add
+    up to what the reference gives with all 16 experts held."""
+    cfg = dict(CONFIG, experts={"published": 16, "held": 16, "first": 0})
+    w = ref.Weights(cfg, SEED)
+    m = w.m
+    toks = np.asarray(jax.random.randint(jax.random.PRNGKey(4), (2, 10), 0, 96))
+    x = ref._embed_rows(w.embed(), toks, quant=None)
+    for li in (1, 2):  # a KDA layer's experts, then the MLA layer's
+        lw = w.layer(li)
+        kw = dict(
+            topk=m["topk"], groups=m["groups"], topk_group=m["topk_group"],
+            scaling=m["scaling"], norm_topk=m["norm_topk"],
+            clamp=m["clamp"][li], shared_clamp=m["shared_clamp"][li],
+            eps=m["eps"], quant=None)
+        ex = {k: lw[k] for k in ref.EXPERTS}
+        whole = np.asarray(ref._experts(x, ex, first=0, held=16, **kw) - x)
+        alike = np.asarray(ref._experts(
+            x, dict(ex, e_gate=ex["e_gate"][:0]), first=0, held=0, **kw) - x)
+        h = np.asarray(ref._rms(x, m["eps"])).reshape(20, -1)
+        shares = []
+        for g in range(4):
+            spec = dataclasses.replace(SPEC, held_experts=(4, 4 * g))
+            lp = {
+                "moe": {
+                    "router": lw["router"].astype(jnp.float32),
+                    "score_bias": lw["score_bias"],
+                    "w_gate": lw["e_gate"][4 * g: 4 * g + 4],
+                    "w_up": lw["e_up"][4 * g: 4 * g + 4],
+                    "w_down": lw["e_down"][4 * g: 4 * g + 4],
+                },
+                "shared": {"w_gate": lw["s_gate"], "w_up": lw["s_up"],
+                           "w_down": lw["s_down"]},
+            }
+            shares.append(np.asarray(
+                llama._ffn(spec, lp, jnp.asarray(h), li=li)
+            ).reshape(2, 10, -1))
+            # a chip's share is the reference's share
+            part = np.asarray(ref._experts(
+                x, dict(ex, **{k: ex[k][4 * g: 4 * g + 4]
+                               for k in ("e_gate", "e_up", "e_down")}),
+                first=4 * g, held=4, **kw) - x)
+            _close(shares[-1], part, tol=1e-4)
+        # (a group that no token's top two groups hold adds nothing)
+        assert sum(np.abs(s - alike).max() > 1e-3 for s in shares) >= 3
+        _close(alike + sum(s - alike for s in shares), whole, tol=1e-4)
+
+
+# ------------------------------------------------------------- the engine
+
+
+def _engine(**kw):
+    base = dict(
+        page_size=PAGE, num_pages=64, max_pages_per_seq=PAGES_PER_SEQ,
+        max_decode_slots=2, prefill_buckets=(16,), max_prefill_chunk_tokens=16,
+        decode_steps_per_dispatch=4, seed=SEED,
+    )
+    base.update(kw)
+    return InferenceEngine(SPEC, EngineConfig(**base))
+
+
+async def _greedy(engine, prompt, n):
+    out = []
+    async for item in engine.generate(
+        {"token_ids": list(prompt), "sampling": {"temperature": 0.0},
+         "stop_conditions": {"max_tokens": n, "ignore_eos": True}},
+        Context(),
+    ):
+        assert item.get("finish_reason") != "error", item
+        out.extend(item.get("token_ids") or [])
+    return out
+
+
+_jit_reference = jax.jit(llama.reference_forward, static_argnums=0)
+
+
+def _greedy_reference(params, prompt, n):
+    seq = list(prompt)
+    for _ in range(n):
+        padded = np.zeros((64,), np.int32)
+        padded[: len(seq)] = seq
+        lg = _jit_reference(SPEC, params, jnp.asarray(padded))
+        seq.append(int(np.argmax(np.asarray(lg[len(seq) - 1]))))
+    return seq[len(prompt):]
+
+
+async def test_serves_through_the_engine_and_counts(monkeypatch):
+    """The toy model through the REAL engine (scheduler, a prompt of two
+    chunks, all four kernels interpreted in bursts): the greedy stream is
+    the whole forward pass's own; nothing is reused under a prefix; the
+    rows go back; the counters read what hand arithmetic gives, by kind
+    of cache."""
+    monkeypatch.setenv("DYNAMO_PALLAS", "1")
+    engine = _engine()
+    fam = engine.fam
+    assert isinstance(fam, GqaFamily) and fam.recurrent
+    assert not fam.supports_prefix_reuse and not engine.allocator.prefix_cache
+    for gate in ("ring_prefill", "spec_decode", "mesh", "page_transfer",
+                 "multimodal"):
+        assert not getattr(fam, f"supports_{gate}"), gate
+    assert engine._prefill_walks == {"latent": 0}
+    assert engine._kv_chunk_pages is not None
+    prompt = [int(t) for t in np.arange(7, 7 + 21) % 96]  # two chunks
+    want = _greedy_reference(engine.params, prompt, 6)
+    assert await _greedy(engine, prompt, 6) == want
+    assert await _greedy(engine, prompt, 6) == want
+    assert engine.allocator._hash_page == {}
+    assert engine.allocator.active_pages == 0
+    # two prompts of 16 + 5 tokens: a block of 64 holds each chunk, the
+    # second chunk of each resumes a row; the latent walk ran four times
+    assert engine.kda["prefill_blocks"] == 4
+    assert engine.kda["rows_resumed"] == 2
+    assert engine.kda["decode_rows"] % 4 == 0 and engine.kda["decode_rows"] >= 16
+    assert engine.prefill_kv["dispatches.latent"] == 4
+    assert engine.prefill_kv["kernel_calls.latent"] == 4
+    assert engine.prefill_kv["blocks_visited.latent"] >= 4
+    assert engine.decode_kv["pages_live"] > 0
+    await engine.close()
+    engine._metrics_publishes = 0
+    for _ in range(34):  # two refreshes bring the device's counters over
+        engine._publish_metrics()
+    c = engine.state_counters()
+    assert c == {"rows": 2, "rows_live": 0, "claims": 2, "row_missing": 0}
+    snap = engine.profile_snapshot()
+    assert snap["kda.rows_resumed"]["calls"] == 2
+    assert snap["prefill_kv.blocks_visited.latent"]["calls"] >= 4
+    m = engine.moe_counters()
+    # two expert layers, 2 x 21 prompt tokens, top-4 of 16 in 2 of 4 groups
+    assert m["layers"] == 2 and m["prefill.assignments"] == 2 * 2 * 21 * 4
+    assert 0 < m["prefill.assignments_held"] < m["prefill.assignments"]
+    assert m["prefill.assignments_held"] == sum(
+        m[f"prefill.expert.{i}"] for i in range(4))
+
+
+@pytest.mark.parametrize("pipeline", [False, True], ids=["plain", "pipelined"])
+async def test_streams_share_the_engine(monkeypatch, pipeline):
+    """Three prompts on two slots, one of them chunked behind running
+    bursts: every stream is what it gets alone, pipelined or not, rows
+    are claimed and freed as slots turn over, none goes missing."""
+    monkeypatch.setenv("DYNAMO_PALLAS", "1")
+    prompts = [[3, 9, 27], [8, 64, 32, 5],
+               [int(t) for t in np.arange(5, 5 + 37) * 7 % 96]]
+    engine = _engine(pipeline_decode=pipeline, async_admissions=True)
+    want = [_greedy_reference(engine.params, p, n)
+            for p, n in zip(prompts, (12, 9, 6))]
+    outs = await asyncio.gather(*(
+        _greedy(engine, p, n) for p, n in zip(prompts, (12, 9, 6))))
+    assert outs == want
+    assert engine.allocator.active_pages == 0
+    await engine.close()
+    assert int(engine.k_pages.rows.stats[0, llama.STAT_MISSING]) == 0
+    assert int(engine.k_pages.rows.stats[0, llama.STAT_CLAIMS]) == 3
+
+
+def test_the_memory_guard_charges_both_kinds():
+    """A model with KDA and latent kinds is charged the chunkwise form's
+    operands AND the latent walk's scores: more than either alone, so a
+    pack halves where a model of one of them keeps it; the latent kind
+    may stand first among the kinds, a state-only kind may not."""
+    wide = dict(hidden_size=2560, num_heads=32, head_dim=128, kda_heads=32,
+                kda_head_dim=128, kv_lora_rank=512, qk_nope_head_dim=128,
+                qk_rope_head_dim=64, v_head_dim=128, rotary_dim=64)
+    spec = ModelSpec.tiny_ling3(**wide)
+    cfg = EngineConfig(
+        page_size=64, num_pages=4096, max_pages_per_seq=160,
+        max_decode_slots=128, prefill_buckets=(1024,), prefill_pack_size=2,
+        max_prefill_chunk_tokens=1024)
+    only_kda = dataclasses.replace(
+        spec, layer_kinds=(LayerKind(4, 1e4), spec.layer_kinds[1]),
+        kv_lora_rank=0)
+    assert cfg.prefill_shapes(spec, 4 * 2**30) == {1024: 2}
+    free = 1000 * 2**20
+    assert cfg.prefill_shapes(only_kda, free) == {1024: 2}
+    assert cfg.prefill_shapes(spec, free) == {1024: 1}
+    with pytest.raises(ValueError, match="paged kind first"):
+        ModelSpec.tiny_ling3(layer_kinds=SPEC.layer_kinds[::-1],
+                             layer_pattern=(0, 0, 1))
+
+
+def test_no_tensor_names_are_invented(tmp_path):
+    """The catalog gives this model's config and no checkpoint names: the
+    loader refuses to save or load it rather than guess."""
+    from dynamo_tpu.models import loader
+
+    with pytest.raises(NotImplementedError, match="drawn weights only"):
+        loader.hf_config_from_spec(SPEC)
+    with pytest.raises(NotImplementedError, match="drawn weights only"):
+        loader.save_params(SPEC, {}, str(tmp_path))

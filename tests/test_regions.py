@@ -45,11 +45,13 @@ def _spec(family: str) -> ModelSpec:
         return ModelSpec.tiny()
     if family == "kda":
         return ModelSpec.tiny_solar(held_experts=(4, 2))
+    if family == "linlat":  # KDA and latent kinds in one model
+        return ModelSpec.tiny_ling3(held_experts=(4, 4))
     mod = {"mimo": "test_mimo", "latent": "test_joyai"}[family]
     return importlib.import_module(mod).SPEC
 
 
-FAMILIES = ("dense", "mimo", "latent", "kda")
+FAMILIES = ("dense", "mimo", "latent", "kda", "linlat")
 PAGE, PAGES, B, T = 4, 8, 4, 16
 
 
@@ -251,10 +253,19 @@ def test_every_instruction_resolves_and_the_unnamed_are_bookkeeping(
         "latent": {"latent_q", "latent_kv", "moe_shared", "moe_combine"},
         "kda": {"kda_proj", "kda_conv", "kda_gates", "state_rows",
                 "moe_shared"},
+        # one program holds KDA's names as Solar's give them AND the
+        # latent layer's as JoyAI's do
+        "linlat": {"kda_proj", "kda_conv", "kda_gates", "state_rows",
+                   "latent_q", "latent_kv", "moe_shared", "moe_route",
+                   "moe_combine", "mlp"},
     }[family]
-    if family == "latent" and program == "decode":
+    if family in ("latent", "linlat") and program == "decode":
         want |= {"latent_absorb", "attn_latent"}
-    if family == "kda":
+    if family == "linlat" and program == "decode":
+        want |= {"latent_schedule"}
+    if family == "linlat" and program == "prefill":
+        want |= {"prefill_latent"}
+    if family in ("kda", "linlat"):
         # the kernel forms a block's operands itself: ``kda_chunk`` alone
         # (``kda_chunk_operands`` is the pad of a ragged row, and XLA's
         # half off the chip: the test below)

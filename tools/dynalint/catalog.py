@@ -147,7 +147,8 @@ PROFILE_COUNTERS: dict[str, str] = {
     "prefill_kv.blocks_visited.window": "the same on window layers",
     "prefill_kv.blocks_table.window": "and a whole-table walk's there",
     "prefill_kv.blocks_visited.latent": "the same for the latent "
-                                        "family's walk",
+                                        "family's walk, and for a latent "
+                                        "kind's beside other kinds",
     "prefill_kv.blocks_table.latent": "and a whole-table walk's there",
     "prefill_kv.dispatches.latent": "prefills, packs and verifies "
                                     "dispatched on the latent family",
@@ -166,6 +167,9 @@ PROFILE_COUNTERS: dict[str, str] = {
     "kda.prefill_blocks": "blocks of 64 tokens with a real token that "
                           "kda_chunk carried a state through, over the "
                           "dispatched prefills; a layer's worth",
+    "kda.rows_resumed": "rows of prefill programs that continued a KDA "
+                        "state (start_pos > 0): a chunk behind a prompt's "
+                        "first",
     # a model with SSD (Mamba-2) layers only
     "ssd.decode_rows": "state rows an ssd_step call updated (live slots), "
                        "over the dispatched bursts' steps; a layer's worth",

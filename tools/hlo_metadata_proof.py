@@ -4,9 +4,9 @@
     python3 tools/hlo_metadata_proof.py --parent <checkout> [--change <checkout>]
 
 For each configuration of ``perfbench/configs`` that stands for a family
-(dense GQA, MiMo, JoyAI, Solar) it compiles, in a process a checkout, the
-single-prompt prefill program of the largest bucket and the longest decode
-burst at the configuration's real widths for a DESCRIBED v5e chip (no chip
+(dense GQA, MiMo, JoyAI, Solar, Falcon-H1) it compiles, in a process a
+checkout, the single-prompt and the packed prefill program of the largest
+bucket and the longest decode burst at the configuration's real widths for a DESCRIBED v5e chip (no chip
 needed; ``jax.default_backend`` is told "tpu" so the programs take the
 chip's paths: the Mosaic kernels, the padded pools, megablox). From the
 optimised HLO text it strips every ``metadata={...}`` and the module's
@@ -33,7 +33,7 @@ import sys
 import tempfile
 
 CONFIGS = ("mistral-7b-v0.3", "mimo-v2.5", "joyai-llm-flash",
-           "solar-open2-250b")
+           "solar-open2-250b", "falcon-h1-34b")
 # an instruction's metadata, and the module's tables of the files,
 # functions, lines and stack frames that the metadata points into
 _METADATA = re.compile(r",? ?metadata=\{[^{}]*\}")
@@ -45,7 +45,7 @@ def strip(text: str) -> str:
     return _TABLES.sub("", _METADATA.sub("", text))
 
 
-def one(tree: str, out: str) -> None:
+def one(tree: str, out: str, configs=CONFIGS) -> None:
     os.environ.setdefault("JAX_PLATFORMS", "cpu")
     os.environ.setdefault("TPU_LOG_DIR", "disabled")
     os.environ["DYNAMO_PALLAS"] = "1"
@@ -77,7 +77,7 @@ def one(tree: str, out: str) -> None:
         return jax.ShapeDtypeStruct(shape, dtype, sharding=chip)
 
     os.makedirs(out, exist_ok=True)
-    for name in CONFIGS:
+    for name in configs:
         with open(os.path.join(tree, "perfbench", "configs", name + ".json")) as f:
             config = json.load(f)
         spec = stk.model_spec(config)
@@ -91,7 +91,7 @@ def one(tree: str, out: str) -> None:
             spec, cfg.num_pages + 1, cfg.page_size, **rows)))
         i32, f32 = jnp.int32, jnp.float32
         T, B, P = max(cfg.prefill_buckets), cfg.max_decode_slots, cfg.max_pages_per_seq
-        n = cfg.decode_steps_per_dispatch
+        n, N = cfg.decode_steps_per_dispatch, cfg.prefill_pack_size
         burst = (arr((B,), bool), arr((B,), f32), arr((B,), i32),
                  arr((B,), f32), arr((B,), jnp.uint32), arr((B,), i32))
         if spec.is_mla:
@@ -100,6 +100,9 @@ def one(tree: str, out: str) -> None:
                 "prefill": m.prefill_forward.lower(
                     spec, params, arr((T,), i32), arr((P,), i32),
                     arr((), i32), k, arr((), i32), mesh=None, counts=v),
+                "packed": m.prefill_forward_batch.lower(
+                    spec, params, arr((N, T), i32), arr((N, P), i32),
+                    arr((N,), i32), k, arr((N,), i32), mesh=None, counts=v),
                 "decode": m.decode_steps.lower(
                     spec, params, arr((B,), i32), arr((B, P), i32),
                     arr((B,), i32), k, *burst, n_steps=n, n_logprobs=0,
@@ -111,6 +114,9 @@ def one(tree: str, out: str) -> None:
                 "prefill": m.prefill_forward.lower(
                     spec, params, arr((T,), i32), arr((P,), i32),
                     arr((), i32), k, v, arr((), i32), mesh=None),
+                "packed": m.prefill_forward_batch.lower(
+                    spec, params, arr((N, T), i32), arr((N, P), i32),
+                    arr((N,), i32), k, v, arr((N,), i32), mesh=None),
                 "decode": m.decode_steps.lower(
                     spec, params, arr((B,), i32), arr((B, P), i32),
                     arr((B,), i32), k, v, *burst, n_steps=n, n_logprobs=0,
@@ -132,9 +138,11 @@ def main(argv=None) -> int:
         os.path.dirname(os.path.abspath(__file__))))
     ap.add_argument("--one", nargs=2, metavar=("TREE", "OUT"))
     ap.add_argument("--keep", default=None, help="keep the stripped HLO here")
+    ap.add_argument("--configs", default=",".join(CONFIGS),
+                    help="the configurations to compile, comma-separated")
     args = ap.parse_args(argv)
     if args.one:
-        one(*args.one)
+        one(*args.one, configs=args.configs.split(","))
         return 0
     if not args.parent:
         ap.error("--parent <checkout> is required")
@@ -152,7 +160,7 @@ def main(argv=None) -> int:
                 ignore=shutil.ignore_patterns("__pycache__"))
         subprocess.run(
             [sys.executable, os.path.abspath(__file__), "--one", at,
-             os.path.join(work, side)],
+             os.path.join(work, side), "--configs", args.configs],
             check=True, cwd=at,
         )
     shutil.rmtree(at, ignore_errors=True)
