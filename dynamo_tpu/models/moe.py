@@ -17,6 +17,20 @@ path (``_held_experts``): ``ModelSpec.held_experts`` for a process that is
 one share of a larger deployment, and an "ep" mesh axis, under which the
 same function runs in a ``shard_map`` over the expert (and "tp") shards
 and the shares meet in a ``psum``.
+
+The layer's index arithmetic is compares, selects and reductions over
+dense ``[T, E]`` / ``[n, T*k]`` arrays (``_top_k``, ``_two_best``,
+``_sizes``), never ``jax.lax.top_k``, ``jnp.take_along_axis`` or
+``jnp.bincount``: on a v5e those lower to a sort, a gather along the
+minor axis and an integer scatter-add, the three things the chip runs
+worst. Beside the program, us a layer call of 128 / 1,024 tokens (my
+chip runs, PR 42): ``route`` over 256 experts, top-8, 17.9 / 125 with
+``top_k`` + ``take_along_axis`` and 9.9 / 19.0 as it stands; over 512
+experts in 8 groups of which 4 are kept 122 / 227 against 12.5 / 36.6;
+the sizes of 16 groups out of 1,024 / 8,192 / 16,384 assignments 10.4 /
+73.8 / 146 by ``bincount`` and 1.3 / 1.7 / 2.4 by a compare and a sum.
+The integers and the floats are the parent's, bit for bit, on the chip
+as on the CPU.
 """
 
 from __future__ import annotations
@@ -103,6 +117,47 @@ _EXPERT_SPECS = {
 }
 
 
+def _top_k(x: jax.Array, k: int, of: jax.Array | None = None):
+    """``jax.lax.top_k(x, k)`` over the last axis as k rounds of a max and
+    the index of its first occurrence, the winner masked out after each:
+    the same indices in the same order, ties included (lower index
+    first), for finite ``x``. -> (values [..., k], indices [..., k]
+    int32); with ``of`` the values are ``of``'s at the indices (one
+    element and zeros summed, so ``of``'s own bits) and not ``x``'s. The
+    rounds are unrolled: as a ``fori_loop`` they serve as fast in a
+    shorter program, but the profiler takes 18 s longer to stop on a
+    traced JoyAI run, which then passes 360 s (my chip runs, PR 42)."""
+    vals, idx = [], []
+    for _ in range(k):
+        m, i, hit = _first_max(x)
+        if of is not None:
+            m = jnp.sum(jnp.where(hit, of, 0.0), axis=-1, keepdims=True)
+        vals.append(m)
+        idx.append(i)
+        x = jnp.where(hit, -jnp.inf, x)
+    # the barrier keeps the values an array of their own: a sum over the
+    # concatenate (the weights' normaliser) the chip's compiler turns into
+    # adds in an order of its own, 3-4 ulp off ``top_k``'s (PR 42)
+    return jax.lax.optimization_barrier(
+        (jnp.concatenate(vals, axis=-1), jnp.concatenate(idx, axis=-1)))
+
+
+def _first_max(x: jax.Array):
+    """(the max over the last axis, the index of its first occurrence,
+    both with that axis kept, and where that one element is)."""
+    iota = jax.lax.broadcasted_iota(jnp.int32, x.shape, x.ndim - 1)
+    m = jnp.max(x, axis=-1, keepdims=True)
+    i = jnp.min(jnp.where(x == m, iota, x.shape[-1]), axis=-1, keepdims=True)
+    return m, i, iota == i
+
+
+def _two_best(x: jax.Array) -> jax.Array:
+    """``jax.lax.top_k(x, 2)[0].sum(-1)``: the max over the last axis plus
+    the max of the rest (a tie counts twice)."""
+    best, _i, hit = _first_max(x)
+    return best[..., 0] + jnp.max(jnp.where(hit, -jnp.inf, x), axis=-1)
+
+
 @jax.named_scope(SCOPE_ROUTE)
 def route(spec: ModelSpec, lp: Params, x: jax.Array):
     """x: [T, d] -> (expert ids [T, k] int32, weights [T, k] f32), over
@@ -120,18 +175,13 @@ def route(spec: ModelSpec, lp: Params, x: jax.Array):
         scores = jax.nn.sigmoid(router_logits)  # [T, E]
         choice = scores + lp["score_bias"]
         if spec.n_group > 1:
-            gsz = E // spec.n_group
-            grouped = choice.reshape(T, spec.n_group, gsz)
-            group_scores = jax.lax.top_k(grouped, 2)[0].sum(-1)  # [T, G]
-            _gv, gidx = jax.lax.top_k(group_scores, spec.topk_group)
-            gmask = jax.nn.one_hot(
-                gidx, spec.n_group, dtype=jnp.float32
-            ).sum(axis=1)  # [T, G]
-            choice = jnp.where(
-                jnp.repeat(gmask, gsz, axis=-1) > 0, choice, 0.0
-            )
-        _cv, topi = jax.lax.top_k(choice, k)  # [T, k]
-        topv = jnp.take_along_axis(scores, topi, axis=1)
+            G, gsz = spec.n_group, E // spec.n_group
+            group_scores = _two_best(choice.reshape(T, G, gsz))  # [T, G]
+            _gv, gidx = _top_k(group_scores, spec.topk_group)  # [T, kept]
+            kept = jnp.any(
+                gidx[:, :, None] == jnp.arange(G, dtype=jnp.int32), axis=1)
+            choice = jnp.where(jnp.repeat(kept, gsz, axis=-1), choice, 0.0)
+        topv, topi = _top_k(choice, k, of=scores)  # [T, k]
         if spec.norm_topk_prob:
             topv = topv / (topv.sum(axis=-1, keepdims=True) + 1e-20)
         topv = topv * spec.routed_scaling_factor
@@ -139,9 +189,9 @@ def route(spec: ModelSpec, lp: Params, x: jax.Array):
         # softmax-all + top-k renormalize == softmax over the top-k
         # logits (HF gpt-oss GptOssTopKRouter): same selection/weights
         probs = jax.nn.softmax(router_logits, axis=-1)  # [T, E]
-        topv, topi = jax.lax.top_k(probs, k)  # [T, k]
+        topv, topi = _top_k(probs, k)  # [T, k]
         topv = topv / jnp.maximum(topv.sum(axis=-1, keepdims=True), 1e-9)
-    return topi.astype(jnp.int32), topv
+    return topi, topv
 
 
 @jax.named_scope(SCOPE_EXPERTS)
@@ -169,7 +219,7 @@ def _held_experts(
         # to. A second sort of 8,192 integers is 7 us on a v5e where the
         # scatter of an iota is 39 (16,384: 9 and 77; my chip run, PR 38)
         inv = jnp.argsort(order)
-        sizes = jnp.bincount(slot, length=n + 1)[:n].astype(jnp.int32)
+        sizes = _sizes(slot, n)
         eid = jnp.minimum(slot[order], n - 1)
         rows = x[order // k]  # [T*k, d]: the held assignments lead, by expert
     # rows past sum(sizes) belong to no group; what the grouped products
@@ -315,7 +365,9 @@ def moe_mlp(
         return y
     # a row that is not counted routes to no expert for the count
     with jax.named_scope(SCOPE_MOE_COUNT):
-        sizes = _count(jnp.where(counted[:, None], topi, -1), first, n_held)
+        sizes = _sizes(
+            _slots(jnp.where(counted[:, None], topi, -1), first, n_held),
+            n_held)
         total = (jnp.sum(counted) * topi.shape[1]).astype(jnp.int32)
         touched = jnp.sum(sizes > 0).astype(jnp.int32)
         return y, jnp.concatenate([sizes, total[None], touched[None]])
@@ -328,7 +380,9 @@ def _slots(topi: jax.Array, first, n: int) -> jax.Array:
     return jnp.where((local >= 0) & (local < n), local, n)
 
 
-def _count(topi: jax.Array, first: int, n: int) -> jax.Array:
-    """Assignments in ``topi`` to each of experts first .. first + n."""
-    return jnp.bincount(_slots(topi, first, n), length=n + 1)[:n].astype(
-        jnp.int32)
+def _sizes(slot: jax.Array, n: int) -> jax.Array:
+    """How many of ``slot`` ([m], values 0 .. n) are each of 0 .. n - 1,
+    int32 [n]: ``bincount`` as a compare against every bin and a sum, the
+    assignments along the lanes."""
+    bins = jnp.arange(n, dtype=slot.dtype)[:, None]
+    return jnp.sum(slot[None, :] == bins, axis=1, dtype=jnp.int32)

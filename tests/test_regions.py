@@ -305,25 +305,23 @@ def test_the_kda_chunk_form_names_what_each_route_runs(monkeypatch, route):
         assert solves and all("kda_chunk_operands" in n for n in solves)
 
 
-def test_moe_experts_sums_back_by_a_gather_and_scatters_integers_only():
-    """Beneath ``moe_experts`` no floating-point scatter is left: the one
-    scatter is the dispatch's bincount of the assignments, over integers
-    (the inverse of the sort is a second sort), and the sum back into the
-    tokens reads ``moe_experts / moe_combine / gather`` and ``/ add`` from
-    the table, under the layer's third step."""
+def test_moe_experts_sums_back_by_a_gather_and_scatters_nothing():
+    """Beneath ``moe_experts`` no scatter is left, floating-point or
+    integer: the groups' sizes are a compare and a sum (the inverse of the
+    sort is a second sort), and the sum back into the tokens reads
+    ``moe_experts / moe_combine / gather`` and ``/ add`` from the table,
+    under the layer's third step. Nor does the router sort, gather or
+    call ``top_k``: its picks are rounds of reductions."""
     for program in ("prefill", "decode"):
-        _table_, comps, _entry, text = _table("mimo", program)
-        beneath = [
-            i for instrs in comps.values() for i in instrs
-            if "moe_experts" in i["op_name"].split("/")
-        ]
-        scatters = [i for i in beneath if i["opcode"] == "scatter"]
-        assert {regions.resolve(i["op_name"]) for i in scatters} == {
-            ("moe_dispatch", "scatter-add")}
-        shapes = [
-            re.search(rf"%?{re.escape(i['name'])} = (\S+) scatter\(",
-                      text).group(1) for i in scatters]
-        assert all(re.match(r"\(?[su]\d+\[", s) for s in shapes), shapes
+        _table_, comps, _entry, _text = _table("mimo", program)
+        ops = [i for instrs in comps.values() for i in instrs]
+        beneath = [i for i in ops if "moe_experts" in i["op_name"].split("/")]
+        assert beneath
+        assert not [i for i in beneath if i["opcode"] == "scatter"]
+        leaves = {leaf for region, leaf in (
+            regions.resolve(i["op_name"]) for i in ops) if region == "moe_route"}
+        assert {"reduce_max", "reduce_min"} <= leaves, leaves
+        assert not leaves & {"top_k", "sort", "gather", "scatter-add"}
         combine = {
             regions.resolve(i["op_name"]): i["op_name"] for i in beneath
             if regions.resolve(i["op_name"])[0] == "moe_combine"}

@@ -380,3 +380,166 @@ def test_moe_combine_by_gather_is_the_scatter_add(case, T, k, monkeypatch):
     elif case == "one_held_expert":
         assert np.abs(got).max() > 0
     np.testing.assert_allclose(got, want, **tol)
+
+
+# ------------------- the index work: compares and reductions, the same integers
+
+
+def _route_by_top_k(spec, lp, x):
+    """``moe.route`` as it was written with ``jax.lax.top_k``, a one-hot
+    of the kept groups and ``take_along_axis``: the yardstick of the
+    rounds. -> (topi, topv, the chosen values)."""
+    T = x.shape[0]
+    E, k = spec.num_experts, spec.num_experts_per_token
+    router_logits = x.astype(jnp.float32) @ lp["router"]
+    if "router_bias" in lp:
+        router_logits = router_logits + lp["router_bias"]
+    if spec.moe_scoring == "sigmoid":
+        scores = jax.nn.sigmoid(router_logits)
+        choice = scores + lp["score_bias"]
+        if spec.n_group > 1:
+            gsz = E // spec.n_group
+            grouped = choice.reshape(T, spec.n_group, gsz)
+            group_scores = jax.lax.top_k(grouped, 2)[0].sum(-1)
+            _gv, gidx = jax.lax.top_k(group_scores, spec.topk_group)
+            gmask = jax.nn.one_hot(
+                gidx, spec.n_group, dtype=jnp.float32).sum(axis=1)
+            choice = jnp.where(
+                jnp.repeat(gmask, gsz, axis=-1) > 0, choice, 0.0)
+        cv, topi = jax.lax.top_k(choice, k)
+        topv = jnp.take_along_axis(scores, topi, axis=1)
+        if spec.norm_topk_prob:
+            topv = topv / (topv.sum(axis=-1, keepdims=True) + 1e-20)
+        topv = topv * spec.routed_scaling_factor
+    else:
+        probs = jax.nn.softmax(router_logits, axis=-1)
+        cv, topi = jax.lax.top_k(probs, k)
+        topv = cv / jnp.maximum(cv.sum(axis=-1, keepdims=True), 1e-9)
+    return topi.astype(jnp.int32), topv, cv
+
+
+_ROUTERS = {
+    "sigmoid_256_top8": dict(
+        num_experts=256, num_experts_per_token=8, moe_scoring="sigmoid",
+        routed_scaling_factor=2.5),
+    "sigmoid_512_4_of_8_groups": dict(
+        num_experts=512, num_experts_per_token=8, moe_scoring="sigmoid",
+        n_group=8, topk_group=4, routed_scaling_factor=2.5),
+    "softmax_bias_32_top4": dict(
+        num_experts=32, num_experts_per_token=4, moe_bias=True),
+}
+
+
+def _router_case(name, scores, T=96):
+    """(spec, lp, x) of a router; ``scores`` "ties": router columns drawn
+    from a pool of five, every bias but three at -2 (under any sigmoid),
+    rows of x repeated and rows of zeros, so that choices
+    tie exactly within a row and a grouped row is left with fewer than k
+    positive choices in the groups it keeps."""
+    import dataclasses
+
+    spec = dataclasses.replace(MOE_SPEC, **_ROUTERS[name])
+    E, d = spec.num_experts, spec.hidden_size
+    keys = jax.random.split(jax.random.PRNGKey(E), 4)
+    lp = {"router": 0.5 * jax.random.normal(keys[0], (d, E), jnp.float32)}
+    x = jax.random.normal(keys[1], (T, d), jnp.float32)
+    bias = 0.1 * jax.random.normal(keys[2], (E,), jnp.float32)
+    if scores == "ties":
+        pool = jax.random.normal(keys[0], (d, 5), jnp.float32)
+        lp["router"] = pool[:, jax.random.randint(keys[3], (E,), 0, 5)]
+        bias = jnp.full((E,), -2.0).at[
+            jnp.asarray([E // 8 + 6, E // 8 + 7, E // 2 + 3])].set(0.25)
+        x = x.at[T // 2:].set(x[: T - T // 2]).at[::7].set(0.0)
+    lp["score_bias" if spec.moe_scoring == "sigmoid" else "router_bias"] = bias
+    return spec, lp, x
+
+
+@pytest.mark.parametrize("scores", ["random", "ties"])
+@pytest.mark.parametrize("name", list(_ROUTERS))
+def test_route_by_rounds_is_top_k_bit_for_bit(name, scores):
+    """The picks by rounds of a first-occurrence max and the weights by a
+    select are ``lax.top_k``'s indices in its order and
+    ``take_along_axis``'s floats, bit for bit: ungrouped sigmoid, group-
+    limited sigmoid and softmax with a bias; on drawn scores and on
+    scores that tie (lower index first), masked zeros picked included."""
+    spec, lp, x = _router_case(name, scores)
+    want_i, want_v, cv = (np.asarray(a) for a in _route_by_top_k(spec, lp, x))
+    if scores == "ties":
+        assert (cv[:, 1:] == cv[:, :-1]).any()  # picks that tie in a row
+        if spec.n_group > 1:
+            # a row whose kept groups hold fewer than k positive choices:
+            # the zeros of the groups left out are picked
+            assert ((cv == 0).sum(axis=1) > 0).any()
+            assert ((cv > 0).sum(axis=1) < spec.num_experts_per_token).any()
+    for fn in (moe.route, jax.jit(moe.route, static_argnums=0)):
+        got_i, got_v = (np.asarray(a) for a in fn(spec, lp, x))
+        assert got_i.dtype == np.int32 and got_v.dtype == np.float32
+        assert (got_i == want_i).all()
+        assert (got_v.view(np.uint32) == want_v.view(np.uint32)).all()
+
+
+@pytest.mark.parametrize("T", [1, 128, 1024])
+@pytest.mark.parametrize("case", [
+    "random", "all_to_one_expert", "none_held", "counted_rows_left_out"])
+def test_sizes_and_counters_are_bincount(case, T, monkeypatch):
+    """The groups' sizes and the counters, written as compares and sums,
+    are ``np.bincount`` of the held assignments, integer for integer."""
+    import dataclasses
+
+    E, k, n, first = 64, 8, 16, 16  # experts 16..31 of 64 are held
+    topi = np.random.default_rng(T).integers(0, E, (T, k))
+    if case == "all_to_one_expert":
+        topi[:] = first + 3
+    elif case == "none_held":
+        topi = np.where((topi >= first) & (topi < first + n), 0, topi)
+    counted = np.ones((T,), bool)
+    if case == "counted_rows_left_out":
+        counted = np.arange(T) % 3 != 1
+
+    def held_bincount(rows):
+        local = rows.reshape(-1) - first
+        return np.bincount(local[(local >= 0) & (local < n)], minlength=n)
+
+    topi_ = jnp.asarray(topi, jnp.int32)
+    slot = moe._slots(topi_, first, n)
+    for fn in (moe._sizes, jax.jit(moe._sizes, static_argnums=1)):
+        sizes = np.asarray(fn(slot, n))
+        assert sizes.dtype == np.int32
+        assert sizes.tolist() == held_bincount(topi).tolist()
+
+    # the counters beside the layer's output, the router's picks replaced
+    spec = dataclasses.replace(
+        MOE_SPEC, num_experts=E, num_experts_per_token=k,
+        held_experts=(n, first))
+    lp = moe.init_moe_layer(spec, jax.random.PRNGKey(3))
+    x = jax.random.normal(
+        jax.random.PRNGKey(4), (T, spec.hidden_size), jnp.float32)
+    real = moe.route
+    monkeypatch.setattr(
+        moe, "route", lambda *a: (topi_, real(*a)[1]))
+    _, counts = moe.moe_mlp(spec, lp, x, counted=jnp.asarray(counted))
+    counts, want = np.asarray(counts), held_bincount(topi[counted])
+    assert counts.dtype == np.int32
+    assert counts[:n].tolist() == want.tolist()
+    assert int(counts[n]) == int(counted.sum()) * k  # assignments in all
+    assert int(counts[n + 1]) == int((want > 0).sum())  # experts touched
+
+
+def test_moe_under_an_ep_mesh_keeps_sizes_and_counters():
+    """Under the "ep" mesh each shard sizes its own groups; the layer and
+    its counters are the single-shard layer's."""
+    spec = MOE_SPEC
+    mesh = make_mesh(ep=2)
+    lp = moe.init_moe_layer(spec, jax.random.PRNGKey(3))
+    x = jax.random.normal(jax.random.PRNGKey(4), (40, spec.hidden_size),
+                          jnp.float32)
+    counted = jnp.arange(40) % 4 != 0
+    want, want_counts = moe.moe_mlp(spec, lp, x, counted=counted)
+    sharded = jax.tree.map(
+        jax.device_put, lp, moe.moe_layer_shardings(mesh, spec))
+    got, counts = jax.jit(
+        lambda lp_, x_: moe.moe_mlp(spec, lp_, x_, mesh=mesh, counted=counted)
+    )(sharded, x)
+    np.testing.assert_allclose(
+        np.asarray(got), np.asarray(want), rtol=2e-4, atol=2e-4)
+    assert np.asarray(counts).tolist() == np.asarray(want_counts).tolist()
