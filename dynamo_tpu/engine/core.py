@@ -22,6 +22,7 @@ import collections
 import contextlib
 import logging
 import queue
+import statistics
 import threading
 import time
 from dataclasses import dataclass, field
@@ -96,6 +97,12 @@ def _with_fed_column(tokens_in, sampled):
     """[B, 1 + n]: the fed tokens ride along as column 0 of the burst's
     samples, so one download carries both."""
     return jnp.concatenate([tokens_in[:, None], sampled], axis=1)
+
+
+def _is_ready(dev) -> bool:
+    """The device has finished computing ``dev`` (anything that cannot say
+    counts as finished)."""
+    return getattr(dev, "is_ready", lambda: True)()
 
 
 @partial(jax.jit, static_argnums=0)
@@ -189,8 +196,14 @@ READMIT_SUMS = {
 # attribute of its name: profile_snapshot() carries them as
 # ``<family>.<name>`` and reset_profile_window() zeroes them
 _COUNTER_FAMILIES = (
-    "decode_kv", "prefill_kv", "chunked_prefill", "kda", "ssd",
+    "decode_kv", "prefill_kv", "chunked_prefill", "burst_hold", "kda", "ssd",
 )
+
+# undisturbed burst times kept a burst length (their smallest is the
+# estimate), and launch / admission costs kept (their medians are the
+# guard): the hold of the queued burst, _hold_queued_burst
+_BURST_SAMPLES = 4
+_COST_SAMPLES = 8
 
 # what _phase and _launch hand out with profiling off: one shared object
 # whose enter and exit do nothing
@@ -474,6 +487,28 @@ class InferenceEngine:
         # between cycles when pipeline_decode, two from a dispatch until
         # the older one is read (_decode_step)
         self._pipeline: list[dict] = []
+        # the hold of the queued burst (_hold_queued_burst) rests on what
+        # this thread has measured, on ``_clock``: the instant the newest
+        # read of a burst returned HAVING BLOCKED, i.e. when that burst
+        # ended on the device (None where the read found it done, and
+        # while the running burst was launched behind nothing); the last
+        # few times between two such instants with nothing but the burst
+        # launched between them, a burst length apart; where other
+        # programs (prefills, their samples) stood between, what is left
+        # of that time a launch of theirs; what building and launching a
+        # burst, and an admission pass that admitted, have lately cost
+        # this thread; and ``_launch_seq`` as the newest burst left it
+        self._clock = time.monotonic
+        self._burst_ended: float | None = None
+        self._burst_secs: dict[int, collections.deque] = {}
+        self._side_secs: collections.deque = collections.deque(
+            maxlen=_BURST_SAMPLES)
+        self._launch_secs: collections.deque = collections.deque(
+            maxlen=_COST_SAMPLES)
+        self._admit_secs: collections.deque = collections.deque(
+            maxlen=_COST_SAMPLES)
+        self._seq_at_burst = 0
+        self._holding = False
         # async first-token waves, oldest first: each holds a device
         # sample whose host copy is in flight; waves touch disjoint live
         # slots (slot-identity guards handle reuse), so they materialize
@@ -536,6 +571,14 @@ class InferenceEngine:
         # included, and those launched with a burst in flight, i.e. queued
         # behind device work and not after a drained device
         self.chunked_prefill = {"chunks": 0, "chunks_behind_burst": 0}
+        # how often the queued burst was held for arrivals (always on, an
+        # int add a cycle): holds begun; those at whose end the running
+        # burst had already finished when the held one was launched (each
+        # a few ms of idle device); requests admitted, and those of them
+        # admitted during a hold, their prefill launched directly behind
+        # the running burst (``held=1`` on their ``engine.launch``)
+        self.burst_hold = {"begun": 0, "overran": 0, "admissions": 0,
+                           "admissions_held": 0}
         # what the KDA kernels were asked to do, a layer's worth (always
         # on, a model with recurrent layers only): state rows a kda_step
         # call updated, over the dispatched bursts' steps; blocks of
@@ -624,10 +667,14 @@ class InferenceEngine:
         k-th execution of that kind. ``counts`` are host values only: an
         annotation never reads a device array. A prefill and a decode
         burst carry ``ahead``, the decode bursts in flight at the launch:
-        how many bursts the program is queued behind at most."""
+        how many bursts the program is queued behind at most; a program
+        launched while the queued burst is held (_hold_queued_burst)
+        carries ``held=1``: it stands directly behind the running burst."""
         self._launch_seq += 1
         if not self._profiling:
             return _NO_SPAN
+        if self._holding:
+            counts["held"] = 1
         return jax.profiler.TraceAnnotation(
             "engine.launch", kind=kind, seq=self._launch_seq, **counts
         )
@@ -663,6 +710,14 @@ class InferenceEngine:
           ``engine.launch``). Their ratio is how often a long prompt's
           next chunk rode the decode pipeline instead of finding the
           device drained; near 1 on a saturated engine.
+        - ``burst_hold.begun`` / ``.overran`` / ``.admissions`` /
+          ``.admissions_held`` (calls): cycles in which the queued burst
+          was held for arrivals (``_hold_queued_burst``), those of them in
+          which the running burst had already ended when the held one was
+          launched (the device idled for the launch), requests admitted,
+          and those admitted during a hold. ``admissions_held /
+          admissions`` is how often a prompt's prefill stood directly
+          behind the running burst; ~0 where the queue is never empty.
         """
         snap = {
             k: {"secs": round(v[0], 4), "calls": int(v[1])}
@@ -1928,7 +1983,14 @@ class InferenceEngine:
             # and _build_batch/_process_burst guard by active mask +
             # request id — so admitting without a flush keeps the decode
             # pipeline deep instead of paying a host sync per admission
-            # wave. An open chunked prefill does not either: its next
+            # wave. The same four facts cover an admission made while the
+            # queued burst is held (_hold_queued_burst): it differs only
+            # in WHEN the next burst is launched, after the prefill and
+            # not before it, so the prefill stands directly behind the
+            # running burst; that burst's active mask was built before
+            # the slot existed, and the held burst, built after, takes
+            # the slot's first token through _wave_feed like any first
+            # burst. An open chunked prefill needs no flush either: its next
             # chunk is launched behind the running burst like any other
             # admission's prefill, and the same four facts cover it.
             # (1) Nothing can take its slot: _admit_phase and
@@ -2027,6 +2089,7 @@ class InferenceEngine:
         halves — measured as the 1.8k-tok/s attractor in the r5 ladder);
         two staggered cohorts interleave their prefills and decode
         bursts instead."""
+        t_pass = self._clock()
         budget = self.config.max_prefill_tokens_per_step
         n_active = sum(s is not None for s in self._slots)
         decoding = n_active > 0
@@ -2048,6 +2111,11 @@ class InferenceEngine:
                     ),
                     None,
                 )
+                if self._holding and self._head_needs_sync_admission():
+                    # its admission reads logits on the host, behind the
+                    # running burst and past the hold's deadline: it ends
+                    # the hold and is the next cycle's, as without one
+                    break
                 if free_idx is None and not self._waiting.empty():
                     # no free slot for a waiting INTERACTIVE request: pause
                     # an over-quota batch stream instead of making the
@@ -2102,6 +2170,14 @@ class InferenceEngine:
         if pending:
             with self._phase("complete_admissions"):
                 self._complete_admissions(pending)
+        if n_admitted:
+            # what a pass that admits costs this thread: a share of the
+            # guard of a hold (_hold_deadline), from every pass, so that
+            # the guard follows the traffic whether or not holds begin
+            self._admit_secs.append(self._clock() - t_pass)
+            self.burst_hold["admissions"] += n_admitted
+            if self._holding:
+                self.burst_hold["admissions_held"] += n_admitted
         if did:
             self._publish_metrics()
         return did
@@ -2348,6 +2424,10 @@ class InferenceEngine:
         step thread is the only consumer, so the head is stable)."""
         head = self._waiting.peek()
         return None if head is None else head.request.get("token_ids")
+
+    def _head_needs_sync_admission(self) -> bool:
+        head = self._waiting.peek()
+        return head is not None and self._needs_sync_admission(head.request)
 
     def _refund_if_charged(self, waiting: _Waiting) -> None:
         """Credit back a charged entry's quota when it is bounced with
@@ -3573,7 +3653,7 @@ class InferenceEngine:
                 )
         for ap in self._admit_waves:
             ap["age"] += 1
-            ready = getattr(ap["dev"], "is_ready", lambda: True)()
+            ready = _is_ready(ap["dev"])
             live = [
                 (si, s, row) for si, s, row in ap["recs"]
                 if self._slots[si] is s and s.first_pending
@@ -4220,11 +4300,26 @@ class InferenceEngine:
         DEVICE from burst k's sampled outputs, and only then is burst k's
         host copy read. The read returns when k ends, so k+1 has just
         started and is the only burst in flight while the thread streams
-        k's tokens, re-admits and builds k+2: that host work hides behind
-        k+1, and a prefill launched in it waits in the device's in-order
-        queue for one burst. (A second queued burst buys nothing unless
-        the device-to-host copy takes longer than a burst, and costs every
-        prompt a burst of time to first token: PERF.md, PR 27.)
+        k's tokens and re-admits. (A second queued burst buys nothing
+        unless the device-to-host copy takes longer than a burst, and
+        costs every prompt a burst of time to first token: PERF.md, PR 27.)
+
+        WHEN k+2 is launched: as late as is safe, not as early as
+        possible (_hold_queued_burst). The device needs k+2 only when k+1
+        ends, and all an early launch covers is the few ms this thread
+        takes to build and launch it; what it costs is that every prompt
+        arriving under k+1 finds k+2 already in the device's in-order
+        queue and the thread blocked on k+1's read, so its prefill runs
+        a burst later (PERF.md, PR 43). So while an arrival could be
+        admitted the moment it came (an empty queue beside a free slot,
+        nothing else pending) the thread waits on the wake event until
+        shortly before k+1 is expected to end, admits each arrival as it
+        comes (its prefill stands directly behind the RUNNING burst), and
+        only then builds k+2, with every slot admitted meanwhile fed
+        through _wave_feed, and goes to block on k+1's read. With a
+        queue that is never empty (closed loops) nothing is held and the
+        launch is at once.
+
         Stops are detected one burst late (discarded garbage, as with
         mid-burst EOS); cancels and admin ops flush the pipeline first
         (_step).
@@ -4247,6 +4342,8 @@ class InferenceEngine:
                 with self._phase("flush"):
                     self._flush_pipeline()
         elif self.config.pipeline_decode:
+            held = self._hold_queued_burst()
+            t_launch = self._clock()
             with self._phase("build_batch"):
                 batch = self._build_batch(self._pipeline)
             if batch is None:
@@ -4259,11 +4356,34 @@ class InferenceEngine:
                     )
                     return True
                 return False
+            if (
+                held
+                and self._pipeline  # a preemption in the hold flushed it
+                and _is_ready(self._pipeline[-1]["results"][0])
+            ):
+                # held too long: the running burst ended before this one
+                # was launched, and the device idles for the launch. If
+                # the thread was not just late, bursts have become shorter
+                # than the times kept say (fewer live slots, a shorter
+                # context), and no read after an overrun blocks twice in
+                # a row to say so: forget the times, and the next two
+                # cycles, not held, take them anew
+                self.burst_hold["overran"] += 1
+                self._burst_secs.pop(
+                    self._pipeline[-1]["batch"]["n_burst"], None)
+            # the programs launched since the last burst (prefills, their
+            # samples) stand before this one on the device: with none, the
+            # time between the two reads is the burst's own
+            # (_note_burst_end)
+            side = self._launch_seq - self._seq_at_burst
             with self._phase("dispatch"):
                 results = self._dispatch_burst(
                     batch, chain=self._pipeline or None
                 )
-            self._pipeline.append({"batch": batch, "results": results})
+            self._seq_at_burst = self._launch_seq
+            self._launch_secs.append(self._clock() - t_launch)
+            self._pipeline.append(
+                {"batch": batch, "results": results, "side": side})
             if len(self._pipeline) > 1:
                 before = sum(s is not None for s in self._slots)
                 with self._phase("process"):
@@ -4289,6 +4409,145 @@ class InferenceEngine:
             before - sum(s is not None for s in self._slots)
         )
         return True
+
+    def _hold_queued_burst(self) -> bool:
+        """Between reading burst k and building burst k+2 (pipelined
+        schedule only; see _decode_step): while an arrival could be
+        admitted the moment it came, wait for arrivals until shortly
+        before the running burst k+1 is expected to end, and admit each as
+        it comes. Returns True when a hold was begun.
+
+        Everything is observed, nothing configured. The hold is open
+        (_hold_open) while a burst is in flight to hide behind, a slot is
+        free, no partial is open and nothing else wants the thread; it is
+        entered only with an empty queue, and ends the moment a pass
+        leaves a request waiting (no slot, a partial opened, the budget
+        spent): there is nothing to hold for then. An arrival whose
+        admission reads its logits on the host (logprobs, a grammar, a
+        remote-decode handoff: _needs_sync_admission) is left waiting so:
+        its pass would block this thread behind the running burst, past
+        the deadline. A pass that had to preempt a stream for its arrival
+        (_preempt_batch_slot) has flushed the pipeline: the hold ends
+        with nothing in flight, and the next burst is launched as after
+        any flush. The deadline (_hold_deadline) is what this thread has
+        measured; with no measurement there is no hold.
+
+        A wake that is no arrival (close, drain, an admin op, a deadline's
+        stop, an SPMD sync request) ends the hold: the flags are read
+        after the event is cleared and every setter raises its flag
+        first, so none is missed, and none waits longer than for the read
+        of k+1 that would block this thread anyway. The hold admits and
+        does not land: a first token still comes home on its slot's first
+        burst (_process_burst) or at the top of _step
+        (_materialize_waves), as without a hold. The wait is the ``idle``
+        phase, the passes are the admission phases they always are."""
+        if not self._waiting.empty():
+            return False
+        deadline = self._hold_deadline()
+        if deadline is None:
+            return False
+        begun = False
+        while True:
+            self._wake.clear()
+            now = self._clock()
+            if now >= deadline or not self._hold_open():
+                break
+            if not begun:
+                begun = True
+                self.burst_hold["begun"] += 1
+            if self._waiting.empty():
+                with self._phase("idle"):
+                    self._wake.wait(deadline - now)
+                if self._waiting.empty():
+                    break  # the deadline, or a wake that is no arrival
+                continue
+            self._holding = True
+            try:
+                self._admit_phase()
+            finally:
+                self._holding = False
+            if not self._pipeline or not self._waiting.empty():
+                # the pass preempted a stream for the arrival and flushed
+                # the pipeline (nothing is left to hide behind), or
+                # nothing more can be admitted
+                break
+        return begun
+
+    def _hold_open(self) -> bool:
+        """A request arriving now would be admitted at once, behind a burst
+        in flight, and nothing else wants the step thread."""
+        return (
+            bool(self._pipeline)
+            and self._partial is None
+            and not self._chunk_cycle
+            and not self._closed
+            and not self._draining
+            and not self._clear_cache_requested
+            and self.spmd is None
+            and self.config.async_admissions
+            and any(s is None for s in self._slots)
+            and not any(
+                s is not None
+                and (s.context.is_stopped or self._spec_managed(s))
+                for s in self._slots
+            )
+            and not self._guided_live()
+        )
+
+    def _hold_deadline(self) -> float | None:
+        """When the held burst must be built, on ``_clock``: the running
+        burst's expected end less a guard. The end is the instant its
+        predecessor's read returned, plus the programs that stand before
+        it (as many launches at the least a launch has lately taken), plus
+        the shortest of the last few undisturbed bursts of its length:
+        every term errs early. (Without the middle term a hold behind a
+        prefill ends ~13 ms early and ``ttft_p50_ms`` reads ~4% higher;
+        asking the prefill's sample ``is_ready()`` at the hold's start
+        reads the same, the answer is mostly yes by then: PERF.md, PR 43.)
+        The guard is what building and launching a
+        burst and one admission pass have lately cost this thread (the
+        medians: a stall of the thread foretells nothing, and the largest
+        would shut the hold for the eight cycles after one), and one
+        decode step. None without those measurements: a burst length not
+        yet timed, a read that did not block."""
+        if not self._pipeline or self._burst_ended is None:
+            return None
+        running = self._pipeline[-1]
+        n_burst = running["batch"]["n_burst"]
+        secs = self._burst_secs.get(n_burst)
+        if not secs or not self._launch_secs:
+            return None
+        burst = min(secs)
+        before = running["side"] * min(self._side_secs, default=0.0)
+        guard = (
+            statistics.median(self._launch_secs)
+            + (statistics.median(self._admit_secs) if self._admit_secs else 0.0)
+            + burst / n_burst
+        )
+        return self._burst_ended + before + burst - guard
+
+    def _note_burst_end(self, pending: dict, blocked: bool) -> None:
+        """The read of a burst has returned. Where it blocked, now is when
+        the burst ended. Where the read before it blocked too, and this
+        burst was queued behind that one (_dispatch_burst), the time
+        between the two instants is what stood between them on the
+        device: the burst alone, or the burst behind the ``side`` programs
+        launched before it, which leaves a time a launch of those once a
+        burst of its length has been timed."""
+        now = self._clock()
+        before, self._burst_ended = self._burst_ended, (
+            now if blocked else None)
+        if not blocked or before is None:
+            return
+        n_burst, side = pending["batch"]["n_burst"], pending.get("side")
+        if not side:
+            self._burst_secs.setdefault(
+                n_burst, collections.deque(maxlen=_BURST_SAMPLES)
+            ).append(now - before)
+        elif n_burst in self._burst_secs:
+            rest = now - before - min(self._burst_secs[n_burst])
+            if rest > 0:
+                self._side_secs.append(rest / side)
 
     def _guided_live(self) -> bool:
         """True while any live slot is grammar-constrained (those cycles
@@ -4485,6 +4744,10 @@ class InferenceEngine:
         ``chain`` is oldest-first; newer bursts override older rows, so a
         slot inactive in the newest burst (page-stalled for one burst)
         still feeds from its latest on-device token."""
+        if not chain:
+            # launched behind nothing, it starts now and not where a
+            # predecessor ends: its end times no burst (_note_burst_end)
+            self._burst_ended = None
         # chain-validity masks: guard rows by request identity, exactly
         # like _build_batch's `extra` accumulation — a slot freed (EOS in
         # an older burst) and reused by a NEW request must not have the
@@ -4612,8 +4875,10 @@ class InferenceEngine:
         sampled_dev, lp_dev, ti_dev, tv_dev = pending["results"]
         n_burst = batch["n_burst"]
         active = batch["active"]
+        blocked = not _is_ready(sampled_dev)
         with self._phase("process.d2h_sync"), self._phase("dispatch.d2h_wait"):
             combined = np.asarray(sampled_dev)  # [B, 1 + n_burst]
+        self._note_burst_end(pending, blocked)
         # column 0 is the burst's FED tokens (_dispatch_burst): the first
         # tokens of slots admitted into this burst land from this same
         # download — sequence order (first token before burst tokens)

@@ -210,9 +210,12 @@ async def test_dispatch_phases_and_attribution():
     over = dispatch_overhead(snap, window_s=10.0, model_steps=engine.steps)
     assert over["target_frac_max"] == 0.15
     assert over["dispatch_plus_readmit_frac_of_window"] is not None
-    # the fraction is exactly (dispatch_s + readmit_s) / window
-    want = round((over["dispatch_s"] + over["readmit_s"]) / 10.0, 4)
-    assert over["dispatch_plus_readmit_frac_of_window"] == want
+    # the fraction is (dispatch_s + readmit_s) / window, each of the three
+    # rounded to four places on its own: the two terms' rounding is worth
+    # 1e-4 / 10 at most, the fraction's own 5e-5
+    want = (over["dispatch_s"] + over["readmit_s"]) / 10.0
+    assert over["dispatch_plus_readmit_frac_of_window"] == pytest.approx(
+        want, abs=6e-5)
 
 
 def test_dispatch_overhead_fraction_math():

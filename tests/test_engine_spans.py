@@ -75,7 +75,10 @@ async def test_profile_off_records_nothing_and_creates_no_annotation(
                          "prefill_kv.blocks_visited.full",
                          "prefill_kv.blocks_table.full",
                          "chunked_prefill.chunks",
-                         "chunked_prefill.chunks_behind_burst"}
+                         "chunked_prefill.chunks_behind_burst",
+                         "burst_hold.begun", "burst_hold.overran",
+                         "burst_hold.admissions",
+                         "burst_hold.admissions_held"}
     # three bursts' worth at least, always on: pages moved for real contexts
     kv = {k.removeprefix("decode_kv."): v["calls"]
           for k, v in snap.items() if k.startswith("decode_kv.")}
@@ -204,7 +207,8 @@ def test_phase_annotations_match_the_profile_sums(traced):
             phase in synthesized
             # counts, not phases
             or phase.startswith(
-                ("decode_kv.", "prefill_kv.", "chunked_prefill."))
+                ("decode_kv.", "prefill_kv.", "chunked_prefill.",
+                 "burst_hold."))
             or phase.startswith("readmit.") and phase != "readmit.d2h_wait"
         ):
             continue

@@ -161,6 +161,16 @@ PROFILE_COUNTERS: dict[str, str] = {
                                            "burst in flight (ahead >= 1): "
                                            "queued behind device work, "
                                            "not after a drained device",
+    "burst_hold.begun": "cycles in which the queued decode burst was held "
+                        "for arrivals (_hold_queued_burst)",
+    "burst_hold.overran": "those at whose end the running burst had "
+                          "already finished: the device idled while the "
+                          "held burst was launched",
+    "burst_hold.admissions": "requests admitted (an admission pass's "
+                             "count, in a hold or not)",
+    "burst_hold.admissions_held": "those admitted during a hold: their "
+                                  "prefill was launched directly behind "
+                                  "the running burst (held=1)",
     # a model with recurrent (KDA) layers only
     "kda.decode_rows": "state rows a kda_step call updated (live slots), "
                        "over the dispatched bursts' steps; a layer's worth",
