@@ -1,8 +1,8 @@
 #!/usr/bin/env python
 """Step-thread phase profile of the serving hot loop.
 
-Runs one closed-loop serving rung (same workload as bench.py's ladder:
-ISL=128, OSL=48) with EngineConfig.profile on and prints where the step
+Runs one closed-loop serving rung (ISL=128, OSL=48) with
+EngineConfig.profile on and prints where the step
 thread's wall time goes: device sync, host bookkeeping, admissions,
 batch building. This is the measurement tool behind the round-5
 serving-efficiency work (VERDICT r4 weak #1: ~40ms/cycle of host-side
@@ -141,8 +141,8 @@ def spec_attribution(snap: dict, counters: dict) -> dict:
     dispatch landed (accepted drafts + the always-emitted target token)
     against the 1.0-token-per-dispatch non-spec decode baseline — the
     CPU step-count proxy for the per-stream speedup claim (>= 1.5 on
-    repetitive/agentic prompts is the acceptance bar; bench.py records
-    it in the spec_decode artifact section)."""
+    repetitive/agentic prompts is the acceptance bar, held by
+    tests/test_spec_decode.py)."""
     verifies = int(counters.get("verifies") or 0)
     accepted = int(counters.get("accepted") or 0)
     return {
@@ -263,10 +263,10 @@ def main() -> None:
             threading.Thread(target=dump_stacks, daemon=True).start()
         rng = np.random.default_rng(0)
 
-        # compile every serving shape BEFORE the measured window (mirrors
-        # bench.py): the full admission wave (packed prefill + burst
-        # programs), the single-prompt prefill + width-1 fused sample
-        # (straggler), and the ramp-up capped-burst program (trickle)
+        # compile every serving shape BEFORE the measured window: the
+        # full admission wave (packed prefill + burst programs), the
+        # single-prompt prefill + width-1 fused sample (straggler), and
+        # the ramp-up capped-burst program (trickle)
         async def warm_one(i: int):
             toks = rng.integers(3, spec.vocab_size, ISL).tolist()
             async for _ in engine.generate(

@@ -1,7 +1,7 @@
 """Shared open-loop trace replay: ONE timestamp/percentile core for every
 trace-driven harness.
 
-``benchmarks/router_bench.py`` (routing-quality trace mode) and
+``benchmarks.router_bench`` (routing-quality trace mode) and
 ``dynamo_tpu/sim`` (cluster chaos scenarios) both replay mooncake-style
 traces open-loop against AsyncEngine-compatible clients. Before this
 module they would each carry their own replay loop — and the two could

@@ -316,7 +316,7 @@ class ModelSpec:
     @classmethod
     def dryrun(cls) -> "ModelSpec":
         """Tiny spec with kv_heads=8 so tp up to 8 divides the KV head axis
-        (shared by bench.py's CPU smoke and __graft_entry__)."""
+        (__graft_entry__, profile_engine's CPU smoke)."""
         return cls(
             name="dryrun", vocab_size=512, hidden_size=256,
             intermediate_size=512, num_layers=2, num_heads=8,
@@ -633,7 +633,6 @@ class EngineConfig:
     spec_k_max: int = 8  # max draft tokens per verify (verify width k+1)
     spec_ngram_min: int = 1  # shortest suffix n-gram the drafter matches
     spec_ngram_max: int = 4  # longest (tried first: stronger predictor)
-    spec_ewma_alpha: float = 0.5  # acceptance-EWMA step per verify
     # emitted tokens between k=1 reprobes while a slot is parked at k=0
     # (0 = never reprobe: once decayed, the request stays non-spec)
     spec_reprobe_tokens: int = 64
@@ -643,34 +642,14 @@ class EngineConfig:
     # them with a typed error. DYN_GUIDED_MODE / --guided set this on
     # workers.
     guided_mode: str = "auto"  # "auto" | "off"
-    # compiled-grammar LRU entries per engine, keyed (grammar, vocab)
-    # like the persistent compile cache — agentic traffic reuses a
-    # handful of schemas, so steady state is all hits
-    guided_cache_entries: int = 32
     # sampling
     seed: int = 0
     # step-thread phase profiler, the one switch: per-phase wall seconds +
-    # call counts via profile_snapshot(), incl. the dispatch.* attribution
-    # (bench.py turns this on for the serving ladder so the artifact can
-    # carry dispatch_overhead_frac); every phase, device launch and loop
-    # cycle as an engine.* annotation in a jax.profiler trace; the flight
-    # recorder keeps every finished timeline (docs/OBSERVABILITY.md)
+    # call counts via profile_snapshot(), incl. the dispatch.* attribution;
+    # every phase, device launch and loop cycle as an engine.* annotation
+    # in a jax.profiler trace; the flight recorder keeps every finished
+    # timeline (docs/OBSERVABILITY.md)
     profile: bool = False
-    # scheduler
-    step_idle_sleep_s: float = 0.002
-    # eager re-admission: when processing a decode burst frees slots, run
-    # the admission pass again IN THE SAME step cycle (the replacement's
-    # prefill dispatches behind the in-flight burst; its first token
-    # feeds the next burst's device chain) instead of leaving the slot
-    # idle until the next step's admission phase — one skipped pass
-    # costs a full burst of slot idleness (~200 ms at serving burst
-    # lengths; the dominant term in the r5 733 ms re-admission TTFT)
-    eager_readmit: bool = True
-    # bounded wait for a closed-loop client's resubmission to cross the
-    # event loop right after its finish item posted (finish -> client
-    # resubmit -> generate enqueue is ~a ms of loop latency); hidden
-    # behind the in-flight burst's device execution. 0 = don't wait.
-    readmit_wait_s: float = 0.002
 
     def __post_init__(self) -> None:
         if self.max_decode_slots is None:

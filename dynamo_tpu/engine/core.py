@@ -205,6 +205,14 @@ _COUNTER_FAMILIES = (
 _BURST_SAMPLES = 4
 _COST_SAMPLES = 8
 
+# the step thread's two bounded waits on the wake event. Idle: slots or a
+# partial are live but the cycle did no work. Readmit: a closed-loop
+# client's resubmission crossing the event loop right after its finish
+# item posted (finish -> client resubmit -> generate enqueue is ~a ms of
+# loop latency), hidden behind the in-flight burst's device execution
+_STEP_IDLE_SLEEP_S = 0.002
+_READMIT_WAIT_S = 0.002
+
 # what _phase and _launch hand out with profiling off: one shared object
 # whose enter and exit do nothing
 _NO_SPAN = contextlib.nullcontext()
@@ -476,7 +484,10 @@ class InferenceEngine:
             self._guided = GrammarCompiler(
                 guided_vocab,
                 vocab_size=spec.vocab_size,
-                cache_entries=self.config.guided_cache_entries,
+                # LRU of compiled grammars, keyed (grammar, vocab):
+                # agentic traffic reuses a handful of schemas, so steady
+                # state is all hits
+                cache_entries=32,
             )
         self._partial: _PartialPrefill | None = None
         # this step-loop cycle ran a chunk of a partial that was open when
@@ -749,7 +760,7 @@ class InferenceEngine:
     def reset_profile_window(self) -> None:
         """Zero the profiling counters so the next profile_snapshot
         covers only work from this point on (drop warmup/compile noise
-        before a measured window — bench.py, profile_engine.py)."""
+        before a measured window — profile_engine.py)."""
         self._prof.clear()
         self._prof_requests.clear()
         self.dispatches = 0
@@ -1877,7 +1888,7 @@ class InferenceEngine:
                             self._wake.wait()
                     else:
                         with self._phase("idle"):
-                            self._wake.wait(self.config.step_idle_sleep_s)
+                            self._wake.wait(_STEP_IDLE_SLEEP_S)
             except Exception:  # noqa: BLE001
                 # fail every in-flight request, then KEEP SERVING: one bad
                 # step must not brick the worker
@@ -2197,11 +2208,9 @@ class InferenceEngine:
         in-flight burst still has a full burst of device execution ahead,
         so the wait is hidden. Control signals (close, cancel, admin ops)
         are level-checked flags re-read every step, so clearing the wake
-        event here delays them by at most readmit_wait_s."""
-        cfg = self.config
+        event here delays them by at most _READMIT_WAIT_S."""
         if (
-            not cfg.eager_readmit
-            or freed <= 0
+            freed <= 0
             or self._partial is not None
             # the cycle that closed a partial: its burst is read here now
             # that no flush lands it first, and an admission pass behind
@@ -2210,11 +2219,7 @@ class InferenceEngine:
             or self._closed
         ):
             return
-        if (
-            self._waiting.empty()
-            and cfg.readmit_wait_s > 0
-            and self._pipeline
-        ):
+        if self._waiting.empty() and self._pipeline:
             # only wait while a dispatched burst is still executing on
             # device (the wait hides behind it); with no burst in flight
             # — non-pipelined mode, or the drain branch just emptied the
@@ -2222,7 +2227,7 @@ class InferenceEngine:
             # added to every open-loop finish
             with self._phase("readmit_wait"):
                 self._wake.clear()
-                self._wake.wait(cfg.readmit_wait_s)
+                self._wake.wait(_READMIT_WAIT_S)
         if self._waiting.empty():
             return
         with self._phase("eager_readmit"):

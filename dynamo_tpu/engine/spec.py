@@ -103,6 +103,9 @@ class PromptLookupDrafter:
         return []
 
 
+_EWMA_ALPHA = 0.5  # acceptance-EWMA step per verify
+
+
 @dataclass
 class SlotSpec:
     """Per-slot speculation state: drafter + acceptance-adaptive k.
@@ -120,7 +123,6 @@ class SlotSpec:
 
     drafter: PromptLookupDrafter
     k_max: int
-    alpha: float
     reprobe_tokens: int
     ewma: float = 1.0  # optimistic start: first verify probes at k_max
     cooldown: int = 0  # tokens until the next k=1 reprobe while parked
@@ -136,7 +138,6 @@ class SlotSpec:
                 cfg.spec_ngram_min, cfg.spec_ngram_max
             ),
             k_max=max(1, cfg.spec_k_max),
-            alpha=cfg.spec_ewma_alpha,
             reprobe_tokens=cfg.spec_reprobe_tokens,
         )
 
@@ -196,7 +197,7 @@ class SlotSpec:
         self.drafted += drafted
         self.accepted += accepted
         rate = accepted / drafted if drafted else 0.0
-        self.ewma = self.alpha * rate + (1.0 - self.alpha) * self.ewma
+        self.ewma = _EWMA_ALPHA * rate + (1.0 - _EWMA_ALPHA) * self.ewma
         if not self.active:
             self.cooldown = self.reprobe_tokens
 

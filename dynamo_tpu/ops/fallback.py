@@ -3,7 +3,7 @@
 ROADMAP #7 named the failure mode: fp8 + tp>1 silently takes the XLA
 path (a QuantPool's scale leaves have no PartitionSpec to ride the tp
 shard_map), and nothing in the metrics or logs says so — the only
-symptom is a throughput number (BENCH_r05's 0.358x). Every
+symptom is a throughput number far under the bf16 one. Every
 capability-gated downgrade in ops/ now calls :func:`note_fallback`:
 the downgrade shows up in ``dynamo_fused_fallback_total{reason}`` and
 the FIRST occurrence of each reason logs — a warning when it is a

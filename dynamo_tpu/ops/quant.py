@@ -1,8 +1,8 @@
 """fp8 KV-cache quantization: pool container + the shared quant math.
 
-Decode is HBM-bandwidth-bound (BENCH_r05: 0.53-0.58 of the roofline at
-~3.2 GB/step), so the next integer speedup is fewer bytes per step, not
-better overlap (ROADMAP #2). KV pages quantize to ``float8_e4m3fn``
+Decode is HBM-bandwidth-bound (PERF.md §5: a dense step runs at 87% of
+its bytes' time), so the next integer speedup is fewer bytes per step, not
+better overlap (ROADMAP S11). KV pages quantize to ``float8_e4m3fn``
 values with ONE bf16 scale per (page, kv_head) — per-head because K/V
 row magnitudes differ by head, per-page because that is the DMA
 granularity of every kernel in ops/pallas (a page moves as one

@@ -189,7 +189,7 @@ class Client:
                     if inst.transport == "tcp" and self._drt.config.prewarm_dials:
                         # warm the pool at discovery so the instance's
                         # first request doesn't pay the dial (cold-vs-warm
-                        # TTFT delta: benchmarks/stream_bench.py)
+                        # TTFT delta: benchmarks.stream_bench)
                         spawn(
                             self._prewarm(inst),
                             name=f"prewarm-{inst.instance_id:x}",

@@ -2,7 +2,7 @@
 
 ``DYN_UVLOOP=1`` swaps the default asyncio event loop for uvloop at the
 frontend/worker/gateway entrypoints — worth ~20-40% on the syscall-bound
-stream plane (benchmarks/stream_bench.py measures it on this box). The
+stream plane (benchmarks.stream_bench measures it on this box). The
 dependency is deliberately optional: when uvloop isn't installed (it is
 not vendored) or the platform doesn't support it, we log once and fall
 back to the stock loop. Library code must never call this — only process

@@ -26,7 +26,7 @@ The send path is corked (framing.FrameWriter): frames buffer in user space
 and hit the socket once per event-loop tick, draining only on transport
 backpressure; adjacent items of one stream coalesce into a single
 ``payloads`` frame (DYN_STREAM_COALESCE, default on). See README "Stream
-plane" and benchmarks/stream_bench.py for the measured effect.
+plane" and benchmarks.stream_bench for the measured effect.
 
 In-process instances short-circuit the wire entirely (LocalRegistry), which
 is what hermetic tests and single-process deployments use.

@@ -23,8 +23,7 @@ Each scenario asserts its invariants continuously (no dual-lead per term
 via the jepsen-style WAL checker, zero client-visible errors with
 migrations > 0 under churn, commit unavailability bounded to the
 partition window, interactive TTFT SLO held during storms) and the run
-writes a saturation-curve artifact (``SIM_r0x.json``) — the
-control-plane analogue of the serving ladder.
+writes a saturation-curve artifact (``SIM_r0x.json``).
 
 Run: ``python -m dynamo_tpu.sim --scenario all --workers 200``.
 """

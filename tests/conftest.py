@@ -30,6 +30,23 @@ jax.config.update("jax_enable_compilation_cache", False)
 import asyncio  # noqa: E402
 import inspect  # noqa: E402
 
+import pytest  # noqa: E402
+
+
+@pytest.fixture(params=[
+    pytest.param({}, id="default"),
+    pytest.param(
+        {"pipeline_decode": True, "decode_steps_per_dispatch": 8},
+        id="serving",
+    ),
+])
+def decode_schedule(request) -> dict:
+    """EngineConfig fields of the decode schedule: the one the fields'
+    defaults give (synchronous single steps), then the one that serves
+    (cli.py, engine/worker.py, chip_smoke.py and every benchmark cell:
+    pipelined bursts of 8). A test that takes this runs once on each."""
+    return request.param
+
 
 def pytest_pyfunc_call(pyfuncitem):
     """Run ``async def`` tests with asyncio.run (pytest-asyncio is not installed)."""

@@ -184,14 +184,14 @@ async def test_disagg_policy_live_update():
 # ------------------------------------------------------------------ e2e parity
 
 
-async def test_disagg_matches_aggregated_greedy():
+async def test_disagg_matches_aggregated_greedy(decode_schedule):
     """prefill worker + decode worker == aggregated worker, token for token."""
     prompt = list(range(40, 40 + 23))  # 23 tokens -> crosses page boundaries
 
     # aggregated ground truth
     drt_a = DistributedRuntime(InMemoryHub())
     agg, _ = await launch_engine_worker(
-        drt_a, spec=SPEC, engine_config=engine_config(), model_name="agg",
+        drt_a, spec=SPEC, engine_config=engine_config(**decode_schedule), model_name="agg",
     )
     want, _ = await collect(agg.generate(request(prompt), Context()))
     await agg.close()
@@ -201,11 +201,11 @@ async def test_disagg_matches_aggregated_greedy():
     # disagg pair on a fresh hub
     drt = DistributedRuntime(InMemoryHub())
     pre, _ = await launch_engine_worker(
-        drt, spec=SPEC, engine_config=engine_config(), model_name="tiny-test",
+        drt, spec=SPEC, engine_config=engine_config(**decode_schedule), model_name="tiny-test",
         mode="prefill",
     )
     dec, _ = await launch_engine_worker(
-        drt, spec=SPEC, engine_config=engine_config(), model_name="tiny-test",
+        drt, spec=SPEC, engine_config=engine_config(**decode_schedule), model_name="tiny-test",
         mode="decode", always_remote_prefill=True,
     )
     handler = dec.frontdoor

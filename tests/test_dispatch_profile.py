@@ -17,6 +17,7 @@ from benchmarks.profile_engine import (
     READMIT_PHASES,
     dispatch_attribution,
     dispatch_overhead,
+    readmission_attribution,
 )
 from dynamo_tpu.engine.compile_cache import compile_snapshot
 from dynamo_tpu.engine.config import EngineConfig, ModelSpec
@@ -216,6 +217,32 @@ async def test_dispatch_phases_and_attribution():
     want = (over["dispatch_s"] + over["readmit_s"]) / 10.0
     assert over["dispatch_plus_readmit_frac_of_window"] == pytest.approx(
         want, abs=6e-5)
+
+
+async def test_readmission_gap_attribution_phases():
+    """EngineConfig.profile breaks the finish->first-token path into
+    the named phases profile_engine.py reports: admit_wait (queue time),
+    prefill_dispatch (prompt forward + fused sample), first_token
+    (residual sample/d2h materialization)."""
+    engine = InferenceEngine(_TINY_F32, EngineConfig(
+        page_size=4, num_pages=64, max_pages_per_seq=16,
+        max_decode_slots=2, prefill_buckets=(16, 32),
+        decode_steps_per_dispatch=2, pipeline_decode=True, profile=True,
+    ))
+    await engine.start()
+    await _serve(engine, [3, 3, 3, 3], "prof")
+    snap = engine.profile_snapshot()
+    await engine.close()
+    for phase in (
+        "readmit.admit_wait", "readmit.prefill_dispatch",
+        "readmit.first_token",
+    ):
+        assert snap.get(phase, {}).get("calls", 0) > 0, phase
+    attr = readmission_attribution(snap)
+    for key in ("admit_wait", "prefill_dispatch", "first_token"):
+        assert attr[key]["events"] > 0
+        assert attr[key]["mean_ms"] is not None
+    assert attr["engine_gap_ms"] > 0
 
 
 def test_dispatch_overhead_fraction_math():

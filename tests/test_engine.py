@@ -222,25 +222,29 @@ def test_page_allocator_prefix_cache_and_eviction():
 # ----------------------------------------------------------- engine end-to-end
 
 
-async def test_engine_generates_stream():
-    eng = InferenceEngine(SPEC, small_config())
+def _tokens(items):
+    return [t for x in items for t in x["token_ids"]]
+
+
+async def test_engine_generates_stream(decode_schedule):
+    eng = InferenceEngine(SPEC, small_config(**decode_schedule))
     req = {
         "token_ids": [5, 6, 7, 8, 9],
         "sampling": {"temperature": 0.0},
         "stop_conditions": {"max_tokens": 6, "ignore_eos": True},
     }
     out = [x async for x in eng.generate(req, Context())]
-    assert len(out) == 6
+    toks = _tokens(out)
+    assert len(toks) == 6
     assert out[-1]["finish_reason"] == "length"
-    toks = [t for x in out for t in x["token_ids"]]
     assert all(0 <= t < SPEC.vocab_size for t in toks)
     # deterministic under greedy: same request -> same tokens
     out2 = [x async for x in eng.generate(req, Context())]
-    assert [x["token_ids"] for x in out2] == [x["token_ids"] for x in out]
+    assert _tokens(out2) == toks
     await eng.close()
 
 
-async def test_engine_concurrent_requests_and_prefix_cache():
+async def test_engine_concurrent_requests_and_prefix_cache(decode_schedule):
     events = []
 
     class _Pub:
@@ -250,7 +254,9 @@ async def test_engine_concurrent_requests_and_prefix_cache():
         def blocks_removed(self, shs):
             events.extend(("evict", sh) for sh in shs)
 
-    eng = InferenceEngine(SPEC, small_config(), event_publisher=_Pub())
+    eng = InferenceEngine(
+        SPEC, small_config(**decode_schedule), event_publisher=_Pub()
+    )
     prompt = list(range(10, 26))  # 16 tokens = 4 pages
 
     async def run(suffix):
@@ -261,7 +267,7 @@ async def test_engine_concurrent_requests_and_prefix_cache():
         return [x async for x in eng.generate(req, Context())]
 
     results = await asyncio.gather(run([90]), run([91]), run([92]))
-    assert all(len(r) == 4 for r in results)
+    assert all(len(_tokens(r)) == 4 for r in results)
     # prompt blocks sealed once -> stored events for the shared prefix exist
     assert any(e[0] == "store" for e in events)
 
@@ -274,8 +280,8 @@ async def test_engine_concurrent_requests_and_prefix_cache():
     await eng.close()
 
 
-async def test_engine_cancellation_frees_pages():
-    eng = InferenceEngine(SPEC, small_config())
+async def test_engine_cancellation_frees_pages(decode_schedule):
+    eng = InferenceEngine(SPEC, small_config(**decode_schedule))
     ctx = Context()
     req = {
         "token_ids": [1, 2, 3, 4, 5],
@@ -338,15 +344,15 @@ def test_tp_sharded_prefill_matches_single_device():
     )
 
 
-async def test_engine_on_tp_mesh_generates():
+async def test_engine_on_tp_mesh_generates(decode_schedule):
     mesh = make_mesh(tp=2)
-    eng = InferenceEngine(SPEC, small_config(), mesh=mesh)
+    eng = InferenceEngine(SPEC, small_config(**decode_schedule), mesh=mesh)
     req = {
         "token_ids": [3, 1, 4, 1, 5],
         "stop_conditions": {"max_tokens": 4, "ignore_eos": True},
     }
     out = [x async for x in eng.generate(req, Context())]
-    assert len(out) == 4
+    assert len(_tokens(out)) == 4
     assert out[-1]["finish_reason"] == "length"
     await eng.close()
 

@@ -349,11 +349,13 @@ async def test_engine_rejects_expired_deadline_at_admission():
     await engine.close()
 
 
-async def test_engine_deadline_bounds_generation():
+async def test_engine_deadline_bounds_generation(decode_schedule):
     """A request whose deadline passes mid-flight ends promptly as
     'cancelled' (not a hang, not a full-length stream) and leaks no
     pages."""
-    engine = InferenceEngine(TINY, _engine_cfg(max_pages_per_seq=64))
+    engine = InferenceEngine(
+        TINY, _engine_cfg(max_pages_per_seq=64, **decode_schedule)
+    )
     try:
         # tight deadline: expires during prefill compile / early decode
         items = []
@@ -377,11 +379,11 @@ async def test_engine_deadline_bounds_generation():
         await engine.close()
 
 
-async def test_engine_step_fault_fails_inflight_then_recovers():
+async def test_engine_step_fault_fails_inflight_then_recovers(decode_schedule):
     """engine.step:error exercises the fail-everything-then-keep-serving
     recovery: the faulted step errors in-flight requests, the NEXT
     request (fault exhausted) serves normally on the same engine."""
-    engine = InferenceEngine(TINY, _engine_cfg())
+    engine = InferenceEngine(TINY, _engine_cfg(**decode_schedule))
     try:
         FAULTS.configure("engine.step:error@1x1")
         items = [

@@ -470,7 +470,12 @@ async def test_pickline_client_close_fails_pending_picks():
     import asyncio
 
     async def silent(reader, writer):
-        await reader.read()  # never answers
+        try:
+            await reader.read()  # never answers
+        finally:
+            # Python 3.12's wait_closed() waits for every connection: the
+            # handler closes its side once the client has hung up
+            writer.close()
 
     srv = await asyncio.start_server(silent, "127.0.0.1", 0)
     port = srv.sockets[0].getsockname()[1]

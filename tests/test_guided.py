@@ -16,7 +16,6 @@ import json
 import numpy as np
 import pytest
 
-import bench
 from dynamo_tpu.engine.config import EngineConfig, ModelSpec
 from dynamo_tpu.engine.core import InferenceEngine
 from dynamo_tpu.guided import (
@@ -465,9 +464,15 @@ async def test_guided_spec_greedy_golden_bit_identical():
     scratch-cursor lookahead means rejected tails never perturb the
     grammar state (rollback-by-construction)."""
     vocab = VOCABS["gqa"]
-    # rng(2): a prompt whose drafts get PARTIALLY rejected (probed), so
-    # the masked-verify + rejected-tail path is genuinely exercised
-    prompt = np.random.default_rng(2).integers(3, 90, 24).tolist()
+    # the prompt is itself a conformant document, one character a token:
+    # the prompt-lookup drafter then proposes ITS string body and values
+    # behind the keys the model emits, which the grammar allows (so they
+    # survive the lookahead) and the model's own argmax does not choose,
+    # so drafts are rejected by construction. A random prompt's drafts
+    # are cut to their grammar-legal prefix, mostly forced tokens, and
+    # every one is accepted (rejected == 0 on rng(0..11))
+    doc = '{"name": "zzzzzzzz", "age": 42, "ok": true}'
+    prompt = [vocab.tokens.index(ch) for ch in doc]
     outs = {}
     for mode in ("off", "ngram"):
         engine = InferenceEngine(
@@ -654,25 +659,3 @@ async def test_guided_phases_metric_and_snapshot():
     text = MetricsRegistry().exposition().decode()
     assert 'dynamo_guided_requests_total{outcome="ok"}' in text
 
-
-def test_guided_bench_artifact_schema():
-    """The bench rung (bench.guided_measurement): artifact fields for
-    the constrained-vs-free ITL comparison, the grammar-compiler
-    micro-bench, and the <5% masking-overhead bar — met on the CPU rung
-    (paired medians over shared engine cycles, so the number is stable
-    enough to assert)."""
-    out = bench.guided_measurement(
-        TINY_GQA, 16, on_tpu=False, family="gqa", concurrency=4, osl=32,
-    )
-    for key in ("guided_itl_ms", "free_itl_ms", "free_itl_ms_baseline",
-                "masking_overhead_frac", "grammar_compiler", "bars"):
-        assert key in out, key
-    assert out["bars"]["masking_itl_overhead_max"] == 0.05
-    assert out["guided_tokens"] > 0 and out["free_tokens"] > 0
-    comp = out["grammar_compiler"]
-    assert comp["compiles"] + comp["hits"] > 0
-    assert comp["compile_ms_total"] >= 0
-    assert "hit_rate" in comp
-    # the acceptance bar itself, on the CPU rung
-    assert out["masking_overhead_frac"] is not None
-    assert out["masking_overhead_frac"] <= 0.05, out

@@ -422,7 +422,7 @@ async def test_migration_resume_corrupt_redrives_from_pristine_copy():
     assert integrity_snapshot()["migration.resume"] == before + 1
 
 
-async def test_migration_resume_engine_intake_bit_identical():
+async def test_migration_resume_engine_intake_bit_identical(decode_schedule):
     """Real-engine leg: a stamped resume prompt that arrives corrupted is
     refused (IntegrityError, no prefill of poison); the same pristine
     request then continues BIT-IDENTICAL to the uninjected greedy run."""
@@ -441,7 +441,7 @@ async def test_migration_resume_engine_intake_bit_identical():
         drt, spec=spec,
         engine_config=EngineConfig(
             page_size=4, num_pages=128, max_pages_per_seq=32,
-            max_decode_slots=4, prefill_buckets=(32, 64),
+            max_decode_slots=4, prefill_buckets=(32, 64), **decode_schedule,
         ),
         model_name="tiny-test",
     )

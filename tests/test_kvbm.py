@@ -118,9 +118,9 @@ def test_host_evictions_cascade_to_disk(tmp_path):
 # ------------------------------------------------- engine offload + onboard
 
 
-async def test_engine_offload_then_onboard_after_g1_eviction():
+async def test_engine_offload_then_onboard_after_g1_eviction(decode_schedule):
     kvbm = KvBlockManager(KvbmConfig(host_bytes=1 << 20))
-    engine = InferenceEngine(SPEC, small_config(), kvbm=kvbm)
+    engine = InferenceEngine(SPEC, small_config(**decode_schedule), kvbm=kvbm)
     prompt = list(range(30, 30 + 13))  # 3 complete blocks of 4
     want = await run(engine, prompt)
 
@@ -145,13 +145,13 @@ async def test_engine_offload_then_onboard_after_g1_eviction():
     await engine.close()
 
 
-async def test_kvbm_disk_tier_roundtrip(tmp_path):
+async def test_kvbm_disk_tier_roundtrip(tmp_path, decode_schedule):
     """Blocks pushed all the way to disk still serve onboards."""
     kvbm = KvBlockManager(KvbmConfig(
         host_bytes=4096,  # tiny G2: prompt blocks spill to disk quickly
         disk_bytes=1 << 20, disk_dir=str(tmp_path / "kv"),
     ))
-    engine = InferenceEngine(SPEC, small_config(), kvbm=kvbm)
+    engine = InferenceEngine(SPEC, small_config(**decode_schedule), kvbm=kvbm)
     prompt = list(range(40, 40 + 13))
     want = await run(engine, prompt)
     engine.offload.flush()
@@ -167,15 +167,18 @@ async def test_kvbm_disk_tier_roundtrip(tmp_path):
     await engine.close()
 
 
-async def test_kvbm_output_parity_with_and_without():
-    """Offloading must never change outputs (reference determinism tests)."""
+async def test_kvbm_output_parity_with_and_without(decode_schedule):
+    """Offloading must never change outputs (reference determinism tests).
+    The plain engine keeps the default schedule: the one under test must
+    agree with it token for token on either."""
     prompt = list(range(50, 50 + 11))
     plain = InferenceEngine(SPEC, small_config())
     want = await run(plain, prompt)
     await plain.close()
 
     with_kvbm = InferenceEngine(
-        SPEC, small_config(), kvbm=KvBlockManager(KvbmConfig(host_bytes=1 << 20))
+        SPEC, small_config(**decode_schedule),
+        kvbm=KvBlockManager(KvbmConfig(host_bytes=1 << 20)),
     )
     got = await run(with_kvbm, prompt)
     assert got == want

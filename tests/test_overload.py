@@ -544,7 +544,9 @@ async def test_preempt_fault_site_skips_preemption_cleanly():
         await eng.close()
 
 
-async def test_page_pressure_preemption_frees_pages_for_interactive():
+async def test_page_pressure_preemption_frees_pages_for_interactive(
+    decode_schedule,
+):
     """OutOfPages at an interactive prefill must preempt a batch stream
     (reason=interactive_pages) and retry — NOT bounce the interactive
     request with 'kv pages exhausted' (review-found: the free-slot scan
@@ -552,7 +554,8 @@ async def test_page_pressure_preemption_frees_pages_for_interactive():
     # 15 usable pages (allocator adds the trash page): the batch
     # stream's clamped budget needs 16, so it must stall
     cfg = small_config(num_pages=15, max_pages_per_seq=16,
-                       max_decode_slots=2, prefill_buckets=(8, 16, 32))
+                       max_decode_slots=2, prefill_buckets=(8, 16, 32),
+                       **decode_schedule)
     eng = InferenceEngine(SPEC, cfg)
     try:
         # budget (clamped to the 64-token context) EXCEEDS the 15-page

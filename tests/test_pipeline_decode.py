@@ -456,3 +456,19 @@ async def test_async_admission_waves_never_refeed_first_token(monkeypatch):
     for _ in range(3):
         got = await run(True, True)
         assert got == want
+
+
+async def test_eager_readmission_fills_slot_in_same_cycle():
+    """A finished slot's replacement must start its prefill in the SAME
+    step cycle that processed the finishing burst, not wait for the next
+    admission pass. With one slot, B can only enter through the eager
+    path the moment A's burst finishes: the engine counts those passes."""
+    engine = InferenceEngine(SPEC, _cfg(True, num_pages=64, slots=1))
+    await engine.start()
+    outs = await asyncio.gather(
+        _collect(engine, [7, 11, 19], 6), _collect(engine, [5, 13, 23], 6),
+    )
+    assert [len(o) for o in outs] == [6, 6]
+    assert engine.eager_readmits >= 1
+    assert engine.allocator.active_pages == 0
+    await engine.close()

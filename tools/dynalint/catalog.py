@@ -75,7 +75,7 @@ FAULT_SITES: dict[str, str] = {
 # EngineConfig.profile every _phase name is also an ``engine.<name>``
 # annotation in a jax.profiler trace (PROFILER_ANNOTATIONS below). DL006-style registry for the
 # SAME reason as METRIC_NAMES: benchmarks/profile_engine.py's
-# attribution sections, bench.py's dispatch_overhead_frac, and the
+# attribution sections, perfbench's span readers, and the
 # dashboards built on profile snapshots reference these exact strings —
 # a renamed phase silently zeroes every consumer. Two-way sync with the
 # code is test-enforced (tests/test_dispatch_profile.py).
@@ -277,7 +277,7 @@ HOT_PATH_ROOTS: dict[str, str] = {
     "dynamo_tpu/engine/core.py::InferenceEngine._thread_loop":
         "the engine step thread — owns the device; every unaccounted "
         "host<->device sync here is serial time added to EVERY decode "
-        "step (the BENCH_r05 dispatch-overhead gap lives here)",
+        "step",
 }
 
 # capability gates whose False branch downgrades a fused/quantized path

@@ -11,7 +11,7 @@ The bug classes these encode are the ones that silently eat serving
 efficiency without failing a single test on CPU:
 
   * DL010 — a host↔device sync on the step thread serializes the device
-    pipeline (the BENCH_r05 dispatch-overhead gap);
+    pipeline;
   * DL011 — a retrace per request turns microseconds into seconds;
   * DL012 — reading a donated buffer is undefined behavior; NOT donating a
     pool doubles its HBM footprint per step;
