@@ -32,7 +32,10 @@ class LayerKind:
     # or "latent" (MLA, models/mla.py): softmax attention over ONE pool of
     # latent rows a layer, ``kv_lora_rank + qk_rope_head_dim`` wide and
     # shared by the heads (``ModelSpec``'s MLA sizes and rope base;
-    # ``num_kv_heads`` and ``window`` are unread for it), and no V pool
+    # ``num_kv_heads`` and ``window`` are unread for it), and no V pool;
+    # or "conv" (LFM2's gated short convolution): ``C * conv(B * x)`` off
+    # one input projection, whose whole state is the ``conv_taps - 1``
+    # last ``B * x`` a sequence: a row of tails and no state matrix
     mixer: str = "softmax"
     # a KDA kind's forms. ``gate_bound`` < 0: the decay a channel is
     # bounded, ``gate_bound * sigmoid(exp(a_log) * (f + dt_bias))`` in
@@ -48,7 +51,14 @@ class LayerKind:
 
     @property
     def recurrent(self) -> bool:
-        """The kind keeps a state row a sequence."""
+        """The kind keeps a row a sequence beside the pages (found
+        through ``llama.StateRows``): convolution tails, and a state
+        matrix where it has one (``state``)."""
+        return self.mixer in ("kda", "ssd", "conv")
+
+    @property
+    def state(self) -> bool:
+        """The kind's row holds a float32 state matrix beside its tails."""
         return self.mixer in ("kda", "ssd")
 
     @property
@@ -172,6 +182,16 @@ class ModelSpec:
     ssm_groups: int = 1
     ssm_conv: int = 4
     ssm_chunk: int = 128
+    # gated short-convolution layers (``LayerKind.mixer == "conv"``): a
+    # causal depthwise convolution of ``conv_taps`` taps, no bias, on
+    # ``B * x`` over ``hidden_size`` channels
+    conv_taps: int = 3
+    # softmax layers norm q and k a head before the rotation (RMSNorm,
+    # one gain ``[head_dim]`` each, shared by the heads)
+    qk_norm: bool = False
+    # what the sigmoid router adds to the chosen scores' sum before it
+    # divides by it (``norm_topk_prob``); a family's published constant
+    moe_norm_eps: float = 1e-20
     # the Falcon-H1 family's fixed scalar multipliers (muP); 1 = absent.
     # ``ssm_multipliers`` scale the z | x | B | C | dt segments of the SSM
     # input projection's output, ``mlp_multipliers`` the gate projection
@@ -496,6 +516,28 @@ class ModelSpec:
             n_group=4, topk_group=2, routed_scaling_factor=2.5,
             n_shared_experts=1, first_k_dense=1,
             expert_clamp=(0.0, 0.5, 0.75), shared_clamp=(0.0, 0.6, 0.4),
+        )
+        base.update(kw)
+        return cls(**base)
+
+    @classmethod
+    def tiny_lfm2(cls, **kw) -> "ModelSpec":
+        """Toy LFM2-MoE architecture: gated short-convolution layers to
+        one QK-normed GQA layer, a leading dense layer, sigmoid routing
+        with a selection-only bias over experts all held, the family's
+        epsilon in the weights' sum."""
+        base = dict(
+            name="tiny-lfm2", vocab_size=96, hidden_size=64,
+            intermediate_size=96, num_layers=4, num_heads=4,
+            num_kv_heads=2, head_dim=16, dtype="float32", rms_eps=1e-5,
+            rope_theta=1e6, tie_embeddings=True, qk_norm=True,
+            layer_kinds=(
+                LayerKind(2, 1e6), LayerKind(0, 0.0, mixer="conv"),
+            ),
+            layer_pattern=(1, 0, 1, 1), conv_taps=3,
+            num_experts=8, num_experts_per_token=4,
+            moe_intermediate_size=32, moe_scoring="sigmoid",
+            moe_norm_eps=1e-6, first_k_dense=1,
         )
         base.update(kw)
         return cls(**base)

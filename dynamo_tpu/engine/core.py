@@ -197,6 +197,7 @@ READMIT_SUMS = {
 # ``<family>.<name>`` and reset_profile_window() zeroes them
 _COUNTER_FAMILIES = (
     "decode_kv", "prefill_kv", "chunked_prefill", "burst_hold", "kda", "ssd",
+    "recurrent_state",
 )
 
 # undisturbed burst times kept a burst length (their smallest is the
@@ -607,6 +608,14 @@ class InferenceEngine:
             {"decode_rows": 0, "prefill_chunks": 0, "rows_resumed": 0}
             if "ssd" in spec.mixers else {}
         )
+        # and what every recurrent mixer shares (a model with recurrent
+        # layers only; beside state_counters() under ``recurrent_state``):
+        # members of the prefill programs with tokens, and those of them
+        # that resumed a state or a tail (start_pos > 0)
+        self.recurrent_state = (
+            {"prefill_chunks": 0, "rows_resumed": 0}
+            if self.fam.recurrent else {}
+        )
         # the state directory's device-side counters [clock, claims, rows
         # missing]: the last host copy and the one on its way
         self.state_stats: np.ndarray | None = None
@@ -878,17 +887,23 @@ class InferenceEngine:
         page = self.config.page_size
         starts = np.asarray(starts, np.int32).reshape(-1, 1)
         nts = np.asarray(nts, np.int32).reshape(-1, 1)
+        # members that continued a state or a tail: a chunk behind a
+        # prompt's first
+        resumed = int(((starts > 0) & (nts > 0)).sum())
         if self.kda:
             from dynamo_tpu.ops.attention import kda_prefill_blocks
 
             self.kda["prefill_blocks"] += kda_prefill_blocks(nts)
-            self.kda["rows_resumed"] += int(((starts > 0) & (nts > 0)).sum())
+            self.kda["rows_resumed"] += resumed
         if self.ssd:
             from dynamo_tpu.ops.attention import ssd_prefill_chunks
 
             self.ssd["prefill_chunks"] += ssd_prefill_chunks(
                 nts, self.spec.ssm_chunk)
-            self.ssd["rows_resumed"] += int(((starts > 0) & (nts > 0)).sum())
+            self.ssd["rows_resumed"] += resumed
+        if self.recurrent_state:
+            self.recurrent_state["prefill_chunks"] += int((nts > 0).sum())
+            self.recurrent_state["rows_resumed"] += resumed
         kv = self.prefill_kv
         for kind, window in self._prefill_walks.items():
             kernel = kind == "latent" and latent_kernel_serves(

@@ -29,6 +29,8 @@ from dynamo_tpu.models.regions import (
     SCOPE_ATTN_FULL,
     SCOPE_ATTN_WINDOW,
     SCOPE_BURST,
+    SCOPE_CONV_MIX,
+    SCOPE_CONV_PROJ,
     SCOPE_EMBED,
     SCOPE_HEAD,
     SCOPE_INDEX,
@@ -135,6 +137,8 @@ def init_params(spec: ModelSpec, key: jax.Array) -> Params:
                     k_gate=extra[0] if kd.head_gate else None,
                 ),
             }
+        elif kd.mixer == "conv":
+            layer = _init_conv_layer(spec, dense, keys, extra)
         elif not kd.paged:
             layer = _init_kda_layer(spec, kd, dense, keys, extra)
         else:
@@ -148,6 +152,11 @@ def init_params(spec: ModelSpec, key: jax.Array) -> Params:
             }
             if spec.attn_gate:
                 layer["w_gate_attn"] = dense(extra[0], (d, nh * vd))
+            if spec.qk_norm:
+                # gains drawn about 1, N(1, 0.1^2): at 1 the two vectors
+                # would drop out of every comparison on random weights
+                layer["q_norm"] = 1 + dense(extra[7], (hd,), scale=0.1)
+                layer["k_norm"] = 1 + dense(extra[8], (hd,), scale=0.1)
             if kd.mixer == "ssd":
                 layer.update(_init_ssd_mixer(spec, dense, extra))
         if spec.attn_bias:
@@ -233,6 +242,22 @@ def _init_ssd_mixer(spec: ModelSpec, dense, extra) -> Params:
     }
 
 
+def _init_conv_layer(spec: ModelSpec, dense, keys, extra) -> Params:
+    """A gated short-convolution layer's mixer weights (its MLP is drawn
+    by the caller): the input projection ``[d, B | C | x]`` and the output
+    projection on the layer's own keys, the taps ``[taps, d]`` N(0, 1 /
+    taps) on ``extra``; no bias."""
+    dtype = jnp.dtype(spec.dtype)
+    d = spec.hidden_size
+    return {
+        "attn_norm": jnp.ones((d,), dtype),
+        "sconv_in": dense(next(keys), (d, 3 * d)),
+        "sconv_out": dense(next(keys), (d, d)),
+        "mlp_norm": jnp.ones((d,), dtype),
+        "sconv_taps": dense(extra[0], (spec.conv_taps, d)),
+    }
+
+
 def _init_kda_layer(spec: ModelSpec, kd, dense, keys, extra) -> Params:
     """A KDA layer's mixer weights (its MLP is drawn by the caller): the
     four big matrices on the layer's own keys like an attention layer's,
@@ -306,6 +331,8 @@ def param_shardings(spec: ModelSpec, mesh: Mesh) -> Params:
         }
         if spec.attn_bias:
             layer.update(bq=ns("tp"), bk=ns("tp"), bv=ns("tp"), bo=ns())
+        if spec.qk_norm:
+            layer.update(q_norm=ns(), k_norm=ns())
         if spec.kind(li).sinks:
             layer["sinks"] = ns("tp")  # per-query-head, rides the head shards
         if spec.is_moe_layer(li):
@@ -344,11 +371,13 @@ class KindPools(NamedTuple):
     (``[L, 2, 0]``) on the V side and where the model has no experts.
 
     A RECURRENT kind's entry in ``pools`` is not pages: on the K side its
-    layers' states ``[layers of the kind, rows + 1, H, dk, dv]`` float32,
-    on the V side the tails of their short convolutions ``[layers, rows +
-    1, taps - 1, 3 (q, k, v), H D]``, a row a live sequence and a trash
-    row last; ``rows`` (K side) is the directory that finds a sequence's
-    row from its block table (``StateRows``).
+    layers' states ``[layers of the kind, rows + 1, H, dk, dv]`` float32
+    (None for a kind that keeps no state matrix, ``LayerKind.state``
+    false: a short convolution's whole state is its tail), on the V side
+    the tails of their short convolutions ``[layers, rows + 1, taps - 1,
+    ...channels]``, a row a live sequence and a trash row last; ``rows``
+    (K side) is the directory that finds a sequence's row from its block
+    table (``StateRows``).
 
     A LATENT kind's entry (``LayerKind.latent``) is ONE pool of latent
     rows on the K side, ``[layers of the kind, num_pages, page_size, D]``
@@ -375,24 +404,28 @@ class PagesAndState(NamedTuple):
     array, and the benchmark's accepted tests read ``pools[ki].shape`` of
     a pages-only and of a state-only kind (``tests/perfbench/
     test_perfbench_mimo.py``, ``test_perfbench_solar.py``); only
-    ``_entry_parts`` / ``_entry_of`` know the three shapes."""
+    ``_entry_parts`` / ``_entry_of`` know the shapes."""
 
     pages: Any
     state: jax.Array
 
 
 def _entry_parts(kd, entry):
-    """(pages, state) of a kind's entry in ``KindPools.pools`` (or of the
-    one pool of a model without kinds): None for what the kind lacks."""
-    if kd.paged and kd.recurrent:
-        return entry.pages, entry.state
-    return (None, entry) if kd.recurrent else (entry, None)
+    """(pages, rows) of a kind's entry on one side of the cache (or of
+    the one pool of a model without kinds): None for what it keeps none
+    of there. A bare array is pages where the kind is paged, else its
+    rows (states on the K side, tails on the V side)."""
+    if isinstance(entry, PagesAndState):
+        return entry
+    return (entry, None) if kd.paged else (None, entry)
 
 
-def _entry_of(kd, pages, state):
-    if kd.paged and kd.recurrent:
-        return PagesAndState(pages, state)
-    return state if kd.recurrent else pages
+def _entry_of(pages, rows):
+    """The entry of a kind that keeps ``pages`` and ``rows`` on a side:
+    the bare array where it keeps one of them, None where neither."""
+    if pages is not None and rows is not None:
+        return PagesAndState(pages, rows)
+    return rows if pages is None else pages
 
 
 def kind_pages(spec: ModelSpec, side, ki: int):
@@ -590,19 +623,20 @@ def init_cache(
     n_counts = spec.experts_here[0] + 3 if spec.num_experts else 0
     R1 = state_rows + 1  # the last row is the trash row
     H, D = spec.kda_heads, spec.kda_head_dim
+    # a row of each recurrent mixer: its state matrix (float32, where
+    # the kind keeps one: ``LayerKind.state``) and its convolution tails
+    state_row = {
+        "kda": (H, D, D),
+        "ssd": (spec.ssm_heads, spec.ssm_head_dim, spec.ssm_state),
+    }
+    tail_row = {
+        "kda": (spec.kda_conv - 1, 3, H * D),
+        "ssd": (spec.ssm_conv - 1, spec.ssm_conv_dim),
+        "conv": (spec.conv_taps - 1, spec.hidden_size),
+    }
 
-    def state(n, kd):
-        if kd.mixer == "ssd":
-            return jnp.zeros(
-                (n, R1, spec.ssm_heads, spec.ssm_head_dim, spec.ssm_state),
-                jnp.float32)
-        return jnp.zeros((n, R1, H, D, D), jnp.float32)
-
-    def tails(n, kd):
-        if kd.mixer == "ssd":
-            return jnp.zeros(
-                (n, R1, spec.ssm_conv - 1, spec.ssm_conv_dim), dtype)
-        return jnp.zeros((n, R1, spec.kda_conv - 1, 3, H * D), dtype)
+    def rows(n, shape, dt):
+        return jnp.zeros((n, R1, *shape), dt)
 
     def latent(n):
         from dynamo_tpu.models import mla
@@ -615,32 +649,30 @@ def init_cache(
         if kd.latent:
             return latent(n)
         return _entry_of(
-            kd,
             side(n, kd.num_kv_heads, spec.head_dim) if kd.paged else None,
-            state(n, kd) if kd.recurrent else None,
+            rows(n, state_row[kd.mixer], jnp.float32) if kd.state else None,
         )
 
     def v_side(n, kd):
         if kd.latent:
             return None
         return _entry_of(
-            kd,
             side(n, kd.num_kv_heads, spec.v_dim) if kd.paged else None,
-            tails(n, kd) if kd.recurrent else None,
+            rows(n, tail_row[kd.mixer], dtype) if kd.recurrent else None,
         )
 
-    rows = None
+    directory = None
     if spec.has_recurrent:
         if state_rows < 1:
             raise ValueError("a model with recurrent layers needs state_rows")
-        rows = StateRows(
+        directory = StateRows(
             jnp.zeros((1, R1), jnp.int32), jnp.zeros((1, R1), jnp.int32),
             jnp.zeros((1, 3), jnp.int32),
         )
     return (
         KindPools(
             tuple(k_side(n, kd) for n, kd in zip(n_layers, spec.layer_kinds)),
-            jnp.zeros((spec.num_layers, 2, n_counts), jnp.int32), rows,
+            jnp.zeros((spec.num_layers, 2, n_counts), jnp.int32), directory,
         ),
         KindPools(
             tuple(v_side(n, kd) for n, kd in zip(n_layers, spec.layer_kinds)),
@@ -855,8 +887,9 @@ def _attn_qkv(
     spec: ModelSpec, li: int, lp: Params, x: jax.Array, positions: jax.Array
 ):
     """x: [..., d], positions: [...] -> q [..., nh, hd], k [..., nkv, hd]
-    with rope applied, v [..., nkv, vd] scaled by ``value_scale``; nkv and
-    the rope base are the layer kind's."""
+    with rope applied (after an RMSNorm a head where the model has
+    ``qk_norm``), v [..., nkv, vd] scaled by ``value_scale``; nkv and the
+    rope base are the layer kind's."""
     kd = spec.kind(li)
     lead = x.shape[:-1]
     x = _times(x, spec.attention_in_multiplier)
@@ -870,6 +903,9 @@ def _attn_qkv(
     v = v.reshape(*lead, kd.num_kv_heads, spec.v_dim)
     if spec.value_scale != 1.0:
         v = v * jnp.asarray(spec.value_scale, v.dtype)
+    if spec.qk_norm:
+        q = rms_norm(q, lp["q_norm"], spec.rms_eps)
+        k = rms_norm(k, lp["k_norm"], spec.rms_eps)
     if spec.use_rope:
         q = rope_spec(spec, q, positions, kd.rope_theta)
         k = rope_spec(spec, k, positions, kd.rope_theta)
@@ -984,6 +1020,30 @@ def _logits(spec: ModelSpec, params: Params, x: jax.Array) -> jax.Array:
     return _times((x @ head).astype(jnp.float32), spec.lm_head_multiplier)
 
 
+# ----------------------------------------------- short convolutions' tails
+
+
+def _causal_taps(taps: jax.Array, ext: jax.Array, T: int) -> jax.Array:
+    """A causal depthwise convolution a channel, in float32: ``sum_i
+    taps[i] * ext[:, i:i + T]``. taps: [n, channels]; ext: [N, n - 1 + T,
+    channels], the sequence with the ``n - 1`` rows before it in front
+    (its tail; zeros at a sequence's start). -> [N, T, channels]."""
+    taps = taps.astype(jnp.float32)
+    return sum(
+        taps[i] * ext[:, i:i + T].astype(jnp.float32)
+        for i in range(taps.shape[0])
+    )
+
+
+def _new_tail(ext: jax.Array, num_tokens: jax.Array, n: int) -> jax.Array:
+    """The tail a sequence leaves: the ``n`` rows of ``ext`` [N, n + T,
+    channels] that end at its last REAL token (num_tokens: [N]); a row
+    without tokens keeps the tail it came with."""
+    return jax.vmap(
+        lambda e, at: jax.lax.dynamic_slice_in_dim(e, at, n, axis=0)
+    )(ext, num_tokens)
+
+
 # ------------------------------------------------------------------- KDA
 
 
@@ -1006,13 +1066,8 @@ def _kda_inputs(
         )
     with jax.named_scope(SCOPE_KDA_CONV):
         ext = jnp.concatenate([tail.astype(x.dtype), x], axis=1)
-        taps = jnp.concatenate(
-            [lp["conv_q"], lp["conv_k"], lp["conv_v"]], axis=1
-        ).astype(f32)
-        conv = sum(
-            taps[i] * ext[:, i:i + T].astype(f32)
-            for i in range(spec.kda_conv)
-        )
+        conv = _causal_taps(jnp.concatenate(
+            [lp["conv_q"], lp["conv_k"], lp["conv_v"]], axis=1), ext, T)
         q, k, v = (
             y.reshape(N, T, H, D)
             for y in jnp.split(jax.nn.silu(conv), 3, axis=-1)
@@ -1083,10 +1138,7 @@ def _kda_prefill(
             q, k, v, g, beta, s_pool, idx, fresh, layer=lj
         )
         # the new tail: the projections of the last taps - 1 REAL tokens
-        new_tail = jax.vmap(
-            lambda e, n: jax.lax.dynamic_slice_in_dim(
-                e, n, spec.kda_conv - 1, axis=0)
-        )(ext, num_tokens)
+        new_tail = _new_tail(ext, num_tokens, spec.kda_conv - 1)
         c_pool = c_pool.at[lj, idx].set(
             new_tail.reshape(N, *c_pool.shape[2:]).astype(c_pool.dtype)
         )
@@ -1155,11 +1207,8 @@ def _ssd_inputs(spec: ModelSpec, lp: Params, h: jax.Array, tail: jax.Array):
         z, xbc, dt = jnp.split(zxbcdt, (d_ssm, d_ssm + ch), axis=-1)
     with jax.named_scope(SCOPE_SSM_CONV):
         ext = jnp.concatenate([tail.astype(xbc.dtype), xbc], axis=1)
-        taps = lp["ssm_conv"].astype(f32)
-        conv = sum(
-            taps[i] * ext[:, i:i + T].astype(f32)
-            for i in range(spec.ssm_conv)
-        ) + lp["ssm_conv_bias"].astype(f32)
+        conv = _causal_taps(lp["ssm_conv"], ext, T) + (
+            lp["ssm_conv_bias"].astype(f32))
         x, B, C = jnp.split(
             jax.nn.silu(conv).astype(h.dtype), (d_ssm, d_ssm + G * S), axis=-1)
     with jax.named_scope(SCOPE_SSM_GATES):
@@ -1205,10 +1254,7 @@ def _ssd_prefill(
             idx, fresh, layer=lj, chunk=spec.ssm_chunk,
         )
         # the new tail: the projections of the last taps - 1 REAL tokens
-        new_tail = jax.vmap(
-            lambda e, n: jax.lax.dynamic_slice_in_dim(
-                e, n, spec.ssm_conv - 1, axis=0)
-        )(ext, num_tokens)
+        new_tail = _new_tail(ext, num_tokens, spec.ssm_conv - 1)
         c_pool = c_pool.at[lj, idx].set(new_tail.astype(c_pool.dtype))
     with jax.named_scope(SCOPE_OUT):
         return _ssd_out(spec, lp, y, z), s_pool, c_pool
@@ -1248,12 +1294,83 @@ def _ssd_whole(spec: ModelSpec, kd, lp: Params, h: jax.Array) -> jax.Array:
     return _ssd_out(spec, lp, y[0], z[0])
 
 
+# ------------------------------------------- the gated short convolution
+
+
+def _conv_mix(lp: Params, h: jax.Array, tail: jax.Array):
+    """A gated short convolution (LFM2) up to its output projection: ``[B
+    | C | x] = h W_in``, ``z = B * x``, ``y = C * conv(z)`` with the taps
+    applied in float32 over ``z`` in the activation dtype (what a tail
+    holds). h: [N, T, d]; tail: [N, taps - 1, d], the ``z`` of the ``taps
+    - 1`` tokens before (zeros at a sequence's start). Returns (y [N, T,
+    d], ext [N, taps - 1 + T, d]: ``z`` with the tail in front, of which
+    the caller keeps the new tail)."""
+    with jax.named_scope(SCOPE_CONV_PROJ):
+        B, C, x = jnp.split(h @ lp["sconv_in"], 3, axis=-1)
+    with jax.named_scope(SCOPE_CONV_MIX):
+        ext = jnp.concatenate([tail.astype(h.dtype), B * x], axis=1)
+        y = C.astype(jnp.float32) * _causal_taps(
+            lp["sconv_taps"], ext, h.shape[1])
+    return y.astype(h.dtype), ext
+
+
+@jax.named_scope(SCOPE_OUT)
+def _conv_out(lp: Params, y: jax.Array) -> jax.Array:
+    with jax.named_scope(SCOPE_CONV_PROJ):
+        return y @ lp["sconv_out"]
+
+
+def _conv_prefill(
+    spec: ModelSpec, kd, lp: Params, h: jax.Array, s_pool, c_pool, lj: int,
+    idx: jax.Array, fresh: jax.Array, num_tokens: jax.Array,
+):
+    """A short-convolution mixer over N sequences' new tokens, from and to
+    their rows' tails (the kind keeps no state matrix: ``s_pool`` is None
+    and goes back as it came). h: [N, T, d]; idx, fresh, num_tokens: [N].
+    Returns (out [N, T, d], s_pool, c_pool)."""
+    with jax.named_scope(SCOPE_QKV):
+        with jax.named_scope(SCOPE_CONV_MIX):
+            tail = jnp.where(fresh[:, None, None], 0, c_pool[lj, idx])
+        y, ext = _conv_mix(lp, h, tail)
+    with jax.named_scope(SCOPE_KV), jax.named_scope(SCOPE_CONV_MIX):
+        # the new tail: the z of the last taps - 1 REAL tokens
+        new_tail = _new_tail(ext, num_tokens, spec.conv_taps - 1)
+        c_pool = c_pool.at[lj, idx].set(new_tail.astype(c_pool.dtype))
+    return _conv_out(lp, y), s_pool, c_pool
+
+
+def _conv_decode(
+    spec: ModelSpec, kd, lp: Params, h: jax.Array, s_pool, c_pool, lj: int,
+    idx: jax.Array,
+):
+    """A short-convolution mixer's decode step over the slots' tails. h:
+    [B, d]; idx: [B] (the trash row for a slot that owns none). Returns
+    (out [B, d], s_pool, c_pool)."""
+    with jax.named_scope(SCOPE_QKV):
+        with jax.named_scope(SCOPE_CONV_MIX):
+            tail = c_pool[lj, idx]
+        y, ext = _conv_mix(lp, h[:, None], tail)
+    with jax.named_scope(SCOPE_KV), jax.named_scope(SCOPE_CONV_MIX):
+        c_pool = c_pool.at[lj, idx].set(ext[:, 1:].astype(c_pool.dtype))
+    return _conv_out(lp, y[:, 0]), s_pool, c_pool
+
+
+def _conv_whole(spec: ModelSpec, kd, lp: Params, h: jax.Array) -> jax.Array:
+    """A short-convolution mixer over one whole sequence from an empty
+    tail, keeping none (embeddings, ``reference_forward``). h: [T, d] ->
+    [T, d]."""
+    tail = jnp.zeros((1, spec.conv_taps - 1, h.shape[-1]), h.dtype)
+    y, _ = _conv_mix(lp, h[None], tail)
+    return _conv_out(lp, y[0])
+
+
 # what a recurrent mixer is called with, by ``LayerKind.mixer``: (prefill
 # over [N, T, d] rows, decode step over [B, d] slots, a whole sequence),
 # each ``(spec, kind, layer weights, ...)``
 _RECURRENT = {
     "kda": (_kda_prefill, _kda_decode, _kda_whole),
     "ssd": (_ssd_prefill, _ssd_decode, _ssd_whole),
+    "conv": (_conv_prefill, _conv_decode, _conv_whole),
 }
 
 
@@ -1278,7 +1395,7 @@ def _mixers(spec: ModelSpec, li: int, kp, vp, attend, recur, latent=None):
     if kd.recurrent:
         rec, s_pool, c_pool = recur(_RECURRENT[kd.mixer], kd, s_pool, c_pool)
         mix = rec if mix is None else mix + rec
-    return mix, _entry_of(kd, k_pg, s_pool), _entry_of(kd, v_pg, c_pool)
+    return mix, _entry_of(k_pg, s_pool), _entry_of(v_pg, c_pool)
 
 
 def _state_owner(block_tables: jax.Array) -> jax.Array:

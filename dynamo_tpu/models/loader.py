@@ -185,6 +185,34 @@ def spec_from_hf_config(cfg: dict, name: str | None = None) -> ModelSpec:
             mlp_multipliers=tuple(cfg.get("mlp_multipliers") or ()),
             **{key: float(cfg.get(key, 1.0)) for key in _FALCON_SCALARS},
         )
+    if model_type == "lfm2_moe":
+        # gated short-convolution layers among QK-normed GQA layers
+        # (``layer_types``), leading dense layers, then a sigmoid router
+        # with a selection-only bias over experts without a shared one
+        from dynamo_tpu.engine.config import LayerKind
+
+        if cfg.get("conv_bias"):
+            raise NotImplementedError("lfm2_moe: conv_bias true")
+        if not cfg.get("use_expert_bias", True):
+            raise NotImplementedError("lfm2_moe: use_expert_bias false")
+        theta = float((cfg.get("rope_parameters") or {}).get("rope_theta")
+                      or cfg.get("rope_theta") or 1e6)
+        extras.update(
+            layer_kinds=(LayerKind(int(cfg["num_key_value_heads"]), theta),
+                         LayerKind(0, 0.0, mixer="conv")),
+            layer_pattern=tuple(
+                0 if t == "full_attention" else 1 for t in cfg["layer_types"]),
+            conv_taps=int(cfg["conv_L_cache"]), qk_norm=True,
+            rope_theta=theta, rms_eps=float(cfg.get("norm_eps", 1e-5)),
+            # the config carries no key for the tie; the family ties
+            tie_embeddings=bool(cfg.get("tie_word_embeddings", True)),
+            first_k_dense=int(cfg.get("num_dense_layers") or 0),
+            moe_scoring="sigmoid",
+            routed_scaling_factor=float(
+                cfg.get("routed_scaling_factor") or 1.0),
+            norm_topk_prob=bool(cfg.get("norm_topk_prob", True)),
+            moe_norm_eps=1e-6,  # the family's published code
+        )
     # YaRN rope scaling (gpt-oss, DeepSeek-R1)
     rs = cfg.get("rope_scaling") or {}
     if (rs.get("rope_type") or rs.get("type")) == "yarn":
@@ -200,7 +228,7 @@ def spec_from_hf_config(cfg: dict, name: str | None = None) -> ModelSpec:
             rope_mscale_all_dim=float(rs.get("mscale_all_dim") or 0),
             rope_truncate=bool(rs.get("truncate", True)),
         )
-    return ModelSpec(
+    kw = dict(
         name=name or cfg.get("_name_or_path") or model_type,
         vocab_size=int(cfg["vocab_size"]),
         hidden_size=hidden,
@@ -228,9 +256,9 @@ def spec_from_hf_config(cfg: dict, name: str | None = None) -> ModelSpec:
         v_head_dim=int(cfg.get("v_head_dim") or 0),
         q_lora_rank=int(cfg.get("q_lora_rank") or 0),
         nextn_predict_layers=int(cfg.get("num_nextn_predict_layers") or 0),
-        **moe,
-        **extras,
     )
+    # a family's own keys win over the llama-family defaults above
+    return ModelSpec(**{**kw, **moe, **extras})
 
 
 def _no_latent_kind(spec: ModelSpec) -> None:
@@ -255,6 +283,8 @@ def hf_config_from_spec(spec: ModelSpec) -> dict:
         model_type = "falcon_h1"
     elif "kda" in spec.mixers:
         model_type = "solar_open2"
+    elif "conv" in spec.mixers:
+        model_type = "lfm2_moe"
     elif spec.attn_sinks:
         model_type = "gpt_oss"
     elif spec.num_experts:
@@ -303,6 +333,25 @@ def hf_config_from_spec(spec: ModelSpec) -> dict:
                 "num_kv_heads": None,
             },
             kda_use_full_proj=False, kda_allow_neg_eigval=spec.kda_neg_eigval,
+        )
+        del cfg["num_local_experts"]
+    if model_type == "lfm2_moe":
+        attn = next(kd for kd in spec.layer_kinds if kd.paged)
+        cfg.update(
+            intermediate_size=spec.intermediate_size,
+            num_experts=spec.num_experts,
+            num_key_value_heads=attn.num_kv_heads,
+            num_dense_layers=spec.first_k_dense,
+            layer_types=[
+                "conv" if spec.kind(i).mixer == "conv" else "full_attention"
+                for i in range(spec.num_layers)
+            ],
+            conv_L_cache=spec.conv_taps, conv_bias=False,
+            norm_eps=spec.rms_eps, use_expert_bias=True,
+            norm_topk_prob=spec.norm_topk_prob,
+            routed_scaling_factor=spec.routed_scaling_factor,
+            rope_parameters={
+                "rope_theta": attn.rope_theta, "rope_type": "default"},
         )
         del cfg["num_local_experts"]
     if model_type == "falcon_h1":
@@ -555,9 +604,55 @@ def _dest_map(
     return m
 
 
+def _dest_map_lfm2(spec: ModelSpec) -> dict[str, tuple[tuple, bool, str | None]]:
+    """``_dest_map`` for an ``lfm2_moe`` checkpoint, under the names of
+    the family's published code: ``operator_norm`` / ``ffn_norm`` around a
+    layer's ``conv`` or ``self_attn`` and its ``feed_forward`` (``w1``
+    gate, ``w3`` up, ``w2`` down; an expert layer's ``gate`` is the
+    router and ``expert_bias`` its selection bias), ``embedding_norm``
+    after the last layer, the head the embedding's transpose. The taps are
+    stored ``[channels, 1, taps]``: load_params folds the middle axis."""
+    m: dict[str, tuple[tuple, bool, str | None]] = {
+        "model.embed_tokens.weight": (("embed",), False, None),
+        "model.embedding_norm.weight": (("final_norm",), False, None),
+    }
+    if not spec.tie_embeddings:
+        m["lm_head.weight"] = (("lm_head",), True, None)
+    mlp = (("w1", "w_gate"), ("w3", "w_up"), ("w2", "w_down"))
+    for i in range(spec.num_layers):
+        p = f"model.layers.{i}."
+        li = ("layers", i)
+        m[p + "operator_norm.weight"] = (li + ("attn_norm",), False, None)
+        m[p + "ffn_norm.weight"] = (li + ("mlp_norm",), False, None)
+        if spec.kind(i).mixer == "conv":
+            for hf, ours in (("in_proj", "sconv_in"), ("conv", "sconv_taps"),
+                             ("out_proj", "sconv_out")):
+                m[p + f"conv.{hf}.weight"] = (li + (ours,), True, None)
+        else:
+            for hf, ours in (("q_proj", "wq"), ("k_proj", "wk"),
+                             ("v_proj", "wv"), ("out_proj", "wo")):
+                m[p + f"self_attn.{hf}.weight"] = (li + (ours,), True, None)
+            for hf, ours in (("q_layernorm", "q_norm"),
+                             ("k_layernorm", "k_norm")):
+                m[p + f"self_attn.{hf}.weight"] = (li + (ours,), False, None)
+        f = p + "feed_forward."
+        if not spec.is_moe_layer(i):
+            for hf, ours in mlp:
+                m[f + f"{hf}.weight"] = (li + (ours,), True, None)
+            continue
+        m[f + "gate.weight"] = (li + ("moe", "router"), True, "float32")
+        m[f + "expert_bias"] = (li + ("moe", "score_bias"), False, "float32")
+        for e in range(spec.num_experts):
+            for hf, ours in mlp:
+                m[f + f"experts.{e}.{hf}.weight"] = (
+                    li + ("moe", ours, e), True, None)
+    return m
+
+
 def _is_taps(path: tuple) -> bool:
     """A short convolution's taps, published ``[channels, 1, taps]``."""
-    return str(path[-1]).startswith("conv_") or path[-1] == "ssm_conv"
+    return str(path[-1]).startswith("conv_") or path[-1] in (
+        "ssm_conv", "sconv_taps")
 
 
 def _tree_set(tree: Params, path: tuple, value) -> None:
@@ -613,6 +708,9 @@ def load_params(
             all_names.update(f.keys())
     if spec.kv_lora_rank:
         dest = _dest_map_mla(spec)
+        fused_gpt_oss = False
+    elif "conv" in spec.mixers:
+        dest = _dest_map_lfm2(spec)
         fused_gpt_oss = False
     else:
         dest = _dest_map(spec, all_names)
@@ -807,6 +905,8 @@ def save_params(
     os.makedirs(model_dir, exist_ok=True)
     if spec.kv_lora_rank:
         dest = _dest_map_mla(spec)
+    elif "conv" in spec.mixers:
+        dest = _dest_map_lfm2(spec)
     elif spec.moe_bias:
         # gpt-oss exports use the FUSED expert naming (synthesized
         # below); the name hint selects the gpt_oss scheme so the dest

@@ -183,7 +183,8 @@ def route(spec: ModelSpec, lp: Params, x: jax.Array):
             choice = jnp.where(jnp.repeat(kept, gsz, axis=-1), choice, 0.0)
         topv, topi = _top_k(choice, k, of=scores)  # [T, k]
         if spec.norm_topk_prob:
-            topv = topv / (topv.sum(axis=-1, keepdims=True) + 1e-20)
+            topv = topv / (
+                topv.sum(axis=-1, keepdims=True) + spec.moe_norm_eps)
         topv = topv * spec.routed_scaling_factor
     else:
         # softmax-all + top-k renormalize == softmax over the top-k

@@ -45,6 +45,10 @@ SCOPE_KDA_GATES = "kda_gates"  # KDA: decay and beta
 SCOPE_SSM_PROJ = "ssm_proj"  # SSD: the input and output projections, mup
 SCOPE_SSM_CONV = "ssm_conv"  # SSD: tails, the causal convolution on x|B|C
 SCOPE_SSM_GATES = "ssm_gates"  # SSD: dt, the decays, the gated group norm
+SCOPE_CONV_PROJ = "conv_proj"  # short convolution: input and output
+# projections
+SCOPE_CONV_MIX = "conv_mix"  # short convolution: tail read, B * x, the
+# taps, C *, tail write
 # -- attn_ctx
 SCOPE_KV = "attn_kv"  # KV write + attention over the paged context
 SCOPE_ATTN_WINDOW = "attn_window"
@@ -98,6 +102,7 @@ REGIONS: dict[str, str] = {
     SCOPE_KDA_PROJ: ATTN_PROJ, SCOPE_KDA_CONV: ATTN_PROJ,
     SCOPE_KDA_GATES: ATTN_PROJ, SCOPE_SSM_PROJ: ATTN_PROJ,
     SCOPE_SSM_CONV: ATTN_PROJ, SCOPE_SSM_GATES: ATTN_PROJ,
+    SCOPE_CONV_PROJ: ATTN_PROJ, SCOPE_CONV_MIX: ATTN_PROJ,
     SCOPE_KV: ATTN_CTX, SCOPE_ATTN_WINDOW: ATTN_CTX,
     SCOPE_ATTN_FULL: ATTN_CTX, SCOPE_FUSED_DECODE: ATTN_CTX,
     SCOPE_ATTN_LATENT: ATTN_CTX, SCOPE_PREFILL_LATENT: ATTN_CTX,

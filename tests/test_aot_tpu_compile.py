@@ -496,3 +496,93 @@ def test_ling_decode_program_compiles_for_v5e(v5e, monkeypatch):
     state = k.pools[1]
     assert compiled.memory_analysis().temp_size_in_bytes < (
         state.size * state.dtype.itemsize // 2)
+
+
+def _lfm2_layers(v5e, vocab=2048):
+    """A short-convolution expert layer and an attention expert layer of
+    LFM2-24B-A2B at the published widths, described: spec, weights and the
+    cache of the cell's engine (128 slots, pages of 64; all 64 experts
+    held; heads of 64 in pools padded to 128 lanes; tails and no state
+    pool)."""
+    import dataclasses
+
+    from dynamo_tpu.engine.config import LayerKind, ModelSpec
+    from dynamo_tpu.models import llama
+
+    spec = dataclasses.replace(
+        ModelSpec.tiny_lfm2(), vocab_size=vocab, hidden_size=2048,
+        intermediate_size=11776, num_layers=2, num_heads=32, num_kv_heads=8,
+        head_dim=64, dtype="bfloat16", layer_pattern=(1, 0),
+        layer_kinds=(LayerKind(8, 1e6), LayerKind(0, 0.0, mixer="conv")),
+        num_experts=64, num_experts_per_token=4, moe_intermediate_size=1536,
+        first_k_dense=0)
+
+    def described(tree):
+        return jax.tree.map(
+            lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=v5e),
+            tree)
+
+    params = described(jax.eval_shape(
+        lambda: llama.init_params(spec, jax.random.PRNGKey(0))))
+    k, v = described(jax.eval_shape(
+        lambda: llama.init_cache(spec, 257, 64, state_rows=128)))
+    return spec, params, k, v
+
+
+@pytest.mark.parametrize("rows", [2, 1], ids=["pack-of-2", "single"])
+def test_lfm2_prefill_program_compiles_for_v5e(v5e, monkeypatch, rows):
+    """The prefill programs of the cell, a layer of each kind at the
+    published widths: 1,024 tokens a row through the short convolution
+    from and to the rows' tails AND the QK-normed attention's page write
+    and walk over 64-wide heads in 128-lane pools; the router over 64
+    experts all held, top-4; every leaf donated."""
+    from dynamo_tpu.models import llama
+
+    _as_on_the_chip(monkeypatch)
+    spec, params, k, v = _lfm2_layers(v5e)
+    assert k.pools[0].shape[-1] == 128 and k.pools[1] is None
+    assert v.pools[1].shape == (1, 129, 2, 2048)
+    i32 = jnp.int32
+    if rows == 1:
+        lowered = jax.jit(
+            llama.prefill_forward_impl, static_argnums=(0,),
+            donate_argnums=(5, 6),
+        ).lower(spec, params, _rows(v5e, 1024, dtype=i32),
+                _rows(v5e, 160, dtype=i32), _rows(v5e, dtype=i32), k, v,
+                _rows(v5e, dtype=i32))
+    else:
+        lowered = jax.jit(
+            llama.prefill_forward_batch_impl, static_argnums=(0,),
+            donate_argnums=(5, 6),
+        ).lower(spec, params, _rows(v5e, rows, 1024, dtype=i32),
+                _rows(v5e, rows, 160, dtype=i32), _rows(v5e, rows, dtype=i32),
+                k, v, _rows(v5e, rows, dtype=i32))
+    text = lowered.compile().as_text()
+    assert "%gmm" in text and "conv_mix" in text
+
+
+def test_lfm2_decode_program_compiles_for_v5e(v5e, monkeypatch):
+    """The decode burst of the cell, a layer of each kind at the published
+    widths: 128 slots through the short convolution's tails (plain XLA)
+    AND the fused attention kernel on the padded pool (``attn_full``), the
+    grouped products over 64 groups, 8 steps, the sampler on the device."""
+    from dynamo_tpu.models import llama
+
+    _as_on_the_chip(monkeypatch)
+    spec, params, k, v = _lfm2_layers(v5e)
+    B_, i32, f32 = 128, jnp.int32, jnp.float32
+    compiled = jax.jit(
+        llama.decode_steps_impl, static_argnums=(0,),
+        static_argnames=("n_steps", "n_logprobs"), donate_argnums=(5, 6),
+    ).lower(
+        spec, params, _rows(v5e, B_, dtype=i32), _rows(v5e, B_, 160, dtype=i32),
+        _rows(v5e, B_, dtype=i32), k, v, _rows(v5e, B_, dtype=jnp.bool_),
+        _rows(v5e, B_, dtype=f32), _rows(v5e, B_, dtype=i32),
+        _rows(v5e, B_, dtype=f32), _rows(v5e, B_, dtype=jnp.uint32),
+        _rows(v5e, B_, dtype=i32), n_steps=8, n_logprobs=0,
+    ).compile()
+    text = compiled.as_text()
+    assert "%attn_full" in text and "%gmm" in text and "conv_mix" in text
+    # the tails are updated in place: what the program holds beside its
+    # arguments is far less than an expert layer's weights (1.2 GB)
+    assert compiled.memory_analysis().temp_size_in_bytes < 256 * 2**20

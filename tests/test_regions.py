@@ -47,11 +47,13 @@ def _spec(family: str) -> ModelSpec:
         return ModelSpec.tiny_solar(held_experts=(4, 2))
     if family == "linlat":  # KDA and latent kinds in one model
         return ModelSpec.tiny_ling3(held_experts=(4, 4))
+    if family == "shortconv":  # a tail-only recurrent kind beside GQA
+        return ModelSpec.tiny_lfm2()
     mod = {"mimo": "test_mimo", "latent": "test_joyai"}[family]
     return importlib.import_module(mod).SPEC
 
 
-FAMILIES = ("dense", "mimo", "latent", "kda", "linlat")
+FAMILIES = ("dense", "mimo", "latent", "kda", "linlat", "shortconv")
 PAGE, PAGES, B, T = 4, 8, 4, 16
 
 
@@ -258,6 +260,11 @@ def test_every_instruction_resolves_and_the_unnamed_are_bookkeeping(
         "linlat": {"kda_proj", "kda_conv", "kda_gates", "state_rows",
                    "latent_q", "latent_kv", "moe_shared", "moe_route",
                    "moe_combine", "mlp"},
+        # the short convolution's two regions beside the QK-normed GQA
+        # layer's (its norm rides in attn_qkv) and the experts'
+        "shortconv": {"conv_proj", "conv_mix", "attn_qkv", "attn_full",
+                      "state_rows", "moe_route", "moe_dispatch",
+                      "moe_grouped", "moe_combine", "mlp"},
     }[family]
     if family in ("latent", "linlat") and program == "decode":
         want |= {"latent_absorb", "attn_latent"}

@@ -197,6 +197,13 @@ PROFILE_COUNTERS: dict[str, str] = {
     "recurrent_state.row_missing": "sequences whose row was not where "
                                    "their block table says (their output "
                                    "is wrong): must read 0",
+    "recurrent_state.prefill_chunks": "members of prefill programs with a "
+                                      "real token (a prompt or a chunk of "
+                                      "one), over the dispatched prefills; "
+                                      "host-side, a layer's worth",
+    "recurrent_state.rows_resumed": "those of them that resumed a state or "
+                                    "a tail (start_pos > 0): a chunk "
+                                    "behind a prompt's first",
 }
 
 # jax.profiler.TraceAnnotation names the profiled engine writes into a
