@@ -266,7 +266,7 @@ def main() -> None:
         # compile every serving shape BEFORE the measured window: the
         # full admission wave (packed prefill + burst programs), the
         # single-prompt prefill + width-1 fused sample (straggler), and
-        # the ramp-up capped-burst program (trickle)
+        # the short burst program (trickle)
         async def warm_one(i: int):
             toks = rng.integers(3, spec.vocab_size, ISL).tolist()
             async for _ in engine.generate(

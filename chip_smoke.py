@@ -813,7 +813,7 @@ def one_chip_mode() -> None:
 
     spec = smoke_spec()
     # what `cli run --out engine` builds, with 8-step decode bursts (and
-    # the 4-step ramp-up burst) instead of single steps
+    # the 4-step short burst) instead of single steps
     cfg = EngineConfig(pipeline_decode=True, decode_steps_per_dispatch=8)
     say(f"spec: {spec.name}: Llama-3-8B widths (hidden {spec.hidden_size}, "
         f"mlp {spec.intermediate_size}, {spec.num_heads} Q / "

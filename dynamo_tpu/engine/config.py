@@ -620,12 +620,16 @@ class EngineConfig:
     # later (never blocks the step thread on the d2h RTT); off = the
     # synchronous sample-and-emit path
     async_admissions: bool = True
-    # decode burst cap during RAMP-UP: applies only while prompts are
-    # waiting AND the batch is under half full (n_active*2 < slots) —
-    # there, a full burst would make each queued prompt wait burst *
-    # step_ms before its prefill, inflating TTFT. At >= 50% occupancy
-    # full bursts win (admissions interleave without flushing the
-    # pipeline). 0 = never cap.
+    # the SHORT decode burst: the length a burst takes while a shorter
+    # one would let somebody in sooner (engine/core.py _short_burst).
+    # That is while the queue is empty beside a free slot (arrivals pace
+    # the engine: the next prompt's prefill waits for the burst in
+    # flight, and its first token rides home on its slot's first burst,
+    # so both waits follow the burst's length), and while prompts are
+    # waiting under half occupancy (n_active*2 < slots: the ramp-up).
+    # Otherwise full bursts: a backlog beside a batch at least half full,
+    # or no free slot. One more compiled decode program where it differs
+    # from decode_steps_per_dispatch and 1. 0 = never shorten.
     decode_steps_admit_pending: int = 4
     # chunked prefill (ref: vLLM max_num_batched_tokens pass-through):
     # prompts whose uncached tail exceeds this run as a sequence of

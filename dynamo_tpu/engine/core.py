@@ -196,8 +196,8 @@ READMIT_SUMS = {
 # attribute of its name: profile_snapshot() carries them as
 # ``<family>.<name>`` and reset_profile_window() zeroes them
 _COUNTER_FAMILIES = (
-    "decode_kv", "prefill_kv", "chunked_prefill", "burst_hold", "kda", "ssd",
-    "recurrent_state",
+    "decode_kv", "prefill_kv", "chunked_prefill", "burst_hold",
+    "decode_bursts", "kda", "ssd", "recurrent_state",
 )
 
 # undisturbed burst times kept a burst length (their smallest is the
@@ -417,9 +417,9 @@ class InferenceEngine:
         )
         self._slots: list[_Slot | None] = [None] * self.config.max_decode_slots
         # the decode burst lengths this engine dispatches — the full
-        # burst, the ramp-up-capped one and the single step (guided masks,
-        # the last tokens before the context cap): one compiled program
-        # each, all walked by precompile()
+        # burst, the short one (_short_burst) and the single step (guided
+        # masks, the last tokens before the context cap): one compiled
+        # program each, all walked by precompile()
         full = max(1, self.config.decode_steps_per_dispatch)
         self._burst_lengths = sorted({
             1, full,
@@ -591,6 +591,11 @@ class InferenceEngine:
         # the running burst (``held=1`` on their ``engine.launch``)
         self.burst_hold = {"begun": 0, "overran": 0, "admissions": 0,
                            "admissions_held": 0}
+        # decode bursts dispatched at each compiled length (always on):
+        # the full burst, the short one (_short_burst) and the single
+        # step (a guided mask, the last tokens before the context cap);
+        # a length that is two of them counts as full, else as single
+        self.decode_bursts = {"full": 0, "short": 0, "single": 0}
         # what the KDA kernels were asked to do, a layer's worth (always
         # on, a model with recurrent layers only): state rows a kda_step
         # call updated, over the dispatched bursts' steps; blocks of
@@ -738,6 +743,14 @@ class InferenceEngine:
           and those admitted during a hold. ``admissions_held /
           admissions`` is how often a prompt's prefill stood directly
           behind the running burst; ~0 where the queue is never empty.
+        - ``decode_bursts.full`` / ``.short`` / ``.single`` (calls): decode
+          bursts dispatched at ``decode_steps_per_dispatch`` steps, at
+          ``decode_steps_admit_pending`` steps (``_short_burst``: an empty
+          queue beside a free slot, or a queue under half occupancy) and
+          at one step (a guided mask, the last tokens before the context
+          cap). ``short / (full + short + single)`` between two snapshots
+          is how often a burst was shortened for an arrival's sake; 0
+          where no short program is compiled.
         """
         snap = {
             k: {"secs": round(v[0], 4), "calls": int(v[1])}
@@ -939,7 +952,7 @@ class InferenceEngine:
         request ever eats a compile (with the persistent cache enabled,
         a restarted worker loads most of these from disk): per-bucket
         single + packed prefill, the decode burst programs (full and
-        ramp-up-capped lengths), and the first-token sample widths. All
+        short lengths), and the first-token sample widths. All
         warmup dispatches write only the trash page (zero block tables,
         inactive slots) against the LIVE pools, so device state is
         exactly as if the engine had served and finished requests.
@@ -4315,6 +4328,12 @@ class InferenceEngine:
         either on the trash page or in pages released when the slot
         finishes.
 
+        HOW LONG a burst is: ``decode_steps_admit_pending`` steps, not
+        the full length, while a shorter burst lets somebody in sooner
+        (_short_burst; PERF.md, PR 46). Every length is a program of its
+        own, compiled ahead (_burst_lengths), and they compute the same
+        tokens; ``decode_bursts.*`` counts the bursts of each.
+
         ``pipeline_decode=True`` keeps ONE burst queued behind the
         running one: burst k+1 dispatches with its fed tokens CHAINED ON
         DEVICE from burst k's sampled outputs, and only then is burst k's
@@ -4587,6 +4606,29 @@ class InferenceEngine:
         for pb in pending:
             self._process_burst(pb)
 
+    def _short_burst(self) -> bool:
+        """Whether the next burst takes the short compiled length
+        (``decode_steps_admit_pending``) and not the full one: while a
+        shorter burst would let somebody in sooner, from what the thread
+        observes as it builds the burst.
+
+        An empty queue beside a free slot: arrivals pace the engine and
+        each is admitted as it comes (the state in which
+        _hold_queued_burst holds), so every ms of burst in flight is a
+        ms the next prompt's prefill waits for the device, and as much
+        again until its first token comes home on its slot's first burst.
+        A queue that is not empty under half occupancy: the ramp-up, the
+        next admission wave gets in sooner. Otherwise full bursts: a
+        backlog beside a batch at least half full (a closed loop at
+        saturation, an open loop past its knee: the host's cycle is paid
+        half as often), or no free slot (nobody can be admitted sooner
+        whatever the burst). Not occupancy alone: an open loop below its
+        knee may well run over half full with nobody waiting."""
+        n_active = sum(s is not None for s in self._slots)
+        if self._waiting.empty():
+            return n_active < len(self._slots)
+        return n_active * 2 < len(self._slots)
+
     def _build_batch(self, pending: list[dict] | None) -> dict | None:
         """Assemble host-side arrays for the next burst.
 
@@ -4616,19 +4658,11 @@ class InferenceEngine:
                 if pb["active"][i] and self._slot_matches(i, pb):
                     extra[i] += pb["n_burst"]
 
-        # burst size: bounded by every ready slot's room to the context cap
-        # (an overshooting position would clamp-index into a LIVE page)
+        # burst size: what an arrival would meet (_short_burst), then
+        # bounded by every ready slot's room to the context cap (an
+        # overshooting position would clamp-index into a LIVE page)
         n_burst = cfg.decode_steps_per_dispatch
-        n_active = sum(s is not None for s in self._slots)
-        if (
-            cfg.decode_steps_admit_pending
-            and not self._waiting.empty()
-            and n_active * 2 < len(self._slots)
-        ):
-            # ramp-up: the batch is mostly empty and prompts are waiting —
-            # short bursts get the next admission wave in sooner. At high
-            # occupancy full bursts win (admissions no longer flush the
-            # pipeline, so they are cheap to interleave).
+        if cfg.decode_steps_admit_pending and self._short_burst():
             n_burst = max(1, min(n_burst, cfg.decode_steps_admit_pending))
         for i, slot in enumerate(self._slots):
             if (
@@ -4838,6 +4872,11 @@ class InferenceEngine:
                         tokens_in,
                     )
         self.dispatches += 1
+        n = batch["n_burst"]
+        self.decode_bursts[
+            "full" if n == self._burst_lengths[-1]
+            else "single" if n == 1 else "short"
+        ] += 1
         self._count_decode_kv(batch)
         allowed = batch.get("allowed")
         with self._launch(

@@ -8,15 +8,22 @@ the test owns, the device is a few lines of arithmetic on it (a burst takes
 one unit, a prefill a fifth, in launch order), ``is_ready`` answers from
 that arithmetic, and the wait of a hold either jumps the clock to its
 deadline or, where a test scripts an arrival, blocks on the enqueue itself.
+
+And how long a burst is (_short_burst, asked by _build_batch): on the
+serving schedule, bursts of 8 with a compiled short length of 4, the length
+follows what an arrival would meet; the hold then hides behind a 4-step
+burst as it does behind any length it has timed.
 """
 
 import asyncio
 import collections
 import threading
 
+import numpy as np
 import pytest
 
 from dynamo_tpu.engine import core
+from dynamo_tpu.engine.cache import SeqPages
 from dynamo_tpu.engine.config import EngineConfig, ModelSpec
 from dynamo_tpu.engine.core import InferenceEngine
 from dynamo_tpu.guided import TokenVocab, grammar_from_request
@@ -31,11 +38,11 @@ SPEC = ModelSpec(
 BURST, PREFILL, LAUNCH = 1.0, 0.2, 0.01  # units of the test's clock
 
 
-def _cfg(*, slots=4, num_pages=256, **kw) -> EngineConfig:
+def _cfg(*, slots=4, num_pages=256, steps=4, **kw) -> EngineConfig:
     return EngineConfig(
         page_size=4, num_pages=num_pages, max_pages_per_seq=32,
         max_decode_slots=slots, prefill_buckets=(16, 32, 64),
-        decode_steps_per_dispatch=4, pipeline_decode=True, **kw,
+        decode_steps_per_dispatch=steps, pipeline_decode=True, **kw,
     )
 
 
@@ -83,7 +90,8 @@ class _Sim:
         self.engine = engine = InferenceEngine(
             SPEC, _cfg(**cfg), guided_vocab=guided_vocab)
         self.t = 1000.0
-        self.burst = BURST  # what a burst takes on this device
+        self.burst = BURST  # what a burst of the full length takes here
+        self.steps = []  # the steps of every burst launched, in order
         self.free_at = 0.0  # when the device has run all it was given
         self.ends = {}  # id(a burst's output) -> (output, its end)
         self.log = []  # (kind, ahead, held) of launches, ("read", n, _)
@@ -115,11 +123,16 @@ class _Sim:
                 self.free_at = max(self.free_at, self.t) + PREFILL
             if kind in ("prefill", "decode"):
                 self.log.append((kind, counts["ahead"], engine._holding))
+            if kind == "decode":
+                self.steps.append(counts["steps"])
             return launch(kind, **counts)
 
         def watched_dispatch(batch, chain):
             self.t += LAUNCH
-            end = self.free_at = max(self.free_at, self.t) + self.burst
+            # a step takes what it takes: a shorter burst ends sooner
+            took = (self.burst * batch["n_burst"]
+                    / engine.config.decode_steps_per_dispatch)
+            end = self.free_at = max(self.free_at, self.t) + took
             results = dispatch(batch, chain)
             self.ends[id(results[0])] = (results[0], end)
             return results
@@ -863,17 +876,24 @@ def test_synchronous_admissions_are_not_held_for():
     assert not engine._hold_open()
 
 
-def test_the_counters_are_in_the_snapshot_and_reset_with_it():
+COUNTERS = {
+    "burst_hold": {"begun": 7, "overran": 1, "admissions": 5,
+                   "admissions_held": 3},
+    "decode_bursts": {"full": 2, "short": 9, "single": 4},
+}
+
+
+@pytest.mark.parametrize("family", list(COUNTERS))
+def test_the_counters_are_in_the_snapshot_and_reset_with_it(family):
     engine, _now = _engine_at(0.0)
-    engine.burst_hold.update(
-        begun=7, overran=1, admissions=5, admissions_held=3)
+    assert set(getattr(engine, family)) == set(COUNTERS[family])
+    getattr(engine, family).update(COUNTERS[family])
     snap = engine.profile_snapshot()
     assert {k: v["calls"] for k, v in snap.items()
-            if k.startswith("burst_hold.")} == {
-        "burst_hold.begun": 7, "burst_hold.overran": 1,
-        "burst_hold.admissions": 5, "burst_hold.admissions_held": 3}
+            if k.startswith(family + ".")} == {
+        f"{family}.{k}": n for k, n in COUNTERS[family].items()}
     engine.reset_profile_window()
-    assert set(engine.burst_hold.values()) == {0}
+    assert set(getattr(engine, family).values()) == {0}
 
 
 def test_a_launch_made_in_a_hold_says_so(monkeypatch):
@@ -894,3 +914,116 @@ def test_a_launch_made_in_a_hold_says_so(monkeypatch):
     engine._launch("decode", steps=4, live=2, slots=4, ahead=1)
     assert [kw.get("held") for _n, kw in notes] == [None, 1, None]
     assert {n for n, _kw in notes} == {"engine.launch"}
+
+
+# -- how long a burst is, on the serving schedule ---------------------------
+
+
+class _Constraining:
+    """A grammar cursor that constrains: its mask is good for one token."""
+
+    constraining = True
+
+    def mask(self):
+        return np.ones((SPEC.vocab_size,), bool)
+
+
+def _live_slot(i, *, seq_len=10, guided=None) -> core._Slot:
+    rid = f"r{i}"
+    return core._Slot(
+        request_id=rid, context=Context(), out_q=None, seq=None,
+        pages=SeqPages(rid), seq_len=seq_len, remaining=50, guided=guided,
+    )
+
+
+CAP = _cfg().max_context
+RULE = {
+    # name: (live slots of 4, a request waits, engine options, what the
+    #        slots are made with, the steps the burst takes)
+    "empty_queue_free_slot": (3, False, {}, {}, 4),
+    "empty_queue_one_stream": (1, False, {}, {}, 4),
+    "empty_queue_no_free_slot": (4, False, {}, {}, 8),
+    "waiter_under_half": (1, True, {}, {}, 4),
+    "waiter_at_half": (2, True, {}, {}, 8),
+    "waiter_over_half": (3, True, {}, {}, 8),
+    "waiter_no_free_slot": (4, True, {}, {}, 8),
+    "no_short_length": (1, False, {"decode_steps_admit_pending": 0}, {}, 8),
+    "no_short_length_waiter": (
+        1, True, {"decode_steps_admit_pending": 0}, {}, 8),
+    "a_constraining_guided_slot": (
+        2, False, {}, {"guided": _Constraining()}, 1),
+    # six tokens of room under a full batch: 8 rounds down to 4, and three
+    # under a short burst to 1: a length that was compiled, never 6 or 3
+    "room_to_the_cap_rounds_a_full_burst_down": (
+        4, False, {}, {"seq_len": CAP - 6}, 4),
+    "room_to_the_cap_rounds_a_short_burst_down": (
+        2, False, {}, {"seq_len": CAP - 3}, 1),
+}
+
+
+@pytest.mark.parametrize("name", list(RULE))
+def test_a_burst_is_as_long_as_what_an_arrival_would_meet(name):
+    """Short (4 of 8) while the queue is empty beside a free slot or a
+    request waits under half occupancy; full with a backlog beside half
+    the slots or more, or with no free slot; then cut to a compiled
+    length by a grammar's mask and by the room to the context cap."""
+    live, waiter, opts, made, want = RULE[name]
+    engine = InferenceEngine(SPEC, _cfg(steps=8, **opts))
+    assert engine._burst_lengths == (
+        [1, 8] if opts.get("decode_steps_admit_pending") == 0 else [1, 4, 8])
+    for i in range(live):
+        # what a case makes its slots with goes to the last of them
+        engine._slots[i] = _live_slot(i, **(made if i == live - 1 else {}))
+    if waiter:
+        engine._waiting.put_nowait(
+            core._Waiting(request={}, context=Context(), out_q=None))
+    batch = engine._build_batch(None)
+    assert batch["n_burst"] == want
+    assert int(batch["active"].sum()) == live
+    assert (batch["allowed"] is not None) == ("guided" in made)
+
+
+async def test_trickled_arrivals_ride_short_bursts_and_holds_go_on(
+        monkeypatch):
+    """Arrivals one at a time beside two running streams, on bursts of 8
+    with a short length of 4: request for request the tokens of the engine
+    that knows only 8 (the same programs' steps, cut elsewhere), the
+    counters say what was dispatched at each length, and once a 4-step
+    burst has been timed the hold begins behind it and admits in it."""
+    async with _Sim(monkeypatch, hold=False, steps=8,
+                    decode_steps_admit_pending=0) as eight:
+        want = await _open_loop(eight, eight.at_read, (2, 3, 5, 7))
+    async with _Sim(monkeypatch, steps=8) as sim:
+        got = await _open_loop(sim, sim.at_hold, (2, 3, 7, 10))
+    assert [len(o) for o in want] == [61, 58, 19, 10, 7, 12]
+    assert got == want
+    assert eight.engine._burst_lengths == [1, 8]
+    assert set(eight.steps) == {8}
+    assert eight.engine.decode_bursts == {
+        "full": len(eight.steps), "short": 0, "single": 0}
+    eng = sim.engine
+    assert eng._burst_lengths == [1, 4, 8]
+    assert eng.decode_bursts == {
+        "full": sim.steps.count(8), "short": sim.steps.count(4),
+        "single": sim.steps.count(1)}
+    assert sum(eng.decode_bursts.values()) == len(sim.steps)
+    # two streams of four slots and a queue that empties on the wake:
+    # short; all four slots taken for a while: full
+    assert eng.decode_bursts["short"] >= 8
+    assert eng.decode_bursts["full"] >= 1
+    assert eng.decode_bursts["single"] == 0
+    # the 4-step burst was timed at what four steps take here, holds
+    # began behind it and every scripted arrival was admitted in one: its
+    # prefill stands directly behind a running 4-step burst
+    assert list(eng._burst_secs[4]) == pytest.approx(
+        [BURST / 2] * len(eng._burst_secs[4]))
+    assert eng.burst_hold["begun"] >= 8
+    assert eng.burst_hold["admissions_held"] == 4
+    assert eng.burst_hold["overran"] == 0
+    launches = sim.launches()
+    held = [i for i, e in enumerate(launches) if e == ("prefill", 1, True)]
+    assert len(held) == 4
+    for i in held:
+        n_before = sum(e[0] == "decode" for e in launches[:i])
+        assert sim.steps[n_before - 1] == 4  # the running burst
+    assert eng.allocator.active_pages == 0

@@ -171,6 +171,13 @@ PROFILE_COUNTERS: dict[str, str] = {
     "burst_hold.admissions_held": "those admitted during a hold: their "
                                   "prefill was launched directly behind "
                                   "the running burst (held=1)",
+    "decode_bursts.full": "decode bursts dispatched at "
+                          "decode_steps_per_dispatch steps",
+    "decode_bursts.short": "those dispatched at decode_steps_admit_pending "
+                           "steps: the queue was empty beside a free slot, "
+                           "or not empty under half occupancy (_short_burst)",
+    "decode_bursts.single": "those of one step (a guided mask, the last "
+                            "tokens before the context cap)",
     # a model with recurrent (KDA) layers only
     "kda.decode_rows": "state rows a kda_step call updated (live slots), "
                        "over the dispatched bursts' steps; a layer's worth",
