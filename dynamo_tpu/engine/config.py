@@ -624,8 +624,9 @@ class EngineConfig:
     # one would let somebody in sooner (engine/core.py _short_burst).
     # That is while the queue is empty beside a free slot (arrivals pace
     # the engine: the next prompt's prefill waits for the burst in
-    # flight, and its first token rides home on its slot's first burst,
-    # so both waits follow the burst's length), and while prompts are
+    # flight, and a first token that no hold lands (_land_ready_waves)
+    # rides home on its slot's first burst, so both waits follow the
+    # burst's length), and while prompts are
     # waiting under half occupancy (n_active*2 < slots: the ramp-up).
     # Otherwise full bursts: a backlog beside a batch at least half full,
     # or no free slot. One more compiled decode program where it differs

@@ -197,7 +197,7 @@ READMIT_SUMS = {
 # ``<family>.<name>`` and reset_profile_window() zeroes them
 _COUNTER_FAMILIES = (
     "decode_kv", "prefill_kv", "chunked_prefill", "burst_hold",
-    "decode_bursts", "kda", "ssd", "recurrent_state",
+    "decode_bursts", "first_tokens", "kda", "ssd", "recurrent_state",
 )
 
 # undisturbed burst times kept a burst length (their smallest is the
@@ -206,13 +206,17 @@ _COUNTER_FAMILIES = (
 _BURST_SAMPLES = 4
 _COST_SAMPLES = 8
 
-# the step thread's two bounded waits on the wake event. Idle: slots or a
+# the step thread's three bounded waits on the wake event. Idle: slots or a
 # partial are live but the cycle did no work. Readmit: a closed-loop
 # client's resubmission crossing the event loop right after its finish
 # item posted (finish -> client resubmit -> generate enqueue is ~a ms of
-# loop latency), hidden behind the in-flight burst's device execution
+# loop latency), hidden behind the in-flight burst's device execution.
+# Wave poll: a hold of the queued burst (_hold_queued_burst) with an
+# admission wave's sample still on its way looks again this often, so a
+# first token is posted within about a ms of reaching the host
 _STEP_IDLE_SLEEP_S = 0.002
 _READMIT_WAIT_S = 0.002
+_WAVE_POLL_S = 0.001
 
 # what _phase and _launch hand out with profiling off: one shared object
 # whose enter and exit do nothing
@@ -596,6 +600,11 @@ class InferenceEngine:
         # step (a guided mask, the last tokens before the context cap);
         # a length that is two of them counts as full, else as single
         self.decode_bursts = {"full": 0, "short": 0, "single": 0}
+        # where async admissions' first tokens came home (always on):
+        # from the wave's own download while the queued burst was held
+        # (_hold_queued_burst) or at the top of a cycle (_step), or from
+        # the fed column of their slot's first burst (_process_burst)
+        self.first_tokens = {"in_hold": 0, "at_step": 0, "on_burst": 0}
         # what the KDA kernels were asked to do, a layer's worth (always
         # on, a model with recurrent layers only): state rows a kda_step
         # call updated, over the dispatched bursts' steps; blocks of
@@ -751,6 +760,15 @@ class InferenceEngine:
           cap). ``short / (full + short + single)`` between two snapshots
           is how often a burst was shortened for an arrival's sake; 0
           where no short program is compiled.
+        - ``first_tokens.in_hold`` / ``.at_step`` / ``.on_burst`` (calls):
+          async admissions' first tokens posted from their wave's own
+          download during a hold of the queued burst
+          (``_hold_queued_burst``), from it at the top of a cycle
+          (``_step``; the forced reads of a flush or a close too), and
+          from the fed column of their slot's first burst
+          (``_process_burst``). ``in_hold`` over the three is how often a
+          first token came home the moment its prefill ended, not a burst
+          later; ~0 where the queue is never empty.
         """
         snap = {
             k: {"secs": round(v[0], 4), "calls": int(v[1])}
@@ -3581,8 +3599,8 @@ class InferenceEngine:
         d2h copies in flight, slots installed with ``first_pending`` set —
         the step thread never waits. The next decode burst feeds the new
         slots' tokens straight from the device samples (_dispatch_burst
-        admit feed); host values materialize later (_materialize_waves /
-        _process_burst ordering).
+        admit feed); host values materialize later (_land_ready_waves /
+        _materialize_waves / _process_burst ordering).
 
         Most records arrive PRESAMPLED: the packed/single prefill stage
         fused the first-token sample onto its own dispatch
@@ -3637,10 +3655,13 @@ class InferenceEngine:
                     "age": 0,
                 }
             for w in waves.values():
-                # start the host copy NOW: by the next cycle the wave can
-                # land from host memory (is_ready) — a full cycle earlier
-                # than the burst-processing backstop, which is what keeps
-                # closed-loop clients resubmitting and the batch full
+                # start the host copy NOW: the wave can land from host
+                # memory (is_ready) the moment its prefill has ended, in
+                # the hold of the queued burst that sees it ready
+                # (_land_ready_waves) or at the top of the next cycle —
+                # a burst earlier than the burst-processing backstop,
+                # which is what keeps closed-loop clients resubmitting
+                # and the batch full
                 try:
                     w["dev"].copy_to_host_async()
                 except AttributeError:
@@ -3661,10 +3682,26 @@ class InferenceEngine:
             self._slots[slot_idx] = slot
         self._admit_waves.extend(waves.values())
 
+    def _live_recs(self, ap: dict) -> list[tuple]:
+        """The records of a wave whose first token is still to land: the
+        slot is the one admitted (not finished, cancelled or reused
+        since) and nothing has landed it yet."""
+        return [
+            (si, s, row) for si, s, row in ap["recs"]
+            if self._slots[si] is s and s.first_pending
+        ]
+
     def _materialize_waves(self, force: bool = False) -> bool:
         """Land admission waves whose device sample is ready. Waves cover
         disjoint LIVE slots, so landing one never depends on another —
         slot-identity guards skip records whose slot was reused since.
+
+        Who lands a first token: this, at the top of a cycle (_step), a
+        wave that says ready; _land_ready_waves, while the queued burst
+        is held, the same; _process_burst, from the fed column of the
+        slot's first burst, whatever neither saw ready before that burst
+        was read. Each of the first two reads the wave's own download,
+        started at the admission (_complete_admissions_async).
 
         A wave whose pending slots are COVERED by an in-flight decode
         burst is left alone even when aged: _process_burst force-lands it
@@ -3674,7 +3711,10 @@ class InferenceEngine:
         burst — measured at ~60 ms/cycle of stall under admission churn
         (the round-5 profile, benchmarks/profile_engine.py). The age
         fallback only catches waves NO burst will ever process (e.g. a
-        one-token budget exhausted by the first token)."""
+        one-token budget exhausted by the first token). It counts calls
+        of THIS function, a cycle apart: a hold asks many times a cycle,
+        of waves no burst covers yet (a prefill admitted in that hold:
+        its burst is the held one), so it must neither age nor force."""
         did = False
         keep: list[dict] = []
         covered: set[int] = set()
@@ -3687,10 +3727,7 @@ class InferenceEngine:
         for ap in self._admit_waves:
             ap["age"] += 1
             ready = _is_ready(ap["dev"])
-            live = [
-                (si, s, row) for si, s, row in ap["recs"]
-                if self._slots[si] is s and s.first_pending
-            ]
+            live = self._live_recs(ap)
             if not live:
                 # every record finished/cancelled since admission: nothing
                 # to land — drop the wave without touching the device
@@ -3705,10 +3742,30 @@ class InferenceEngine:
         self._admit_waves = keep
         return did
 
+    def _land_ready_waves(self) -> bool:
+        """The landing of a hold (_hold_queued_burst): post the first
+        tokens of every wave whose device sample says ready, from the
+        wave's own download, and return whether a live wave is still on
+        its way. Asks and never insists: no wave is aged and none is read
+        by force (_materialize_waves says why), so this thread never
+        blocks on the device here."""
+        keep: list[dict] = []
+        for ap in self._admit_waves:
+            if not self._live_recs(ap):
+                continue  # finished/cancelled since admission: dropped
+            if _is_ready(ap["dev"]):
+                with self._phase("materialize"):
+                    self._materialize_one(ap, where="in_hold")
+            else:
+                keep.append(ap)
+        self._admit_waves = keep
+        return bool(keep)
+
     def _materialize_one(
         self,
         ap: dict,
         *,
+        where: str = "at_step",
         fed_col: np.ndarray | None = None,
         fed: set | None = None,
         part: np.ndarray | None = None,
@@ -3717,7 +3774,8 @@ class InferenceEngine:
         """Land an async admission wave's first tokens.
 
         Direct mode (``fed_col`` is None): read the wave's own device
-        sample — one d2h transfer. Burst mode (_process_burst): slots
+        sample — one d2h transfer; ``where`` is the ``first_tokens``
+        counter it lands under. Burst mode (_process_burst): slots
         that were FED into the burst being processed take their token
         from the burst download's fed column — no extra transfer; any
         record not covered (a page-stalled slot that joined a later
@@ -3748,7 +3806,7 @@ class InferenceEngine:
             for slot_idx, slot, row in ap["recs"]:
                 if self._slots[slot_idx] is not slot:
                     continue  # finished/cancelled since admission
-                self._land_first_token(slot_idx, slot, int(toks[row]))
+                self._land_first_token(slot_idx, slot, int(toks[row]), where)
             return None
         rest: list[tuple] = []
         for slot_idx, slot, row in ap["recs"]:
@@ -3761,7 +3819,7 @@ class InferenceEngine:
                 and participants.get(slot_idx) == slot.request_id
             ):
                 self._land_first_token(
-                    slot_idx, slot, int(fed_col[slot_idx])
+                    slot_idx, slot, int(fed_col[slot_idx]), "on_burst"
                 )
             else:
                 rest.append((slot_idx, slot, row))
@@ -3769,9 +3827,13 @@ class InferenceEngine:
             return {**ap, "recs": rest}
         return None
 
-    def _land_first_token(self, slot_idx: int, slot: _Slot, tok: int) -> None:
+    def _land_first_token(
+        self, slot_idx: int, slot: _Slot, tok: int, where: str
+    ) -> None:
         """Record + stream an async admission's first token (stop
-        semantics of _accept_token, with counters pre-advanced)."""
+        semantics of _accept_token, with counters pre-advanced), counted
+        under ``first_tokens[where]``."""
+        self.first_tokens[where] += 1
         FLIGHT.event(slot.context.id, "first_token")
         slot.seq.append(tok)
         slot.last_token = tok
@@ -4359,6 +4421,18 @@ class InferenceEngine:
         queue that is never empty (closed loops) nothing is held and the
         launch is at once.
 
+        WHEN a first token comes home: the moment its prefill has ended,
+        where a hold is there to see it. An admission's sample is made
+        inside its prefill's dispatch and its host copy started at once;
+        the prefill of an admission made under k stands before k+1, so
+        it ends early in the hold of k+2, and that hold posts the token
+        from the wave's own download (_land_ready_waves; PERF.md, PR 47).
+        Where no hold begins, the token comes home at the top of a cycle
+        if its wave is ready then (_materialize_waves), else as column 0
+        of its slot's first burst's download, a burst after it existed
+        (_process_burst); ``first_tokens.*`` counts the three. The
+        blocked read of a burst is not split to fetch a wave first.
+
         Stops are detected one burst late (discarded garbage, as with
         mid-burst EOS); cancels and admin ops flush the pipeline first
         (_step).
@@ -4475,11 +4549,26 @@ class InferenceEngine:
         stop, an SPMD sync request) ends the hold: the flags are read
         after the event is cleared and every setter raises its flag
         first, so none is missed, and none waits longer than for the read
-        of k+1 that would block this thread anyway. The hold admits and
-        does not land: a first token still comes home on its slot's first
-        burst (_process_burst) or at the top of _step
-        (_materialize_waves), as without a hold. The wait is the ``idle``
-        phase, the passes are the admission phases they always are."""
+        of k+1 that would block this thread anyway.
+
+        The hold lands as well as admits. The prefill of an admission
+        made under burst k (in the hold before this one, or in the pass
+        at the top of that cycle) stands before k+1 and ends early in
+        this wait, and its sample is on the host a fraction of a ms later
+        (_complete_admissions_async started the copy): with nothing to
+        admit the thread posts every first token whose wave says ready
+        (_land_ready_waves) and, while a live wave is still on its way,
+        waits ``_WAVE_POLL_S`` at a time and looks again, until the same
+        deadline. It asks and never reads by force: a prefill admitted in
+        THIS hold stands behind k+1, no burst covers it yet, and a forced
+        read would block this thread past the deadline. An arrival comes
+        first: a wake with a request waiting goes to the admission pass,
+        and the landing is made once the queue is empty again. What no
+        hold saw ready still comes home at the top of _step
+        (_materialize_waves) or on its slot's first burst
+        (_process_burst), as without a hold. The wait is the ``idle``
+        phase, the passes are the admission phases they always are, a
+        landing is ``materialize``."""
         if not self._waiting.empty():
             return False
         deadline = self._hold_deadline()
@@ -4495,9 +4584,13 @@ class InferenceEngine:
                 begun = True
                 self.burst_hold["begun"] += 1
             if self._waiting.empty():
+                wait = deadline - now
+                polling = self._land_ready_waves()
+                if polling:
+                    wait = min(wait, _WAVE_POLL_S)
                 with self._phase("idle"):
-                    self._wake.wait(deadline - now)
-                if self._waiting.empty():
+                    woken = self._wake.wait(wait)
+                if self._waiting.empty() and (woken or not polling):
                     break  # the deadline, or a wake that is no arrival
                 continue
             self._holding = True
@@ -4616,7 +4709,8 @@ class InferenceEngine:
         each is admitted as it comes (the state in which
         _hold_queued_burst holds), so every ms of burst in flight is a
         ms the next prompt's prefill waits for the device, and as much
-        again until its first token comes home on its slot's first burst.
+        again for a first token that no hold lands (_land_ready_waves)
+        and that comes home on its slot's first burst.
         A queue that is not empty under half occupancy: the ramp-up, the
         next admission wave gets in sooner. Otherwise full bursts: a
         backlog beside a batch at least half full (a closed loop at

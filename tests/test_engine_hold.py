@@ -9,6 +9,11 @@ one unit, a prefill a fifth, in launch order), ``is_ready`` answers from
 that arithmetic, and the wait of a hold either jumps the clock to its
 deadline or, where a test scripts an arrival, blocks on the enqueue itself.
 
+The hold lands too (_land_ready_waves): an admission's sample ends with its
+prefill, on the same arithmetic, and a hold that sees it ready posts the
+first token then and there; the hold looks every ``POLL`` of the test's
+clock while a wave is on its way.
+
 And how long a burst is (_short_burst, asked by _build_batch): on the
 serving schedule, bursts of 8 with a compiled short length of 4, the length
 follows what an arrival would meet; the hold then hides behind a 4-step
@@ -36,6 +41,7 @@ SPEC = ModelSpec(
     num_layers=2, num_heads=4, num_kv_heads=2, head_dim=8, dtype="float32",
 )
 BURST, PREFILL, LAUNCH = 1.0, 0.2, 0.01  # units of the test's clock
+POLL = 0.05  # a hold looks at a wave on its way this often, on that clock
 
 
 def _cfg(*, slots=4, num_pages=256, steps=4, **kw) -> EngineConfig:
@@ -84,24 +90,32 @@ class _Sim:
     ``at_hold[n]`` / ``at_read[n]`` script what happens at the n-th wait of
     a hold / the n-th read of a burst (from 1): a callable run on the step
     thread. ``arrive(coro_fn)`` inside one starts a request on the event
-    loop and returns once it is enqueued."""
+    loop and returns once it is enqueued. The short waits a hold makes
+    while an admission wave is on its way (``POLL`` apart) are counted
+    apart and scripted by ``at_poll[n]``: ``at_hold`` counts the waits to
+    the deadline, every wait there was before a hold landed anything."""
 
     def __init__(self, monkeypatch, *, hold=True, guided_vocab=None, **cfg):
         self.engine = engine = InferenceEngine(
             SPEC, _cfg(**cfg), guided_vocab=guided_vocab)
+        self.hold = hold
         self.t = 1000.0
         self.burst = BURST  # what a burst of the full length takes here
+        self.prefill = PREFILL  # what a prefill takes here
         self.steps = []  # the steps of every burst launched, in order
         self.free_at = 0.0  # when the device has run all it was given
         self.ends = {}  # id(a burst's output) -> (output, its end)
         self.log = []  # (kind, ahead, held) of launches, ("read", n, _)
+        self.landed = []  # (where, entries of the log before it, when) of
+        #                    every first token an admission wave landed
         self.holds = []  # (begun, the queue was empty, a slot was free,
         #                   no partial, not draining) at every decision
         self.in_hold = False
-        self.hold_waits = 0
+        self.hold_waits = self.polls = 0
         self.reads = 0
-        self.at_hold, self.at_read = {}, {}
-        self.woken = []  # what every wait of a hold returned
+        self.at_hold, self.at_poll, self.at_read = {}, {}, {}
+        self.woken = []  # what every wait of a hold returned ...
+        self.polled = []  # ... and every short one with a wave on its way
         self.enqueued = threading.Event()
         self.started = []  # futures of the requests that arrived by script
         self.loop = None
@@ -113,14 +127,17 @@ class _Sim:
         if not hold:
             engine._hold_deadline = lambda: None
         monkeypatch.setattr(core, "_is_ready", self.is_ready)
+        monkeypatch.setattr(core, "_WAVE_POLL_S", POLL)
 
         launch, dispatch = engine._launch, engine._dispatch_burst
         process, note = engine._process_burst, engine._note_burst_end
         holdfn, put = engine._hold_queued_burst, engine._waiting.put_nowait
+        complete, land = (
+            engine._complete_admissions_async, engine._land_first_token)
 
         def watched_launch(kind, **counts):
             if kind == "prefill":
-                self.free_at = max(self.free_at, self.t) + PREFILL
+                self.free_at = max(self.free_at, self.t) + self.prefill
             if kind in ("prefill", "decode"):
                 self.log.append((kind, counts["ahead"], engine._holding))
             if kind == "decode":
@@ -169,29 +186,56 @@ class _Sim:
             put(item)
             self.enqueued.set()
 
+        def watched_complete(pending):
+            complete(pending)
+            # an admission's sample ends with its prefill, the last the
+            # device was given
+            for wave in engine._admit_waves:
+                self.ends.setdefault(
+                    id(wave["dev"]), (wave["dev"], self.free_at))
+
+        def watched_land(slot_idx, slot, tok, where):
+            self.landed.append((where, len(self.log), self.t))
+            return land(slot_idx, slot, tok, where)
+
         engine._launch = watched_launch
         engine._dispatch_burst = watched_dispatch
         engine._process_burst = watched_process
         engine._note_burst_end = watched_note
         engine._hold_queued_burst = watched_hold
         engine._waiting.put_nowait = watched_put
+        engine._complete_admissions_async = watched_complete
+        engine._land_first_token = watched_land
 
     def is_ready(self, dev) -> bool:
+        # a burst's output at the burst's end, an admission's sample at
+        # its prefill's
         known = self.ends.get(id(dev))
-        # an admission's sample is never ready early: it lands with its
-        # slot's first burst, as under a busy device
         return known is not None and self.t >= known[1]
 
     def hold_wait(self, timeout) -> bool:
-        self.hold_waits += 1
-        script = self.at_hold.pop(self.hold_waits, None)
+        if self.engine._admit_waves:
+            # a wave is on its way: one of the short waits between two
+            # looks at it
+            self.polls += 1
+            script, told = self.at_poll.pop(self.polls, None), self.polled
+        else:
+            self.hold_waits += 1
+            script, told = self.at_hold.pop(self.hold_waits, None), self.woken
         if script is None:
-            self.t += timeout  # nothing arrives: the deadline
+            self.t += timeout  # nothing arrives: the deadline, or a look
             woke = False
         else:
             woke = bool(script())
-        self.woken.append(woke)
+        told.append(woke)
         return woke
+
+    @property
+    def points(self) -> dict:
+        """Where a test scripts what happens beside a running burst: the
+        waits of holds, or on the parent's schedule, which holds nothing,
+        the blocked reads."""
+        return self.at_hold if self.hold else self.at_read
 
     def arrive(self, make_coro) -> bool:
         self.enqueued.clear()
@@ -378,12 +422,18 @@ async def test_closed_loop_begins_no_hold_after_warm_up(monkeypatch):
         # warm: every client has been served once, and the queue holds
         # the three that have no slot
         begun0, holds0 = eng.burst_hold["begun"], len(sim.holds)
+        landed0 = dict(eng.first_tokens)
         while done[0] < 24:
             await asyncio.sleep(0.002)
         begun1, holds1 = eng.burst_hold["begun"], len(sim.holds)
+        landed1 = dict(eng.first_tokens)
         await asyncio.gather(*clients)
     assert holds1 - holds0 >= 10  # cycles that asked
     assert begun1 - begun0 == 0
+    # no hold, so none landed a first token: they came home at the top of
+    # a cycle or on their slot's first burst, as they always did
+    assert landed1["in_hold"] - landed0["in_hold"] == 0
+    assert sum(landed1.values()) - sum(landed0.values()) >= 12
     assert eng.burst_hold["admissions_held"] == 0
     assert not any(held for _k, _n, held in sim.log)
 
@@ -415,10 +465,17 @@ async def test_open_loop_streams_the_parents_tokens(monkeypatch):
     async with _Sim(monkeypatch, hold=False) as parent:
         want = await _open_loop(parent, parent.at_read, (3, 5, 8, 11))
     async with _Sim(monkeypatch) as sim:
-        got = await _open_loop(sim, sim.at_hold, (2, 5, 8, 12))
+        got = await _open_loop(sim, sim.at_hold, (2, 4, 7, 9))
     assert [len(o) for o in want] == [61, 58, 19, 10, 7, 12]
     assert got == want
     assert sim.engine.burst_hold["admissions_held"] == 4
+    # and whichever way its first token came home: in the hold that saw
+    # its prefill end, or on its slot's first burst; the parent's never in
+    # a hold
+    assert sim.engine.first_tokens["in_hold"] >= 2
+    assert sum(sim.engine.first_tokens.values()) == 6
+    assert parent.engine.first_tokens["in_hold"] == 0
+    assert sum(parent.engine.first_tokens.values()) == 6
     assert parent.engine.burst_hold["admissions_held"] == 0
     assert parent.engine.burst_hold["begun"] == 0
     assert parent.engine.burst_hold["admissions"] == 6
@@ -723,6 +780,260 @@ async def test_a_burst_launched_behind_nothing_times_no_burst(
     assert kept == pytest.approx([BURST] * len(kept))
 
 
+# -- the hold lands ---------------------------------------------------------
+
+
+def _held_prefills(sim):
+    """Where in the log the prefills launched in a hold stand."""
+    return [i for i, e in enumerate(sim.log) if e == ("prefill", 1, True)]
+
+
+async def _arrival_held(sim, n=9, at=3, **more):
+    """Two streams decode and one request arrives at the ``at``-th wait of
+    a hold: its prefill stands behind the running burst, and ends early in
+    the hold of the burst after."""
+    eng = sim.engine
+    sim.points[at] = lambda: sim.arrive(
+        lambda: _collect(eng, [3, 5, 9, 13, 4], n, **more))
+    outs = await _two_streams(sim, (41, 37))
+    return outs, (await sim.arrivals())[0]
+
+
+async def test_a_first_token_comes_home_in_the_hold_that_sees_its_prefill_end(
+        monkeypatch):
+    """The prefill of an arrival admitted in a hold stands before the held
+    burst and ends a fifth of a burst into the next hold: that hold posts
+    the first token from the wave's own download, a look after the
+    sample's end, and not a burst later from the read of the slot's first
+    burst; then it waits on to its deadline as any hold does."""
+    async with _Sim(monkeypatch, hold=False) as parent:
+        want = await _arrival_held(parent)
+    async with _Sim(monkeypatch) as sim:
+        got = await _arrival_held(sim)
+    assert got == want
+    eng = sim.engine
+    (_where, before, when), = [x for x in sim.landed if x[0] == "in_hold"]
+    # the launch before it is the burst the slot was fed into, the held
+    # one; its read is two reads on (the running burst's comes first)
+    prefill, = _held_prefills(sim)
+    assert [e[0] for e in sim.log[prefill:before]] == [
+        "prefill", "decode", "read"]
+    assert [e[0] for e in sim.log[before:before + 3]] == [
+        "decode", "read", "decode"]
+    # posted within a look of the sample's end; the read that carries it
+    # on the parent returns a burst after that end
+    ended = [end for _dev, end in sim.ends.values() if end <= when][-1]
+    assert 0 <= when - ended <= POLL
+    assert eng.first_tokens == {"in_hold": 1, "at_step": 0, "on_burst": 2}
+    assert parent.engine.first_tokens == {
+        "in_hold": 0, "at_step": 0, "on_burst": 3}
+    # the hold went on to its deadline: nothing overran, no look was made
+    # with no wave on its way, and the first token was not landed twice
+    assert eng.burst_hold["overran"] == 0
+    assert sim.polls >= 4 and not any(sim.polled)
+    assert eng._admit_waves == []
+    assert eng.allocator.active_pages == 0
+
+
+async def test_a_sample_not_ready_at_the_deadline_lands_from_the_fed_column(
+        monkeypatch):
+    """A prompt whose prefill outlasts the next hold: every look finds the
+    sample on its way, the hold ends at its deadline as it would have, and
+    the first token comes home as column 0 of the read of its slot's first
+    burst, as on the parent."""
+    runs = []
+    for hold in (False, True):
+        async with _Sim(monkeypatch, hold=hold) as sim:
+            sim.points[2] = lambda sim=sim: setattr(
+                sim, "prefill", 1.5 * BURST)
+            runs.append((await _arrival_held(sim), sim))
+    (want, parent), (got, sim) = runs
+    assert got == want
+    assert sim.engine.first_tokens == {
+        "in_hold": 0, "at_step": 0, "on_burst": 3}
+    assert parent.engine.first_tokens == sim.engine.first_tokens
+    # looked for through two holds (its own, and the next to its deadline)
+    assert sim.polls >= 8 and not any(sim.polled)
+    assert sim.engine.burst_hold["overran"] == 0
+    _where, before, _when = sim.landed[-1]
+    assert sim.log[before - 1][0] == "read"
+
+
+async def test_a_prefill_admitted_in_this_hold_is_never_read_by_force(
+        monkeypatch):
+    """No burst covers a slot admitted in this hold (its burst is the held
+    one, not yet built) and the hold looks at its wave many times: were
+    looking to age the wave, the second look would read it by force and
+    block the thread behind the running burst, past the deadline. Every
+    read of a wave's own download finds it ready, and the wave is as young
+    at the hold's end as at its admission."""
+    async with _Sim(monkeypatch) as sim:
+        eng = sim.engine
+        reads, ages = [], []
+        direct = eng._materialize_one
+
+        def watched_one(ap, **kw):
+            if kw.get("fed_col") is None:
+                reads.append((sim.is_ready(ap["dev"]), sim.in_hold))
+            return direct(ap, **kw)
+
+        eng._materialize_one = watched_one
+        looks = {}
+
+        def look():
+            ages.append([w["age"] for w in eng._admit_waves])
+            looks[len(sim.holds)] = looks.get(len(sim.holds), 0) + 1
+            sim.t += POLL
+            return False
+
+        def arrive():
+            for n in range(1, 40):
+                sim.at_poll[sim.polls + n] = look
+            return sim.arrive(lambda: _collect(eng, [3, 5, 9, 13, 4], 9))
+
+        sim.at_hold[3] = arrive
+        await _two_streams(sim, (41, 37))
+        await sim.arrivals()
+    # the hold that admitted it looked many times and read nothing
+    assert max(looks.values()) >= 5
+    first = min(looks)
+    assert ages[:looks[first]] == [[0]] * looks[first]
+    assert reads == [(True, True)]  # the next hold's, the sample ready
+    held, = _held_prefills(sim)
+    assert sim.log[held + 1][0] == "decode"  # launched, nothing read between
+    assert eng.burst_hold["overran"] == 0
+
+
+async def test_an_arrival_and_a_ready_wave_at_one_wake_the_arrival_first(
+        monkeypatch):
+    """A wake that finds a request waiting AND a wave whose sample has
+    ended: the admission pass comes first (its prefill is launched behind
+    the running burst), the first token is posted after it, in the same
+    hold."""
+    async with _Sim(monkeypatch, slots=5) as sim:  # one stays free
+        eng = sim.engine
+
+        def both():
+            wave, = eng._admit_waves
+            sim.t = max(sim.t, sim.ends[id(wave["dev"])][1])
+            return sim.arrive(lambda: _collect(eng, [17, 19, 4], 7))
+
+        def first():
+            # its prefill stands behind the running burst: the next read
+            # is that burst's, and the first look after it is made in the
+            # hold that will see the sample end
+            sim.at_read[sim.reads + 1] = lambda: sim.at_poll.update(
+                {sim.polls + 1: both})
+            return sim.arrive(lambda: _collect(eng, [3, 5, 9, 13, 4], 9))
+
+        sim.at_hold[3] = first
+        outs = await _two_streams(sim, (41, 37))
+        arrived = await sim.arrivals()
+    assert [len(o) for o in outs + arrived] == [41, 37, 9, 7]
+    assert sim.polled.count(True) == 1
+    where, before, _when = sim.landed[2]
+    assert where == "in_hold"
+    # the launch before the landing is the second arrival's prefill, made
+    # in that hold, and the launch after it the held burst
+    assert _held_prefills(sim)[1] == before - 1
+    assert sim.log[before][0] == "decode"
+    assert eng.burst_hold["admissions_held"] == 2
+    assert eng.first_tokens["in_hold"] == 2  # the second's, a hold later
+    assert eng.burst_hold["overran"] == 0
+
+
+ENDS_AT_ONCE = {
+    # name: what ends the stream at its first token, given that token
+    "a_stop_id": lambda tok: {"stop_conditions": {
+        "max_tokens": 9, "ignore_eos": True, "stop_token_ids": [tok]}},
+    "a_budget_of_one_token": lambda tok: {"stop_conditions": {
+        "max_tokens": 1, "ignore_eos": True}},
+}
+
+
+@pytest.mark.parametrize("name", list(ENDS_AT_ONCE))
+async def test_a_first_token_that_ends_its_stream_in_a_hold_frees_the_slot(
+        name, monkeypatch):
+    """A stop id, a budget of one token: the stream is finished in the
+    hold as it is at the top of a cycle, its slot and pages are free from
+    then on, and what the burst in flight computed for the slot is
+    discarded at its read (the request id guards it)."""
+    async with _Sim(monkeypatch, hold=False) as parent:
+        want, full = await _arrival_held(parent)
+    reasons, seen = [], []
+    async with _Sim(monkeypatch) as sim:
+        eng = sim.engine
+        land = eng._land_first_token
+
+        def watched_land(slot_idx, slot, tok, where):
+            pages = eng.allocator.active_pages
+            land(slot_idx, slot, tok, where)
+            seen.append((where, eng._slots[slot_idx] is None,
+                         len(eng._pipeline),
+                         pages - eng.allocator.active_pages))
+
+        eng._land_first_token = watched_land
+        got, ended = await _arrival_held(
+            sim, reasons=reasons, **ENDS_AT_ONCE[name](full[0]))
+    assert got == want and ended == full[:1]
+    assert reasons == ["stop" if name == "a_stop_id" else "length"]
+    where, freed, in_flight, released = seen[2]
+    assert (where, freed, in_flight) == ("in_hold", True, 1)
+    assert released >= 1  # its pages went with it
+    assert eng.first_tokens["in_hold"] == 1
+    assert eng.burst_hold["overran"] == 0
+    assert eng.allocator.active_pages == 0
+
+
+def test_the_landing_of_a_hold_asks_and_never_insists():
+    """On a stopped engine: a wave that says ready lands, one that does
+    not is kept as young as it was, one whose slot is gone is dropped
+    unread; what is returned is whether a live wave is still on its way."""
+    engine, _now = _engine_at(0.0)
+
+    class Sample:
+        def __init__(self, ready):
+            self.ready, self.reads = ready, 0
+
+        def is_ready(self):
+            return self.ready
+
+        def __array__(self, dtype=None, copy=None):
+            self.reads += 1
+            return np.asarray([7, 9], np.int32)
+
+    landed = []
+
+    def land(i, slot, tok, where):
+        landed.append((i, tok, where))
+        slot.first_pending = False
+
+    engine._land_first_token = land
+    slots = [_StubSlot() for _ in range(3)]
+    for i, s in enumerate(slots):
+        s.first_pending = True
+        engine._slots[i] = s
+    gone = _StubSlot()
+    gone.first_pending = True
+    ready, late, dead = Sample(True), Sample(False), Sample(True)
+    engine._admit_waves = [
+        {"dev": ready, "recs": [(0, slots[0], 1), (1, slots[1], 0)],
+         "fed": set(), "age": 0},
+        {"dev": late, "recs": [(2, slots[2], 0)], "fed": set(), "age": 1},
+        {"dev": dead, "recs": [(3, gone, 0)], "fed": set(), "age": 5},
+    ]
+    assert engine._land_ready_waves() is True
+    assert landed == [(0, 9, "in_hold"), (1, 7, "in_hold")]
+    assert (ready.reads, late.reads, dead.reads) == (1, 0, 0)
+    assert [(w["dev"], w["age"]) for w in engine._admit_waves] == [(late, 1)]
+    for _ in range(5):
+        assert engine._land_ready_waves() is True
+    assert (late.reads, engine._admit_waves[0]["age"]) == (0, 1)
+    late.ready = True
+    assert engine._land_ready_waves() is False
+    assert landed[-1] == (2, 7, "in_hold") and engine._admit_waves == []
+
+
 # -- the arithmetic, on a stopped engine ------------------------------------
 
 
@@ -880,6 +1191,7 @@ COUNTERS = {
     "burst_hold": {"begun": 7, "overran": 1, "admissions": 5,
                    "admissions_held": 3},
     "decode_bursts": {"full": 2, "short": 9, "single": 4},
+    "first_tokens": {"in_hold": 6, "at_step": 1, "on_burst": 2},
 }
 
 
@@ -994,9 +1306,10 @@ async def test_trickled_arrivals_ride_short_bursts_and_holds_go_on(
                     decode_steps_admit_pending=0) as eight:
         want = await _open_loop(eight, eight.at_read, (2, 3, 5, 7))
     async with _Sim(monkeypatch, steps=8) as sim:
-        got = await _open_loop(sim, sim.at_hold, (2, 3, 7, 10))
+        got = await _open_loop(sim, sim.at_hold, (2, 3, 6, 8))
     assert [len(o) for o in want] == [61, 58, 19, 10, 7, 12]
     assert got == want
+    assert sim.engine.first_tokens["in_hold"] >= 2
     assert eight.engine._burst_lengths == [1, 8]
     assert set(eight.steps) == {8}
     assert eight.engine.decode_bursts == {

@@ -80,7 +80,9 @@ async def test_profile_off_records_nothing_and_creates_no_annotation(
                          "burst_hold.admissions",
                          "burst_hold.admissions_held",
                          "decode_bursts.full", "decode_bursts.short",
-                         "decode_bursts.single"}
+                         "decode_bursts.single",
+                         "first_tokens.in_hold", "first_tokens.at_step",
+                         "first_tokens.on_burst"}
     # three bursts' worth at least, always on: pages moved for real contexts
     kv = {k.removeprefix("decode_kv."): v["calls"]
           for k, v in snap.items() if k.startswith("decode_kv.")}
@@ -210,7 +212,7 @@ def test_phase_annotations_match_the_profile_sums(traced):
             # counts, not phases
             or phase.startswith(
                 ("decode_kv.", "prefill_kv.", "chunked_prefill.",
-                 "burst_hold.", "decode_bursts."))
+                 "burst_hold.", "decode_bursts.", "first_tokens."))
             or phase.startswith("readmit.") and phase != "readmit.d2h_wait"
         ):
             continue

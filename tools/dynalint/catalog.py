@@ -178,6 +178,16 @@ PROFILE_COUNTERS: dict[str, str] = {
                            "or not empty under half occupancy (_short_burst)",
     "decode_bursts.single": "those of one step (a guided mask, the last "
                             "tokens before the context cap)",
+    "first_tokens.in_hold": "async admissions' first tokens posted from "
+                            "their wave's own download while the queued "
+                            "burst was held (_land_ready_waves): the "
+                            "moment the prefill had ended",
+    "first_tokens.at_step": "those posted from it at the top of a cycle "
+                            "(_materialize_waves; a flush's or a close's "
+                            "forced reads too)",
+    "first_tokens.on_burst": "those posted from the fed column of their "
+                             "slot's first burst's download "
+                             "(_process_burst): a burst after they existed",
     # a model with recurrent (KDA) layers only
     "kda.decode_rows": "state rows a kda_step call updated (live slots), "
                        "over the dispatched bursts' steps; a layer's worth",
