@@ -93,20 +93,39 @@ class ModelSpec:
     moe_intermediate_size: int = 0
     n_shared_experts: int = 0  # always-on dense experts (DeepSeek)
     first_k_dense: int = 0  # leading layers with plain dense MLP
-    # routing flavor: "softmax" (mixtral/qwen/gpt-oss) or "sigmoid"
+    # routing flavor: "softmax" (mixtral/qwen/gpt-oss), "sigmoid"
     # (DeepSeek-V3 noaux_tc: sigmoid scores + learned correction bias +
-    # group-limited top-k + routed scaling)
+    # group-limited top-k + routed scaling) or "softmax_bias" (LongCat-
+    # Flash: softmax over ALL the router's outputs, the correction bias
+    # picks, the weights are the probabilities x routed scaling,
+    # renormalised only under ``norm_topk_prob``)
     moe_scoring: str = "softmax"
     n_group: int = 0  # expert groups for group-limited routing (0 = off)
     topk_group: int = 0  # groups each token may route into
     routed_scaling_factor: float = 1.0
     norm_topk_prob: bool = True
+    # identity ("zero-computation") experts behind the ``num_experts`` FFN
+    # experts: the router has ``num_experts + zero_experts`` outputs, and a
+    # pick of id >= ``num_experts`` adds ``w * u`` at the token's own chip.
+    # No chip holds them and they have no weights
+    zero_experts: int = 0
+    # shortcut-connected MoE (LongCat-Flash): a decoder layer is TWO
+    # sub-layers (a latent attention and a dense FFN of
+    # ``intermediate_size`` each) and one expert layer whose input is the
+    # first FFN's and whose output is added after the second FFN. The
+    # cache keeps a pool a sub-layer (models/mla.py)
+    shortcut_moe: bool = False
     # MLA (DeepSeek-family latent attention; 0 = plain GQA attention)
     kv_lora_rank: int = 0  # latent dim d_c (the per-token KV cache row)
     qk_nope_head_dim: int = 0
     qk_rope_head_dim: int = 0  # decoupled-RoPE key dim, shared across heads
     v_head_dim: int = 0
     q_lora_rank: int = 0  # query low-rank compression (0 = full q_proj)
+    # LongCat-Flash's two scalars: the queries times sqrt(hidden /
+    # q_lora_rank) behind ``wq_b``, the normed latent (not the roped key)
+    # times sqrt(hidden / kv_lora_rank) before ``w_kv_b``
+    mla_scale_q_lora: bool = False
+    mla_scale_kv_lora: bool = False
     # gpt-oss attention extras (ref recipes/gpt-oss-120b; HF GptOssConfig)
     sliding_window: int = 0  # 0 = full attention everywhere
     layer_types: tuple[str, ...] = ()  # per-layer "sliding_attention" /
@@ -238,6 +257,19 @@ class ModelSpec:
         if self.layer_kinds and not self.layer_kinds[0].paged:
             # the cache's first leaf is a page pool (llama.page_size_of)
             raise ValueError("layer_kinds must list a paged kind first")
+        if self.zero_experts and self.moe_scoring != "softmax_bias":
+            raise ValueError(
+                "zero_experts are routed by moe_scoring 'softmax_bias' alone"
+            )
+        if self.shortcut_moe and not (
+            self.is_mla and self.num_experts
+            and not (self.first_k_dense or self.n_shared_experts)
+        ):
+            raise ValueError(
+                "shortcut_moe: every layer is two latent attentions, two "
+                "dense FFNs and an expert layer (no leading dense layer, "
+                "no shared expert)"
+            )
         if self.held_experts:
             n, first = self.held_experts
             if not 0 < n <= self.num_experts - first:
@@ -308,6 +340,16 @@ class ModelSpec:
     def experts_here(self) -> tuple[int, int]:
         """(count, first) of the experts this process holds."""
         return self.held_experts or (self.num_experts, 0)
+
+    @property
+    def router_outputs(self) -> int:
+        """The router's width: the FFN experts, then the identity ones."""
+        return self.num_experts + self.zero_experts
+
+    @property
+    def sub_layers(self) -> int:
+        """Attentions (and cache layers) a decoder layer."""
+        return 2 if self.shortcut_moe else 1
 
     @property
     def has_attn_extras(self) -> bool:
@@ -542,6 +584,29 @@ class ModelSpec:
         base.update(kw)
         return cls(**base)
 
+    @classmethod
+    def tiny_longcat(cls, **kw) -> "ModelSpec":
+        """Toy LongCat-Flash architecture: shortcut-connected double
+        layers (two latent attentions, two dense FFNs, one expert layer
+        on the shortcut), identity experts behind the FFN experts, the
+        softmax router with a correction bias and un-normalised weights,
+        both MLA scalars."""
+        base = dict(
+            name="tiny-longcat", vocab_size=96, hidden_size=64,
+            intermediate_size=96, num_layers=2, num_heads=4,
+            num_kv_heads=4, head_dim=16, dtype="float32", rms_eps=1e-5,
+            rope_theta=1e7, tie_embeddings=False, rope_interleave=True,
+            shortcut_moe=True, num_experts=8, zero_experts=4,
+            num_experts_per_token=3, moe_intermediate_size=32,
+            moe_scoring="softmax_bias", routed_scaling_factor=6.0,
+            norm_topk_prob=False,
+            kv_lora_rank=32, qk_nope_head_dim=16, qk_rope_head_dim=8,
+            v_head_dim=16, q_lora_rank=16,
+            mla_scale_q_lora=True, mla_scale_kv_lora=True,
+        )
+        base.update(kw)
+        return cls(**base)
+
     @property
     def ssm_conv_dim(self) -> int:
         """Channels the SSD convolution runs over: x | B | C."""
@@ -565,6 +630,7 @@ class ModelSpec:
             "tiny-solar": cls.tiny_solar,
             "tiny-falcon-h1": cls.tiny_falcon_h1,
             "tiny-ling3": cls.tiny_ling3,
+            "tiny-longcat": cls.tiny_longcat,
             "llama-3-8b": cls.llama3_8b,
             "llama-3-70b": cls.llama3_70b,
             "mixtral-8x7b": cls.mixtral_8x7b,

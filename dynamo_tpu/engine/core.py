@@ -821,6 +821,7 @@ class InferenceEngine:
 
         k, v = self.k_pages, self.v_pages
         if self.spec.is_mla:
+            k = self._latent_pool()
             if is_quant(k):
                 return None  # an fp8 latent pool keeps the XLA walk
             return latent_chunk_pages(k, self.config.max_pages_per_seq)
@@ -955,10 +956,13 @@ class InferenceEngine:
             )
 
     def _latent_pool(self):
-        """The pool of latent rows: the latent family's cache, or a latent
-        kind's pool among the kinds'."""
+        """The pool of latent rows: the latent family's cache (the first
+        of a double layer's two, which share a shape), or a latent kind's
+        pool among the kinds'."""
         if self.spec.is_mla:
-            return self.k_pages
+            from dynamo_tpu.models.mla import sub_pools
+
+            return sub_pools(self.k_pages)[0]
         from dynamo_tpu.models.llama import latent_pool
 
         return latent_pool(self.spec, self.k_pages)
@@ -1358,18 +1362,26 @@ class InferenceEngine:
         the held experts
         touched, a layer a step (``<phase>.experts_touched``) and the
         phase's steps (``<phase>.steps``: prefill programs, decode model
-        steps). Empty where the cache keeps none."""
+        steps). A model with identity experts splits the assignments in
+        two more: the picks that were identity experts
+        (``<phase>.zero_picks``) and those that were FFN experts, held
+        here or not (``<phase>.ffn_picks``). Empty where the cache keeps
+        none."""
         if self.moe_counts is None:
             return {}
         c = self.moe_counts.astype(np.int64)
+        n_held = self.spec.experts_here[0]
         out = {"layers": int((c[:, :, -1].sum(axis=1) > 0).sum())}
         for phase, name in enumerate(("prefill", "decode")):
             out[f"{name}.steps"] = int(c[:, phase, -1].max())
             out[f"{name}.experts_touched"] = int(c[:, phase, -2].sum())
             out[f"{name}.assignments"] = int(c[:, phase, -3].sum())
-            out[f"{name}.assignments_held"] = int(c[:, phase, :-3].sum())
-            for i, n in enumerate(c[:, phase, :-3].sum(axis=0)):
+            out[f"{name}.assignments_held"] = int(c[:, phase, :n_held].sum())
+            for i, n in enumerate(c[:, phase, :n_held].sum(axis=0)):
                 out[f"{name}.expert.{i}"] = int(n)
+            if self.spec.zero_experts:
+                out[f"{name}.zero_picks"] = int(c[:, phase, n_held].sum())
+                out[f"{name}.ffn_picks"] = int(c[:, phase, n_held + 1].sum())
         return out
 
     def _publish_metrics(self) -> None:

@@ -109,7 +109,8 @@ _M_MOE = REGISTRY.counter(
     "the expert layers' device-side counters, summed over layers "
     "(engine.moe_counters): by phase (prefill | decode) and what "
     "(steps | assignments | expert.<i> = assignments of real tokens "
-    "that reached held expert i)",
+    "that reached held expert i | zero_picks, ffn_picks = a model with "
+    "identity experts: the picks that were identity / FFN experts)",
     ["engine", "phase", "what"],
 )
 

@@ -173,7 +173,9 @@ class MlaFamily:
     """Latent-attention (MLA) adapter over models/mla.py. The engine's
     ``(k_pages, v_pages)`` pair carries the family's two device-side
     states: ``k_pages`` the ONE latent cache ``[L, pages, page, D]`` (an
-    array, or a ``QuantPool`` for fp8) and ``v_pages`` the expert layers'
+    array, or a ``QuantPool`` for fp8; for a model of shortcut-connected
+    double layers a tuple of two such pools, one a sub-layer:
+    ``mla.sub_pools``) and ``v_pages`` the expert layers'
     counters ``[L, 2, n_held + 3]`` int32 (``[L, 2, 0]`` without
     experts), which every program adds to as it runs. Both lead with a
     layer axis, as every leaf of the pair does; page bookkeeping, KVBM
@@ -215,7 +217,9 @@ class MlaFamily:
         from jax.sharding import NamedSharding, PartitionSpec as P
 
         # the counters are a single replicated leaf either way
-        return self.mla.cache_shardings(mesh, kv_dtype), NamedSharding(mesh, P())
+        sub = spec.sub_layers if spec is not None else 1
+        return (self.mla.cache_shardings(mesh, kv_dtype, sub),
+                NamedSharding(mesh, P()))
 
     def init_cache(self, spec, num_pages, page_size, kv_dtype="bf16"):
         cache = self.mla.init_cache(
@@ -266,14 +270,25 @@ class MlaFamily:
         return v
 
     def extract_pages(self, k, v, page_ids):
-        # latent blocks [L, n, page, D]; the v slot stays inert (kept in
-        # kvbm/transfer payloads so block plumbing is shape-agnostic)
-        blocks = _extract_latent(k, page_ids)
+        # latent blocks [L, n, page, D] (a double layer's two pools one
+        # behind the other on the layer axis); the v slot stays inert
+        # (kept in kvbm/transfer payloads so block plumbing is
+        # shape-agnostic)
+        pools = self.mla.sub_pools(k)
+        blocks = [_extract_latent(pool, page_ids) for pool in pools]
         n = page_ids.shape[0]
-        return blocks, jnp.zeros((1, n), jnp.int8)
+        return (blocks[0] if len(pools) == 1 else jnp.concatenate(blocks),
+                jnp.zeros((1, n), jnp.int8))
 
     def insert_pages(self, k, v, page_ids, kb, vb):
-        return _insert_latent(k, page_ids, kb), v
+        pools = self.mla.sub_pools(k)
+        if len(pools) == 1:
+            return _insert_latent(k, page_ids, kb), v
+        L = len(kb) // len(pools)
+        return tuple(
+            _insert_latent(pool, page_ids, kb[j * L: (j + 1) * L])
+            for j, pool in enumerate(pools)
+        ), v
 
     def embed_forward(self, spec, params, tokens, num_tokens):
         return self.mla.embed_forward(spec, params, tokens, num_tokens)

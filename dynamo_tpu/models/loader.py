@@ -37,6 +37,7 @@ from __future__ import annotations
 import json
 import math
 import os
+import re
 from typing import Any, Callable
 
 import jax
@@ -82,10 +83,12 @@ def spec_from_hf_config(cfg: dict, name: str | None = None) -> ModelSpec:
             num_experts=n_experts,
             num_experts_per_token=int(
                 cfg.get("num_experts_per_tok")
-                or cfg.get("experts_per_token") or 2
+                or cfg.get("experts_per_token") or cfg.get("moe_topk") or 2
             ),
             moe_intermediate_size=int(
-                cfg.get("moe_intermediate_size") or cfg["intermediate_size"]
+                cfg.get("moe_intermediate_size")
+                or cfg.get("expert_ffn_hidden_size")
+                or cfg["intermediate_size"]
             ),
         )
     # gpt-oss attention extras: sinks + per-layer sliding windows +
@@ -125,6 +128,33 @@ def spec_from_hf_config(cfg: dict, name: str | None = None) -> ModelSpec:
                 ),
                 norm_topk_prob=bool(cfg.get("norm_topk_prob", True)),
             )
+    if model_type == "longcat_flash" or "zero_expert_num" in cfg:
+        # LongCat-Flash: shortcut-connected double layers over latent
+        # attention, identity experts behind the FFN experts in one
+        # softmax router with a correction bias. The config names its
+        # depth ``num_layers`` and its widths ``ffn_hidden_size`` /
+        # ``expert_ffn_hidden_size``; it has no ``rope_interleave`` key
+        # (the DeepSeek family's interleaved pairs are assumed) and no
+        # ``norm_topk_prob`` (absent = false)
+        if cfg.get("zero_expert_type", "identity") != "identity":
+            raise NotImplementedError(
+                f"longcat_flash: zero_expert_type {cfg['zero_expert_type']!r}")
+        if str(cfg.get("attention_method", "MLA")).upper() != "MLA":
+            raise NotImplementedError(
+                f"longcat_flash: attention_method {cfg['attention_method']!r}")
+        if cfg.get("router_bias"):
+            raise NotImplementedError("longcat_flash: router_bias true")
+        extras.update(
+            shortcut_moe=True,
+            zero_experts=int(cfg.get("zero_expert_num") or 0),
+            moe_scoring="softmax_bias",
+            routed_scaling_factor=float(
+                cfg.get("routed_scaling_factor") or 1.0),
+            norm_topk_prob=bool(cfg.get("norm_topk_prob", False)),
+            mla_scale_q_lora=bool(cfg.get("mla_scale_q_lora", False)),
+            mla_scale_kv_lora=bool(cfg.get("mla_scale_kv_lora", False)),
+            rope_interleave=bool(cfg.get("rope_interleave", True)),
+        )
     if model_type == "solar_open2":
         # KDA layers among gated NoPE GQA layers (``gqa_layers`` lists the
         # softmax ones), sigmoid routing with a correction bias in every
@@ -232,8 +262,9 @@ def spec_from_hf_config(cfg: dict, name: str | None = None) -> ModelSpec:
         name=name or cfg.get("_name_or_path") or model_type,
         vocab_size=int(cfg["vocab_size"]),
         hidden_size=hidden,
-        intermediate_size=int(cfg["intermediate_size"]),
-        num_layers=int(cfg["num_hidden_layers"]),
+        intermediate_size=int(
+            cfg.get("intermediate_size") or cfg["ffn_hidden_size"]),
+        num_layers=int(cfg.get("num_hidden_layers") or cfg["num_layers"]),
         num_heads=heads,
         num_kv_heads=int(cfg.get("num_key_value_heads", heads)),
         head_dim=int(cfg.get("head_dim") or hidden // heads),
@@ -277,6 +308,8 @@ def hf_config_from_spec(spec: ModelSpec) -> dict:
     architecture field the loader reads must round-trip, or an exported
     checkpoint silently loses features on reload."""
     _no_latent_kind(spec)
+    if spec.shortcut_moe:
+        return _longcat_config_from_spec(spec)
     if spec.kv_lora_rank:
         model_type = "deepseek_v3"
     elif "ssd" in spec.mixers:
@@ -417,6 +450,37 @@ def hf_config_from_spec(spec: ModelSpec) -> dict:
     return cfg
 
 
+def _longcat_config_from_spec(spec: ModelSpec) -> dict:
+    """LongCat-Flash's own keys (the config has no ``num_hidden_layers``,
+    ``intermediate_size`` or ``num_experts_per_tok``)."""
+    return {
+        "model_type": "longcat_flash", "attention_method": "MLA",
+        "attention_bias": False,
+        "vocab_size": spec.vocab_size, "hidden_size": spec.hidden_size,
+        "ffn_hidden_size": spec.intermediate_size,
+        "expert_ffn_hidden_size": spec.moe_intermediate_size,
+        "num_layers": spec.num_layers,
+        "num_attention_heads": spec.num_heads,
+        "kv_lora_rank": spec.kv_lora_rank, "q_lora_rank": spec.q_lora_rank,
+        "qk_rope_head_dim": spec.qk_rope_head_dim,
+        "qk_nope_head_dim": spec.qk_nope_head_dim,
+        "v_head_dim": spec.v_head_dim,
+        "mla_scale_q_lora": spec.mla_scale_q_lora,
+        "mla_scale_kv_lora": spec.mla_scale_kv_lora,
+        "routed_scaling_factor": spec.routed_scaling_factor,
+        "norm_topk_prob": spec.norm_topk_prob,
+        "n_routed_experts": spec.num_experts,
+        "zero_expert_num": spec.zero_experts,
+        "zero_expert_type": "identity", "moe_topk": spec.num_experts_per_token,
+        "rms_norm_eps": spec.rms_eps, "rope_theta": spec.rope_theta,
+        # params in memory are half-split already (see the deepseek
+        # branch of hf_config_from_spec): a reload must not permute again
+        "rope_interleave": False,
+        "tie_word_embeddings": spec.tie_embeddings,
+        "dtype": spec.dtype, "torch_dtype": spec.dtype,
+    }
+
+
 # ------------------------------------------------------------------- name map
 
 
@@ -441,6 +505,29 @@ def _moe_scheme(names: set[str] | None) -> str:
     return "mixtral"
 
 
+def _latent_attention_names(m: dict, spec: ModelSpec, a: str, at: tuple):
+    """One latent attention's tensors under the name prefix ``a`` -> the
+    tree path ``at`` (its fused ``kv_b_proj`` splits in load_params)."""
+    m[a + "o_proj.weight"] = (at + ("wo",), True, None)
+    m[a + "kv_a_proj_with_mqa.weight"] = (at + ("w_kv_a",), True, None)
+    m[a + "kv_a_layernorm.weight"] = (at + ("kv_norm",), False, None)
+    if spec.q_lora_rank:
+        m[a + "q_a_proj.weight"] = (at + ("wq_a",), True, None)
+        m[a + "q_a_layernorm.weight"] = (at + ("q_norm",), False, None)
+        m[a + "q_b_proj.weight"] = (at + ("wq_b",), True, None)
+    else:
+        m[a + "q_proj.weight"] = (at + ("wq",), True, None)
+
+
+def _expert_names(m: dict, spec: ModelSpec, experts: str, at: tuple):
+    """Every FFN expert's three projections, named one by one under the
+    prefix ``experts`` -> the stacked leaves under ``at``."""
+    for e in range(spec.num_experts):
+        for hf, ours in (("gate_proj", "w_gate"), ("up_proj", "w_up"),
+                         ("down_proj", "w_down")):
+            m[f"{experts}{e}.{hf}.weight"] = (at + (ours, e), True, None)
+
+
 def _dest_map_mla(
     spec: ModelSpec,
 ) -> dict[str, tuple[tuple, bool, str | None]]:
@@ -457,28 +544,14 @@ def _dest_map_mla(
         li = ("layers", i)
         m[p + "input_layernorm.weight"] = (li + ("attn_norm",), False, None)
         m[p + "post_attention_layernorm.weight"] = (li + ("mlp_norm",), False, None)
-        m[p + "self_attn.o_proj.weight"] = (li + ("wo",), True, None)
-        m[p + "self_attn.kv_a_proj_with_mqa.weight"] = (
-            li + ("w_kv_a",), True, None
-        )
-        m[p + "self_attn.kv_a_layernorm.weight"] = (li + ("kv_norm",), False, None)
-        if spec.q_lora_rank:
-            m[p + "self_attn.q_a_proj.weight"] = (li + ("wq_a",), True, None)
-            m[p + "self_attn.q_a_layernorm.weight"] = (li + ("q_norm",), False, None)
-            m[p + "self_attn.q_b_proj.weight"] = (li + ("wq_b",), True, None)
-        else:
-            m[p + "self_attn.q_proj.weight"] = (li + ("wq",), True, None)
+        _latent_attention_names(m, spec, p + "self_attn.", li)
         if spec.num_experts and i >= spec.first_k_dense:
             m[p + "mlp.gate.weight"] = (li + ("moe", "router"), True, "float32")
             if spec.moe_scoring == "sigmoid":
                 m[p + "mlp.gate.e_score_correction_bias"] = (
                     li + ("moe", "score_bias"), False, "float32"
                 )
-            for e in range(spec.num_experts):
-                ep = p + f"mlp.experts.{e}."
-                m[ep + "gate_proj.weight"] = (li + ("moe", "w_gate", e), True, None)
-                m[ep + "up_proj.weight"] = (li + ("moe", "w_up", e), True, None)
-                m[ep + "down_proj.weight"] = (li + ("moe", "w_down", e), True, None)
+            _expert_names(m, spec, p + "mlp.experts.", li + ("moe",))
             if spec.n_shared_experts:
                 sp_ = p + "mlp.shared_experts."
                 m[sp_ + "gate_proj.weight"] = (li + ("shared", "w_gate"), True, None)
@@ -489,6 +562,59 @@ def _dest_map_mla(
                              ("down_proj", "w_down")):
                 m[p + f"mlp.{hf}.weight"] = (li + (ours,), True, None)
     return m
+
+
+def _dest_map_longcat(
+    spec: ModelSpec,
+) -> dict[str, tuple[tuple, bool, str | None]]:
+    """LongCat-Flash tensor names (HF ``LongcatFlashForCausalLM``: the
+    two attentions, dense MLPs and norm pairs of a layer are module lists
+    indexed 0, 1) -> models/mla.py tree paths of a double layer. The
+    identity experts have no tensors. ``kv_b_proj`` splits in
+    load_params."""
+    m: dict[str, tuple[tuple, bool, str | None]] = {
+        "model.embed_tokens.weight": (("embed",), False, None),
+        "model.norm.weight": (("final_norm",), False, None),
+    }
+    if not spec.tie_embeddings:
+        m["lm_head.weight"] = (("lm_head",), True, None)
+    for i in range(spec.num_layers):
+        p = f"model.layers.{i}."
+        li = ("layers", i)
+        for j in range(2):
+            sub = li + ("sub", j)
+            a = p + f"self_attn.{j}."
+            m[p + f"input_layernorm.{j}.weight"] = (
+                sub + ("attn_norm",), False, None)
+            m[p + f"post_attention_layernorm.{j}.weight"] = (
+                sub + ("mlp_norm",), False, None)
+            _latent_attention_names(m, spec, a, sub)
+            for hf, ours in (("gate_proj", "w_gate"), ("up_proj", "w_up"),
+                             ("down_proj", "w_down")):
+                m[p + f"mlps.{j}.{hf}.weight"] = (sub + (ours,), True, None)
+        m[p + "mlp.router.classifier.weight"] = (
+            li + ("moe", "router"), True, "float32")
+        m[p + "mlp.router.e_score_correction_bias"] = (
+            li + ("moe", "score_bias"), False, "float32")
+        _expert_names(m, spec, p + "mlp.experts.", li + ("moe",))
+    return m
+
+
+def _mla_dest_map(spec: ModelSpec):
+    return (_dest_map_longcat if spec.shortcut_moe else _dest_map_mla)(spec)
+
+
+def _kv_b_names(spec: ModelSpec) -> dict[str, tuple]:
+    """The fused ``kv_b_proj`` tensor of every latent attention -> the
+    tree path of the layer (or sub-layer) that holds its two halves."""
+    if spec.shortcut_moe:
+        return {
+            f"model.layers.{i}.self_attn.{j}.kv_b_proj.weight":
+                ("layers", i, "sub", j)
+            for i in range(spec.num_layers) for j in range(2)
+        }
+    return {f"model.layers.{i}.self_attn.kv_b_proj.weight": ("layers", i)
+            for i in range(spec.num_layers)}
 
 
 def _dest_map(
@@ -663,7 +789,8 @@ def _tree_set(tree: Params, path: tuple, value) -> None:
                 node.append({})
             node = node[key]
         else:
-            node = node.setdefault(key, [] if key in ("layers",) else {})
+            node = node.setdefault(
+                key, [] if key in ("layers", "sub") else {})
     node[path[-1]] = value
 
 
@@ -707,7 +834,8 @@ def load_params(
         with safe_open(path_file, framework="numpy") as f:
             all_names.update(f.keys())
     if spec.kv_lora_rank:
-        dest = _dest_map_mla(spec)
+        dest = _mla_dest_map(spec)
+        kv_b = _kv_b_names(spec)
         fused_gpt_oss = False
     elif "conv" in spec.mixers:
         dest = _dest_map_lfm2(spec)
@@ -756,12 +884,10 @@ def load_params(
                                             + spec.nextn_predict_layers):
                             skipped_extras.append(name)
                         continue
-                    if spec.kv_lora_rank and name.endswith(
-                        "self_attn.kv_b_proj.weight"
-                    ):
+                    if spec.kv_lora_rank and name in kv_b:
                         # fused per-head up-projections [H*(dn+dv), dc]:
                         # split into w_uk [H, dc, dn] / w_uv [H, dc, dv]
-                        li = ("layers", int(name.split(".")[2]))
+                        li = kv_b[name]
                         arr = f.get_tensor(name)
                         H, dn, dv = (spec.num_heads, spec.qk_nope_head_dim,
                                      spec.v_head_dim)
@@ -831,10 +957,7 @@ def load_params(
 
     dest_expected = set(dest)
     if spec.kv_lora_rank:
-        dest_expected |= {
-            f"model.layers.{i}.self_attn.kv_b_proj.weight"
-            for i in range(spec.num_layers)
-        }
+        dest_expected |= set(kv_b)
     if fused_gpt_oss:
         tails = ["gate_up_proj", "down_proj"]
         if spec.moe_bias:
@@ -873,6 +996,8 @@ def _deinterleave_rope_cols(
     ``arr`` is already transposed to [in, out]."""
     dr = spec.qk_rope_head_dim
     perm = np.concatenate([np.arange(0, dr, 2), np.arange(1, dr, 2)])
+    # a double layer's attentions are ``self_attn.0.`` / ``self_attn.1.``
+    name = re.sub(r"self_attn\.\d+\.", "self_attn.", name)
     if name.endswith(("self_attn.q_b_proj.weight", "self_attn.q_proj.weight")):
         H, dn = spec.num_heads, spec.qk_nope_head_dim
         out = arr.reshape(arr.shape[0], H, dn + dr)
@@ -904,7 +1029,7 @@ def save_params(
     _no_latent_kind(spec)
     os.makedirs(model_dir, exist_ok=True)
     if spec.kv_lora_rank:
-        dest = _dest_map_mla(spec)
+        dest = _mla_dest_map(spec)
     elif "conv" in spec.mixers:
         dest = _dest_map_lfm2(spec)
     elif spec.moe_bias:
@@ -957,14 +1082,13 @@ def save_params(
         # (load_params splits them; see the kv_b_proj branch there)
         H, dn, dv, dc = (spec.num_heads, spec.qk_nope_head_dim,
                          spec.v_head_dim, spec.kv_lora_rank)
-        for i, lp in enumerate(params["layers"]):
+        for name, path in _kv_b_names(spec).items():
+            lp = _tree_get(params, path)
             fused = np.concatenate(
                 [np.asarray(lp["w_uk"]).transpose(0, 2, 1),
                  np.asarray(lp["w_uv"]).transpose(0, 2, 1)], axis=1
             ).reshape(H * (dn + dv), dc)
-            tensors[f"model.layers.{i}.self_attn.kv_b_proj.weight"] = (
-                np.ascontiguousarray(fused)
-            )
+            tensors[name] = np.ascontiguousarray(fused)
 
     shards: list[dict[str, np.ndarray]] = [{}]
     size = 0

@@ -44,6 +44,17 @@ permuted where the weights are made (``init_params``) or loaded
 (models/loader.py): exact, since both sides of every rope dot product get
 the same permutation. YaRN when the spec configures it.
 
+A model of shortcut-connected double layers (``ModelSpec.shortcut_moe``:
+LongCat-Flash) runs the same programs: ``_layer`` is the one place a
+decoder layer's data flow is written, and there a layer is two sub-layers
+(a latent attention and a dense FFN each) with ONE expert layer fed by the
+first FFN's input and added behind the second FFN. Its cache is a tuple of
+two pools, one a sub-layer, each ``[L, num_pages, page_size, D]`` under the
+same block tables; its layer holds ``"sub": [first, second]`` beside
+``"moe"``. The queries' and the latent's fixed scalars
+(``mla_scale_q_lora`` / ``mla_scale_kv_lora``) are applied where the
+projections are (``_q_heads``, ``_latent_row``).
+
 Every program takes the latent cache and, as ``counts``, the expert
 layers' device-side counters (``[L, 2, n_held + 3]`` int32, the layout of
 ``llama.KindPools.counts``); ``models/family.MlaFamily`` carries the two as
@@ -68,7 +79,7 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 from dynamo_tpu.engine.config import ModelSpec
 from dynamo_tpu.models.llama import (
     COUNT_DECODE, COUNT_PREFILL, TRASH_PAGE, _add, _draw, _embed, _norm,
-    _replicate, rms_norm, rope_spec,
+    _replicate, _times, rms_norm, rope_spec,
 )
 # the regions this family opens (jax.named_scope: metadata only)
 from dynamo_tpu.models.regions import (
@@ -131,7 +142,9 @@ def _half_split(spec: ModelSpec, w: jax.Array, lead: int) -> jax.Array:
 def init_params(spec: ModelSpec, key: jax.Array) -> Params:
     """Random weights in a STATED order, so that a reference that shares
     no code can draw the same (perfbench/references/latent_moe.py): the key
-    split in ``4 + 8 x layers``; embedding, head, then a layer ``wq_a``
+    split in ``4 + 8 x layers`` (``4 + 17 x layers`` for shortcut-connected
+    double layers: the first sub-layer's eight, the second's eight, then
+    the expert layer's one); embedding, head, then a layer ``wq_a``
     (``wq`` without a query rank), ``wq_b``, ``w_kv_a``, ``w_kv_b``, ``wo``
     and its MLP (dense: gate, up, down; experts: one key for
     ``moe.init_moe_layer``, one split in three for the shared expert's
@@ -143,12 +156,24 @@ def init_params(spec: ModelSpec, key: jax.Array) -> Params:
     assert spec.kv_lora_rank > 0, "not an MLA spec"
     dtype = jnp.dtype(spec.dtype)
     d = spec.hidden_size
-    keys = iter(jax.random.split(key, 4 + spec.num_layers * 8))
+    per_layer = 17 if spec.shortcut_moe else 8
+    keys = iter(jax.random.split(key, 4 + spec.num_layers * per_layer))
 
     def dense(k, shape, scale=None):
         if scale is None:
             scale = 1.0 / jnp.sqrt(shape[0])
         return _draw(k, scale, shape=shape, dtype=dtype)
+
+    def sub_layer(k_qa, k_qb, k_kva, k_kvb, k_o, k1, k2, k3) -> Params:
+        """A latent attention and a dense MLP with their two norms."""
+        return {
+            "attn_norm": jnp.ones((d,), dtype),
+            "mlp_norm": jnp.ones((d,), dtype),
+            **init_latent_mixer(spec, dense, k_qa, k_qb, k_kva, k_kvb, k_o),
+            "w_gate": dense(k1, (d, spec.intermediate_size)),
+            "w_up": dense(k2, (d, spec.intermediate_size)),
+            "w_down": dense(k3, (spec.intermediate_size, d)),
+        }
 
     params: Params = {
         "embed": dense(next(keys), (spec.vocab_size, d), scale=0.02),
@@ -159,30 +184,38 @@ def init_params(spec: ModelSpec, key: jax.Array) -> Params:
     if not spec.tie_embeddings:
         params["lm_head"] = dense(head_key, (d, spec.vocab_size))
     for li in range(spec.num_layers):
+        if spec.shortcut_moe:
+            from dynamo_tpu.models import moe
+
+            subs = [
+                sub_layer(*(next(keys) for _ in range(8))) for _ in range(2)
+            ]
+            params["layers"].append(
+                {"sub": subs, "moe": moe.init_moe_layer(spec, next(keys))})
+            continue
         k_qa, k_qb, k_kva, k_kvb, k_o, k1, k2, k3 = (
             next(keys) for _ in range(8)
         )
+        if not (spec.num_experts and li >= spec.first_k_dense):
+            params["layers"].append(
+                sub_layer(k_qa, k_qb, k_kva, k_kvb, k_o, k1, k2, k3))
+            continue
+        from dynamo_tpu.models import moe
+
         layer: Params = {
             "attn_norm": jnp.ones((d,), dtype),
             "mlp_norm": jnp.ones((d,), dtype),
             **init_latent_mixer(spec, dense, k_qa, k_qb, k_kva, k_kvb, k_o),
+            "moe": moe.init_moe_layer(spec, k1),
         }
-        if spec.num_experts and li >= spec.first_k_dense:
-            from dynamo_tpu.models import moe
-
-            layer["moe"] = moe.init_moe_layer(spec, k1)
-            if spec.n_shared_experts:
-                f = spec.moe_intermediate_size * spec.n_shared_experts
-                kg, ku, kd = jax.random.split(k2, 3)
-                layer["shared"] = {
-                    "w_gate": dense(kg, (d, f)),
-                    "w_up": dense(ku, (d, f)),
-                    "w_down": dense(kd, (f, d)),
-                }
-        else:
-            layer["w_gate"] = dense(k1, (d, spec.intermediate_size))
-            layer["w_up"] = dense(k2, (d, spec.intermediate_size))
-            layer["w_down"] = dense(k3, (spec.intermediate_size, d))
+        if spec.n_shared_experts:
+            f = spec.moe_intermediate_size * spec.n_shared_experts
+            kg, ku, kd = jax.random.split(k2, 3)
+            layer["shared"] = {
+                "w_gate": dense(kg, (d, f)),
+                "w_up": dense(ku, (d, f)),
+                "w_down": dense(kd, (f, d)),
+            }
         params["layers"].append(layer)
     return params
 
@@ -200,7 +233,16 @@ def init_latent_mixer(
     d, H = spec.hidden_size, spec.num_heads
     dn, dr, dv = spec.qk_nope_head_dim, spec.qk_rope_head_dim, spec.v_head_dim
     dc = spec.kv_lora_rank
-    kv_b = dense(k_kvb, (dc, H * (dn + dv))).reshape(dc, H, dn + dv)
+    # behind a rank whose output the model scales by sqrt(d / rank)
+    # (``mla_scale_*``), an up-projection is drawn with the model's WIDTH
+    # as its fan-in: the scalar then brings its output back to unit
+    # variance, which is what it is for. Drawn at 1 / rank the queries and
+    # keys are 2 and 3.5 times too large, the scores' spread 7 times, and
+    # the softmax a hard maximum over positions that bfloat16 rounding
+    # flips (PERF.md section 6, PR 49)
+    up_q = 1.0 / jnp.sqrt(d) if spec.mla_scale_q_lora else None
+    up_kv = 1.0 / jnp.sqrt(d) if spec.mla_scale_kv_lora else None
+    kv_b = dense(k_kvb, (dc, H * (dn + dv)), up_kv).reshape(dc, H, dn + dv)
     layer: Params = {
         "w_kv_a": _half_split(spec, dense(k_kva, (d, dc + dr)), dc),
         "kv_norm": jnp.ones((dc,), dtype),
@@ -212,7 +254,7 @@ def init_latent_mixer(
     wq = _half_split(
         spec,
         dense(k_qb if spec.q_lora_rank else k_qa,
-              (q_in, H * (dn + dr))).reshape(q_in, H, dn + dr),
+              (q_in, H * (dn + dr)), up_q).reshape(q_in, H, dn + dr),
         dn,
     ).reshape(q_in, H * (dn + dr))
     if spec.q_lora_rank:
@@ -240,20 +282,38 @@ def init_cache(
     with no head axis the row is the natural scale unit, appends never
     requantize their neighbors, and the finer granularity keeps the
     attention drift inside the tolerance goldens (a single per-page scale
-    measured ~2x the greedy-token disagreement on CPU)."""
+    measured ~2x the greedy-token disagreement on CPU). A model of double
+    layers (``spec.sub_layers`` 2) gets a TUPLE of such pools, one a
+    sub-layer, under the same page ids (``sub_pools``)."""
     dtype = dtype or jnp.dtype(spec.dtype)
     lead = (spec.num_layers, num_pages, page_size)
-    if kv_dtype == "fp8":
-        return init_quant_pool(lead + (latent_dim(spec),), 3)
-    return jnp.zeros(lead + (pool_head_dim(latent_dim(spec)),), dtype)
+
+    def pool():
+        if kv_dtype == "fp8":
+            return init_quant_pool(lead + (latent_dim(spec),), 3)
+        return jnp.zeros(lead + (pool_head_dim(latent_dim(spec)),), dtype)
+
+    if spec.sub_layers == 1:
+        return pool()
+    return tuple(pool() for _ in range(spec.sub_layers))
+
+
+def sub_pools(cache) -> tuple:
+    """The cache as its pools, one a sub-layer of a decoder layer: the
+    one pool of a model of plain layers, or the tuple ``init_cache`` made
+    (a ``QuantPool`` is a named tuple and ONE pool)."""
+    return cache if type(cache) is tuple else (cache,)
 
 
 def init_counts(spec: ModelSpec) -> jax.Array:
     """The expert layers' counters, zeroed: ``[L, 2, n_held + 3]`` int32
     by layer and phase (``llama.COUNT_PREFILL`` / ``COUNT_DECODE``), as
     ``llama.KindPools.counts``; ``[L, 2, 0]`` for a model without
-    experts."""
+    experts. Identity experts add two behind the held experts' sizes
+    (``moe.moe_mlp``)."""
     n = spec.experts_here[0] + 3 if spec.num_experts else 0
+    if spec.zero_experts:
+        n += 2
     return jnp.zeros((spec.num_layers, 2, n), jnp.int32)
 
 
@@ -288,8 +348,7 @@ def param_shardings(spec: ModelSpec, mesh: Mesh) -> Params:
     def ns(*axes):
         return NamedSharding(mesh, P(*axes))
 
-    layers = []
-    for li in range(spec.num_layers):
+    def mixer() -> Params:
         layer: Params = {
             "attn_norm": ns(),
             "mlp_norm": ns(),
@@ -305,6 +364,23 @@ def param_shardings(spec: ModelSpec, mesh: Mesh) -> Params:
             layer["wq_b"] = ns(None, "tp")  # column (heads major)
         else:
             layer["wq"] = ns(None, "tp")
+        return layer
+
+    dense_mlp = {
+        "w_gate": ns(None, "tp"), "w_up": ns(None, "tp"),
+        "w_down": ns("tp", None),
+    }
+    layers = []
+    for li in range(spec.num_layers):
+        if spec.shortcut_moe:
+            from dynamo_tpu.models import moe
+
+            layers.append({
+                "sub": [{**mixer(), **dense_mlp} for _ in range(2)],
+                "moe": moe.moe_layer_shardings(mesh, spec),
+            })
+            continue
+        layer = mixer()
         if spec.num_experts and li >= spec.first_k_dense:
             from dynamo_tpu.models import moe
 
@@ -316,9 +392,7 @@ def param_shardings(spec: ModelSpec, mesh: Mesh) -> Params:
                     "w_down": ns("tp", None),
                 }
         else:
-            layer["w_gate"] = ns(None, "tp")
-            layer["w_up"] = ns(None, "tp")
-            layer["w_down"] = ns("tp", None)
+            layer.update(dense_mlp)
         layers.append(layer)
     out = {
         "embed": ns(None, "tp"),
@@ -330,15 +404,16 @@ def param_shardings(spec: ModelSpec, mesh: Mesh) -> Params:
     return out
 
 
-def cache_shardings(mesh: Mesh, kv_dtype: str = "bf16"):
+def cache_shardings(mesh: Mesh, kv_dtype: str = "bf16", sub_layers: int = 1):
     """Latent cache [L, pages, page, d_c + d_r]: REPLICATED across the
     mesh. There is no head axis to split — the latent row is shared by
     every head — and at ~14x compression vs GQA the duplication is the
     cheap side of the trade (each rank attends against its local copy
     with zero gather collectives in the decode hot loop). Quantized
-    caches replicate both leaves."""
+    caches replicate both leaves; a pool a sub-layer, each the same."""
     s = NamedSharding(mesh, P())
-    return QuantPool(s, s) if kv_dtype == "fp8" else s
+    pool = QuantPool(s, s) if kv_dtype == "fp8" else s
+    return pool if sub_layers == 1 else (pool,) * sub_layers
 
 
 # --------------------------------------------------------------- pieces
@@ -353,6 +428,8 @@ def _q_heads(spec: ModelSpec, lp: Params, h: jax.Array, positions) -> tuple:
         q = rms_norm(h @ lp["wq_a"], lp["q_norm"], spec.rms_eps) @ lp["wq_b"]
     else:
         q = h @ lp["wq"]
+    if spec.mla_scale_q_lora:
+        q = _times(q, (spec.hidden_size / spec.q_lora_rank) ** 0.5)
     q = q.reshape(*h.shape[:-1], H, dn + dr)
     q_nope, q_rope = q[..., :dn], q[..., dn:]
     return q_nope, rope_spec(spec, q_rope, positions)
@@ -365,6 +442,11 @@ def _latent_row(spec: ModelSpec, lp: Params, h: jax.Array, positions):
     dc = spec.kv_lora_rank
     kv_a = h @ lp["w_kv_a"]
     c = rms_norm(kv_a[..., :dc], lp["kv_norm"], spec.rms_eps)
+    if spec.mla_scale_kv_lora:
+        # in float32: sqrt(12) rounded to bfloat16 first is 0.13% off on
+        # every key and value
+        c = (c.astype(jnp.float32) * (spec.hidden_size / dc) ** 0.5).astype(
+            c.dtype)
     k_r = rope_spec(spec, kv_a[..., None, dc:], positions)[..., 0, :]
     return jnp.concatenate([c, k_r], axis=-1)
 
@@ -569,6 +651,62 @@ def whole_layer(spec: ModelSpec, lp: Params, h: jax.Array, positions, mask):
     return _o_proj(lp, attn, h)
 
 
+def _layer(
+    spec: ModelSpec, li: int, lp: Params, x: jax.Array, mix, cache, counts,
+    phase: int, counted, mesh: Mesh | None,
+):
+    """Decoder layer ``li`` over the residual stream ``x`` [..., d]: THE
+    place a layer's data flow is written, for every program. ``mix(li, ap,
+    h, pool) -> (out, pool)`` is the calling program's latent mixer over
+    the normed input ``h`` (``ap``: the weights of one attention; ``pool``:
+    that attention's pool of the cache, None for a cacheless pass);
+    ``counted`` [rows] bool the real rows of ``x`` flattened, for the
+    expert layer's counters. Returns (x, cache, counts).
+
+    A plain layer: attention, then its MLP (dense, or experts). A
+    shortcut-connected double layer (``lp["sub"]``):
+
+        x1 = x  + MLA_0(norm(x));   u = norm(x1);   m = MoE(u)
+        x2 = x1 + FFN_0(u)
+        x3 = x2 + MLA_1(norm(x2))
+        x' = x3 + FFN_1(norm(x3)) + m
+
+    ``m`` is made from the first attention's output and is not read until
+    the end: nothing of the second attention or either dense FFN depends
+    on it, which is what lets a deployment hide the experts' exchange
+    behind them and lets the compiler order the grouped products beside
+    the dense ones."""
+
+    def ffn(mp, hh, counts):
+        y, counts = _ffn_counting(
+            spec, li, mp, hh.reshape(-1, hh.shape[-1]), counts, phase,
+            counted, mesh,
+        )
+        return y.reshape(hh.shape), counts
+
+    if "sub" not in lp:
+        h = _norm(x, lp["attn_norm"], spec.rms_eps)
+        out, cache = mix(li, lp, h, cache)
+        x = _add(x, out)
+        y, counts = ffn(lp, _norm(x, lp["mlp_norm"], spec.rms_eps), counts)
+        return _add(x, y), cache, counts
+    first, second = lp["sub"]
+    pools = (None, None) if cache is None else sub_pools(cache)
+    out, pool0 = mix(li, first, _norm(x, first["attn_norm"], spec.rms_eps),
+                     pools[0])
+    x = _add(x, out)
+    u = _norm(x, first["mlp_norm"], spec.rms_eps)
+    m, counts = ffn({"moe": lp["moe"]}, u, counts)  # the shortcut
+    y, counts = ffn(first, u, counts)
+    x = _add(x, y)
+    out, pool1 = mix(li, second, _norm(x, second["attn_norm"], spec.rms_eps),
+                     pools[1])
+    x = _add(x, out)
+    y, counts = ffn(second, _norm(x, second["mlp_norm"], spec.rms_eps), counts)
+    x = _add(_add(x, y), m)
+    return x, None if cache is None else (pool0, pool1), counts
+
+
 def _with_counts(out: tuple, counts):
     """A program's results, the counters appended where the caller passed
     them."""
@@ -588,11 +726,12 @@ def reference_forward(
     positions = jnp.arange(T)
     x = _embed(params, tokens)
     mask = positions[:, None] >= positions[None, :]
+
+    def mix(li, ap, h, pool):
+        return whole_layer(spec, ap, h, positions, mask), pool
+
     for li, lp in enumerate(params["layers"]):
-        h = _norm(x, lp["attn_norm"], spec.rms_eps)
-        x = _add(x, whole_layer(spec, lp, h, positions, mask))
-        hh = _norm(x, lp["mlp_norm"], spec.rms_eps)
-        x = _add(x, _ffn(spec, li, lp, hh))
+        x, _, _ = _layer(spec, li, lp, x, mix, None, None, 0, None, None)
     return _logits_all(spec, params, x)
 
 
@@ -621,7 +760,7 @@ def prefill_forward_impl(
     page-granularly; returns (last_logits, cache[, counts]). Mirrors
     llama.prefill_forward_impl."""
     T = tokens.shape[0]
-    page_size = cache.shape[2]
+    page_size = sub_pools(cache)[0].shape[2]
     n_pg = T // page_size
     with jax.named_scope(SCOPE_INDEX):
         idx = jnp.arange(T)
@@ -635,25 +774,24 @@ def prefill_forward_impl(
     x = _embed(params, tokens)
     with jax.named_scope(SCOPE_INDEX):
         kv_len = start_pos + num_tokens
-    for li, lp in enumerate(params["layers"]):
-        h = _norm(x, lp["attn_norm"], spec.rms_eps)
-        q_nope, q_rope, new_rows = _attn_inputs(spec, lp, h, positions)
+
+    def mix(li, ap, h, pool):
+        q_nope, q_rope, new_rows = _attn_inputs(spec, ap, h, positions)
         with jax.named_scope(SCOPE_KV):
-            cache = _set_latent_tiles(
-                cache, li, safe_pg,
+            pool = _set_latent_tiles(
+                pool, li, safe_pg,
                 new_rows.reshape(n_pg, page_size, -1),
                 real.reshape(n_pg, page_size),
             )
         attn = _seq_attention(
-            spec, li, lp, q_nope[None], q_rope[None], new_rows[None], cache,
+            spec, li, ap, q_nope[None], q_rope[None], new_rows[None], pool,
             block_table[None], start_pos[None], kv_len[None], mesh,
         )[0]
-        x = _add(x, _o_proj(lp, attn, h))
-        hh = _norm(x, lp["mlp_norm"], spec.rms_eps)
-        y, counts = _ffn_counting(
-            spec, li, lp, hh, counts, COUNT_PREFILL, real, mesh
-        )
-        x = _add(x, y)
+        return _o_proj(ap, attn, h), pool
+
+    for li, lp in enumerate(params["layers"]):
+        x, cache, counts = _layer(
+            spec, li, lp, x, mix, cache, counts, COUNT_PREFILL, real, mesh)
     with jax.named_scope(SCOPE_HEAD):
         last = jnp.clip(num_tokens - 1, 0, T - 1)
         logits = _logits_all(spec, params, x)[last]
@@ -685,7 +823,7 @@ def prefill_forward_batch_impl(
     prompt over its own table). Returns (last_logits [N, V], cache[,
     counts])."""
     N, T = tokens.shape
-    page_size = cache.shape[2]
+    page_size = sub_pools(cache)[0].shape[2]
     n_pg = T // page_size
     with jax.named_scope(SCOPE_INDEX):
         idx = jnp.arange(T)
@@ -704,20 +842,19 @@ def prefill_forward_batch_impl(
     x = _embed(params, tokens)  # [N, T, d]
     with jax.named_scope(SCOPE_INDEX):
         kv_len = start_pos + num_tokens  # [N]
-    for li, lp in enumerate(params["layers"]):
-        h = _norm(x, lp["attn_norm"], spec.rms_eps)
-        mix, cache = prefill_layer(
-            spec, li, lp, h, positions, cache, safe_pg,
+
+    def mix(li, ap, h, pool):
+        return prefill_layer(
+            spec, li, ap, h, positions, pool, safe_pg,
             real.reshape(N * n_pg, page_size), block_tables, start_pos,
             kv_len, mesh,
         )
-        x = _add(x, mix)
-        hh = _norm(x, lp["mlp_norm"], spec.rms_eps)
-        y, counts = _ffn_counting(
-            spec, li, lp, hh.reshape(N * T, -1), counts, COUNT_PREFILL,
+
+    for li, lp in enumerate(params["layers"]):
+        x, cache, counts = _layer(
+            spec, li, lp, x, mix, cache, counts, COUNT_PREFILL,
             real.reshape(N * T), mesh,
         )
-        x = _add(x, y.reshape(N, T, -1))
 
     with jax.named_scope(SCOPE_HEAD):
         last = jnp.clip(num_tokens - 1, 0, T - 1)  # [N]
@@ -753,7 +890,7 @@ def verify_forward_impl(
     argmax at all W positions, returned as [N, W] int32 so only token ids
     cross to the host. Returns (targets, cache[, counts])."""
     N, W = tokens.shape
-    page_size = cache.shape[2]
+    page_size = sub_pools(cache)[0].shape[2]
     idx = jnp.arange(W)
     positions = start_pos[:, None] + idx[None, :]  # [N, W]
     valid = idx[None, :] < num_tokens[:, None]
@@ -765,31 +902,31 @@ def verify_forward_impl(
 
     x = _embed(params, tokens)  # [N, W, d]
     kv_len = start_pos + num_tokens  # [N]
-    for li, lp in enumerate(params["layers"]):
-        h = _norm(x, lp["attn_norm"], spec.rms_eps)
-        q_nope, q_rope, new_rows = _attn_inputs(spec, lp, h, positions)
+
+    def mix(li, ap, h, pool):
+        q_nope, q_rope, new_rows = _attn_inputs(spec, ap, h, positions)
         flat = new_rows.reshape(N * W, -1)
         with jax.named_scope(SCOPE_KV):
-            if is_quant(cache):
+            if is_quant(pool):
                 # per-row scales make this a plain scatter: every (page,
                 # offset) slot owns its scale, so same-page siblings never
                 # clash (unlike the GQA page RMW)
-                cache = quant_append_rows(cache, flat, safe_pg, offs, li)
+                pool = quant_append_rows(pool, flat, safe_pg, offs, li)
             else:
-                cache = cache.at[li, safe_pg, offs].set(
-                    pad_heads(flat, cache.shape[-1]).astype(cache.dtype)
+                pool = pool.at[li, safe_pg, offs].set(
+                    pad_heads(flat, pool.shape[-1]).astype(pool.dtype)
                 )
         attn = _seq_attention(
-            spec, li, lp, q_nope, q_rope, new_rows, cache, block_tables,
+            spec, li, ap, q_nope, q_rope, new_rows, pool, block_tables,
             start_pos, kv_len, mesh,
         )
-        x = _add(x, _o_proj(lp, attn, h))
-        hh = _norm(x, lp["mlp_norm"], spec.rms_eps)
-        y, counts = _ffn_counting(
-            spec, li, lp, hh.reshape(N * W, -1), counts, COUNT_PREFILL,
+        return _o_proj(ap, attn, h), pool
+
+    for li, lp in enumerate(params["layers"]):
+        x, cache, counts = _layer(
+            spec, li, lp, x, mix, cache, counts, COUNT_PREFILL,
             valid.reshape(N * W), mesh,
         )
-        x = _add(x, y.reshape(N, W, -1))
 
     logits = _logits_all(spec, params, x)  # [N, W, V]
     if allowed is not None:
@@ -820,8 +957,7 @@ def decode_forward_impl(
 ):
     """One decode step (absorbed latent attention over the sequences' live
     pages); returns (logits, cache[, counts])."""
-    B = tokens.shape[0]
-    page_size = cache.shape[2]
+    page_size = sub_pools(cache)[0].shape[2]
     with jax.named_scope(SCOPE_INDEX):
         positions = seq_lens - 1
         page_idx = jnp.take_along_axis(
@@ -830,20 +966,20 @@ def decode_forward_impl(
         safe_page = jnp.where(active, page_idx, TRASH_PAGE)
         offset = positions % page_size
     # the kernel's schedule follows the lengths alone: once a step
-    schedule = latent_decode_schedule(cache, block_tables, seq_lens, mesh)
+    # (and the pools of a double layer's sub-layers share their shape)
+    schedule = latent_decode_schedule(
+        sub_pools(cache)[0], block_tables, seq_lens, mesh)
     x = _embed(params, tokens)
-    for li, lp in enumerate(params["layers"]):
-        h = _norm(x, lp["attn_norm"], spec.rms_eps)
-        mix, cache = decode_layer(
-            spec, li, lp, h, positions, cache, block_tables, seq_lens,
+
+    def mix(li, ap, h, pool):
+        return decode_layer(
+            spec, li, ap, h, positions, pool, block_tables, seq_lens,
             safe_page, offset, schedule, mesh,
         )
-        x = _add(x, mix)
-        hh = _norm(x, lp["mlp_norm"], spec.rms_eps)
-        y, counts = _ffn_counting(
-            spec, li, lp, hh, counts, COUNT_DECODE, active, mesh
-        )
-        x = _add(x, y)
+
+    for li, lp in enumerate(params["layers"]):
+        x, cache, counts = _layer(
+            spec, li, lp, x, mix, cache, counts, COUNT_DECODE, active, mesh)
     logits = _replicate(_logits_all(spec, params, x), mesh)
     return _with_counts((logits, cache), counts)
 
@@ -951,11 +1087,12 @@ def embed_forward_impl(
     mask2d = (positions[:, None] >= positions[None, :]) & (
         positions[None, :] < num_tokens
     )
+
+    def mix(li, ap, h, pool):
+        return whole_layer(spec, ap, h, positions, mask2d), pool
+
     for li, lp in enumerate(params["layers"]):
-        h = _norm(x, lp["attn_norm"], spec.rms_eps)
-        x = _add(x, whole_layer(spec, lp, h, positions, mask2d))
-        hh = _norm(x, lp["mlp_norm"], spec.rms_eps)
-        x = _add(x, _ffn(spec, li, lp, hh))
+        x, _, _ = _layer(spec, li, lp, x, mix, None, None, 0, None, None)
     xn = rms_norm(x, params["final_norm"], spec.rms_eps).astype(jnp.float32)
     valid = (positions < num_tokens)[:, None].astype(jnp.float32)
     pooled = (xn * valid).sum(axis=0) / jnp.maximum(valid.sum(), 1.0)

@@ -47,6 +47,7 @@ from dynamo_tpu.models.regions import (
     SCOPE_MOE_COUNT,
     SCOPE_MOE_DISPATCH,
     SCOPE_MOE_GROUPED,
+    SCOPE_MOE_ZERO,
     SCOPE_ROUTE,
 )
 
@@ -54,9 +55,10 @@ Params = dict
 
 
 def init_moe_layer(spec: ModelSpec, key: jax.Array) -> Params:
-    """Router over all experts + stacked weights of the held ones."""
+    """Router over all experts (and the identity experts behind them,
+    which have no weights) + stacked weights of the held ones."""
     dtype = jnp.dtype(spec.dtype)
-    d, e, f = spec.hidden_size, spec.num_experts, spec.moe_intermediate_size
+    d, e, f = spec.hidden_size, spec.router_outputs, spec.moe_intermediate_size
     held = spec.experts_here[0]
     k1, k2, k3, k4 = jax.random.split(key, 4)
 
@@ -67,8 +69,13 @@ def init_moe_layer(spec: ModelSpec, key: jax.Array) -> Params:
             scale = 1.0 / jnp.sqrt(shape[-2])
         return _draw(k, scale, shape=shape, dtype=dtype)
 
+    # a softmax router over hundreds of outputs is drawn so that a
+    # unit-RMS input gives logits of standard deviation 1.5 at EVERY
+    # width (0.019 at d = 6,144): at 0.02 a toy width's probabilities are
+    # all but equal and the correction bias alone would pick
+    r_scale = 1.5 / d ** 0.5 if spec.moe_scoring == "softmax_bias" else 0.02
     out = {
-        "router": dense(k1, (d, e), scale=0.02).astype(jnp.float32),
+        "router": dense(k1, (d, e), scale=r_scale).astype(jnp.float32),
         "w_gate": dense(k2, (held, d, f)),
         "w_up": dense(k3, (held, d, f)),
         "w_down": dense(k4, (held, f, d)),
@@ -85,6 +92,14 @@ def init_moe_layer(spec: ModelSpec, key: jax.Array) -> Params:
         # comparison made on random weights.
         out["score_bias"] = _draw(
             jax.random.fold_in(key, 1), 0.1, shape=(e,), dtype=jnp.float32
+        )
+    elif spec.moe_scoring == "softmax_bias":
+        # the same mechanism over probabilities, whose mean is 1 / e: a
+        # bias of that size moves picks near the k-th without choosing
+        # them alone
+        out["score_bias"] = _draw(
+            jax.random.fold_in(key, 1), 1.0 / e, shape=(e,),
+            dtype=jnp.float32,
         )
     return out
 
@@ -103,7 +118,7 @@ def moe_layer_shardings(mesh: Mesh, spec: ModelSpec | None = None) -> Params:
             **{k: ns(*v) for k, v in _EXPERT_SPECS.items()
                if k.startswith("b_")},
         )
-    if spec is not None and spec.moe_scoring == "sigmoid":
+    if spec is not None and spec.moe_scoring in ("sigmoid", "softmax_bias"):
         out["score_bias"] = ns()
     return out
 
@@ -161,7 +176,8 @@ def _two_best(x: jax.Array) -> jax.Array:
 @jax.named_scope(SCOPE_ROUTE)
 def route(spec: ModelSpec, lp: Params, x: jax.Array):
     """x: [T, d] -> (expert ids [T, k] int32, weights [T, k] f32), over
-    ALL ``num_experts``. Router arithmetic in float32."""
+    ALL ``num_experts`` (and, behind them, ``zero_experts`` identity
+    experts: ids >= ``num_experts``). Router arithmetic in float32."""
     T = x.shape[0]
     E, k = spec.num_experts, spec.num_experts_per_token
     router_logits = x.astype(jnp.float32) @ lp["router"]
@@ -182,6 +198,17 @@ def route(spec: ModelSpec, lp: Params, x: jax.Array):
                 gidx[:, :, None] == jnp.arange(G, dtype=jnp.int32), axis=1)
             choice = jnp.where(jnp.repeat(kept, gsz, axis=-1), choice, 0.0)
         topv, topi = _top_k(choice, k, of=scores)  # [T, k]
+        if spec.norm_topk_prob:
+            topv = topv / (
+                topv.sum(axis=-1, keepdims=True) + spec.moe_norm_eps)
+        topv = topv * spec.routed_scaling_factor
+    elif spec.moe_scoring == "softmax_bias":
+        # LongCat-Flash (HF LongcatFlashTopkRouter): probabilities over
+        # every output, the identity experts' among them; the correction
+        # bias picks, the weights are the unbiased probabilities times
+        # routed_scaling_factor, renormalised only where the config says
+        probs = jax.nn.softmax(router_logits, axis=-1)
+        topv, topi = _top_k(probs + lp["score_bias"], k, of=probs)
         if spec.norm_topk_prob:
             topv = topv / (
                 topv.sum(axis=-1, keepdims=True) + spec.moe_norm_eps)
@@ -324,7 +351,12 @@ def moe_mlp(
     output: (y, counts [n_held + 2] int32) = assignments of the counted
     rows to each held expert, then their assignments in all (k a row),
     then how many held experts got at least one (whose weights this call
-    had to read).
+    had to read). A model with identity experts (``spec.zero_experts``)
+    adds each token's own input times the sum of its identity picks'
+    weights, once, outside the shares (``moe_zero``), and counts two more
+    behind the held experts' sizes ([n_held + 4]): the counted rows' picks
+    that were identity experts, and those that were FFN experts, held
+    here or not (the two sum to k a counted row).
     Under a mesh with an "ep" or "tp" axis the expert weights are shards
     (moe_layer_shardings) and each shard's share is summed across them.
     """
@@ -361,6 +393,13 @@ def moe_mlp(
             }),
             out_specs=P(), check_vma=False,
         )(x, topi, topv, experts)
+    if spec.zero_experts:
+        # held by no chip: every chip adds its own tokens' term, as it
+        # does a shared expert's, so it counts once when shares are summed
+        with jax.named_scope(SCOPE_MOE_ZERO):
+            zero = topi >= spec.num_experts
+            y = y + x.astype(jnp.float32) * jnp.sum(
+                jnp.where(zero, topv, 0.0), axis=-1, keepdims=True)
     y = y.astype(x.dtype)
     if counted is None:
         return y
@@ -371,7 +410,12 @@ def moe_mlp(
             n_held)
         total = (jnp.sum(counted) * topi.shape[1]).astype(jnp.int32)
         touched = jnp.sum(sizes > 0).astype(jnp.int32)
-        return y, jnp.concatenate([sizes, total[None], touched[None]])
+        picks = []
+        if spec.zero_experts:
+            zeros = jnp.sum(zero & counted[:, None]).astype(jnp.int32)
+            picks = [zeros[None], (total - zeros)[None]]
+        return y, jnp.concatenate(
+            [sizes, *picks, total[None], touched[None]])
 
 
 def _slots(topi: jax.Array, first, n: int) -> jax.Array:

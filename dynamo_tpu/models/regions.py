@@ -83,6 +83,8 @@ SCOPE_GMM = "gmm"  # megablox's own jit: the Mosaic grouped product
 SCOPE_MOE_COMBINE = "moe_combine"  # un-permute: a token gathers its k rows
 # back and adds them, weighted, in float32
 SCOPE_MOE_SHARED = "moe_shared"  # the shared expert
+SCOPE_MOE_ZERO = "moe_zero"  # the identity experts: a token's own input
+# times the sum of its identity picks' weights
 SCOPE_MOE_COUNT = "moe_count"  # the layer's device-side counters
 # -- head
 SCOPE_HEAD = "head"  # final norm + vocabulary projection
@@ -113,6 +115,7 @@ REGIONS: dict[str, str] = {
     SCOPE_MLP: FFN, SCOPE_ROUTE: FFN, SCOPE_EXPERTS: FFN,
     SCOPE_MOE_DISPATCH: FFN, SCOPE_MOE_GROUPED: FFN, SCOPE_GMM: FFN,
     SCOPE_MOE_COMBINE: FFN, SCOPE_MOE_SHARED: FFN, SCOPE_MOE_COUNT: FFN,
+    SCOPE_MOE_ZERO: FFN,
     SCOPE_HEAD: HEAD, SCOPE_SAMPLER: HEAD,
     SCOPE_NORM: REST, SCOPE_RESIDUAL: REST, SCOPE_EMBED: REST,
     SCOPE_INDEX: REST, SCOPE_BURST: REST, SCOPE_FEED: REST,

@@ -586,3 +586,89 @@ def test_lfm2_decode_program_compiles_for_v5e(v5e, monkeypatch):
     # the tails are updated in place: what the program holds beside its
     # arguments is far less than an expert layer's weights (1.2 GB)
     assert compiled.memory_analysis().temp_size_in_bytes < 256 * 2**20
+
+
+def _longcat_layer(v5e, vocab=2048):
+    """ONE shortcut-connected double layer of LongCat-Flash-Chat at the
+    published widths, described: spec, weights and the cache of the cell's
+    engine (128 slots, pages of 64; 16 of 512 FFN experts held, 256
+    identity experts behind them in a router of 768 outputs; 64 heads
+    over a latent of 512 + 64; a pool of latent pages a sub-layer)."""
+    import dataclasses
+
+    from dynamo_tpu.engine.config import ModelSpec
+    from dynamo_tpu.models import mla
+
+    spec = dataclasses.replace(
+        ModelSpec.tiny_longcat(), vocab_size=vocab, hidden_size=6144,
+        intermediate_size=12288, num_layers=1, num_heads=64, num_kv_heads=64,
+        head_dim=96, dtype="bfloat16", kv_lora_rank=512, q_lora_rank=1536,
+        qk_nope_head_dim=128, qk_rope_head_dim=64, v_head_dim=128,
+        num_experts=512, held_experts=(16, 0), zero_experts=256,
+        num_experts_per_token=12, moe_intermediate_size=2048)
+
+    def described(tree):
+        return jax.tree.map(
+            lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=v5e),
+            tree)
+
+    params = described(jax.eval_shape(
+        lambda: mla.init_params(spec, jax.random.PRNGKey(0))))
+    cache = described(jax.eval_shape(lambda: mla.init_cache(spec, 2049, 64)))
+    counts = described(jax.eval_shape(lambda: mla.init_counts(spec)))
+    return spec, params, cache, counts
+
+
+@pytest.mark.parametrize("rows", [2, 1], ids=["pack-of-2", "single"])
+def test_longcat_prefill_program_compiles_for_v5e(v5e, monkeypatch, rows):
+    """The prefill programs of the cell, one double layer at the published
+    widths: 1,024 tokens a row through both sub-layers' page writes and
+    ``prefill_latent`` at 64 heads, the router over 768 outputs, the
+    grouped products; both pools donated."""
+    from dynamo_tpu.models import mla
+
+    _as_on_the_chip(monkeypatch)
+    spec, params, cache, counts = _longcat_layer(v5e)
+    i32 = jnp.int32
+    if rows == 1:
+        lowered = mla.prefill_forward.lower(
+            spec, params, _rows(v5e, 1024, dtype=i32),
+            _rows(v5e, 160, dtype=i32), _rows(v5e, dtype=i32), cache,
+            _rows(v5e, dtype=i32), counts=counts)
+    else:
+        lowered = mla.prefill_forward_batch.lower(
+            spec, params, _rows(v5e, rows, 1024, dtype=i32),
+            _rows(v5e, rows, 160, dtype=i32), _rows(v5e, rows, dtype=i32),
+            cache, _rows(v5e, rows, dtype=i32), counts=counts)
+    compiled = lowered.compile()
+    text = compiled.as_text()
+    assert "%prefill_latent" in text and "attn_latent" not in text
+    pools = sum(p.size * p.dtype.itemsize for p in cache)
+    assert compiled.memory_analysis().alias_size_in_bytes >= pools
+
+
+def test_longcat_decode_program_compiles_for_v5e(v5e, monkeypatch):
+    """The decode burst of the cell, one double layer at the published
+    widths: 128 slots through ``attn_latent`` at 64 heads TWICE (a pool a
+    sub-layer, the schedule made once a step), the shortcut's expert layer
+    between them, 8 steps, the sampler on the device; the kernels update
+    both pools in place."""
+    from dynamo_tpu.models import mla
+
+    _as_on_the_chip(monkeypatch)
+    spec, params, cache, counts = _longcat_layer(v5e)
+    B_, i32, f32 = 128, jnp.int32, jnp.float32
+    compiled = mla.decode_steps.lower(
+        spec, params, _rows(v5e, B_, dtype=i32), _rows(v5e, B_, 160, dtype=i32),
+        _rows(v5e, B_, dtype=i32), cache, _rows(v5e, B_, dtype=jnp.bool_),
+        _rows(v5e, B_, dtype=f32), _rows(v5e, B_, dtype=i32),
+        _rows(v5e, B_, dtype=f32), _rows(v5e, B_, dtype=jnp.uint32),
+        _rows(v5e, B_, dtype=i32), n_steps=8, n_logprobs=0, counts=counts,
+    ).compile()
+    text = compiled.as_text()
+    assert text.count("tpu_custom_call") == 2 + 3  # two attentions, gmm x 3
+    assert "%attn_latent" in text and "moe_zero" in text
+    pools = sum(p.size * p.dtype.itemsize for p in cache)
+    mem = compiled.memory_analysis()
+    assert mem.alias_size_in_bytes >= pools
+    assert mem.temp_size_in_bytes < pools

@@ -421,7 +421,8 @@ METRIC_NAMES: dict[str, str] = {
     "engine_dispatches_total": "jitted device programs issued",
     "engine_moe_counts_total": "expert layers' device-side counters by "
                                "phase and what (steps, assignments, "
-                               "experts_touched, expert.<i>)",
+                               "experts_touched, expert.<i>; zero_picks, "
+                               "ffn_picks with identity experts)",
     "engine_admission_rejects_total": "requests refused at admission by "
                                       "reason (draining | saturated | "
                                       "deadline) — the 503/504 feeders",
