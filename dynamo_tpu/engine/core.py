@@ -63,6 +63,7 @@ from dynamo_tpu.runtime.faults import FAULTS
 from dynamo_tpu.runtime.integrity import verify_resume_tokens
 from dynamo_tpu.runtime import race, tracing
 from dynamo_tpu.runtime.flight import FLIGHT, emit_request_spans
+from dynamo_tpu.runtime.loop_probe import LoopProbe
 from dynamo_tpu.tokens import TokenBlockSequence
 
 log = logging.getLogger("dynamo.engine")
@@ -198,6 +199,7 @@ READMIT_SUMS = {
 _COUNTER_FAMILIES = (
     "decode_kv", "prefill_kv", "chunked_prefill", "burst_hold",
     "decode_bursts", "first_tokens", "kda", "ssd", "recurrent_state",
+    "stream",
 )
 
 # undisturbed burst times kept a burst length (their smallest is the
@@ -246,6 +248,37 @@ class _PhaseSpan:
         rec = self._prof.setdefault(self._name, [0.0, 0])
         rec[0] += dt
         rec[1] += 1
+
+
+# the ONE private key of a profiled engine's items: the post instant in ns
+# of time.monotonic, put on by _post inside a stream.post span and taken
+# off by generate() before the yield
+_POSTED = "_posted"
+
+
+class _PostSpan:
+    """A profiled engine's ``stream.post``: the step thread hands the
+    streams the tokens of one device program (a decode burst, or the
+    prefill whose sample an admission wave lands). An annotation on the
+    profiler's clock carrying the program's launch number; while it is open
+    ``_post`` marks every item that carries tokens with the post instant
+    on ``time.monotonic``, read once a span."""
+
+    __slots__ = ("_engine", "_mark", "_note")
+
+    def __init__(self, engine: "InferenceEngine", seq: int):
+        self._engine = engine
+        self._mark = time.monotonic_ns()
+        self._note = jax.profiler.TraceAnnotation("stream.post", seq=seq)
+
+    def __enter__(self) -> None:
+        self._note.__enter__()
+        self._engine._post_mark = self._mark
+
+    def __exit__(self, *exc) -> None:
+        # spans of posts never nest: a wave lands before or after a burst
+        self._engine._post_mark = None
+        self._note.__exit__(*exc)
 
 
 @dataclass
@@ -552,6 +585,11 @@ class InferenceEngine:
         # running number of the device programs the step thread has
         # launched (_launch): always on, one int add a launch
         self._launch_seq = 0
+        # the delivery path (profiled only): the mark an open stream.post
+        # span holds out to _post, and the running number of the streams
+        # that took a marked item (``rid`` on stream.take)
+        self._post_mark: int | None = None
+        self._stream_rids = 0
         # the flight recorder the step thread records into; profiled, it
         # keeps every finished timeline of a measured window
         self.flight = FLIGHT
@@ -610,6 +648,16 @@ class InferenceEngine:
         # (_hold_queued_burst) or at the top of a cycle (_step), or from
         # the fed column of their slot's first burst (_process_burst)
         self.first_tokens = {"in_hold": 0, "at_step": 0, "on_burst": 0}
+        # a token-carrying item's way from the step thread's post to
+        # generate()'s take (a profiled engine only; the event loop alone
+        # writes them): items taken and their waits summed
+        self.stream = {"items": 0, "wait_us": 0}
+        # the event loop's heartbeat (runtime/loop_probe.py; always on,
+        # 20 wake-ups a second between start() and close()): its sum of
+        # the lags over 50 ms is ``event_loop.stalled_us``; profiled, such
+        # a lag is a ``loop.stall`` annotation too
+        self.loop_probe = LoopProbe(
+            self._note_loop_stall if self._profiling else None)
         # what the KDA kernels were asked to do, a layer's worth (always
         # on, a model with recurrent layers only): state rows a kda_step
         # call updated, over the dispatched bursts' steps; blocks of
@@ -718,6 +766,53 @@ class InferenceEngine:
             "engine.launch", kind=kind, seq=self._launch_seq, **counts
         )
 
+    def _stream_post(self, seq: int):
+        """Around the posts of one device program's tokens (phase 2 of
+        _process_burst; the landing of an admission wave). ``seq`` is that
+        program's launch number: a decode burst's, noted on its batch at
+        the launch, or the prefill's whose sample the wave is. Profiled: a
+        ``stream.post`` annotation, and every item posted inside it leaves
+        marked for generate()'s ``stream.take``. Unprofiled: the one
+        shared no-op, and no clock is read."""
+        if not self._profiling:
+            return _NO_SPAN
+        return _PostSpan(self, seq)
+
+    def _stream_take(self, item: dict, request_id: str, rid: int,
+                     first: bool) -> int:
+        """generate() takes a token-carrying item off its queue (profiled
+        engine, event loop): the flight recorder's ``delta`` for every
+        such item after the first, and for an item a stream.post span
+        marked the ``stream`` counts and a ``stream.take`` annotation
+        (``rid`` the stream's running number, ``wait_us`` take minus
+        post). The mark comes off here: nothing
+        downstream of generate() sees it. Returns the stream's ``rid``,
+        handed out at its first marked item."""
+        if not first:
+            FLIGHT.event(request_id, "delta")
+        posted_ns = item.pop(_POSTED, None)
+        if posted_ns is None:
+            return rid
+        if not rid:
+            self._stream_rids = rid = self._stream_rids + 1
+        wait_us = max(0, time.monotonic_ns() - posted_ns) // 1000
+        c = self.stream
+        c["items"] += 1
+        c["wait_us"] += wait_us
+        with jax.profiler.TraceAnnotation(
+            "stream.take", rid=rid, wait_us=wait_us
+        ):
+            pass
+        return rid
+
+    @staticmethod
+    def _note_loop_stall(lag_us: int) -> None:
+        """A profiled engine's ``loop.stall``: the event loop's heartbeat
+        (runtime/loop_probe.py) woke ``lag_us`` late, over 50 ms: the loop
+        stood still for that long up to this annotation's instant."""
+        with jax.profiler.TraceAnnotation("loop.stall", lag_us=lag_us):
+            pass
+
     def profile_snapshot(self) -> dict[str, dict[str, float]]:
         """Per-phase accumulated step-thread wall time (profiling mode),
         plus the always-on dispatch accounting:
@@ -776,6 +871,17 @@ class InferenceEngine:
           (``_process_burst``). ``in_hold`` over the three is how often a
           first token came home the moment its prefill ended, not a burst
           later; ~0 where the queue is never empty.
+        - ``stream.items`` / ``.wait_us`` (calls; a profiled engine only,
+          else 0): token-carrying items generate() took off its queue and
+          the time they lay between the step thread's post and that take,
+          summed (``_stream_take``): the one over the other is the mean
+          wait of a window.
+        - ``event_loop.stalled_us`` (calls; always on): the event loop's
+          heartbeat (runtime/loop_probe.py), the sum of the lags over
+          50 ms of a 50 ms sleep's wake-ups. Between two snapshots over
+          ``window.at``'s difference it is the share of the time the loop
+          stood still; every wake-up's own lag is in the probe's ring
+          (``loop_probe.lags``).
         """
         snap = {
             k: {"secs": round(v[0], 4), "calls": int(v[1])}
@@ -802,6 +908,9 @@ class InferenceEngine:
         for family in (*_COUNTER_FAMILIES, "kv_pool"):
             for name, n in getattr(self, family).items():
                 snap[f"{family}.{name}"] = {"secs": 0.0, "calls": n}
+        # the heartbeat's one count that two snapshots can difference
+        for name, n in (("stalled_us", self.loop_probe.stalled_us),):
+            snap[f"event_loop.{name}"] = {"secs": 0.0, "calls": n}
         return snap
 
     def reset_profile_window(self) -> None:
@@ -813,6 +922,7 @@ class InferenceEngine:
         self.dispatches = 0
         for family in _COUNTER_FAMILIES:
             setattr(self, family, dict.fromkeys(getattr(self, family), 0))
+        self.loop_probe.stalled_us = 0
         self._compile_base = compile_snapshot()
 
     def _full_table_chunk_pages(self) -> int | None:
@@ -1458,7 +1568,10 @@ class InferenceEngine:
 
     def _post(self, q: asyncio.Queue, item: Any) -> None:
         """Thread-safe queue put: compute threads must not touch asyncio
-        primitives directly."""
+        primitives directly. Inside a profiled engine's stream.post span an
+        item that carries tokens leaves marked (_PostSpan)."""
+        if self._post_mark is not None and item and item.get("token_ids"):
+            item[_POSTED] = self._post_mark
         race.release(q, "engine.out_q")
         if self._loop is None or threading.get_ident() == self._loop_thread:
             q.put_nowait(item)
@@ -1476,6 +1589,7 @@ class InferenceEngine:
             )
             race.fork(self._thread)
             self._thread.start()
+            self.loop_probe.start()
         return self
 
     @property
@@ -1561,6 +1675,7 @@ class InferenceEngine:
     async def close(self) -> None:
         self._closed = True
         self._wake.set()
+        await self.loop_probe.stop()
         if self.telemetry is not None:
             await self.telemetry.close()
         if self._thread is not None and self._thread.is_alive():
@@ -1866,6 +1981,7 @@ class InferenceEngine:
         finish_reason: str | None = None
         finish_error: str | None = None
         n_generated = 0
+        rid = 0  # the stream's running number (_stream_take), profiled
         try:
             while True:
                 # after the deadline every wait is bounded (2s per item):
@@ -1910,6 +2026,9 @@ class InferenceEngine:
                 if toks:
                     if n_generated == 0:
                         FLIGHT.event(context.id, "first_delta")
+                    if self._profiling:
+                        rid = self._stream_take(
+                            item, context.id, rid, n_generated == 0)
                     n_generated += len(toks)
                 # record BEFORE the yield: downstream operators stop
                 # iterating once they see the finish item, so this
@@ -3556,7 +3675,8 @@ class InferenceEngine:
                     # disagg prefill: stage KV to host, hand off, free pages
                     self._export_and_finish(slot, sp, token_ids, tok, entry)
                     continue
-                self._emit_token(slot_idx, slot, tok, logprob_entry=entry)
+                self._emit_token(slot_idx, slot, tok, logprob_entry=entry,
+                                 seq=waiting.prefill_seq)
             except Exception as e:  # noqa: BLE001
                 log.exception(
                     "admission emit failed for %s", waiting.context.id
@@ -3682,8 +3802,11 @@ class InferenceEngine:
                 recs.append((slot_idx, slot))
                 if pre is not None:
                     arr, row, _seed = pre
+                    # seq: the prefill whose fused sample the wave is
+                    # (stream.post carries it when the wave lands)
                     wave = waves.setdefault(
-                        id(arr), {"dev": arr, "recs": [], "fed": set(), "age": 0}
+                        id(arr), {"dev": arr, "recs": [], "fed": set(),
+                                  "age": 0, "seq": waiting.prefill_seq}
                     )
                     wave["recs"].append((slot_idx, slot, row))
                 else:
@@ -3704,6 +3827,7 @@ class InferenceEngine:
                     ],
                     "fed": set(),
                     "age": 0,
+                    "seq": self._launch_seq,  # the sample program's own
                 }
             for w in waves.values():
                 # start the host copy NOW: the wave can land from host
@@ -3854,26 +3978,32 @@ class InferenceEngine:
                             error=f"admission failed: {e}",
                         )
                 return None
-            for slot_idx, slot, row in ap["recs"]:
-                if self._slots[slot_idx] is not slot:
-                    continue  # finished/cancelled since admission
-                self._land_first_token(slot_idx, slot, int(toks[row]), where)
+            with self._stream_post(ap.get("seq", 0)):
+                for slot_idx, slot, row in ap["recs"]:
+                    if self._slots[slot_idx] is not slot:
+                        continue  # finished/cancelled since admission
+                    self._land_first_token(
+                        slot_idx, slot, int(toks[row]), where)
             return None
         rest: list[tuple] = []
-        for slot_idx, slot, row in ap["recs"]:
-            if self._slots[slot_idx] is not slot or not slot.first_pending:
-                continue  # finished/cancelled since admission
-            if (
-                slot_idx in fed
-                and part[slot_idx]
-                and participants is not None
-                and participants.get(slot_idx) == slot.request_id
-            ):
-                self._land_first_token(
-                    slot_idx, slot, int(fed_col[slot_idx]), "on_burst"
-                )
-            else:
-                rest.append((slot_idx, slot, row))
+        with self._stream_post(ap.get("seq", 0)):
+            for slot_idx, slot, row in ap["recs"]:
+                if (
+                    self._slots[slot_idx] is not slot
+                    or not slot.first_pending
+                ):
+                    continue  # finished/cancelled since admission
+                if (
+                    slot_idx in fed
+                    and part[slot_idx]
+                    and participants is not None
+                    and participants.get(slot_idx) == slot.request_id
+                ):
+                    self._land_first_token(
+                        slot_idx, slot, int(fed_col[slot_idx]), "on_burst"
+                    )
+                else:
+                    rest.append((slot_idx, slot, row))
         if rest:
             return {**ap, "recs": rest}
         return None
@@ -5029,6 +5159,8 @@ class InferenceEngine:
             live=len(batch["participants"]), slots=len(self._slots),
             ahead=len(self._pipeline),
         ):
+            # whose tokens _process_burst will post: stream.post's seq
+            batch["seq"] = self._launch_seq
             result = self.fam.decode_steps(
                 self.spec,
                 self.params,
@@ -5140,25 +5272,27 @@ class InferenceEngine:
             )
 
         # phase 2: stream tokens, finish slots
-        for i, (toks, finish) in burst.items():
-            slot = self._slots[i]
-            item: dict[str, Any] = {"token_ids": toks, "finish_reason": finish}
-            if slot.logprobs is not None and lp is not None:
-                item["logprobs"] = [
-                    {
-                        "id": int(sampled[i, j]),
-                        "logprob": float(lp[i, j]),
-                        "top": [
-                            {"id": int(top_i[i, j, t]),
-                             "logprob": float(top_v[i, j, t])}
-                            for t in range(slot.logprobs)
-                        ],
-                    }
-                    for j in range(len(toks))
-                ]
-            if finish is not None:
-                self._finish(i, slot, finish, emit=False)
-            self._post(slot.out_q, item)
+        with self._stream_post(batch.get("seq", 0)):
+            for i, (toks, finish) in burst.items():
+                slot = self._slots[i]
+                item: dict[str, Any] = {
+                    "token_ids": toks, "finish_reason": finish}
+                if slot.logprobs is not None and lp is not None:
+                    item["logprobs"] = [
+                        {
+                            "id": int(sampled[i, j]),
+                            "logprob": float(lp[i, j]),
+                            "top": [
+                                {"id": int(top_i[i, j, t]),
+                                 "logprob": float(top_v[i, j, t])}
+                                for t in range(slot.logprobs)
+                            ],
+                        }
+                        for j in range(len(toks))
+                    ]
+                if finish is not None:
+                    self._finish(i, slot, finish, emit=False)
+                self._post(slot.out_q, item)
 
         if self.steps % 16 < n_burst:
             self._publish_metrics()
@@ -5247,9 +5381,11 @@ class InferenceEngine:
 
     def _emit_token(
         self, slot_idx: int, slot: _Slot, tok: int,
-        logprob_entry: dict | None = None,
+        logprob_entry: dict | None = None, seq: int = 0,
     ) -> None:
-        """Record + stream one sampled token; place slot or finish."""
+        """Record + stream one sampled token; place slot or finish.
+        ``seq``: the launch number of the request's prefill, for the
+        post's stream.post."""
         FLIGHT.event(slot.context.id, "first_token")
         finish = self._accept_token(slot, tok)
         if finish is not None:
@@ -5263,7 +5399,8 @@ class InferenceEngine:
         item: dict[str, Any] = {"token_ids": [tok], "finish_reason": finish}
         if logprob_entry is not None:
             item["logprobs"] = [logprob_entry]
-        self._post(slot.out_q, item)
+        with self._stream_post(seq):
+            self._post(slot.out_q, item)
 
     def _finish(
         self, slot_idx: int, slot: _Slot, reason: str,

@@ -3,9 +3,9 @@
 publisher.rs ForwardPassMetrics).
 
 A module-level ``MetricsRegistry`` holds step-latency and burst-size
-histograms, page-pool / batch-occupancy / waiting-queue gauges, and
-dispatch / admission-reject / spec counters. ``EngineCollector`` is the
-cheap periodic sampler: the step thread only appends to two bounded
+histograms, the event loop's lag histogram, page-pool / batch-occupancy /
+waiting-queue gauges, and dispatch / admission-reject / spec counters.
+``EngineCollector`` is the cheap periodic sampler: the step thread only appends to two bounded
 deques (step durations, burst fills) and bumps plain ints; the collector
 drains those into Prometheus objects off the hot path. The registry is
 exported through ``metrics.register_registry``, so it renders on EVERY
@@ -19,7 +19,6 @@ from __future__ import annotations
 
 import asyncio
 import logging
-import time
 
 from dynamo_tpu.runtime import metrics as metrics_mod
 from dynamo_tpu.runtime import race
@@ -73,10 +72,11 @@ _M_REJECTS = REGISTRY.counter(
     "requests refused at admission (503/504 feeders)",
     ["engine", "reason"],
 )
-_M_OVERHEAD = REGISTRY.gauge(
-    "engine_dispatch_overhead_frac",
-    "step-thread d2h-blocked fraction of the sample window "
-    "(0 unless EngineConfig.profile)", ["engine"],
+_M_LOOP_LAG = REGISTRY.histogram(
+    "event_loop_lag_seconds",
+    "how late the event loop woke a 50 ms sleep (the engine's heartbeat, "
+    "runtime/loop_probe.py): a tail of whole seconds is a stalled loop",
+    ["engine"], buckets=_STEP_BUCKETS,
 )
 _M_SPEC_ACCEPT = REGISTRY.gauge(
     "engine_spec_acceptance_rate",
@@ -144,8 +144,7 @@ class EngineCollector:
         self._preempt_base: dict[str, int] = {}
         self._tenant_base: dict[tuple[str, str], int] = {}
         self._moe_base: dict[str, int] = {}
-        self._d2h_base = self._d2h_secs()
-        self._t_base = time.monotonic()
+        self._lag_ticks = 0  # the probe's wake-ups already observed
 
     def start(self) -> "EngineCollector":
         from dynamo_tpu.runtime.context import spawn
@@ -154,15 +153,6 @@ class EngineCollector:
             self.sample()
             self._task = spawn(self._loop(), name="engine-telemetry")
         return self
-
-    def _d2h_secs(self) -> float:
-        prof = self.engine._prof
-        total = 0.0
-        for name in ("dispatch.d2h_wait", "readmit.d2h_wait"):
-            rec = prof.get(name)
-            if rec:
-                total += rec[0]
-        return total
 
     def sample(self) -> None:
         eng = self.engine
@@ -180,6 +170,12 @@ class EngineCollector:
                 _M_BURST.labels(lbl).observe(eng.burst_fills.popleft())
             except IndexError:  # pragma: no cover
                 break
+        # the heartbeat's wake-ups since the last sample (the event loop
+        # appends to the probe's ring; this runs on it)
+        probe = eng.loop_probe
+        for _at, lag_us in probe.since(self._lag_ticks):
+            _M_LOOP_LAG.labels(lbl).observe(lag_us * 1e-6)
+        self._lag_ticks = probe.ticks
         alloc = eng.allocator
         _M_PAGES.labels(lbl, "active").set(alloc.active_pages)
         _M_PAGES.labels(lbl, "cached").set(alloc.evictable_pages)
@@ -226,15 +222,6 @@ class EngineCollector:
         _M_SPEC_ACCEPT.labels(lbl).set(
             eng.spec_accepted / judged if judged else 0.0
         )
-        now = time.monotonic()
-        d2h = self._d2h_secs()
-        window = now - self._t_base
-        if window > 0:
-            _M_OVERHEAD.labels(lbl).set(
-                min((d2h - self._d2h_base) / window, 1.0)
-            )
-        self._d2h_base = d2h
-        self._t_base = now
 
     async def _loop(self) -> None:
         try:

@@ -83,7 +83,9 @@ async def test_profile_off_records_nothing_and_creates_no_annotation(
                          "decode_bursts.full", "decode_bursts.short",
                          "decode_bursts.single",
                          "first_tokens.in_hold", "first_tokens.at_step",
-                         "first_tokens.on_burst"}
+                         "first_tokens.on_burst",
+                         "stream.items", "stream.wait_us",
+                         "event_loop.stalled_us"}
     # three bursts' worth at least, always on: pages moved for real contexts
     kv = {k.removeprefix("decode_kv."): v["calls"]
           for k, v in snap.items() if k.startswith("decode_kv.")}
@@ -213,7 +215,8 @@ def test_phase_annotations_match_the_profile_sums(traced):
             # counts, not phases
             or phase.startswith(
                 ("decode_kv.", "kv_pool.", "prefill_kv.", "chunked_prefill.",
-                 "burst_hold.", "decode_bursts.", "first_tokens."))
+                 "burst_hold.", "decode_bursts.", "first_tokens.",
+                 "stream.", "event_loop."))
             or phase.startswith("readmit.") and phase != "readmit.d2h_wait"
         ):
             continue

@@ -228,6 +228,19 @@ PROFILE_COUNTERS: dict[str, str] = {
     "recurrent_state.rows_resumed": "those of them that resumed a state or "
                                     "a tail (start_pos > 0): a chunk "
                                     "behind a prompt's first",
+    # the delivery path: a profiled engine only (0 otherwise), written on
+    # the event loop (engine/core.py _stream_take)
+    "stream.items": "token-carrying items generate() took off its queue "
+                    "that a stream.post span had marked",
+    "stream.wait_us": "the time they lay between the step thread's post "
+                      "and that take, summed (us): over stream.items, a "
+                      "window's mean wait",
+    # the event loop's heartbeat (runtime/loop_probe.py): always on
+    "event_loop.stalled_us": "the sum of the 50 ms sleep's lags over 50 ms "
+                             "(us): over the time between two snapshots "
+                             "(window.at), the share of it the loop stood "
+                             "still; each wake-up's lag is in the probe's "
+                             "ring (LoopProbe.lags)",
 }
 
 # jax.profiler.TraceAnnotation names the profiled engine writes into a
@@ -247,6 +260,20 @@ PROFILER_ANNOTATIONS: dict[str, str] = {
     "engine.clock": "once a step-loop cycle: mono_ns = "
                     "time.monotonic_ns(), to fit the profiler's clock to "
                     "the flight recorder's and the clients'",
+    # the delivery path. NOT under ``engine.``: perfbench/lib/spans.py
+    # takes every ``engine.*`` annotation for a step-thread phase
+    "stream.post": "step thread, around the posts of one device "
+                   "program's tokens (phase 2 of _process_burst; an "
+                   "admission wave's landing): seq (the launch number of "
+                   "that decode burst, or of the prefill whose sample the "
+                   "wave is)",
+    "stream.take": "event loop, generate() takes a marked item off its "
+                   "queue: rid (the stream's running number), wait_us "
+                   "(take minus post: the take's instant less it is its "
+                   "stream.post's)",
+    "loop.stall": "event loop, the heartbeat woke over 50 ms late: "
+                  "lag_us: the loop stood still for that long up to the "
+                  "annotation's instant",
 }
 
 # flight-recorder event names (runtime/flight.py FLIGHT.event) the engine
@@ -262,6 +289,11 @@ FLIGHT_EVENTS: dict[str, str] = {
     "first_token": "the host has the first token's value (step thread)",
     "first_delta": "generate() hands the stream its first tokens (event "
                    "loop): the engine's side of time to first token",
+    "delta": "a profiled engine: generate() hands the stream later tokens; "
+             "repeats coalesce (n = items after the first, t_last = the "
+             "last of them), and with ``generated`` on the finish the "
+             "LAST delta entry gives the engine's side of a stream's "
+             "time per output token",
     "disagg_resume": "decode-side resume from remotely prefilled KV",
     "spec_verify": "one speculative verify landed (accepted = n)",
     "preempt": "paused for a higher-priority admission, re-queued",
@@ -433,9 +465,9 @@ METRIC_NAMES: dict[str, str] = {
     "engine_admission_rejects_total": "requests refused at admission by "
                                       "reason (draining | saturated | "
                                       "deadline) — the 503/504 feeders",
-    "engine_dispatch_overhead_frac": "step-thread d2h-blocked fraction "
-                                     "of the sample window (0 unless "
-                                     "EngineConfig.profile)",
+    "event_loop_lag_seconds": "how late the worker's event loop woke a "
+                              "50 ms sleep (runtime/loop_probe.py, the "
+                              "engine's heartbeat), histogram",
     "engine_spec_acceptance_rate": "cumulative speculative-draft "
                                    "acceptance rate",
     # fused-kernel fallback accounting (ops/fallback.py, on every
