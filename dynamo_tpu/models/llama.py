@@ -1064,13 +1064,14 @@ def _kda_inputs(
     spec: ModelSpec, kd, lp: Params, h: jax.Array, tail: jax.Array,
 ):
     """A KDA layer's operands from its normed input, in the forms its kind
-    ``kd`` selects (``LayerKind.full_rank``, ``gate_bound``). h: [N, T, d]; tail:
+    ``kd`` selects (``LayerKind.full_rank``, ``gate_bound``), for the chunkwise
+    form (prefill, chunks, whole sequences; a decode step hands its
+    projections to ``kda_decode_step`` instead). h: [N, T, d]; tail:
     [N, taps - 1, 3 H D], the q | k | v projections of the ``taps - 1``
     tokens before (zeros at a sequence's start). Returns (q, k, v, g [N,
     T, H, D] float32, beta [N, T, H] float32, ext [N, taps - 1 + T, 3 H
     D]: the projections with the tail in front, of which the caller keeps
     the new tail)."""
-    f32 = jnp.float32
     N, T, _ = h.shape
     H, D = spec.kda_heads, spec.kda_head_dim
     with jax.named_scope(SCOPE_KDA_PROJ):
@@ -1088,23 +1089,32 @@ def _kda_inputs(
         q = q * jax.lax.rsqrt(
             jnp.sum(q * q, -1, keepdims=True) + 1e-6) * D ** -0.5
         k = k * jax.lax.rsqrt(jnp.sum(k * k, -1, keepdims=True) + 1e-6)
-    with jax.named_scope(SCOPE_KDA_GATES):
-        f = h @ lp["w_f"] if kd.full_rank else (
-            (h @ lp["w_f_down"]) @ lp["w_f_up"])
-        f = f.astype(f32) + lp["dt_bias"]
-        if kd.gate_bound:
-            # the bounded ("safe") gate: a token's log decay in
-            # (gate_bound, 0), inside what kda_chunk's sub-block inverse
-            # decay holds at -5
-            g = kd.gate_bound * jax.nn.sigmoid(
-                jnp.exp(lp["a_log"])[:, None] * f.reshape(N, T, H, D))
-        else:
-            g = -jnp.exp(lp["a_log"])[:, None] * jax.nn.softplus(f).reshape(
-                N, T, H, D)
-        beta = jax.nn.sigmoid((h @ lp["w_beta"]).astype(f32))
-        if spec.kda_neg_eigval:
-            beta = 2.0 * beta
+    g, beta = _kda_gates(spec, kd, lp, h)
     return q, k, v, g, beta, ext
+
+
+@jax.named_scope(SCOPE_KDA_GATES)
+def _kda_gates(spec: ModelSpec, kd, lp: Params, h: jax.Array):
+    """A KDA layer's gates from its normed input h [..., d], in the forms
+    its kind selects: (g [..., H, D] float32, the log decay a channel;
+    beta [..., H] float32)."""
+    f32 = jnp.float32
+    heads = (*h.shape[:-1], spec.kda_heads, spec.kda_head_dim)
+    f = h @ lp["w_f"] if kd.full_rank else (
+        (h @ lp["w_f_down"]) @ lp["w_f_up"])
+    f = f.astype(f32) + lp["dt_bias"]
+    if kd.gate_bound:
+        # the bounded ("safe") gate: a token's log decay in
+        # (gate_bound, 0), inside what kda_chunk's sub-block inverse
+        # decay holds at -5
+        g = kd.gate_bound * jax.nn.sigmoid(
+            jnp.exp(lp["a_log"])[:, None] * f.reshape(heads))
+    else:
+        g = -jnp.exp(lp["a_log"])[:, None] * jax.nn.softplus(f).reshape(heads)
+    beta = jax.nn.sigmoid((h @ lp["w_beta"]).astype(f32))
+    if spec.kda_neg_eigval:
+        beta = 2.0 * beta
+    return g, beta
 
 
 @jax.named_scope(SCOPE_OUT)
@@ -1122,7 +1132,8 @@ def _kda_out(spec: ModelSpec, kd, lp: Params, o: jax.Array, h: jax.Array):
 def ext_width(c_pool) -> int:
     """Channels of a convolution tail, q | k | v side by side: the tails'
     pool keeps them ``[..., taps - 1, 3, H D]``, a head block's channels of
-    each apart, so that the decode kernel writes a block a program."""
+    each apart, so that a program of the decode kernel reads and writes
+    one block (as it does of the step's projections, ``[B, 3, H D]``)."""
     return c_pool.shape[-2] * c_pool.shape[-1]
 
 
@@ -1162,20 +1173,21 @@ def _kda_decode(
     spec: ModelSpec, kd, lp: Params, h: jax.Array, s_pool, c_pool, lj: int,
     idx: jax.Array,
 ):
-    """A KDA layer's decode step over the slots' state rows. h: [B, d];
-    idx: [B] (the trash row for a slot that owns none). Returns (out [B,
-    d], s_pool, c_pool)."""
-    B = h.shape[0]
+    """A KDA layer's decode step over the slots' state rows: the three
+    projections as the matmuls leave them (q | k | v apart, the layout of
+    the tails' pool), the gates, and ONE call that convolves over each
+    slot's tail, normalises, steps the state and shifts the tail
+    (``kda_decode_step``). h: [B, d]; idx: [B] (the trash row for a slot
+    that owns none). Returns (out [B, d], s_pool, c_pool)."""
     with jax.named_scope(SCOPE_QKV):
-        q, k, v, g, beta, ext = _kda_inputs(
-            spec, kd, lp, h[:, None],
-            c_pool[lj, idx].reshape(B, -1, ext_width(c_pool)),
-        )
+        with jax.named_scope(SCOPE_KDA_PROJ):
+            x = jnp.stack([h @ lp["wq"], h @ lp["wk"], h @ lp["wv"]], axis=1)
+            taps = jnp.stack(
+                [lp["conv_q"], lp["conv_k"], lp["conv_v"]], axis=1)
+        g, beta = _kda_gates(spec, kd, lp, h)
     with jax.named_scope(SCOPE_KV):
         o, s_pool, c_pool = kda_decode_step(
-            s_pool, c_pool, idx, q[:, 0], k[:, 0], v[:, 0], g[:, 0],
-            beta[:, 0], ext[:, 1:].reshape(B, *c_pool.shape[2:]), layer=lj,
-        )
+            s_pool, c_pool, idx, x, taps, g, beta, layer=lj)
     return _kda_out(spec, kd, lp, o, h), s_pool, c_pool
 
 

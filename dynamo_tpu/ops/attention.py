@@ -1247,38 +1247,38 @@ def kda_chunk_prefill(q, k, v, g, beta, pool, rows, fresh, *, layer: int):
         return o[:, :T], pool
 
 
-def kda_decode_step(pool, conv, rows, q, k, v, g, beta, tail, *, layer: int):
+def kda_decode_step(pool, conv, rows, x, taps, g, beta, *, layer: int):
     """One decode step of every slot over layer ``layer`` of the state
-    pool ``[L, rows + 1, H, dk, dv]`` float32, the slots' new convolution
-    tails ``tail [B, taps - 1, 3, H dk]`` put into ``conv [L, rows + 1,
-    taps - 1, 3, H dk]``, and the place the implementation is chosen: the
-    ``kda_step`` kernel wherever Pallas is active (each live slot's row
-    read once and written once, in place, the tails in the same call),
-    else a gather of the slots' rows, the step in XLA and scatters back
-    (counted ``no_pallas_backend``). ``rows`` [B]: each slot's row, the
-    trash row (the pools' last) for a slot that owns none. q, k, g: [B, H,
-    dk]; v: [B, H, dv]; beta: [B, H]. Returns (o [B, H, dv] float32, pool,
-    conv)."""
+    pool ``[L, rows + 1, H, dk, dv]`` float32 and of the tails' pool ``conv
+    [L, rows + 1, taps - 1, 3, H dk]``, FROM THE LAYER'S PROJECTIONS as
+    the matmuls leave them, and the place the implementation is chosen.
+    x: [B, 3, H dk], the new token's q | k | v projections apart; taps:
+    [taps, 3, H dk], the layer's short convolutions; g: [B, H, dk] float32,
+    the log decay a channel; beta: [B, H] float32; ``rows`` [B]: each
+    slot's row, the trash row (the pools' last) for a slot that owns none.
+    What lies between the projections and the state happens here, a slot
+    at a time: the causal convolution over [the slot's tail; its new row]
+    in float32, SiLU, q's and k's norm a head (q also ``dk ** -0.5``), the
+    gated delta step, the tail shifted by the new row. Wherever Pallas is
+    active that is the ``kda_step`` kernel (each live slot's state row and
+    tail read once and written once, in place, in one call); else
+    ``kda_step_xla`` at this file's end: a gather of the slots' rows and
+    tails, the same arithmetic and scatters back (counted
+    ``no_pallas_backend``). Returns (o [B, H, dv] float32, pool, conv)."""
     from dynamo_tpu.ops.fallback import note_fallback
 
-    f32 = jnp.float32
-    q, k, v, beta = (x.astype(f32) for x in (q, k, v, beta))
-    alpha = jnp.exp(g.astype(f32))
+    alpha, x = jnp.exp(g.astype(jnp.float32)), x.astype(conv.dtype)
+    beta = beta.astype(jnp.float32)
     if use_pallas():
         from dynamo_tpu.ops.pallas.kda import kda_step
 
         return kda_step(
-            pool, conv, rows, q, k, v, alpha, beta, tail, layer=layer,
+            pool, conv, rows, x, taps, alpha, beta, layer=layer,
             interpret=jax.default_backend() != "tpu", scope=SCOPE_KDA_STEP,
         )
     note_fallback("no_pallas_backend", expected=True,
                   detail="kda_decode_step: gather, step, scatter")
-    sd = alpha[..., None] * pool[layer, rows]  # [B, H, dk, dv]
-    r = jnp.einsum("bhkv,bhk->bhv", sd, k, precision=_HI)
-    s = sd + k[..., None] * (beta[..., None] * (v - r))[:, :, None, :]
-    o = jnp.einsum("bhkv,bhk->bhv", s, q, precision=_HI)
-    return (o, pool.at[layer, rows].set(s),
-            conv.at[layer, rows].set(tail.astype(conv.dtype)))
+    return kda_step_xla(pool, conv, rows, x, taps, alpha, beta, layer=layer)
 
 
 # ------------------------------------------------------------------- SSD
@@ -1441,3 +1441,31 @@ def ssd_decode_step(pool, conv, rows, x, dt, A, B, C, D, tail, *, layer: int):
             pool = pool.at[layer, rows].set(h)
             conv = conv.at[layer, rows].set(tail.astype(conv.dtype))
     return y + D[:, None] * x, pool, conv
+
+
+# --------------------------------------------------- KDA's decode step, XLA
+# Appended at the file's end: no softmax, KDA or SSD line moved.
+
+
+def kda_step_xla(pool, conv, rows, x, taps, alpha, beta, *, layer: int):
+    """``ops/pallas/kda.kda_step``'s XLA twin, from the same arguments
+    (``kda_decode_step`` documents them; alpha = exp(g)): the slots' tails
+    and state rows gathered, the causal taps in float32 in their order,
+    SiLU, the norms, the step, the rows and the shifted tails scattered
+    back."""
+    f32 = jnp.float32
+    B, H, dk = alpha.shape
+    ext = jnp.concatenate(
+        [conv[layer, rows].astype(x.dtype), x[:, None]], axis=1)
+    taps = taps.astype(f32)
+    q, k, v = jnp.moveaxis(jax.nn.silu(sum(
+        taps[i] * ext[:, i].astype(f32) for i in range(taps.shape[0])
+    )).reshape(B, 3, H, dk), 1, 0)
+    q = q * jax.lax.rsqrt(jnp.sum(q * q, -1, keepdims=True) + 1e-6) * dk ** -0.5
+    k = k * jax.lax.rsqrt(jnp.sum(k * k, -1, keepdims=True) + 1e-6)
+    sd = alpha[..., None] * pool[layer, rows]  # [B, H, dk, dv]
+    r = jnp.einsum("bhkv,bhk->bhv", sd, k, precision=_HI)
+    s = sd + k[..., None] * (beta[..., None] * (v - r))[:, :, None, :]
+    o = jnp.einsum("bhkv,bhk->bhv", s, q, precision=_HI)
+    return (o, pool.at[layer, rows].set(s),
+            conv.at[layer, rows].set(ext[:, 1:].astype(conv.dtype)))

@@ -14,13 +14,16 @@ Two programs, each chosen beside its XLA twin in ``ops/attention.py``:
   the scalar-prefetched ``rows`` (the trash row for a slot that owns none),
   applies the step and writes the block back in place: every live row is
   read once and written once, and that traffic IS the kernel (4 MiB a row a
-  layer at 64 heads of 128 x 128; the arithmetic is a few passes of the
-  vector unit over the block, hidden behind it). The state's ``dv`` lies on
-  the lanes, so the quantities a key channel (alpha, k, q) come in as
-  columns ``[dk, heads]`` (the caller transposes the step's few rows) and
-  the quantities a value channel (v, the output) as rows. The slots' new
-  convolution tails are written to their rows of the tails' pool in the
-  same call, a head block's channels a program.
+  layer at 64 heads of 128 x 128). It takes the layer's projections as the
+  matmuls leave them (q | k | v apart, ``[B, 3, H dk]``) and the slot's
+  block of the tails' pool, and does in VMEM what lies between them and
+  the state: the causal convolution over [the tail's rows; the new row] in
+  float32, SiLU, q's and k's norm a head, ``beta v``. The state's ``dv``
+  lies on the lanes, so the quantities a key channel (alpha, k, q) are
+  needed as columns ``[dk, heads]``: ONE transpose of a lane tile that
+  stacks the block's heads' rows of the three gives them all. The tail
+  shifted by the new row goes back to the slot's block of the tails' pool
+  in the same call. ``_step_kernel`` is at the file's end.
 - ``kda_chunk`` (prefill, packed prefill, chunks): the chunkwise form
   WHOLE, a grid step a (sequence, block of heads, block of 64 tokens). What
   a block needs that does not depend on the state (the decayed keys and
@@ -33,6 +36,10 @@ Two programs, each chosen beside its XLA twin in ``ops/attention.py``:
 
 Matrix products are float32 at ``HIGHEST``: ``U`` is a difference of
 values and the state's read-out of them, which cancels.
+
+The lines of ``_head_operands``, ``_chunk_kernel`` and ``kda_chunk`` are
+part of the prefill programs' compile-cache keys (a Mosaic body serializes
+them): what is added goes after them, or into the room above them.
 """
 
 from __future__ import annotations
@@ -61,76 +68,38 @@ STEP_HEADS = 16
 CHUNK_HEADS = 8
 
 
-def _step_kernel(rows_ref, live_ref, qT_ref, kT_ref, aT_ref, bv_ref, bb_ref,
-                 tail_ref, s_ref, c_ref, o_ref, s_out_ref, c_out_ref, *, hb: int):
-    del rows_ref, c_ref  # the index maps' and the alias's alone
-    b = pl.program_id(1)
-
-    @pl.when(live_ref[b] == 0)
-    def _():
-        # a slot that owns no row: nothing is fetched for it (its blocks
-        # are the slot's before it, so no index changes) and nothing
-        # written; its output is defined
-        o_ref[...] = jnp.zeros_like(o_ref)
-
-    @pl.when(live_ref[b] != 0)
-    def _():
-        for j in range(hb):
-            a = aT_ref[:, j:j + 1]  # [dk, 1]: broadcast along the lanes
-            k = kT_ref[:, j:j + 1]
-            q = qT_ref[:, j:j + 1]
-            sd = s_ref[j] * a  # decayed state [dk, dv]
-            r = jnp.sum(sd * k, axis=0, keepdims=True)  # S'^T k: [1, dv]
-            u = bv_ref[j:j + 1, :] - bb_ref[j:j + 1, :] * r
-            sn = sd + k * u
-            o_ref[j:j + 1, :] = jnp.sum(sn * q, axis=0, keepdims=True)
-            s_out_ref[j] = sn
-        c_out_ref[...] = tail_ref[...]  # this head block's convolution tail
-
-
 def kda_step(
     pool: jax.Array,  # [L, rows + 1, H, dk, dv] float32 (aliased in place)
     conv: jax.Array,  # [L, rows + 1, taps - 1, 3, H * dk] (aliased in place)
     rows: jax.Array,  # [B] int32: each slot's row (the last = trash)
-    q: jax.Array,  # [B, H, dk] float32: normalised and scaled
-    k: jax.Array,  # [B, H, dk] float32: normalised
-    v: jax.Array,  # [B, H, dv] float32
+    x: jax.Array,  # [B, 3, H * dk]: the q | k | v projections of the token
+    taps: jax.Array,  # [taps, 3, H * dk]: the layer's convolutions
     alpha: jax.Array,  # [B, H, dk] float32: exp(g), the decay a channel
     beta: jax.Array,  # [B, H] float32
-    tail: jax.Array,  # [B, taps - 1, 3, H * dk]: the slots' new tails
     *,
     layer: int,
     interpret: bool = False,
     scope: str | None = None,
 ) -> tuple[jax.Array, jax.Array, jax.Array]:
     """One decode step of every slot over layer ``layer`` of the state
-    pool, the convolution tails shifted in the same call. Returns ``(o [B,
-    H, dv] float32, pool, conv)``. A slot on the trash row (inactive, or
-    its row missing) costs no state traffic: the grid walks the slots
-    innermost, a block of heads at a time, and such a slot's blocks are
-    mapped to the live slot's before it, whose index then does not
-    change."""
+    pool, from the layer's projections: a program (block of heads, slot)
+    reads the slot's block of the tails' pool and of ``x``, convolves,
+    normalises, steps the state and writes state and shifted tail back
+    (``_step_kernel``). Returns ``(o [B, H, dv] float32, pool, conv)``. A
+    slot on the trash row (inactive, or its row missing) costs no state
+    or tail traffic: the grid walks the slots innermost, a block of heads
+    at a time, and such a slot's blocks are mapped to the live slot's
+    before it, whose index then does not change."""
     L, R1, H, dk, dv = pool.shape
-    B = q.shape[0]
+    B = x.shape[0]
     hb = head_block(H, STEP_HEADS)
-    nh = H // hb
+    assert dk == dv and 3 * hb <= _LANES, (dk, dv, hb)
     rows = rows.astype(jnp.int32)
     live = rows != R1 - 1
     # each slot's blocks: its own row's, or those of the last live slot
     # before it (the first live slot's for the leading ones)
     at = jax.lax.cummax(jnp.where(live, jnp.arange(B), -1))
     fetch = rows[jnp.where(at >= 0, at, jnp.argmax(live))]
-
-    def cols(x):  # [B, H, dk] -> [B, H / hb, dk, hb]: a head a lane
-        return x.astype(jnp.float32).reshape(B, nh, hb, dk).transpose(0, 1, 3, 2)
-
-    bb = jnp.broadcast_to(beta.astype(jnp.float32)[..., None], (B, H, dv))
-    bv = bb * v.astype(jnp.float32)
-    col_spec = pl.BlockSpec(
-        (None, None, dk, hb), lambda h, b, *_: (b, h, 0, 0))
-    row_spec = pl.BlockSpec((None, hb, dv), lambda h, b, *_: (b, h, 0))
-    tail_spec = pl.BlockSpec(
-        (None,) + conv.shape[2:4] + (hb * dk,), lambda h, b, *_: (b, 0, 0, h))
     state_spec = pl.BlockSpec(
         (None, None, hb, dk, dv),
         lambda h, b, fetch_, live_: (layer, fetch_[b], h, 0, 0),
@@ -139,15 +108,24 @@ def kda_step(
         (None, None) + conv.shape[2:4] + (hb * dk,),
         lambda h, b, fetch_, live_: (layer, fetch_[b], 0, 0, h),
     )
+    row_spec = pl.BlockSpec((None, hb, dv), lambda h, b, *_: (b, h, 0))
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,
-        grid=(nh, B),
-        in_specs=[col_spec, col_spec, col_spec, row_spec, row_spec,
-                  tail_spec, state_spec, pl.BlockSpec(memory_space=pl.ANY)],
+        grid=(H // hb, B),
+        in_specs=[
+            pl.BlockSpec((None, 3, hb * dk), lambda h, b, *_: (b, 0, h)),
+            pl.BlockSpec(taps.shape[:2] + (hb * dk,),
+                         lambda h, b, *_: (0, 0, h)),
+            row_spec,
+            pl.BlockSpec((None, 1, H), lambda h, b, *_: (b, 0, 0)),
+            state_spec, conv_spec,
+        ],
         out_specs=[row_spec, state_spec, conv_spec],
+        # the block's heads' q, k and alpha rows, a lane tile to transpose
+        scratch_shapes=[pltpu.VMEM((_LANES, dk), jnp.float32)],
     )
-    # operands count the two scalar-prefetch arguments: 8 = the state pool
-    # -> output 1, 9 = the tails' pool -> output 2
+    # operands count the two scalar-prefetch arguments: 6 = the state pool
+    # -> output 1, 7 = the tails' pool -> output 2
     with jax.named_scope(scope) if scope else contextlib.nullcontext():
         o, pool, conv = pl.pallas_call(
             functools.partial(_step_kernel, hb=hb),
@@ -157,14 +135,36 @@ def kda_step(
                 jax.ShapeDtypeStruct(pool.shape, pool.dtype),
                 jax.ShapeDtypeStruct(conv.shape, conv.dtype),
             ],
-            input_output_aliases={8: 1, 9: 2},
+            input_output_aliases={6: 1, 7: 2},
             compiler_params=pltpu.CompilerParams(
                 dimension_semantics=("arbitrary", "arbitrary"),
             ),
             interpret=interpret,
-        )(fetch, live.astype(jnp.int32), cols(q), cols(k), cols(alpha), bv,
-          bb, tail.astype(conv.dtype), pool, conv)
+        )(fetch, live.astype(jnp.int32), x, taps,
+          alpha.astype(jnp.float32),
+          beta.astype(jnp.float32).reshape(B, 1, H), pool, conv)
     return o, pool, conv
+
+
+def _step_rows(x_ref, taps_ref, c_ref, c_out_ref):
+    """``_step_kernel``'s first half: the causal convolution over [the
+    slot's tail; the new row] in float32, in the order of the taps, then
+    SiLU: the token's q | k | v rows ``[3, hb dk]`` float32. The tail the
+    token leaves (the old rows but the first, then the new row) goes to
+    ``c_out_ref``. x_ref: [3, hb dk]; taps_ref: [taps, 3, hb dk]; c_ref,
+    c_out_ref: [taps - 1, 3, hb dk]."""
+    f32 = jnp.float32
+    n = c_ref.shape[0]
+    x = x_ref[...].astype(c_ref.dtype)
+    # models/llama.py: _causal_taps' sum, tap 0 on the oldest row
+    conv = taps_ref[0].astype(f32) * c_ref[0].astype(f32)
+    for i in range(1, n):
+        conv = conv + taps_ref[i].astype(f32) * c_ref[i].astype(f32)
+    conv = conv + taps_ref[n].astype(f32) * x.astype(f32)
+    for i in range(n - 1):
+        c_out_ref[i] = c_ref[i + 1]
+    c_out_ref[n - 1] = x
+    return jax.nn.silu(conv)
 
 
 _LANES = 128
@@ -350,3 +350,55 @@ def kda_chunk(
         )(rows.astype(jnp.int32), fresh.astype(jnp.int32), q, k, v, g, beta,
           pool)
     return o, pool
+
+
+def _step_kernel(rows_ref, live_ref, x_ref, taps_ref, a_ref, beta_ref, s_ref,
+                 c_ref, o_ref, s_out_ref, c_out_ref, cols_ref, *, hb: int):
+    """``kda_step``'s program: one slot's block of ``hb`` heads. a_ref:
+    [hb, dk]; beta_ref: [1, H] (every head's); s_ref, s_out_ref: [hb, dk,
+    dv]; o_ref: [hb, dv]; cols_ref: [128, dk] scratch; the rest as
+    ``_step_rows`` takes them."""
+    del rows_ref  # the index maps' alone
+    b = pl.program_id(1)
+    first = pl.program_id(0) * hb  # the block's first head
+
+    @pl.when(live_ref[b] == 0)
+    def _():
+        # a slot that owns no row: nothing is fetched for it (its blocks
+        # are the slot's before it, so no index changes) and nothing
+        # written; its output is defined
+        o_ref[...] = jnp.zeros_like(o_ref)
+
+    @pl.when(live_ref[b] != 0)
+    def _():
+        dk, dv = s_ref.shape[1:]
+        conv = _step_rows(x_ref, taps_ref, c_ref, c_out_ref)
+        # q's and k's channels a head as ROWS of one lane tile, normalised
+        # a head; alpha's rows beneath them
+        for j in range(hb):
+            at = slice(j * dk, (j + 1) * dk)
+            cols_ref[j:j + 1, :] = conv[0:1, at]
+            cols_ref[hb + j:hb + j + 1, :] = conv[1:2, at]
+        q, k = cols_ref[:hb, :], cols_ref[hb:2 * hb, :]
+        cols_ref[:hb, :] = q * jax.lax.rsqrt(
+            jnp.sum(q * q, axis=1, keepdims=True) + 1e-6) * dk ** -0.5
+        cols_ref[hb:2 * hb, :] = k * jax.lax.rsqrt(
+            jnp.sum(k * k, axis=1, keepdims=True) + 1e-6)
+        cols_ref[2 * hb:3 * hb, :] = a_ref[...]
+        # one transpose of the tile: a head of q, k, alpha a column (the
+        # rows past 3 hb are whatever the scratch held: never read)
+        cols = cols_ref[...].T  # [dk, 128]
+        betas = beta_ref[...]
+        lane = jax.lax.broadcasted_iota(jnp.int32, betas.shape, 1)
+        for j in range(hb):
+            q = cols[:, j:j + 1]  # [dk, 1]: broadcast along the lanes
+            k = cols[:, hb + j:hb + j + 1]
+            a = cols[:, 2 * hb + j:2 * hb + j + 1]
+            beta = jnp.sum(
+                jnp.where(lane == first + j, betas, 0.0), axis=1, keepdims=True)
+            sd = s_ref[j] * a  # decayed state [dk, dv]
+            r = jnp.sum(sd * k, axis=0, keepdims=True)  # S'^T k: [1, dv]
+            u = beta * conv[2:3, j * dv:(j + 1) * dv] - beta * r
+            sn = sd + k * u
+            o_ref[j:j + 1, :] = jnp.sum(sn * q, axis=0, keepdims=True)
+            s_out_ref[j] = sn

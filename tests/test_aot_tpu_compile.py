@@ -229,28 +229,30 @@ def test_latent_prefill_compiles_for_v5e(v5e, members, rows, dtype, widths):
     assert "%prefill_latent" in text and "attn_latent" not in text
 
 
-def test_kda_step_compiles_for_v5e(v5e):
-    """Solar-Open2's cell: 128 slots on 129 state rows, 64 heads of 128 x
-    128 float32 and the convolution tails, both pools aliased in place, a
-    row found through the scalar-prefetched ``rows``."""
+@pytest.mark.parametrize("heads", [64, 32], ids=["solar-64", "ling-32"])
+def test_kda_step_compiles_for_v5e(v5e, heads):
+    """Solar-Open2's and Ling's cells: 128 slots on 129 state rows, 64 / 32
+    heads of 128 x 128 float32 and the convolution tails, both pools read
+    through their blocks and aliased in place, a row found through the
+    scalar-prefetched ``rows``; the step's projections ``[B, 3, H dk]`` and
+    the taps in bf16 as the layer makes them, the convolution, the norms
+    and the one transpose in the kernel."""
     from dynamo_tpu.ops.pallas.kda import kda_step
 
-    slots, heads, d, rows = 128, 64, 128, 128
+    slots, d, rows = 128, 128, 128
     f32 = jnp.float32
     compiled = jax.jit(
-        lambda pool, conv, at, q, k, v, a, b, tail: kda_step(
-            pool, conv, at, q, k, v, a, b, tail, layer=1, scope="kda_step"),
+        lambda pool, conv, at, x, taps, a, b: kda_step(
+            pool, conv, at, x, taps, a, b, layer=1, scope="kda_step"),
         donate_argnums=(0, 1),
     ).lower(
         _rows(v5e, 3, rows + 1, heads, d, d, dtype=f32),
         _rows(v5e, 3, rows + 1, 3, 3, heads * d),
         _rows(v5e, slots, dtype=jnp.int32),
-        _rows(v5e, slots, heads, d, dtype=f32),
-        _rows(v5e, slots, heads, d, dtype=f32),
-        _rows(v5e, slots, heads, d, dtype=f32),
+        _rows(v5e, slots, 3, heads * d),
+        _rows(v5e, 4, 3, heads * d),
         _rows(v5e, slots, heads, d, dtype=f32),
         _rows(v5e, slots, heads, dtype=f32),
-        _rows(v5e, slots, 3, 3, heads * d),
     ).compile()
     # the kernel is named after the scope: the trace's readers match it
     assert "%kda_step" in compiled.as_text()

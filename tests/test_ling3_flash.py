@@ -15,6 +15,7 @@ import os
 
 import jax
 import jax.numpy as jnp
+import kda_step_cases
 import numpy as np
 import pytest
 
@@ -365,6 +366,18 @@ def test_kernels_equal_their_xla_twins_on_a_kinds_pool(monkeypatch):
     assert not np.allclose(pool[0], pool[1])
     want = llama.reference_forward(spec, params, jnp.asarray(toks[1, :30]))
     _close(outs["1"][0][-1], np.asarray(want[29]))
+
+
+@pytest.mark.parametrize("case", kda_step_cases.CASES)
+def test_kda_step_from_the_projections_equals_its_xla_twin(monkeypatch, case):
+    """``kda_step``'s new half (the tail's shift, the taps, SiLU, the
+    norms, the columns: in the kernel) against its XLA twin through the
+    layer's decode function, under THIS model's gates (full rank, the
+    bounded decay): the output, the state AND the tails' pool; a slot on
+    the trash row among live ones; a tail that a ragged pack's prefill
+    just wrote; 8 steps in a row; bf16 tails
+    (``tests/kda_step_cases.py``)."""
+    kda_step_cases.check(monkeypatch, SPEC, KDA_KIND, case)
 
 
 def _kind(spec, ki, **kw):

@@ -253,13 +253,12 @@ def test_every_instruction_resolves_and_the_unnamed_are_bookkeeping(
         "mimo": {"attn_qkv", "moe_route", "moe_dispatch", "moe_grouped",
                  "moe_combine", "attn_full", "attn_window"},
         "latent": {"latent_q", "latent_kv", "moe_shared", "moe_combine"},
-        "kda": {"kda_proj", "kda_conv", "kda_gates", "state_rows",
-                "moe_shared"},
+        "kda": {"kda_proj", "kda_gates", "state_rows", "moe_shared"},
         # one program holds KDA's names as Solar's give them AND the
         # latent layer's as JoyAI's do
-        "linlat": {"kda_proj", "kda_conv", "kda_gates", "state_rows",
-                   "latent_q", "latent_kv", "moe_shared", "moe_route",
-                   "moe_combine", "mlp"},
+        "linlat": {"kda_proj", "kda_gates", "state_rows", "latent_q",
+                   "latent_kv", "moe_shared", "moe_route", "moe_combine",
+                   "mlp"},
         # the short convolution's two regions beside the QK-normed GQA
         # layer's (its norm rides in attn_qkv) and the experts'
         "shortconv": {"conv_proj", "conv_mix", "attn_qkv", "attn_full",
@@ -277,6 +276,20 @@ def test_every_instruction_resolves_and_the_unnamed_are_bookkeeping(
         # (``kda_chunk_operands`` is the pad of a ragged row, and XLA's
         # half off the chip: the test below)
         want |= {"kda_chunk"} if program == "prefill" else {"kda_step"}
+        # ``kda_conv`` (tails, taps, norms in XLA) is a prefill region
+        # only: a decode step hands ``kda_step`` the projections, and the
+        # kernel reads each slot's tail through its own block, so nothing
+        # under ``attn_qkv`` gathers the tails' rows
+        if program == "prefill":
+            want |= {"kda_conv"}
+        else:
+            inside = executed + [
+                j for i in executed if i["opcode"] == "fusion"
+                for c in i["calls"] for j in comps[c]]
+            assert "kda_conv" not in found
+            assert not [j["op_name"] for j in inside
+                        if "attn_qkv" in j["op_name"].split("/")
+                        and j["opcode"] in ("gather", "dynamic-slice")]
     assert want <= found, want - found
 
 

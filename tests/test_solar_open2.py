@@ -13,6 +13,7 @@ import os
 import jax
 import jax.numpy as jnp
 import numpy as np
+import kda_step_cases
 import pytest
 
 from dynamo_tpu.engine.config import EngineConfig, LayerKind, ModelSpec
@@ -336,19 +337,19 @@ def test_kda_kernels_equal_their_xla_twins(monkeypatch, case):
 
     pool = jax.random.normal(jax.random.PRNGKey(9), (2, 6, 4, 16, 16))
     conv = jax.random.normal(jax.random.PRNGKey(8), (2, 6, 3, 3, 64))
-    tail = jax.random.normal(jax.random.PRNGKey(7), (5, 3, 3, 64))
+    x = jax.random.normal(jax.random.PRNGKey(7), (5, 3, 64))
+    taps = jax.random.normal(jax.random.PRNGKey(6), (4, 3, 64)) * 0.5
     # row 5 is the trash row: a dead slot first, between and last
     rows = jnp.asarray([5, 3, 5, 0, 5], jnp.int32)
-    at = jnp.asarray([0, 0, 1, 1, 2])  # the operands a slot brings
+    at = jnp.asarray([0, 0, 1, 1, 2])  # the gates a slot brings
     steps = {}
     for pallas in ("0", "1"):
         monkeypatch.setenv("DYNAMO_PALLAS", pallas)
         steps[pallas] = attn_ops.kda_decode_step(
-            pool, conv, rows, q[0, at], k[0, at], v[0, at], g[0, at],
-            beta[0, at], tail, layer=1)
+            pool, conv, rows, x, taps, g[0, at], beta[0, at], layer=1)
     live = np.asarray([1, 3])
-    for x, y in zip(steps["0"], steps["1"]):
-        assert x.shape == y.shape
+    for x_, y in zip(steps["0"], steps["1"]):
+        assert x_.shape == y.shape
     _close(steps["0"][0][live], np.asarray(steps["1"][0][live]), tol=1e-5)
     for i in (1, 2):  # the pools: every row but the trash row
         _close(steps["0"][i][:, :5], np.asarray(steps["1"][i][:, :5]), tol=1e-5)
@@ -358,12 +359,33 @@ def test_kda_kernels_equal_their_xla_twins(monkeypatch, case):
         np.asarray(got_s[1, [1, 2, 4]]), np.asarray(pool[1, [1, 2, 4]]))
     np.testing.assert_array_equal(
         np.asarray(got_c[1, [1, 2, 4]]), np.asarray(conv[1, [1, 2, 4]]))
-    np.testing.assert_array_equal(np.asarray(got_c[1, 3]), np.asarray(tail[1]))
-    np.testing.assert_array_equal(np.asarray(got_c[1, 0]), np.asarray(tail[3]))
+    # a live slot's tail: its old rows but the first, then its new row
+    for b in live:
+        np.testing.assert_array_equal(
+            np.asarray(got_c[1, rows[b]]),
+            np.asarray(jnp.concatenate([conv[1, rows[b], 1:], x[b][None]])))
+    # the step by hand: the taps over [tail; x], SiLU, the norms, the
+    # recurrence a token at a time
+    ext = jnp.concatenate([conv[1, 3], x[1][None]])  # [4, 3, 64]
+    q1, k1, v1 = jax.nn.silu((taps * ext).sum(0)).reshape(3, 4, 16)
+    q1 = q1 / jnp.sqrt((q1 * q1).sum(-1, keepdims=True) + 1e-6) / 4.0
+    k1 = k1 / jnp.sqrt((k1 * k1).sum(-1, keepdims=True) + 1e-6)
     want_o, want_s = attn_ops.kda_recurrence(
-        q[0, :1], k[0, :1], v[0, :1], g[0, :1], beta[0, :1], pool[1, 3])
+        q1[None], k1[None], v1[None], g[0, :1], beta[0, :1], pool[1, 3])
     _close(got_o[1], np.asarray(want_o[0]), tol=1e-5)
     _close(got_s[1, 3], np.asarray(want_s), tol=1e-5)
+
+
+@pytest.mark.parametrize("case", kda_step_cases.CASES)
+def test_kda_step_from_the_projections_equals_its_xla_twin(monkeypatch, case):
+    """``kda_step``'s new half (the tail's shift, the taps, SiLU, the
+    norms, the columns: in the kernel) against its XLA twin through the
+    layer's decode function, under THIS model's gates (low rank, softplus
+    decay, beta doubled): the output, the state AND the tails' pool; a
+    slot on the trash row among live ones; a tail that a ragged pack's
+    prefill just wrote; 8 steps in a row; bf16 tails
+    (``tests/kda_step_cases.py``)."""
+    kda_step_cases.check(monkeypatch, SPEC, 1, case)
 
 
 def test_the_state_directory_under_tables_anyone_may_build():
