@@ -48,6 +48,11 @@ class LayerKind:
     # ``wo (a_h * sigmoid(x @ w_gate_head)_h)`` (``ModelSpec.attn_gate``
     # is the gate by element)
     head_gate: bool = False
+    # softmax layers of this kind rotate q and k at the position. False:
+    # the kind carries no position (NoPE) and ``rope_theta`` is unread,
+    # beside kinds of the same model that do rotate
+    # (``ModelSpec.use_rope`` is the switch for every kind at once)
+    rope: bool = True
 
     @property
     def recurrent(self) -> bool:
@@ -211,6 +216,11 @@ class ModelSpec:
     # what the sigmoid router adds to the chosen scores' sum before it
     # divides by it (``norm_topk_prob``); a family's published constant
     moe_norm_eps: float = 1e-20
+    # sandwich norms (afmoe): a layer norms what its mixer and its FFN put
+    # OUT, each with a gain of its own (``post_attn_norm``,
+    # ``post_mlp_norm``), before the residual add: ``x + norm(attn(norm(
+    # x)))``, then ``x + norm(ffn(norm(x)))``
+    sandwich_norm: bool = False
     # the Falcon-H1 family's fixed scalar multipliers (muP); 1 = absent.
     # ``ssm_multipliers`` scale the z | x | B | C | dt segments of the SSM
     # input projection's output, ``mlp_multipliers`` the gate projection
@@ -585,6 +595,32 @@ class ModelSpec:
         return cls(**base)
 
     @classmethod
+    def tiny_trinity(cls, **kw) -> "ModelSpec":
+        """Toy Trinity (afmoe) architecture: gated, QK-normed GQA whose
+        window layers rotate and whose full layer carries no position, a
+        pool a kind, sandwich norms, the embedding's muP
+        factor, a leading dense layer, sigmoid routing with a
+        selection-only bias beside a shared expert."""
+        base = dict(
+            name="tiny-trinity", vocab_size=96, hidden_size=64,
+            intermediate_size=96, num_layers=4, num_heads=4,
+            num_kv_heads=2, head_dim=16, dtype="float32", rms_eps=1e-5,
+            rope_theta=10000.0, tie_embeddings=False, qk_norm=True,
+            attn_gate=True, sandwich_norm=True, embedding_multiplier=8.0,
+            layer_kinds=(
+                LayerKind(2, 10000.0, window=8),
+                LayerKind(2, 10000.0, rope=False),
+            ),
+            layer_pattern=(0, 0, 1, 0),
+            num_experts=8, num_experts_per_token=2,
+            moe_intermediate_size=32, moe_scoring="sigmoid",
+            routed_scaling_factor=2.826, n_shared_experts=1,
+            first_k_dense=1,
+        )
+        base.update(kw)
+        return cls(**base)
+
+    @classmethod
     def tiny_longcat(cls, **kw) -> "ModelSpec":
         """Toy LongCat-Flash architecture: shortcut-connected double
         layers (two latent attentions, two dense FFNs, one expert layer
@@ -631,6 +667,7 @@ class ModelSpec:
             "tiny-falcon-h1": cls.tiny_falcon_h1,
             "tiny-ling3": cls.tiny_ling3,
             "tiny-longcat": cls.tiny_longcat,
+            "tiny-trinity": cls.tiny_trinity,
             "llama-3-8b": cls.llama3_8b,
             "llama-3-70b": cls.llama3_70b,
             "mixtral-8x7b": cls.mixtral_8x7b,
@@ -810,7 +847,9 @@ class EngineConfig:
         its pack back, a program more), which is why it waits on a pack
         width a bucket in the configuration: ROADMAP.md S3 (b). A pack
         width that does not fit halves; a bucket that does not fit even
-        one row is not offered, nor is any above it — longer prompts then
+        one row is not offered, nor is any above it. A bucket of more than
+        1,024 rows of a model that lists its kinds is charged the walk's
+        true tiles: see ``_WHOLE_TABLE_ROWS`` below — longer prompts then
         go through chunks of the largest bucket that is
         (``max_prefill_chunk_tokens`` is capped by it). A guard with
         margin, not a tuner. The latent family (``spec.is_mla``) is
@@ -828,11 +867,24 @@ class EngineConfig:
             self.prefill_buckets[-1],
         ))
         heads = max(1, spec.num_heads // max(1, tp))
+        # the widest bucket the whole-table charge below still prices for
+        # a model that lists its layer kinds. Every such configuration it
+        # shapes (MiMo's {512: 2, 1024: 1} is its doing) stops at 1,024
+        # rows; past that it charges ONE row of a 4,096-row bucket under
+        # a 10,240-token table 8 GiB of scores that no program has held
+        # since the walk, and refuses the bucket on a chip whose programs
+        # hold 26 MiB of them. So such a model's wider bucket is charged
+        # the walk's true tiles (``need_recurrent``, whose state terms
+        # are 0 without such layers). A model of one kind keeps the old
+        # charge at every bucket (tests/test_chunked_prefill.py pins its
+        # sets), until ROADMAP.md S5 (b) replaces all of it
+        _WHOLE_TABLE_ROWS = 1024
 
         def need(rows: int, bucket: int) -> int:
             if spec.is_mla:
                 return need_latent(rows, bucket)
-            if spec.has_recurrent or spec.has_latent:
+            if (spec.has_recurrent or spec.has_latent
+                    or (spec.layer_kinds and bucket > _WHOLE_TABLE_ROWS)):
                 # a model with both kinds is charged both: the layers of
                 # one program run in turn, so the sum is an upper bound
                 # (the rest of the program counted once)

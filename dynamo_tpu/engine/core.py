@@ -199,7 +199,7 @@ READMIT_SUMS = {
 _COUNTER_FAMILIES = (
     "decode_kv", "prefill_kv", "chunked_prefill", "burst_hold",
     "decode_bursts", "first_tokens", "kda", "ssd", "recurrent_state",
-    "stream",
+    "stream", "kv",
 )
 
 # undisturbed burst times kept a burst length (their smallest is the
@@ -611,6 +611,15 @@ class InferenceEngine:
         self.kv_pool = self._kv_pool_layout()
         self.decode_kv = {"pages_live": 0, "pages_fetched": 0,
                           "pages_table": 0}
+        # what the window layers' pages hold that no later query can see
+        # (always on where the model has window layers, from the lengths
+        # the step thread holds: no device work): see _count_decode_kv
+        # {window: layers of it}
+        kinds = map(self.spec.kind, range(self.spec.num_layers))
+        self._window_layers = collections.Counter(
+            kd.window for kd in kinds if kd.window)
+        self.kv = {"window_layer_tokens": 0, "window_dead_tokens": 0} if (
+            self._window_layers) else {}
         # what the prefill walk visited, in blocks of pages a layer, over
         # the dispatched prefills and verifies (always on: integer
         # arithmetic on [rows, tiles] a dispatch), a layer kind apart,
@@ -831,6 +840,8 @@ class InferenceEngine:
           (calls): see ``_count_decode_kv``.
         - ``kv_pool.heads_per_lane_row`` / ``.bytes`` (calls): see
           ``_kv_pool_layout``; fixed at the build, no window zeroes them.
+        - ``kv.window_layer_tokens`` / ``.window_dead_tokens`` (calls; a
+          model with window layers only): see ``_count_decode_kv``.
         - ``prefill_kv.blocks_visited.<kind>`` / ``.blocks_table.<kind>``
           (calls; kind ``full`` or ``window``, or ``latent`` for the
           latent family's walk, which also reports
@@ -1001,7 +1012,15 @@ class InferenceEngine:
         the table would move. One layer's worth, as a full-attention
         layer sees them. ``pages_fetched / pages_table`` is how far the
         kernel's work follows the contexts; ``pages_live /
-        pages_fetched`` is what the chunk's size wastes."""
+        pages_fetched`` is what the chunk's size wastes.
+
+        And to ``kv`` where the model has window layers, which keep every
+        page of a sequence while it lives: the tokens its live slots hold
+        at each step times the window layers (``window_layer_tokens``)
+        and, of those, the ones more than the layer's window behind their
+        row's length, which no later query of the row can see
+        (``window_dead_tokens``). Their ratio is the share of the window
+        layers' pool that pages found by layer kind would give back."""
         from dynamo_tpu.ops.pallas.fused_decode import live_chunks
 
         for counters in (self.kda, self.ssd):
@@ -1009,10 +1028,6 @@ class InferenceEngine:
                 counters["decode_rows"] += (
                     int(batch["active"].sum()) * batch["n_burst"]
                 )
-        chunk = self._kv_chunk_pages
-        if chunk is None:
-            return
-        page = self.config.page_size
         n_burst = batch["n_burst"]
         # a live slot's length grows by one a step; the pool holds all
         # of it but the step's own token
@@ -1020,6 +1035,14 @@ class InferenceEngine:
             batch["seq_lens"][batch["active"]][:, None]
             + np.arange(n_burst, dtype=np.int32)[None, :]
         )
+        for window, layers in self._window_layers.items():
+            self.kv["window_layer_tokens"] += int(lens.sum()) * layers
+            self.kv["window_dead_tokens"] += (
+                int((lens - window).clip(0).sum()) * layers)
+        chunk = self._kv_chunk_pages
+        if chunk is None:
+            return
+        page = self.config.page_size
         _, chunks = live_chunks(lens, page, chunk)
         kv = self.decode_kv
         kv["pages_live"] += int((-(-(lens - 1) // page)).sum())
@@ -1143,7 +1166,9 @@ class InferenceEngine:
         cfg = self.config
         report: dict[str, dict] = {}
 
-        def timed(name: str, fn) -> None:
+        t_start = time.perf_counter()
+
+        def timed(name: str, fn, ahead: tuple[float, int] = (0.0, 0)) -> None:
             c0, s0 = thread_compile_snapshot()
             t0 = time.perf_counter()
             try:
@@ -1159,14 +1184,20 @@ class InferenceEngine:
                 log.warning("precompile %s failed (%s); first request "
                             "pays this compile instead", name, e)
                 report[name] = {
-                    "secs": round(time.perf_counter() - t0, 3),
-                    "compiles": thread_compile_snapshot()[0] - c0,
+                    "secs": round(time.perf_counter() - t0 + ahead[0], 3),
+                    "compiles": thread_compile_snapshot()[0] - c0 + ahead[1],
                     "error": str(e),
                 }
                 return
             dt = time.perf_counter() - t0
             c1, s1 = thread_compile_snapshot()
-            report[name] = {"secs": round(dt, 3), "compiles": c1 - c0}
+            report[name] = {
+                "secs": round(dt + ahead[0], 3),
+                "compiles": c1 - c0 + ahead[1],
+            }
+            if ahead[0]:
+                # of ``secs``, lowering and compiling beside the others
+                report[name]["ahead_secs"] = round(ahead[0], 3)
             log.info(
                 "precompile %s: %.0f ms (%d compiles, %.0f ms in XLA)",
                 name, dt * 1e3, c1 - c0, (s1 - s0) * 1e3,
@@ -1210,6 +1241,11 @@ class InferenceEngine:
         )
         samplers.start()
 
+        # the model's programs, in the order their warm-up dispatches run:
+        # compiled ahead beside one another (_compile_ahead), dispatched
+        # one after the other below (each donates the live pools)
+        programs: list[tuple[str, Any]] = []
+
         # every prefill shape the engine offers (chunked prefill
         # re-enters through the same bucketed shapes)
         bt1 = jnp.zeros((cfg.max_pages_per_seq,), jnp.int32)
@@ -1229,7 +1265,7 @@ class InferenceEngine:
                     first_logits[1] = logits[None, :]
                     first_logits.setdefault(B, jnp.stack([logits] * B))
 
-            timed(f"prefill[{bucket}]", one_prefill)
+            programs.append((f"prefill[{bucket}]", one_prefill))
             if self.fam.supports_packed_prefill and nb > 1:
 
                 def packed(bucket=bucket, nb=nb):
@@ -1249,7 +1285,7 @@ class InferenceEngine:
                     # unsampled admission paths
                     jax.block_until_ready(logits[0])
 
-                timed(f"prefill_packed[{nb}x{bucket}]", packed)
+                programs.append((f"prefill_packed[{nb}x{bucket}]", packed))
 
         # decode burst programs: every length _build_batch dispatches
         zB = jnp.zeros((B,), jnp.int32)
@@ -1269,7 +1305,7 @@ class InferenceEngine:
                 )
                 burst_out[n] = jax.block_until_ready(out)
 
-            timed(f"decode[{B}x{n}]", burst)
+            programs.append((f"decode[{B}x{n}]", burst))
 
         # speculative-verify grid (spec mode): one program per
         # power-of-two row count at the static k+1 token width — the
@@ -1297,7 +1333,7 @@ class InferenceEngine:
                     )
                     jax.block_until_ready(out)
 
-                timed(f"verify[{nrows}x{W}]", verify)
+                programs.append((f"verify[{nrows}x{W}]", verify))
                 if self._guided is not None:
                     # guided x spec: the MASKED verify program is its own
                     # compiled shape per row tier — warm it too, or the
@@ -1323,7 +1359,12 @@ class InferenceEngine:
                         )
                         jax.block_until_ready(out)
 
-                    timed(f"verify_masked[{nrows}x{W}]", verify_masked)
+                    programs.append(
+                        (f"verify_masked[{nrows}x{W}]", verify_masked))
+
+        ahead = self._compile_ahead(programs)
+        for name, fn in programs:
+            timed(name, fn, ahead.get(name, (0.0, 0)))
 
         samplers.join()
 
@@ -1392,7 +1433,8 @@ class InferenceEngine:
 
             timed("release_state_rows", release)
 
-        total = sum(r["secs"] for r in report.values())
+        # by the clock: the shapes' own seconds overlap
+        total = time.perf_counter() - t_start
         compiles = sum(r["compiles"] for r in report.values())
         misses = sum(1 for r in report.values() if "error" in r)
         log.info(
@@ -1404,6 +1446,60 @@ class InferenceEngine:
         # not serve with a refused shape fails on any "error" entry
         self.precompile_report = report
         return report
+
+    def _compile_ahead(self, programs) -> dict[str, tuple[float, int]]:
+        """Compile the model's programs beside one another, before their
+        warm-up dispatches run one after the other. A dispatch donates the
+        live pools, so the dispatches cannot overlap, and compiling inside
+        them made a cold start the SUM of the programs' compile times
+        (124 s of a 245 s set-up over four programs on a v5e, and the
+        cache's loads one after the other when warm: PERF.md section 6,
+        PR 53; ROADMAP.md S7 (e)). Each program is traced and lowered HERE,
+        from the very call its dispatch makes (``lowered_calls``: tracing
+        holds the interpreter, and what a trace leaves behind it leaves
+        once), then compiled, or loaded from the persistent cache, on a
+        thread of its own, at most four at a time (a compile holds
+        gigabytes of the host's memory). ``jit``'s call path and
+        ``.lower().compile()`` share their caches, so the dispatch that
+        follows finds its program and compiles nothing. Returns ``{name:
+        (seconds, compiles)}``; a program that does not lower or compile
+        here is left to its dispatch, which reports why."""
+        from dynamo_tpu.models.family import Lowered, lowered_calls
+
+        done: dict[str, tuple[float, int]] = {}
+        room = threading.Semaphore(4)
+
+        def compile_one(name: str, lowered, t0: float) -> None:
+            with room:
+                c0 = thread_compile_snapshot()[0]
+                try:
+                    lowered.compile()
+                except Exception as e:  # noqa: BLE001
+                    log.debug("compile ahead of %s: %s", name, e)
+                    return
+                done[name] = (
+                    time.perf_counter() - t0,
+                    thread_compile_snapshot()[0] - c0,
+                )
+
+        threads = []
+        for name, fn in programs:
+            t0 = time.perf_counter()
+            try:
+                with lowered_calls(self.fam):
+                    fn()
+            except Lowered as e:
+                th = threading.Thread(
+                    target=compile_one, args=(name, e.lowered, t0),
+                    name=f"precompile-{name}",
+                )
+                th.start()
+                threads.append(th)
+            except Exception as e:  # noqa: BLE001
+                log.debug("lowering ahead of %s: %s", name, e)
+        for th in threads:
+            th.join()
+        return done
 
     # -- events ------------------------------------------------------------
 

@@ -114,6 +114,15 @@ _M_MOE = REGISTRY.counter(
     ["engine", "phase", "what"],
 )
 
+_M_WINDOW = REGISTRY.counter(
+    "engine_window_tokens_total",
+    "a model with window layers: tokens its live slots held at each "
+    "decode step x window layers (what = held), and those of them past "
+    "their layer's window, which no later query sees (what = dead): "
+    "engine.kv, the kv.window_* counters of profile_snapshot()",
+    ["engine", "what"],
+)
+
 _REJECT_REASONS = ("draining", "saturated", "deadline", "over_quota", "shed")
 _COLLECTOR_IDS = iter(range(1 << 30))
 
@@ -144,6 +153,7 @@ class EngineCollector:
         self._preempt_base: dict[str, int] = {}
         self._tenant_base: dict[tuple[str, str], int] = {}
         self._moe_base: dict[str, int] = {}
+        self._window_base: dict[str, int] = {}
         self._lag_ticks = 0  # the probe's wake-ups already observed
 
     def start(self) -> "EngineCollector":
@@ -215,6 +225,15 @@ class EngineCollector:
             if what and delta > 0:
                 _M_MOE.labels(lbl, phase, what).inc(delta)
                 self._moe_base[name] = cur
+        for what, name in (("held", "window_layer_tokens"),
+                           ("dead", "window_dead_tokens")):
+            cur = eng.kv.get(name, 0)
+            base = self._window_base.get(what, 0)
+            if cur < base:  # reset_profile_window zeroed the engine's
+                base = 0
+            if cur > base:
+                _M_WINDOW.labels(lbl, what).inc(cur - base)
+            self._window_base[what] = cur
         if eng.kvbm is not None:
             for tier, nbytes in eng.kvbm.tier_bytes().items():
                 _M_KVBM_TIER.labels(lbl, tier).set(nbytes)

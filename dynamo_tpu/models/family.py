@@ -35,6 +35,7 @@ registry); here it is explicit and small.
 
 from __future__ import annotations
 
+import contextlib
 from functools import partial
 from typing import Any
 
@@ -43,7 +44,53 @@ import jax.numpy as jnp
 
 from dynamo_tpu.engine.config import ModelSpec
 
-__all__ = ["get_family", "GqaFamily", "MlaFamily"]
+__all__ = ["get_family", "GqaFamily", "MlaFamily", "Lowered",
+           "lowered_calls"]
+
+
+class Lowered(Exception):
+    """Raised in place of running one of a family's jitted programs inside
+    ``lowered_calls``; ``lowered`` is the program lowered for the call's
+    arguments (``jax.stages.Lowered``)."""
+
+    def __init__(self, lowered):
+        super().__init__("a program was lowered in place of running")
+        self.lowered = lowered
+
+
+class _Lowering:
+    """A family's module of programs whose jitted functions lower and
+    raise ``Lowered`` where the real ones would run."""
+
+    def __init__(self, real):
+        self._real = real
+
+    def __getattr__(self, name):
+        fn = getattr(self._real, name)
+        if not (callable(fn) and hasattr(fn, "lower")):
+            return fn
+
+        def lower(*args, **kw):
+            raise Lowered(fn.lower(*args, **kw))
+
+        return lower
+
+
+@contextlib.contextmanager
+def lowered_calls(fam):
+    """Inside, the first program ``fam`` dispatches (``fam.prefill``,
+    ``fam.decode_steps``, ...) is traced and lowered for its arguments and
+    raised as ``Lowered`` instead of run: nothing is donated and nothing
+    executes. What ``InferenceEngine.precompile`` compiles ahead from, with
+    the very call its warm-up dispatch makes, so that the dispatch finds
+    the program in ``jit``'s own cache."""
+    attr = "mla" if isinstance(fam, MlaFamily) else "m"
+    real = getattr(fam, attr)
+    setattr(fam, attr, _Lowering(real))
+    try:
+        yield
+    finally:
+        setattr(fam, attr, real)
 
 
 class GqaFamily:

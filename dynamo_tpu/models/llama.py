@@ -42,6 +42,7 @@ from dynamo_tpu.models.regions import (
     SCOPE_MOE_COUNT,
     SCOPE_MOE_SHARED,
     SCOPE_NORM,
+    SCOPE_NORM_OUT,
     SCOPE_OUT,
     SCOPE_QKV,
     SCOPE_RESIDUAL,
@@ -166,6 +167,14 @@ def init_params(spec: ModelSpec, key: jax.Array) -> Params:
                 bv=jnp.zeros((nkv * vd,), dtype),
                 bo=jnp.zeros((d,), dtype),
             )
+        if spec.sandwich_norm:
+            # the gains of the two norms on the way OUT, drawn about 1 on
+            # keys of their own (as ``q_norm``'s: at 1 a gain left out of
+            # a program would show in no comparison on random weights)
+            k_pa, k_pm = jax.random.split(
+                jax.random.fold_in(key, 3000 + li))
+            layer["post_attn_norm"] = 1 + dense(k_pa, (d,), scale=0.1)
+            layer["post_mlp_norm"] = 1 + dense(k_pm, (d,), scale=0.1)
         if kd.sinks:
             # drawn non-zero, on a key of their own (the matrices' keys
             # stay where they were): a zero sink is exp(0) in every
@@ -333,6 +342,10 @@ def param_shardings(spec: ModelSpec, mesh: Mesh) -> Params:
             layer.update(bq=ns("tp"), bk=ns("tp"), bv=ns("tp"), bo=ns())
         if spec.qk_norm:
             layer.update(q_norm=ns(), k_norm=ns())
+        if spec.attn_gate:
+            layer["w_gate_attn"] = ns(None, "tp")  # column (heads), as wq
+        if spec.sandwich_norm:
+            layer.update(post_attn_norm=ns(), post_mlp_norm=ns())
         if spec.kind(li).sinks:
             layer["sinks"] = ns("tp")  # per-query-head, rides the head shards
         if spec.is_moe_layer(li):
@@ -874,6 +887,20 @@ def _add(x: jax.Array, y: jax.Array) -> jax.Array:
     return x + y
 
 
+def _residual(
+    spec: ModelSpec, lp: Params, x: jax.Array, y: jax.Array, gain: str
+) -> jax.Array:
+    """The residual half of a layer, for every program: the stream ``x``
+    plus what a mixer or an FFN put out, ``y``, normed first under the
+    layer's gain ``gain`` (``post_attn_norm`` / ``post_mlp_norm``) where
+    the model norms its outputs (``ModelSpec.sandwich_norm``), under a
+    region of its own so that a trace tells it from the input norms."""
+    if spec.sandwich_norm:
+        with jax.named_scope(SCOPE_NORM_OUT):
+            y = rms_norm(y, lp[gain], spec.rms_eps)
+    return _add(x, y)
+
+
 def _times(x: jax.Array, m: float) -> jax.Array:
     """x scaled by one of a family's fixed multipliers; nothing where the
     model has none (1)."""
@@ -900,8 +927,9 @@ def _attn_qkv(
 ):
     """x: [..., d], positions: [...] -> q [..., nh, hd], k [..., nkv, hd]
     with rope applied (after an RMSNorm a head where the model has
-    ``qk_norm``), v [..., nkv, vd] scaled by ``value_scale``; nkv and the
-    rope base are the layer kind's."""
+    ``qk_norm``; not at all where the layer's kind carries no position),
+    v [..., nkv, vd] scaled by ``value_scale``; nkv, the rope base and
+    whether it rotates are the layer kind's."""
     kd = spec.kind(li)
     lead = x.shape[:-1]
     x = _times(x, spec.attention_in_multiplier)
@@ -918,7 +946,7 @@ def _attn_qkv(
     if spec.qk_norm:
         q = rms_norm(q, lp["q_norm"], spec.rms_eps)
         k = rms_norm(k, lp["k_norm"], spec.rms_eps)
-    if spec.use_rope:
+    if spec.use_rope and kd.rope:
         q = rope_spec(spec, q, positions, kd.rope_theta)
         k = rope_spec(spec, k, positions, kd.rope_theta)
     return q, k, v
@@ -1523,12 +1551,12 @@ def prefill_forward_impl(
 
         mix, kp, vp = _mixers(spec, li, kp, vp, attend, recur, latent)
         k_pages, v_pages = _put_pools(spec, k_pages, v_pages, li, kp, vp)
-        x = _add(x, mix)
+        x = _residual(spec, lp, x, mix, "post_attn_norm")
         h = _norm(x, lp["mlp_norm"], spec.rms_eps)
         f, k_pages = _ffn_counting(
             spec, li, lp, h, k_pages, COUNT_PREFILL, real, mesh
         )
-        x = _add(x, f)
+        x = _residual(spec, lp, x, f, "post_mlp_norm")
 
     with jax.named_scope(SCOPE_HEAD):
         last = jnp.clip(num_tokens - 1, 0, T - 1)
@@ -1653,13 +1681,14 @@ def prefill_forward_batch_impl(
 
         mix, kp, vp = _mixers(spec, li, kp, vp, attend, recur, latent)
         k_pages, v_pages = _put_pools(spec, k_pages, v_pages, li, kp, vp)
-        x = _add(x, mix)
+        x = _residual(spec, lp, x, mix, "post_attn_norm")
         h = _norm(x, lp["mlp_norm"], spec.rms_eps)
         f, k_pages = _ffn_counting(
             spec, li, lp, h.reshape(N * T, -1), k_pages, COUNT_PREFILL,
             real.reshape(N * T), mesh,
         )
-        x = _add(x, f.reshape(N, T, -1))
+        x = _residual(
+            spec, lp, x, f.reshape(N, T, -1), "post_mlp_norm")
 
     with jax.named_scope(SCOPE_HEAD):
         last = jnp.clip(num_tokens - 1, 0, T - 1)  # [N]
@@ -1720,9 +1749,11 @@ def prefill_forward_ring_impl(
         vp = _set_page_tiles(vp, lj, safe_pg, v, page_size, valid_tok)
         k_pages, v_pages = _put_pools(spec, k_pages, v_pages, li, kp, vp)
         attn = ring_attention(q, k, v, mesh=mesh)
-        x = _add(x, _o_proj(spec, lp, attn, h))
+        x = _residual(
+            spec, lp, x, _o_proj(spec, lp, attn, h), "post_attn_norm")
         h = _norm(x, lp["mlp_norm"], spec.rms_eps)
-        x = _add(x, _ffn(spec, lp, h, mesh=mesh, li=li))
+        f = _ffn(spec, lp, h, mesh=mesh, li=li)
+        x = _residual(spec, lp, x, f, "post_mlp_norm")
         x = jax.lax.with_sharding_constraint(x, sp_spec)
 
     last = jnp.clip(num_tokens - 1, 0, T - 1)
@@ -1822,11 +1853,12 @@ def verify_forward_impl(
             )
         )(q, k, v, block_tables, positions, kv_len)
         k_pages, v_pages = _put_pools(spec, k_pages, v_pages, li, kp, vp)
-        x = _add(x, _o_proj(spec, lp, attn, h))
+        x = _residual(
+            spec, lp, x, _o_proj(spec, lp, attn, h), "post_attn_norm")
         h = _norm(x, lp["mlp_norm"], spec.rms_eps)
-        x = _add(x, _ffn(
+        x = _residual(spec, lp, x, _ffn(
             spec, lp, h.reshape(N * W, -1), mesh=mesh, li=li
-        ).reshape(N, W, -1))
+        ).reshape(N, W, -1), "post_mlp_norm")
 
     logits = _logits(spec, params, x)  # [N, W, V]
     if allowed is not None:
@@ -1918,12 +1950,12 @@ def decode_forward_impl(
 
         mix, kp, vp = _mixers(spec, li, kp, vp, attend, recur, latent)
         k_pages, v_pages = _put_pools(spec, k_pages, v_pages, li, kp, vp)
-        x = _add(x, mix)
+        x = _residual(spec, lp, x, mix, "post_attn_norm")
         h = _norm(x, lp["mlp_norm"], spec.rms_eps)
         f, k_pages = _ffn_counting(
             spec, li, lp, h, k_pages, COUNT_DECODE, active, mesh
         )
-        x = _add(x, f)
+        x = _residual(spec, lp, x, f, "post_mlp_norm")
 
     logits = _logits(spec, params, x)  # [B, V]
     return logits, k_pages, v_pages
@@ -2160,9 +2192,10 @@ def embed_forward_impl(
     x = _embed(params, tokens, spec)
     for li, lp in enumerate(params["layers"]):
         h = _norm(x, lp["attn_norm"], spec.rms_eps)
-        x = _add(x, _whole_mixer(spec, li, lp, h, positions, num_tokens))
+        mix = _whole_mixer(spec, li, lp, h, positions, num_tokens)
+        x = _residual(spec, lp, x, mix, "post_attn_norm")
         h = _norm(x, lp["mlp_norm"], spec.rms_eps)
-        x = _add(x, _ffn(spec, lp, h, li=li))
+        x = _residual(spec, lp, x, _ffn(spec, lp, h, li=li), "post_mlp_norm")
     xn = rms_norm(x, params["final_norm"], spec.rms_eps).astype(jnp.float32)
     mask = (positions < num_tokens)[:, None].astype(jnp.float32)
     pooled = (xn * mask).sum(axis=0) / jnp.maximum(mask.sum(), 1.0)
@@ -2185,9 +2218,10 @@ def reference_forward(
     x = _embed(params, tokens, spec)
     for li, lp in enumerate(params["layers"]):
         h = _norm(x, lp["attn_norm"], spec.rms_eps)
-        x = _add(x, _whole_mixer(spec, li, lp, h, positions, jnp.asarray(T)))
+        mix = _whole_mixer(spec, li, lp, h, positions, jnp.asarray(T))
+        x = _residual(spec, lp, x, mix, "post_attn_norm")
         h = _norm(x, lp["mlp_norm"], spec.rms_eps)
-        x = _add(x, _ffn(spec, lp, h, li=li))
+        x = _residual(spec, lp, x, _ffn(spec, lp, h, li=li), "post_mlp_norm")
     xn = rms_norm(x, params["final_norm"], spec.rms_eps)
     head = params["embed"].T if spec.tie_embeddings else params["lm_head"]
     return _times((xn @ head).astype(jnp.float32), spec.lm_head_multiplier)

@@ -243,6 +243,8 @@ def spec_from_hf_config(cfg: dict, name: str | None = None) -> ModelSpec:
             norm_topk_prob=bool(cfg.get("norm_topk_prob", True)),
             moe_norm_eps=1e-6,  # the family's published code
         )
+    if model_type == "afmoe":
+        extras.update(_afmoe_spec(cfg, hidden))
     # YaRN rope scaling (gpt-oss, DeepSeek-R1)
     rs = cfg.get("rope_scaling") or {}
     if (rs.get("rope_type") or rs.get("type")) == "yarn":
@@ -292,6 +294,50 @@ def spec_from_hf_config(cfg: dict, name: str | None = None) -> ModelSpec:
     return ModelSpec(**{**kw, **moe, **extras})
 
 
+def _afmoe_spec(cfg: dict, hidden: int) -> dict:
+    """The ``afmoe`` family's (Arcee Trinity) own fields from its
+    config.json: gated, QK-normed GQA whose ``sliding_attention`` layers
+    rotate and whose ``full_attention`` layers carry no position (a kind
+    each, in the order the layers first show them), four norms a layer,
+    the embedding times ``sqrt(hidden)`` under ``mup_enabled``,
+    ``num_dense_layers`` leading dense layers, then a sigmoid router whose
+    ``expert_bias`` enters the choice alone, ``route_norm`` /
+    ``route_scale`` on the chosen scores, beside ``num_shared_experts``."""
+    from dynamo_tpu.engine.config import LayerKind
+
+    if str(cfg.get("score_func") or "sigmoid") != "sigmoid":
+        raise NotImplementedError(f"afmoe: score_func {cfg['score_func']!r}")
+    if max(int(cfg.get(k) or 1) for k in (
+            "n_group", "topk_group", "num_expert_groups",
+            "num_limited_groups")) > 1:
+        raise NotImplementedError("afmoe: group-limited routing")
+    n_layers = int(cfg["num_hidden_layers"])
+    every = int(cfg.get("global_attn_every_n_layers") or 4)
+    types = list(cfg.get("layer_types") or (
+        "full_attention" if (i + 1) % every == 0 else "sliding_attention"
+        for i in range(n_layers)))
+    nkv = int(cfg.get("num_key_value_heads") or cfg["num_attention_heads"])
+    theta = float(cfg.get("rope_theta", 10000.0))
+    kind_of = {
+        "sliding_attention": LayerKind(
+            nkv, theta, window=int(cfg["sliding_window"])),
+        "full_attention": LayerKind(nkv, theta, rope=False),
+    }
+    seen = list(dict.fromkeys(types))
+    return dict(
+        layer_kinds=tuple(kind_of[t] for t in seen),
+        layer_pattern=tuple(seen.index(t) for t in types),
+        qk_norm=True, attn_gate=True, sandwich_norm=True,
+        embedding_multiplier=(
+            float(hidden) ** 0.5 if cfg.get("mup_enabled") else 1.0),
+        first_k_dense=int(cfg.get("num_dense_layers") or 0),
+        n_shared_experts=int(cfg.get("num_shared_experts") or 0),
+        moe_scoring="sigmoid",
+        routed_scaling_factor=float(cfg.get("route_scale") or 1.0),
+        norm_topk_prob=bool(cfg.get("route_norm", True)),
+    )
+
+
 def _no_latent_kind(spec: ModelSpec) -> None:
     """A model that keeps latent layers as a KIND beside others (Ling-3.0)
     runs on drawn weights only: the catalog it was added from gives its
@@ -318,6 +364,8 @@ def hf_config_from_spec(spec: ModelSpec) -> dict:
         model_type = "solar_open2"
     elif "conv" in spec.mixers:
         model_type = "lfm2_moe"
+    elif spec.sandwich_norm:
+        model_type = "afmoe"
     elif spec.attn_sinks:
         model_type = "gpt_oss"
     elif spec.num_experts:
@@ -385,6 +433,27 @@ def hf_config_from_spec(spec: ModelSpec) -> dict:
             routed_scaling_factor=spec.routed_scaling_factor,
             rope_parameters={
                 "rope_theta": attn.rope_theta, "rope_type": "default"},
+        )
+        del cfg["num_local_experts"]
+    if model_type == "afmoe":
+        window = max(kd.window for kd in spec.kinds)
+        attn = spec.kinds[0]
+        cfg.update(
+            intermediate_size=spec.intermediate_size,
+            num_experts=spec.num_experts,
+            num_key_value_heads=attn.num_kv_heads,
+            rope_theta=attn.rope_theta,
+            num_dense_layers=spec.first_k_dense,
+            num_shared_experts=spec.n_shared_experts,
+            layer_types=[
+                "sliding_attention" if spec.kind(i).window
+                else "full_attention" for i in range(spec.num_layers)
+            ],
+            sliding_window=window,
+            score_func="sigmoid", route_norm=spec.norm_topk_prob,
+            route_scale=spec.routed_scaling_factor,
+            mup_enabled=spec.embedding_multiplier != 1.0,
+            n_group=1, topk_group=1,
         )
         del cfg["num_local_experts"]
     if model_type == "falcon_h1":
@@ -775,6 +844,51 @@ def _dest_map_lfm2(spec: ModelSpec) -> dict[str, tuple[tuple, bool, str | None]]
     return m
 
 
+def _dest_map_afmoe(spec: ModelSpec) -> dict[str, tuple[tuple, bool, str | None]]:
+    """``_dest_map`` for an ``afmoe`` checkpoint, under the names of the
+    family's published code: FOUR norms a layer (``input_layernorm`` and
+    ``pre_mlp_layernorm`` on the way in, ``post_attention_layernorm`` and
+    ``post_mlp_layernorm`` on the way OUT: the second is no input norm
+    here, as it is in every other family), ``self_attn.gate_proj`` the
+    output gate, ``q_norm`` / ``k_norm`` a head, an expert layer's
+    ``router.gate``, ``expert_bias``, ``shared_experts`` and ``experts``
+    named one by one."""
+    m: dict[str, tuple[tuple, bool, str | None]] = {
+        "model.embed_tokens.weight": (("embed",), False, None),
+        "model.norm.weight": (("final_norm",), False, None),
+    }
+    if not spec.tie_embeddings:
+        m["lm_head.weight"] = (("lm_head",), True, None)
+    mlp = (("gate_proj", "w_gate"), ("up_proj", "w_up"),
+           ("down_proj", "w_down"))
+    for i in range(spec.num_layers):
+        p = f"model.layers.{i}."
+        li = ("layers", i)
+        for hf, ours in (("input_layernorm", "attn_norm"),
+                         ("post_attention_layernorm", "post_attn_norm"),
+                         ("pre_mlp_layernorm", "mlp_norm"),
+                         ("post_mlp_layernorm", "post_mlp_norm"),
+                         ("self_attn.q_norm", "q_norm"),
+                         ("self_attn.k_norm", "k_norm")):
+            m[p + hf + ".weight"] = (li + (ours,), False, None)
+        for hf, ours in (("q_proj", "wq"), ("k_proj", "wk"), ("v_proj", "wv"),
+                         ("o_proj", "wo"), ("gate_proj", "w_gate_attn")):
+            m[p + f"self_attn.{hf}.weight"] = (li + (ours,), True, None)
+        f = p + "mlp."
+        if not spec.is_moe_layer(i):
+            for hf, ours in mlp:
+                m[f + f"{hf}.weight"] = (li + (ours,), True, None)
+            continue
+        m[f + "router.gate.weight"] = (li + ("moe", "router"), True, "float32")
+        m[f + "expert_bias"] = (li + ("moe", "score_bias"), False, "float32")
+        _expert_names(m, spec, f + "experts.", li + ("moe",))
+        if spec.n_shared_experts:
+            for hf, ours in mlp:
+                m[f + f"shared_experts.{hf}.weight"] = (
+                    li + ("shared", ours), True, None)
+    return m
+
+
 def _is_taps(path: tuple) -> bool:
     """A short convolution's taps, published ``[channels, 1, taps]``."""
     return str(path[-1]).startswith("conv_") or path[-1] in (
@@ -839,6 +953,9 @@ def load_params(
         fused_gpt_oss = False
     elif "conv" in spec.mixers:
         dest = _dest_map_lfm2(spec)
+        fused_gpt_oss = False
+    elif spec.sandwich_norm:
+        dest = _dest_map_afmoe(spec)
         fused_gpt_oss = False
     else:
         dest = _dest_map(spec, all_names)
@@ -1032,6 +1149,8 @@ def save_params(
         dest = _mla_dest_map(spec)
     elif "conv" in spec.mixers:
         dest = _dest_map_lfm2(spec)
+    elif spec.sandwich_norm:
+        dest = _dest_map_afmoe(spec)
     elif spec.moe_bias:
         # gpt-oss exports use the FUSED expert naming (synthesized
         # below); the name hint selects the gpt_oss scheme so the dest

@@ -91,6 +91,8 @@ SCOPE_HEAD = "head"  # final norm + vocabulary projection
 SCOPE_SAMPLER = "sampler"
 # -- rest
 SCOPE_NORM = "norm"  # a layer's two input norms
+SCOPE_NORM_OUT = "norm_out"  # the norms of what a layer's mixer and its
+# FFN put out, before the residual add (``ModelSpec.sandwich_norm``)
 SCOPE_RESIDUAL = "residual"
 SCOPE_EMBED = "embed"
 SCOPE_INDEX = "page_index"  # positions, page ids and masks a program
@@ -117,7 +119,8 @@ REGIONS: dict[str, str] = {
     SCOPE_MOE_COMBINE: FFN, SCOPE_MOE_SHARED: FFN, SCOPE_MOE_COUNT: FFN,
     SCOPE_MOE_ZERO: FFN,
     SCOPE_HEAD: HEAD, SCOPE_SAMPLER: HEAD,
-    SCOPE_NORM: REST, SCOPE_RESIDUAL: REST, SCOPE_EMBED: REST,
+    SCOPE_NORM: REST, SCOPE_NORM_OUT: REST, SCOPE_RESIDUAL: REST,
+    SCOPE_EMBED: REST,
     SCOPE_INDEX: REST, SCOPE_BURST: REST, SCOPE_FEED: REST,
 }
 

@@ -307,6 +307,22 @@ def causal_attention(
 # cost a third more time a call, 128 MiB (512 x 1,024) three times.
 _TILE_ROWS = 128
 _BLOCK_TOKENS = 256
+# A call of more than 1,024 rows (a 4,096-row bucket: PR 53) walks blocks
+# four times as long. On the chip the block length does not move such a
+# call's time (a 4,096-row prefill of a 9-layer window/full model at
+# blocks of 256 / 512 / 1,024 tokens: 63.8 / 61.2 / 64.0 ms from position
+# 0, 77.4 / 72.5 / 75.9 ms from position 4,096, 132.3 / 130.9 / 133.9 ms a
+# pack of two: my chip run, PR 53): what it moves is the walk's count of
+# loop steps, ~1,100 a layer a row at 256 tokens, a dozen small operations
+# each, of which a traced run wrote ~600,000 events in 6 s that the
+# profiler took 277 s to put away, 194-223 s at 1,024. At 1,024 tokens the
+# scores of a tile against a block are 16 MiB a row at 32 heads, under the
+# 32 MiB that the probe above found to cost time; a rule by those bytes
+# alone would also lengthen the blocks of every older configuration's
+# calls (<= 1,024 rows), which keep their blocks and their compiled
+# programs until their cells are measured at another (ROADMAP.md S5 (a))
+_LONG_CALL_ROWS = 1024
+_LONG_BLOCK_TOKENS = 1024
 
 
 def prefill_tiling(
@@ -316,12 +332,15 @@ def prefill_tiling(
     time: rows a query tile and pages a KV block. A table, or a window
     layer's reach from one tile (its rows and the ``window - 1`` tokens
     before them), no wider than one block IS one block: such a tile's
-    walk is a single step."""
+    walk is a single step. A call of more than ``_LONG_CALL_ROWS`` rows
+    takes the longer block."""
     tq = min(n_queries, _TILE_ROWS)
     reach = pages_per_seq
     if window:
         reach = min(reach, (tq + window - 2) // page_size + 2)
-    return tq, min(reach, max(1, _BLOCK_TOKENS // page_size))
+    block = (_LONG_BLOCK_TOKENS if n_queries > _LONG_CALL_ROWS
+             else _BLOCK_TOKENS)
+    return tq, min(reach, max(1, block // page_size))
 
 
 def prefill_blocks(

@@ -677,3 +677,110 @@ def test_longcat_decode_program_compiles_for_v5e(v5e, monkeypatch):
     mem = compiled.memory_analysis()
     assert mem.alias_size_in_bytes >= pools
     assert mem.temp_size_in_bytes < pools
+
+
+def _trinity_layers(v5e, vocab=2048):
+    """Two layers of Trinity-Mini (afmoe) at the published widths,
+    described: a window expert layer and a full (NoPE) expert layer,
+    gated and QK-normed, four norms each (the dense MLP of layer 0 is the
+    dense cells' own product at another width); spec,
+    weights and the cache of the cell's engine (64 slots, pages of 64,
+    tables of 160 pages; 16 of 128 experts held beside the shared one; a
+    pool a kind)."""
+    import dataclasses
+
+    from dynamo_tpu.engine.config import LayerKind, ModelSpec
+    from dynamo_tpu.models import llama
+
+    spec = dataclasses.replace(
+        ModelSpec.tiny_trinity(), vocab_size=vocab, hidden_size=2048,
+        intermediate_size=6144, num_layers=2, num_heads=32, num_kv_heads=4,
+        head_dim=128, dtype="bfloat16", layer_pattern=(0, 1), first_k_dense=0,
+        layer_kinds=(LayerKind(4, 1e4, window=2048),
+                     LayerKind(4, 1e4, rope=False)),
+        embedding_multiplier=2048 ** 0.5, num_experts=128,
+        held_experts=(16, 0), num_experts_per_token=8,
+        moe_intermediate_size=1024)
+
+    def described(tree):
+        return jax.tree.map(
+            lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=v5e),
+            tree)
+
+    params = described(jax.eval_shape(
+        lambda: llama.init_params(spec, jax.random.PRNGKey(0))))
+    k, v = described(jax.eval_shape(lambda: llama.init_cache(spec, 513, 64)))
+    return spec, params, k, v
+
+
+@pytest.mark.parametrize("rows", [2], ids=["pack-of-2"])
+def test_trinity_prefill_program_compiles_for_v5e(v5e, monkeypatch, rows):
+    """The prefill programs of the cell, a layer of each sort at the
+    published widths: 4,096 tokens a row through the page write and the
+    walk at a 2,048-token window (nine blocks a tile) and over the whole
+    row, 32 gated heads over 4 KV heads, the router over 128 experts of
+    which 16 are held, the shared expert, the four norms; every leaf
+    donated. What the program holds beside its arguments stays far under
+    what the whole-table charge of ``EngineConfig.prefill_shapes`` would
+    price a 4,096-row bucket at (8 GiB a row)."""
+    from dynamo_tpu.models import llama
+
+    _as_on_the_chip(monkeypatch)
+    spec, params, k, v = _trinity_layers(v5e)
+    assert k.pools[0].shape == v.pools[0].shape == (1, 513, 4, 64, 128)
+    assert k.pools[1].shape == v.pools[1].shape == (1, 513, 4, 64, 128)
+    i32 = jnp.int32
+    if rows == 1:
+        lowered = jax.jit(
+            llama.prefill_forward_impl, static_argnums=(0,),
+            donate_argnums=(5, 6),
+        ).lower(spec, params, _rows(v5e, 4096, dtype=i32),
+                _rows(v5e, 160, dtype=i32), _rows(v5e, dtype=i32), k, v,
+                _rows(v5e, dtype=i32))
+    else:
+        lowered = jax.jit(
+            llama.prefill_forward_batch_impl, static_argnums=(0,),
+            donate_argnums=(5, 6),
+        ).lower(spec, params, _rows(v5e, rows, 4096, dtype=i32),
+                _rows(v5e, rows, 160, dtype=i32), _rows(v5e, rows, dtype=i32),
+                k, v, _rows(v5e, rows, dtype=i32))
+    compiled = lowered.compile()
+    text = compiled.as_text()
+    assert "%gmm" in text and "norm_out" in text and "moe_shared" in text
+    assert compiled.memory_analysis().temp_size_in_bytes < 3 * 2**30
+
+
+def test_trinity_decode_program_compiles_for_v5e(v5e, monkeypatch):
+    """The decode burst of the cell, a layer of each sort at the published
+    widths: 64 slots through the fused kernel TWICE as two programs of it
+    (``attn_window`` over the 33 pages a 2,048-token window reaches, in
+    chunks of 7; ``attn_full`` over the table's 160 in chunks of 8), 8
+    queries a KV head, the gate behind it, the grouped products over 16
+    groups, 8 steps, the sampler on the device; the pools updated in
+    place."""
+    from dynamo_tpu.models import llama
+    from dynamo_tpu.ops.pallas.fused_decode import chunk_pages
+
+    _as_on_the_chip(monkeypatch)
+    spec, params, k, v = _trinity_layers(v5e)
+    page_bytes = 4 * 64 * (128 + 128) * 2
+    assert chunk_pages(page_bytes, 33) == 7
+    assert chunk_pages(page_bytes, 160) == 8
+    B_, i32, f32 = 64, jnp.int32, jnp.float32
+    compiled = jax.jit(
+        llama.decode_steps_impl, static_argnums=(0,),
+        static_argnames=("n_steps", "n_logprobs"), donate_argnums=(5, 6),
+    ).lower(
+        spec, params, _rows(v5e, B_, dtype=i32), _rows(v5e, B_, 160, dtype=i32),
+        _rows(v5e, B_, dtype=i32), k, v, _rows(v5e, B_, dtype=jnp.bool_),
+        _rows(v5e, B_, dtype=f32), _rows(v5e, B_, dtype=i32),
+        _rows(v5e, B_, dtype=f32), _rows(v5e, B_, dtype=jnp.uint32),
+        _rows(v5e, B_, dtype=i32), n_steps=8, n_logprobs=0,
+    ).compile()
+    text = compiled.as_text()
+    assert "%attn_window" in text and "%attn_full" in text and "%gmm" in text
+    assert "norm_out" in text
+    pools = sum(p.size * p.dtype.itemsize for p in (*k.pools, *v.pools))
+    mem = compiled.memory_analysis()
+    assert mem.alias_size_in_bytes >= pools
+    assert mem.temp_size_in_bytes < 256 * 2**20

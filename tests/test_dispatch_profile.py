@@ -166,6 +166,43 @@ async def test_precompile_report_covers_serving_shapes():
     await engine.close()
 
 
+@pytest.mark.parametrize("spec", ["tiny", "tiny_deepseek", "tiny_trinity"])
+def test_precompile_compiles_each_program_once_and_ahead(spec, caplog):
+    """The model's programs are lowered and compiled AHEAD, beside one
+    another (``_compile_ahead``), and their warm-up dispatches find them
+    in jit's own cache: each program is compiled once, not once ahead and
+    once at its dispatch, in either family."""
+    import collections
+    import logging
+    import re
+
+    import jax
+
+    # a pool of its own size: programs no earlier test of this process
+    # has compiled (jit would answer those from its cache, compiling none)
+    engine = InferenceEngine(getattr(ModelSpec, spec)(), _cfg(num_pages=96))
+    # (the flag process-wide: a ``with jax.log_compiles()`` is the calling
+    # thread's alone, and the compiles happen on threads of their own)
+    was = jax.config.jax_log_compiles
+    jax.config.update("jax_log_compiles", True)
+    try:
+        with caplog.at_level(logging.WARNING, logger="jax"):
+            report = engine.precompile()
+    finally:
+        jax.config.update("jax_log_compiles", was)
+    made = collections.Counter(re.findall(
+        r"Finished XLA compilation of jit\((\w+)\)", caplog.text))
+    programs = {k: r for k, r in report.items()
+                if k.startswith(("prefill", "decode["))}
+    assert len(programs) >= 4
+    model = {k: n for k, n in made.items()
+             if k.startswith(("prefill_forward", "decode_steps"))}
+    assert sum(model.values()) == len(programs), (model, sorted(programs))
+    for name, rec in programs.items():
+        assert 0 < rec["ahead_secs"] <= rec["secs"], (name, rec)
+        assert rec["compiles"] >= 1 and "error" not in rec
+
+
 async def test_precompile_warmup_miss_fault_keeps_serving():
     """Injected engine.compile failures (DYN_FAULTS site) = warmup
     misses: precompile reports them and serving still works, eating the

@@ -214,7 +214,8 @@ def test_phase_annotations_match_the_profile_sums(traced):
             phase in synthesized
             # counts, not phases
             or phase.startswith(
-                ("decode_kv.", "kv_pool.", "prefill_kv.", "chunked_prefill.",
+                ("decode_kv.", "kv_pool.", "kv.", "prefill_kv.",
+                 "chunked_prefill.",
                  "burst_hold.", "decode_bursts.", "first_tokens.",
                  "stream.", "event_loop."))
             or phase.startswith("readmit.") and phase != "readmit.d2h_wait"

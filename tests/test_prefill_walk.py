@@ -243,6 +243,34 @@ def test_tiling_follows_the_table_the_bucket_and_the_window():
     assert count.max() == 1
 
 
+@pytest.mark.parametrize("window", [0, 20], ids=["full", "window"])
+def test_a_call_of_many_rows_walks_longer_blocks_to_the_same_attention(
+        monkeypatch, window):
+    """Past ``_LONG_CALL_ROWS`` rows a call's blocks are
+    ``_LONG_BLOCK_TOKENS`` long (a 4,096-row bucket under a 2,048-token
+    window: 16 pages of 64 where a 1,024-row bucket takes 4); what is
+    computed is the same attention, a quarter of the loop steps."""
+    assert att.prefill_tiling(4096, 160, 64, 2048) == (att._TILE_ROWS, 16)
+    assert att.prefill_tiling(4096, 160, 64) == (att._TILE_ROWS, 16)
+    assert att.prefill_tiling(1024, 160, 64, 2048) == (att._TILE_ROWS, 4)
+    c = Case(T=32, start=32, n=30, window=window, block=8)
+    short, want = _walk_and_oracle(c, 7, monkeypatch)
+    steps = att.prefill_blocks(32, 30, np.arange(4), 8, window, PAGE, 2)[1]
+    monkeypatch.setattr(att, "_LONG_CALL_ROWS", 16)
+    monkeypatch.setattr(att, "_LONG_BLOCK_TOKENS", 32)
+    assert att.prefill_tiling(32, c.P, PAGE, window)[1] == min(
+        8, (8 + window - 2) // PAGE + 2 if window else 8)
+    att.paged_prefill_attention.clear_cache()  # the tiling is read at trace
+    long, _ = _walk_and_oracle(c, 7, monkeypatch)
+    att.paged_prefill_attention.clear_cache()
+    np.testing.assert_allclose(np.asarray(long), np.asarray(want), 2e-5, 2e-5)
+    np.testing.assert_allclose(np.asarray(long), np.asarray(short), 2e-5, 2e-5)
+    fewer = att.prefill_blocks(
+        32, 30, np.arange(4), 8, window, PAGE,
+        att.prefill_tiling(32, c.P, PAGE, window)[1])[1]
+    assert fewer.sum() < steps.sum()
+
+
 def _mimo_shaped_spec() -> ModelSpec:
     return ModelSpec(
         name="walk-mimo-shaped", vocab_size=128, hidden_size=64,

@@ -140,6 +140,12 @@ PROFILE_COUNTERS: dict[str, str] = {
                                "fetched and scored for them",
     "decode_kv.pages_table": "slots x table width x steps: what a kernel "
                              "that followed the table would move",
+    "kv.window_layer_tokens": "tokens the live slots hold at each decode "
+                              "step x the model's window layers (a model "
+                              "with window layers only)",
+    "kv.window_dead_tokens": "of those, the tokens more than the layer's "
+                             "window behind their row's length: what "
+                             "pages found by layer kind would free",
     "kv_pool.heads_per_lane_row": "KV heads a row of an attention kind's K "
                                   "pool holds (ops/attention.pool_head_dim: "
                                   "2 where 64-wide heads pack two a "
@@ -462,6 +468,10 @@ METRIC_NAMES: dict[str, str] = {
                                "phase and what (steps, assignments, "
                                "experts_touched, expert.<i>; zero_picks, "
                                "ffn_picks with identity experts)",
+    "engine_window_tokens_total": "a model with window layers: tokens "
+                                  "its live slots held a decode step x "
+                                  "window layers (held) and those past "
+                                  "their window (dead)",
     "engine_admission_rejects_total": "requests refused at admission by "
                                       "reason (draining | saturated | "
                                       "deadline) — the 503/504 feeders",
