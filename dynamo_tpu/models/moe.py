@@ -43,6 +43,7 @@ from dynamo_tpu.engine.config import ModelSpec
 # the regions of the expert layer (jax.named_scope: metadata only)
 from dynamo_tpu.models.regions import (
     SCOPE_EXPERTS,
+    SCOPE_GMM,
     SCOPE_MOE_COMBINE,
     SCOPE_MOE_COUNT,
     SCOPE_MOE_DISPATCH,
@@ -302,8 +303,9 @@ def _combine(out: jax.Array, inv: jax.Array, w: jax.Array) -> jax.Array:
     return y
 
 
-# rows a tile of the grouped product; its tiles of the contracted and
-# the output dims. On a v5e at MiMo's expert (4096 x 2048, 16 held) one
+# megablox's tiles, for the calls whose rows stream: rows a tile of the
+# grouped product; its tiles of the contracted and the output dims. On a
+# v5e at MiMo's expert (4096 x 2048, 16 held) one
 # product over 64 to 4,096 live rows takes 0.43 ms at (128, 1024, 2048),
 # 76% of what reading the 268 MB of weights takes; ``jax.lax.ragged_dot``
 # 0.94 ms (my chip runs, PR 28). (128, 2048, 2048) does not fit VMEM.
@@ -313,25 +315,35 @@ _GMM_TILES = (128, 1024, 2048)
 def _grouped_matmul(a: jax.Array, w: jax.Array, sizes: jax.Array):
     """rows [m, k] sorted by group x w [g, k, n] -> [m, n]: row r of group
     i times ``w[i]``; ``sizes`` [g] rows a group, in order. Rows past
-    ``sum(sizes)`` come back undefined. On the chip the Pallas grouped
-    matmul that ships with JAX (megablox), which visits only the row
-    tiles a group reaches; ``jax.lax.ragged_dot`` elsewhere."""
+    ``sum(sizes)`` come back undefined. On the chip a Mosaic call under
+    the name ``gmm``, chosen by the call's static shape alone: where the
+    rows and the output fit VMEM beside two weight tiles (a decode step's
+    call) the repo's own kernel, which reads each touched expert once
+    (``ops/pallas/grouped.py``); else (rows that stream: prefill) the
+    grouped matmul that ships with JAX (megablox), which visits only the
+    row tiles a group reaches. ``jax.lax.ragged_dot`` elsewhere."""
     from dynamo_tpu.ops.attention import use_pallas
+    from dynamo_tpu.ops.fallback import note_fallback, note_grouped_product
 
     on_tpu = jax.default_backend() == "tpu"
     if not (on_tpu and use_pallas()):
         if on_tpu:
             # off the chip ragged_dot IS the path (the kernel interpreted
             # would take minutes); on it, this is DYNAMO_PALLAS=0
-            from dynamo_tpu.ops.fallback import note_fallback
-
             note_fallback("no_pallas_backend", expected=True,
                           detail="moe grouped product: jax.lax.ragged_dot")
         return jax.lax.ragged_dot(a, w, sizes)
+    from dynamo_tpu.ops.pallas import grouped
+
+    m = a.shape[0]
+    shape = f"{a.shape} x {w.shape}"
+    if grouped.tile_n(m, *w.shape[1:], a.dtype.itemsize) is not None:
+        note_grouped_product("resident", detail=shape)
+        return grouped.grouped_matmul(a, w, sizes, scope=SCOPE_GMM)
+    note_grouped_product("streamed", detail=shape)
     from jax.experimental.pallas.ops.tpu.megablox.gmm import gmm
 
     tm, tk, tn = _GMM_TILES
-    m = a.shape[0]
     a = jnp.pad(a, ((0, -m % tm), (0, 0)))
     out = gmm(
         a, w, sizes, preferred_element_type=a.dtype,

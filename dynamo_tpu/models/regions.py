@@ -79,7 +79,8 @@ SCOPE_EXPERTS = "moe_experts"
 SCOPE_MOE_DISPATCH = "moe_dispatch"  # sort the assignments by expert
 # (and invert the sort for the combine), gather their rows
 SCOPE_MOE_GROUPED = "moe_grouped"  # the three grouped products + swiglu
-SCOPE_GMM = "gmm"  # megablox's own jit: the Mosaic grouped product
+SCOPE_GMM = "gmm"  # the Mosaic grouped product: ops/pallas/grouped.py where
+# a call's rows fit VMEM, else megablox's own jit of that name
 SCOPE_MOE_COMBINE = "moe_combine"  # un-permute: a token gathers its k rows
 # back and adds them, weighted, in float32
 SCOPE_MOE_SHARED = "moe_shared"  # the shared expert

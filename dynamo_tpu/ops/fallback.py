@@ -34,6 +34,17 @@ _FALLBACKS = REGISTRY.counter(
     "Fused/quantized kernel downgrades taken at dispatch, by reason",
     ["reason"],
 )
+# Not a downgrade, so not a reason of the counter above (the benchmark's
+# output check refuses a run that shows ANY of its series): which of its
+# two kernels a grouped product of an expert layer compiled to on the
+# chip, chosen by the call's static shape (models/moe.py: _grouped_matmul)
+_GROUPED = REGISTRY.counter(
+    "grouped_product_total",
+    "Expert-layer grouped products compiled on the chip, by path "
+    "(resident: ops/pallas/grouped.py, rows and output in VMEM; "
+    "streamed: megablox, rows that do not fit)",
+    ["path"],
+)
 register_registry("ops.fallback", REGISTRY)
 
 _seen: set[str] = set()
@@ -59,6 +70,15 @@ def note_fallback(
     if detail:
         msg += f" ({detail})"
     (log.debug if expected else log.warning)(msg)
+
+
+def note_grouped_product(path: str, *, detail: str = "") -> None:
+    """Count a compiled grouped product by its path (``resident`` |
+    ``streamed``); trace-time, like ``note_fallback``: once a compiled
+    specialisation, not once a step. A decode program that counts
+    ``streamed`` reads its experts' weights as megablox tiles them."""
+    _GROUPED.labels(path).inc()
+    log.debug("grouped product: %s (%s)", path, detail)
 
 
 def reset_seen() -> None:

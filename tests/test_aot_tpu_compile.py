@@ -16,6 +16,8 @@ import os
 
 os.environ.setdefault("TPU_LOG_DIR", "disabled")  # keep compiler logs out of /tmp
 
+import re
+
 import jax
 import jax.numpy as jnp
 import pytest
@@ -780,6 +782,16 @@ def test_trinity_decode_program_compiles_for_v5e(v5e, monkeypatch):
     text = compiled.as_text()
     assert "%attn_window" in text and "%attn_full" in text and "%gmm" in text
     assert "norm_out" in text
+    # the stacked weights stay in HBM: the kernel fetches the experts a
+    # step touched itself (``ops/pallas/grouped.py``). megablox's operand
+    # the compiler copied WHOLE into VMEM (memory space S(1)) ahead of
+    # the call, 4 of this program's 6 (PERF.md section 6, PRs 53 and 54)
+    made = dict(re.findall(r"^\s*(?:ROOT )?(%[\w.-]+) = (\S+)", text, re.M))
+    calls = re.findall(r"%gmm[.\d]* = \S+ custom-call\(([^)]*)\)", text)
+    assert len(calls) == 6  # three products a layer
+    for operands in calls:
+        weights = operands.split(",")[-1].strip()
+        assert "[16," in made[weights] and "S(1)" not in made[weights]
     pools = sum(p.size * p.dtype.itemsize for p in (*k.pools, *v.pools))
     mem = compiled.memory_analysis()
     assert mem.alias_size_in_bytes >= pools

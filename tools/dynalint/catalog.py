@@ -482,6 +482,10 @@ METRIC_NAMES: dict[str, str] = {
                                    "acceptance rate",
     # fused-kernel fallback accounting (ops/fallback.py, on every
     # /metrics surface via the module registry)
+    "grouped_product_total": "expert-layer grouped products compiled "
+                             "on the chip by path (resident: "
+                             "ops/pallas/grouped.py | streamed: "
+                             "megablox) — counted at TRACE time",
     "fused_fallback_total": "fused/quantized fast-path downgrades by "
                             "reason (quant_tp_shardmap | "
                             "no_pallas_backend | latent_fp8_xla | "
