@@ -35,7 +35,14 @@ class LayerKind:
     # ``num_kv_heads`` and ``window`` are unread for it), and no V pool;
     # or "conv" (LFM2's gated short convolution): ``C * conv(B * x)`` off
     # one input projection, whose whole state is the ``conv_taps - 1``
-    # last ``B * x`` a sequence: a row of tails and no state matrix
+    # last ``B * x`` a sequence: a row of tails and no state matrix;
+    # or "scan" (Mamba-1, arXiv:2312.00752): a selective scan ALONE in its
+    # layer over a state ``[scan_state, scan_inner]`` a row, a decay a
+    # channel a state (``ModelSpec.scan_*``), beside the tail of its
+    # 4-tap convolution; or "gmu" (SambaY's Gated Memory Unit,
+    # arXiv:2507.06607): ``W_2 (silu(W_1 h) * m)`` with ``m`` the output
+    # of the model's memory layer for the same token
+    # (``ModelSpec.memory_layer``): it keeps neither pages nor a row
     mixer: str = "softmax"
     # a KDA kind's forms. ``gate_bound`` < 0: the decay a channel is
     # bounded, ``gate_bound * sigmoid(exp(a_log) * (f + dt_bias))`` in
@@ -53,18 +60,31 @@ class LayerKind:
     # beside kinds of the same model that do rotate
     # (``ModelSpec.use_rope`` is the switch for every kind at once)
     rope: bool = True
+    # differential attention (arXiv:2410.05258): the heads pair up (even,
+    # odd), a pair's output is ``rms(a1 - lambda a2)`` over a V twice as
+    # wide, and the kind's pool holds a PAIR a row: ``num_kv_heads / 2``
+    # heads of ``2 head_dim`` (K ``[k1 | k2]``, V ``[v_2j | v_2j+1]``)
+    differential: bool = False
+    # a softmax kind that owns no pool: its layers have queries and an
+    # output projection alone and read the pages of layer ``reads[1]``
+    # (among its kind's layers) of kind ``reads[0]`` (SambaY's
+    # cross-decoder). Empty: the kind reads and writes its own
+    reads: tuple[int, ...] = ()
+
+    def __post_init__(self) -> None:
+        object.__setattr__(self, "reads", tuple(self.reads))
 
     @property
     def recurrent(self) -> bool:
         """The kind keeps a row a sequence beside the pages (found
         through ``llama.StateRows``): convolution tails, and a state
         matrix where it has one (``state``)."""
-        return self.mixer in ("kda", "ssd", "conv")
+        return self.mixer in ("kda", "ssd", "conv", "scan")
 
     @property
     def state(self) -> bool:
         """The kind's row holds a float32 state matrix beside its tails."""
-        return self.mixer in ("kda", "ssd")
+        return self.mixer in ("kda", "ssd", "scan")
 
     @property
     def latent(self) -> bool:
@@ -74,8 +94,16 @@ class LayerKind:
     @property
     def paged(self) -> bool:
         """The kind keeps pages: it has softmax attention (KV heads, or
-        the one latent row the heads share)."""
-        return self.num_kv_heads > 0 or self.latent
+        the one latent row the heads share) over pages of its own."""
+        return (self.num_kv_heads > 0 or self.latent) and not self.reads
+
+    @property
+    def carried(self) -> bool:
+        """A layer of the kind writes nothing a later token reads: it
+        mixes what earlier layers left for the SAME token (a GMU) or
+        another layer's pages (``reads``), so a prefill runs it for a
+        sequence's last row alone."""
+        return self.mixer == "gmu" or bool(self.reads)
 
 
 @dataclass(frozen=True)
@@ -210,6 +238,22 @@ class ModelSpec:
     # causal depthwise convolution of ``conv_taps`` taps, no bias, on
     # ``B * x`` over ``hidden_size`` channels
     conv_taps: int = 3
+    # selective-scan layers (``LayerKind.mixer == "scan"``, Mamba-1):
+    # ``scan_inner`` channels over ``scan_state`` states a channel, the
+    # time step through a rank of ``scan_dt_rank``, a causal depthwise
+    # convolution of ``scan_conv`` taps (with bias); GMU layers are
+    # ``scan_inner`` wide too
+    scan_inner: int = 0
+    scan_state: int = 16
+    scan_dt_rank: int = 0
+    scan_conv: int = 4
+    # the norms' form: "rms" (a gain), or "layer" (LayerNorm: mean and
+    # variance, a gain and a bias; the layers' two and the final one)
+    norm: str = "rms"
+    # the PUBLISHED index of each layer where a cut keeps some of them
+    # (``layers_kept``) and a layer's constants follow it (differential
+    # attention's ``lambda_init``); empty: a layer's own index
+    layer_ids: tuple[int, ...] = ()
     # softmax layers norm q and k a head before the rotation (RMSNorm,
     # one gain ``[head_dim]`` each, shared by the heads)
     qk_norm: bool = False
@@ -250,6 +294,7 @@ class ModelSpec:
 
         fix("layer_types", tuple(self.layer_types))
         fix("layer_pattern", tuple(self.layer_pattern))
+        fix("layer_ids", tuple(self.layer_ids))
         fix("held_experts", tuple(self.held_experts))
         fix("expert_clamp", tuple(float(c) for c in self.expert_clamp))
         fix("shared_clamp", tuple(float(c) for c in self.shared_clamp))
@@ -267,6 +312,14 @@ class ModelSpec:
         if self.layer_kinds and not self.layer_kinds[0].paged:
             # the cache's first leaf is a page pool (llama.page_size_of)
             raise ValueError("layer_kinds must list a paged kind first")
+        if self.carried_from < self.num_layers and not all(
+            self.kind(li).carried
+            for li in range(self.carried_from, self.num_layers)
+        ):
+            raise ValueError(
+                "layers that read a memory or another layer's pages (gmu, "
+                "reads) come last: a prefill runs them for one row"
+            )
         if self.zero_experts and self.moe_scoring != "softmax_bias":
             raise ValueError(
                 "zero_experts are routed by moe_scoring 'softmax_bias' alone"
@@ -320,6 +373,30 @@ class ModelSpec:
     def has_latent(self) -> bool:
         """Some kind of layer keeps latent pages beside the other kinds."""
         return any(k.latent for k in self.layer_kinds)
+
+    @property
+    def carried_from(self) -> int:
+        """The first layer of the model's upper half, whose layers write
+        no cache (``LayerKind.carried``: SambaY's cross-decoder), or
+        ``num_layers`` where it has none. A prefill program takes every
+        row through the layers below and one row a sequence from here."""
+        return next(
+            (li for li in range(self.num_layers) if self.kind(li).carried),
+            self.num_layers,
+        ) if self.layer_kinds else self.num_layers
+
+    @property
+    def memory_layer(self) -> int:
+        """The scan layer whose output before its gate every GMU layer
+        reads: the last selective scan below ``carried_from`` (SambaY: the
+        self-decoder's last scan), or -1 where the model has none."""
+        return max(
+            (li for li in range(self.carried_from)
+             if self.kind(li).mixer == "scan"), default=-1)
+
+    def layer_id(self, li: int) -> int:
+        """Layer ``li``'s published index (``layer_ids``)."""
+        return self.layer_ids[li] if self.layer_ids else li
 
     def clamps(self, li: int) -> tuple[float, float]:
         """Layer ``li``'s plain-SiLU clamps (routed experts, shared
@@ -621,6 +698,33 @@ class ModelSpec:
         return cls(**base)
 
     @classmethod
+    def tiny_phi4flash(cls, **kw) -> "ModelSpec":
+        """Toy Phi-4-mini-flash (SambaY) architecture: Mamba-1 scan layers
+        beside window layers of differential attention, one full layer
+        whose pages the cross layers above read, GMU layers that read the
+        last scan's output, LayerNorm with bias, biased projections, no
+        position, a tied head; the published layer indices of a cut."""
+        scan, gmu = (LayerKind(0, 0.0, mixer=m) for m in ("scan", "gmu"))
+        base = dict(
+            name="tiny-phi4flash", vocab_size=96, hidden_size=64,
+            intermediate_size=96, num_layers=8, num_heads=8,
+            num_kv_heads=4, head_dim=16, dtype="float32", rms_eps=1e-5,
+            tie_embeddings=True, use_rope=False, attn_bias=True,
+            norm="layer",
+            layer_kinds=(
+                LayerKind(4, 0.0, window=8, differential=True),
+                LayerKind(4, 0.0, differential=True),
+                scan, gmu,
+                LayerKind(4, 0.0, differential=True, reads=(1, 0)),
+            ),
+            layer_pattern=(2, 0, 2, 1, 3, 4, 3, 4),
+            layer_ids=(0, 1, 16, 17, 18, 19, 20, 21),
+            scan_inner=128, scan_state=16, scan_dt_rank=4, scan_conv=4,
+        )
+        base.update(kw)
+        return cls(**base)
+
+    @classmethod
     def tiny_longcat(cls, **kw) -> "ModelSpec":
         """Toy LongCat-Flash architecture: shortcut-connected double
         layers (two latent attentions, two dense FFNs, one expert layer
@@ -668,6 +772,7 @@ class ModelSpec:
             "tiny-ling3": cls.tiny_ling3,
             "tiny-longcat": cls.tiny_longcat,
             "tiny-trinity": cls.tiny_trinity,
+            "tiny-phi4flash": cls.tiny_phi4flash,
             "llama-3-8b": cls.llama3_8b,
             "llama-3-70b": cls.llama3_70b,
             "mixtral-8x7b": cls.mixtral_8x7b,
@@ -944,7 +1049,16 @@ class EngineConfig:
                 + 4 * spec.ssm_groups * spec.ssm_state
             ) + 4 * 2 * rows * -(-bucket // max(1, Q)) * (
                 Hs * spec.ssm_head_dim * spec.ssm_state)
-            return scores * 3 + kda + ssd + 96 * 1024 * rows * bucket
+            # a selective-scan layer's chunk form (ops/attention.
+            # scan_chunk_prefill): a chunk's (decay, input) pairs [chunk,
+            # state, channels] float32, the associative scan's copies of
+            # them and the states, and a handful of [channels] a token
+            from dynamo_tpu.ops.attention import SCAN_CHUNK
+
+            scan = 4 * 6 * rows * spec.scan_inner * (
+                min(SCAN_CHUNK, bucket) * spec.scan_state + bucket
+            ) if "scan" in spec.mixers else 0
+            return scores * 3 + kda + ssd + scan + 96 * 1024 * rows * bucket
 
         shapes: dict[int, int] = {}
         for bucket in self.prefill_buckets:

@@ -199,7 +199,7 @@ READMIT_SUMS = {
 _COUNTER_FAMILIES = (
     "decode_kv", "prefill_kv", "chunked_prefill", "burst_hold",
     "decode_bursts", "first_tokens", "kda", "ssd", "recurrent_state",
-    "stream", "kv",
+    "stream", "kv", "prefill",
 )
 
 # undisturbed burst times kept a burst length (their smallest is the
@@ -620,6 +620,23 @@ class InferenceEngine:
             kd.window for kd in kinds if kd.window)
         self.kv = {"window_layer_tokens": 0, "window_dead_tokens": 0} if (
             self._window_layers) else {}
+        # a model whose upper layers write no cache (SambaY's
+        # cross-decoder; always on for it alone, from the lengths the step
+        # thread holds: no device work): the layers that read ANOTHER
+        # layer's pages, whose reads of the one pool ``kv.
+        # shared_read_tokens`` sums (live tokens x those layers, a decode
+        # step); and the prompt tokens the prefill programs took through
+        # the layers below (``prefill.rows``) beside the rows, one a
+        # sequence, they took through those above (``prefill.cross_rows``)
+        self._shared_readers = sum(
+            bool(self.spec.kind(li).reads)
+            for li in range(self.spec.num_layers))
+        if self._shared_readers:
+            self.kv["shared_read_tokens"] = 0
+        self.prefill = (
+            {"rows": 0, "cross_rows": 0}
+            if self.spec.carried_from < self.spec.num_layers else {}
+        )
         # what the prefill walk visited, in blocks of pages a layer, over
         # the dispatched prefills and verifies (always on: integer
         # arithmetic on [rows, tiles] a dispatch), a layer kind apart,
@@ -842,6 +859,16 @@ class InferenceEngine:
           ``_kv_pool_layout``; fixed at the build, no window zeroes them.
         - ``kv.window_layer_tokens`` / ``.window_dead_tokens`` (calls; a
           model with window layers only): see ``_count_decode_kv``.
+        - ``kv.shared_read_tokens`` (calls; a model with layers that read
+          another layer's pages only): live tokens x those layers, summed
+          over the decode steps: what the ONE shared pool is read for
+          beyond its own layer.
+        - ``prefill.rows`` / ``.cross_rows`` (calls; a model whose upper
+          layers write no cache only): prompt tokens the prefill programs
+          took through the layers below ``ModelSpec.carried_from``, and
+          rows (one a sequence with tokens) through those above. Their
+          ratio is ~1 / the prompts' length; 1 would say the upper half
+          ran for every row.
         - ``prefill_kv.blocks_visited.<kind>`` / ``.blocks_table.<kind>``
           (calls; kind ``full`` or ``window``, or ``latent`` for the
           latent family's walk, which also reports
@@ -1039,6 +1066,9 @@ class InferenceEngine:
             self.kv["window_layer_tokens"] += int(lens.sum()) * layers
             self.kv["window_dead_tokens"] += (
                 int((lens - window).clip(0).sum()) * layers)
+        if self._shared_readers:
+            self.kv["shared_read_tokens"] += (
+                int(lens.sum()) * self._shared_readers)
         chunk = self._kv_chunk_pages
         if chunk is None:
             return
@@ -1108,6 +1138,9 @@ class InferenceEngine:
         if self.recurrent_state:
             self.recurrent_state["prefill_chunks"] += int((nts > 0).sum())
             self.recurrent_state["rows_resumed"] += resumed
+        if self.prefill:
+            self.prefill["rows"] += int(nts.sum())
+            self.prefill["cross_rows"] += int((nts > 0).sum())
         kv = self.prefill_kv
         for kind, window in self._prefill_walks.items():
             kernel = kind == "latent" and latent_kernel_serves(

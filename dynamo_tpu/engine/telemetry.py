@@ -123,6 +123,17 @@ _M_WINDOW = REGISTRY.counter(
     ["engine", "what"],
 )
 
+_M_CARRIED = REGISTRY.counter(
+    "engine_carried_rows_total",
+    "a model whose upper layers write no cache (a cross-decoder): prompt "
+    "tokens its prefill programs took through the layers below (what = "
+    "rows) and rows, one a sequence, through those above (what = "
+    "cross_rows); live tokens x the layers that read another layer's "
+    "pages, a decode step (what = shared_read_tokens): engine.prefill and "
+    "kv.shared_read_tokens of profile_snapshot()",
+    ["engine", "what"],
+)
+
 _REJECT_REASONS = ("draining", "saturated", "deadline", "over_quota", "shed")
 _COLLECTOR_IDS = iter(range(1 << 30))
 
@@ -154,6 +165,7 @@ class EngineCollector:
         self._tenant_base: dict[tuple[str, str], int] = {}
         self._moe_base: dict[str, int] = {}
         self._window_base: dict[str, int] = {}
+        self._carried_base: dict[str, int] = {}
         self._lag_ticks = 0  # the probe's wake-ups already observed
 
     def start(self) -> "EngineCollector":
@@ -234,6 +246,16 @@ class EngineCollector:
             if cur > base:
                 _M_WINDOW.labels(lbl, what).inc(cur - base)
             self._window_base[what] = cur
+        carried = dict(getattr(eng, "prefill", {}))
+        if "shared_read_tokens" in eng.kv:
+            carried["shared_read_tokens"] = eng.kv["shared_read_tokens"]
+        for what, cur in carried.items():
+            base = self._carried_base.get(what, 0)
+            if cur < base:  # reset_profile_window zeroed the engine's
+                base = 0
+            if cur > base:
+                _M_CARRIED.labels(lbl, what).inc(cur - base)
+            self._carried_base[what] = cur
         if eng.kvbm is not None:
             for tier, nbytes in eng.kvbm.tier_bytes().items():
                 _M_KVBM_TIER.labels(lbl, tier).set(nbytes)

@@ -26,12 +26,15 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from dynamo_tpu.engine.config import ModelSpec
 from dynamo_tpu.models.regions import (
+    SCOPE_ATTN_CROSS,
+    SCOPE_ATTN_DIFF,
     SCOPE_ATTN_FULL,
     SCOPE_ATTN_WINDOW,
     SCOPE_BURST,
     SCOPE_CONV_MIX,
     SCOPE_CONV_PROJ,
     SCOPE_EMBED,
+    SCOPE_GMU,
     SCOPE_HEAD,
     SCOPE_INDEX,
     SCOPE_KDA_CONV,
@@ -59,6 +62,8 @@ from dynamo_tpu.ops.attention import (
     kda_decode_step,
     page_tiles,
     paged_prefill_attention,
+    scan_chunk_prefill,
+    scan_decode_step,
     ssd_chunk_prefill,
     ssd_decode_step,
 )
@@ -118,6 +123,10 @@ def init_params(spec: ModelSpec, key: jax.Array) -> Params:
     }
     if not spec.tie_embeddings:
         params["lm_head"] = table(next(keys), (d, spec.vocab_size), 1)
+    if spec.norm == "layer":
+        k_g, k_b = jax.random.split(jax.random.fold_in(key, 5000))
+        params["final_norm"] = 1 + dense(k_g, (d,), scale=0.1)
+        params["final_norm_bias"] = dense(k_b, (d,), scale=0.1)
     for li in range(spec.num_layers):
         kd = spec.kind(li)
         nkv = kd.num_kv_heads
@@ -140,6 +149,10 @@ def init_params(spec: ModelSpec, key: jax.Array) -> Params:
             }
         elif kd.mixer == "conv":
             layer = _init_conv_layer(spec, dense, keys, extra)
+        elif kd.mixer in ("scan", "gmu"):
+            layer = _init_scan_layer(spec, kd, dense, _own_keys(key, li))
+        elif kd.differential:
+            layer = _init_diff_layer(spec, kd, dense, _own_keys(key, li))
         elif not kd.paged:
             layer = _init_kda_layer(spec, kd, dense, keys, extra)
         else:
@@ -160,12 +173,25 @@ def init_params(spec: ModelSpec, key: jax.Array) -> Params:
                 layer["k_norm"] = 1 + dense(extra[8], (hd,), scale=0.1)
             if kd.mixer == "ssd":
                 layer.update(_init_ssd_mixer(spec, dense, extra))
-        if spec.attn_bias:
+        # zero biases on a layer's attention projections, unless its
+        # kind has none (a scan, a GMU) or drew its own (a differential)
+        if spec.attn_bias and "wq" in layer and "bo" not in layer:
             layer.update(
                 bq=jnp.zeros((nh * hd,), dtype),
                 bk=jnp.zeros((nkv * hd,), dtype),
                 bv=jnp.zeros((nkv * vd,), dtype),
                 bo=jnp.zeros((d,), dtype),
+            )
+        if spec.norm == "layer":
+            # LayerNorm: gains 1 + N(0, 0.1^2) and biases N(0, 0.1^2),
+            # drawn away from 1 and 0 (a term left out of a program then
+            # shows on random weights), whatever the layer's kind
+            sk = _own_keys(key, li)
+            layer.update(
+                attn_norm=1 + dense(sk[0], (d,), scale=0.1),
+                attn_norm_bias=dense(sk[1], (d,), scale=0.1),
+                mlp_norm=1 + dense(sk[2], (d,), scale=0.1),
+                mlp_norm_bias=dense(sk[3], (d,), scale=0.1),
             )
         if spec.sandwich_norm:
             # the gains of the two norms on the way OUT, drawn about 1 on
@@ -249,6 +275,92 @@ def _init_ssd_mixer(spec: ModelSpec, dense, extra) -> Params:
         "ssm_norm": jnp.ones((d_ssm,), dtype),
         "ssm_out": dense(extra[6], (d_ssm, d)),
     }
+
+
+def _own_keys(key, li: int):
+    """Layer ``li``'s 20 keys of its own, one fold of the root: 0-3 a
+    LayerNorm model's gains and biases (``init_params``), 4-16 the mixer
+    of a kind that draws none of the layers' running keys (a scan, a
+    GMU, a differential kind)."""
+    return jax.random.split(jax.random.fold_in(key, 4000 + li), 20)
+
+
+def _init_scan_layer(spec: ModelSpec, kd, dense, sk) -> Params:
+    """A selective-scan (Mamba-1) or a GMU layer's mixer weights on the
+    layer's own keys ``sk`` (its MLP is drawn by the caller).
+
+    - a scan layer, 4-10: ``scan_in [d, x | z]``, the taps ``[taps, C]``
+      N(0, 1 / taps) and their bias N(0, 0.1^2), ``scan_x [C, dt | B |
+      C]``, ``scan_dt [R, C]``, the time step log-uniform in (1e-3, 1e-1)
+      a channel with ``scan_dt_bias`` its inverse softplus, ``scan_out``;
+      ``scan_a_log [N, C]`` is ``log(1..N)`` a channel and ``scan_d`` 1
+      (Mamba's own), both float32 beside ``scan_dt_bias``;
+    - a GMU layer, 4-5: ``gmu_in [d, C]``, ``gmu_out [C, d]``."""
+    dtype, f32 = jnp.dtype(spec.dtype), jnp.float32
+    d = spec.hidden_size
+    C, N, R = spec.scan_inner, spec.scan_state, spec.scan_dt_rank
+    layer = {
+        "attn_norm": jnp.ones((d,), dtype),
+        "mlp_norm": jnp.ones((d,), dtype),
+    }
+    if kd.mixer == "gmu":
+        layer.update(gmu_in=dense(sk[4], (d, C)), gmu_out=dense(sk[5], (C, d)))
+        return layer
+    step = jnp.exp(jax.random.uniform(
+        sk[9], (C,), f32, jnp.log(1e-3), jnp.log(1e-1)))
+    layer.update(
+        scan_in=dense(sk[4], (d, 2 * C)),
+        scan_conv=dense(sk[5], (spec.scan_conv, C)),
+        scan_conv_bias=dense(sk[6], (C,), scale=0.1),
+        scan_x=dense(sk[7], (C, R + 2 * N)),
+        scan_dt=dense(sk[8], (R, C)),
+        scan_dt_bias=jnp.log(jnp.expm1(step)),
+        scan_a_log=jnp.broadcast_to(
+            jnp.log(jnp.arange(1, N + 1, dtype=f32))[:, None], (N, C)),
+        scan_d=jnp.ones((C,), f32),
+        scan_out=dense(sk[10], (C, d)),
+    )
+    return layer
+
+
+def _init_diff_layer(spec: ModelSpec, kd, dense, sk) -> Params:
+    """A differential-attention layer's mixer weights on the layer's own
+    keys ``sk`` (its MLP is drawn by the caller), 4-16: ``wq, wk, wv,
+    wo``, the four lambda vectors ``[head_dim]`` N(0, 0.1^2) float32, the
+    pair norm's gain ``[2 v_dim]`` ``1 + N(0, 0.1^2)``, and under
+    ``attn_bias`` the projections' biases N(0, 0.1^2), not zero, so that
+    they are in a comparison on random weights; a layer that reads
+    another's pages (``kd.reads``) draws the queries' and the output's
+    alone, on the same keys."""
+    dtype, f32 = jnp.dtype(spec.dtype), jnp.float32
+    d, hd, vd, nh = spec.hidden_size, spec.head_dim, spec.v_dim, spec.num_heads
+    nkv = kd.num_kv_heads
+    layer = {
+        "attn_norm": jnp.ones((d,), dtype),
+        "mlp_norm": jnp.ones((d,), dtype),
+        "wq": dense(sk[4], (d, nh * hd)),
+        "wo": dense(sk[7], (nh * vd, d)),
+        "subln": 1 + dense(sk[16], (2 * vd,), scale=0.1),
+        **{
+            name: _draw(sk[12 + i], 0.1, shape=(hd,), dtype=f32)
+            for i, name in enumerate(
+                ("lambda_q1", "lambda_k1", "lambda_q2", "lambda_k2"))
+        },
+    }
+    if not kd.reads:
+        layer.update(
+            wk=dense(sk[5], (d, nkv * hd)), wv=dense(sk[6], (d, nkv * vd)))
+    if spec.attn_bias:
+        layer.update(
+            bq=dense(sk[8], (nh * hd,), scale=0.1),
+            bo=dense(sk[11], (d,), scale=0.1),
+        )
+        if not kd.reads:
+            layer.update(
+                bk=dense(sk[9], (nkv * hd,), scale=0.1),
+                bv=dense(sk[10], (nkv * vd,), scale=0.1),
+            )
+    return layer
 
 
 def _init_conv_layer(spec: ModelSpec, dense, keys, extra) -> Params:
@@ -650,12 +762,19 @@ def init_cache(
     state_row = {
         "kda": (H, D, D),
         "ssd": (spec.ssm_heads, spec.ssm_head_dim, spec.ssm_state),
+        "scan": (spec.scan_state, spec.scan_inner),
     }
     tail_row = {
         "kda": (spec.kda_conv - 1, 3, H * D),
         "ssd": (spec.ssm_conv - 1, spec.ssm_conv_dim),
         "conv": (spec.conv_taps - 1, spec.hidden_size),
+        "scan": (spec.scan_conv - 1, spec.scan_inner),
     }
+
+    def pages(n, kd, model_d, pair_d):
+        # a differential kind keeps a PAIR of heads a row
+        p = 2 if kd.differential else 1
+        return side(n, kd.num_kv_heads // p, p * model_d, p * pair_d)
 
     def rows(n, shape, dt):
         return jnp.zeros((n, R1, *shape), dt)
@@ -671,8 +790,7 @@ def init_cache(
         if kd.latent:
             return latent(n)
         return _entry_of(
-            side(n, kd.num_kv_heads, spec.head_dim, spec.v_dim)
-            if kd.paged else None,
+            pages(n, kd, spec.head_dim, spec.v_dim) if kd.paged else None,
             rows(n, state_row[kd.mixer], jnp.float32) if kd.state else None,
         )
 
@@ -680,8 +798,7 @@ def init_cache(
         if kd.latent:
             return None
         return _entry_of(
-            side(n, kd.num_kv_heads, spec.v_dim, spec.head_dim)
-            if kd.paged else None,
+            pages(n, kd, spec.v_dim, spec.head_dim) if kd.paged else None,
             rows(n, tail_row[kd.mixer], dtype) if kd.recurrent else None,
         )
 
@@ -724,8 +841,11 @@ def _put_pools(spec: ModelSpec, k_pages, v_pages, li: int, kp, vp):
     """The cache with layer ``li``'s pools replaced by ``kp``, ``vp``."""
     if not spec.layer_kinds:
         return kp, vp
-    ki = spec.layer_pattern[li]
+    return _put_kind(k_pages, v_pages, spec.layer_pattern[li], kp, vp)
 
+
+def _put_kind(k_pages, v_pages, ki: int, kp, vp):
+    """The cache with kind ``ki``'s entries replaced by ``kp``, ``vp``."""
     def put(side, pool):
         return side._replace(
             pools=side.pools[:ki] + (pool,) + side.pools[ki + 1:]
@@ -876,10 +996,27 @@ def _scope(name: str | None):
     return jax.named_scope(name) if name else contextlib.nullcontext()
 
 
+def layer_norm(x: jax.Array, w: jax.Array, b: jax.Array, eps: float):
+    """LayerNorm over the last axis, a gain and a bias, in float32."""
+    xf = x.astype(jnp.float32)
+    xf = xf - jnp.mean(xf, axis=-1, keepdims=True)
+    var = jnp.mean(xf * xf, axis=-1, keepdims=True)
+    return (xf * jax.lax.rsqrt(var + eps)).astype(x.dtype) * w + b
+
+
+def _any_norm(spec: ModelSpec, x: jax.Array, p: Params, name: str):
+    """The model's norm of ``x`` under the gain ``p[name]``: RMSNorm, or
+    LayerNorm with the bias ``p[name + "_bias"]`` where ``ModelSpec.norm``
+    is "layer" (such a model draws and loads a bias beside every gain)."""
+    if spec.norm == "layer":
+        return layer_norm(x, p[name], p[name + "_bias"], spec.rms_eps)
+    return rms_norm(x, p[name], spec.rms_eps)
+
+
 @jax.named_scope(SCOPE_NORM)
-def _norm(x: jax.Array, w: jax.Array, eps: float) -> jax.Array:
+def _norm(spec: ModelSpec, x: jax.Array, lp: Params, name: str) -> jax.Array:
     """A layer's input norm, under its region's name."""
-    return rms_norm(x, w, eps)
+    return _any_norm(spec, x, lp, name)
 
 
 @jax.named_scope(SCOPE_RESIDUAL)
@@ -1043,9 +1180,7 @@ def _ctx_attention(
     with _scope(attn_scope(spec, li)):
         return paged_prefill_attention(
             q, k_pool, v_pool, lj, block_table, positions[0], kv_len,
-            head_dim=spec.head_dim, v_dim=spec.v_dim,
-            kv_heads=kd.num_kv_heads, window=kd.window,
-            sinks=lp.get("sinks"),
+            **_row_dims(spec, li), window=kd.window, sinks=lp.get("sinks"),
             # the EXACT in-flight rows over a quantised pool's read-back
             # (the XLA mirror of the fused kernel's analytic new-token
             # merge): the new tokens attend to each other at full
@@ -1056,7 +1191,7 @@ def _ctx_attention(
 
 @jax.named_scope(SCOPE_HEAD)
 def _logits(spec: ModelSpec, params: Params, x: jax.Array) -> jax.Array:
-    x = rms_norm(x, params["final_norm"], spec.rms_eps)
+    x = _any_norm(spec, x, params, "final_norm")
     head = params["embed"].T if spec.tie_embeddings else params["lm_head"]
     return _times((x @ head).astype(jnp.float32), spec.lm_head_multiplier)
 
@@ -1417,6 +1552,271 @@ def _conv_whole(spec: ModelSpec, kd, lp: Params, h: jax.Array) -> jax.Array:
     return _conv_out(lp, y[0])
 
 
+# ---------------------------------------------------- the selective scan
+
+
+def _scan_inputs(spec: ModelSpec, lp: Params, h: jax.Array, tail: jax.Array):
+    """A selective-scan (Mamba-1) mixer's operands from the layer's normed
+    input, under the SSD mixer's region names. h: [N, T, d]; tail: [N,
+    taps - 1, C], the ``x`` half of the input projection for the ``taps -
+    1`` tokens before (zeros at a sequence's start). Returns (z [N, T, C],
+    x [N, T, C] (convolved, SiLU), B, C [N, T, S], dt [N, T, C] float32
+    with the softplus applied, ext [N, taps - 1 + T, C]: the projection
+    with the tail in front, of which the caller keeps the new tail)."""
+    f32 = jnp.float32
+    T = h.shape[1]
+    R, S = spec.scan_dt_rank, spec.scan_state
+    with jax.named_scope(SCOPE_SSM_PROJ):
+        xs, z = jnp.split(h @ lp["scan_in"], 2, axis=-1)
+    with jax.named_scope(SCOPE_SSM_CONV):
+        ext = jnp.concatenate([tail.astype(xs.dtype), xs], axis=1)
+        conv = _causal_taps(lp["scan_conv"], ext, T) + (
+            lp["scan_conv_bias"].astype(f32))
+        x = jax.nn.silu(conv).astype(h.dtype)
+    with jax.named_scope(SCOPE_SSM_PROJ):
+        dr, B, C = jnp.split(x @ lp["scan_x"], (R, R + S), axis=-1)
+        dt = dr @ lp["scan_dt"]
+    with jax.named_scope(SCOPE_SSM_GATES):
+        dt = jax.nn.softplus(dt.astype(f32) + lp["scan_dt_bias"])
+    return z, x, B, C, dt, ext
+
+
+def _scan_out(lp: Params, y: jax.Array, z: jax.Array):
+    """y: [..., C] float32, z: [..., C] -> (the mixer's output [..., d]:
+    the gate ``silu(z)``, the output projection; ``y`` itself in the
+    activations' dtype: the MEMORY a GMU layer reads, taken with the ``D``
+    term and before the gate)."""
+    with jax.named_scope(SCOPE_SSM_GATES):
+        m = y.astype(z.dtype)
+        g = (y * jax.nn.silu(z.astype(jnp.float32))).astype(z.dtype)
+    with jax.named_scope(SCOPE_SSM_PROJ):
+        return g @ lp["scan_out"], m
+
+
+def _scan_prefill(
+    spec: ModelSpec, kd, lp: Params, h: jax.Array, s_pool, c_pool, lj: int,
+    idx: jax.Array, fresh: jax.Array, num_tokens: jax.Array,
+):
+    """A selective-scan mixer over N sequences' new tokens, from and to
+    their state rows. h: [N, T, d]; idx, fresh, num_tokens: [N]. Returns
+    ((out [N, T, d], memory [N, T, C]), s_pool, c_pool)."""
+    T = h.shape[1]
+    with jax.named_scope(SCOPE_QKV):
+        with jax.named_scope(SCOPE_SSM_CONV):
+            tail = jnp.where(fresh[:, None, None], 0, c_pool[lj, idx])
+        z, x, B, C, dt, ext = _scan_inputs(spec, lp, h, tail)
+        with jax.named_scope(SCOPE_SSM_GATES):
+            # a padded token leaves the state as it was
+            real = jnp.arange(T)[None, :] < num_tokens[:, None]
+            dt = jnp.where(real[..., None], dt, 0.0)
+    with jax.named_scope(SCOPE_KV):
+        y, s_pool = scan_chunk_prefill(
+            x, dt, -jnp.exp(lp["scan_a_log"]), B, C, lp["scan_d"], s_pool,
+            idx, fresh, layer=lj,
+        )
+        # the new tail: the projections of the last taps - 1 REAL tokens
+        new_tail = _new_tail(ext, num_tokens, spec.scan_conv - 1)
+        c_pool = c_pool.at[lj, idx].set(new_tail.astype(c_pool.dtype))
+    with jax.named_scope(SCOPE_OUT):
+        return _scan_out(lp, y, z), s_pool, c_pool
+
+
+def _scan_decode(
+    spec: ModelSpec, kd, lp: Params, h: jax.Array, s_pool, c_pool, lj: int,
+    idx: jax.Array,
+):
+    """A selective-scan mixer's decode step over the slots' state rows. h:
+    [B, d]; idx: [B] (the trash row for a slot that owns none). Returns
+    ((out [B, d], memory [B, C]), s_pool, c_pool)."""
+    with jax.named_scope(SCOPE_QKV):
+        z, x, B, C, dt, ext = _scan_inputs(
+            spec, lp, h[:, None], c_pool[lj, idx])
+    with jax.named_scope(SCOPE_KV):
+        y, s_pool, c_pool = scan_decode_step(
+            s_pool, c_pool, idx, x[:, 0], dt[:, 0],
+            -jnp.exp(lp["scan_a_log"]), B[:, 0], C[:, 0], lp["scan_d"],
+            ext[:, 1:], layer=lj,
+        )
+    with jax.named_scope(SCOPE_OUT):
+        return _scan_out(lp, y, z[:, 0]), s_pool, c_pool
+
+
+def _scan_whole(spec: ModelSpec, kd, lp: Params, h: jax.Array):
+    """A selective-scan mixer over one whole sequence from an empty state,
+    keeping none (embeddings, ``reference_forward``). h: [T, d] -> (out
+    [T, d], memory [T, C])."""
+    S, C = spec.scan_state, spec.scan_inner
+    tail = jnp.zeros((1, spec.scan_conv - 1, C), h.dtype)
+    z, x, B, Cm, dt, _ = _scan_inputs(spec, lp, h[None], tail)
+    y, _ = scan_chunk_prefill(
+        x, dt, -jnp.exp(lp["scan_a_log"]), B, Cm, lp["scan_d"],
+        jnp.zeros((1, 2, S, C), jnp.float32), jnp.zeros((1,), jnp.int32),
+        jnp.ones((1,), bool), layer=0,
+    )
+    return _scan_out(lp, y[0], z[0])
+
+
+# --------------------------------------------------- differential attention
+# arXiv:2410.05258, as Phi-4-mini-flash pairs it: the even query heads q1
+# and the odd ones q2, the even KV heads k1 and the odd ones k2, a value a
+# PAIR of KV heads ``V_j = [v_2j | v_2j+1]``; query pair i reads KV pair i //
+# (pairs a KV pair):
+#
+#     o_i = (1 - lam0) rms(softmax(q1_i k1^T / sqrt D) V - lam softmax(q2_i k2^T / sqrt D) V)
+#
+# A pair of KV heads is ONE row of the kind's pool, ``[k1 | k2]`` and ``[v_2j
+# | v_2j+1]`` (``init_cache``), and a query head stands in the lanes of its
+# own K with zeros in the other's, which add 0 to every score: both maps are
+# then ORDINARY attention of ``num_heads`` queries ``2 D`` wide over
+# ``num_kv_heads / 2`` heads, at the scale ``1 / sqrt D``, and every reader
+# (the decode kernels, the prefill walk, ``causal_attention``) serves them in
+# one call that reads K and V once.
+
+
+def _pair_rows(spec: ModelSpec, li: int, q, k=None, v=None):
+    """A differential layer's q [..., H, D], k [..., KH, D], v [..., KH,
+    Dv] as its pool's rows see them: q [..., H, 2 D] with the pair's two
+    maps' heads side by side a KV pair (``[q1.., q2..]``: the readers'
+    grouping by ``H // pool heads`` then needs no more), k [..., KH / 2, 2
+    D], v [..., KH / 2, 2 Dv]. Any other layer's come back as they are."""
+    kd = spec.kind(li)
+    if not kd.differential:
+        return q, k, v
+    *lead, H, D = q.shape
+    P = kd.num_kv_heads // 2  # KV pairs
+    g = H // (2 * P)  # query pairs a KV pair
+    # [..., KV pair, query pair, map, D] -> [..., KV pair, map, query pair]
+    q = q.reshape(*lead, P, g, 2, D).swapaxes(-2, -3)
+    keep = [(0, 0)] * (q.ndim - 1)
+    q = jnp.concatenate([
+        jnp.pad(q[..., :1, :, :], keep + [(0, D)]),  # [q1 | 0]
+        jnp.pad(q[..., 1:, :, :], keep + [(D, 0)]),  # [0 | q2]
+    ], axis=-3).reshape(*lead, H, 2 * D)
+    if k is not None:
+        k = k.reshape(*lead, P, -1)
+        v = v.reshape(*lead, P, -1)
+    return q, k, v
+
+
+def _row_dims(spec: ModelSpec, li: int) -> dict:
+    """What the prefill walk is told of layer ``li``'s rows: the model's
+    heads, or a differential layer's pairs (``_pair_rows``: half the KV
+    heads, twice as wide, at the heads' own scale)."""
+    p = 2 if spec.kind(li).differential else 1
+    return dict(
+        head_dim=p * spec.head_dim, v_dim=p * spec.v_dim,
+        kv_heads=spec.kind(li).num_kv_heads // p,
+        scale=_pair_scale(spec, li))
+
+
+def _pair_scale(spec: ModelSpec, li: int) -> float | None:
+    """The softmax scale of layer ``li`` where its rows are a pair wide
+    (``1 / sqrt(head_dim)``, not the rows' width); None: the readers'."""
+    return spec.head_dim ** -0.5 if spec.kind(li).differential else None
+
+
+def lambda_init(layer_id: int) -> float:
+    """Differential attention's ``lambda_init`` at a PUBLISHED layer."""
+    return 0.8 - 0.6 * math.exp(-0.3 * layer_id)
+
+
+@jax.named_scope(SCOPE_ATTN_DIFF)
+def _diff_out(spec: ModelSpec, li: int, lp: Params, attn: jax.Array):
+    """The two maps' outputs ``attn`` [..., H, 2 Dv] in ``_pair_rows``'
+    order -> the pairs' [..., H / 2, 2 Dv] in the model's: ``(1 - lam0)
+    rms(a1 - lam a2)`` under the layer's lambdas and the one gain a layer,
+    in float32. Any other layer's come back as they are."""
+    kd = spec.kind(li)
+    if not kd.differential:
+        return attn
+    f32 = jnp.float32
+    *lead, H, W = attn.shape
+    P = kd.num_kv_heads // 2
+    a = attn.astype(f32).reshape(*lead, P, 2, H // (2 * P), W)
+    lam0 = lambda_init(spec.layer_id(li))
+    lam = (
+        jnp.exp(jnp.sum(lp["lambda_q1"].astype(f32) * lp["lambda_k1"]))
+        - jnp.exp(jnp.sum(lp["lambda_q2"].astype(f32) * lp["lambda_k2"]))
+        + lam0
+    )
+    o = a[..., 0, :, :] - lam * a[..., 1, :, :]
+    var = jnp.mean(o * o, axis=-1, keepdims=True)
+    o = o * jax.lax.rsqrt(var + spec.rms_eps) * lp["subln"].astype(f32)
+    return (o * (1.0 - lam0)).astype(attn.dtype).reshape(*lead, H // 2, W)
+
+
+# -------------------------------------------- the layers that write no cache
+# SambaY's cross-decoder (arXiv:2507.06607): the layers from
+# ``ModelSpec.carried_from`` up mix what the layers below left for the SAME
+# token (a GMU gates the memory layer's output) or read another layer's
+# pages with queries of their own (``LayerKind.reads``), so they write
+# nothing a later token needs and a prefill runs them for a sequence's
+# last row alone.
+
+
+def _gmu(lp: Params, h: jax.Array, m: jax.Array) -> jax.Array:
+    """A Gated Memory Unit: ``W_2 (silu(W_1 h) * m)``, ``m`` the memory
+    layer's output for the same rows."""
+    with jax.named_scope(SCOPE_GMU):
+        gate = jax.nn.silu((h @ lp["gmu_in"]).astype(jnp.float32))
+        return (gate.astype(h.dtype) * m) @ lp["gmu_out"]
+
+
+def _carried_layers(
+    spec: ModelSpec, params: Params, x: jax.Array, mem: dict, k_pages,
+    v_pages, cross, phase: int, counted: jax.Array, mesh: Mesh | None,
+):
+    """The layers from ``spec.carried_from`` up over the rows ``x`` [R, d]
+    (a decode step's slots, a prefill's last row a sequence, every row of
+    a whole-sequence pass), ONE body for every program. ``mem["m"]`` [R,
+    C]: the memory layer's output for those rows. ``cross(li, q, k_pool,
+    v_pool, lj) -> (attn, k_pool, v_pool)``: the program's attention of the
+    rows' queries q [R, H, 2 D] (``_pair_rows``) over layer ``lj`` of the
+    pools of the kind the layer reads. Returns (x, k_pages, v_pages)."""
+    for li in range(spec.carried_from, spec.num_layers):
+        lp, kd = params["layers"][li], spec.kind(li)
+        h = _norm(spec, x, lp, "attn_norm")
+        if kd.mixer == "gmu":
+            mix = _gmu(lp, h, mem["m"])
+        else:
+            with jax.named_scope(SCOPE_QKV):
+                q = (h @ lp["wq"] + lp["bq"]).reshape(
+                    *h.shape[:-1], spec.num_heads, spec.head_dim)
+                q, _, _ = _pair_rows(spec, li, q)
+            ki, lj = kd.reads
+            with jax.named_scope(SCOPE_KV):
+                attn, kp, vp = cross(
+                    li, q, kind_pages(spec, k_pages, ki),
+                    kind_pages(spec, v_pages, ki), lj)
+            if kp is not None:
+                k_pages, v_pages = _put_kind(k_pages, v_pages, ki, kp, vp)
+            mix = _o_proj(spec, lp, _diff_out(spec, li, lp, attn), h)
+        x = _residual(spec, lp, x, mix, "post_attn_norm")
+        h = _norm(spec, x, lp, "mlp_norm")
+        f, k_pages = _ffn_counting(
+            spec, li, lp, h, k_pages, phase, counted, mesh)
+        x = _residual(spec, lp, x, f, "post_mlp_norm")
+    return x, k_pages, v_pages
+
+
+def _last_row_cross(spec: ModelSpec, block_tables, kv_len):
+    """``_carried_layers``' ``cross`` of a prefill program: each
+    sequence's ONE row, the last real one at ``kv_len - 1``, over the
+    pages the layers below wrote in this call and before it, through the
+    prefill walk. block_tables: [N, P]; kv_len: [N]."""
+    def cross(li, q, k_pool, v_pool, lj):
+        with jax.named_scope(SCOPE_ATTN_CROSS):
+            attn = jax.vmap(
+                lambda q_i, bt_i, n_i: paged_prefill_attention(
+                    q_i[None], k_pool, v_pool, lj, bt_i,
+                    jnp.maximum(n_i - 1, 0), n_i, **_row_dims(spec, li),
+                )[0]
+            )(q, block_tables, kv_len)
+        return attn, None, None
+
+    return cross
+
+
 # what a recurrent mixer is called with, by ``LayerKind.mixer``: (prefill
 # over [N, T, d] rows, decode step over [B, d] slots, a whole sequence),
 # each ``(spec, kind, layer weights, ...)``
@@ -1424,10 +1824,24 @@ _RECURRENT = {
     "kda": (_kda_prefill, _kda_decode, _kda_whole),
     "ssd": (_ssd_prefill, _ssd_decode, _ssd_whole),
     "conv": (_conv_prefill, _conv_decode, _conv_whole),
+    "scan": (_scan_prefill, _scan_decode, _scan_whole),
 }
 
 
-def _mixers(spec: ModelSpec, li: int, kp, vp, attend, recur, latent=None):
+def _keep_memory(spec: ModelSpec, li: int, rec, mem: dict | None):
+    """A recurrent mixer's output; a selective scan hands back (output,
+    its output before the gate), of which the model's memory layer leaves
+    the second in ``mem["m"]`` for the layers above."""
+    if spec.kind(li).mixer != "scan":
+        return rec
+    rec, m = rec
+    if li == spec.memory_layer:
+        mem["m"] = m
+    return rec
+
+
+def _mixers(spec: ModelSpec, li: int, kp, vp, attend, recur, latent=None,
+            mem: dict | None = None):
     """Layer ``li``'s token mixers over its normed input, ONE body for
     every kind: softmax attention over the kind's pages where it has KV
     heads (``attend(k_pool, v_pool) -> (out, k_pool, v_pool)``), latent
@@ -1436,8 +1850,10 @@ def _mixers(spec: ModelSpec, li: int, kp, vp, attend, recur, latent=None):
     the recurrent mixer over its state rows where it has one (``recur(fn,
     kind, states, tails) -> (out, states, tails)``, ``fn`` the mixer's
     prefill and decode forms), their outputs summed where it has both. kp,
-    vp: the kind's entries of the cache's two sides. Returns (mix, kp,
-    vp)."""
+    vp: the kind's entries of the cache's two sides. A selective-scan
+    mixer also hands back its output before the gate, which the model's
+    memory layer leaves in ``mem["m"]`` for the layers above
+    (``_carried_layers``). Returns (mix, kp, vp)."""
     kd = spec.kind(li)
     (k_pg, s_pool), (v_pg, c_pool) = _entry_parts(kd, kp), _entry_parts(kd, vp)
     mix = None
@@ -1447,6 +1863,7 @@ def _mixers(spec: ModelSpec, li: int, kp, vp, attend, recur, latent=None):
         mix, k_pg, v_pg = attend(k_pg, v_pg)
     if kd.recurrent:
         rec, s_pool, c_pool = recur(_RECURRENT[kd.mixer], kd, s_pool, c_pool)
+        rec = _keep_memory(spec, li, rec, mem)
         mix = rec if mix is None else mix + rec
     return mix, _entry_of(k_pg, s_pool), _entry_of(v_pg, c_pool)
 
@@ -1517,12 +1934,14 @@ def prefill_forward_impl(
         )
         k_pages = k_pages._replace(rows=rows)
 
-    for li, lp in enumerate(params["layers"]):
-        h = _norm(x, lp["attn_norm"], spec.rms_eps)
+    mem: dict = {}  # what the layers leave for those above (_mixers)
+    for li, lp in enumerate(params["layers"][:spec.carried_from]):
+        h = _norm(spec, x, lp, "attn_norm")
         kp, vp, lj = _layer_pools(spec, k_pages, v_pages, li)
 
         def attend(kp, vp, li=li, lp=lp, lj=lj, h=h):
-            q, k, v = _attn_qkv(spec, li, lp, h, positions)
+            q, k, v = _pair_rows(
+                spec, li, *_attn_qkv(spec, li, lp, h, positions))
             with jax.named_scope(SCOPE_KV):
                 kp = _set_page_tiles(kp, lj, safe_pg, k, page_size, valid_tok)
                 vp = _set_page_tiles(vp, lj, safe_pg, v, page_size, valid_tok)
@@ -1530,14 +1949,15 @@ def prefill_forward_impl(
                     spec, li, lp, q, k, v, kp, vp, lj, block_table,
                     positions, kv_len,
                 )
-            return _o_proj(spec, lp, attn, h), kp, vp
+            return _o_proj(
+                spec, lp, _diff_out(spec, li, lp, attn), h), kp, vp
 
         def recur(fn, kd, sp, cp, lp=lp, lj=lj, h=h):
             mix, sp, cp = fn[0](
                 spec, kd, lp, h[None], sp, cp, lj, idx, fresh,
                 num_tokens[None],
             )
-            return mix[0], sp, cp
+            return jax.tree.map(lambda y: y[0], mix), sp, cp
 
         def latent(pool, lp=lp, lj=lj, h=h):
             from dynamo_tpu.models import mla
@@ -1549,15 +1969,26 @@ def prefill_forward_impl(
             )
             return mix[0], pool
 
-        mix, kp, vp = _mixers(spec, li, kp, vp, attend, recur, latent)
+        mix, kp, vp = _mixers(spec, li, kp, vp, attend, recur, latent, mem)
         k_pages, v_pages = _put_pools(spec, k_pages, v_pages, li, kp, vp)
         x = _residual(spec, lp, x, mix, "post_attn_norm")
-        h = _norm(x, lp["mlp_norm"], spec.rms_eps)
+        h = _norm(spec, x, lp, "mlp_norm")
         f, k_pages = _ffn_counting(
             spec, li, lp, h, k_pages, COUNT_PREFILL, real, mesh
         )
         x = _residual(spec, lp, x, f, "post_mlp_norm")
 
+    if spec.carried_from < spec.num_layers:
+        # the layers that write no cache, for the last real row alone
+        last = jnp.clip(num_tokens - 1, 0, T - 1)
+        x_up, k_pages, v_pages = _carried_layers(
+            spec, params, x[last][None], {"m": mem["m"][last][None]},
+            k_pages, v_pages,
+            _last_row_cross(spec, block_table[None], kv_len[None]),
+            COUNT_PREFILL, (num_tokens > 0)[None], mesh,
+        )
+        logits = _logits(spec, params, x_up[0])
+        return _replicate(logits, mesh), k_pages, v_pages, _no_drops(mesh)
     with jax.named_scope(SCOPE_HEAD):
         last = jnp.clip(num_tokens - 1, 0, T - 1)
         logits = _logits(spec, params, x[last])  # [V]
@@ -1650,12 +2081,14 @@ def prefill_forward_batch_impl(
         )
         k_pages = k_pages._replace(rows=rows)
 
-    for li, lp in enumerate(params["layers"]):
-        h = _norm(x, lp["attn_norm"], spec.rms_eps)
+    mem: dict = {}  # what the layers leave for those above (_mixers)
+    for li, lp in enumerate(params["layers"][:spec.carried_from]):
+        h = _norm(spec, x, lp, "attn_norm")
         kp, vp, lj = _layer_pools(spec, k_pages, v_pages, li)
 
         def attend(kp, vp, li=li, lp=lp, lj=lj, h=h):
-            q, k, v = _attn_qkv(spec, li, lp, h, positions)
+            q, k, v = _pair_rows(
+                spec, li, *_attn_qkv(spec, li, lp, h, positions))
             with jax.named_scope(SCOPE_KV):
                 kp = _set_page_tiles(kp, lj, safe_pg, k, page_size, valid_tok)
                 vp = _set_page_tiles(vp, lj, safe_pg, v, page_size, valid_tok)
@@ -1666,7 +2099,8 @@ def prefill_forward_batch_impl(
                         kvl_i,
                     )
                 )(q, k, v, block_tables, positions, kv_len)
-            return _o_proj(spec, lp, attn, h), kp, vp
+            return _o_proj(
+                spec, lp, _diff_out(spec, li, lp, attn), h), kp, vp
 
         def recur(fn, kd, sp, cp, lp=lp, lj=lj, h=h):
             return fn[0](spec, kd, lp, h, sp, cp, lj, idx, fresh, num_tokens)
@@ -1679,10 +2113,10 @@ def prefill_forward_batch_impl(
                 block_tables, start_pos, kv_len, mesh,
             )
 
-        mix, kp, vp = _mixers(spec, li, kp, vp, attend, recur, latent)
+        mix, kp, vp = _mixers(spec, li, kp, vp, attend, recur, latent, mem)
         k_pages, v_pages = _put_pools(spec, k_pages, v_pages, li, kp, vp)
         x = _residual(spec, lp, x, mix, "post_attn_norm")
-        h = _norm(x, lp["mlp_norm"], spec.rms_eps)
+        h = _norm(spec, x, lp, "mlp_norm")
         f, k_pages = _ffn_counting(
             spec, li, lp, h.reshape(N * T, -1), k_pages, COUNT_PREFILL,
             real.reshape(N * T), mesh,
@@ -1690,6 +2124,17 @@ def prefill_forward_batch_impl(
         x = _residual(
             spec, lp, x, f.reshape(N, T, -1), "post_mlp_norm")
 
+    if spec.carried_from < spec.num_layers:
+        # the layers that write no cache, for each sequence's last real row
+        last = jnp.clip(num_tokens - 1, 0, T - 1)[:, None, None]
+        x_up, k_pages, v_pages = _carried_layers(
+            spec, params, jnp.take_along_axis(x, last, axis=1)[:, 0],
+            {"m": jnp.take_along_axis(mem["m"], last, axis=1)[:, 0]},
+            k_pages, v_pages, _last_row_cross(spec, block_tables, kv_len),
+            COUNT_PREFILL, num_tokens > 0, mesh,
+        )
+        logits = _logits(spec, params, x_up)
+        return _replicate(logits, mesh), k_pages, v_pages, _no_drops(mesh)
     with jax.named_scope(SCOPE_HEAD):
         last = jnp.clip(num_tokens - 1, 0, T - 1)  # [N]
         x_last = jnp.take_along_axis(x, last[:, None, None], axis=1)[:, 0]
@@ -1742,7 +2187,7 @@ def prefill_forward_ring_impl(
     x = jax.lax.with_sharding_constraint(x, sp_spec)
 
     for li, lp in enumerate(params["layers"]):
-        h = _norm(x, lp["attn_norm"], spec.rms_eps)
+        h = _norm(spec, x, lp, "attn_norm")
         q, k, v = _attn_qkv(spec, li, lp, h, idx)
         kp, vp, lj = _layer_pools(spec, k_pages, v_pages, li)
         kp = _set_page_tiles(kp, lj, safe_pg, k, page_size, valid_tok)
@@ -1751,7 +2196,7 @@ def prefill_forward_ring_impl(
         attn = ring_attention(q, k, v, mesh=mesh)
         x = _residual(
             spec, lp, x, _o_proj(spec, lp, attn, h), "post_attn_norm")
-        h = _norm(x, lp["mlp_norm"], spec.rms_eps)
+        h = _norm(spec, x, lp, "mlp_norm")
         f = _ffn(spec, lp, h, mesh=mesh, li=li)
         x = _residual(spec, lp, x, f, "post_mlp_norm")
         x = jax.lax.with_sharding_constraint(x, sp_spec)
@@ -1824,7 +2269,7 @@ def verify_forward_impl(
     kv_len = start_pos + num_tokens  # [N]
 
     for li, lp in enumerate(params["layers"]):
-        h = _norm(x, lp["attn_norm"], spec.rms_eps)
+        h = _norm(spec, x, lp, "attn_norm")
         q, k, v = _attn_qkv(spec, li, lp, h, positions)
         kp, vp, lj = _layer_pools(spec, k_pages, v_pages, li)
         if is_quant(kp):
@@ -1855,7 +2300,7 @@ def verify_forward_impl(
         k_pages, v_pages = _put_pools(spec, k_pages, v_pages, li, kp, vp)
         x = _residual(
             spec, lp, x, _o_proj(spec, lp, attn, h), "post_attn_norm")
-        h = _norm(x, lp["mlp_norm"], spec.rms_eps)
+        h = _norm(spec, x, lp, "mlp_norm")
         x = _residual(spec, lp, x, _ffn(
             spec, lp, h.reshape(N * W, -1), mesh=mesh, li=li
         ).reshape(N, W, -1), "post_mlp_norm")
@@ -1917,13 +2362,21 @@ def decode_forward_impl(
         schedule = latent_decode_schedule(
             latent_pool(spec, k_pages), block_tables, seq_lens, mesh)
     x = _embed(params, tokens, spec)  # [B, d]
+    mem: dict = {}  # what the layers leave for those above (_mixers)
+    reads = {spec.kind(li).reads for li in range(spec.num_layers)} - {()}
 
-    for li, lp in enumerate(params["layers"]):
-        h = _norm(x, lp["attn_norm"], spec.rms_eps)
+    for li, lp in enumerate(params["layers"][:spec.carried_from]):
+        h = _norm(spec, x, lp, "attn_norm")
         kp, vp, lj = _layer_pools(spec, k_pages, v_pages, li)
 
         def attend(kp, vp, li=li, lp=lp, lj=lj, h=h):
-            q, k, v = _attn_qkv(spec, li, lp, h, positions)
+            q, k, v = _pair_rows(
+                spec, li, *_attn_qkv(spec, li, lp, h, positions))
+            if reads and spec.pool_slot(li) in reads:
+                # the step's new rows of the layer whose pages the cross
+                # layers read: the decode kernel scores the new token from
+                # its rows, not from the page they land in
+                mem["kv"] = (k, v)
             # KV append + paged attention in ONE kernel per layer on the
             # Pallas path (ops/pallas/fused_decode.py — halves the decode
             # program's kernel-launch count); scatter + gather attention
@@ -1934,8 +2387,10 @@ def decode_forward_impl(
                     safe_page, offset, layer=lj, mesh=mesh,
                     window=spec.kind(li).window, sinks=lp.get("sinks"),
                     scope=attn_scope(spec, li),
+                    scale=_pair_scale(spec, li),
                 )
-            return _o_proj(spec, lp, attn, h), kp, vp
+            return _o_proj(
+                spec, lp, _diff_out(spec, li, lp, attn), h), kp, vp
 
         def recur(fn, kd, sp, cp, lp=lp, lj=lj, h=h):
             return fn[1](spec, kd, lp, h, sp, cp, lj, state_idx)
@@ -1948,15 +2403,31 @@ def decode_forward_impl(
                 safe_page, offset, schedule, mesh,
             )
 
-        mix, kp, vp = _mixers(spec, li, kp, vp, attend, recur, latent)
+        mix, kp, vp = _mixers(spec, li, kp, vp, attend, recur, latent, mem)
         k_pages, v_pages = _put_pools(spec, k_pages, v_pages, li, kp, vp)
         x = _residual(spec, lp, x, mix, "post_attn_norm")
-        h = _norm(x, lp["mlp_norm"], spec.rms_eps)
+        h = _norm(spec, x, lp, "mlp_norm")
         f, k_pages = _ffn_counting(
             spec, li, lp, h, k_pages, COUNT_DECODE, active, mesh
         )
         x = _residual(spec, lp, x, f, "post_mlp_norm")
 
+    if spec.carried_from < spec.num_layers:
+
+        def cross(li, q, k_pool, v_pool, lj):
+            # the read layer's decode kernel with NO write: a row bound
+            # for the trash page is not written, and the step's own token
+            # is scored from the rows that layer made (``mem["kv"]``)
+            return decode_update_attention(
+                q, k_pool, v_pool, *mem["kv"], block_tables, seq_lens,
+                jnp.zeros_like(safe_page), offset, layer=lj, mesh=mesh,
+                scope=SCOPE_ATTN_CROSS, scale=_pair_scale(spec, li),
+            )
+
+        x, k_pages, v_pages = _carried_layers(
+            spec, params, x, mem, k_pages, v_pages, cross, COUNT_DECODE,
+            active, mesh,
+        )
     logits = _logits(spec, params, x)  # [B, V]
     return logits, k_pages, v_pages
 
@@ -2149,11 +2620,14 @@ insert_kv_pages = jax.jit(_insert_kv_pages_impl, donate_argnums=(0, 1))
 # ------------------------------------------------------------- embeddings
 
 
-def _whole_mixer(spec: ModelSpec, li: int, lp: Params, h, positions, n):
+def _whole_mixer(spec: ModelSpec, li: int, lp: Params, h, positions, n,
+                 mem: dict | None = None):
     """Layer ``li``'s mixer over one whole sequence with no cache (h: [T,
     d]; ``n`` real tokens): plain causal attention, a recurrent mixer
     from an empty state, or both summed, as the kind has them (a padded
-    tail cannot reach a real token either way)."""
+    tail cannot reach a real token either way). ``mem``: what the layers
+    leave for those above, as ``_mixers`` keeps it, with the keys and
+    values of a layer that others read."""
     kd = spec.kind(li)
     mix = None
     if kd.latent:
@@ -2165,15 +2639,46 @@ def _whole_mixer(spec: ModelSpec, li: int, lp: Params, h, positions, n):
             & (positions[None, :] < n),
         )
     elif kd.paged:
-        q, k, v = _attn_qkv(spec, li, lp, h, positions)
+        q, k, v = _pair_rows(
+            spec, li, *_attn_qkv(spec, li, lp, h, positions))
+        if mem is not None:
+            mem[spec.pool_slot(li)] = (k, v)
         attn = causal_attention(
             q, k, v, positions, n, window=kd.window, sinks=lp.get("sinks"),
+            scale=_pair_scale(spec, li),
         )
-        mix = _o_proj(spec, lp, attn, h)
+        mix = _o_proj(spec, lp, _diff_out(spec, li, lp, attn), h)
     if kd.recurrent:
-        rec = _RECURRENT[kd.mixer][2](spec, kd, lp, h)
+        rec = _keep_memory(
+            spec, li, _RECURRENT[kd.mixer][2](spec, kd, lp, h), mem)
         mix = rec if mix is None else mix + rec
     return mix
+
+
+def _whole_layers(spec: ModelSpec, params: Params, x, positions, n):
+    """Every layer over one whole sequence with no cache, EVERY row
+    through every layer (embeddings, ``reference_forward``). x: [T, d]."""
+    mem = {} if spec.carried_from < spec.num_layers else None
+    for li, lp in enumerate(params["layers"][:spec.carried_from]):
+        h = _norm(spec, x, lp, "attn_norm")
+        mix = _whole_mixer(spec, li, lp, h, positions, n, mem)
+        x = _residual(spec, lp, x, mix, "post_attn_norm")
+        h = _norm(spec, x, lp, "mlp_norm")
+        x = _residual(spec, lp, x, _ffn(spec, lp, h, li=li), "post_mlp_norm")
+    if mem is not None:
+
+        def cross(li, q, k_pool, v_pool, lj):
+            k, v = mem[spec.kind(li).reads]
+            return causal_attention(
+                q, k, v, positions, n, scale=_pair_scale(spec, li),
+            ), None, None
+
+        sides = KindPools((None,) * len(spec.layer_kinds), jnp.zeros((0,)))
+        x, _, _ = _carried_layers(
+            spec, params, x, mem, sides, sides, cross, COUNT_PREFILL,
+            positions < n, None,
+        )
+    return x
 
 
 def embed_forward_impl(
@@ -2190,13 +2695,8 @@ def embed_forward_impl(
     T = tokens.shape[0]
     positions = jnp.arange(T)
     x = _embed(params, tokens, spec)
-    for li, lp in enumerate(params["layers"]):
-        h = _norm(x, lp["attn_norm"], spec.rms_eps)
-        mix = _whole_mixer(spec, li, lp, h, positions, num_tokens)
-        x = _residual(spec, lp, x, mix, "post_attn_norm")
-        h = _norm(x, lp["mlp_norm"], spec.rms_eps)
-        x = _residual(spec, lp, x, _ffn(spec, lp, h, li=li), "post_mlp_norm")
-    xn = rms_norm(x, params["final_norm"], spec.rms_eps).astype(jnp.float32)
+    x = _whole_layers(spec, params, x, positions, num_tokens)
+    xn = _any_norm(spec, x, params, "final_norm").astype(jnp.float32)
     mask = (positions < num_tokens)[:, None].astype(jnp.float32)
     pooled = (xn * mask).sum(axis=0) / jnp.maximum(mask.sum(), 1.0)
     return pooled / jnp.maximum(jnp.linalg.norm(pooled), 1e-9)
@@ -2216,12 +2716,7 @@ def reference_forward(
     T = tokens.shape[0]
     positions = jnp.arange(T)
     x = _embed(params, tokens, spec)
-    for li, lp in enumerate(params["layers"]):
-        h = _norm(x, lp["attn_norm"], spec.rms_eps)
-        mix = _whole_mixer(spec, li, lp, h, positions, jnp.asarray(T))
-        x = _residual(spec, lp, x, mix, "post_attn_norm")
-        h = _norm(x, lp["mlp_norm"], spec.rms_eps)
-        x = _residual(spec, lp, x, _ffn(spec, lp, h, li=li), "post_mlp_norm")
-    xn = rms_norm(x, params["final_norm"], spec.rms_eps)
+    x = _whole_layers(spec, params, x, positions, jnp.asarray(T))
+    xn = _any_norm(spec, x, params, "final_norm")
     head = params["embed"].T if spec.tie_embeddings else params["lm_head"]
     return _times((xn @ head).astype(jnp.float32), spec.lm_head_multiplier)

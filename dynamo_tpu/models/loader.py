@@ -245,6 +245,8 @@ def spec_from_hf_config(cfg: dict, name: str | None = None) -> ModelSpec:
         )
     if model_type == "afmoe":
         extras.update(_afmoe_spec(cfg, hidden))
+    if model_type == "phi4flash":
+        extras.update(_phi4flash_spec(cfg, hidden))
     # YaRN rope scaling (gpt-oss, DeepSeek-R1)
     rs = cfg.get("rope_scaling") or {}
     if (rs.get("rope_type") or rs.get("type")) == "yarn":
@@ -292,6 +294,59 @@ def spec_from_hf_config(cfg: dict, name: str | None = None) -> ModelSpec:
     )
     # a family's own keys win over the llama-family defaults above
     return ModelSpec(**{**kw, **moe, **extras})
+
+
+def _phi4flash_spec(cfg: dict, hidden: int) -> dict:
+    """The ``phi4flash`` family's (Phi-4-mini-flash, SambaY) own fields
+    from its config.json. ``mb_per_layer`` 2: every even layer is a
+    Mamba-1 scan below the model's middle and a GMU above it; the odd
+    layers are differential attention, over a ``sliding_window`` in the
+    self-decoder but for its last layer (``half + 1``), whose pages the
+    cross layers above read. No key gives Mamba-1's sizes: the family's
+    ``d_state`` 16, ``d_conv`` 4, ``expand`` 2, ``dt_rank`` ceil(d / 16).
+    A cut names the published layers it keeps (``layers_kept``) beside
+    the published depth (``published_layers``)."""
+    import math
+
+    from dynamo_tpu.engine.config import LayerKind
+
+    if int(cfg.get("mb_per_layer", 2)) != 2:
+        raise NotImplementedError("phi4flash: mb_per_layer 2 only")
+    if cfg.get("mlp_bias") or cfg.get("lm_head_bias"):
+        raise NotImplementedError("phi4flash: no MLP or head bias")
+    n_layers = int(cfg["num_hidden_layers"])
+    published = int(cfg.get("published_layers") or n_layers)
+    kept = tuple(int(l) for l in cfg.get("layers_kept") or range(n_layers))
+    half = published // 2
+    if len(kept) != n_layers or half not in kept or half + 1 not in kept:
+        raise ValueError(
+            "phi4flash: layers_kept lists num_hidden_layers layers, the "
+            f"memory layer {half} and the shared layer {half + 1} among them")
+    nkv = int(cfg["num_key_value_heads"])
+    window = int(cfg.get("sliding_window") or 0)
+    kinds = (
+        LayerKind(nkv, 0.0, window=window, differential=True),
+        LayerKind(nkv, 0.0, differential=True),
+        LayerKind(0, 0.0, mixer="scan"),
+        LayerKind(0, 0.0, mixer="gmu"),
+        LayerKind(nkv, 0.0, differential=True, reads=(1, 0)),
+    )
+
+    def kind_of(l: int) -> int:
+        if l % 2 == 0:
+            return 2 if l <= half else 3
+        return 4 if l > half + 1 else 1 if l == half + 1 else 0
+
+    return dict(
+        layer_kinds=kinds, layer_pattern=tuple(kind_of(l) for l in kept),
+        layer_ids=kept if kept != tuple(range(n_layers)) or (
+            published != n_layers) else (),
+        norm="layer", use_rope=False,
+        attn_bias=True, rms_eps=float(cfg.get("layer_norm_eps", 1e-5)),
+        scan_inner=2 * hidden, scan_state=16, scan_conv=4,
+        scan_dt_rank=math.ceil(hidden / 16),
+        tie_embeddings=bool(cfg.get("tie_word_embeddings", True)),
+    )
 
 
 def _afmoe_spec(cfg: dict, hidden: int) -> dict:
@@ -360,6 +415,8 @@ def hf_config_from_spec(spec: ModelSpec) -> dict:
         model_type = "deepseek_v3"
     elif "ssd" in spec.mixers:
         model_type = "falcon_h1"
+    elif "scan" in spec.mixers:
+        model_type = "phi4flash"
     elif "kda" in spec.mixers:
         model_type = "solar_open2"
     elif "conv" in spec.mixers:
@@ -456,6 +513,19 @@ def hf_config_from_spec(spec: ModelSpec) -> dict:
             n_group=1, topk_group=1,
         )
         del cfg["num_local_experts"]
+    if model_type == "phi4flash":
+        window = next(k.window for k in spec.layer_kinds if k.window)
+        # the published depth: the memory layer stands at its middle
+        published = 2 * spec.layer_id(spec.memory_layer)
+        cfg.update(
+            mb_per_layer=2, sliding_window=window,
+            layer_norm_eps=spec.rms_eps, mlp_bias=False, lm_head_bias=False,
+        )
+        for key in ("head_dim", "rope_theta", "rms_norm_eps"):
+            del cfg[key]
+        if spec.layer_ids:
+            cfg.update(layers_kept=list(spec.layer_ids),
+                       published_layers=published)
     if model_type == "falcon_h1":
         cfg.update(
             mamba_n_heads=spec.ssm_heads, mamba_d_head=spec.ssm_head_dim,
@@ -889,10 +959,87 @@ def _dest_map_afmoe(spec: ModelSpec) -> dict[str, tuple[tuple, bool, str | None]
     return m
 
 
+def _dest_map_phi4flash(
+    spec: ModelSpec,
+) -> dict[str, tuple[tuple, bool, str | None]]:
+    """The published ``phi4flash`` names (Phi-4-mini-flash, SambaY) of the
+    tensors that map one to one; the fused ones (``attn.Wqkv`` of a self
+    attention layer, ``mlp.gate_up_proj``) split in ``_phi4flash_fused``.
+    A layer's mixer is ``attn.`` whatever its kind: Mamba-1's own names
+    on a scan layer, ``in_proj`` / ``out_proj`` alone on a GMU, ``Wqkv``
+    (the queries alone) / ``out_proj`` on a cross layer, the lambdas and
+    the pair norm under ``inner_cross_attn``. ``A_log`` is published
+    ``[channels, states]`` and kept ``[states, channels]``."""
+    f32 = "float32"
+    m: dict[str, tuple[tuple, bool, str | None]] = {
+        "model.embed_tokens.weight": (("embed",), False, None),
+        "model.final_layernorm.weight": (("final_norm",), False, None),
+        "model.final_layernorm.bias": (("final_norm_bias",), False, None),
+    }
+    for i in range(spec.num_layers):
+        p, li, kd = f"model.layers.{i}.", ("layers", i), spec.kind(i)
+        a = p + "attn."
+        for hf, ours in (("input_layernorm", "attn_norm"),
+                         ("post_attention_layernorm", "mlp_norm")):
+            m[p + hf + ".weight"] = (li + (ours,), False, None)
+            m[p + hf + ".bias"] = (li + (ours + "_bias",), False, None)
+        m[p + "mlp.down_proj.weight"] = (li + ("w_down",), True, None)
+        if kd.mixer == "scan":
+            for hf, ours, tr, dt in (
+                ("in_proj.weight", "scan_in", True, None),
+                ("conv1d.weight", "scan_conv", True, None),
+                ("conv1d.bias", "scan_conv_bias", False, None),
+                ("x_proj.weight", "scan_x", True, None),
+                ("dt_proj.weight", "scan_dt", True, None),
+                ("dt_proj.bias", "scan_dt_bias", False, f32),
+                ("A_log", "scan_a_log", True, f32),
+                ("D", "scan_d", False, f32),
+                ("out_proj.weight", "scan_out", True, None),
+            ):
+                m[a + hf] = (li + (ours,), tr, dt)
+        elif kd.mixer == "gmu":
+            m[a + "in_proj.weight"] = (li + ("gmu_in",), True, None)
+            m[a + "out_proj.weight"] = (li + ("gmu_out",), True, None)
+        else:
+            m[a + "out_proj.weight"] = (li + ("wo",), True, None)
+            m[a + "out_proj.bias"] = (li + ("bo",), False, None)
+            for name in ("lambda_q1", "lambda_k1", "lambda_q2", "lambda_k2"):
+                m[a + "inner_cross_attn." + name] = (li + (name,), False, f32)
+            m[a + "inner_cross_attn.subln.weight"] = (
+                li + ("subln",), False, None)
+            if kd.reads:  # the queries alone
+                m[a + "Wqkv.weight"] = (li + ("wq",), True, None)
+                m[a + "Wqkv.bias"] = (li + ("bq",), False, None)
+    return m
+
+
+def _phi4flash_fused(spec: ModelSpec) -> dict[str, list[tuple]]:
+    """The published tensors that hold several of ours along their first
+    axis: name -> [(path, first row, rows, transpose)]. ``attn.Wqkv`` of a
+    self attention layer is ``[q | k | v]``, ``mlp.gate_up_proj`` ``[gate
+    | up]``."""
+    q = spec.num_heads * spec.head_dim
+    f = spec.intermediate_size
+    out: dict[str, list[tuple]] = {}
+    for i in range(spec.num_layers):
+        p, li, kd = f"model.layers.{i}.", ("layers", i), spec.kind(i)
+        out[p + "mlp.gate_up_proj.weight"] = [
+            (li + ("w_gate",), 0, f, True), (li + ("w_up",), f, f, True)]
+        if kd.mixer == "softmax" and not kd.reads:
+            kv = kd.num_kv_heads * spec.head_dim
+            out[p + "attn.Wqkv.weight"] = [
+                (li + ("wq",), 0, q, True), (li + ("wk",), q, kv, True),
+                (li + ("wv",), q + kv, kv, True)]
+            out[p + "attn.Wqkv.bias"] = [
+                (li + ("bq",), 0, q, False), (li + ("bk",), q, kv, False),
+                (li + ("bv",), q + kv, kv, False)]
+    return out
+
+
 def _is_taps(path: tuple) -> bool:
     """A short convolution's taps, published ``[channels, 1, taps]``."""
     return str(path[-1]).startswith("conv_") or path[-1] in (
-        "ssm_conv", "sconv_taps")
+        "ssm_conv", "sconv_taps", "scan_conv")
 
 
 def _tree_set(tree: Params, path: tuple, value) -> None:
@@ -957,11 +1104,15 @@ def load_params(
     elif spec.sandwich_norm:
         dest = _dest_map_afmoe(spec)
         fused_gpt_oss = False
+    elif "scan" in spec.mixers:
+        dest = _dest_map_phi4flash(spec)
+        fused_gpt_oss = False
     else:
         dest = _dest_map(spec, all_names)
         fused_gpt_oss = bool(
             spec.num_experts and _moe_scheme(all_names) == "gpt_oss"
         )
+    fused = _phi4flash_fused(spec) if "scan" in spec.mixers else {}
 
     params: Params = {}
     seen: set[str] = set()
@@ -1000,6 +1151,15 @@ def load_params(
                         if int(part[2]) >= (spec.num_layers
                                             + spec.nextn_predict_layers):
                             skipped_extras.append(name)
+                        continue
+                    if name in fused:
+                        # several of ours along the published first axis
+                        arr = f.get_tensor(name)
+                        for path, lo, n, tr in fused[name]:
+                            part = arr[lo:lo + n]
+                            place(path, np.ascontiguousarray(
+                                part.T if tr else part), dtype)
+                        seen.add(name)
                         continue
                     if spec.kv_lora_rank and name in kv_b:
                         # fused per-head up-projections [H*(dn+dv), dc]:
@@ -1072,7 +1232,7 @@ def load_params(
                 else:
                     place(path, arr, dt)
 
-    dest_expected = set(dest)
+    dest_expected = set(dest) | set(fused)
     if spec.kv_lora_rank:
         dest_expected |= set(kv_b)
     if fused_gpt_oss:
@@ -1151,6 +1311,8 @@ def save_params(
         dest = _dest_map_lfm2(spec)
     elif spec.sandwich_norm:
         dest = _dest_map_afmoe(spec)
+    elif "scan" in spec.mixers:
+        dest = _dest_map_phi4flash(spec)
     elif spec.moe_bias:
         # gpt-oss exports use the FUSED expert naming (synthesized
         # below); the name hint selects the gpt_oss scheme so the dest
@@ -1174,6 +1336,13 @@ def save_params(
         if _is_taps(path):
             arr = arr[:, None, :]  # the published [channels, 1, taps]
         tensors[name] = arr
+    if "scan" in spec.mixers:
+        # phi4flash's fused tensors: ours side by side along the first axis
+        for name, parts in _phi4flash_fused(spec).items():
+            tensors[name] = np.ascontiguousarray(np.concatenate([
+                np.asarray(_tree_get(params, path)).T if tr
+                else np.asarray(_tree_get(params, path))
+                for path, _lo, _n, tr in parts]))
     if spec.moe_bias and not spec.kv_lora_rank:
         # gpt-oss fused expert tensors: re-interleave gate/up (weights
         # AND biases) the way load_params de-interleaves them

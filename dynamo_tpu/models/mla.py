@@ -685,24 +685,24 @@ def _layer(
         return y.reshape(hh.shape), counts
 
     if "sub" not in lp:
-        h = _norm(x, lp["attn_norm"], spec.rms_eps)
+        h = _norm(spec, x, lp, "attn_norm")
         out, cache = mix(li, lp, h, cache)
         x = _add(x, out)
-        y, counts = ffn(lp, _norm(x, lp["mlp_norm"], spec.rms_eps), counts)
+        y, counts = ffn(lp, _norm(spec, x, lp, "mlp_norm"), counts)
         return _add(x, y), cache, counts
     first, second = lp["sub"]
     pools = (None, None) if cache is None else sub_pools(cache)
-    out, pool0 = mix(li, first, _norm(x, first["attn_norm"], spec.rms_eps),
+    out, pool0 = mix(li, first, _norm(spec, x, first, "attn_norm"),
                      pools[0])
     x = _add(x, out)
-    u = _norm(x, first["mlp_norm"], spec.rms_eps)
+    u = _norm(spec, x, first, "mlp_norm")
     m, counts = ffn({"moe": lp["moe"]}, u, counts)  # the shortcut
     y, counts = ffn(first, u, counts)
     x = _add(x, y)
-    out, pool1 = mix(li, second, _norm(x, second["attn_norm"], spec.rms_eps),
+    out, pool1 = mix(li, second, _norm(spec, x, second, "attn_norm"),
                      pools[1])
     x = _add(x, out)
-    y, counts = ffn(second, _norm(x, second["mlp_norm"], spec.rms_eps), counts)
+    y, counts = ffn(second, _norm(spec, x, second, "mlp_norm"), counts)
     x = _add(_add(x, y), m)
     return x, None if cache is None else (pool0, pool1), counts
 

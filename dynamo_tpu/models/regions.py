@@ -49,6 +49,8 @@ SCOPE_CONV_PROJ = "conv_proj"  # short convolution: input and output
 # projections
 SCOPE_CONV_MIX = "conv_mix"  # short convolution: tail read, B * x, the
 # taps, C *, tail write
+SCOPE_GMU = "gmu"  # a Gated Memory Unit: both projections and the gate
+# by the memory layer's output
 # -- attn_ctx
 SCOPE_KV = "attn_kv"  # KV write + attention over the paged context
 SCOPE_ATTN_WINDOW = "attn_window"
@@ -72,6 +74,13 @@ SCOPE_KDA_CHUNK_OPERANDS = "kda_chunk_operands"  # what XLA does of the
 SCOPE_SSD_STEP = "ssd_step"  # SSD decode: the state rows' update
 SCOPE_SSD_CHUNK = "ssd_chunk"  # SSD prefill: the chunk form, all of it
 SCOPE_STATE_ROWS = "state_rows"  # the recurrent state's directory
+SCOPE_SCAN = "scan"  # Mamba-1's selective scan: the decode step's kernel
+# (and its XLA twin), the prefill's chunk form. Its projections,
+# convolution and gates report under the SSD mixer's names
+SCOPE_ATTN_CROSS = "attn_cross"  # a layer that reads another layer's
+# pages and writes none (SambaY's cross-decoder): its decode kernel
+SCOPE_ATTN_DIFF = "attn_diff"  # differential attention: the two maps'
+# difference under lambda and the norm a pair
 # -- ffn
 SCOPE_MLP = "mlp"
 SCOPE_ROUTE = "moe_route"
@@ -108,13 +117,15 @@ REGIONS: dict[str, str] = {
     SCOPE_KDA_GATES: ATTN_PROJ, SCOPE_SSM_PROJ: ATTN_PROJ,
     SCOPE_SSM_CONV: ATTN_PROJ, SCOPE_SSM_GATES: ATTN_PROJ,
     SCOPE_CONV_PROJ: ATTN_PROJ, SCOPE_CONV_MIX: ATTN_PROJ,
+    SCOPE_GMU: ATTN_PROJ,
     SCOPE_KV: ATTN_CTX, SCOPE_ATTN_WINDOW: ATTN_CTX,
     SCOPE_ATTN_FULL: ATTN_CTX, SCOPE_FUSED_DECODE: ATTN_CTX,
     SCOPE_ATTN_LATENT: ATTN_CTX, SCOPE_PREFILL_LATENT: ATTN_CTX,
     SCOPE_LATENT_SCHEDULE: ATTN_CTX, SCOPE_KDA_STEP: ATTN_CTX,
     SCOPE_KDA_CHUNK: ATTN_CTX, SCOPE_KDA_CHUNK_OPERANDS: ATTN_CTX,
     SCOPE_SSD_STEP: ATTN_CTX, SCOPE_SSD_CHUNK: ATTN_CTX,
-    SCOPE_STATE_ROWS: ATTN_CTX,
+    SCOPE_STATE_ROWS: ATTN_CTX, SCOPE_SCAN: ATTN_CTX,
+    SCOPE_ATTN_CROSS: ATTN_CTX, SCOPE_ATTN_DIFF: ATTN_CTX,
     SCOPE_MLP: FFN, SCOPE_ROUTE: FFN, SCOPE_EXPERTS: FFN,
     SCOPE_MOE_DISPATCH: FFN, SCOPE_MOE_GROUPED: FFN, SCOPE_GMM: FFN,
     SCOPE_MOE_COMBINE: FFN, SCOPE_MOE_SHARED: FFN, SCOPE_MOE_COUNT: FFN,
@@ -131,7 +142,8 @@ REGIONS: dict[str, str] = {
 KERNEL_SCOPES = (
     SCOPE_FUSED_DECODE, SCOPE_ATTN_WINDOW, SCOPE_ATTN_FULL,
     SCOPE_ATTN_LATENT, SCOPE_PREFILL_LATENT, SCOPE_GMM, SCOPE_KDA_STEP,
-    SCOPE_KDA_CHUNK, SCOPE_SSD_STEP, SCOPE_SSD_CHUNK,
+    SCOPE_KDA_CHUNK, SCOPE_SSD_STEP, SCOPE_SSD_CHUNK, SCOPE_SCAN,
+    SCOPE_ATTN_CROSS,
 )
 
 _WRAPPED = re.compile(r"\(([^()]*)\)")

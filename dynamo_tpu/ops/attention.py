@@ -39,6 +39,7 @@ from dynamo_tpu.models.regions import (
     SCOPE_KDA_STEP,
     SCOPE_LATENT_SCHEDULE,
     SCOPE_PREFILL_LATENT,
+    SCOPE_SCAN,
     SCOPE_SSD_CHUNK,
     SCOPE_SSD_STEP,
 )
@@ -261,6 +262,7 @@ def causal_attention(
     window: int = 0,  # sliding window (0 = full); key j needs j > pos - window
     sinks: jax.Array | None = None,  # [H] learned sink logits (gpt-oss)
     kv_offset: jax.Array | int = 0,  # absolute position of k[0]
+    scale: float | None = None,  # the softmax scale (None: 1 / sqrt(D))
 ) -> jax.Array:
     """Causal attention of new queries over (cached + new) keys. K and V
     may differ in width: the scale is q's, the output is V's.
@@ -278,7 +280,8 @@ def causal_attention(
     n_rep = H // KH
     k = repeat_kv(k, n_rep)
     v = repeat_kv(v, n_rep)
-    scale = 1.0 / jnp.sqrt(jnp.asarray(D, jnp.float32))
+    if scale is None:
+        scale = 1.0 / jnp.sqrt(jnp.asarray(D, jnp.float32))
     logits = jnp.einsum("thd,shd->hts", q.astype(jnp.float32), k.astype(jnp.float32))
     logits = logits * scale
     kv_pos = kv_offset + jnp.arange(S)[None, :]  # [1, S]
@@ -365,7 +368,8 @@ def prefill_blocks(
 
 
 @functools.partial(
-    jax.jit, static_argnames=("head_dim", "v_dim", "window", "kv_heads"))
+    jax.jit,
+    static_argnames=("head_dim", "v_dim", "window", "kv_heads", "scale"))
 def paged_prefill_attention(
     q: jax.Array,  # [T, H, D]: queries at positions start_pos + arange(T)
     k_pool,  # [L, num_pages, KH, page, >= D] (arrays or a QuantPool;
@@ -383,6 +387,7 @@ def paged_prefill_attention(
     window: int = 0,
     sinks: jax.Array | None = None,  # [H]
     new_kv: tuple | None = None,  # (k [T, KH, D], v [T, KH, Dv]) exact rows
+    scale: float | None = None,  # the softmax scale (None: 1 / sqrt(D))
 ) -> jax.Array:
     """``causal_attention`` of a call's queries over the sequence's PAGED
     context, walked in blocks with a running softmax: what is gathered and
@@ -415,7 +420,8 @@ def paged_prefill_attention(
     tq, bp = prefill_tiling(T, P, page, window)
     n_tiles = -(-T // tq)
     span = bp * page
-    scale = 1.0 / jnp.sqrt(jnp.asarray(D, jnp.float32))
+    if scale is None:
+        scale = 1.0 / jnp.sqrt(jnp.asarray(D, jnp.float32))
     # [tiles, KH, G * tq, D]: a KV head's G query heads, tile rows within
     qt = jnp.pad(q.astype(jnp.float32), ((0, n_tiles * tq - T), (0, 0), (0, 0)))
     qt = qt.reshape(n_tiles, tq, KH, G, D).transpose(0, 2, 3, 1, 4)
@@ -576,6 +582,8 @@ def decode_update_attention(
     window: int = 0,
     sinks: jax.Array | None = None,
     scope: str | None = None,  # names the kernel in a trace (llama.attn_scope)
+    scale: float | None = None,  # the softmax scale (None: 1 / sqrt(D)):
+    # differential attention's rows are a PAIR of heads wide
 ) -> tuple[jax.Array, jax.Array, jax.Array]:
     """The per-layer decode step, KV append + paged attention, and THE
     place its implementation is chosen, from what the program can observe:
@@ -629,7 +637,8 @@ def decode_update_attention(
     q = slot_queries(q, KH, pool_kh, k_pages.shape[-1])
     k_new = pool_rows(k_new, pool_kh, k_pages.shape[-1])
     v_new = pool_rows(v_new, v_pages.shape[2], v_pages.shape[-1])
-    scale = 1.0 / float(D) ** 0.5
+    if scale is None:
+        scale = 1.0 / float(D) ** 0.5
     # quantized pools under the tp shard_map are not plumbed yet: the
     # scale leaves would need their own specs. They take the XLA path,
     # which GSPMD partitions like any other gather/scatter
@@ -1488,3 +1497,113 @@ def kda_step_xla(pool, conv, rows, x, taps, alpha, beta, *, layer: int):
     o = jnp.einsum("bhkv,bhk->bhv", s, q, precision=_HI)
     return (o, pool.at[layer, rows].set(s),
             conv.at[layer, rows].set(ext[:, 1:].astype(conv.dtype)))
+
+
+# ------------------------------------------------------ the selective scan
+# Mamba-1's mixer (arXiv:2312.00752): a state S [N, C] a sequence, float32,
+# N states a channel over C channels, under a decay that differs in EVERY
+# element, with B and C shared by the channels:
+#
+#     S_t[n, c] = exp(dt_t[c] A[n, c]) S_{t-1}[n, c] + dt_t[c] x_t[c] B_t[n]
+#     y_t[c]    = sum_n S_t[n, c] C_t[n] + D[c] x_t[c]
+#
+# The pool keeps the states LEADING and the channels on the lanes ([L, rows
+# + 1, N, C]: ops/pallas/scan.py). Appended at the file's end: no softmax,
+# KDA or SSD line moved.
+
+# tokens a chunk of the prefill form: its two temporaries are ``[rows,
+# chunk, N, C]`` float32 each, 21 MB a row at 64 x 16 x 5,120
+SCAN_CHUNK = 64
+
+
+@jax.named_scope(SCOPE_SCAN)
+def scan_chunk_prefill(x, dt, A, B, C, D, pool, rows, fresh, *, layer: int,
+                       chunk: int = SCAN_CHUNK):
+    """The selective scan over whole rows, from and to the sequences' rows
+    of the state pool ``[L, rows + 1, N, C]`` float32: a ``lax.scan`` over
+    chunks of ``chunk`` tokens that carries the state, and inside a chunk
+    the recurrence as an ASSOCIATIVE scan of the pairs ``(decay, input)``
+    under ``(a1, b1) . (a2, b2) = (a1 a2, a2 b1 + b2)``: every factor is an
+    exponential of a non-positive number, so nothing overflows however
+    fast a channel forgets (a cumulative-sum form divides by decays that
+    underflow). Its temporaries are a chunk's pairs, ``[rows, chunk, N,
+    C]`` float32 twice (21 MB a row each at 64 x 16 x 5,120; a 1,024-row
+    call walks 16 chunks, a pack of two holds 84 MB), and the scan's
+    halved copies of them. Plain XLA, all in float32. x: [R, T, C]; dt:
+    [R, T, C] float32, softplus applied, 0 at a padded token (which then
+    leaves the state as it was and adds nothing); A: [N, C], D: [C]
+    float32; B, C: [R, T, N]; rows: [R] int32 (the pool's last row =
+    trash); fresh: [R] bool. Returns (y [R, T, C] float32, pool)."""
+    f32 = jnp.float32
+    R, T, Cn = x.shape
+    Q = min(chunk, T)
+    pad = -T % Q
+    nc = (T + pad) // Q
+
+    def chunks(a):  # [R, T, ...] -> [nc, R, Q, ...]
+        a = jnp.pad(a.astype(f32), ((0, 0), (0, pad)) + ((0, 0),) * (a.ndim - 2))
+        return jnp.moveaxis(a.reshape(R, nc, Q, *a.shape[2:]), 1, 0)
+
+    def combine(left, right):
+        (a1, b1), (a2, b2) = left, right
+        return a1 * a2, a2 * b1 + b2
+
+    def one(s, at):
+        x_c, dt_c, b_c, c_c = at  # [R, Q, C] x2, [R, Q, N] x2
+        decay = jnp.exp(dt_c[:, :, None, :] * A)  # [R, Q, N, C]
+        add = (dt_c * x_c)[:, :, None, :] * b_c[..., None]
+        cum, inner = jax.lax.associative_scan(combine, (decay, add), axis=1)
+        states = cum * s[:, None] + inner
+        y = jnp.sum(states * c_c[..., None], axis=2)  # [R, Q, C]
+        return states[:, -1], y
+
+    # a row at a time, by dynamic slices (``ssd_chunk_prefill``: a gather
+    # from the pool makes the chip's compiler copy it)
+    row = (1, 1) + pool.shape[2:]
+    s0 = jnp.concatenate([
+        jax.lax.dynamic_slice(pool, (layer, rows[i], 0, 0), row)[0]
+        for i in range(R)
+    ])
+    s0 = jnp.where(fresh[:, None, None], 0.0, s0)
+    s, y = jax.lax.scan(one, s0, (chunks(x), chunks(dt), chunks(B), chunks(C)))
+    y = jnp.moveaxis(y, 0, 1).reshape(R, nc * Q, Cn)[:, :T]
+    y = y + D * x.astype(f32)
+    for i in range(R):  # in order: two members on the trash row are fine
+        pool = jax.lax.dynamic_update_slice(
+            pool, s[i][None, None], (layer, rows[i], 0, 0))
+    return y, pool
+
+
+def scan_decode_step(pool, conv, rows, x, dt, A, B, C, D, tail, *, layer: int):
+    """One decode step of every slot over layer ``layer`` of the state
+    pool ``[L, rows + 1, N, C]`` float32, the slots' new convolution tails
+    ``tail [B, taps - 1, C]`` put into ``conv [L, rows + 1, taps - 1,
+    C]``, and the place the implementation is chosen: the ``scan_step``
+    kernel (ops/pallas/scan.py) wherever Pallas is active (each live
+    slot's row read once and written once, in place, the tails in the
+    same call), else a gather of the slots' rows, the step in XLA and
+    scatters back (counted ``no_pallas_backend``). ``rows`` [B]: each
+    slot's row, the trash row (the pools' last) for a slot that owns
+    none. x, dt: [B, C] (dt float32, softplus applied); A: [N, C], D:
+    [C]; B, C: [B, N]. Returns (y [B, C] float32, pool, conv)."""
+    from dynamo_tpu.ops.fallback import note_fallback
+
+    f32 = jnp.float32
+    x, dt, B, C = (a.astype(f32) for a in (x, dt, B, C))
+    if use_pallas():
+        from dynamo_tpu.ops.pallas.scan import scan_step
+
+        y, pool, conv = scan_step(
+            pool, conv, rows, dt, dt * x, A, B, C, tail, layer=layer,
+            interpret=jax.default_backend() != "tpu", scope=SCOPE_SCAN,
+        )
+    else:
+        note_fallback("no_pallas_backend", expected=True,
+                      detail="scan_decode_step: gather, step, scatter")
+        with jax.named_scope(SCOPE_SCAN):
+            s = jnp.exp(dt[:, None, :] * A) * pool[layer, rows] + (
+                (dt * x)[:, None, :] * B[..., None])
+            y = jnp.sum(s * C[..., None], axis=1)
+            pool = pool.at[layer, rows].set(s)
+            conv = conv.at[layer, rows].set(tail.astype(conv.dtype))
+    return y + D * x, pool, conv

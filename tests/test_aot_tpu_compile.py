@@ -796,3 +796,114 @@ def test_trinity_decode_program_compiles_for_v5e(v5e, monkeypatch):
     mem = compiled.memory_analysis()
     assert mem.alias_size_in_bytes >= pools
     assert mem.temp_size_in_bytes < 256 * 2**20
+
+
+def test_scan_step_compiles_for_v5e(v5e):
+    """Phi-4-mini-flash's cell: 128 slots on 129 state rows of 16 x 5,120
+    float32 (the channels on the lanes) and the convolution tails of
+    5,120 channels, both pools aliased in place, a row found through the
+    scalar-prefetched ``rows``."""
+    from dynamo_tpu.ops.pallas.scan import scan_step
+
+    slots, n, ch, rows = 128, 16, 5120, 128
+    f32 = jnp.float32
+    compiled = jax.jit(
+        lambda pool, conv, at, dt, dx, a, b, c, tail: scan_step(
+            pool, conv, at, dt, dx, a, b, c, tail, layer=1, scope="scan"),
+        donate_argnums=(0, 1),
+    ).lower(
+        _rows(v5e, 4, rows + 1, n, ch, dtype=f32),
+        _rows(v5e, 4, rows + 1, 3, ch),
+        _rows(v5e, slots, dtype=jnp.int32),
+        _rows(v5e, slots, ch, dtype=f32),
+        _rows(v5e, slots, ch, dtype=f32),
+        _rows(v5e, n, ch, dtype=f32),
+        _rows(v5e, slots, n, dtype=f32),
+        _rows(v5e, slots, n, dtype=f32),
+        _rows(v5e, slots, 3, ch),
+    ).compile()
+    # the kernel is named after the scope: the trace's readers match it
+    assert "%scan" in compiled.as_text()
+
+
+def _phi4flash_layers(v5e, vocab=2048):
+    """One layer of each kind of Phi-4-mini-flash at the published widths
+    (the published layers 15-19: window, scan (the memory), full, GMU,
+    cross), described: spec, weights and the cache of the cell's engine
+    (128 slots, pages of 64)."""
+    from dynamo_tpu.models import llama
+    from dynamo_tpu.models.loader import spec_from_hf_config
+
+    spec = spec_from_hf_config(dict(
+        model_type="phi4flash", hidden_size=2560, num_attention_heads=40,
+        num_key_value_heads=20, intermediate_size=10240, vocab_size=vocab,
+        num_hidden_layers=5, layers_kept=[15, 16, 17, 18, 19],
+        published_layers=32, sliding_window=512, layer_norm_eps=1e-5,
+        mb_per_layer=2, tie_word_embeddings=True, torch_dtype="bfloat16",
+    ), name="phi4flash-aot")
+
+    def described(tree):
+        return jax.tree.map(
+            lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=v5e),
+            tree)
+
+    params = described(jax.eval_shape(
+        lambda: llama.init_params(spec, jax.random.PRNGKey(0))))
+    k, v = described(jax.eval_shape(
+        lambda: llama.init_cache(spec, 257, 64, state_rows=128)))
+    return spec, params, k, v
+
+
+@pytest.mark.parametrize("rows", [2, 1], ids=["pack-of-2", "single"])
+def test_phi4flash_prefill_program_compiles_for_v5e(v5e, monkeypatch, rows):
+    """The prefill programs of the cell, a layer of each kind at the
+    published widths: 1,024 tokens a row through the page write of a
+    pair a row, the walk under a 512 window and over all keys, the scan's
+    chunk form, and ONE row a sequence through the GMU and the cross
+    layer; every leaf of the cache donated."""
+    from dynamo_tpu.models import llama
+
+    _as_on_the_chip(monkeypatch)
+    spec, params, k, v = _phi4flash_layers(v5e)
+    i32 = jnp.int32
+    if rows == 1:
+        lowered = jax.jit(
+            llama.prefill_forward_impl, static_argnums=(0,),
+            donate_argnums=(5, 6),
+        ).lower(spec, params, _rows(v5e, 1024, dtype=i32),
+                _rows(v5e, 160, dtype=i32), _rows(v5e, dtype=i32), k, v,
+                _rows(v5e, dtype=i32))
+    else:
+        lowered = jax.jit(
+            llama.prefill_forward_batch_impl, static_argnums=(0,),
+            donate_argnums=(5, 6),
+        ).lower(spec, params, _rows(v5e, rows, 1024, dtype=i32),
+                _rows(v5e, rows, 160, dtype=i32), _rows(v5e, rows, dtype=i32),
+                k, v, _rows(v5e, rows, dtype=i32))
+    text = lowered.compile().as_text()
+    for region in ("scan", "gmu", "attn_cross", "attn_diff"):
+        assert f"/{region}/" in text, region
+
+
+def test_phi4flash_decode_program_compiles_for_v5e(v5e, monkeypatch):
+    """The decode burst of the cell, a layer of each kind at the
+    published widths: 128 slots through ``scan``, the window and the full
+    kernel over rows a pair wide, and the full kernel again with no write
+    for the cross layer, 8 steps, the sampler on the device."""
+    from dynamo_tpu.models import llama
+
+    _as_on_the_chip(monkeypatch)
+    spec, params, k, v = _phi4flash_layers(v5e)
+    B_, i32, f32 = 128, jnp.int32, jnp.float32
+    text = jax.jit(
+        llama.decode_steps_impl, static_argnums=(0,),
+        static_argnames=("n_steps", "n_logprobs"), donate_argnums=(5, 6),
+    ).lower(
+        spec, params, _rows(v5e, B_, dtype=i32), _rows(v5e, B_, 160, dtype=i32),
+        _rows(v5e, B_, dtype=i32), k, v, _rows(v5e, B_, dtype=jnp.bool_),
+        _rows(v5e, B_, dtype=f32), _rows(v5e, B_, dtype=i32),
+        _rows(v5e, B_, dtype=f32), _rows(v5e, B_, dtype=jnp.uint32),
+        _rows(v5e, B_, dtype=i32), n_steps=8, n_logprobs=0,
+    ).compile().as_text()
+    for kernel in ("%scan", "%attn_window", "%attn_full", "%attn_cross"):
+        assert kernel in text, kernel

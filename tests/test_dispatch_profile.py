@@ -395,7 +395,7 @@ def test_profile_counter_catalog_sync():
     for spec in (ModelSpec.tiny(), ModelSpec.tiny_deepseek(),
                  ModelSpec.tiny_gpt_oss(), ModelSpec.tiny_solar(),
                  ModelSpec.tiny_falcon_h1(), ModelSpec.tiny_ling3(),
-                 ModelSpec.tiny_lfm2()):
+                 ModelSpec.tiny_lfm2(), ModelSpec.tiny_phi4flash()):
         engine = InferenceEngine(spec, _cfg(profile=False))
         reported |= {
             k for k in engine.profile_snapshot()

@@ -1,0 +1,487 @@
+"""Phi-4-mini-flash (SambaY) at toy widths on the CPU, against the
+benchmark's own plain reference (``perfbench/references/sambay.py``, loaded
+by path: the same module the chip is held to, not a copy): Mamba-1 scan
+layers beside window layers of differential attention, ONE full layer
+whose pages the cross layers read, GMU layers that read the last scan's
+output, and a prefill that sends one row a sequence through the upper
+half. Programs, the scan's decode kernel (interpreted) and its XLA twin,
+the chunk form, the counters, the published names.
+"""
+
+import dataclasses
+import importlib.util
+import os
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+from dynamo_tpu.engine.config import EngineConfig, ModelSpec
+from dynamo_tpu.engine.core import InferenceEngine
+from dynamo_tpu.models import llama, loader
+from dynamo_tpu.models.family import get_family
+from dynamo_tpu.ops import attention as attn_ops
+from dynamo_tpu.runtime.context import Context
+
+REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+
+# the reference reads the published keys; the program reads the spec the
+# loader makes of them. A toy cut of a 32-layer stack: S W | S F | G C G C
+CONFIG = {
+    "name": "toy-phi4flash", "model_type": "phi4flash", "hidden_size": 64,
+    "num_attention_heads": 8, "num_key_value_heads": 4,
+    "intermediate_size": 96, "vocab_size": 96, "num_hidden_layers": 8,
+    "layers_kept": [0, 1, 16, 17, 18, 19, 20, 21],
+    "published_layers": 32, "sliding_window": 8, "layer_norm_eps": 1e-5,
+    "mb_per_layer": 2, "tie_word_embeddings": True, "mlp_bias": False,
+    "lm_head_bias": False, "torch_dtype": "float32",
+}
+SPEC = dataclasses.replace(
+    loader.spec_from_hf_config(CONFIG, name="toy-phi4flash"),
+    vocab_draw_blocks=8)
+PAGE, PAGES_PER_SEQ, T, ROWS = 4, 16, 40, 3
+SEED = 11
+TOL = 2e-5  # float32 on both sides; logits of magnitude ~0.5
+
+
+@pytest.fixture(scope="module")
+def ref():
+    spec = importlib.util.spec_from_file_location(
+        "sambay", os.path.join(REPO, "perfbench/references/sambay.py"))
+    mod = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(mod)
+    return mod
+
+
+@pytest.fixture(scope="module")
+def model(ref):
+    params = llama.init_params(SPEC, jax.random.PRNGKey(SEED))
+    toks = np.asarray(
+        jax.random.randint(jax.random.PRNGKey(1), (3, T), 0, 96))
+    want = np.asarray(ref.forward(
+        CONFIG, SEED, toks, np.tile(np.arange(T), (3, 1))))
+    return params, toks, want
+
+
+def _cache(rows=ROWS, spec=SPEC):
+    return llama.init_cache(
+        spec, 1 + 3 * PAGES_PER_SEQ, PAGE, state_rows=rows)
+
+
+def _table(row):
+    return jnp.arange(PAGES_PER_SEQ, dtype=jnp.int32) + 1 + row * PAGES_PER_SEQ
+
+
+def _close(got, want, tol=TOL):
+    err = float(np.abs(np.asarray(got) - np.asarray(want)).max())
+    assert err < tol, err
+
+
+def _prefill(params, toks, row, start, n, k, v, bucket=16, spec=SPEC):
+    padded = np.zeros((bucket,), np.int32)
+    padded[:n] = toks[start:start + n]
+    logits, k, v, _ = llama.prefill_forward(
+        spec, params, jnp.asarray(padded), _table(row),
+        jnp.asarray(start, jnp.int32), k, v, jnp.asarray(n, jnp.int32))
+    return logits, k, v
+
+
+def _decode(params, fed, lens, rows, k, v, spec=SPEC, B=4):
+    """One decode step: ``rows`` (table rows) in the leading slots, the
+    last slot idle."""
+    tok = np.zeros((B,), np.int32)
+    seq = np.ones((B,), np.int32)
+    bts = np.zeros((B, PAGES_PER_SEQ), np.int32)
+    active = np.zeros((B,), bool)
+    for s, r in enumerate(rows):
+        tok[s], seq[s], active[s] = fed[s], lens[s], True
+        bts[s] = np.asarray(_table(r))
+    return llama.decode_forward(
+        spec, params, jnp.asarray(tok), jnp.asarray(bts), jnp.asarray(seq),
+        k, v, jnp.asarray(active))
+
+
+def test_the_spec_and_the_cache_of_the_five_kinds():
+    kinds = [SPEC.kind(li) for li in range(SPEC.num_layers)]
+    assert [kd.mixer for kd in kinds] == [
+        "scan", "softmax", "scan", "softmax", "gmu", "softmax", "gmu",
+        "softmax"]
+    assert [kd.window for kd in kinds if kd.mixer == "softmax"] == [8, 0, 0, 0]
+    assert SPEC.carried_from == 4 and SPEC.memory_layer == 2
+    assert SPEC.layer_ids == (0, 1, 16, 17, 18, 19, 20, 21)
+    cross = kinds[5]
+    assert cross.reads == (1, 0) and not cross.paged and cross.carried
+    assert get_family(SPEC).recurrent
+    k, v = _cache()
+    # a pair of KV heads a row; the cross and the GMU kinds own nothing
+    assert k.pools[0].shape == (1, 49, 2, PAGE, 16)
+    assert k.pools[1].shape == v.pools[1].shape == (1, 49, 2, PAGE, 16)
+    assert k.pools[2].shape == (2, ROWS + 1, 16, 128)  # [N, C] float32
+    assert k.pools[2].dtype == jnp.float32
+    assert v.pools[2].shape == (2, ROWS + 1, 3, 128)
+    assert k.pools[3] is None and k.pools[4] is None
+    assert v.pools[3] is None and v.pools[4] is None
+
+
+def test_layers_that_write_no_cache_come_last():
+    with pytest.raises(ValueError, match="come last"):
+        dataclasses.replace(
+            SPEC, layer_pattern=(2, 0, 2, 1, 3, 4, 2, 4))
+
+
+def test_the_whole_sequence_pass_equals_the_reference(model):
+    params, toks, want = model
+    for r in range(3):
+        _close(llama.reference_forward(SPEC, params, jnp.asarray(toks[r])),
+               want[r], 4e-5)
+
+
+@pytest.mark.parametrize("pallas", ["0", "1"], ids=["xla", "kernel"])
+@pytest.mark.parametrize("n", [5, 30], ids=["under-window", "over-window"])
+def test_prefill_then_decode_through_pages_and_state(
+        model, monkeypatch, pallas, n):
+    monkeypatch.setenv("DYNAMO_PALLAS", pallas)
+    params, toks, want = model
+    k, v = _cache()
+    logits, k, v = _prefill(params, toks[0], 0, 0, n, k, v, bucket=32)
+    _close(logits, want[0, n - 1])
+    for j in range(6):
+        logits, k, v = _decode(
+            params, [toks[0, n + j]], [n + j + 1], [0], k, v)
+        _close(logits[0], want[0, n + j])
+
+
+def test_a_prompt_in_three_chunks_resumes_state_tail_and_pages(model):
+    params, toks, want = model
+    k, v = _cache()
+    for start, n in ((0, 16), (16, 16), (32, 5)):
+        logits, k, v = _prefill(params, toks[1], 1, start, n, k, v)
+        _close(logits, want[1, start + n - 1])
+    logits, k, v = _decode(params, [toks[1, 37]], [38], [1], k, v)
+    _close(logits[0], want[1, 37])
+
+
+def test_a_pack_of_two_with_an_empty_member_and_an_idle_slot(model):
+    params, toks, want = model
+    k, v = _cache()
+    padded = np.zeros((3, 32), np.int32)
+    lens = np.asarray([30, 0, 21], np.int32)
+    padded[0, :30], padded[2, :21] = toks[0, :30], toks[2, :21]
+    bts = np.stack([np.asarray(_table(0)), np.zeros(PAGES_PER_SEQ, np.int32),
+                    np.asarray(_table(2))])
+    logits, k, v, _ = llama.prefill_forward_batch(
+        SPEC, params, jnp.asarray(padded), jnp.asarray(bts),
+        jnp.zeros((3,), jnp.int32), k, v, jnp.asarray(lens))
+    _close(logits[0], want[0, 29])
+    _close(logits[2], want[2, 20])
+    logits, k, v = _decode(
+        params, [toks[0, 30], toks[2, 21]], [31, 22], [0, 2], k, v)
+    _close(logits[0], want[0, 30])
+    _close(logits[1], want[2, 21])
+
+
+def test_bursts_of_one_and_eight_agree_with_the_reference(model, ref):
+    params, toks, _ = model
+    k, v = _cache()
+    n, B = 20, 2
+    _, k, v = _prefill(params, toks[0], 0, 0, n, k, v, bucket=32)
+    bts = jnp.stack([_table(0), jnp.zeros((PAGES_PER_SEQ,), jnp.int32)])
+    z = jnp.zeros((B,), jnp.int32)
+    fed, seq, made = int(toks[0, n]), n + 1, []
+    for n_steps in (1, 8):
+        out, k, v = llama.decode_steps(
+            SPEC, params, jnp.asarray([fed, 0], jnp.int32), bts,
+            jnp.asarray([seq, 1], jnp.int32), k, v,
+            jnp.asarray([True, False]), jnp.zeros((B,), jnp.float32), z,
+            jnp.ones((B,), jnp.float32), jnp.zeros((B,), jnp.uint32), z,
+            n_steps=n_steps, n_logprobs=0)
+        made += [int(t) for t in np.asarray(out)[0]]
+        fed, seq = made[-1], seq + n_steps
+    row = np.concatenate([toks[0, :n + 1], made]).astype(np.int32)[None]
+    at = np.arange(n, n + 9)[None]
+    want = np.asarray(ref.forward(CONFIG, SEED, row, at))[0]
+    assert made == [int(t) for t in want.argmax(-1)]
+
+
+def _all_rows_prefill(params, toks, n, k, v):
+    """The prefill program with EVERY row through every layer: the same
+    lower loop, then the upper layers over all rows against the pages the
+    full layer left, the logits of the last row."""
+    spec = SPEC
+    padded = np.zeros((32,), np.int32)
+    padded[:n] = toks[:n]
+
+    def program(params, k, v):
+        # the lower half as served: one row through a model cut at the
+        # upper half leaves exactly its pages, state and tails
+        low = dataclasses.replace(
+            spec, num_layers=spec.carried_from,
+            layer_pattern=spec.layer_pattern[:spec.carried_from],
+            layer_ids=spec.layer_ids[:spec.carried_from])
+        return llama.prefill_forward_impl(
+            low, dict(params, layers=params["layers"][:spec.carried_from]),
+            jnp.asarray(padded), _table(0), jnp.asarray(0), k, v,
+            jnp.asarray(n))
+
+    _, k, v, _ = program(params, k, v)
+    whole = llama.reference_forward(spec, params, jnp.asarray(toks[:n]))
+    return whole[n - 1], k, v
+
+
+def test_the_last_row_prefill_equals_the_all_rows_pass(model):
+    params, toks, want = model
+    n = 30
+    logits, k, v = _prefill(
+        params, toks[0], 0, 0, n, *_cache(), bucket=32)
+    all_rows, k2, v2 = _all_rows_prefill(params, toks[0], n, *_cache())
+    _close(logits, all_rows)
+    _close(logits, want[0, n - 1])
+    # and leaves the same pages, state and tails (to the rounding of two
+    # compilations): the upper half wrote none
+    for a, b in zip(jax.tree.leaves((k.pools, v.pools)),
+                    jax.tree.leaves((k2.pools, v2.pools))):
+        np.testing.assert_allclose(
+            np.asarray(a), np.asarray(b), rtol=0, atol=TOL)
+
+
+def test_cross_layers_read_the_full_layers_pages_and_write_none(model):
+    params, toks, want = model
+    n = 20
+    _, k, v = _prefill(params, toks[0], 0, 0, n, *_cache(), bucket=32)
+    before = jax.tree.map(np.asarray, (k.pools[1], v.pools[1]))
+    # a decode program of the upper half alone over a live slot leaves the
+    # shared pool as it found it, the trash page apart
+    logits, k, v = _decode(params, [toks[0, n]], [n + 1], [0], k, v)
+    _close(logits[0], want[0, n])
+    page = int(np.asarray(_table(0))[n // PAGE])
+    for was, now in zip(before, (k.pools[1], v.pools[1])):
+        now = np.asarray(now)
+        # the step's one new row, written by the full layer itself
+        changed = np.argwhere((was != now).any(axis=(0, 2, 3, 4)))[:, 0]
+        assert set(changed) <= {0, page}
+    # spoil the full layer's pages: the cross layers see it
+    spoiled = k._replace(pools=(k.pools[0], k.pools[1] * 0.5, *k.pools[2:]))
+    bad, _, _ = _decode(params, [toks[0, n + 1]], [n + 2], [0], spoiled, v)
+    assert float(np.abs(np.asarray(bad[0]) - want[0, n + 1]).max()) > 1e-3
+
+
+def test_the_memory_is_layer_16s_scan_and_no_other(model, monkeypatch):
+    params, toks, want = model
+    assert SPEC.layer_id(SPEC.memory_layer) == 16
+    # the memory taken from the first scan in place of the last
+    monkeypatch.setattr(ModelSpec, "memory_layer", property(lambda spec: 0))
+    swapped = dataclasses.replace(SPEC, name="toy-phi4flash-swapped")
+    got = llama.reference_forward(swapped, params, jnp.asarray(toks[0]))
+    assert float(np.abs(np.asarray(got) - want[0]).max()) > 1e-3
+
+
+@pytest.mark.parametrize("drop", ["lambda", "factor", "norm"])
+def test_each_term_of_the_difference_is_in_the_comparison(
+        model, monkeypatch, drop):
+    params, toks, want = model
+    if drop == "lambda":
+        params = dict(params, layers=[
+            dict(lp, lambda_q1=lp["lambda_q1"] * 0) if "lambda_q1" in lp
+            else lp for lp in params["layers"]])
+    elif drop == "factor":
+        monkeypatch.setattr(llama, "lambda_init", lambda layer_id: 0.8)
+    else:
+        params = dict(params, layers=[
+            dict(lp, subln=jnp.ones_like(lp["subln"])) if "subln" in lp
+            else lp for lp in params["layers"]])
+    with jax.disable_jit():
+        got = llama.reference_forward(SPEC, params, jnp.asarray(toks[0, :12]))
+    assert float(np.abs(np.asarray(got) - want[0, :12]).max()) > 1e-4
+
+
+def _scan_recurrence(x, dt, A, B, C, D, s0):
+    """The recurrence a token at a time: what the chunk form and the
+    decode step must equal. x, dt: [T, C]; A: [N, C]; B, C: [T,
+    N]; D: [C]; s0: [N, C]. Returns (y [T, C] float32, s)."""
+    f32 = jnp.float32
+
+    def step(s, at):
+        x_t, dt_t, b_t, c_t = at
+        s = jnp.exp(dt_t * A) * s + (dt_t * x_t) * b_t[:, None]
+        return s, jnp.sum(s * c_t[:, None], axis=0) + D * x_t
+
+    s, y = jax.lax.scan(step, s0.astype(f32), (
+        x.astype(f32), dt.astype(f32), B.astype(f32), C.astype(f32)))
+    return y, s
+
+
+def _scan_case(T_, seed=3, R=2, C=128, N=16):
+    ks = jax.random.split(jax.random.PRNGKey(seed), 6)
+    x = jax.random.normal(ks[0], (R, T_, C), jnp.float32)
+    dt = jax.nn.softplus(jax.random.normal(ks[1], (R, T_, C)) - 2.0)
+    A = -jnp.exp(jax.random.uniform(ks[2], (N, C), minval=0.0, maxval=2.8))
+    B = jax.random.normal(ks[3], (R, T_, N), jnp.float32)
+    Cm = jax.random.normal(ks[4], (R, T_, N), jnp.float32)
+    D = jax.random.uniform(ks[5], (C,), minval=0.5, maxval=1.5)
+    return x, dt, A, B, Cm, D
+
+
+@pytest.mark.parametrize("T_", [16, 64, 150], ids=["short", "one-chunk", "ragged"])
+def test_the_chunk_form_equals_the_token_recurrence(T_):
+    x, dt, A, B, C, D = _scan_case(T_)
+    R, _, Cn = x.shape
+    N = A.shape[0]
+    s0 = jax.random.normal(jax.random.PRNGKey(9), (R, N, Cn), jnp.float32)
+    pool = jnp.zeros((2, 4, N, Cn), jnp.float32).at[1, 1:3].set(s0)
+    rows = jnp.asarray([1, 2], jnp.int32)
+    # member 0 resumes its row, member 1 starts fresh; a padded tail
+    real = jnp.arange(T_) < T_ - 3
+    dt = dt.at[1].set(jnp.where(real[:, None], dt[1], 0.0))
+    y, pool2 = attn_ops.scan_chunk_prefill(
+        x, dt, A, B, C, D, pool, rows, jnp.asarray([False, True]), layer=1)
+    for r, start in ((0, s0[0]), (1, jnp.zeros_like(s0[0]))):
+        want_y, want_s = _scan_recurrence(
+            x[r], dt[r], A, B[r], C[r], D, start)
+        np.testing.assert_allclose(y[r], want_y, rtol=2e-5, atol=2e-5)
+        np.testing.assert_allclose(pool2[1, 1 + r], want_s, rtol=2e-5,
+                                   atol=2e-5)
+    np.testing.assert_array_equal(pool2[0], pool[0])
+
+
+def test_scan_step_equals_its_xla_twin_and_the_recurrence(monkeypatch):
+    x, dt, A, B, C, D = _scan_case(1, R=5)
+    x, dt, B, C = x[:, 0], dt[:, 0], B[:, 0], C[:, 0]
+    N, Cn = A.shape
+    pool = jax.random.normal(
+        jax.random.PRNGKey(4), (2, 5, N, Cn), jnp.float32)
+    conv = jnp.zeros((2, 5, 3, Cn), jnp.float32)
+    tail = jax.random.normal(jax.random.PRNGKey(5), (5, 3, Cn), jnp.float32)
+    # slots: rows 2, 0, the trash row (idle), 3, the trash row
+    rows = jnp.asarray([2, 0, 4, 3, 4], jnp.int32)
+    outs = {}
+    for pallas in ("0", "1"):
+        monkeypatch.setenv("DYNAMO_PALLAS", pallas)
+        outs[pallas] = attn_ops.scan_decode_step(
+            pool, conv, rows, x, dt, A, B, C, D, tail, layer=1)
+    (y0, p0, c0), (y1, p1, c1) = outs["0"], outs["1"]
+    live = np.asarray([0, 1, 3])
+    np.testing.assert_allclose(y1[live], y0[live], rtol=1e-6, atol=1e-6)
+    for r in (0, 2, 3):  # the live rows; the trash row holds anything
+        np.testing.assert_allclose(p1[1, r], p0[1, r], rtol=1e-6, atol=1e-6)
+        np.testing.assert_array_equal(c1[1, r], c0[1, r])
+    np.testing.assert_array_equal(p1[0], pool[0])  # the other layer
+    np.testing.assert_array_equal(p1[1, 1], pool[1, 1])  # nobody's row
+    for s in live:
+        want_y, want_s = _scan_recurrence(
+            x[s][None], dt[s][None], A, B[s][None], C[s][None], D,
+            pool[1, rows[s]])
+        np.testing.assert_allclose(y1[s], want_y[0], rtol=1e-5, atol=1e-5)
+        np.testing.assert_allclose(p1[1, rows[s]], want_s, rtol=1e-5,
+                                   atol=1e-5)
+
+
+def test_the_config_round_trips_by_the_published_names(tmp_path, model):
+    params = model[0]
+    cfg = loader.hf_config_from_spec(SPEC)
+    for key in ("mb_per_layer", "sliding_window", "layer_norm_eps",
+                "tie_word_embeddings", "num_key_value_heads", "layers_kept",
+                "published_layers"):
+        assert cfg[key] == CONFIG[key], key
+    assert cfg["model_type"] == "phi4flash"
+    again = loader.spec_from_hf_config(cfg, name=SPEC.name)
+    assert dataclasses.replace(again, vocab_draw_blocks=8) == SPEC
+    loader.save_params(SPEC, params, str(tmp_path))
+    from safetensors import safe_open
+
+    with safe_open(str(tmp_path / "model.safetensors"), "numpy") as f:
+        names = set(f.keys())
+        wqkv = f.get_tensor("model.layers.1.attn.Wqkv.weight")
+        a_log = f.get_tensor("model.layers.0.attn.A_log")
+    for name in (
+        "model.embed_tokens.weight", "model.final_layernorm.weight",
+        "model.final_layernorm.bias", "model.layers.0.attn.in_proj.weight",
+        "model.layers.0.attn.conv1d.weight", "model.layers.0.attn.conv1d.bias",
+        "model.layers.0.attn.x_proj.weight", "model.layers.0.attn.dt_proj.bias",
+        "model.layers.0.attn.D", "model.layers.0.attn.out_proj.weight",
+        "model.layers.1.attn.Wqkv.bias", "model.layers.1.attn.out_proj.weight",
+        "model.layers.1.attn.inner_cross_attn.lambda_q1",
+        "model.layers.1.attn.inner_cross_attn.subln.weight",
+        "model.layers.4.attn.in_proj.weight", "model.layers.5.attn.Wqkv.weight",
+        "model.layers.0.mlp.gate_up_proj.weight",
+        "model.layers.0.mlp.down_proj.weight",
+        "model.layers.0.input_layernorm.bias",
+        "model.layers.0.post_attention_layernorm.weight",
+    ):
+        assert name in names, name
+    assert "lm_head.weight" not in names
+    assert wqkv.shape == (64 + 2 * 32, 64)  # [q | k | v] rows, published
+    assert a_log.shape == (128, 16)  # [channels, states], published
+    spec2, loaded = loader.load_model_dir(str(tmp_path), name=SPEC.name)
+    assert dataclasses.replace(spec2, vocab_draw_blocks=8) == SPEC
+    for a, b in zip(jax.tree.leaves(params), jax.tree.leaves(loaded)):
+        np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+
+
+def test_a_lower_precision_than_stated_fails_a_tolerance(model, ref):
+    _, toks, want = model
+    low = np.asarray(ref.forward(
+        CONFIG, SEED, toks[:1], np.arange(T)[None], quant="fp8"))
+    assert float(np.abs(low - want[:1]).max()) > 100 * TOL
+
+
+def _engine(**kw):
+    cfg = dict(
+        page_size=PAGE, num_pages=96, max_pages_per_seq=PAGES_PER_SEQ,
+        max_decode_slots=4, prefill_buckets=(16, 32),
+        max_prefill_chunk_tokens=32, prefill_pack_size=2,
+        decode_steps_per_dispatch=4, seed=SEED)
+    cfg.update(kw)
+    return InferenceEngine(SPEC, EngineConfig(**cfg))
+
+
+async def _greedy(engine, prompt, n):
+    out = []
+    async for item in engine.generate(
+        {"token_ids": [int(t) for t in prompt],
+         "sampling": {"temperature": 0.0},
+         "stop": {"max_tokens": n, "ignore_eos": True}}, Context(),
+    ):
+        out.extend(item.get("token_ids") or [])
+    return out
+
+
+async def test_serves_through_the_engine_and_counts(model, ref):
+    _, toks, _ = model
+    engine = _engine()
+    assert engine.fam.recurrent and not engine.fam.supports_prefix_reuse
+    await engine.start()
+    try:
+        made = await _greedy(engine, toks[0, :30], 9)
+        more = await _greedy(engine, toks[2, :7], 3)
+    finally:
+        await engine.close()
+    for prompt, out in ((toks[0, :30], made), (toks[2, :7], more)):
+        row = np.concatenate([prompt, out]).astype(np.int32)[None]
+        at = np.arange(len(prompt) - 1, len(prompt) - 1 + len(out))[None]
+        want = np.asarray(ref.forward(CONFIG, SEED, row, at))[0]
+        assert out == [int(t) for t in want.argmax(-1)]
+    snap = engine.profile_snapshot()
+    # by hand: two prompts of 30 and 7 tokens through the lower layers, one
+    # row each through the upper
+    assert snap["prefill.rows"]["calls"] == 37
+    assert snap["prefill.cross_rows"]["calls"] == 2
+    # two cross layers read the live rows' tokens at every dispatched step
+    steps = snap["kv.shared_read_tokens"]["calls"]
+    assert steps and steps % 2 == 0
+    assert steps >= 2 * (sum(range(31, 39)) + sum(range(8, 10)))
+    # one window layer to the two cross layers
+    assert 2 * snap["kv.window_layer_tokens"]["calls"] == steps
+
+
+def test_the_memory_guard_charges_the_chunk_form():
+    cfg = EngineConfig(
+        page_size=64, num_pages=512, max_pages_per_seq=160,
+        max_decode_slots=8, prefill_buckets=(1024,), prefill_pack_size=2,
+        max_prefill_chunk_tokens=1024)
+    wide = dataclasses.replace(
+        SPEC, hidden_size=2560, scan_inner=5120, num_heads=40, head_dim=64)
+    # two rows' pairs of a chunk and their copies are ~0.5 GB: a pack of two
+    # fits 1 GB and not 0.4
+    assert cfg.prefill_shapes(wide, 2 ** 30)[1024] == 2
+    assert cfg.prefill_shapes(wide, int(0.4 * 2 ** 30))[1024] == 1

@@ -146,6 +146,18 @@ PROFILE_COUNTERS: dict[str, str] = {
     "kv.window_dead_tokens": "of those, the tokens more than the layer's "
                              "window behind their row's length: what "
                              "pages found by layer kind would free",
+    # a model whose upper layers write no cache (SambaY's cross-decoder:
+    # the regions gmu, attn_cross and attn_diff beside scan) only
+    "kv.shared_read_tokens": "tokens the live slots hold at each decode "
+                             "step x the layers that read ANOTHER layer's "
+                             "pages (attn_cross): what the one shared pool "
+                             "is read for beyond its own layer",
+    "prefill.rows": "prompt tokens the prefill programs took through the "
+                    "layers below ModelSpec.carried_from (scan, attn_window, "
+                    "attn_full)",
+    "prefill.cross_rows": "rows, one a sequence with tokens, they took "
+                          "through the layers above (gmu, attn_cross): "
+                          "cross_rows / rows is ~1 / the prompts' length",
     "kv_pool.heads_per_lane_row": "KV heads a row of an attention kind's K "
                                   "pool holds (ops/attention.pool_head_dim: "
                                   "2 where 64-wide heads pack two a "
@@ -472,6 +484,12 @@ METRIC_NAMES: dict[str, str] = {
                                   "its live slots held a decode step x "
                                   "window layers (held) and those past "
                                   "their window (dead)",
+    "engine_carried_rows_total": "a model whose upper layers write no "
+                                 "cache: prompt tokens through the lower "
+                                 "layers (rows), rows through the upper "
+                                 "(cross_rows), live tokens x layers that "
+                                 "read another's pages a decode step "
+                                 "(shared_read_tokens)",
     "engine_admission_rejects_total": "requests refused at admission by "
                                       "reason (draining | saturated | "
                                       "deadline) — the 503/504 feeders",
