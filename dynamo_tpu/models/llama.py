@@ -1612,7 +1612,7 @@ def _scan_prefill(
     with jax.named_scope(SCOPE_KV):
         y, s_pool = scan_chunk_prefill(
             x, dt, -jnp.exp(lp["scan_a_log"]), B, C, lp["scan_d"], s_pool,
-            idx, fresh, layer=lj,
+            idx, fresh, layer=lj, num_tokens=num_tokens,
         )
         # the new tail: the projections of the last taps - 1 REAL tokens
         new_tail = _new_tail(ext, num_tokens, spec.scan_conv - 1)

@@ -75,8 +75,13 @@ SCOPE_SSD_STEP = "ssd_step"  # SSD decode: the state rows' update
 SCOPE_SSD_CHUNK = "ssd_chunk"  # SSD prefill: the chunk form, all of it
 SCOPE_STATE_ROWS = "state_rows"  # the recurrent state's directory
 SCOPE_SCAN = "scan"  # Mamba-1's selective scan: the decode step's kernel
-# (and its XLA twin), the prefill's chunk form. Its projections,
-# convolution and gates report under the SSD mixer's names
+# (and its XLA twin), the prefill's walk (``scan_chunk`` below, and its
+# XLA twin, the chunk form). Its projections, convolution and gates
+# report under the SSD mixer's names
+SCOPE_SCAN_CHUNK = "scan_chunk"  # the prefill walk's kernel, named by its
+# own jit (ops/pallas/scan.py: scan_chunk), called inside ``scan``: a LEAF
+# of that region (``LEAVES``), so the region's seconds compare across the
+# XLA form and the kernel
 SCOPE_ATTN_CROSS = "attn_cross"  # a layer that reads another layer's
 # pages and writes none (SambaY's cross-decoder): its decode kernel
 SCOPE_ATTN_DIFF = "attn_diff"  # differential attention: the two maps'
@@ -136,6 +141,11 @@ REGIONS: dict[str, str] = {
     SCOPE_INDEX: REST, SCOPE_BURST: REST, SCOPE_FEED: REST,
 }
 
+# kernel names that are no region of their own: each is opened inside the
+# region it names here and reports as a leaf of it (``resolve`` passes
+# over a name that ``REGIONS`` does not hold)
+LEAVES: dict[str, str] = {SCOPE_SCAN_CHUNK: SCOPE_SCAN}
+
 # the names a trace gives the Mosaic calls, which the benchmark's
 # ``kernels.*`` metrics read by: each stays the innermost name around its
 # kernel
@@ -143,7 +153,7 @@ KERNEL_SCOPES = (
     SCOPE_FUSED_DECODE, SCOPE_ATTN_WINDOW, SCOPE_ATTN_FULL,
     SCOPE_ATTN_LATENT, SCOPE_PREFILL_LATENT, SCOPE_GMM, SCOPE_KDA_STEP,
     SCOPE_KDA_CHUNK, SCOPE_SSD_STEP, SCOPE_SSD_CHUNK, SCOPE_SCAN,
-    SCOPE_ATTN_CROSS,
+    SCOPE_ATTN_CROSS, SCOPE_SCAN_CHUNK,
 )
 
 _WRAPPED = re.compile(r"\(([^()]*)\)")
@@ -159,14 +169,18 @@ def _bare(component: str) -> str:
 def resolve(op_name: str) -> tuple[str | None, str]:
     """(region, leaf) of an operation from its ``op_name``: the innermost
     component that is a name of ``REGIONS`` (None where the path holds
-    none), and the path's last component, the primitive."""
+    none), and the path's last component, the primitive, or the name of
+    ``LEAVES`` it stands under inside the region."""
     parts = [p for p in op_name.split("/") if p]
     if not parts:
         return None, ""
+    leaf = parts[-1]
     for part in reversed(parts):
         name = _bare(part)
         if name in REGIONS:
-            return name, parts[-1]
+            return name, leaf
+        if name in LEAVES:
+            leaf = name
     return None, parts[-1]
 
 

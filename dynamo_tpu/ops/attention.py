@@ -1511,16 +1511,22 @@ def kda_step_xla(pool, conv, rows, x, taps, alpha, beta, *, layer: int):
 # + 1, N, C]: ops/pallas/scan.py). Appended at the file's end: no softmax,
 # KDA or SSD line moved.
 
-# tokens a chunk of the prefill form: its two temporaries are ``[rows,
+# tokens a chunk of the prefill's XLA form (the twin of the ``scan_chunk``
+# kernel: the CPU, ``DYNAMO_PALLAS=0``): its two temporaries are ``[rows,
 # chunk, N, C]`` float32 each, 21 MB a row at 64 x 16 x 5,120
 SCAN_CHUNK = 64
 
 
 @jax.named_scope(SCOPE_SCAN)
 def scan_chunk_prefill(x, dt, A, B, C, D, pool, rows, fresh, *, layer: int,
-                       chunk: int = SCAN_CHUNK):
+                       num_tokens=None, chunk: int = SCAN_CHUNK):
     """The selective scan over whole rows, from and to the sequences' rows
-    of the state pool ``[L, rows + 1, N, C]`` float32: a ``lax.scan`` over
+    of the state pool ``[L, rows + 1, N, C]`` float32, and the place the
+    implementation is chosen: the ``scan_chunk`` kernel
+    (ops/pallas/scan.py) wherever Pallas is active (the state on the chip
+    through the walk of a row's tokens, the recurrence token by token, a
+    block of tokens past ``num_tokens`` skipped), else its XLA twin
+    (counted ``no_pallas_backend``): a ``lax.scan`` over
     chunks of ``chunk`` tokens that carries the state, and inside a chunk
     the recurrence as an ASSOCIATIVE scan of the pairs ``(decay, input)``
     under ``(a1, b1) . (a2, b2) = (a1 a2, a2 b1 + b2)``: every factor is an
@@ -1529,11 +1535,30 @@ def scan_chunk_prefill(x, dt, A, B, C, D, pool, rows, fresh, *, layer: int,
     underflow). Its temporaries are a chunk's pairs, ``[rows, chunk, N,
     C]`` float32 twice (21 MB a row each at 64 x 16 x 5,120; a 1,024-row
     call walks 16 chunks, a pack of two holds 84 MB), and the scan's
-    halved copies of them. Plain XLA, all in float32. x: [R, T, C]; dt:
+    halved copies of them. All in float32 on both paths. x: [R, T, C]; dt:
     [R, T, C] float32, softplus applied, 0 at a padded token (which then
     leaves the state as it was and adds nothing); A: [N, C], D: [C]
     float32; B, C: [R, T, N]; rows: [R] int32 (the pool's last row =
-    trash); fresh: [R] bool. Returns (y [R, T, C] float32, pool)."""
+    trash); fresh: [R] bool; num_tokens: [R] int32, the members' real
+    tokens (None: every token), past which a row's ``y`` is unspecified.
+    Returns (y [R, T, C] float32, pool)."""
+    from dynamo_tpu.ops.fallback import note_fallback
+
+    if use_pallas():
+        from dynamo_tpu.ops.pallas.scan import scan_chunk
+
+        if num_tokens is None:
+            num_tokens = jnp.full(x.shape[:1], x.shape[1], jnp.int32)
+        # the kernel's own jit carries its name, SCOPE_SCAN_CHUNK, a leaf
+        # of the region opened here
+        y, pool = scan_chunk(
+            x, dt, A, B, C, pool, rows, fresh, num_tokens, layer,
+            interpret=jax.default_backend() != "tpu",
+        )
+        return y + D * x.astype(jnp.float32), pool
+    note_fallback("no_pallas_backend", expected=True,
+                  detail="scan_chunk_prefill: lax.scan over chunks, an "
+                         "associative scan inside")
     f32 = jnp.float32
     R, T, Cn = x.shape
     Q = min(chunk, T)

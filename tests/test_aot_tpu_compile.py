@@ -826,6 +826,37 @@ def test_scan_step_compiles_for_v5e(v5e):
     assert "%scan" in compiled.as_text()
 
 
+def test_scan_chunk_compiles_for_v5e(v5e):
+    """The cell's packed prefill: two rows of 1,024 tokens over 5,120
+    channels, the state ``[16, 5120]`` float32 from and to a row of the
+    129-row pool in place, the rows, the fresh flags and the lengths
+    scalar-prefetched; ``x`` in the activations' dtype."""
+    from dynamo_tpu.ops.pallas.scan import scan_chunk
+
+    members, tokens, n, ch, rows = 2, 1024, 16, 5120, 128
+    f32, i32 = jnp.float32, jnp.int32
+    compiled = jax.jit(
+        lambda x, dt, a, b, c, pool, at, fresh, lens: scan_chunk(
+            x, dt, a, b, c, pool, at, fresh, lens, 1),
+        donate_argnums=(5,),
+    ).lower(
+        _rows(v5e, members, tokens, ch),
+        _rows(v5e, members, tokens, ch, dtype=f32),
+        _rows(v5e, n, ch, dtype=f32),
+        _rows(v5e, members, tokens, n, dtype=f32),
+        _rows(v5e, members, tokens, n, dtype=f32),
+        _rows(v5e, 4, rows + 1, n, ch, dtype=f32),
+        _rows(v5e, members, dtype=i32),
+        _rows(v5e, members, dtype=jnp.bool_),
+        _rows(v5e, members, dtype=i32),
+    ).compile()
+    # the kernel is named after its own jit: the trace's readers match it
+    assert "%scan_chunk" in compiled.as_text()
+    # the pool is updated in place: no copy of its 42 MB a layer
+    assert compiled.memory_analysis().alias_size_in_bytes >= (
+        4 * (rows + 1) * n * ch * 4)
+
+
 def _phi4flash_layers(v5e, vocab=2048):
     """One layer of each kind of Phi-4-mini-flash at the published widths
     (the published layers 15-19: window, scan (the memory), full, GMU,
@@ -883,6 +914,8 @@ def test_phi4flash_prefill_program_compiles_for_v5e(v5e, monkeypatch, rows):
     text = lowered.compile().as_text()
     for region in ("scan", "gmu", "attn_cross", "attn_diff"):
         assert f"/{region}/" in text, region
+    # the scan's walk is the kernel, a leaf of the region ``scan``
+    assert "%scan_chunk" in text and "/scan/jit(scan_chunk)/" in text
 
 
 def test_phi4flash_decode_program_compiles_for_v5e(v5e, monkeypatch):

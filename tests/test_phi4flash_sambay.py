@@ -322,8 +322,12 @@ def _scan_case(T_, seed=3, R=2, C=128, N=16):
     return x, dt, A, B, Cm, D
 
 
+@pytest.mark.parametrize("pallas", ["0", "1"], ids=["xla", "kernel"])
 @pytest.mark.parametrize("T_", [16, 64, 150], ids=["short", "one-chunk", "ragged"])
-def test_the_chunk_form_equals_the_token_recurrence(T_):
+def test_the_chunk_form_equals_the_token_recurrence(monkeypatch, T_, pallas):
+    """The XLA twin and the ``scan_chunk`` kernel (interpreted), each
+    against the recurrence a token at a time."""
+    monkeypatch.setenv("DYNAMO_PALLAS", pallas)
     x, dt, A, B, C, D = _scan_case(T_)
     R, _, Cn = x.shape
     N = A.shape[0]
@@ -334,7 +338,8 @@ def test_the_chunk_form_equals_the_token_recurrence(T_):
     real = jnp.arange(T_) < T_ - 3
     dt = dt.at[1].set(jnp.where(real[:, None], dt[1], 0.0))
     y, pool2 = attn_ops.scan_chunk_prefill(
-        x, dt, A, B, C, D, pool, rows, jnp.asarray([False, True]), layer=1)
+        x, dt, A, B, C, D, pool, rows, jnp.asarray([False, True]), layer=1,
+        num_tokens=jnp.asarray([T_, T_ - 3], jnp.int32))
     for r, start in ((0, s0[0]), (1, jnp.zeros_like(s0[0]))):
         want_y, want_s = _scan_recurrence(
             x[r], dt[r], A, B[r], C[r], D, start)
@@ -342,6 +347,55 @@ def test_the_chunk_form_equals_the_token_recurrence(T_):
         np.testing.assert_allclose(pool2[1, 1 + r], want_s, rtol=2e-5,
                                    atol=2e-5)
     np.testing.assert_array_equal(pool2[0], pool[0])
+    np.testing.assert_array_equal(pool2[1, 0], pool[1, 0])  # nobody's row
+
+
+@pytest.mark.parametrize("pallas", ["0", "1"], ids=["xla", "kernel"])
+def test_a_pack_with_an_empty_member_on_the_trash_row(monkeypatch, pallas):
+    """A pack of two whose second member has no tokens and owns no row:
+    the first member's walk is the recurrence, the rows of the pool that
+    belong to somebody are as they were."""
+    monkeypatch.setenv("DYNAMO_PALLAS", pallas)
+    T_ = 32
+    x, dt, A, B, C, D = _scan_case(T_)
+    N, Cn = A.shape
+    pool = jax.random.normal(
+        jax.random.PRNGKey(9), (2, 4, N, Cn), jnp.float32)
+    dt = dt.at[1].set(0.0)
+    y, pool2 = attn_ops.scan_chunk_prefill(
+        x, dt, A, B, C, D, pool, jnp.asarray([2, 3], jnp.int32),
+        jnp.asarray([False, True]), layer=0,
+        num_tokens=jnp.asarray([T_, 0], jnp.int32))
+    want_y, want_s = _scan_recurrence(
+        x[0], dt[0], A, B[0], C[0], D, pool[0, 2])
+    np.testing.assert_allclose(y[0], want_y, rtol=2e-5, atol=2e-5)
+    np.testing.assert_allclose(pool2[0, 2], want_s, rtol=2e-5, atol=2e-5)
+    assert bool(jnp.isfinite(y[1]).all())
+    np.testing.assert_array_equal(pool2[0, :2], pool[0, :2])
+    np.testing.assert_array_equal(pool2[1], pool[1])
+
+
+@pytest.mark.parametrize("pallas", ["0", "1"], ids=["xla", "kernel"])
+def test_the_blocks_past_a_rows_tokens_leave_its_state(monkeypatch, pallas):
+    """A row of 1,024 places with 40 real tokens: the state is what the
+    40 tokens make of it (the kernel walks one block of its sixteen and
+    skips the rest), every ``y`` is finite."""
+    monkeypatch.setenv("DYNAMO_PALLAS", pallas)
+    T_, n = 1024, 40
+    x, dt, A, B, C, D = _scan_case(T_, R=1)
+    N, Cn = A.shape
+    pool = jax.random.normal(
+        jax.random.PRNGKey(9), (1, 3, N, Cn), jnp.float32)
+    dt = jnp.where((jnp.arange(T_) < n)[None, :, None], dt, 0.0)
+    y, pool2 = attn_ops.scan_chunk_prefill(
+        x, dt, A, B, C, D, pool, jnp.asarray([1], jnp.int32),
+        jnp.asarray([False]), layer=0, num_tokens=jnp.asarray([n], jnp.int32))
+    want_y, want_s = _scan_recurrence(
+        x[0, :n], dt[0, :n], A, B[0, :n], C[0, :n], D, pool[0, 1])
+    np.testing.assert_allclose(y[0, :n], want_y, rtol=2e-5, atol=2e-5)
+    np.testing.assert_allclose(pool2[0, 1], want_s, rtol=2e-5, atol=2e-5)
+    assert bool(jnp.isfinite(y).all())
+    np.testing.assert_array_equal(pool2[0, 0], pool[0, 0])
 
 
 def test_scan_step_equals_its_xla_twin_and_the_recurrence(monkeypatch):

@@ -129,7 +129,10 @@ def _executed(comps: dict, entry: int) -> list[dict]:
 
 def test_registry_groups_every_scope_constant():
     names = {v for k, v in vars(regions).items() if k.startswith("SCOPE_")}
-    assert names == set(regions.REGIONS)
+    # a kernel's name that is a LEAF of a region is no region itself
+    assert names == set(regions.REGIONS) | set(regions.LEAVES)
+    assert not set(regions.REGIONS) & set(regions.LEAVES)
+    assert set(regions.LEAVES.values()) <= set(regions.REGIONS)
     assert set(regions.REGIONS.values()) == set(regions.GROUPS)
     assert set(regions.KERNEL_SCOPES) <= names
     assert all(regions.group_of(n) == g for n, g in regions.REGIONS.items())
@@ -178,6 +181,10 @@ def test_the_program_opens_only_names_of_the_registry():
      ("gmm", "pallas_call")),
     ("jit(f)/attn_kv/vmap(attn_full)/dot_general",
      ("attn_full", "dot_general")),
+    # a kernel that is a leaf of its region, not a region
+    ("jit(f)/attn_kv/scan/jit(scan_chunk)/pallas_call",
+     ("scan", "scan_chunk")),
+    ("jit(f)/attn_kv/scan/mul", ("scan", "mul")),
     ("jit(f)/while/body/add", (None, "add")),
     ("", (None, "")),
 ])

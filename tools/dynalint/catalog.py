@@ -147,7 +147,9 @@ PROFILE_COUNTERS: dict[str, str] = {
                              "window behind their row's length: what "
                              "pages found by layer kind would free",
     # a model whose upper layers write no cache (SambaY's cross-decoder:
-    # the regions gmu, attn_cross and attn_diff beside scan) only
+    # the regions gmu, attn_cross and attn_diff beside scan, whose prefill
+    # kernel scan_chunk is a LEAF of that region, models/regions.py:
+    # LEAVES, and no region of its own) only
     "kv.shared_read_tokens": "tokens the live slots hold at each decode "
                              "step x the layers that read ANOTHER layer's "
                              "pages (attn_cross): what the one shared pool "
