@@ -48,6 +48,49 @@ def decode_schedule(request) -> dict:
     return request.param
 
 
+def pytest_collection_modifyitems(items):
+    """The layer families' files (a module with a ``FAMILY``: its row of
+    ``tests/family_contract.py``) run first. ``--dist load`` deals the
+    collection in runs of consecutive tests, the longest at the start
+    (a 24th of the collection a worker), so a family's cases land on one
+    worker or two, which compile its toy programs once, where the end of
+    the collection is dealt two tests at a time to whichever worker is
+    free and every worker compiles every family. The order is the same
+    on every run."""
+    items.sort(key=lambda item: not hasattr(
+        getattr(item, "module", None), "FAMILY"))
+
+
+@pytest.fixture(scope="module", autouse=True)
+def _a_familys_programs_go_with_its_file(request):
+    """A compiled CPU program holds memory MAPPINGS for as long as JAX
+    caches it, and a process may hold 65,530 (``vm.max_map_count``): one
+    family's file leaves ~19,000 behind, and nothing after it runs its toy
+    spec again. Behind a family's LAST test on a worker, what the worker
+    compiled goes; the families run first, so little else is held yet."""
+    yield
+    if hasattr(request.module, "FAMILY"):
+        jax.clear_caches()
+
+
+@pytest.fixture(scope="module")
+def ref(request):
+    """The plain reference of the module's ``FAMILY`` (its row of the
+    contract, ``tests/family_contract.py``), loaded by path."""
+    import family_contract
+
+    return family_contract._reference(request.module.FAMILY)
+
+
+@pytest.fixture(scope="module")
+def model(request):
+    """(weights, tokens, the reference's logits) of the module's
+    ``FAMILY``, made once a worker."""
+    import family_contract
+
+    return family_contract._model(request.module.FAMILY)
+
+
 def pytest_pyfunc_call(pyfuncitem):
     """Run ``async def`` tests with asyncio.run (pytest-asyncio is not installed)."""
     fn = pyfuncitem.obj

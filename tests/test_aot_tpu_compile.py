@@ -12,6 +12,7 @@ short on a slow box, and these guard every later kernel change at no
 chip time.
 """
 
+import dataclasses
 import os
 
 os.environ.setdefault("TPU_LOG_DIR", "disabled")  # keep compiler logs out of /tmp
@@ -23,6 +24,9 @@ import jax.numpy as jnp
 import pytest
 from jax.sharding import SingleDeviceSharding
 
+from dynamo_tpu.engine.config import LayerKind, ModelSpec
+from dynamo_tpu.models import llama, mla
+from dynamo_tpu.models.family import get_family
 from dynamo_tpu.ops.pallas.fused_decode import fused_decode_attention
 from dynamo_tpu.ops.pallas.kv_write import kv_write_pallas
 from dynamo_tpu.ops.quant import FP8_DTYPE, SCALE_DTYPE, QuantPool
@@ -321,482 +325,6 @@ def test_ssd_step_compiles_for_v5e(v5e):
     assert "%ssd_step" in compiled.as_text()
 
 
-def _falcon_layer(v5e, vocab=2048):
-    """One layer of Falcon-H1 at the published widths, described: spec,
-    weights and the cache of the cell's engine (128 slots, pages of 64)."""
-    import dataclasses
-
-    from dynamo_tpu.engine.config import LayerKind, ModelSpec
-    from dynamo_tpu.models import llama
-
-    spec = dataclasses.replace(
-        ModelSpec.tiny_falcon_h1(), vocab_size=vocab, hidden_size=5120,
-        intermediate_size=21504, num_layers=1, num_heads=20, num_kv_heads=4,
-        head_dim=128, dtype="bfloat16", layer_pattern=(0,),
-        layer_kinds=(LayerKind(4, 1e11, mixer="ssd"),),
-        ssm_heads=32, ssm_head_dim=128, ssm_state=256, ssm_chunk=128)
-
-    def described(tree):
-        return jax.tree.map(
-            lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=v5e),
-            tree)
-
-    params = described(jax.eval_shape(
-        lambda: llama.init_params(spec, jax.random.PRNGKey(0))))
-    k, v = described(jax.eval_shape(
-        lambda: llama.init_cache(spec, 257, 64, state_rows=128)))
-    return spec, params, k, v
-
-
-def _as_on_the_chip(monkeypatch):
-    """The programs choose their kernels, and whether to interpret them,
-    by the default backend: for a described device it is the CPU, so the
-    choice is told what the chip would say."""
-    monkeypatch.setenv("DYNAMO_PALLAS", "1")
-    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
-
-
-@pytest.mark.parametrize("rows", [2, 1], ids=["pack-of-2", "single"])
-def test_falcon_prefill_program_compiles_for_v5e(v5e, monkeypatch, rows):
-    """The prefill programs of the cell, a layer of them at the published
-    widths: 1,024 tokens a row through the page write, the walk and the
-    SSD chunk form (8 chunks of 128), state and tails from and to their
-    rows; every leaf of the cache donated."""
-    from dynamo_tpu.models import llama
-
-    _as_on_the_chip(monkeypatch)
-    spec, params, k, v = _falcon_layer(v5e)
-    i32 = jnp.int32
-    if rows == 1:
-        lowered = jax.jit(
-            llama.prefill_forward_impl, static_argnums=(0,),
-            donate_argnums=(5, 6),
-        ).lower(spec, params, _rows(v5e, 1024, dtype=i32),
-                _rows(v5e, 160, dtype=i32), _rows(v5e, dtype=i32), k, v,
-                _rows(v5e, dtype=i32))
-    else:
-        lowered = jax.jit(
-            llama.prefill_forward_batch_impl, static_argnums=(0,),
-            donate_argnums=(5, 6),
-        ).lower(spec, params, _rows(v5e, rows, 1024, dtype=i32),
-                _rows(v5e, rows, 160, dtype=i32), _rows(v5e, rows, dtype=i32),
-                k, v, _rows(v5e, rows, dtype=i32))
-    text = lowered.compile().as_text()
-    assert "ssd_chunk" in text
-
-
-def test_falcon_decode_program_compiles_for_v5e(v5e, monkeypatch):
-    """The decode burst of the cell, a layer of it at the published
-    widths: 128 slots through the fused attention kernel (``attn_full``)
-    AND ``ssd_step`` in one layer, 8 steps, the sampler on the device."""
-    from dynamo_tpu.models import llama
-
-    _as_on_the_chip(monkeypatch)
-    spec, params, k, v = _falcon_layer(v5e)
-    B_, i32, f32 = 128, jnp.int32, jnp.float32
-    text = jax.jit(
-        llama.decode_steps_impl, static_argnums=(0,),
-        static_argnames=("n_steps", "n_logprobs"), donate_argnums=(5, 6),
-    ).lower(
-        spec, params, _rows(v5e, B_, dtype=i32), _rows(v5e, B_, 160, dtype=i32),
-        _rows(v5e, B_, dtype=i32), k, v, _rows(v5e, B_, dtype=jnp.bool_),
-        _rows(v5e, B_, dtype=f32), _rows(v5e, B_, dtype=i32),
-        _rows(v5e, B_, dtype=f32), _rows(v5e, B_, dtype=jnp.uint32),
-        _rows(v5e, B_, dtype=i32), n_steps=8, n_logprobs=0,
-    ).compile().as_text()
-    assert "%ssd_step" in text and "%attn_full" in text
-
-
-def _ling_layers(v5e, vocab=2048):
-    """A KDA expert layer and the latent (MLA) expert layer of Ling-3.0-
-    flash at the published widths, described: spec, weights and the cache
-    of the cell's engine (128 slots, pages of 64; 64 of 512 experts held,
-    one of the router's 8 groups; both layers clamped)."""
-    import dataclasses
-
-    from dynamo_tpu.engine.config import LayerKind, ModelSpec
-    from dynamo_tpu.models import llama
-
-    spec = dataclasses.replace(
-        ModelSpec.tiny_ling3(), vocab_size=vocab, hidden_size=2560,
-        intermediate_size=6144, num_layers=2, num_heads=32, num_kv_heads=32,
-        head_dim=128, dtype="bfloat16", layer_pattern=(1, 0),
-        layer_kinds=(
-            LayerKind(0, 6e6, mixer="latent", head_gate=True),
-            LayerKind(0, 0.0, mixer="kda", gate_bound=-5.0, full_rank=True),
-        ),
-        kv_lora_rank=512, qk_nope_head_dim=128, qk_rope_head_dim=64,
-        v_head_dim=128, rotary_dim=64, kda_heads=32, kda_head_dim=128,
-        num_experts=512, held_experts=(64, 0), num_experts_per_token=8,
-        moe_intermediate_size=768, n_group=8, topk_group=4,
-        first_k_dense=0, expert_clamp=(4.0, 4.0), shared_clamp=(5.0, 7.0))
-
-    def described(tree):
-        return jax.tree.map(
-            lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=v5e),
-            tree)
-
-    params = described(jax.eval_shape(
-        lambda: llama.init_params(spec, jax.random.PRNGKey(0))))
-    k, v = described(jax.eval_shape(
-        lambda: llama.init_cache(spec, 257, 64, state_rows=128)))
-    return spec, params, k, v
-
-
-@pytest.mark.parametrize("rows", [2, 1], ids=["pack-of-2", "single"])
-def test_ling_prefill_program_compiles_for_v5e(v5e, monkeypatch, rows):
-    """The prefill programs of the cell, a layer of each kind at the
-    published widths: 1,024 tokens a row through ``kda_chunk`` at 32 heads
-    from and to the state rows AND the latent page write and
-    ``prefill_latent`` over the kind's pool, under one block table; the
-    group-limited router over 512 experts; every leaf donated."""
-    from dynamo_tpu.models import llama
-
-    _as_on_the_chip(monkeypatch)
-    spec, params, k, v = _ling_layers(v5e)
-    i32 = jnp.int32
-    if rows == 1:
-        lowered = jax.jit(
-            llama.prefill_forward_impl, static_argnums=(0,),
-            donate_argnums=(5, 6),
-        ).lower(spec, params, _rows(v5e, 1024, dtype=i32),
-                _rows(v5e, 160, dtype=i32), _rows(v5e, dtype=i32), k, v,
-                _rows(v5e, dtype=i32))
-    else:
-        lowered = jax.jit(
-            llama.prefill_forward_batch_impl, static_argnums=(0,),
-            donate_argnums=(5, 6),
-        ).lower(spec, params, _rows(v5e, rows, 1024, dtype=i32),
-                _rows(v5e, rows, 160, dtype=i32), _rows(v5e, rows, dtype=i32),
-                k, v, _rows(v5e, rows, dtype=i32))
-    text = lowered.compile().as_text()
-    assert "%kda_chunk" in text and "%prefill_latent" in text
-
-
-def test_ling_decode_program_compiles_for_v5e(v5e, monkeypatch):
-    """The decode burst of the cell, a layer of each kind at the published
-    widths: 128 slots through ``kda_step`` at 32 heads AND ``attn_latent``
-    on the kind's pool (its schedule made once a step) in one program, 8
-    steps, the sampler on the device."""
-    from dynamo_tpu.models import llama
-
-    _as_on_the_chip(monkeypatch)
-    spec, params, k, v = _ling_layers(v5e)
-    B_, i32, f32 = 128, jnp.int32, jnp.float32
-    compiled = jax.jit(
-        llama.decode_steps_impl, static_argnums=(0,),
-        static_argnames=("n_steps", "n_logprobs"), donate_argnums=(5, 6),
-    ).lower(
-        spec, params, _rows(v5e, B_, dtype=i32), _rows(v5e, B_, 160, dtype=i32),
-        _rows(v5e, B_, dtype=i32), k, v, _rows(v5e, B_, dtype=jnp.bool_),
-        _rows(v5e, B_, dtype=f32), _rows(v5e, B_, dtype=i32),
-        _rows(v5e, B_, dtype=f32), _rows(v5e, B_, dtype=jnp.uint32),
-        _rows(v5e, B_, dtype=i32), n_steps=8, n_logprobs=0,
-    ).compile()
-    text = compiled.as_text()
-    assert "%kda_step" in text and "%attn_latent" in text
-    # the kernels update the pools in place: what the program holds beside
-    # its arguments is less than the state pool (258 MiB a KDA layer)
-    state = k.pools[1]
-    assert compiled.memory_analysis().temp_size_in_bytes < (
-        state.size * state.dtype.itemsize // 2)
-
-
-def _lfm2_layers(v5e, vocab=2048):
-    """A short-convolution expert layer and an attention expert layer of
-    LFM2-24B-A2B at the published widths, described: spec, weights and the
-    cache of the cell's engine (128 slots, pages of 64; all 64 experts
-    held; heads of 64 packed two a 128-lane row of the pools; tails and no
-    state pool)."""
-    import dataclasses
-
-    from dynamo_tpu.engine.config import LayerKind, ModelSpec
-    from dynamo_tpu.models import llama
-
-    spec = dataclasses.replace(
-        ModelSpec.tiny_lfm2(), vocab_size=vocab, hidden_size=2048,
-        intermediate_size=11776, num_layers=2, num_heads=32, num_kv_heads=8,
-        head_dim=64, dtype="bfloat16", layer_pattern=(1, 0),
-        layer_kinds=(LayerKind(8, 1e6), LayerKind(0, 0.0, mixer="conv")),
-        num_experts=64, num_experts_per_token=4, moe_intermediate_size=1536,
-        first_k_dense=0)
-
-    def described(tree):
-        return jax.tree.map(
-            lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=v5e),
-            tree)
-
-    params = described(jax.eval_shape(
-        lambda: llama.init_params(spec, jax.random.PRNGKey(0))))
-    k, v = described(jax.eval_shape(
-        lambda: llama.init_cache(spec, 257, 64, state_rows=128)))
-    return spec, params, k, v
-
-
-@pytest.mark.parametrize("rows", [2, 1], ids=["pack-of-2", "single"])
-def test_lfm2_prefill_program_compiles_for_v5e(v5e, monkeypatch, rows):
-    """The prefill programs of the cell, a layer of each kind at the
-    published widths: 1,024 tokens a row through the short convolution
-    from and to the rows' tails AND the QK-normed attention's page write
-    and walk over 64-wide heads packed two a row in 128-lane pools; the
-    router over 64 experts all held, top-4; every leaf donated."""
-    from dynamo_tpu.models import llama
-
-    _as_on_the_chip(monkeypatch)
-    spec, params, k, v = _lfm2_layers(v5e)
-    # 8 KV heads of 64 as 4 rows of 128 lanes: no zeros in the pool
-    assert k.pools[0].shape == v.pools[0].shape == (1, 257, 4, 64, 128)
-    assert k.pools[1] is None
-    assert v.pools[1].shape == (1, 129, 2, 2048)
-    i32 = jnp.int32
-    if rows == 1:
-        lowered = jax.jit(
-            llama.prefill_forward_impl, static_argnums=(0,),
-            donate_argnums=(5, 6),
-        ).lower(spec, params, _rows(v5e, 1024, dtype=i32),
-                _rows(v5e, 160, dtype=i32), _rows(v5e, dtype=i32), k, v,
-                _rows(v5e, dtype=i32))
-    else:
-        lowered = jax.jit(
-            llama.prefill_forward_batch_impl, static_argnums=(0,),
-            donate_argnums=(5, 6),
-        ).lower(spec, params, _rows(v5e, rows, 1024, dtype=i32),
-                _rows(v5e, rows, 160, dtype=i32), _rows(v5e, rows, dtype=i32),
-                k, v, _rows(v5e, rows, dtype=i32))
-    text = lowered.compile().as_text()
-    assert "%gmm" in text and "conv_mix" in text
-
-
-def test_lfm2_decode_program_compiles_for_v5e(v5e, monkeypatch):
-    """The decode burst of the cell, a layer of each kind at the published
-    widths: 128 slots through the short convolution's tails (plain XLA)
-    AND the fused attention kernel on the packed pool (``attn_full``: 4
-    heads of 128 lanes under 8 query heads each), the grouped products
-    over 64 groups, 8 steps, the sampler on the device."""
-    from dynamo_tpu.models import llama
-
-    _as_on_the_chip(monkeypatch)
-    spec, params, k, v = _lfm2_layers(v5e)
-    B_, i32, f32 = 128, jnp.int32, jnp.float32
-    compiled = jax.jit(
-        llama.decode_steps_impl, static_argnums=(0,),
-        static_argnames=("n_steps", "n_logprobs"), donate_argnums=(5, 6),
-    ).lower(
-        spec, params, _rows(v5e, B_, dtype=i32), _rows(v5e, B_, 160, dtype=i32),
-        _rows(v5e, B_, dtype=i32), k, v, _rows(v5e, B_, dtype=jnp.bool_),
-        _rows(v5e, B_, dtype=f32), _rows(v5e, B_, dtype=i32),
-        _rows(v5e, B_, dtype=f32), _rows(v5e, B_, dtype=jnp.uint32),
-        _rows(v5e, B_, dtype=i32), n_steps=8, n_logprobs=0,
-    ).compile()
-    text = compiled.as_text()
-    assert "%attn_full" in text and "%gmm" in text and "conv_mix" in text
-    # the tails are updated in place: what the program holds beside its
-    # arguments is far less than an expert layer's weights (1.2 GB)
-    assert compiled.memory_analysis().temp_size_in_bytes < 256 * 2**20
-
-
-def _longcat_layer(v5e, vocab=2048):
-    """ONE shortcut-connected double layer of LongCat-Flash-Chat at the
-    published widths, described: spec, weights and the cache of the cell's
-    engine (128 slots, pages of 64; 16 of 512 FFN experts held, 256
-    identity experts behind them in a router of 768 outputs; 64 heads
-    over a latent of 512 + 64; a pool of latent pages a sub-layer)."""
-    import dataclasses
-
-    from dynamo_tpu.engine.config import ModelSpec
-    from dynamo_tpu.models import mla
-
-    spec = dataclasses.replace(
-        ModelSpec.tiny_longcat(), vocab_size=vocab, hidden_size=6144,
-        intermediate_size=12288, num_layers=1, num_heads=64, num_kv_heads=64,
-        head_dim=96, dtype="bfloat16", kv_lora_rank=512, q_lora_rank=1536,
-        qk_nope_head_dim=128, qk_rope_head_dim=64, v_head_dim=128,
-        num_experts=512, held_experts=(16, 0), zero_experts=256,
-        num_experts_per_token=12, moe_intermediate_size=2048)
-
-    def described(tree):
-        return jax.tree.map(
-            lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=v5e),
-            tree)
-
-    params = described(jax.eval_shape(
-        lambda: mla.init_params(spec, jax.random.PRNGKey(0))))
-    cache = described(jax.eval_shape(lambda: mla.init_cache(spec, 2049, 64)))
-    counts = described(jax.eval_shape(lambda: mla.init_counts(spec)))
-    return spec, params, cache, counts
-
-
-@pytest.mark.parametrize("rows", [2, 1], ids=["pack-of-2", "single"])
-def test_longcat_prefill_program_compiles_for_v5e(v5e, monkeypatch, rows):
-    """The prefill programs of the cell, one double layer at the published
-    widths: 1,024 tokens a row through both sub-layers' page writes and
-    ``prefill_latent`` at 64 heads, the router over 768 outputs, the
-    grouped products; both pools donated."""
-    from dynamo_tpu.models import mla
-
-    _as_on_the_chip(monkeypatch)
-    spec, params, cache, counts = _longcat_layer(v5e)
-    i32 = jnp.int32
-    if rows == 1:
-        lowered = mla.prefill_forward.lower(
-            spec, params, _rows(v5e, 1024, dtype=i32),
-            _rows(v5e, 160, dtype=i32), _rows(v5e, dtype=i32), cache,
-            _rows(v5e, dtype=i32), counts=counts)
-    else:
-        lowered = mla.prefill_forward_batch.lower(
-            spec, params, _rows(v5e, rows, 1024, dtype=i32),
-            _rows(v5e, rows, 160, dtype=i32), _rows(v5e, rows, dtype=i32),
-            cache, _rows(v5e, rows, dtype=i32), counts=counts)
-    compiled = lowered.compile()
-    text = compiled.as_text()
-    assert "%prefill_latent" in text and "attn_latent" not in text
-    pools = sum(p.size * p.dtype.itemsize for p in cache)
-    assert compiled.memory_analysis().alias_size_in_bytes >= pools
-
-
-def test_longcat_decode_program_compiles_for_v5e(v5e, monkeypatch):
-    """The decode burst of the cell, one double layer at the published
-    widths: 128 slots through ``attn_latent`` at 64 heads TWICE (a pool a
-    sub-layer, the schedule made once a step), the shortcut's expert layer
-    between them, 8 steps, the sampler on the device; the kernels update
-    both pools in place."""
-    from dynamo_tpu.models import mla
-
-    _as_on_the_chip(monkeypatch)
-    spec, params, cache, counts = _longcat_layer(v5e)
-    B_, i32, f32 = 128, jnp.int32, jnp.float32
-    compiled = mla.decode_steps.lower(
-        spec, params, _rows(v5e, B_, dtype=i32), _rows(v5e, B_, 160, dtype=i32),
-        _rows(v5e, B_, dtype=i32), cache, _rows(v5e, B_, dtype=jnp.bool_),
-        _rows(v5e, B_, dtype=f32), _rows(v5e, B_, dtype=i32),
-        _rows(v5e, B_, dtype=f32), _rows(v5e, B_, dtype=jnp.uint32),
-        _rows(v5e, B_, dtype=i32), n_steps=8, n_logprobs=0, counts=counts,
-    ).compile()
-    text = compiled.as_text()
-    assert text.count("tpu_custom_call") == 2 + 3  # two attentions, gmm x 3
-    assert "%attn_latent" in text and "moe_zero" in text
-    pools = sum(p.size * p.dtype.itemsize for p in cache)
-    mem = compiled.memory_analysis()
-    assert mem.alias_size_in_bytes >= pools
-    assert mem.temp_size_in_bytes < pools
-
-
-def _trinity_layers(v5e, vocab=2048):
-    """Two layers of Trinity-Mini (afmoe) at the published widths,
-    described: a window expert layer and a full (NoPE) expert layer,
-    gated and QK-normed, four norms each (the dense MLP of layer 0 is the
-    dense cells' own product at another width); spec,
-    weights and the cache of the cell's engine (64 slots, pages of 64,
-    tables of 160 pages; 16 of 128 experts held beside the shared one; a
-    pool a kind)."""
-    import dataclasses
-
-    from dynamo_tpu.engine.config import LayerKind, ModelSpec
-    from dynamo_tpu.models import llama
-
-    spec = dataclasses.replace(
-        ModelSpec.tiny_trinity(), vocab_size=vocab, hidden_size=2048,
-        intermediate_size=6144, num_layers=2, num_heads=32, num_kv_heads=4,
-        head_dim=128, dtype="bfloat16", layer_pattern=(0, 1), first_k_dense=0,
-        layer_kinds=(LayerKind(4, 1e4, window=2048),
-                     LayerKind(4, 1e4, rope=False)),
-        embedding_multiplier=2048 ** 0.5, num_experts=128,
-        held_experts=(16, 0), num_experts_per_token=8,
-        moe_intermediate_size=1024)
-
-    def described(tree):
-        return jax.tree.map(
-            lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=v5e),
-            tree)
-
-    params = described(jax.eval_shape(
-        lambda: llama.init_params(spec, jax.random.PRNGKey(0))))
-    k, v = described(jax.eval_shape(lambda: llama.init_cache(spec, 513, 64)))
-    return spec, params, k, v
-
-
-@pytest.mark.parametrize("rows", [2], ids=["pack-of-2"])
-def test_trinity_prefill_program_compiles_for_v5e(v5e, monkeypatch, rows):
-    """The prefill programs of the cell, a layer of each sort at the
-    published widths: 4,096 tokens a row through the page write and the
-    walk at a 2,048-token window (nine blocks a tile) and over the whole
-    row, 32 gated heads over 4 KV heads, the router over 128 experts of
-    which 16 are held, the shared expert, the four norms; every leaf
-    donated. What the program holds beside its arguments stays far under
-    what the whole-table charge of ``EngineConfig.prefill_shapes`` would
-    price a 4,096-row bucket at (8 GiB a row)."""
-    from dynamo_tpu.models import llama
-
-    _as_on_the_chip(monkeypatch)
-    spec, params, k, v = _trinity_layers(v5e)
-    assert k.pools[0].shape == v.pools[0].shape == (1, 513, 4, 64, 128)
-    assert k.pools[1].shape == v.pools[1].shape == (1, 513, 4, 64, 128)
-    i32 = jnp.int32
-    if rows == 1:
-        lowered = jax.jit(
-            llama.prefill_forward_impl, static_argnums=(0,),
-            donate_argnums=(5, 6),
-        ).lower(spec, params, _rows(v5e, 4096, dtype=i32),
-                _rows(v5e, 160, dtype=i32), _rows(v5e, dtype=i32), k, v,
-                _rows(v5e, dtype=i32))
-    else:
-        lowered = jax.jit(
-            llama.prefill_forward_batch_impl, static_argnums=(0,),
-            donate_argnums=(5, 6),
-        ).lower(spec, params, _rows(v5e, rows, 4096, dtype=i32),
-                _rows(v5e, rows, 160, dtype=i32), _rows(v5e, rows, dtype=i32),
-                k, v, _rows(v5e, rows, dtype=i32))
-    compiled = lowered.compile()
-    text = compiled.as_text()
-    assert "%gmm" in text and "norm_out" in text and "moe_shared" in text
-    assert compiled.memory_analysis().temp_size_in_bytes < 3 * 2**30
-
-
-def test_trinity_decode_program_compiles_for_v5e(v5e, monkeypatch):
-    """The decode burst of the cell, a layer of each sort at the published
-    widths: 64 slots through the fused kernel TWICE as two programs of it
-    (``attn_window`` over the 33 pages a 2,048-token window reaches, in
-    chunks of 7; ``attn_full`` over the table's 160 in chunks of 8), 8
-    queries a KV head, the gate behind it, the grouped products over 16
-    groups, 8 steps, the sampler on the device; the pools updated in
-    place."""
-    from dynamo_tpu.models import llama
-    from dynamo_tpu.ops.pallas.fused_decode import chunk_pages
-
-    _as_on_the_chip(monkeypatch)
-    spec, params, k, v = _trinity_layers(v5e)
-    page_bytes = 4 * 64 * (128 + 128) * 2
-    assert chunk_pages(page_bytes, 33) == 7
-    assert chunk_pages(page_bytes, 160) == 8
-    B_, i32, f32 = 64, jnp.int32, jnp.float32
-    compiled = jax.jit(
-        llama.decode_steps_impl, static_argnums=(0,),
-        static_argnames=("n_steps", "n_logprobs"), donate_argnums=(5, 6),
-    ).lower(
-        spec, params, _rows(v5e, B_, dtype=i32), _rows(v5e, B_, 160, dtype=i32),
-        _rows(v5e, B_, dtype=i32), k, v, _rows(v5e, B_, dtype=jnp.bool_),
-        _rows(v5e, B_, dtype=f32), _rows(v5e, B_, dtype=i32),
-        _rows(v5e, B_, dtype=f32), _rows(v5e, B_, dtype=jnp.uint32),
-        _rows(v5e, B_, dtype=i32), n_steps=8, n_logprobs=0,
-    ).compile()
-    text = compiled.as_text()
-    assert "%attn_window" in text and "%attn_full" in text and "%gmm" in text
-    assert "norm_out" in text
-    # the stacked weights stay in HBM: the kernel fetches the experts a
-    # step touched itself (``ops/pallas/grouped.py``). megablox's operand
-    # the compiler copied WHOLE into VMEM (memory space S(1)) ahead of
-    # the call, 4 of this program's 6 (PERF.md section 6, PRs 53 and 54)
-    made = dict(re.findall(r"^\s*(?:ROOT )?(%[\w.-]+) = (\S+)", text, re.M))
-    calls = re.findall(r"%gmm[.\d]* = \S+ custom-call\(([^)]*)\)", text)
-    assert len(calls) == 6  # three products a layer
-    for operands in calls:
-        weights = operands.split(",")[-1].strip()
-        assert "[16," in made[weights] and "S(1)" not in made[weights]
-    pools = sum(p.size * p.dtype.itemsize for p in (*k.pools, *v.pools))
-    mem = compiled.memory_analysis()
-    assert mem.alias_size_in_bytes >= pools
-    assert mem.temp_size_in_bytes < 256 * 2**20
-
 
 def test_scan_step_compiles_for_v5e(v5e):
     """Phi-4-mini-flash's cell: 128 slots on 129 state rows of 16 x 5,120
@@ -857,86 +385,296 @@ def test_scan_chunk_compiles_for_v5e(v5e):
         4 * (rows + 1) * n * ch * 4)
 
 
-def _phi4flash_layers(v5e, vocab=2048):
-    """One layer of each kind of Phi-4-mini-flash at the published widths
-    (the published layers 15-19: window, scan (the memory), full, GMU,
-    cross), described: spec, weights and the cache of the cell's engine
-    (128 slots, pages of 64)."""
-    from dynamo_tpu.models import llama
+# ------------------------------------------- whole programs, a row a cell
+
+
+def _as_on_the_chip(monkeypatch):
+    """The programs choose their kernels, and whether to interpret them,
+    by the default backend: for a described device it is the CPU, so the
+    choice is told what the chip would say."""
+    monkeypatch.setenv("DYNAMO_PALLAS", "1")
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+
+
+def _falcon_spec():
+    """One layer of Falcon-H1: attention AND an SSD mixer off one norm."""
+    return dataclasses.replace(
+        ModelSpec.tiny_falcon_h1(), vocab_size=2048, hidden_size=5120,
+        intermediate_size=21504, num_layers=1, num_heads=20, num_kv_heads=4,
+        head_dim=128, dtype="bfloat16", layer_pattern=(0,),
+        layer_kinds=(LayerKind(4, 1e11, mixer="ssd"),),
+        ssm_heads=32, ssm_head_dim=128, ssm_state=256, ssm_chunk=128)
+
+
+def _ling_spec():
+    """A KDA and the latent (MLA) expert layer of Ling-3.0-flash: 64 of 512
+    experts held, one of the router's 8 groups; both layers clamped."""
+    return dataclasses.replace(
+        ModelSpec.tiny_ling3(), vocab_size=2048, hidden_size=2560,
+        intermediate_size=6144, num_layers=2, num_heads=32, num_kv_heads=32,
+        head_dim=128, dtype="bfloat16", layer_pattern=(1, 0),
+        layer_kinds=(
+            LayerKind(0, 6e6, mixer="latent", head_gate=True),
+            LayerKind(0, 0.0, mixer="kda", gate_bound=-5.0, full_rank=True),
+        ),
+        kv_lora_rank=512, qk_nope_head_dim=128, qk_rope_head_dim=64,
+        v_head_dim=128, rotary_dim=64, kda_heads=32, kda_head_dim=128,
+        num_experts=512, held_experts=(64, 0), num_experts_per_token=8,
+        moe_intermediate_size=768, n_group=8, topk_group=4,
+        first_k_dense=0, expert_clamp=(4.0, 4.0), shared_clamp=(5.0, 7.0))
+
+
+def _lfm2_spec():
+    """A short-convolution and an attention expert layer of LFM2-24B-A2B:
+    64 experts all held; heads of 64 packed two a 128-lane row; tails and
+    no state pool."""
+    return dataclasses.replace(
+        ModelSpec.tiny_lfm2(), vocab_size=2048, hidden_size=2048,
+        intermediate_size=11776, num_layers=2, num_heads=32, num_kv_heads=8,
+        head_dim=64, dtype="bfloat16", layer_pattern=(1, 0),
+        layer_kinds=(LayerKind(8, 1e6), LayerKind(0, 0.0, mixer="conv")),
+        num_experts=64, num_experts_per_token=4, moe_intermediate_size=1536,
+        first_k_dense=0)
+
+
+def _longcat_spec():
+    """ONE shortcut-connected double layer of LongCat-Flash-Chat: 16 of 512
+    FFN experts held, 256 identity experts behind them (768 router outputs);
+    64 heads over a latent of 512 + 64; a pool a sub-layer."""
+    return dataclasses.replace(
+        ModelSpec.tiny_longcat(), vocab_size=2048, hidden_size=6144,
+        intermediate_size=12288, num_layers=1, num_heads=64, num_kv_heads=64,
+        head_dim=96, dtype="bfloat16", kv_lora_rank=512, q_lora_rank=1536,
+        qk_nope_head_dim=128, qk_rope_head_dim=64, v_head_dim=128,
+        num_experts=512, held_experts=(16, 0), zero_experts=256,
+        num_experts_per_token=12, moe_intermediate_size=2048)
+
+
+def _trinity_spec():
+    """A window and a full (NoPE) expert layer of Trinity-Mini (afmoe), gated
+    and QK-normed, four norms each (layer 0's dense MLP is the dense cells'
+    own product); 16 of 128 experts held beside the shared one."""
+    return dataclasses.replace(
+        ModelSpec.tiny_trinity(), vocab_size=2048, hidden_size=2048,
+        intermediate_size=6144, num_layers=2, num_heads=32, num_kv_heads=4,
+        head_dim=128, dtype="bfloat16", layer_pattern=(0, 1), first_k_dense=0,
+        layer_kinds=(LayerKind(4, 1e4, window=2048),
+                     LayerKind(4, 1e4, rope=False)),
+        embedding_multiplier=2048 ** 0.5, num_experts=128,
+        held_experts=(16, 0), num_experts_per_token=8,
+        moe_intermediate_size=1024)
+
+
+def _phi4flash_spec():
+    """One layer of each kind of Phi-4-mini-flash (the published layers
+    15-19: window, scan (the memory), full, GMU, cross)."""
     from dynamo_tpu.models.loader import spec_from_hf_config
 
-    spec = spec_from_hf_config(dict(
+    return spec_from_hf_config(dict(
         model_type="phi4flash", hidden_size=2560, num_attention_heads=40,
-        num_key_value_heads=20, intermediate_size=10240, vocab_size=vocab,
+        num_key_value_heads=20, intermediate_size=10240, vocab_size=2048,
         num_hidden_layers=5, layers_kept=[15, 16, 17, 18, 19],
         published_layers=32, sliding_window=512, layer_norm_eps=1e-5,
         mb_per_layer=2, tie_word_embeddings=True, torch_dtype="bfloat16",
     ), name="phi4flash-aot")
 
-    def described(tree):
-        return jax.tree.map(
-            lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=v5e),
-            tree)
 
-    params = described(jax.eval_shape(
-        lambda: llama.init_params(spec, jax.random.PRNGKey(0))))
-    k, v = described(jax.eval_shape(
-        lambda: llama.init_cache(spec, 257, 64, state_rows=128)))
-    return spec, params, k, v
+def _lfm2_cache(k, v):
+    # 8 KV heads of 64 as 4 rows of 128 lanes: no zeros in the pool
+    assert k.pools[0].shape == v.pools[0].shape == (1, 257, 4, 64, 128)
+    assert k.pools[1] is None
+    assert v.pools[1].shape == (1, 129, 2, 2048)
 
 
-@pytest.mark.parametrize("rows", [2, 1], ids=["pack-of-2", "single"])
-def test_phi4flash_prefill_program_compiles_for_v5e(v5e, monkeypatch, rows):
-    """The prefill programs of the cell, a layer of each kind at the
-    published widths: 1,024 tokens a row through the page write of a
-    pair a row, the walk under a 512 window and over all keys, the scan's
-    chunk form, and ONE row a sequence through the GMU and the cross
-    layer; every leaf of the cache donated."""
-    from dynamo_tpu.models import llama
+def _trinity_cache(k, v):
+    assert k.pools[0].shape == v.pools[0].shape == (1, 513, 4, 64, 128)
+    assert k.pools[1].shape == v.pools[1].shape == (1, 513, 4, 64, 128)
 
+
+def _longcat_prefill(compiled, text, k, v):
+    assert "attn_latent" not in text
+    pools = sum(p.size * p.dtype.itemsize for p in k)
+    assert compiled.memory_analysis().alias_size_in_bytes >= pools
+
+
+def _trinity_prefill(compiled, text, k, v):
+    # far under what the whole-table charge of ``EngineConfig.
+    # prefill_shapes`` would price a 4,096-row bucket at (8 GiB a row)
+    assert compiled.memory_analysis().temp_size_in_bytes < 3 * 2**30
+
+
+def _ling_decode(compiled, text, k, v):
+    # the kernels update the pools in place: what the program holds beside
+    # its arguments is less than the state pool (258 MiB a KDA layer)
+    state = k.pools[1]
+    assert compiled.memory_analysis().temp_size_in_bytes < (
+        state.size * state.dtype.itemsize // 2)
+
+
+def _lfm2_decode(compiled, text, k, v):
+    # the tails are updated in place: what the program holds beside its
+    # arguments is far less than an expert layer's weights (1.2 GB)
+    assert compiled.memory_analysis().temp_size_in_bytes < 256 * 2**20
+
+
+def _longcat_decode(compiled, text, k, v):
+    assert text.count("tpu_custom_call") == 2 + 3  # two attentions, gmm x 3
+    pools = sum(p.size * p.dtype.itemsize for p in k)
+    mem = compiled.memory_analysis()
+    assert mem.alias_size_in_bytes >= pools
+    assert mem.temp_size_in_bytes < pools
+
+
+def _trinity_decode(compiled, text, k, v):
+    """``attn_window`` over the 33 pages a 2,048-token window reaches, in
+    chunks of 7; ``attn_full`` over the table's 160 in chunks of 8."""
+    from dynamo_tpu.ops.pallas.fused_decode import chunk_pages
+
+    page_bytes = 4 * 64 * (128 + 128) * 2
+    assert chunk_pages(page_bytes, 33) == 7
+    assert chunk_pages(page_bytes, 160) == 8
+    # the stacked weights stay in HBM: the kernel fetches the experts a
+    # step touched itself (``ops/pallas/grouped.py``). megablox's operand
+    # the compiler copied WHOLE into VMEM (memory space S(1)) ahead of
+    # the call, 4 of this program's 6 (PERF.md section 6, PRs 53 and 54)
+    made = dict(re.findall(r"^\s*(?:ROOT )?(%[\w.-]+) = (\S+)", text, re.M))
+    calls = re.findall(r"%gmm[.\d]* = \S+ custom-call\(([^)]*)\)", text)
+    assert len(calls) == 6  # three products a layer
+    for operands in calls:
+        weights = operands.split(",")[-1].strip()
+        assert "[16," in made[weights] and "S(1)" not in made[weights]
+    pools = sum(p.size * p.dtype.itemsize for p in (*k.pools, *v.pools))
+    mem = compiled.memory_analysis()
+    assert mem.alias_size_in_bytes >= pools
+    assert mem.temp_size_in_bytes < 256 * 2**20
+
+
+# configuration -> the layers of its cell at the PUBLISHED widths (``spec``),
+# the cell's engine (pages of 64, tables of 160: ``pages``, ``rows`` of state,
+# ``slots``), the prefill programs it serves (``members`` of ``tokens`` tokens)
+# and what each compiled text must hold: the kernels named after their scopes,
+# which the trace's readers match. ``*_also`` is handed (compiled, text, k, v)
+# for what is no name in the text. A ``model_config`` PR adds a row.
+CELLS = {
+    # the walk and the SSD chunk form (8 chunks of 128) in one layer
+    "falcon": dict(
+        spec=_falcon_spec, pages=257, rows=128, slots=128, tokens=1024,
+        members=(2, 1), prefill_holds=("ssd_chunk",),
+        decode_holds=("%ssd_step", "%attn_full")),
+    # state rows AND latent pages under one block table, one program
+    "ling": dict(
+        spec=_ling_spec, pages=257, rows=128, slots=128, tokens=1024,
+        members=(2, 1), prefill_holds=("%kda_chunk", "%prefill_latent"),
+        decode_holds=("%kda_step", "%attn_latent"), decode_also=_ling_decode),
+    # the rows' tails (plain XLA) AND the packed pool (4 heads of 128
+    # lanes under 8 query heads each); grouped products over 64 groups
+    "lfm2": dict(
+        spec=_lfm2_spec, pages=257, rows=128, slots=128, tokens=1024,
+        members=(2, 1), cache_is=_lfm2_cache,
+        prefill_holds=("%gmm", "conv_mix"),
+        decode_holds=("%attn_full", "%gmm", "conv_mix"),
+        decode_also=_lfm2_decode),
+    # ``attn_latent`` TWICE (a pool a sub-layer), the shortcut's expert
+    # layer between them; both pools in place
+    "longcat": dict(
+        spec=_longcat_spec, pages=2049, rows=0, slots=128, tokens=1024,
+        members=(2, 1), prefill_holds=("%prefill_latent",),
+        prefill_also=_longcat_prefill,
+        decode_holds=("%attn_latent", "moe_zero"),
+        decode_also=_longcat_decode),
+    # 4,096 tokens a row, a 2,048-token window (nine blocks a tile); the
+    # fused kernel TWICE as two programs of it
+    "trinity": dict(
+        spec=_trinity_spec, pages=513, rows=0, slots=64, tokens=4096,
+        members=(2,), cache_is=_trinity_cache,
+        prefill_holds=("%gmm", "norm_out", "moe_shared"),
+        prefill_also=_trinity_prefill,
+        decode_holds=("%attn_window", "%attn_full", "%gmm", "norm_out"),
+        decode_also=_trinity_decode),
+    # the scan's chunk form is the kernel, a leaf of the region ``scan``;
+    # ONE row a sequence through the GMU and the cross layer, which runs
+    # the full kernel again with no write
+    "phi4flash": dict(
+        spec=_phi4flash_spec, pages=257, rows=128, slots=128, tokens=1024,
+        members=(2, 1),
+        prefill_holds=("/scan/", "/gmu/", "/attn_cross/", "/attn_diff/",
+                       "%scan_chunk", "/scan/jit(scan_chunk)/"),
+        decode_holds=("%scan", "%attn_window", "%attn_full", "%attn_cross")),
+}
+_DESCRIBED: dict = {}  # name -> the tree (one described device a session)
+
+
+def described(v5e, name):
+    """(cell, spec, weights, the cache's two sides) of ``name``, described
+    on the device: made once a configuration, so a prefill pair and the
+    decode burst lower the same tree. For a latent family the two sides
+    are the pools and the experts' counters (``models/family.py``)."""
+    if name not in _DESCRIBED:
+        cell = CELLS[name]
+        spec = cell["spec"]()
+        fam = get_family(spec)
+        kw = {"state_rows": cell["rows"]} if cell["rows"] else {}
+
+        def on_device(make):
+            return jax.tree.map(
+                lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=v5e),
+                jax.eval_shape(make))
+
+        params = on_device(
+            lambda: fam.init_params(spec, jax.random.PRNGKey(0)))
+        k, v = on_device(
+            lambda: fam.init_cache(spec, cell["pages"], 64, **kw))
+        cell.get("cache_is", lambda k, v: None)(k, v)
+        _DESCRIBED[name] = (cell, spec, params, k, v)
+    return _DESCRIBED[name]
+
+
+def _holds(cell, phase, compiled, k, v):
+    text = compiled.as_text()
+    for name in cell[f"{phase}_holds"]:
+        assert name in text, name
+    cell.get(f"{phase}_also", lambda *a: None)(compiled, text, k, v)
+
+
+@pytest.mark.parametrize("name,members", [
+    pytest.param(name, n, id=f"{name}-{'pack-of-2' if n == 2 else 'single'}")
+    for name, cell in CELLS.items() for n in cell["members"]])
+def test_prefill_program_compiles_for_v5e(v5e, monkeypatch, name, members):
+    """The prefill programs of the cell (the model module's own jits), its
+    layers at the published widths: a pack of two rows and the single row
+    (which is also a resumed chunk's); every leaf of the cache donated."""
     _as_on_the_chip(monkeypatch)
-    spec, params, k, v = _phi4flash_layers(v5e)
+    cell, spec, params, k, v = described(v5e, name)
     i32 = jnp.int32
-    if rows == 1:
-        lowered = jax.jit(
-            llama.prefill_forward_impl, static_argnums=(0,),
-            donate_argnums=(5, 6),
-        ).lower(spec, params, _rows(v5e, 1024, dtype=i32),
-                _rows(v5e, 160, dtype=i32), _rows(v5e, dtype=i32), k, v,
-                _rows(v5e, dtype=i32))
-    else:
-        lowered = jax.jit(
-            llama.prefill_forward_batch_impl, static_argnums=(0,),
-            donate_argnums=(5, 6),
-        ).lower(spec, params, _rows(v5e, rows, 1024, dtype=i32),
-                _rows(v5e, rows, 160, dtype=i32), _rows(v5e, rows, dtype=i32),
-                k, v, _rows(v5e, rows, dtype=i32))
-    text = lowered.compile().as_text()
-    for region in ("scan", "gmu", "attn_cross", "attn_diff"):
-        assert f"/{region}/" in text, region
-    # the scan's walk is the kernel, a leaf of the region ``scan``
-    assert "%scan_chunk" in text and "/scan/jit(scan_chunk)/" in text
+    lead = () if members == 1 else (members,)
+    tokens, tables, starts, lens = (
+        _rows(v5e, *lead, cell["tokens"], dtype=i32),
+        _rows(v5e, *lead, 160, dtype=i32), _rows(v5e, *lead, dtype=i32),
+        _rows(v5e, *lead, dtype=i32))
+    m, pair, kw = (mla, (k,), {"counts": v}) if spec.is_mla else (
+        llama, (k, v), {})
+    program = m.prefill_forward if members == 1 else m.prefill_forward_batch
+    lowered = program.lower(
+        spec, params, tokens, tables, starts, *pair, lens, **kw)
+    _holds(cell, "prefill", lowered.compile(), k, v)
 
 
-def test_phi4flash_decode_program_compiles_for_v5e(v5e, monkeypatch):
-    """The decode burst of the cell, a layer of each kind at the
-    published widths: 128 slots through ``scan``, the window and the full
-    kernel over rows a pair wide, and the full kernel again with no write
-    for the cross layer, 8 steps, the sampler on the device."""
-    from dynamo_tpu.models import llama
-
+@pytest.mark.parametrize("name", list(CELLS))
+def test_decode_program_compiles_for_v5e(v5e, monkeypatch, name):
+    """The decode burst of the cell, its layers at the published widths:
+    every slot through each kind's kernel in one program, 8 steps, the
+    sampler on the device."""
     _as_on_the_chip(monkeypatch)
-    spec, params, k, v = _phi4flash_layers(v5e)
-    B_, i32, f32 = 128, jnp.int32, jnp.float32
-    text = jax.jit(
-        llama.decode_steps_impl, static_argnums=(0,),
-        static_argnames=("n_steps", "n_logprobs"), donate_argnums=(5, 6),
-    ).lower(
-        spec, params, _rows(v5e, B_, dtype=i32), _rows(v5e, B_, 160, dtype=i32),
-        _rows(v5e, B_, dtype=i32), k, v, _rows(v5e, B_, dtype=jnp.bool_),
-        _rows(v5e, B_, dtype=f32), _rows(v5e, B_, dtype=i32),
-        _rows(v5e, B_, dtype=f32), _rows(v5e, B_, dtype=jnp.uint32),
-        _rows(v5e, B_, dtype=i32), n_steps=8, n_logprobs=0,
-    ).compile().as_text()
-    for kernel in ("%scan", "%attn_window", "%attn_full", "%attn_cross"):
-        assert kernel in text, kernel
+    cell, spec, params, k, v = described(v5e, name)
+    B_, i32, f32 = cell["slots"], jnp.int32, jnp.float32
+    head = (spec, params, _rows(v5e, B_, dtype=i32),
+            _rows(v5e, B_, 160, dtype=i32), _rows(v5e, B_, dtype=i32))
+    tail = (_rows(v5e, B_, dtype=jnp.bool_),
+            _rows(v5e, B_, dtype=f32), _rows(v5e, B_, dtype=i32),
+            _rows(v5e, B_, dtype=f32), _rows(v5e, B_, dtype=jnp.uint32),
+            _rows(v5e, B_, dtype=i32))
+    m, pair, kw = (mla, (k,), {"counts": v}) if spec.is_mla else (
+        llama, (k, v), {})
+    lowered = m.decode_steps.lower(
+        *head, *pair, *tail, n_steps=8, n_logprobs=0, **kw)
+    _holds(cell, "decode", lowered.compile(), k, v)

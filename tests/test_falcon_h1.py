@@ -8,24 +8,17 @@ shared with the pages' block table, and the engine around a sequence that
 owns both.
 """
 
-import asyncio
 import dataclasses
-import importlib.util
-import os
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
+from family_contract import Family, _cache, _whole, case, cases, gates, run
 
 from dynamo_tpu.engine.config import EngineConfig, LayerKind, ModelSpec
-from dynamo_tpu.engine.core import InferenceEngine
 from dynamo_tpu.models import llama
-from dynamo_tpu.models.family import GqaFamily, get_family
 from dynamo_tpu.ops import attention as attn_ops
-from dynamo_tpu.runtime.context import Context
-
-REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
 SPEC = ModelSpec.tiny_falcon_h1()
 # the reference reads the published keys; the program reads SPEC
@@ -45,62 +38,38 @@ CONFIG = {
     "ssm_out_multiplier": 0.7, "ssm_multipliers": [0.35, 0.5, 0.7, 0.8, 0.6],
     "mlp_multipliers": [0.7, 0.4],
 }
-PAGE, PAGES_PER_SEQ, T, ROWS = 4, 16, 40, 3
+PAGE, T, ROWS = 4, 40, 3
 SEED = 11
 TOL = 2e-5  # float32 on both sides; logits of magnitude ~0.5
 
 
-@pytest.fixture(scope="module")
-def ref():
-    spec = importlib.util.spec_from_file_location(
-        "parallel_ssm",
-        os.path.join(REPO, "perfbench/references/parallel_ssm.py"))
-    mod = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(mod)
-    return mod
+def _served(engine, snap, served, outs):
+    """Two prompts of 16 + 5 tokens: chunks of 16 tokens, 1 + 1 a prompt,
+    the second resumed; five decode steps a prompt in bursts of 4."""
+    assert engine.kda == {} and engine._kv_chunk_pages is not None
+    assert engine.ssd["prefill_chunks"] == 4
+    assert engine.ssd["rows_resumed"] == 2
+    assert engine.ssd["decode_rows"] % 4 == 0 and engine.ssd["decode_rows"] >= 16
+    assert engine.decode_kv["pages_live"] > 0  # the pages are counted too
+    assert snap["recurrent_state.rows"]["calls"] == 2
+    assert snap["ssd.prefill_chunks"]["calls"] == 4
+    assert snap["ssd.rows_resumed"]["calls"] == 2
+    assert "kda.decode_rows" not in snap
 
 
-@pytest.fixture(scope="module")
-def model(ref):
-    params = llama.init_params(SPEC, jax.random.PRNGKey(SEED))
-    toks = np.asarray(
-        jax.random.randint(jax.random.PRNGKey(1), (3, T), 0, 96))
-    want = np.asarray(ref.forward(
-        CONFIG, SEED, toks, np.tile(np.arange(T), (3, 1))))
-    return params, toks, want
+# the family's row of the contract (tests/family_contract.py): the state a
+# packed row left is the single prefill's to 1e-6, as are a burst's pools
+F = FAMILY = Family(
+    spec=SPEC, config=CONFIG, reference="parallel_ssm",
+    seed=SEED, tol=TOL, pool_tol=1e-6, pack_tol=1e-6, state_rows=ROWS,
+    packs=([(0, 0, 13), (0, 0, 0)], [(1, 0, 16), (2, 0, 7)]),
+    inactive_paths=("0", "1"), streams=(False, True),
+    also={"serves": _served})
 
 
-def _cache(rows=ROWS):
-    return llama.init_cache(
-        SPEC, 1 + 3 * PAGES_PER_SEQ, PAGE, state_rows=rows)
-
-
-def _table(row):
-    return jnp.arange(PAGES_PER_SEQ, dtype=jnp.int32) + 1 + row * PAGES_PER_SEQ
-
-
-def _close(got, want, tol=TOL):
-    np.testing.assert_allclose(np.asarray(got), want, rtol=tol, atol=tol)
-
-
-# fresh jits a test: the kernel/XLA choice is read at trace time
-def _programs(spec=SPEC):
-    del spec
-    return (jax.jit(llama.prefill_forward_impl, static_argnums=(0,)),
-            jax.jit(llama.prefill_forward_batch_impl, static_argnums=(0,)),
-            jax.jit(llama.decode_forward_impl, static_argnums=(0,)),
-            jax.jit(llama.decode_steps_impl, static_argnums=(0,),
-                    static_argnames=("n_steps", "n_logprobs")))
-
-
-def _prefill(pf, params, toks, row, start, n, k, v, bucket=16, spec=SPEC):
-    padded = np.zeros((bucket,), np.int32)
-    padded[:n] = toks[row, start: start + n]
-    logits, k, v, _ = pf(
-        spec, params, jnp.asarray(padded), _table(row),
-        jnp.asarray(start, jnp.int32), k, v, jnp.asarray(n, jnp.int32),
-    )
-    return logits, k, v
+@pytest.mark.parametrize("case,kw", cases(F, case("engine-gates", gates)))
+def test_the_family_contract(case, kw, monkeypatch):
+    run(case, F, monkeypatch, **kw)
 
 
 def test_the_cache_keeps_pages_and_a_state_for_one_kind():
@@ -109,7 +78,7 @@ def test_the_cache_keeps_pages_and_a_state_for_one_kind():
     the first leaf of the cache is still a page pool."""
     kd = SPEC.kind(0)
     assert kd.paged and kd.recurrent and SPEC.has_recurrent and SPEC.mixers == {"ssd"}
-    k, v = _cache()
+    k, v = _cache(F)
     assert isinstance(k.pools[0], llama.PagesAndState)
     assert k.pools[0].pages.shape == (3, 49, 2, PAGE, 16)
     assert k.pools[0].state.shape == (3, ROWS + 1, 4, 8, 16)
@@ -121,154 +90,6 @@ def test_the_cache_keeps_pages_and_a_state_for_one_kind():
     # a softmax-only and a state-only kind keep the bare array
     sk, _ = llama.init_cache(ModelSpec.tiny_solar(), 9, PAGE, state_rows=2)
     assert sk.pools[0].ndim == 5 and sk.pools[1].shape[1] == 3
-
-
-@pytest.mark.parametrize("pallas", ["0", "1"], ids=["xla", "kernel"])
-def test_prefill_then_decode_through_pages_and_state(model, monkeypatch, pallas):
-    """A prompt through the prefill program, then teacher-forced decode
-    steps through the pages AND the state rows of every layer: every
-    position's logits are the reference's whole forward pass. The other
-    slots are empty or inactive."""
-    monkeypatch.setenv("DYNAMO_PALLAS", pallas)
-    params, toks, want = model
-    pf, _, df, _ = _programs()
-    k, v = _cache()
-    n = 21
-    logits, k, v = _prefill(pf, params, toks, 1, 0, n, k, v, bucket=32)
-    _close(logits, want[1, n - 1])
-    bts = np.zeros((3, PAGES_PER_SEQ), np.int32)
-    bts[2] = np.asarray(_table(1))
-    active = np.array([False, False, True])
-    for j in range(6):
-        fed = np.zeros((3,), np.int32)
-        seq = np.ones((3,), np.int32)
-        fed[2], seq[2] = toks[1, n + j], n + j + 1
-        lg, k, v = df(SPEC, params, jnp.asarray(fed), jnp.asarray(bts),
-                      jnp.asarray(seq), k, v, jnp.asarray(active))
-        _close(lg[2], want[1, n + j])
-    stats = np.asarray(k.rows.stats[0])
-    assert stats[llama.STAT_CLAIMS] == 1 and stats[llama.STAT_MISSING] == 0
-
-
-@pytest.mark.parametrize("chunks", [
-    [(0, 37)], [(0, 16), (16, 16), (32, 5)],
-], ids=["one-shot", "three-chunks"])
-def test_a_chunked_prompt_resumes_state_tail_and_pages(model, chunks):
-    """Chunks at ``start_pos`` > 0 resume the chunk form from the state
-    and the convolution tail the chunk before left in the row, and append
-    to the pages it wrote: the last chunk's logits are the one-shot
-    prefill's and the reference's."""
-    params, toks, want = model
-    pf = _programs()[0]
-    k, v = _cache()
-    for start, n in chunks:
-        logits, k, v = _prefill(
-            pf, params, toks, 0, start, n, k, v,
-            bucket=64 if n > 16 else 16)
-    _close(logits, want[0, 36])
-    assert int(k.rows.stats[0, llama.STAT_MISSING]) == 0
-    assert int(k.rows.stats[0, llama.STAT_CLAIMS]) == 1
-
-
-def test_a_pack_of_two_with_an_empty_member(model):
-    """Packed rows, one of them empty: each row's logits are the
-    reference's, the empty row claims no state, writes no page and leaves
-    the other rows' state as a single prefill does."""
-    params, toks, want = model
-    pf, pb, _, _ = _programs()
-    k, v = _cache()
-    for lens, rows in (([13, 0], (0, 0)), ([16, 7], (1, 2))):
-        padded = np.zeros((2, 16), np.int32)
-        bts = np.zeros((2, PAGES_PER_SEQ), np.int32)
-        for i, (row, n) in enumerate(zip(rows, lens)):
-            padded[i, :n] = toks[row, :n]
-            if n:
-                bts[i] = np.asarray(_table(row))
-        logits, k, v, _ = pb(
-            SPEC, params, jnp.asarray(padded), jnp.asarray(bts),
-            jnp.zeros((2,), jnp.int32), k, v, jnp.asarray(lens, jnp.int32))
-        for i, (row, n) in enumerate(zip(rows, lens)):
-            if n:
-                _close(logits[i], want[row, n - 1])
-    owner = np.asarray(k.rows.owner[0])
-    assert sorted(owner[:ROWS]) == [1, 1 + PAGES_PER_SEQ, 1 + 2 * PAGES_PER_SEQ]
-    assert owner[ROWS] == 0  # the trash row is nobody's
-    # the state a packed row left is the single prefill's
-    k1, v1 = _cache()
-    _, k1, v1 = _prefill(pf, params, toks, 0, 0, 13, k1, v1)
-    at = int(np.argmax(owner[:ROWS] == 1))
-    _close(k.pools[0].state[:, at], np.asarray(k1.pools[0].state[:, 0]), 1e-6)
-    _close(v.pools[0].state[:, at], np.asarray(v1.pools[0].state[:, 0]), 1e-6)
-
-
-def test_bursts_of_one_and_eight_agree(model, monkeypatch):
-    """Eight greedy steps as one burst and as eight bursts of one: the
-    same tokens, and the same state, tails and pages afterwards (the burst
-    finds its rows once; tail and state carry between steps)."""
-    monkeypatch.setenv("DYNAMO_PALLAS", "1")
-    params, toks, _ = model
-    pf, _, _, ds = _programs()
-    B = 3
-    bts = np.zeros((B, PAGES_PER_SEQ), np.int32)
-    bts[0], bts[1] = np.asarray(_table(0)), np.asarray(_table(1))
-    active = jnp.asarray([True, True, False])
-    z = jnp.zeros((B,), jnp.int32)
-
-    def run(bursts):
-        k, v = _cache()
-        for row, n in ((0, 9), (1, 14)):
-            _, k, v = _prefill(pf, params, toks, row, 0, n, k, v)
-        fed = np.array([toks[0, 9], toks[1, 14], 0], np.int32)
-        seq = np.array([10, 15, 1], np.int32)
-        out = []
-        for n_steps in bursts:
-            o, k, v = ds(
-                SPEC, params, jnp.asarray(fed), jnp.asarray(bts),
-                jnp.asarray(seq), k, v, active, jnp.zeros((B,)), z,
-                jnp.ones((B,)), jnp.zeros((B,), jnp.uint32), z,
-                n_steps=n_steps, n_logprobs=0)
-            o = np.asarray(o)
-            out.append(o[:2])
-            fed[:2], seq[:2] = o[:2, -1], seq[:2] + n_steps
-        return np.concatenate(out, axis=1), k, v
-
-    one, k1, v1 = run([1] * 8)
-    eight, k8, v8 = run([8])
-    np.testing.assert_array_equal(one, eight)
-    _close(k8.pools[0].state[:, :2], np.asarray(k1.pools[0].state[:, :2]), 1e-6)
-    _close(v8.pools[0].state[:, :2], np.asarray(v1.pools[0].state[:, :2]), 1e-6)
-    _close(k8.pools[0].pages[:, 1:], np.asarray(k1.pools[0].pages[:, 1:]), 1e-6)
-
-
-@pytest.mark.parametrize("pallas", ["0", "1"], ids=["xla", "kernel"])
-def test_an_inactive_slot_and_a_released_row_touch_nothing(
-        model, monkeypatch, pallas):
-    """A decode step with one live slot: the other sequence's row (its
-    slot inactive) and a row whose owner was released keep their state
-    and tail to the bit; so do the pages of both."""
-    monkeypatch.setenv("DYNAMO_PALLAS", pallas)
-    params, toks, _ = model
-    pf, _, df, _ = _programs()
-    k, v = _cache()
-    for row, n in ((0, 9), (1, 14), (2, 11)):
-        _, k, v = _prefill(pf, params, toks, row, 0, n, k, v)
-    k = llama.release_state_rows(k, jnp.asarray(
-        [int(_table(2)[0]), -1], jnp.int32))
-    assert list(np.asarray(k.rows.owner[0])) == [1, 1 + PAGES_PER_SEQ, 0, 0]
-    before = jax.tree.map(np.asarray, (k, v))
-    bts = np.stack([np.asarray(_table(r)) for r in range(3)])
-    lg, k, v = df(
-        SPEC, params, jnp.asarray(toks[:, 20]), jnp.asarray(bts),
-        jnp.asarray([10, 15, 12], jnp.int32), k, v,
-        jnp.asarray([True, False, False]))
-    for side, was in zip((k, v), before):
-        now = np.asarray(side.pools[0].state)
-        assert not np.array_equal(now[:, 0], was.pools[0].state[:, 0])
-        np.testing.assert_array_equal(now[:, 1:3], was.pools[0].state[:, 1:3])
-        pages = np.asarray(side.pools[0].pages)
-        np.testing.assert_array_equal(
-            pages[:, 1 + PAGES_PER_SEQ:], was.pools[0].pages[:, 1 + PAGES_PER_SEQ:])
-    assert int(k.rows.stats[0, llama.STAT_MISSING]) == 0
 
 
 def _ssd_case(T_, seed=3, N=2, H=4, P=8, G=2, S=16):
@@ -300,16 +121,16 @@ def test_the_chunk_form_equals_the_token_recurrence(T_):
     for n in range(N):
         want_y, want_h = attn_ops.ssd_recurrence(
             x[n], dt[n], A, B[n], C[n], D, h0[n])
-        _close(y[n], np.asarray(want_y), tol=2e-5)
-        _close(out[0, 1 + n], np.asarray(want_h), tol=2e-5)
+        F.close(y[n], np.asarray(want_y), tol=2e-5)
+        F.close(out[0, 1 + n], np.asarray(want_h), tol=2e-5)
     np.testing.assert_array_equal(np.asarray(out[0, 0]), np.asarray(pool[0, 0]))
     fresh_y, fresh = attn_ops.ssd_chunk_prefill(
         x[:1], dt[:1], A, B[:1], C[:1], D, pool, jnp.ones((1,), jnp.int32),
         jnp.ones((1,), bool), layer=0, chunk=16)
     want_y, want_h = attn_ops.ssd_recurrence(
         x[0], dt[0], A, B[0], C[0], D, h0[0] * 0)
-    _close(fresh_y[0], np.asarray(want_y), tol=2e-5)
-    _close(fresh[0, 1], np.asarray(want_h), tol=2e-5)
+    F.close(fresh_y[0], np.asarray(want_y), tol=2e-5)
+    F.close(fresh[0, 1], np.asarray(want_h), tol=2e-5)
     # the tail of the tokens padded: the state after the real ones
     real = T_ - 5
     _, cut = attn_ops.ssd_chunk_prefill(
@@ -317,7 +138,7 @@ def test_the_chunk_form_equals_the_token_recurrence(T_):
         D, pool, jnp.arange(1, N + 1), jnp.zeros((N,), bool), layer=0, chunk=16)
     _, want_h = attn_ops.ssd_recurrence(
         x[0, :real], dt[0, :real], A, B[0, :real], C[0, :real], D, h0[0])
-    _close(cut[0, 1], np.asarray(want_h), tol=2e-5)
+    F.close(cut[0, 1], np.asarray(want_h), tol=2e-5)
 
 
 def test_ssd_step_equals_its_xla_twin(monkeypatch):
@@ -341,9 +162,9 @@ def test_ssd_step_equals_its_xla_twin(monkeypatch):
     live = np.asarray([1, 3])
     for a, b in zip(steps["0"], steps["1"]):
         assert a.shape == b.shape
-    _close(steps["0"][0][live], np.asarray(steps["1"][0][live]), tol=1e-5)
+    F.close(steps["0"][0][live], np.asarray(steps["1"][0][live]), tol=1e-5)
     for i in (1, 2):  # the pools: every row but the trash row
-        _close(steps["0"][i][:, :5], np.asarray(steps["1"][i][:, :5]), tol=1e-5)
+        F.close(steps["0"][i][:, :5], np.asarray(steps["1"][i][:, :5]), tol=1e-5)
     got_y, got_s, got_c = steps["1"]
     np.testing.assert_array_equal(np.asarray(got_s[0]), np.asarray(pool[0]))
     np.testing.assert_array_equal(
@@ -354,8 +175,8 @@ def test_ssd_step_equals_its_xla_twin(monkeypatch):
     np.testing.assert_array_equal(np.asarray(got_c[1, 0]), np.asarray(tail[3]))
     want_y, want_h = attn_ops.ssd_recurrence(
         x[0, :1], dt[0, :1], A, B[0, :1], C[0, :1], D, pool[1, 3])
-    _close(got_y[1], np.asarray(want_y[0]), tol=1e-5)
-    _close(got_s[1, 3], np.asarray(want_h), tol=1e-5)
+    F.close(got_y[1], np.asarray(want_y[0]), tol=1e-5)
+    F.close(got_s[1, 3], np.asarray(want_h), tol=1e-5)
 
 
 MULTIPLIERS = [
@@ -388,12 +209,12 @@ def test_every_multiplier_moves_the_logits(ref, model, name, at):
     cfg = dict(CONFIG)
     cfg[name] = (other(tuple(CONFIG[name])) if at is not None
                  else other(CONFIG[name]))
-    got = np.asarray(llama.reference_forward(spec, params, jnp.asarray(toks[0])))
+    got = np.asarray(_whole(spec, params, jnp.asarray(toks[0])))
     moved = np.abs(got - want[0]).max()
     assert moved > 100 * TOL, (name, at, moved)
     theirs = np.asarray(ref.forward(
         cfg, SEED, toks[:1], np.arange(T)[None]))[0]
-    _close(got, theirs)
+    F.close(got, theirs)
 
 
 def test_a_checkpoint_in_the_published_layout_round_trips(tmp_path):
@@ -443,151 +264,11 @@ def test_the_tables_are_drawn_in_blocks():
     n = 96 // blocks
     for b in (0, 3, 7):
         part = jax.random.normal(jax.random.fold_in(key, b), (n, 64)) * 0.02
-        _close(table[b * n: (b + 1) * n], np.asarray(part), 1e-7)
+        F.close(table[b * n: (b + 1) * n], np.asarray(part), 1e-7)
         part = jax.random.normal(jax.random.fold_in(key, b), (64, n)) * 0.125
-        _close(head[:, b * n: (b + 1) * n], np.asarray(part), 1e-7)
+        F.close(head[:, b * n: (b + 1) * n], np.asarray(part), 1e-7)
     with pytest.raises(ValueError, match="does not cut"):
         llama._draw_blocks(key, 1.0, (100, 8), 0, jnp.float32, blocks)
-
-
-# ------------------------------------------------------------- the engine
-
-
-def _engine(**kw):
-    base = dict(
-        page_size=PAGE, num_pages=64, max_pages_per_seq=PAGES_PER_SEQ,
-        max_decode_slots=2, prefill_buckets=(16,), max_prefill_chunk_tokens=16,
-        decode_steps_per_dispatch=4, seed=SEED,
-    )
-    base.update(kw)
-    return InferenceEngine(SPEC, EngineConfig(**base))
-
-
-async def _greedy(engine, prompt, n, out=None, ctx=None):
-    out = [] if out is None else out
-    async for item in engine.generate(
-        {"token_ids": list(prompt), "sampling": {"temperature": 0.0},
-         "stop_conditions": {"max_tokens": n, "ignore_eos": True}},
-        ctx or Context(),
-    ):
-        assert item.get("finish_reason") != "error", item
-        out.extend(item.get("token_ids") or [])
-    return out
-
-
-_jit_reference = jax.jit(llama.reference_forward, static_argnums=0)
-
-
-def _greedy_reference(params, prompt, n):
-    seq = list(prompt)
-    for _ in range(n):
-        padded = np.zeros((64,), np.int32)
-        padded[: len(seq)] = seq
-        lg = _jit_reference(SPEC, params, jnp.asarray(padded))
-        seq.append(int(np.argmax(np.asarray(lg[len(seq) - 1]))))
-    return seq[len(prompt):]
-
-
-async def test_serves_through_the_engine_and_counts(monkeypatch):
-    """The toy model through the REAL engine (scheduler, a prompt of two
-    chunks, the decode kernel interpreted in bursts): the greedy stream is
-    the whole forward pass's own; the same prompt a second time gives the
-    same tokens and seals nothing; the rows go back; the counters read
-    what hand arithmetic gives."""
-    monkeypatch.setenv("DYNAMO_PALLAS", "1")
-    engine = _engine()
-    fam = engine.fam
-    assert isinstance(fam, GqaFamily) and fam.recurrent
-    assert not fam.supports_prefix_reuse and not engine.allocator.prefix_cache
-    assert engine.kda == {} and engine._kv_chunk_pages is not None
-    prompt = [int(t) for t in np.arange(7, 7 + 21) % 96]  # two chunks
-    want = _greedy_reference(engine.params, prompt, 6)
-    assert await _greedy(engine, prompt, 6) == want
-    assert await _greedy(engine, prompt, 6) == want
-    assert engine.allocator._hash_page == {}
-    assert engine.prefix_hit_tokens(prompt) == 0
-    assert engine.allocator.active_pages == 0
-    # two prompts of 16 + 5 tokens: chunks of 16 tokens, 1 + 1 a prompt,
-    # the second resumed; five decode steps a prompt in bursts of 4
-    assert engine.ssd["prefill_chunks"] == 4
-    assert engine.ssd["rows_resumed"] == 2
-    assert engine.ssd["decode_rows"] % 4 == 0 and engine.ssd["decode_rows"] >= 16
-    assert engine.decode_kv["pages_live"] > 0  # the pages are counted too
-    await engine.close()
-    engine._metrics_publishes = 0
-    for _ in range(34):  # two refreshes bring the device's counters over
-        engine._publish_metrics()
-    c = engine.state_counters()
-    assert c == {"rows": 2, "rows_live": 0, "claims": 2, "row_missing": 0}
-    engine._flush_state_releases()
-    assert list(np.asarray(engine.k_pages.rows.owner[0])) == [0, 0, 0]
-    snap = engine.profile_snapshot()
-    assert snap["recurrent_state.rows"]["calls"] == 2
-    assert snap["ssd.prefill_chunks"]["calls"] == 4
-    assert snap["ssd.rows_resumed"]["calls"] == 2
-    assert "kda.decode_rows" not in snap
-
-
-@pytest.mark.parametrize("pipeline", [False, True], ids=["plain", "pipelined"])
-async def test_streams_share_the_engine(monkeypatch, pipeline):
-    """Three prompts on two slots, one of them chunked behind running
-    bursts: every stream is what it gets alone, pipelined or not, rows
-    are claimed and freed as slots turn over, none goes missing."""
-    monkeypatch.setenv("DYNAMO_PALLAS", "1")
-    prompts = [[3, 9, 27], [8, 64, 32, 5],
-               [int(t) for t in np.arange(5, 5 + 37) * 7 % 96]]
-    engine = _engine(pipeline_decode=pipeline, async_admissions=True)
-    want = [_greedy_reference(engine.params, p, n)
-            for p, n in zip(prompts, (12, 9, 6))]
-    outs = await asyncio.gather(*(
-        _greedy(engine, p, n) for p, n in zip(prompts, (12, 9, 6))))
-    assert outs == want
-    assert engine.allocator.active_pages == 0
-    await engine.close()
-    assert int(engine.k_pages.rows.stats[0, llama.STAT_MISSING]) == 0
-    assert int(engine.k_pages.rows.stats[0, llama.STAT_CLAIMS]) == 3
-
-
-def _fallbacks(*reasons):
-    from dynamo_tpu.ops import fallback
-
-    return [fallback._FALLBACKS.labels(r)._value.get() for r in reasons]
-
-
-async def test_every_gate_counts_its_reason_for_this_family_too():
-    """Pages alone do not hold a sequence here either: reuse, offload,
-    transfer, verify and meshes are off by the family's attributes, and
-    what is asked for anyway joins the fallback series."""
-    fam = get_family(SPEC)
-    assert fam.recurrent and fam.supports_packed_prefill
-    for gate in ("ring_prefill", "spec_decode", "mesh", "prefix_reuse",
-                 "page_transfer", "multimodal"):
-        assert not getattr(fam, f"supports_{gate}"), gate
-    names = ("recurrent_no_page_offload", "recurrent_no_spec_decode",
-             "recurrent_no_ring_prefill")
-    before = _fallbacks(*names)
-    from dynamo_tpu.kvbm import KvBlockManager, KvbmConfig
-
-    engine = InferenceEngine(
-        SPEC, EngineConfig(
-            page_size=PAGE, num_pages=64, max_pages_per_seq=PAGES_PER_SEQ,
-            max_decode_slots=2, prefill_buckets=(16,), spec_mode="ngram",
-            sp=2, seed=SEED,
-        ), kvbm=KvBlockManager(KvbmConfig(host_bytes=1 << 20)),
-    )
-    assert engine.kvbm is None and not engine._spec_on
-    assert [b - a for a, b in zip(before, _fallbacks(*names))] == [1, 1, 1]
-    await engine.close()
-    k, v = _cache()
-    with pytest.raises(NotImplementedError, match="speculative verify"):
-        llama.verify_forward_impl(
-            SPEC, engine.params, jnp.zeros((1, 2), jnp.int32),
-            jnp.zeros((1, PAGES_PER_SEQ), jnp.int32), jnp.zeros((1,), jnp.int32),
-            k, v, jnp.ones((1,), jnp.int32))
-    with pytest.raises(ValueError, match="one device"):
-        from dynamo_tpu.parallel.mesh import make_mesh
-
-        llama.cache_shardings(make_mesh(tp=2, dp=1), "bf16", SPEC)
 
 
 def test_the_memory_guard_charges_the_chunk_form():

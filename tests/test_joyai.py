@@ -6,17 +6,15 @@ latent cache, the decode kernel interpreted and the XLA walk) against the
 benchmark's plain reference (``perfbench/references/latent_moe.py``), which
 shares no code with it."""
 
-import asyncio
-import importlib.util
-import os
-
 import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
+from family_contract import (
+    PROMPT, Family, _whole, behind_bursts, case, cases, run, two_slots,
+)
 
-from dynamo_tpu.engine.config import EngineConfig, ModelSpec
-from dynamo_tpu.engine.core import InferenceEngine
+from dynamo_tpu.engine.config import ModelSpec
 from dynamo_tpu.models import mla
 from dynamo_tpu.models.family import MlaFamily
 from dynamo_tpu.ops import attention as attn_ops
@@ -24,9 +22,6 @@ from dynamo_tpu.ops.pallas.fused_decode import live_chunks
 from dynamo_tpu.ops.pallas.latent_decode import (
     latent_chunk_pages, latent_decode_attention,
 )
-from dynamo_tpu.runtime.context import Context
-
-REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
 # the reference reads the published keys; the program reads SPEC
 CONFIG = {
@@ -59,112 +54,62 @@ def _spec(**kw) -> ModelSpec:
 
 
 SPEC = _spec()
-PAGE, PAGES_PER_SEQ, T = 4, 16, 40
+PAGE, PAGES_PER_SEQ = 4, 16
 SEED = 11
 
 
-@pytest.fixture(scope="module")
-def ref():
-    spec = importlib.util.spec_from_file_location(
-        "latent_moe", os.path.join(REPO, "perfbench/references/latent_moe.py"))
-    mod = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(mod)
-    return mod
-
-
-@pytest.fixture(scope="module")
-def model(ref):
-    params = mla.init_params(SPEC, jax.random.PRNGKey(SEED))
-    toks = np.asarray(
-        jax.random.randint(jax.random.PRNGKey(1), (3, T), 0, 96))
-    want = np.asarray(ref.forward(
-        CONFIG, SEED, toks, np.tile(np.arange(T), (3, 1))))
-    return params, toks, want
-
-
-def _cache():
-    return (mla.init_cache(SPEC, 1 + 3 * PAGES_PER_SEQ, PAGE),
-            mla.init_counts(SPEC))
-
-
-def _table(row):
-    return jnp.arange(PAGES_PER_SEQ, dtype=jnp.int32) + 1 + row * PAGES_PER_SEQ
-
-
-def _close(got, want, tol=3e-4):
-    np.testing.assert_allclose(np.asarray(got), want, rtol=tol, atol=tol)
-
-
-def _prefill(params, toks, row, start, n, cache, counts, bucket=16):
-    padded = np.zeros((bucket,), np.int32)
-    padded[:n] = toks[row, start: start + n]
-    return mla.prefill_forward(
-        SPEC, params, jnp.asarray(padded), _table(row),
-        jnp.asarray(start, jnp.int32), cache, jnp.asarray(n, jnp.int32),
-        counts=counts,
-    )
-
-
-@pytest.mark.parametrize("chunks", [
-    pytest.param([13], id="single"),
-    pytest.param([16, 16, 7], id="three-chunks"),  # start_pos > 0 twice
-])
-def test_prefill_against_the_reference(model, chunks):
-    params, toks, want = model
-    cache, counts = _cache()
-    start = 0
-    for n in chunks:
-        logits, cache, counts = _prefill(
-            params, toks, 0, start, n, cache, counts)
-        start += n
-        _close(logits, want[0, start - 1])
-
-
-def test_packed_prefill_against_the_reference(model):
-    params, toks, want = model
-    cache, counts = _cache()
-    lens = np.asarray([16, 9, 0], np.int32)  # the third member is padding
-    padded = np.zeros((3, 16), np.int32)
-    for r, n in enumerate(lens):
-        padded[r, :n] = toks[r, :n]
-    bts = jnp.stack([_table(0), _table(1), jnp.zeros(PAGES_PER_SEQ, jnp.int32)])
-    logits, cache, counts = mla.prefill_forward_batch(
-        SPEC, params, jnp.asarray(padded), bts, jnp.zeros((3,), jnp.int32),
-        cache, jnp.asarray(lens), counts=counts,
-    )
-    _close(logits[0], want[0, 15])
-    _close(logits[1], want[1, 8])
-    assert np.isfinite(np.asarray(logits)).all()
-
-
-@pytest.mark.parametrize("pallas", ["0", "1"], ids=["xla-walk", "kernel"])
-def test_decode_through_the_paged_latent_cache(model, monkeypatch, pallas):
-    """Teacher-forced steps after prefills of 14, 3 and 0 tokens: across a
-    page boundary (the kernel's chunk boundaries are
-    ``test_latent_kernel_against_the_xla_walk``'s); a slot that starts
-    from ONE token in the pool, and an empty slot that stays inactive."""
-    monkeypatch.setenv("DYNAMO_PALLAS", pallas)
-    params, toks, want = model
-    cache, counts = _cache()
-    lens = [14, 1]
-    for r, n in enumerate(lens):
-        _, cache, counts = _prefill(params, toks, r, 0, n, cache, counts)
-    bts = jnp.stack([_table(0), _table(1), jnp.zeros(PAGES_PER_SEQ, jnp.int32)])
-    active = jnp.asarray([True, True, False])
-    before = np.asarray(counts)
-    for j in range(12):
-        fed = jnp.asarray([toks[0, 14 + j], toks[1, 1 + j], 0], jnp.int32)
-        seq = jnp.asarray([15 + j, 2 + j, 1], jnp.int32)
-        logits, cache, counts = mla.decode_forward(
-            SPEC, params, fed, bts, seq, cache, active, counts=counts)
-        _close(logits[0], want[0, 14 + j])
-        _close(logits[1], want[1, 1 + j])
-    # the counters: 12 steps a layer, 2 counted rows x top-4 a step
-    grew = np.asarray(counts) - before
+def _two_slots(grew, steps):
+    """The counters behind ``steps`` steps of two counted rows, top-4."""
     assert (grew[0] == 0).all()  # the dense layer keeps none
-    assert (grew[1:, 1, -1] == 12).all() and (grew[1:, 0] == 0).all()
-    assert (grew[1:, 1, -3] == 12 * 2 * 4).all()
-    assert (grew[1:, 1, :4].sum(axis=1) <= 12 * 2 * 4).all()
+    assert (grew[1:, 1, -1] == steps).all() and (grew[1:, 0] == 0).all()
+    assert (grew[1:, 1, -3] == steps * 2 * 4).all()
+    assert (grew[1:, 1, :4].sum(axis=1) <= steps * 2 * 4).all()
+
+
+def _served(engine, snap, served, outs):
+    """``decode_kv``, ``prefill_kv.*.latent`` and ``moe_counters()`` read
+    what hand arithmetic gives behind ONE prompt of 21 tokens."""
+    assert isinstance(engine.fam, MlaFamily)
+    # prefill: chunks of 16 and 5 rows; one tile of all 16 rows, blocks of
+    # 16 pages (a 64-token table is one block): 1 block visited a chunk
+    assert engine.prefill_kv["blocks_visited.latent"] == 2
+    assert engine.prefill_kv["blocks_table.latent"] == 2
+    # decode: 5 model steps served (the first token came from prefill) in
+    # bursts of 4; every dispatched burst is counted over its 4 steps
+    kv = engine.decode_kv
+    chunk = latent_chunk_pages(engine.k_pages, PAGES_PER_SEQ)
+    assert chunk == PAGES_PER_SEQ  # a 16-page table is one chunk
+    assert kv["pages_fetched"] % chunk == 0 and kv["pages_fetched"] > 0
+    assert kv["pages_table"] % (2 * PAGES_PER_SEQ * 4) == 0
+    assert 0 < kv["pages_live"] <= kv["pages_fetched"] <= kv["pages_table"]
+    first, count = live_chunks(np.asarray([22, 1]), PAGE, chunk)
+    assert list(count) == [1, 0]  # an empty slot fetches nothing
+    c = engine.moe_counters()
+    assert c["layers"] == 2  # the dense first layer keeps none
+    assert c["prefill.steps"] == 2 and c["prefill.assignments"] == 2 * 21 * 4
+    assert c["decode.assignments"] >= 2 * 5 * 4
+    assert sum(c[f"decode.expert.{i}"] for i in range(4)) <= c[
+        "decode.assignments"]
+
+
+# the family's row of the contract (tests/family_contract.py): a latent
+# family (the pair is the pool and the experts' counters); the pack's third
+# member is padding; a 37-token prompt in chunks of 16 behind bursts
+F = FAMILY = Family(
+    spec=SPEC, config=CONFIG, reference="latent_moe", seed=SEED,
+    prompts=(), chunked={"single": [(0, 13)],
+                         "three-chunks": [(0, 16), (16, 16), (32, 7)]},
+    packs=([(0, 0, 16), (1, 0, 9), (0, 0, 0)],), served=((PROMPT, 6),),
+    bursts_paths=(), also={"two-slots": _two_slots, "serves": _served})
+
+
+@pytest.mark.parametrize("case,kw", cases(
+    F, case("two-slots-xla", two_slots, path="0"),
+    case("two-slots-kernel", two_slots, path="1"),
+    case("engine-chunks-behind-bursts", behind_bursts,
+         chunk=16, n=37, busy=40)))
+def test_the_family_contract(case, kw, monkeypatch):
+    run(case, F, monkeypatch, **kw)
 
 
 @pytest.mark.parametrize("dtype,tol", [
@@ -212,9 +157,9 @@ def test_interleaved_rope_is_a_permutation_of_the_same_weights(ref):
     for flag in (True, False):
         spec = _spec(rope_interleave=flag)
         params = mla.init_params(spec, jax.random.PRNGKey(SEED))
-        got = mla.reference_forward(spec, params, jnp.asarray(toks[0]))
+        got = _whole(spec, params, jnp.asarray(toks[0]))
         want = ref.forward(dict(CONFIG, rope_interleave=flag), SEED, toks, at)
-        _close(got, np.asarray(want)[0])
+        F.close(got, np.asarray(want)[0])
     a = mla.init_params(_spec(rope_interleave=True), jax.random.PRNGKey(SEED))
     b = mla.init_params(_spec(rope_interleave=False), jax.random.PRNGKey(SEED))
     wa, wb = (np.asarray(p["layers"][0]["w_kv_a"]) for p in (a, b))
@@ -258,13 +203,13 @@ def test_the_shares_add_up(ref):
         {k: lw[k] for k in ref.SHARED}, quant=None))
     shares = [layer1(4, first)[0] for first in (0, 4, 8, 12)]
     routed = sum(s - alike for s in shares)
-    _close(alike + routed, whole, tol=1e-4)
+    F.close(alike + routed, whole, tol=1e-4)
     # and the program's share is the reference's share
     spec = _spec(num_layers=2)
     params = mla.init_params(spec, jax.random.PRNGKey(SEED))
-    got = mla.reference_forward(spec, params, jnp.asarray(toks[0]))
+    got = _whole(spec, params, jnp.asarray(toks[0]))
     want = ref.forward(cfg, SEED, toks, np.arange(10)[None].repeat(2, 0))
-    _close(got, np.asarray(want)[0])
+    F.close(got, np.asarray(want)[0])
 
 
 def test_memory_does_not_follow_the_table():
@@ -296,120 +241,3 @@ def test_memory_does_not_follow_the_table():
     (d0, p0), (d1, p1) = temp_bytes(80), temp_bytes(320)
     assert abs(d1 - d0) <= 0.1 * d0, (d0, d1)
     assert abs(p1 - p0) <= 0.1 * p0, (p0, p1)
-
-
-async def test_serves_through_the_engine_and_counts(monkeypatch):
-    """The toy model through the REAL engine (scheduler, chunked prefill
-    over the latent cache, the kernel interpreted in pipelined bursts): the
-    greedy stream is the reference's own, and ``decode_kv``,
-    ``prefill_kv.*.latent`` and ``moe_counters()`` read what hand
-    arithmetic gives."""
-    monkeypatch.setenv("DYNAMO_PALLAS", "1")
-    engine = InferenceEngine(SPEC, EngineConfig(
-        page_size=PAGE, num_pages=64, max_pages_per_seq=PAGES_PER_SEQ,
-        max_decode_slots=2, prefill_buckets=(16,), max_prefill_chunk_tokens=16,
-        decode_steps_per_dispatch=4, seed=SEED,
-    ))
-    assert isinstance(engine.fam, MlaFamily)
-    prompt = [int(t) for t in np.arange(7, 7 + 21) % 96]  # two chunks
-    out = []
-    async for item in engine.generate(
-        {"token_ids": prompt, "sampling": {"temperature": 0.0},
-         "stop_conditions": {"max_tokens": 6, "ignore_eos": True}},
-        Context(),
-    ):
-        assert item.get("finish_reason") != "error", item
-        out.extend(item.get("token_ids") or [])
-    assert len(out) == 6
-    seq = list(prompt)
-    for _ in range(6):
-        padded = np.zeros((32,), np.int32)
-        padded[: len(seq)] = seq
-        lg = _jit_reference(SPEC, engine.params, jnp.asarray(padded))
-        seq.append(int(np.argmax(np.asarray(lg[len(seq) - 1]))))
-    assert out == seq[len(prompt):]
-
-    # prefill: chunks of 16 and 5 rows; one tile of all 16 rows, blocks of
-    # 16 pages (a 64-token table is one block): 1 block visited a chunk
-    assert engine.prefill_kv["blocks_visited.latent"] == 2
-    assert engine.prefill_kv["blocks_table.latent"] == 2
-    # decode: 5 model steps served (the first token came from prefill) in
-    # bursts of 4; every dispatched burst is counted over its 4 steps
-    kv = engine.decode_kv
-    chunk = latent_chunk_pages(engine.k_pages, PAGES_PER_SEQ)
-    assert chunk == PAGES_PER_SEQ  # a 16-page table is one chunk
-    assert kv["pages_fetched"] % chunk == 0 and kv["pages_fetched"] > 0
-    assert kv["pages_table"] % (2 * PAGES_PER_SEQ * 4) == 0
-    assert 0 < kv["pages_live"] <= kv["pages_fetched"] <= kv["pages_table"]
-    first, count = live_chunks(np.asarray([22, 1]), PAGE, chunk)
-    assert list(count) == [1, 0]  # an empty slot fetches nothing
-    await engine.close()
-    engine._metrics_publishes = 0
-    for _ in range(34):  # two refreshes bring the device's counters over
-        engine._publish_metrics()
-    c = engine.moe_counters()
-    assert c["layers"] == 2  # the dense first layer keeps none
-    assert c["prefill.steps"] == 2 and c["prefill.assignments"] == 2 * 21 * 4
-    assert c["decode.assignments"] >= 2 * 5 * 4
-    assert sum(c[f"decode.expert.{i}"] for i in range(4)) <= c[
-        "decode.assignments"]
-
-
-async def _greedy(engine, prompt, n, out=None):
-    out = [] if out is None else out
-    async for item in engine.generate(
-        {"token_ids": list(prompt), "sampling": {"temperature": 0.0},
-         "stop_conditions": {"max_tokens": n, "ignore_eos": True}},
-        Context(),
-    ):
-        assert item.get("finish_reason") != "error", item
-        out.extend(item.get("token_ids") or [])
-    return out
-
-
-async def test_a_chunked_prompt_behind_running_bursts(monkeypatch):
-    """Chunked under load: two streams decode in pipelined bursts through
-    the kernel while a 37-token prompt prefills in chunks of 16. The
-    chunks at ``start_pos`` 16 and 32 walk latents an earlier chunk wrote,
-    each launched behind the burst in flight (no flush lands it first),
-    and the prompt's tokens are those it gets alone and unchunked."""
-    monkeypatch.setenv("DYNAMO_PALLAS", "1")
-    prompt = [int(t) for t in np.arange(5, 5 + 37) * 7 % 96]
-
-    def build(**kw):
-        return InferenceEngine(SPEC, EngineConfig(
-            page_size=PAGE, num_pages=64, max_pages_per_seq=PAGES_PER_SEQ,
-            max_decode_slots=3, decode_steps_per_dispatch=4, seed=SEED, **kw))
-
-    alone = build(prefill_buckets=(64,), max_prefill_chunk_tokens=64)
-    want = await _greedy(alone, prompt, 6)
-    assert alone.chunked_prefill["chunks"] == 0
-    await alone.close()
-
-    engine = build(prefill_buckets=(16,), max_prefill_chunk_tokens=16,
-                   pipeline_decode=True)
-    chunks, run_chunk = [], engine._run_partial_chunk
-
-    def watched(waiting, sp, token_ids, start, end):
-        chunks.append((start, len(engine._pipeline)))
-        return run_chunk(waiting, sp, token_ids, start, end)
-
-    engine._run_partial_chunk = watched
-    a, b = [], []
-
-    async def later():
-        while min(len(a), len(b)) < 4:
-            await asyncio.sleep(0.002)
-        return await _greedy(engine, prompt, 6)
-
-    outs = await asyncio.gather(
-        _greedy(engine, [3, 9, 27], 40, out=a),
-        _greedy(engine, [8, 64, 32, 5], 40, out=b), later())
-    assert outs[2] == want and [len(o) for o in outs[:2]] == [40, 40]
-    assert chunks == [(0, 1), (16, 1), (32, 1)]
-    assert engine.chunked_prefill == {"chunks": 3, "chunks_behind_burst": 3}
-    assert engine.allocator.active_pages == 0
-    await engine.close()
-
-
-_jit_reference = jax.jit(mla.reference_forward, static_argnums=0)

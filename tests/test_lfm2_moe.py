@@ -11,21 +11,23 @@ engine around a sequence that owns pages and a tail.
 import asyncio
 import dataclasses
 import functools
-import importlib.util
 import os
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
+from family_contract import (
+    REPO, Family, _cache, _greedy, _greedy_reference, _prefill,
+    _programs, _table, _whole, case, cases, chunked, preempt,
+    prefill_then_decode, run,
+)
+
 
 from dynamo_tpu.engine.config import EngineConfig, ModelSpec
 from dynamo_tpu.engine.core import InferenceEngine
 from dynamo_tpu.models import llama, moe
 from dynamo_tpu.models.family import GqaFamily, get_family
-from dynamo_tpu.runtime.context import PRIORITY_HEADER, Context
-
-REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
 # the reference reads the published keys; the program reads SPEC. Four
 # layers: a dense conv layer, an expert attention layer, two expert conv
@@ -47,57 +49,53 @@ PAGE, PAGES_PER_SEQ, T, ROWS = 4, 16, 40, 3
 SEED = 13
 
 
-@pytest.fixture(scope="module")
-def ref():
-    spec = importlib.util.spec_from_file_location(
-        "shortconv_moe",
-        os.path.join(REPO, "perfbench/references/shortconv_moe.py"))
-    mod = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(mod)
-    return mod
+def _packed(k, v):
+    """Behind the pack of 13 tokens and an empty member: the tail the row
+    keeps is the ``z`` of its last two REAL tokens; the trash row took the
+    empty member's "tail": zeros, as it was."""
+    owner = np.asarray(k.rows.owner[0])
+    tails = np.asarray(v.pools[CONV])
+    assert np.abs(tails[:, int(np.argmax(owner == 1))]).max() > 0
+    assert not tails[:, ROWS].any()
 
 
-@pytest.fixture(scope="module")
-def model(ref):
-    params = llama.init_params(SPEC, jax.random.PRNGKey(SEED))
-    toks = np.asarray(
-        jax.random.randint(jax.random.PRNGKey(1), (3, T), 0, 96))
-    want = np.asarray(ref.forward(
-        CONFIG, SEED, toks, np.tile(np.arange(T), (3, 1))))
-    return params, toks, want
+def _served(engine, snap, served, outs):
+    """Two prompts of 16 + 5 tokens: four prefill members, the second
+    chunk of each resumes a tail; three expert layers, 2 x 21 prompt
+    tokens, top-4 of 8, all held."""
+    assert engine._prefill_walks == {"full": 0}
+    assert engine.recurrent_state == {
+        "prefill_chunks": 4, "rows_resumed": 2}
+    assert not engine.kda and not engine.ssd
+    assert snap["recurrent_state.prefill_chunks"]["calls"] == 4
+    assert snap["recurrent_state.rows_resumed"]["calls"] == 2
+    assert snap["recurrent_state.row_missing"]["calls"] == 0
+    m = engine.moe_counters()
+    assert m["layers"] == 3 and m["prefill.assignments"] == 3 * 2 * 21 * 4
+    assert m["prefill.assignments_held"] == m["prefill.assignments"]
+    assert m["prefill.assignments_held"] == sum(
+        m[f"prefill.expert.{i}"] for i in range(8))
 
 
-def _cache(rows=ROWS, spec=SPEC, page=PAGE):
-    return llama.init_cache(
-        spec, 1 + 3 * PAGES_PER_SEQ * (PAGE // page), page, state_rows=rows)
+# the family's row of the contract (tests/family_contract.py). Chunks also
+# where the boundary falls one and two tokens after the start (a tail still
+# part zeros), on pages of one token; no kernel of its own: the default path
+F = FAMILY = Family(
+    spec=SPEC, config=CONFIG, reference="shortconv_moe",
+    seed=SEED, state_rows=ROWS, prompts=(), pack_tol=1e-6,
+    bursts_paths=(None,), inactive_paths=(None,), engine_path=None,
+    streams=(False, True), also={"pack": _packed, "serves": _served})
+RESUMED = {"resumed-after-one-token": [(0, 1), (1, 36)],
+           "resumed-after-two-tokens": [(0, 2), (2, 35)],
+           "three-one-token-chunks": [(0, 1), (1, 1), (2, 1), (3, 34)]}
 
 
-def _table(row, page=PAGE):
-    n = PAGES_PER_SEQ * (PAGE // page)
-    return jnp.arange(n, dtype=jnp.int32) + 1 + row * n
-
-
-def _close(got, want, tol=3e-4):
-    np.testing.assert_allclose(np.asarray(got), want, rtol=tol, atol=tol)
-
-
-def _programs():
-    return (jax.jit(llama.prefill_forward_impl, static_argnums=(0,)),
-            jax.jit(llama.prefill_forward_batch_impl, static_argnums=(0,)),
-            jax.jit(llama.decode_forward_impl, static_argnums=(0,)),
-            jax.jit(llama.decode_steps_impl, static_argnums=(0,),
-                    static_argnames=("n_steps", "n_logprobs")))
-
-
-def _prefill(pf, params, toks, row, start, n, k, v, bucket=16, spec=SPEC,
-             page=PAGE):
-    padded = np.zeros((bucket,), np.int32)
-    padded[:n] = toks[row, start: start + n]
-    logits, k, v, _ = pf(
-        spec, params, jnp.asarray(padded), _table(row, page),
-        jnp.asarray(start, jnp.int32), k, v, jnp.asarray(n, jnp.int32),
-    )
-    return logits, k, v
+@pytest.mark.parametrize("case,kw", cases(
+    F, *(case(f"chunked-{name}", chunked, chunks=chunks, page=1)
+         for name, chunks in RESUMED.items()),
+    case("engine-preempt", preempt)))
+def test_the_family_contract(case, kw, monkeypatch):
+    run(case, F, monkeypatch, **kw)
 
 
 def test_a_recurrent_kind_that_keeps_tails_and_no_state():
@@ -111,7 +109,7 @@ def test_a_recurrent_kind_that_keeps_tails_and_no_state():
     assert kinds[CONV].recurrent and not kinds[CONV].state
     assert not kinds[CONV].paged and kinds[ATTN].paged
     assert SPEC.has_recurrent and SPEC.mixers == {"softmax", "conv"}
-    k, v = _cache()
+    k, v = _cache(F)
     assert k.pools[CONV] is None
     assert v.pools[CONV].shape == (3, ROWS + 1, 2, 64)
     assert v.pools[CONV].dtype == jnp.float32  # the toy's activation dtype
@@ -155,166 +153,31 @@ def test_the_other_kinds_entries_keep_their_shapes(kinds):
 
 @pytest.mark.parametrize("dtype,tol", [("float32", 3e-4), ("bfloat16", 0.12)])
 def test_a_whole_prompt_and_decode_steps_are_the_references(
-        ref, model, dtype, tol):
+        ref, model, monkeypatch, dtype, tol):
     """A prompt through the prefill program, then teacher-forced decode
     steps through the tails of the conv layers and the pages of the
     attention layer: every position's logits are the reference's whole
     forward pass; in bfloat16 (weights, activations, pages, tails) to its
     rounding. The other slots are empty or inactive."""
     if dtype == "float32":
-        params, toks, want = model
-        spec = SPEC
-    else:
-        spec = dataclasses.replace(SPEC, dtype=dtype)
-        params = llama.init_params(spec, jax.random.PRNGKey(SEED))
-        toks = model[1]
-        want = np.asarray(ref.forward(
-            dict(CONFIG, torch_dtype=dtype), SEED, toks,
-            np.tile(np.arange(T), (3, 1))))
+        prefill_then_decode(F, monkeypatch)
+        return
+    spec = dataclasses.replace(SPEC, dtype=dtype)
+    params = llama.init_params(spec, jax.random.PRNGKey(SEED))
+    toks = model[1]
+    want = np.asarray(ref.forward(
+        dict(CONFIG, torch_dtype=dtype), SEED, toks,
+        np.tile(np.arange(T), (3, 1))))
     scale = float(np.sqrt(np.mean(want ** 2)))
 
     def held(got, at, what):
         # float32: to 3e-4 by element; bfloat16: the root mean square of
         # the difference under ``tol`` of the logits' own
-        if dtype == "float32":
-            return _close(got, want[1, at])
         err = np.sqrt(np.mean((np.asarray(got, np.float32) - want[1, at]) ** 2))
         assert err < tol * scale, (what, err, scale)
 
-    pf, _, df, _ = _programs()
-    k, v = _cache(spec=spec)
-    n = 21
-    logits, k, v = _prefill(pf, params, toks, 1, 0, n, k, v, bucket=32,
-                            spec=spec)
-    held(logits, n - 1, "prefill")
-    bts = np.zeros((3, PAGES_PER_SEQ), np.int32)
-    bts[2] = np.asarray(_table(1))
-    active = np.array([False, False, True])
-    for j in range(6):
-        fed = np.zeros((3,), np.int32)
-        seq = np.ones((3,), np.int32)
-        fed[2], seq[2] = toks[1, n + j], n + j + 1
-        lg, k, v = df(spec, params, jnp.asarray(fed), jnp.asarray(bts),
-                      jnp.asarray(seq), k, v, jnp.asarray(active))
-        held(lg[2], n + j, f"decode step {j}")
-    stats = np.asarray(k.rows.stats[0])
-    assert stats[llama.STAT_CLAIMS] == 1 and stats[llama.STAT_MISSING] == 0
-
-
-@pytest.mark.parametrize("page,chunks", [
-    (4, [(0, 37)]),
-    (4, [(0, 16), (16, 16), (32, 5)]),
-    (1, [(0, 1), (1, 36)]),
-    (1, [(0, 2), (2, 35)]),
-    (1, [(0, 1), (1, 1), (2, 1), (3, 34)]),
-], ids=["one-shot", "three-chunks", "resumed-after-one-token",
-        "resumed-after-two-tokens", "three-one-token-chunks"])
-def test_a_chunked_prompt_resumes_its_tail(model, page, chunks):
-    """Chunks at ``start_pos`` > 0 resume the convolution from the tail
-    the chunk before left in the row, also where the boundary falls one
-    and two tokens after the sequence's start (a tail that is still part
-    zeros): the last chunk's logits are the one-shot prefill's and the
-    reference's."""
-    params, toks, want = model
-    pf = _programs()[0]
-    k, v = _cache(page=page)
-    for start, n in chunks:
-        logits, k, v = _prefill(
-            pf, params, toks, 0, start, n, k, v,
-            bucket=64 if n > 16 else 16, page=page)
-    _close(logits, want[0, 36])
-    assert int(k.rows.stats[0, llama.STAT_MISSING]) == 0
-    assert int(k.rows.stats[0, llama.STAT_CLAIMS]) == 1
-
-
-def test_a_ragged_pack_keeps_rows_apart(model):
-    """Rows of different lengths and an empty row in packed calls, one of
-    them a pack of two RESUMED chunks: each row's logits are the
-    reference's (no row's ``B * x`` leaks into its neighbour's
-    convolution), the empty row claims nothing, and the tail a row keeps
-    is the ``z`` of its last two REAL tokens whatever the padding."""
-    params, toks, want = model
-    pb = _programs()[1]
-    k, v = _cache()
-
-    def pack(members, bucket=16):
-        nonlocal k, v
-        padded = np.zeros((2, bucket), np.int32)
-        bts = np.zeros((2, PAGES_PER_SEQ), np.int32)
-        starts, lens = np.zeros(2, np.int32), np.zeros(2, np.int32)
-        for i, (row, start, n) in enumerate(members):
-            padded[i, :n] = toks[row, start: start + n]
-            if n:
-                bts[i], starts[i], lens[i] = np.asarray(_table(row)), start, n
-        logits, k, v, _ = pb(
-            SPEC, params, jnp.asarray(padded), jnp.asarray(bts),
-            jnp.asarray(starts), k, v, jnp.asarray(lens))
-        return logits
-
-    logits = pack([(0, 0, 13), (0, 0, 0)])
-    _close(logits[0], want[0, 12])
-    owner = np.asarray(k.rows.owner[0])
-    assert sorted(owner[:ROWS]) == [0, 0, 1] and owner[ROWS] == 0
-    tails_13 = np.asarray(v.pools[CONV])[:, int(np.argmax(owner == 1))]
-    # the same 13 tokens in a wider bucket leave the same tail: padding
-    # past the last real token does not reach it
-    k2, v2 = _cache()
-    _, k2, v2 = _prefill(_programs()[0], params, toks, 0, 0, 13, k2, v2,
-                         bucket=32)
-    np.testing.assert_allclose(
-        np.asarray(v2.pools[CONV])[:, 0], tails_13, rtol=1e-6, atol=1e-6)
-    assert np.abs(tails_13).max() > 0
-    # the trash row took the empty member's "tail": zeros, as it was
-    assert not np.asarray(v.pools[CONV])[:, ROWS].any()
-    logits = pack([(1, 0, 16), (2, 0, 8)])
-    _close(logits[0], want[1, 15])
-    _close(logits[1], want[2, 7])
-    logits = pack([(1, 16, 9), (2, 8, 16)])  # two resumed chunks
-    _close(logits[0], want[1, 24])
-    _close(logits[1], want[2, 23])
-    stats = np.asarray(k.rows.stats[0])
-    assert stats[llama.STAT_CLAIMS] == 3 and stats[llama.STAT_MISSING] == 0
-
-
-def test_bursts_of_one_and_eight_agree_after_prefill(model):
-    """Eight greedy steps as one burst and as eight bursts of one after
-    two prefills: the same tokens, the reference's own choices, the same
-    tails and the same pages afterwards."""
-    params, toks, want = model
-    pf, _, _, ds = _programs()
-    B = 3
-    bts = np.zeros((B, PAGES_PER_SEQ), np.int32)
-    bts[0], bts[1] = np.asarray(_table(0)), np.asarray(_table(1))
-    active = jnp.asarray([True, True, False])
-    z = jnp.zeros((B,), jnp.int32)
-
-    def run(bursts):
-        k, v = _cache()
-        for row, n in ((0, 9), (1, 14)):
-            _, k, v = _prefill(pf, params, toks, row, 0, n, k, v)
-        fed = np.array([toks[0, 9], toks[1, 14], 0], np.int32)
-        seq = np.array([10, 15, 1], np.int32)
-        out = []
-        for n_steps in bursts:
-            o, k, v = ds(
-                SPEC, params, jnp.asarray(fed), jnp.asarray(bts),
-                jnp.asarray(seq), k, v, active, jnp.zeros((B,)), z,
-                jnp.ones((B,)), jnp.zeros((B,), jnp.uint32), z,
-                n_steps=n_steps, n_logprobs=0)
-            o = np.asarray(o)
-            out.append(o[:2])
-            fed[:2], seq[:2] = o[:2, -1], seq[:2] + n_steps
-        return np.concatenate(out, axis=1), k, v
-
-    one, k1, v1 = run([1] * 8)
-    eight, k8, v8 = run([8])
-    np.testing.assert_array_equal(one, eight)
-    # the first token of each row is the reference's greedy choice
-    assert one[0, 0] == int(np.argmax(want[0, 9]))
-    assert one[1, 0] == int(np.argmax(want[1, 14]))
-    _close(v8.pools[CONV][:, :2], np.asarray(v1.pools[CONV][:, :2]), tol=1e-5)
-    _close(k8.pools[ATTN][:, 1:], np.asarray(k1.pools[ATTN][:, 1:]), tol=1e-5)
-    assert int(k8.rows.stats[0, llama.STAT_MISSING]) == 0
+    prefill_then_decode(
+        F, monkeypatch, spec=spec, model=(params, toks, want), held=held)
 
 
 def test_a_released_row_taken_over_starts_from_zeros(model):
@@ -323,14 +186,14 @@ def test_a_released_row_taken_over_starts_from_zeros(model):
     logits are the reference's. An inactive slot and the other rows keep
     their tails to the bit through a decode step."""
     params, toks, want = model
-    pf, _, df, _ = _programs()
-    k, v = _cache(rows=2)
+    pf, _, df, _ = _programs(F)
+    k, v = _cache(F, rows=2)
     for row, n in ((0, 9), (1, 14)):
-        _, k, v = _prefill(pf, params, toks, row, 0, n, k, v)
+        _, k, v = _prefill(F, pf, params, toks, row, 0, n, k, v)
     before = np.asarray(v.pools[CONV])
     assert np.abs(before[:, 0]).max() > 0 and np.abs(before[:, 1]).max() > 0
     # a decode step with row 1's slot live alone leaves row 0's tail
-    bts = np.stack([np.asarray(_table(r)) for r in range(2)])
+    bts = np.stack([np.asarray(_table(F, r)) for r in range(2)])
     _, k, v = df(
         SPEC, params, jnp.asarray(toks[:2, 20]), jnp.asarray(bts),
         jnp.asarray([10, 15], jnp.int32), k, v, jnp.asarray([False, True]))
@@ -339,10 +202,10 @@ def test_a_released_row_taken_over_starts_from_zeros(model):
     assert not np.array_equal(now[:, 1], before[:, 1])
     # sequence 0 goes; sequence 2 takes its row over and is the reference's
     k = llama.release_state_rows(k, jnp.asarray(
-        [int(_table(0)[0]), -1], jnp.int32))
+        [int(_table(F, 0)[0]), -1], jnp.int32))
     assert list(np.asarray(k.rows.owner[0])) == [0, 1 + PAGES_PER_SEQ, 0]
-    logits, k, v = _prefill(pf, params, toks, 2, 0, 11, k, v)
-    _close(logits, want[2, 10])
+    logits, k, v = _prefill(F, pf, params, toks, 2, 0, 11, k, v)
+    F.close(logits, want[2, 10])
     assert list(np.asarray(k.rows.owner[0]))[0] == 1 + 2 * PAGES_PER_SEQ
     assert int(k.rows.stats[0, llama.STAT_MISSING]) == 0
 
@@ -381,11 +244,11 @@ def test_every_published_mechanism_moves_the_logits(model, name):
     order of B | C | x rolled) it is not, by many times that: the
     comparison sees each. The gains are drawn about 1, not AT 1."""
     params, toks, want = model
-    got = llama.reference_forward(SPEC, params, jnp.asarray(toks[0]))
-    _close(got, want[0])
+    got = _whole(SPEC, params, jnp.asarray(toks[0]))
+    F.close(got, want[0])
     assert float(jnp.abs(params["layers"][1]["q_norm"] - 1).max()) > 0.05
     spec, changed = MECHANISMS[name](SPEC, params)
-    off = llama.reference_forward(spec, changed, jnp.asarray(toks[0]))
+    off = _whole(spec, changed, jnp.asarray(toks[0]))
     assert float(np.abs(np.asarray(off) - want[0]).max()) > 3e-3, name
 
 
@@ -548,87 +411,6 @@ def test_the_published_config_maps_to_the_spec():
     assert spec.intermediate_size == 11776 and spec.vocab_size == 65536
 
 
-# ------------------------------------------------------------- the engine
-
-
-def _engine(**kw):
-    base = dict(
-        page_size=PAGE, num_pages=64, max_pages_per_seq=PAGES_PER_SEQ,
-        max_decode_slots=2, prefill_buckets=(16,), max_prefill_chunk_tokens=16,
-        decode_steps_per_dispatch=4, seed=SEED,
-    )
-    base.update(kw)
-    return InferenceEngine(SPEC, EngineConfig(**base))
-
-
-async def _greedy(engine, prompt, n, out=None, ctx=None):
-    out = [] if out is None else out
-    async for item in engine.generate(
-        {"token_ids": list(prompt), "sampling": {"temperature": 0.0},
-         "stop_conditions": {"max_tokens": n, "ignore_eos": True}},
-        ctx or Context(),
-    ):
-        assert item.get("finish_reason") != "error", item
-        out.extend(item.get("token_ids") or [])
-    return out
-
-
-_jit_reference = jax.jit(llama.reference_forward, static_argnums=0)
-
-
-def _greedy_reference(params, prompt, n):
-    seq = list(prompt)
-    for _ in range(n):
-        padded = np.zeros((64,), np.int32)
-        padded[: len(seq)] = seq
-        lg = _jit_reference(SPEC, params, jnp.asarray(padded))
-        seq.append(int(np.argmax(np.asarray(lg[len(seq) - 1]))))
-    return seq[len(prompt):]
-
-
-async def test_serves_through_the_engine_and_counts():
-    """The toy model through the REAL engine (scheduler, a prompt of two
-    chunks, bursts): the greedy stream is the whole forward pass's own;
-    nothing is reused under a prefix; the rows go back; the gates a
-    recurrent model sets are set; the counters read what hand arithmetic
-    gives."""
-    engine = _engine()
-    fam = engine.fam
-    assert isinstance(fam, GqaFamily) and fam.recurrent
-    assert not fam.supports_prefix_reuse and not engine.allocator.prefix_cache
-    for gate in ("ring_prefill", "spec_decode", "mesh", "page_transfer",
-                 "multimodal"):
-        assert not getattr(fam, f"supports_{gate}"), gate
-    assert engine._prefill_walks == {"full": 0}
-    prompt = [int(t) for t in np.arange(7, 7 + 21) % 96]  # two chunks
-    want = _greedy_reference(engine.params, prompt, 6)
-    assert await _greedy(engine, prompt, 6) == want
-    assert await _greedy(engine, prompt, 6) == want
-    assert engine.allocator._hash_page == {}
-    assert engine.allocator.active_pages == 0
-    # two prompts of 16 + 5 tokens: four prefill members, the second
-    # chunk of each resumes a tail
-    assert engine.recurrent_state == {
-        "prefill_chunks": 4, "rows_resumed": 2}
-    assert not engine.kda and not engine.ssd
-    await engine.close()
-    engine._metrics_publishes = 0
-    for _ in range(34):  # two refreshes bring the device's counters over
-        engine._publish_metrics()
-    c = engine.state_counters()
-    assert c == {"rows": 2, "rows_live": 0, "claims": 2, "row_missing": 0}
-    snap = engine.profile_snapshot()
-    assert snap["recurrent_state.prefill_chunks"]["calls"] == 4
-    assert snap["recurrent_state.rows_resumed"]["calls"] == 2
-    assert snap["recurrent_state.row_missing"]["calls"] == 0
-    m = engine.moe_counters()
-    # three expert layers, 2 x 21 prompt tokens, top-4 of 8, all held
-    assert m["layers"] == 3 and m["prefill.assignments"] == 3 * 2 * 21 * 4
-    assert m["prefill.assignments_held"] == m["prefill.assignments"]
-    assert m["prefill.assignments_held"] == sum(
-        m[f"prefill.expert.{i}"] for i in range(8))
-
-
 @pytest.mark.parametrize("pallas", ["0", "1"], ids=["xla", "kernel"])
 async def test_the_chips_pool_layout_serves_the_same_tokens(
     monkeypatch, pallas
@@ -668,54 +450,5 @@ async def test_the_chips_pool_layout_serves_the_same_tokens(
     assert shape == (1, 65, 1, PAGE, 128) and a_row == 2
     assert packed_bytes == nbytes  # no zeros: the model's own bytes
     assert packed == plain
-    reference = jax.jit(llama.reference_forward, static_argnums=0)
     for prompt, got in zip(prompts, plain):
-        seq = list(prompt)
-        for _ in range(7):
-            padded = np.zeros((64,), np.int32)
-            padded[: len(seq)] = seq
-            lg = reference(spec, params, jnp.asarray(padded))
-            seq.append(int(np.argmax(np.asarray(lg[len(seq) - 1]))))
-        assert got == seq[len(prompt):]
-
-
-@pytest.mark.parametrize("pipeline", [False, True], ids=["plain", "pipelined"])
-async def test_streams_share_the_engine(pipeline):
-    """Three prompts on two slots, one of them chunked behind running
-    bursts: every stream is what it gets alone, pipelined or not, rows
-    are claimed and freed as slots turn over, none goes missing."""
-    prompts = [[3, 9, 27], [8, 64, 32, 5],
-               [int(t) for t in np.arange(5, 5 + 37) * 7 % 96]]
-    engine = _engine(pipeline_decode=pipeline, async_admissions=True)
-    want = [_greedy_reference(engine.params, p, n)
-            for p, n in zip(prompts, (12, 9, 6))]
-    outs = await asyncio.gather(*(
-        _greedy(engine, p, n) for p, n in zip(prompts, (12, 9, 6))))
-    assert outs == want
-    assert engine.allocator.active_pages == 0
-    await engine.close()
-    assert int(engine.k_pages.rows.stats[0, llama.STAT_MISSING]) == 0
-    assert int(engine.k_pages.rows.stats[0, llama.STAT_CLAIMS]) == 3
-
-
-async def test_preempt_and_resume_by_recomputation():
-    """A batch stream preempted for an interactive one gives its row and
-    pages back and resumes by prefilling its prompt and its output so far
-    from an empty tail: the tokens of an undisturbed run."""
-    prompt = [5, 11, 17, 23, 29]
-    engine = _engine(max_decode_slots=1, prefill_buckets=(16, 32, 64),
-                     max_prefill_chunk_tokens=64)
-    want = _greedy_reference(engine.params, prompt, 24)
-    got: list = []
-    batch = asyncio.create_task(_greedy(
-        engine, prompt, 24, out=got,
-        ctx=Context(headers={PRIORITY_HEADER: "batch"})))
-    while len(got) < 6:
-        await asyncio.sleep(0.002)
-    quick = await _greedy(engine, [2, 4, 6], 3)
-    assert quick == _greedy_reference(engine.params, [2, 4, 6], 3)
-    assert await batch == want
-    assert sum(engine.preemptions.values()) >= 1
-    assert engine.allocator.active_pages == 0
-    await engine.close()
-    assert int(engine.k_pages.rows.stats[0, llama.STAT_MISSING]) == 0
+        assert got == _greedy_reference(F, params, prompt, 7, spec=spec)

@@ -8,25 +8,22 @@ Programs, kernels (interpreted) on a kind's pool, the share of an ep
 deployment, and the engine around a sequence that owns both.
 """
 
-import asyncio
 import dataclasses
-import importlib.util
-import os
 
 import jax
 import jax.numpy as jnp
 import kda_step_cases
 import numpy as np
 import pytest
+from family_contract import (
+    Family, _cache, _prefill, _programs, _table, _whole, cases, run,
+)
+
 
 from dynamo_tpu.engine.config import EngineConfig, LayerKind, ModelSpec
-from dynamo_tpu.engine.core import InferenceEngine
 from dynamo_tpu.models import llama
 from dynamo_tpu.models.family import GqaFamily, get_family
 from dynamo_tpu.ops import attention as attn_ops
-from dynamo_tpu.runtime.context import Context
-
-REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
 # the reference reads the published keys; the program reads SPEC. Three
 # layers: a dense KDA layer, an expert KDA layer, an expert MLA layer
@@ -56,56 +53,44 @@ PAGE, PAGES_PER_SEQ, T, ROWS = 4, 16, 40, 3
 SEED = 13
 
 
-@pytest.fixture(scope="module")
-def ref():
-    spec = importlib.util.spec_from_file_location(
-        "linear_latent_moe",
-        os.path.join(REPO, "perfbench/references/linear_latent_moe.py"))
-    mod = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(mod)
-    return mod
+def _served(engine, snap, served, outs):
+    """Two prompts of 16 + 5 tokens: a block of 64 holds each chunk, the
+    second chunk of each resumes a row; the latent walk ran four times;
+    the counters by kind of cache."""
+    assert engine._prefill_walks == {"latent": 0}
+    assert engine._kv_chunk_pages is not None
+    assert engine.kda["prefill_blocks"] == 4
+    assert engine.kda["rows_resumed"] == 2
+    assert engine.kda["decode_rows"] % 4 == 0 and engine.kda["decode_rows"] >= 16
+    assert engine.prefill_kv["dispatches.latent"] == 4
+    assert engine.prefill_kv["kernel_calls.latent"] == 4
+    assert engine.prefill_kv["blocks_visited.latent"] >= 4
+    assert engine.decode_kv["pages_live"] > 0
+    assert snap["kda.rows_resumed"]["calls"] == 2
+    assert snap["prefill_kv.blocks_visited.latent"]["calls"] >= 4
+    m = engine.moe_counters()
+    # two expert layers, 2 x 21 prompt tokens, top-4 of 16 in 2 of 4 groups
+    assert m["layers"] == 2 and m["prefill.assignments"] == 2 * 2 * 21 * 4
+    assert 0 < m["prefill.assignments_held"] < m["prefill.assignments"]
+    assert m["prefill.assignments_held"] == sum(
+        m[f"prefill.expert.{i}"] for i in range(4))
 
 
-@pytest.fixture(scope="module")
-def model(ref):
-    params = llama.init_params(SPEC, jax.random.PRNGKey(SEED))
-    toks = np.asarray(
-        jax.random.randint(jax.random.PRNGKey(1), (3, T), 0, 96))
-    want = np.asarray(ref.forward(
-        CONFIG, SEED, toks, np.tile(np.arange(T), (3, 1))))
-    return params, toks, want
+# the family's row of the contract (tests/family_contract.py); its packs
+# end in a pack of two RESUMED chunks of 16-token starts
+F = FAMILY = Family(
+    spec=SPEC, config=CONFIG,
+    reference="linear_latent_moe", seed=SEED, state_rows=ROWS,
+    chunked_paths=("0", "1"), pack_path="1",
+    packs=([(0, 0, 13), (0, 0, 0)], [(1, 0, 16), (2, 0, 16)],
+           [(1, 16, 9), (2, 16, 16)]),
+    inactive_paths=("0", "1"), streams=(False, True),
+    also={"serves": _served})
 
 
-def _cache(rows=ROWS, spec=SPEC):
-    return llama.init_cache(
-        spec, 1 + 3 * PAGES_PER_SEQ, PAGE, state_rows=rows)
-
-
-def _table(row):
-    return jnp.arange(PAGES_PER_SEQ, dtype=jnp.int32) + 1 + row * PAGES_PER_SEQ
-
-
-def _close(got, want, tol=3e-4):
-    np.testing.assert_allclose(np.asarray(got), want, rtol=tol, atol=tol)
-
-
-# fresh jits a test: the kernel/XLA choice is read at trace time
-def _programs():
-    return (jax.jit(llama.prefill_forward_impl, static_argnums=(0,)),
-            jax.jit(llama.prefill_forward_batch_impl, static_argnums=(0,)),
-            jax.jit(llama.decode_forward_impl, static_argnums=(0,)),
-            jax.jit(llama.decode_steps_impl, static_argnums=(0,),
-                    static_argnames=("n_steps", "n_logprobs")))
-
-
-def _prefill(pf, params, toks, row, start, n, k, v, bucket=16, spec=SPEC):
-    padded = np.zeros((bucket,), np.int32)
-    padded[:n] = toks[row, start: start + n]
-    logits, k, v, _ = pf(
-        spec, params, jnp.asarray(padded), _table(row),
-        jnp.asarray(start, jnp.int32), k, v, jnp.asarray(n, jnp.int32),
-    )
-    return logits, k, v
+@pytest.mark.parametrize("case,kw", cases(F))
+def test_the_family_contract(case, kw, monkeypatch):
+    run(case, F, monkeypatch, **kw)
 
 
 def test_one_family_one_table_one_directory():
@@ -117,7 +102,7 @@ def test_one_family_one_table_one_directory():
     assert isinstance(fam, GqaFamily) and fam.recurrent
     assert not SPEC.is_mla and SPEC.has_latent and SPEC.has_recurrent
     assert ModelSpec.tiny_deepseek().is_mla
-    k, v = _cache()
+    k, v = _cache(F)
     D = SPEC.kv_lora_rank + SPEC.qk_rope_head_dim
     assert k.pools[LATENT].shape == (1, 1 + 3 * PAGES_PER_SEQ, PAGE, D)
     assert v.pools[LATENT] is None
@@ -127,168 +112,6 @@ def test_one_family_one_table_one_directory():
     assert llama.page_size_of(k) == PAGE
     assert k.rows.owner.shape == (1, ROWS + 1)
     assert SPEC.clamps(0) == (0.0, 0.0) and SPEC.clamps(2) == (0.75, 0.4)
-
-
-@pytest.mark.parametrize("pallas", ["0", "1"], ids=["xla", "kernels"])
-def test_prefill_then_decode_through_state_and_latent_pages(
-        model, monkeypatch, pallas):
-    """A prompt through the prefill program, then teacher-forced decode
-    steps through the state rows of the KDA layers and the latent pages
-    of the MLA layer: every position's logits are the reference's whole
-    forward pass. The other slots are empty or inactive."""
-    monkeypatch.setenv("DYNAMO_PALLAS", pallas)
-    params, toks, want = model
-    pf, _, df, _ = _programs()
-    k, v = _cache()
-    n = 21
-    logits, k, v = _prefill(pf, params, toks, 1, 0, n, k, v, bucket=32)
-    _close(logits, want[1, n - 1])
-    bts = np.zeros((3, PAGES_PER_SEQ), np.int32)
-    bts[2] = np.asarray(_table(1))
-    active = np.array([False, False, True])
-    for j in range(6):
-        fed = np.zeros((3,), np.int32)
-        seq = np.ones((3,), np.int32)
-        fed[2], seq[2] = toks[1, n + j], n + j + 1
-        lg, k, v = df(SPEC, params, jnp.asarray(fed), jnp.asarray(bts),
-                      jnp.asarray(seq), k, v, jnp.asarray(active))
-        _close(lg[2], want[1, n + j])
-    stats = np.asarray(k.rows.stats[0])
-    assert stats[llama.STAT_CLAIMS] == 1 and stats[llama.STAT_MISSING] == 0
-
-
-@pytest.mark.parametrize("chunks", [
-    [(0, 37)], [(0, 16), (16, 16), (32, 5)],
-], ids=["one-shot", "three-chunks"])
-@pytest.mark.parametrize("pallas", ["0", "1"], ids=["xla", "kernels"])
-def test_a_chunked_prompt_resumes_state_and_appends_latents(
-        model, monkeypatch, chunks, pallas):
-    """Chunks at ``start_pos`` > 0 resume the chunkwise form from the
-    state and the convolution tail the chunk before left in the row, and
-    attend over the latent pages the chunks before wrote: the last
-    chunk's logits are the one-shot prefill's and the reference's."""
-    monkeypatch.setenv("DYNAMO_PALLAS", pallas)
-    params, toks, want = model
-    pf = _programs()[0]
-    k, v = _cache()
-    for start, n in chunks:
-        logits, k, v = _prefill(
-            pf, params, toks, 0, start, n, k, v,
-            bucket=64 if n > 16 else 16)
-    _close(logits, want[0, 36])
-    assert int(k.rows.stats[0, llama.STAT_MISSING]) == 0
-
-
-def test_a_pack_of_two_with_an_empty_member(model, monkeypatch):
-    """Rows of different lengths and an empty row in packed calls, one of
-    them a pack of two RESUMED chunks: each row's logits are the
-    reference's, the empty row claims no state and writes no page."""
-    monkeypatch.setenv("DYNAMO_PALLAS", "1")
-    params, toks, want = model
-    pb = _programs()[1]
-    k, v = _cache()
-
-    def pack(members, bucket=16):
-        nonlocal k, v
-        padded = np.zeros((2, bucket), np.int32)
-        bts = np.zeros((2, PAGES_PER_SEQ), np.int32)
-        starts, lens = np.zeros(2, np.int32), np.zeros(2, np.int32)
-        for i, (row, start, n) in enumerate(members):
-            padded[i, :n] = toks[row, start: start + n]
-            if n:
-                bts[i], starts[i], lens[i] = np.asarray(_table(row)), start, n
-        logits, k, v, _ = pb(
-            SPEC, params, jnp.asarray(padded), jnp.asarray(bts),
-            jnp.asarray(starts), k, v, jnp.asarray(lens))
-        return logits
-
-    logits = pack([(0, 0, 13), (0, 0, 0)])
-    _close(logits[0], want[0, 12])
-    owner = np.asarray(k.rows.owner[0])
-    assert sorted(owner[:ROWS]) == [0, 0, 1] and owner[ROWS] == 0
-    # the empty member's table is the trash page's: no other page moved
-    assert not np.asarray(k.pools[LATENT][:, 1 + PAGES_PER_SEQ:]).any()
-    logits = pack([(1, 0, 16), (2, 0, 16)])
-    _close(logits[0], want[1, 15])
-    _close(logits[1], want[2, 15])
-    logits = pack([(1, 16, 9), (2, 16, 16)])  # two resumed chunks
-    _close(logits[0], want[1, 24])
-    _close(logits[1], want[2, 31])
-    stats = np.asarray(k.rows.stats[0])
-    assert stats[llama.STAT_CLAIMS] == 3 and stats[llama.STAT_MISSING] == 0
-
-
-def test_bursts_of_one_and_eight_agree(model, monkeypatch):
-    """Eight greedy steps as one burst and as eight bursts of one: the
-    same tokens, the same state and the same latent pages afterwards (the
-    burst finds its rows once; the latent schedule is made a step)."""
-    monkeypatch.setenv("DYNAMO_PALLAS", "1")
-    params, toks, _ = model
-    pf, _, _, ds = _programs()
-    B = 3
-    bts = np.zeros((B, PAGES_PER_SEQ), np.int32)
-    bts[0], bts[1] = np.asarray(_table(0)), np.asarray(_table(1))
-    active = jnp.asarray([True, True, False])
-    z = jnp.zeros((B,), jnp.int32)
-
-    def run(bursts):
-        k, v = _cache()
-        for row, n in ((0, 9), (1, 14)):
-            _, k, v = _prefill(pf, params, toks, row, 0, n, k, v)
-        fed = np.array([toks[0, 9], toks[1, 14], 0], np.int32)
-        seq = np.array([10, 15, 1], np.int32)
-        out = []
-        for n_steps in bursts:
-            o, k, v = ds(
-                SPEC, params, jnp.asarray(fed), jnp.asarray(bts),
-                jnp.asarray(seq), k, v, active, jnp.zeros((B,)), z,
-                jnp.ones((B,)), jnp.zeros((B,), jnp.uint32), z,
-                n_steps=n_steps, n_logprobs=0)
-            o = np.asarray(o)
-            out.append(o[:2])
-            fed[:2], seq[:2] = o[:2, -1], seq[:2] + n_steps
-        return np.concatenate(out, axis=1), k
-
-    one, k1 = run([1] * 8)
-    eight, k8 = run([8])
-    np.testing.assert_array_equal(one, eight)
-    _close(k8.pools[KDA_KIND][:, :2], np.asarray(k1.pools[KDA_KIND][:, :2]),
-           tol=1e-5)
-    _close(k8.pools[LATENT][:, 1:], np.asarray(k1.pools[LATENT][:, 1:]),
-           tol=1e-5)
-
-
-@pytest.mark.parametrize("pallas", ["0", "1"], ids=["xla", "kernels"])
-def test_an_inactive_slot_and_a_released_row_touch_nothing(
-        model, monkeypatch, pallas):
-    """A decode step with one live slot: the other sequence's row (its
-    slot inactive) and a row whose owner was released keep their state
-    and tail to the bit; so do the latent pages of both."""
-    monkeypatch.setenv("DYNAMO_PALLAS", pallas)
-    params, toks, _ = model
-    pf, _, df, _ = _programs()
-    k, v = _cache()
-    for row, n in ((0, 9), (1, 14), (2, 11)):
-        _, k, v = _prefill(pf, params, toks, row, 0, n, k, v)
-    k = llama.release_state_rows(k, jnp.asarray(
-        [int(_table(2)[0]), -1], jnp.int32))
-    assert list(np.asarray(k.rows.owner[0])) == [1, 1 + PAGES_PER_SEQ, 0, 0]
-    before = jax.tree.map(np.asarray, (k, v))
-    bts = np.stack([np.asarray(_table(r)) for r in range(3)])
-    lg, k, v = df(
-        SPEC, params, jnp.asarray(toks[:, 20]), jnp.asarray(bts),
-        jnp.asarray([10, 15, 12], jnp.int32), k, v,
-        jnp.asarray([True, False, False]))
-    for side, was in zip((k, v), before):
-        now = np.asarray(side.pools[KDA_KIND])
-        assert not np.array_equal(now[:, 0], was.pools[KDA_KIND][:, 0])
-        np.testing.assert_array_equal(now[:, 1:3], was.pools[KDA_KIND][:, 1:3])
-    pages, old = np.asarray(k.pools[LATENT]), before[0].pools[LATENT]
-    assert not np.array_equal(
-        pages[:, 1: 1 + PAGES_PER_SEQ], old[:, 1: 1 + PAGES_PER_SEQ])
-    np.testing.assert_array_equal(
-        pages[:, 1 + PAGES_PER_SEQ:], old[:, 1 + PAGES_PER_SEQ:])
-    assert int(k.rows.stats[0, llama.STAT_MISSING]) == 0
 
 
 @pytest.mark.parametrize("pallas", ["0", "1"], ids=["xla", "kernel"])
@@ -320,8 +143,8 @@ def test_the_chunk_form_is_the_recurrence_at_both_ends_of_the_bound(
     for n in range(N):
         want_o, want_s = attn_ops.kda_recurrence(
             q[n], k[n], v[n], g[n], beta[n], s0[n])
-        _close(o[n], np.asarray(want_o), tol=1e-4)
-        _close(new[0, n], np.asarray(want_s), tol=1e-4)
+        F.close(o[n], np.asarray(want_o), tol=1e-4)
+        F.close(new[0, n], np.asarray(want_s), tol=1e-4)
 
 
 def test_kernels_equal_their_xla_twins_on_a_kinds_pool(monkeypatch):
@@ -338,16 +161,16 @@ def test_kernels_equal_their_xla_twins_on_a_kinds_pool(monkeypatch):
     outs = {}
     for pallas in ("0", "1"):
         monkeypatch.setenv("DYNAMO_PALLAS", pallas)
-        pf, _, df, _ = _programs()
-        k, v = _cache(spec=spec)
+        pf, _, df, _ = _programs(F)
+        k, v = _cache(F, spec=spec)
         assert k.pools[LATENT].shape[0] == 2
         got = []
         for start, n in ((0, 16), (16, 11)):
             lg, k, v = _prefill(
-                pf, params, toks, 1, start, n, k, v, spec=spec)
+                F, pf, params, toks, 1, start, n, k, v, spec=spec)
             got.append(np.asarray(lg))
         bts = np.zeros((3, PAGES_PER_SEQ), np.int32)
-        bts[0] = np.asarray(_table(1))
+        bts[0] = np.asarray(_table(F, 1))
         for j in range(3):
             lg, k, v = df(
                 spec, params, jnp.asarray([toks[1, 27 + j], 0, 0]),
@@ -357,15 +180,15 @@ def test_kernels_equal_their_xla_twins_on_a_kinds_pool(monkeypatch):
         outs[pallas] = (got, np.asarray(k.pools[LATENT][:, 1:]),
                         np.asarray(k.pools[KDA_KIND][:, :1]))
     for a, b in zip(outs["1"][0], outs["0"][0]):
-        _close(a, b, tol=2e-4)
-    _close(outs["1"][1], outs["0"][1], tol=1e-5)
-    _close(outs["1"][2], outs["0"][2], tol=1e-4)
+        F.close(a, b, tol=2e-4)
+    F.close(outs["1"][1], outs["0"][1], tol=1e-5)
+    F.close(outs["1"][2], outs["0"][2], tol=1e-4)
     # both layers of the kind's pool hold rows, and they differ
     pool = outs["1"][1]
     assert np.abs(pool[0]).max() > 0 and np.abs(pool[1]).max() > 0
     assert not np.allclose(pool[0], pool[1])
-    want = llama.reference_forward(spec, params, jnp.asarray(toks[1, :30]))
-    _close(outs["1"][0][-1], np.asarray(want[29]))
+    want = _whole(spec, params, jnp.asarray(toks[1, :30]))
+    F.close(outs["1"][0][-1], np.asarray(want[29]))
 
 
 @pytest.mark.parametrize("case", kda_step_cases.CASES)
@@ -423,10 +246,10 @@ def test_every_published_mechanism_moves_the_logits(model, name):
     its shared expert's lifted, one more routing group, the routed
     scale) it is not, by a hundred times that: the comparison sees each."""
     params, toks, want = model
-    got = llama.reference_forward(SPEC, params, jnp.asarray(toks[0]))
-    _close(got, want[0])
+    got = _whole(SPEC, params, jnp.asarray(toks[0]))
+    F.close(got, want[0])
     spec, changed = MECHANISMS[name](SPEC, params)
-    off = llama.reference_forward(spec, changed, jnp.asarray(toks[0]))
+    off = _whole(spec, changed, jnp.asarray(toks[0]))
     assert float(np.abs(np.asarray(off) - want[0]).max()) > 3e-2, name
 
 
@@ -474,116 +297,10 @@ def test_the_shares_add_up(ref):
                 x, dict(ex, **{k: ex[k][4 * g: 4 * g + 4]
                                for k in ("e_gate", "e_up", "e_down")}),
                 first=4 * g, held=4, **kw) - x)
-            _close(shares[-1], part, tol=1e-4)
+            F.close(shares[-1], part, tol=1e-4)
         # (a group that no token's top two groups hold adds nothing)
         assert sum(np.abs(s - alike).max() > 1e-3 for s in shares) >= 3
-        _close(alike + sum(s - alike for s in shares), whole, tol=1e-4)
-
-
-# ------------------------------------------------------------- the engine
-
-
-def _engine(**kw):
-    base = dict(
-        page_size=PAGE, num_pages=64, max_pages_per_seq=PAGES_PER_SEQ,
-        max_decode_slots=2, prefill_buckets=(16,), max_prefill_chunk_tokens=16,
-        decode_steps_per_dispatch=4, seed=SEED,
-    )
-    base.update(kw)
-    return InferenceEngine(SPEC, EngineConfig(**base))
-
-
-async def _greedy(engine, prompt, n):
-    out = []
-    async for item in engine.generate(
-        {"token_ids": list(prompt), "sampling": {"temperature": 0.0},
-         "stop_conditions": {"max_tokens": n, "ignore_eos": True}},
-        Context(),
-    ):
-        assert item.get("finish_reason") != "error", item
-        out.extend(item.get("token_ids") or [])
-    return out
-
-
-_jit_reference = jax.jit(llama.reference_forward, static_argnums=0)
-
-
-def _greedy_reference(params, prompt, n):
-    seq = list(prompt)
-    for _ in range(n):
-        padded = np.zeros((64,), np.int32)
-        padded[: len(seq)] = seq
-        lg = _jit_reference(SPEC, params, jnp.asarray(padded))
-        seq.append(int(np.argmax(np.asarray(lg[len(seq) - 1]))))
-    return seq[len(prompt):]
-
-
-async def test_serves_through_the_engine_and_counts(monkeypatch):
-    """The toy model through the REAL engine (scheduler, a prompt of two
-    chunks, all four kernels interpreted in bursts): the greedy stream is
-    the whole forward pass's own; nothing is reused under a prefix; the
-    rows go back; the counters read what hand arithmetic gives, by kind
-    of cache."""
-    monkeypatch.setenv("DYNAMO_PALLAS", "1")
-    engine = _engine()
-    fam = engine.fam
-    assert isinstance(fam, GqaFamily) and fam.recurrent
-    assert not fam.supports_prefix_reuse and not engine.allocator.prefix_cache
-    for gate in ("ring_prefill", "spec_decode", "mesh", "page_transfer",
-                 "multimodal"):
-        assert not getattr(fam, f"supports_{gate}"), gate
-    assert engine._prefill_walks == {"latent": 0}
-    assert engine._kv_chunk_pages is not None
-    prompt = [int(t) for t in np.arange(7, 7 + 21) % 96]  # two chunks
-    want = _greedy_reference(engine.params, prompt, 6)
-    assert await _greedy(engine, prompt, 6) == want
-    assert await _greedy(engine, prompt, 6) == want
-    assert engine.allocator._hash_page == {}
-    assert engine.allocator.active_pages == 0
-    # two prompts of 16 + 5 tokens: a block of 64 holds each chunk, the
-    # second chunk of each resumes a row; the latent walk ran four times
-    assert engine.kda["prefill_blocks"] == 4
-    assert engine.kda["rows_resumed"] == 2
-    assert engine.kda["decode_rows"] % 4 == 0 and engine.kda["decode_rows"] >= 16
-    assert engine.prefill_kv["dispatches.latent"] == 4
-    assert engine.prefill_kv["kernel_calls.latent"] == 4
-    assert engine.prefill_kv["blocks_visited.latent"] >= 4
-    assert engine.decode_kv["pages_live"] > 0
-    await engine.close()
-    engine._metrics_publishes = 0
-    for _ in range(34):  # two refreshes bring the device's counters over
-        engine._publish_metrics()
-    c = engine.state_counters()
-    assert c == {"rows": 2, "rows_live": 0, "claims": 2, "row_missing": 0}
-    snap = engine.profile_snapshot()
-    assert snap["kda.rows_resumed"]["calls"] == 2
-    assert snap["prefill_kv.blocks_visited.latent"]["calls"] >= 4
-    m = engine.moe_counters()
-    # two expert layers, 2 x 21 prompt tokens, top-4 of 16 in 2 of 4 groups
-    assert m["layers"] == 2 and m["prefill.assignments"] == 2 * 2 * 21 * 4
-    assert 0 < m["prefill.assignments_held"] < m["prefill.assignments"]
-    assert m["prefill.assignments_held"] == sum(
-        m[f"prefill.expert.{i}"] for i in range(4))
-
-
-@pytest.mark.parametrize("pipeline", [False, True], ids=["plain", "pipelined"])
-async def test_streams_share_the_engine(monkeypatch, pipeline):
-    """Three prompts on two slots, one of them chunked behind running
-    bursts: every stream is what it gets alone, pipelined or not, rows
-    are claimed and freed as slots turn over, none goes missing."""
-    monkeypatch.setenv("DYNAMO_PALLAS", "1")
-    prompts = [[3, 9, 27], [8, 64, 32, 5],
-               [int(t) for t in np.arange(5, 5 + 37) * 7 % 96]]
-    engine = _engine(pipeline_decode=pipeline, async_admissions=True)
-    want = [_greedy_reference(engine.params, p, n)
-            for p, n in zip(prompts, (12, 9, 6))]
-    outs = await asyncio.gather(*(
-        _greedy(engine, p, n) for p, n in zip(prompts, (12, 9, 6))))
-    assert outs == want
-    assert engine.allocator.active_pages == 0
-    await engine.close()
-    assert int(engine.k_pages.rows.stats[0, llama.STAT_MISSING]) == 0
-    assert int(engine.k_pages.rows.stats[0, llama.STAT_CLAIMS]) == 3
+        F.close(alike + sum(s - alike for s in shares), whole, tol=1e-4)
 
 
 def test_the_memory_guard_charges_both_kinds():

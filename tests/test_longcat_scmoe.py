@@ -7,21 +7,19 @@ _layer``, a pool of latent pages a sub-layer, ``models/moe.py``) against
 the benchmark's plain reference (``perfbench/references/scmoe_latent.py``),
 which shares no code with it."""
 
-import importlib.util
-import os
-
 import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
+from family_contract import (
+    PROMPT, Family, _cache, _prefill, _programs, _tables, _whole, case, cases,
+    run, two_slots,
+)
 
-from dynamo_tpu.engine.config import EngineConfig, ModelSpec
-from dynamo_tpu.engine.core import InferenceEngine
+
+from dynamo_tpu.engine.config import ModelSpec
 from dynamo_tpu.models import mla, moe
 from dynamo_tpu.models.family import MlaFamily
-from dynamo_tpu.runtime.context import Context
-
-REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
 # the reference reads the published keys; the program reads SPEC
 CONFIG = {
@@ -47,157 +45,82 @@ PAGE, PAGES_PER_SEQ, T = 4, 16, 40
 SEED = 11
 
 
-@pytest.fixture(scope="module")
-def ref():
-    spec = importlib.util.spec_from_file_location(
-        "scmoe_latent",
-        os.path.join(REPO, "perfbench/references/scmoe_latent.py"))
-    mod = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(mod)
-    return mod
+def _prefilled(k, counts, chunks):
+    """Every counted row's picks are identity or FFN experts, k a row."""
+    total = sum(n for _, n in chunks)
+    c = np.asarray(counts)[:, 0]
+    assert (c[:, 2] + c[:, 3] == K * total).all()
+    assert (c[:, 4] == K * total).all() and (c[:, -1] == len(chunks)).all()
 
 
-@pytest.fixture(scope="module")
-def model(ref):
-    params = mla.init_params(SPEC, jax.random.PRNGKey(SEED))
-    toks = np.asarray(
-        jax.random.randint(jax.random.PRNGKey(1), (3, T), 0, 96))
-    want = np.asarray(ref.forward(
-        CONFIG, SEED, toks, np.tile(np.arange(T), (3, 1))))
-    return params, toks, want
+def _packed(k, counts):
+    c = np.asarray(counts)[:, 0]
+    assert (c[:, 2] + c[:, 3] == K * 25).all()
 
 
-def _cache():
-    return (mla.init_cache(SPEC, 1 + 3 * PAGES_PER_SEQ, PAGE),
-            mla.init_counts(SPEC))
+def _burst(k, counts, steps):
+    """Every layer counted each step of the burst once."""
+    assert (np.asarray(counts)[:, 1, -1] == steps).all()
 
 
-def _table(row):
-    return jnp.arange(PAGES_PER_SEQ, dtype=jnp.int32) + 1 + row * PAGES_PER_SEQ
+def _two_slots(grew, steps):
+    """Both sub-layers' pools under one table; the empty slot is counted
+    nowhere."""
+    grew = grew[:, 1]
+    assert (grew[:, -1] == steps).all()
+    assert (grew[:, 4] == steps * 2 * K).all()  # two counted rows a step
+    assert (grew[:, 2] + grew[:, 3] == steps * 2 * K).all()  # zero + ffn picks
+    assert (grew[:, :2].sum(axis=1) <= grew[:, 3]).all()  # held among ffn
 
 
-def _tables():
-    return jnp.stack(
-        [_table(0), _table(1), jnp.zeros(PAGES_PER_SEQ, jnp.int32)])
+def _served(engine, snap, served, outs):
+    """Chunked prefill over both pools behind ONE prompt of 21 tokens;
+    ``moe_counters()`` splits the picks."""
+    assert isinstance(engine.fam, MlaFamily)
+    assert type(engine.k_pages) is tuple and len(engine.k_pages) == 2
+    assert engine.chunked_prefill["chunks"] == 2
+    assert engine.prefill_kv["dispatches.latent"] == 2
+    c = engine.moe_counters()
+    assert c["layers"] == 2
+    assert c["prefill.steps"] == 2 and c["prefill.assignments"] == 2 * 21 * K
+    for phase in ("prefill", "decode"):
+        assert (c[f"{phase}.zero_picks"] + c[f"{phase}.ffn_picks"]
+                == c[f"{phase}.assignments"])
+        assert c[f"{phase}.assignments_held"] <= c[f"{phase}.ffn_picks"]
+        assert sum(c[f"{phase}.expert.{i}"] for i in range(2)) == c[
+            f"{phase}.assignments_held"]
+    assert c["decode.zero_picks"] > 0 and c["decode.ffn_picks"] > 0
+    assert snap["moe.decode.zero_picks"]["calls"] == c["decode.zero_picks"]
 
 
-def _close(got, want, tol=3e-4):
-    np.testing.assert_allclose(np.asarray(got), want, rtol=tol, atol=tol)
+# the family's row of the contract (tests/family_contract.py): a latent
+# family of double layers (a pool a sub-layer under one table; the pair is
+# the pools and the experts' counters); its pack's third member is padding
+F = FAMILY = Family(
+    spec=SPEC, config=CONFIG, reference="scmoe_latent",
+    seed=SEED, prompts=(),
+    chunked={"single": [(0, 13)],
+             "three-chunks": [(0, 16), (16, 16), (32, 7)]},
+    packs=([(0, 0, 16), (1, 0, 9), (0, 0, 0)],), served=((PROMPT, 6),),
+    also={"chunked": _prefilled, "pack": _packed, "two-slots": _two_slots,
+          "bursts": _burst, "serves": _served})
 
 
-def _prefill(params, toks, row, start, n, cache, counts, bucket=16):
-    padded = np.zeros((bucket,), np.int32)
-    padded[:n] = toks[row, start: start + n]
-    return mla.prefill_forward(
-        SPEC, params, jnp.asarray(padded), _table(row),
-        jnp.asarray(start, jnp.int32), cache, jnp.asarray(n, jnp.int32),
-        counts=counts,
-    )
+@pytest.mark.parametrize("case,kw", cases(
+    F, case("two-slots-xla", two_slots, path="0"),
+    case("two-slots-kernel", two_slots, path="1")))
+def test_the_family_contract(case, kw, monkeypatch):
+    run(case, F, monkeypatch, **kw)
 
 
 def test_the_cache_is_a_pool_a_sub_layer():
-    cache, counts = _cache()
+    cache, counts = _cache(F)
     assert type(cache) is tuple and len(cache) == 2
     assert all(p.shape[:3] == (2, 1 + 3 * PAGES_PER_SEQ, PAGE) for p in cache)
     assert counts.shape == (2, 2, 2 + 5)  # sizes, zero, ffn, total, touched, steps
     assert mla.sub_pools(cache) is cache
     one = mla.init_cache(ModelSpec.tiny_deepseek(), 4, PAGE)
     assert mla.sub_pools(one) == (one,)
-
-
-@pytest.mark.parametrize("chunks", [
-    pytest.param([13], id="single"),
-    pytest.param([16, 16, 7], id="three-chunks"),  # start_pos > 0 twice
-])
-def test_prefill_against_the_reference(model, chunks):
-    params, toks, want = model
-    cache, counts = _cache()
-    start = 0
-    for n in chunks:
-        logits, cache, counts = _prefill(
-            params, toks, 0, start, n, cache, counts)
-        start += n
-        _close(logits, want[0, start - 1])
-    # every counted row's picks are identity or FFN experts, k a row
-    c = np.asarray(counts)[:, 0]
-    assert (c[:, 2] + c[:, 3] == K * sum(chunks)).all()
-    assert (c[:, 4] == K * sum(chunks)).all() and (c[:, -1] == len(chunks)).all()
-
-
-def test_packed_prefill_against_the_reference(model):
-    params, toks, want = model
-    cache, counts = _cache()
-    lens = np.asarray([16, 9, 0], np.int32)  # the third member is padding
-    padded = np.zeros((3, 16), np.int32)
-    for r, n in enumerate(lens):
-        padded[r, :n] = toks[r, :n]
-    logits, cache, counts = mla.prefill_forward_batch(
-        SPEC, params, jnp.asarray(padded), _tables(),
-        jnp.zeros((3,), jnp.int32), cache, jnp.asarray(lens), counts=counts,
-    )
-    _close(logits[0], want[0, 15])
-    _close(logits[1], want[1, 8])
-    assert np.isfinite(np.asarray(logits)).all()
-    c = np.asarray(counts)[:, 0]
-    assert (c[:, 2] + c[:, 3] == K * 25).all()
-
-
-@pytest.mark.parametrize("pallas", ["0", "1"], ids=["xla-walk", "kernel"])
-def test_decode_through_the_paged_latent_cache(model, monkeypatch, pallas):
-    """Teacher-forced steps after prefills of 14 and 1 tokens, both
-    sub-layers' pools under one table: across page boundaries, a slot that
-    starts from ONE token in the pool, an empty slot that stays inactive
-    and is counted nowhere."""
-    monkeypatch.setenv("DYNAMO_PALLAS", pallas)
-    params, toks, want = model
-    cache, counts = _cache()
-    for r, n in enumerate([14, 1]):
-        _, cache, counts = _prefill(params, toks, r, 0, n, cache, counts)
-    active = jnp.asarray([True, True, False])
-    before = np.asarray(counts)
-    for j in range(12):
-        fed = jnp.asarray([toks[0, 14 + j], toks[1, 1 + j], 0], jnp.int32)
-        seq = jnp.asarray([15 + j, 2 + j, 1], jnp.int32)
-        logits, cache, counts = mla.decode_forward(
-            SPEC, params, fed, _tables(), seq, cache, active, counts=counts)
-        _close(logits[0], want[0, 14 + j])
-        _close(logits[1], want[1, 1 + j])
-    grew = (np.asarray(counts) - before)[:, 1]
-    assert (grew[:, -1] == 12).all()
-    assert (grew[:, 4] == 12 * 2 * K).all()  # two counted rows a step
-    assert (grew[:, 2] + grew[:, 3] == 12 * 2 * K).all()  # zero + ffn picks
-    assert (grew[:, :2].sum(axis=1) <= grew[:, 3]).all()  # held among ffn
-
-
-@pytest.mark.parametrize("n_steps", [1, 8])
-def test_greedy_bursts_choose_the_references_tokens(model, ref, n_steps):
-    """``decode_steps`` with the sampler on the device, bursts of 1 and 8,
-    one slot empty: each token is the reference's argmax at its position
-    of the sequence the program decoded."""
-    params, toks, _ = model
-    cache, counts = _cache()
-    lens = [14, 5]
-    for r, n in enumerate(lens):
-        _, cache, counts = _prefill(params, toks, r, 0, n, cache, counts)
-    B = 3
-    zB = jnp.zeros((B,), jnp.int32)
-    out, cache, counts = mla.decode_steps(
-        SPEC, params, jnp.asarray([toks[0, 14], toks[1, 5], 0], jnp.int32),
-        _tables(), jnp.asarray([15, 6, 1], jnp.int32), cache,
-        jnp.asarray([True, True, False]), jnp.zeros((B,), jnp.float32), zB,
-        jnp.ones((B,), jnp.float32), jnp.zeros((B,), jnp.uint32), zB,
-        n_steps=n_steps, counts=counts,
-    )
-    out = np.asarray(out)
-    seqs = np.zeros((2, 32), np.int32)
-    at = np.zeros((2, n_steps), np.int32)
-    for r, n in enumerate(lens):
-        seqs[r, : n + 1] = toks[r, : n + 1]
-        seqs[r, n + 1: n + 1 + n_steps] = out[r]
-        at[r] = n + np.arange(n_steps)
-    want = np.asarray(ref.forward(CONFIG, SEED, seqs, at))
-    np.testing.assert_array_equal(out[:2], want.argmax(axis=-1))
-    assert (np.asarray(counts)[:, 1, -1] == n_steps).all()
 
 
 # ------------------------------------------------------------------ router
@@ -256,7 +179,7 @@ def test_identity_experts_add_the_tokens_own_input():
             h = np.asarray(jax.nn.silu(xs[t] @ lp["w_gate"][e])) * np.asarray(
                 xs[t] @ lp["w_up"][e])
             want[t] += w * np.asarray(h @ lp["w_down"][e])
-    _close(y, want, tol=1e-5)
+    F.close(y, want, tol=1e-5)
     np.testing.assert_allclose(np.asarray(y)[1], topv[1].sum() * xs[1],
                                rtol=1e-6)  # all identity: a scaled copy
     row = np.asarray(row)
@@ -309,9 +232,9 @@ def test_the_regions_tell_the_shortcut_from_the_dense_ffns(model):
     identity term and the counters beside the dense FFNs' ``mlp`` and the
     attentions' own."""
     params, _, _ = model
-    cache, counts = _cache()
+    cache, counts = _cache(F)
     text = mla.decode_forward.lower(
-        SPEC, params, jnp.zeros((3,), jnp.int32), _tables(),
+        SPEC, params, jnp.zeros((3,), jnp.int32), _tables(F, [0, 1, None]),
         jnp.ones((3,), jnp.int32), cache, jnp.zeros((3,), bool),
         counts=counts,
     ).as_text(debug_info=True)
@@ -357,23 +280,23 @@ def test_the_shares_add_up(ref):
     ffn_parts = [share(first, False) - base[0] for first in (0, 2, 4, 6)]
     identity_once = share(0, True) - share(0, False)
     assert np.abs(identity_once).max() > 1e-3
-    _close(base[0] + sum(ffn_parts) + identity_once, whole, tol=1e-4)
+    F.close(base[0] + sum(ffn_parts) + identity_once, whole, tol=1e-4)
     # counted with every share instead, the identity term is four times it
     assert np.abs(
         base[0] + sum(share(f, True) - base[0] for f in (0, 2, 4, 6)) - whole
     ).max() > 1e-3
     spec = _spec(num_layers=1)
     params = mla.init_params(spec, jax.random.PRNGKey(SEED))
-    got = mla.reference_forward(spec, params, jnp.asarray(toks[0]))
+    got = _whole(spec, params, jnp.asarray(toks[0]))
     want = ref.forward(cfg, SEED, toks, np.arange(10)[None].repeat(2, 0))
-    _close(got, np.asarray(want)[0])
+    F.close(got, np.asarray(want)[0])
 
 
 def test_a_lower_precision_fails_a_tolerance(model, ref):
     """The check's control: the same pass with fp8 weights is outside a
     tolerance that the program's own difference is well inside."""
     params, toks, want = model
-    got = np.stack([np.asarray(mla.reference_forward(
+    got = np.stack([np.asarray(_whole(
         SPEC, params, jnp.asarray(t))) for t in toks])
     control = np.asarray(ref.forward(
         CONFIG, SEED, toks, np.tile(np.arange(T), (3, 1)), quant="fp8"))
@@ -396,64 +319,6 @@ def test_the_scalars_are_in_the_comparison(model, ref):
 
 
 # ------------------------------------------------------------------ engine
-
-
-async def _greedy(engine, prompt, n):
-    out = []
-    async for item in engine.generate(
-        {"token_ids": list(prompt), "sampling": {"temperature": 0.0},
-         "stop_conditions": {"max_tokens": n, "ignore_eos": True}},
-        Context(),
-    ):
-        assert item.get("finish_reason") != "error", item
-        out.extend(item.get("token_ids") or [])
-    return out
-
-
-_jit_reference = jax.jit(mla.reference_forward, static_argnums=0)
-
-
-async def test_serves_through_the_engine_and_counts(monkeypatch):
-    """The toy model through the REAL engine (scheduler, chunked prefill
-    over both pools, the kernel interpreted in bursts): the greedy stream
-    is the reference's own, and ``moe_counters()`` splits the picks."""
-    monkeypatch.setenv("DYNAMO_PALLAS", "1")
-    engine = InferenceEngine(SPEC, EngineConfig(
-        page_size=PAGE, num_pages=64, max_pages_per_seq=PAGES_PER_SEQ,
-        max_decode_slots=2, prefill_buckets=(16,), max_prefill_chunk_tokens=16,
-        decode_steps_per_dispatch=4, seed=SEED,
-    ))
-    assert isinstance(engine.fam, MlaFamily)
-    assert type(engine.k_pages) is tuple and len(engine.k_pages) == 2
-    prompt = [int(t) for t in np.arange(7, 7 + 21) % 96]  # two chunks
-    out = await _greedy(engine, prompt, 6)
-    assert len(out) == 6
-    seq = list(prompt)
-    for _ in range(6):
-        padded = np.zeros((32,), np.int32)
-        padded[: len(seq)] = seq
-        lg = _jit_reference(SPEC, engine.params, jnp.asarray(padded))
-        seq.append(int(np.argmax(np.asarray(lg[len(seq) - 1]))))
-    assert out == seq[len(prompt):]
-    assert engine.chunked_prefill["chunks"] == 2
-    assert engine.prefill_kv["dispatches.latent"] == 2
-    assert engine.allocator.active_pages == 0
-    await engine.close()
-    engine._metrics_publishes = 0
-    for _ in range(34):  # two refreshes bring the device's counters over
-        engine._publish_metrics()
-    c = engine.moe_counters()
-    assert c["layers"] == 2
-    assert c["prefill.steps"] == 2 and c["prefill.assignments"] == 2 * 21 * K
-    for phase in ("prefill", "decode"):
-        assert (c[f"{phase}.zero_picks"] + c[f"{phase}.ffn_picks"]
-                == c[f"{phase}.assignments"])
-        assert c[f"{phase}.assignments_held"] <= c[f"{phase}.ffn_picks"]
-        assert sum(c[f"{phase}.expert.{i}"] for i in range(2)) == c[
-            f"{phase}.assignments_held"]
-    assert c["decode.zero_picks"] > 0 and c["decode.ffn_picks"] > 0
-    snap = engine.profile_snapshot()
-    assert snap["moe.decode.zero_picks"]["calls"] == c["decode.zero_picks"]
 
 
 async def test_pages_move_with_both_pools():
@@ -504,8 +369,8 @@ def test_a_checkpoint_round_trips_through_the_published_names(tmp_path):
         96, 32, 3, 6.0)
     assert not spec2.rope_interleave  # exported layout is half-split
     tokens = jnp.asarray(np.arange(9) % spec.vocab_size, jnp.int32)
-    _close(mla.reference_forward(spec2, params2, tokens),
-           np.asarray(mla.reference_forward(spec, params, tokens)), tol=1e-4)
+    F.close(_whole(spec2, params2, tokens),
+           np.asarray(_whole(spec, params, tokens)), tol=1e-4)
     # the published config as the catalog has it
     published = {
         "attention_bias": False, "vocab_size": 131072, "hidden_size": 6144,
@@ -533,19 +398,20 @@ def test_the_verify_pass_writes_both_pools(model, ref):
     pools from mid-page): the targets at all W positions are the
     reference's argmax, and a decode step behind it reads what it wrote."""
     params, toks, want = model
-    cache, counts = _cache()
-    _, cache, counts = _prefill(params, toks, 0, 0, 10, cache, counts)
+    cache, counts = _cache(F)
+    _, cache, counts = _prefill(
+        F, _programs(F)[0], params, toks, 0, 0, 10, cache, counts)
     fed = np.zeros((2, 4), np.int32)
     fed[0] = toks[0, 10:14]
     targets, cache, counts = mla.verify_forward(
-        SPEC, params, jnp.asarray(fed), _tables()[:2],
+        SPEC, params, jnp.asarray(fed), _tables(F, [0, 1]),
         jnp.asarray([10, 0], jnp.int32), cache,
         jnp.asarray([4, 0], jnp.int32), counts=counts,
     )
     np.testing.assert_array_equal(
         np.asarray(targets)[0], want[0, 10:14].argmax(axis=-1))
     logits, cache, counts = mla.decode_forward(
-        SPEC, params, jnp.asarray([toks[0, 14], 0, 0], jnp.int32), _tables(),
-        jnp.asarray([15, 1, 1], jnp.int32), cache,
+        SPEC, params, jnp.asarray([toks[0, 14], 0, 0], jnp.int32),
+        _tables(F, [0, 1, None]), jnp.asarray([15, 1, 1], jnp.int32), cache,
         jnp.asarray([True, False, False]), counts=counts)
-    _close(logits[0], want[0, 14])
+    F.close(logits[0], want[0, 14])

@@ -5,17 +5,15 @@ and held experts behind a router over all of them. Against the benchmark's
 plain reference (``perfbench/references/hybrid_swa_moe.py``), which shares
 no code with the program."""
 
-import asyncio
-import importlib.util
-import os
-
 import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
+from family_contract import (
+    Family, _cache, _model, _table, behind_bursts, case, cases, run, tokens,
+)
 
-from dynamo_tpu.engine.config import EngineConfig, LayerKind, ModelSpec
-from dynamo_tpu.engine.core import InferenceEngine
+from dynamo_tpu.engine.config import LayerKind, ModelSpec
 from dynamo_tpu.models import llama
 from dynamo_tpu.ops.attention import (
     window_table,
@@ -23,9 +21,6 @@ from dynamo_tpu.ops.attention import (
     paged_decode_attention,
 )
 from dynamo_tpu.ops.pallas.fused_decode import fused_decode_attention
-from dynamo_tpu.runtime.context import Context
-
-REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
 # the reference reads the published keys; the program reads SPEC
 CONFIG = {
@@ -49,210 +44,86 @@ SPEC = ModelSpec(
     layer_kinds=(LayerKind(1, 1e7), LayerKind(2, 1e4, window=8, sinks=True)),
     layer_pattern=(0, 1, 1, 0),
 )
-PAGE, PAGES_PER_SEQ = 4, 12
+PAGES_PER_SEQ = 12
 SEED = 5
 
 
-@pytest.fixture(scope="module")
-def ref():
-    spec = importlib.util.spec_from_file_location(
-        "hybrid_swa_moe",
-        os.path.join(REPO, "perfbench/references/hybrid_swa_moe.py"),
-    )
-    mod = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(mod)
-    return mod
-
-
-@pytest.fixture(scope="module")
-def model(ref):
-    params = llama.init_params(SPEC, jax.random.PRNGKey(SEED))
-    toks = np.asarray(
-        jax.random.randint(jax.random.PRNGKey(1), (2, 40), 0, 96))
-    want = np.asarray(ref.forward(
-        CONFIG, SEED, toks, np.tile(np.arange(40), (2, 1))))
-    return params, toks, want
-
-
-def _cache():
-    return llama.init_cache(SPEC, 1 + 3 * PAGES_PER_SEQ, PAGE)
-
-
-def _table(row):
-    return jnp.arange(PAGES_PER_SEQ, dtype=jnp.int32) + 1 + row * PAGES_PER_SEQ
-
-
-def _close(got, want):
-    np.testing.assert_allclose(np.asarray(got), want, rtol=2e-4, atol=2e-4)
-
-
-def test_prefill_then_decode_through_the_paged_cache(model):
-    params, toks, want = model
-    k, v = _cache()
-    T = 30  # longer than the window of 8
-    pad = jnp.zeros((32,), jnp.int32).at[:T].set(toks[0, :T])
-    logits, k, v, zero = llama.prefill_forward(
-        SPEC, params, pad, _table(0), jnp.asarray(0), k, v, jnp.asarray(T))
-    _close(logits, want[0, T - 1])
-    assert int(zero) == 0
-    tables = jnp.zeros((2, PAGES_PER_SEQ), jnp.int32).at[0].set(_table(0))
-    for j in range(6):  # crosses a page
-        logits, k, v = llama.decode_forward(
-            SPEC, params, jnp.asarray([toks[0, T + j], 0]), tables,
-            jnp.asarray([T + j + 1, 1]), k, v, jnp.asarray([True, False]))
-        _close(logits[0], want[0, T + j])
-    # the counters: 3 expert layers, a prefill program and six steps
+def _counted(k, v, n, steps):
+    """The counters behind a prompt of ``n`` tokens and ``steps`` decode
+    steps of one live slot: 3 expert layers, top-2."""
     counts = np.asarray(k.counts)
     assert counts[0].sum() == 0  # layer 0 is dense
     assert counts[1:, llama.COUNT_PREFILL, -1].tolist() == [1, 1, 1]
-    assert counts[1:, llama.COUNT_DECODE, -1].tolist() == [6, 6, 6]
-    assert counts[1:, llama.COUNT_PREFILL, -3].tolist() == [T * 2] * 3
-    assert counts[1:, llama.COUNT_DECODE, -3].tolist() == [6 * 2] * 3
+    assert counts[1:, llama.COUNT_DECODE, -1].tolist() == [steps] * 3
+    assert counts[1:, llama.COUNT_PREFILL, -3].tolist() == [n * 2] * 3
+    assert counts[1:, llama.COUNT_DECODE, -3].tolist() == [steps * 2] * 3
     # every expert is held: every assignment reached one
     assert (counts[1:, :, :-3].sum(-1) == counts[1:, :, -3]).all()
     # a decode step of one token touches its two experts, no more
-    assert counts[1:, llama.COUNT_DECODE, -2].tolist() == [6 * 2] * 3
+    assert counts[1:, llama.COUNT_DECODE, -2].tolist() == [steps * 2] * 3
 
 
-def test_a_prompt_longer_than_a_chunk_and_than_the_window(model):
-    params, toks, want = model
-    k, v = _cache()
-    _, k, v, _ = llama.prefill_forward(
-        SPEC, params, jnp.asarray(toks[0, :16]), _table(0), jnp.asarray(0),
-        k, v, jnp.asarray(16))
-    pad = jnp.zeros((16,), jnp.int32).at[:14].set(toks[0, 16:30])
-    logits, k, v, _ = llama.prefill_forward(
-        SPEC, params, pad, _table(0), jnp.asarray(16), k, v, jnp.asarray(14))
-    _close(logits, want[0, 29])
-
-
-def test_packed_prefill(model):
-    params, toks, want = model
-    k, v = _cache()
-    lens = [30, 19]
-    batch = jnp.zeros((2, 32), jnp.int32)
-    for i, n in enumerate(lens):
-        batch = batch.at[i, :n].set(toks[i, :n])
-    logits, k, v, _ = llama.prefill_forward_batch(
-        SPEC, params, batch, jnp.stack([_table(0), _table(1)]),
-        jnp.zeros((2,), jnp.int32), k, v, jnp.asarray(lens))
-    for i, n in enumerate(lens):
-        _close(logits[i], want[i, n - 1])
-
-
-def test_verify_scores_every_position(model):
-    params, toks, want = model
-    k, v = _cache()
-    T, W = 21, 4  # the verify window starts mid-page
-    pad = jnp.zeros((32,), jnp.int32).at[:T].set(toks[0, :T])
-    _, k, v, _ = llama.prefill_forward(
-        SPEC, params, pad, _table(0), jnp.asarray(0), k, v, jnp.asarray(T))
-    targets, k, v, _ = llama.verify_forward(
-        SPEC, params, jnp.asarray(toks[:1, T: T + W]), _table(0)[None],
-        jnp.asarray([T]), k, v, jnp.asarray([W]))
-    assert np.asarray(targets)[0].tolist() == want[
-        0, T: T + W].argmax(-1).tolist()
-
-
-async def test_the_engine_serves_it_chunked_and_counts(model):
-    _, toks, want = model
-    cfg = EngineConfig(
-        page_size=PAGE, num_pages=64, max_pages_per_seq=PAGES_PER_SEQ,
-        max_decode_slots=2, prefill_buckets=(8, 16), prefill_pack_size=2,
-        max_prefill_chunk_tokens=16, decode_steps_per_dispatch=4,
-        seed=SEED, guided_mode="off",
-    )
-    engine = InferenceEngine(SPEC, cfg)
-    prompt = [int(t) for t in toks[0, :30]]  # two chunks, four windows
-    req = {"token_ids": prompt, "stop_conditions": {"max_tokens": 1},
-           "sampling_options": {"temperature": 0.0}}
-    out = []
-    async for item in engine.generate(req, Context()):
-        out.extend(item.get("token_ids", []))
-    assert out == [int(want[0, 29].argmax())]
-    for _ in range(40):  # the counters come to the host on a duty cycle
-        engine._publish_metrics()
+def _served(engine, snap, served, outs):
+    """A prompt of two chunks and four windows: its first token is the
+    plain reference's."""
+    assert outs == [[int(_model(F)[2][0, 29].argmax())]]
     counters = engine.moe_counters()
     assert counters["layers"] == 3
     assert counters["prefill.assignments"] >= 3 * 30 * 2
     assert sum(counters[f"prefill.expert.{i}"] for i in range(8)) == (
         counters["prefill.assignments"])
-    snap = engine.profile_snapshot()
     assert snap["moe.prefill.steps"]["calls"] == counters["prefill.steps"]
     assert 0 < counters["prefill.experts_touched"] <= (
         3 * 8 * counters["prefill.steps"])
-    await engine.close()
 
 
-async def _greedy(engine, prompt, n, out=None):
-    out = [] if out is None else out
-    req = {"token_ids": [int(t) for t in prompt],
-           "stop_conditions": {"max_tokens": n, "ignore_eos": True},
-           "sampling": {"temperature": 0.0}}
-    async for item in engine.generate(req, Context()):
-        assert item.get("finish_reason") != "error", item
-        out.extend(item.get("token_ids", []))
-    return out
+# the family's row of the contract (tests/family_contract.py): a prompt
+# and a second chunk longer than the window of 8 (the decode steps cross a
+# page); a 30-token prompt in chunks of a window's length behind bursts
+F = FAMILY = Family(
+    spec=SPEC, config=CONFIG, reference="hybrid_swa_moe",
+    seed=SEED, tol=2e-4, pages_per_seq=PAGES_PER_SEQ, seqs=2,
+    prompts=((30, None),), chunked={"two-chunks": [(0, 16), (16, 14)]},
+    packs=([(0, 0, 30), (1, 0, 19)],), bursts_paths=(), engine_path=None,
+    served=((tokens(2, 40)[0, :30], 1),),
+    engine=dict(prefill_buckets=(8, 16), prefill_pack_size=2,
+                guided_mode="off"),
+    also={"prefill-decode": _counted, "serves": _served})
 
 
-async def test_a_chunked_prompt_behind_running_bursts(model):
-    """Chunked under load: two streams decode in pipelined bursts while a
-    30-token prompt prefills in chunks of 8, a window's length, through
-    the window pool and the full pool. Every chunk after the first is
-    launched behind the burst in flight (no flush lands it first); the
-    first token is the reference's and all six are those the prompt gets
-    alone and unchunked."""
-    _, toks, want = model
+@pytest.mark.parametrize("case,kw", cases(
+    F, case("engine-chunks-behind-bursts", behind_bursts,
+            chunk=8, n=30, busy=36)))
+def test_the_family_contract(case, kw, monkeypatch):
+    run(case, F, monkeypatch, **kw)
 
-    def build(**kw):
-        return InferenceEngine(SPEC, EngineConfig(
-            page_size=PAGE, num_pages=64, max_pages_per_seq=PAGES_PER_SEQ,
-            max_decode_slots=3, decode_steps_per_dispatch=4, seed=SEED,
-            guided_mode="off", **kw))
 
-    alone = build(prefill_buckets=(32,), max_prefill_chunk_tokens=32)
-    unchunked = await _greedy(alone, toks[0, :30], 6)
-    assert unchunked[0] == int(want[0, 29].argmax())
-    await alone.close()
-
-    engine = build(prefill_buckets=(8,), max_prefill_chunk_tokens=8,
-                   pipeline_decode=True)
-    chunks, run_chunk = [], engine._run_partial_chunk
-
-    def watched(waiting, sp, token_ids, start, end):
-        chunks.append((start, len(engine._pipeline)))
-        return run_chunk(waiting, sp, token_ids, start, end)
-
-    engine._run_partial_chunk = watched
-    a, b = [], []
-
-    async def later():
-        while min(len(a), len(b)) < 4:
-            await asyncio.sleep(0.002)
-        return await _greedy(engine, toks[0, :30], 6)
-
-    outs = await asyncio.gather(
-        _greedy(engine, toks[1, :5], 36, out=a),
-        _greedy(engine, toks[1, 7:11], 36, out=b), later())
-    assert outs[2] == unchunked and [len(o) for o in outs[:2]] == [36, 36]
-    assert chunks == [(0, 1), (8, 1), (16, 1), (24, 1)]
-    assert engine.chunked_prefill == {"chunks": 4, "chunks_behind_burst": 4}
-    assert engine.allocator.active_pages == 0
-    await engine.close()
+def test_verify_scores_every_position(model):
+    params, toks, want = model
+    k, v = _cache(F)
+    T, W = 21, 4  # the verify window starts mid-page
+    pad = jnp.zeros((32,), jnp.int32).at[:T].set(toks[0, :T])
+    _, k, v, _ = llama.prefill_forward(
+        SPEC, params, pad, _table(F, 0), jnp.asarray(0), k, v, jnp.asarray(T))
+    targets, k, v, _ = llama.verify_forward(
+        SPEC, params, jnp.asarray(toks[:1, T: T + W]), _table(F, 0)[None],
+        jnp.asarray([T]), k, v, jnp.asarray([W]))
+    assert np.asarray(targets)[0].tolist() == want[
+        0, T: T + W].argmax(-1).tolist()
 
 
 def test_pages_move_by_kind(model):
     """extract / insert carry one block a kind and leave counters be."""
     params, toks, _ = model
-    k, v = _cache()
+    k, v = _cache(F)
     pad = jnp.zeros((32,), jnp.int32).at[:30].set(toks[0, :30])
     _, k, v, _ = llama.prefill_forward(
-        SPEC, params, pad, _table(0), jnp.asarray(0), k, v, jnp.asarray(30))
+        SPEC, params, pad, _table(F, 0), jnp.asarray(0), k, v, jnp.asarray(30))
     ids = jnp.asarray([1, 2, 3])
     kb, vb = llama.extract_kv_pages(k, v, ids)
     assert [b.shape for b in kb] == [(2, 3, 1, 4, 12), (2, 3, 2, 4, 12)]
     assert [b.shape for b in vb] == [(2, 3, 1, 4, 8), (2, 3, 2, 4, 8)]
-    k2, v2 = _cache()
+    k2, v2 = _cache(F)
     k2, v2 = llama.insert_kv_pages(k2, v2, ids, kb, vb)
     for a, b in zip(k.pools + v.pools, k2.pools + v2.pools):
         np.testing.assert_array_equal(np.asarray(a[:, 1:4]),

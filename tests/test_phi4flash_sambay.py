@@ -9,22 +9,21 @@ the chunk form, the counters, the published names.
 """
 
 import dataclasses
-import importlib.util
-import os
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
+from family_contract import (
+    CHUNKS, Family, _cache, _model, _prefill, _programs, _reference, _step,
+    _table, _whole, cases, run, tokens,
+)
+
 
 from dynamo_tpu.engine.config import EngineConfig, ModelSpec
-from dynamo_tpu.engine.core import InferenceEngine
 from dynamo_tpu.models import llama, loader
 from dynamo_tpu.models.family import get_family
 from dynamo_tpu.ops import attention as attn_ops
-from dynamo_tpu.runtime.context import Context
-
-REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
 # the reference reads the published keys; the program reads the spec the
 # loader makes of them. A toy cut of a 32-layer stack: S W | S F | G C G C
@@ -40,66 +39,61 @@ CONFIG = {
 SPEC = dataclasses.replace(
     loader.spec_from_hf_config(CONFIG, name="toy-phi4flash"),
     vocab_draw_blocks=8)
-PAGE, PAGES_PER_SEQ, T, ROWS = 4, 16, 40, 3
+PAGE, T, ROWS = 4, 40, 3
 SEED = 11
 TOL = 2e-5  # float32 on both sides; logits of magnitude ~0.5
 
 
-@pytest.fixture(scope="module")
-def ref():
-    spec = importlib.util.spec_from_file_location(
-        "sambay", os.path.join(REPO, "perfbench/references/sambay.py"))
-    mod = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(mod)
-    return mod
+def _served(engine, snap, served, outs):
+    """Prompts of 30 and 7 tokens, over and under the window of 8: every
+    token is the PLAIN reference's choice. By hand: the prompts' tokens
+    through the lower layers, one row each through the upper; two cross
+    layers, to one window layer, read the live rows at every step."""
+    for (prompt, _), out in zip(served, outs):
+        row = np.concatenate([prompt, out]).astype(np.int32)[None]
+        at = np.arange(len(prompt) - 1, len(prompt) - 1 + len(out))[None]
+        want = np.asarray(_reference(F).forward(CONFIG, SEED, row, at))[0]
+        assert out == [int(t) for t in want.argmax(-1)]
+    assert snap["prefill.rows"]["calls"] == 37
+    assert snap["prefill.cross_rows"]["calls"] == 2
+    steps = snap["kv.shared_read_tokens"]["calls"]
+    assert steps and steps % 2 == 0
+    assert steps >= 2 * (sum(range(31, 39)) + sum(range(8, 10)))
+    assert 2 * snap["kv.window_layer_tokens"]["calls"] == steps
 
 
-@pytest.fixture(scope="module")
-def model(ref):
-    params = llama.init_params(SPEC, jax.random.PRNGKey(SEED))
-    toks = np.asarray(
-        jax.random.randint(jax.random.PRNGKey(1), (3, T), 0, 96))
-    want = np.asarray(ref.forward(
-        CONFIG, SEED, toks, np.tile(np.arange(T), (3, 1))))
-    return params, toks, want
+def _decodes(k, v, members):
+    """A decode step behind the chunks or the pack (``members``: table
+    row, start, tokens) reads what they wrote: pages, state and tails."""
+    params, toks, want = _model(F)
+    at = {s: (row, start + n)
+          for s, (row, start, n) in enumerate(members) if n}
+    lg, k, v = _step(F, _programs(F)[2], params, toks, at, k, v)
+    for s, (row, n) in at.items():
+        F.close(lg[s], want[row, n])
 
 
-def _cache(rows=ROWS, spec=SPEC):
-    return llama.init_cache(
-        spec, 1 + 3 * PAGES_PER_SEQ, PAGE, state_rows=rows)
+# the family's row of the contract (tests/family_contract.py): prompts
+# under and over the window of 8 on both paths; the absolute difference
+# under TOL; a pack of three; the engine packs two and holds four slots
+F = FAMILY = Family(
+    spec=SPEC, config=CONFIG, reference="sambay",
+    seed=SEED, tol=TOL, rtol=0.0, state_rows=ROWS,
+    prompts=((5, "0"), (5, "1"), (30, "0"), (30, "1")),
+    chunked={"three-chunks": CHUNKS["three-chunks"]},
+    packs=([(0, 0, 30), (0, 0, 0), (2, 0, 21)],),
+    bursts_paths=(None,), inactive_paths=("1",), engine_path=None,
+    engine=dict(num_pages=96, max_decode_slots=4, prefill_buckets=(16, 32),
+                max_prefill_chunk_tokens=32, prefill_pack_size=2),
+    served=((tokens()[0, :30], 9), (tokens()[2, :7], 3)),
+    also={"serves": _served, "packed": _decodes,
+          "chunked": lambda k, v, chunks: _decodes(
+              k, v, [(0, 0, sum(n for _, n in chunks))])})
 
 
-def _table(row):
-    return jnp.arange(PAGES_PER_SEQ, dtype=jnp.int32) + 1 + row * PAGES_PER_SEQ
-
-
-def _close(got, want, tol=TOL):
-    err = float(np.abs(np.asarray(got) - np.asarray(want)).max())
-    assert err < tol, err
-
-
-def _prefill(params, toks, row, start, n, k, v, bucket=16, spec=SPEC):
-    padded = np.zeros((bucket,), np.int32)
-    padded[:n] = toks[start:start + n]
-    logits, k, v, _ = llama.prefill_forward(
-        spec, params, jnp.asarray(padded), _table(row),
-        jnp.asarray(start, jnp.int32), k, v, jnp.asarray(n, jnp.int32))
-    return logits, k, v
-
-
-def _decode(params, fed, lens, rows, k, v, spec=SPEC, B=4):
-    """One decode step: ``rows`` (table rows) in the leading slots, the
-    last slot idle."""
-    tok = np.zeros((B,), np.int32)
-    seq = np.ones((B,), np.int32)
-    bts = np.zeros((B, PAGES_PER_SEQ), np.int32)
-    active = np.zeros((B,), bool)
-    for s, r in enumerate(rows):
-        tok[s], seq[s], active[s] = fed[s], lens[s], True
-        bts[s] = np.asarray(_table(r))
-    return llama.decode_forward(
-        spec, params, jnp.asarray(tok), jnp.asarray(bts), jnp.asarray(seq),
-        k, v, jnp.asarray(active))
+@pytest.mark.parametrize("case,kw", cases(F))
+def test_the_family_contract(case, kw, monkeypatch):
+    run(case, F, monkeypatch, **kw)
 
 
 def test_the_spec_and_the_cache_of_the_five_kinds():
@@ -113,7 +107,7 @@ def test_the_spec_and_the_cache_of_the_five_kinds():
     cross = kinds[5]
     assert cross.reads == (1, 0) and not cross.paged and cross.carried
     assert get_family(SPEC).recurrent
-    k, v = _cache()
+    k, v = _cache(F)
     # a pair of KV heads a row; the cross and the GMU kinds own nothing
     assert k.pools[0].shape == (1, 49, 2, PAGE, 16)
     assert k.pools[1].shape == v.pools[1].shape == (1, 49, 2, PAGE, 16)
@@ -133,75 +127,8 @@ def test_layers_that_write_no_cache_come_last():
 def test_the_whole_sequence_pass_equals_the_reference(model):
     params, toks, want = model
     for r in range(3):
-        _close(llama.reference_forward(SPEC, params, jnp.asarray(toks[r])),
+        F.close(_whole(SPEC, params, jnp.asarray(toks[r])),
                want[r], 4e-5)
-
-
-@pytest.mark.parametrize("pallas", ["0", "1"], ids=["xla", "kernel"])
-@pytest.mark.parametrize("n", [5, 30], ids=["under-window", "over-window"])
-def test_prefill_then_decode_through_pages_and_state(
-        model, monkeypatch, pallas, n):
-    monkeypatch.setenv("DYNAMO_PALLAS", pallas)
-    params, toks, want = model
-    k, v = _cache()
-    logits, k, v = _prefill(params, toks[0], 0, 0, n, k, v, bucket=32)
-    _close(logits, want[0, n - 1])
-    for j in range(6):
-        logits, k, v = _decode(
-            params, [toks[0, n + j]], [n + j + 1], [0], k, v)
-        _close(logits[0], want[0, n + j])
-
-
-def test_a_prompt_in_three_chunks_resumes_state_tail_and_pages(model):
-    params, toks, want = model
-    k, v = _cache()
-    for start, n in ((0, 16), (16, 16), (32, 5)):
-        logits, k, v = _prefill(params, toks[1], 1, start, n, k, v)
-        _close(logits, want[1, start + n - 1])
-    logits, k, v = _decode(params, [toks[1, 37]], [38], [1], k, v)
-    _close(logits[0], want[1, 37])
-
-
-def test_a_pack_of_two_with_an_empty_member_and_an_idle_slot(model):
-    params, toks, want = model
-    k, v = _cache()
-    padded = np.zeros((3, 32), np.int32)
-    lens = np.asarray([30, 0, 21], np.int32)
-    padded[0, :30], padded[2, :21] = toks[0, :30], toks[2, :21]
-    bts = np.stack([np.asarray(_table(0)), np.zeros(PAGES_PER_SEQ, np.int32),
-                    np.asarray(_table(2))])
-    logits, k, v, _ = llama.prefill_forward_batch(
-        SPEC, params, jnp.asarray(padded), jnp.asarray(bts),
-        jnp.zeros((3,), jnp.int32), k, v, jnp.asarray(lens))
-    _close(logits[0], want[0, 29])
-    _close(logits[2], want[2, 20])
-    logits, k, v = _decode(
-        params, [toks[0, 30], toks[2, 21]], [31, 22], [0, 2], k, v)
-    _close(logits[0], want[0, 30])
-    _close(logits[1], want[2, 21])
-
-
-def test_bursts_of_one_and_eight_agree_with_the_reference(model, ref):
-    params, toks, _ = model
-    k, v = _cache()
-    n, B = 20, 2
-    _, k, v = _prefill(params, toks[0], 0, 0, n, k, v, bucket=32)
-    bts = jnp.stack([_table(0), jnp.zeros((PAGES_PER_SEQ,), jnp.int32)])
-    z = jnp.zeros((B,), jnp.int32)
-    fed, seq, made = int(toks[0, n]), n + 1, []
-    for n_steps in (1, 8):
-        out, k, v = llama.decode_steps(
-            SPEC, params, jnp.asarray([fed, 0], jnp.int32), bts,
-            jnp.asarray([seq, 1], jnp.int32), k, v,
-            jnp.asarray([True, False]), jnp.zeros((B,), jnp.float32), z,
-            jnp.ones((B,), jnp.float32), jnp.zeros((B,), jnp.uint32), z,
-            n_steps=n_steps, n_logprobs=0)
-        made += [int(t) for t in np.asarray(out)[0]]
-        fed, seq = made[-1], seq + n_steps
-    row = np.concatenate([toks[0, :n + 1], made]).astype(np.int32)[None]
-    at = np.arange(n, n + 9)[None]
-    want = np.asarray(ref.forward(CONFIG, SEED, row, at))[0]
-    assert made == [int(t) for t in want.argmax(-1)]
 
 
 def _all_rows_prefill(params, toks, n, k, v):
@@ -221,11 +148,11 @@ def _all_rows_prefill(params, toks, n, k, v):
             layer_ids=spec.layer_ids[:spec.carried_from])
         return llama.prefill_forward_impl(
             low, dict(params, layers=params["layers"][:spec.carried_from]),
-            jnp.asarray(padded), _table(0), jnp.asarray(0), k, v,
+            jnp.asarray(padded), _table(F, 0), jnp.asarray(0), k, v,
             jnp.asarray(n))
 
     _, k, v, _ = program(params, k, v)
-    whole = llama.reference_forward(spec, params, jnp.asarray(toks[:n]))
+    whole = _whole(spec, params, jnp.asarray(toks[:n]))
     return whole[n - 1], k, v
 
 
@@ -233,10 +160,10 @@ def test_the_last_row_prefill_equals_the_all_rows_pass(model):
     params, toks, want = model
     n = 30
     logits, k, v = _prefill(
-        params, toks[0], 0, 0, n, *_cache(), bucket=32)
-    all_rows, k2, v2 = _all_rows_prefill(params, toks[0], n, *_cache())
-    _close(logits, all_rows)
-    _close(logits, want[0, n - 1])
+        F, _programs(F)[0], params, toks, 0, 0, n, *_cache(F), bucket=32)
+    all_rows, k2, v2 = _all_rows_prefill(params, toks[0], n, *_cache(F))
+    F.close(logits, all_rows)
+    F.close(logits, want[0, n - 1])
     # and leaves the same pages, state and tails (to the rounding of two
     # compilations): the upper half wrote none
     for a, b in zip(jax.tree.leaves((k.pools, v.pools)),
@@ -248,13 +175,14 @@ def test_the_last_row_prefill_equals_the_all_rows_pass(model):
 def test_cross_layers_read_the_full_layers_pages_and_write_none(model):
     params, toks, want = model
     n = 20
-    _, k, v = _prefill(params, toks[0], 0, 0, n, *_cache(), bucket=32)
+    pf, _, df, _ = _programs(F)
+    _, k, v = _prefill(F, pf, params, toks, 0, 0, n, *_cache(F), bucket=32)
     before = jax.tree.map(np.asarray, (k.pools[1], v.pools[1]))
     # a decode program of the upper half alone over a live slot leaves the
     # shared pool as it found it, the trash page apart
-    logits, k, v = _decode(params, [toks[0, n]], [n + 1], [0], k, v)
-    _close(logits[0], want[0, n])
-    page = int(np.asarray(_table(0))[n // PAGE])
+    logits, k, v = _step(F, df, params, toks, {0: (0, n)}, k, v)
+    F.close(logits[0], want[0, n])
+    page = int(np.asarray(_table(F, 0))[n // PAGE])
     for was, now in zip(before, (k.pools[1], v.pools[1])):
         now = np.asarray(now)
         # the step's one new row, written by the full layer itself
@@ -262,7 +190,7 @@ def test_cross_layers_read_the_full_layers_pages_and_write_none(model):
         assert set(changed) <= {0, page}
     # spoil the full layer's pages: the cross layers see it
     spoiled = k._replace(pools=(k.pools[0], k.pools[1] * 0.5, *k.pools[2:]))
-    bad, _, _ = _decode(params, [toks[0, n + 1]], [n + 2], [0], spoiled, v)
+    bad, _, _ = _step(F, df, params, toks, {0: (0, n + 1)}, spoiled, v)
     assert float(np.abs(np.asarray(bad[0]) - want[0, n + 1]).max()) > 1e-3
 
 
@@ -477,55 +405,6 @@ def test_a_lower_precision_than_stated_fails_a_tolerance(model, ref):
     low = np.asarray(ref.forward(
         CONFIG, SEED, toks[:1], np.arange(T)[None], quant="fp8"))
     assert float(np.abs(low - want[:1]).max()) > 100 * TOL
-
-
-def _engine(**kw):
-    cfg = dict(
-        page_size=PAGE, num_pages=96, max_pages_per_seq=PAGES_PER_SEQ,
-        max_decode_slots=4, prefill_buckets=(16, 32),
-        max_prefill_chunk_tokens=32, prefill_pack_size=2,
-        decode_steps_per_dispatch=4, seed=SEED)
-    cfg.update(kw)
-    return InferenceEngine(SPEC, EngineConfig(**cfg))
-
-
-async def _greedy(engine, prompt, n):
-    out = []
-    async for item in engine.generate(
-        {"token_ids": [int(t) for t in prompt],
-         "sampling": {"temperature": 0.0},
-         "stop": {"max_tokens": n, "ignore_eos": True}}, Context(),
-    ):
-        out.extend(item.get("token_ids") or [])
-    return out
-
-
-async def test_serves_through_the_engine_and_counts(model, ref):
-    _, toks, _ = model
-    engine = _engine()
-    assert engine.fam.recurrent and not engine.fam.supports_prefix_reuse
-    await engine.start()
-    try:
-        made = await _greedy(engine, toks[0, :30], 9)
-        more = await _greedy(engine, toks[2, :7], 3)
-    finally:
-        await engine.close()
-    for prompt, out in ((toks[0, :30], made), (toks[2, :7], more)):
-        row = np.concatenate([prompt, out]).astype(np.int32)[None]
-        at = np.arange(len(prompt) - 1, len(prompt) - 1 + len(out))[None]
-        want = np.asarray(ref.forward(CONFIG, SEED, row, at))[0]
-        assert out == [int(t) for t in want.argmax(-1)]
-    snap = engine.profile_snapshot()
-    # by hand: two prompts of 30 and 7 tokens through the lower layers, one
-    # row each through the upper
-    assert snap["prefill.rows"]["calls"] == 37
-    assert snap["prefill.cross_rows"]["calls"] == 2
-    # two cross layers read the live rows' tokens at every dispatched step
-    steps = snap["kv.shared_read_tokens"]["calls"]
-    assert steps and steps % 2 == 0
-    assert steps >= 2 * (sum(range(31, 39)) + sum(range(8, 10)))
-    # one window layer to the two cross layers
-    assert 2 * snap["kv.window_layer_tokens"]["calls"] == steps
 
 
 def test_the_memory_guard_charges_the_chunk_form():

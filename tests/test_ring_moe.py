@@ -6,6 +6,9 @@ the MoE model must serve through the full engine, and both must compose
 with tp sharding in the multi-chip jit path.
 """
 
+import dataclasses
+import functools
+
 import numpy as np
 import pytest
 
@@ -319,22 +322,35 @@ def _scatter_add_experts(spec, lp, x, topi, topv, first):
 _HELD, _FIRST, _EXPERTS = 4, 4, 16  # experts 4..7 of 16 are held
 
 
-def _combine_case(case, T, k):
-    """(spec, lp, x, topi, topv) of a case: a share of 4 of 16 experts."""
-    import dataclasses
-
+@functools.cache
+def _combine_layer(k):
+    """(spec, lp, route, the scatter-add, ``_held_experts``) of a share of
+    4 of 16 experts under top-``k``: the layer initialised once a module,
+    the three under one ``jax.jit`` each, so a shape ``[T, k]`` is traced
+    and compiled once a worker and not dispatched op by op a case."""
     spec = dataclasses.replace(
         MOE_SPEC, num_experts=_EXPERTS, num_experts_per_token=k,
         held_experts=(_HELD, _FIRST))
-    lp = moe.init_moe_layer(spec, jax.random.PRNGKey(3))
+    return (
+        spec, moe.init_moe_layer(spec, jax.random.PRNGKey(3)),
+        jax.jit(lambda lp, x: moe.route(spec, lp, x)),
+        jax.jit(lambda lp, x, topi, topv: _scatter_add_experts(
+            spec, lp, x, topi, topv, _FIRST)),
+        jax.jit(lambda lp, x, topi, topv: moe._held_experts(
+            spec, lp, x, topi, topv, _FIRST)))
+
+
+def _combine_case(case, T, k):
+    """(x, topi, topv) of a case."""
+    spec, lp, route, _, _ = _combine_layer(k)
     x = jax.random.normal(
         jax.random.PRNGKey(T * 8 + k), (T, spec.hidden_size), jnp.float32)
-    topi, topv = moe.route(spec, lp, x)
+    topi, topv = route(lp, x)
     if case == "one_held_expert":  # every assignment of every token
         topi = jnp.full((T, k), _FIRST + 1, jnp.int32)
     elif case == "none_held":
         topi = (topi % _FIRST).astype(jnp.int32)  # experts 0..3: elsewhere
-    return spec, lp, x, topi, topv
+    return x, topi, topv
 
 
 @pytest.mark.parametrize("k", [1, 4, 8])
@@ -348,8 +364,9 @@ def test_moe_combine_by_gather_is_the_scatter_add(case, T, k, monkeypatch):
     rows, with all assignments on one held expert, with none held
     (exactly 0), with NaN and inf in the rows no group reached, and under
     the "ep" mesh."""
-    spec, lp, x, topi, topv = _combine_case(case, T, k)
-    want = np.asarray(_scatter_add_experts(spec, lp, x, topi, topv, _FIRST))
+    spec, lp, _, scatter_add, held_experts = _combine_layer(k)
+    x, topi, topv = _combine_case(case, T, k)
+    want = np.asarray(scatter_add(lp, x, topi, topv))
     tol = dict(rtol=1e-6, atol=1e-6 * max(1.0, float(np.abs(want).max())))
     if case == "ep_mesh":
         # the whole layer through the shard_map: two shards of two experts
@@ -372,7 +389,9 @@ def test_moe_combine_by_gather_is_the_scatter_add(case, T, k, monkeypatch):
             return jnp.where(past, junk, out)
 
         monkeypatch.setattr(moe, "_grouped_matmul", poisoned)
-    got = np.asarray(moe._held_experts(spec, lp, x, topi, topv, _FIRST))
+        # traced under the patch: a program of its own
+        held_experts = jax.jit(lambda *a: moe._held_experts(spec, *a, _FIRST))
+    got = np.asarray(held_experts(lp, x, topi, topv))
     assert got.dtype == np.float32 and got.shape == x.shape
     assert np.isfinite(got).all()
     if case == "none_held":

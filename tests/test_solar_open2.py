@@ -6,24 +6,19 @@ a shared expert in every layer. Programs, kernels (interpreted), the state
 directory, and the engine's gates around a sequence that owns a state row.
 """
 
-import asyncio
-import importlib.util
+import functools
 import os
 
 import jax
 import jax.numpy as jnp
-import numpy as np
 import kda_step_cases
+import numpy as np
 import pytest
+from family_contract import Family, _whole, case, cases, gates, preempt, run
 
 from dynamo_tpu.engine.config import EngineConfig, LayerKind, ModelSpec
-from dynamo_tpu.engine.core import InferenceEngine
 from dynamo_tpu.models import llama
-from dynamo_tpu.models.family import GqaFamily, get_family
 from dynamo_tpu.ops import attention as attn_ops
-from dynamo_tpu.runtime.context import PRIORITY_HEADER, Context
-
-REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
 # the reference reads the published keys; the program reads SPEC
 CONFIG = {
@@ -41,168 +36,35 @@ CONFIG = {
     "experts": {"published": 8, "held": 4, "first": 2},
 }
 SPEC = ModelSpec.tiny_solar(held_experts=(4, 2))
-PAGE, PAGES_PER_SEQ, T, ROWS = 4, 16, 40, 3
+PAGE, ROWS = 4, 3
 SEED = 11
 
 
-@pytest.fixture(scope="module")
-def ref():
-    spec = importlib.util.spec_from_file_location(
-        "linear_moe", os.path.join(REPO, "perfbench/references/linear_moe.py"))
-    mod = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(mod)
-    return mod
+def _served(engine, snap, served, outs):
+    """Two prompts of 16 + 5 tokens: a block of 64 holds each chunk; five
+    decode steps a prompt served in bursts of 4, each counted whole."""
+    assert engine.kda["prefill_blocks"] == 4
+    assert engine.kda["decode_rows"] % 4 == 0 and engine.kda["decode_rows"] >= 16
+    assert snap["recurrent_state.rows"]["calls"] == 2
+    assert snap["kda.prefill_blocks"]["calls"] == 4
+    m = engine.moe_counters()
+    assert m["layers"] == 4 and m["prefill.assignments"] == 4 * 2 * 21 * 2
 
 
-@pytest.fixture(scope="module")
-def model(ref):
-    params = llama.init_params(SPEC, jax.random.PRNGKey(SEED))
-    toks = np.asarray(
-        jax.random.randint(jax.random.PRNGKey(1), (3, T), 0, 96))
-    want = np.asarray(ref.forward(
-        CONFIG, SEED, toks, np.tile(np.arange(T), (3, 1))))
-    return params, toks, want
+# the family's row of the contract (tests/family_contract.py); its pack is
+# three rows of different lengths and an empty one in ONE call
+F = FAMILY = Family(
+    spec=SPEC, config=CONFIG, reference="linear_moe",
+    seed=SEED, state_rows=ROWS, chunked_paths=("1",), pack_path="1",
+    packs=([(0, 0, 13), (0, 0, 0), (1, 0, 16), (2, 0, 7)],),
+    inactive_paths=("1",), streams=(False, True),
+    also={"serves": _served})
 
 
-def _cache(rows=ROWS):
-    return llama.init_cache(
-        SPEC, 1 + 3 * PAGES_PER_SEQ, PAGE, state_rows=rows)
-
-
-def _table(row):
-    return jnp.arange(PAGES_PER_SEQ, dtype=jnp.int32) + 1 + row * PAGES_PER_SEQ
-
-
-def _close(got, want, tol=3e-4):
-    np.testing.assert_allclose(np.asarray(got), want, rtol=tol, atol=tol)
-
-
-# fresh jits a test: the kernel/XLA choice is read at trace time
-def _programs():
-    return (jax.jit(llama.prefill_forward_impl, static_argnums=(0,)),
-            jax.jit(llama.prefill_forward_batch_impl, static_argnums=(0,)),
-            jax.jit(llama.decode_forward_impl, static_argnums=(0,)),
-            jax.jit(llama.decode_steps_impl, static_argnums=(0,),
-                    static_argnames=("n_steps", "n_logprobs")))
-
-
-def _prefill(pf, params, toks, row, start, n, k, v, bucket=16):
-    padded = np.zeros((bucket,), np.int32)
-    padded[:n] = toks[row, start: start + n]
-    logits, k, v, _ = pf(
-        SPEC, params, jnp.asarray(padded), _table(row),
-        jnp.asarray(start, jnp.int32), k, v, jnp.asarray(n, jnp.int32),
-    )
-    return logits, k, v
-
-
-@pytest.mark.parametrize("pallas", ["0", "1"], ids=["xla", "kernels"])
-def test_prefill_then_decode_through_pages_and_state(model, monkeypatch, pallas):
-    """A prompt through the prefill program, then teacher-forced decode
-    steps through the pages of the GQA layer and the state rows of the
-    KDA layers: every position's logits are the reference's whole forward
-    pass. The other slots are empty or inactive."""
-    monkeypatch.setenv("DYNAMO_PALLAS", pallas)
-    params, toks, want = model
-    pf, _, df, _ = _programs()
-    k, v = _cache()
-    n = 21
-    logits, k, v = _prefill(pf, params, toks, 1, 0, n, k, v, bucket=32)
-    _close(logits, want[1, n - 1])
-    bts = np.zeros((3, PAGES_PER_SEQ), np.int32)
-    bts[2] = np.asarray(_table(1))
-    active = np.array([False, False, True])
-    for j in range(6):
-        fed = np.zeros((3,), np.int32)
-        seq = np.ones((3,), np.int32)
-        fed[2], seq[2] = toks[1, n + j], n + j + 1
-        lg, k, v = df(SPEC, params, jnp.asarray(fed), jnp.asarray(bts),
-                      jnp.asarray(seq), k, v, jnp.asarray(active))
-        _close(lg[2], want[1, n + j])
-    stats = np.asarray(k.rows.stats[0])
-    assert stats[llama.STAT_CLAIMS] == 1 and stats[llama.STAT_MISSING] == 0
-
-
-@pytest.mark.parametrize("chunks", [
-    [(0, 37)], [(0, 16), (16, 16), (32, 5)],
-], ids=["one-shot", "three-chunks"])
-def test_a_chunked_prompt_resumes_the_state(model, monkeypatch, chunks):
-    """Chunks at ``start_pos`` > 0 resume the chunkwise kernel from the
-    state and the convolution tail the chunk before left in the row: the
-    last chunk's logits are the one-shot prefill's and the reference's."""
-    monkeypatch.setenv("DYNAMO_PALLAS", "1")
-    params, toks, want = model
-    pf = _programs()[0]
-    k, v = _cache()
-    for start, n in chunks:
-        logits, k, v = _prefill(
-            pf, params, toks, 0, start, n, k, v,
-            bucket=64 if n > 16 else 16)
-    _close(logits, want[0, 36])
-    assert int(k.rows.stats[0, llama.STAT_MISSING]) == 0
-
-
-def test_packed_rows_equal_single_rows(model, monkeypatch):
-    """Three rows of different lengths and an empty row in one packed
-    call: each row's logits are the reference's, the empty row claims no
-    state, and no two rows share one."""
-    monkeypatch.setenv("DYNAMO_PALLAS", "1")
-    params, toks, want = model
-    pb = _programs()[1]
-    k, v = _cache()
-    lens = [13, 0, 16, 7]
-    padded = np.zeros((4, 16), np.int32)
-    bts = np.zeros((4, PAGES_PER_SEQ), np.int32)
-    for i, (row, n) in enumerate(zip((0, 0, 1, 2), lens)):
-        padded[i, :n] = toks[row, :n]
-        if n:
-            bts[i] = np.asarray(_table(row))
-    logits, k, v, _ = pb(
-        SPEC, params, jnp.asarray(padded), jnp.asarray(bts),
-        jnp.zeros((4,), jnp.int32), k, v, jnp.asarray(lens, jnp.int32))
-    for i, (row, n) in enumerate(zip((0, 0, 1, 2), lens)):
-        if n:
-            _close(logits[i], want[row, n - 1])
-    owner = np.asarray(k.rows.owner[0])
-    assert sorted(owner[:ROWS]) == [1, 1 + PAGES_PER_SEQ, 1 + 2 * PAGES_PER_SEQ]
-    assert owner[ROWS] == 0  # the trash row is nobody's
-
-
-def test_bursts_of_one_and_eight_agree(model, monkeypatch):
-    """Eight greedy steps as one burst and as eight bursts of one: the
-    same tokens, and the same state afterwards (the burst finds its rows
-    once; the conv tail and the state carry between steps)."""
-    monkeypatch.setenv("DYNAMO_PALLAS", "1")
-    params, toks, _ = model
-    pf, _, _, ds = _programs()
-    B = 3
-    bts = np.zeros((B, PAGES_PER_SEQ), np.int32)
-    bts[0], bts[1] = np.asarray(_table(0)), np.asarray(_table(1))
-    active = jnp.asarray([True, True, False])
-    z = jnp.zeros((B,), jnp.int32)
-
-    def run(bursts):
-        k, v = _cache()
-        for row, n in ((0, 9), (1, 14)):
-            _, k, v = _prefill(pf, params, toks, row, 0, n, k, v)
-        fed = np.array([toks[0, 9], toks[1, 14], 0], np.int32)
-        seq = np.array([10, 15, 1], np.int32)
-        out = []
-        for n_steps in bursts:
-            o, k, v = ds(
-                SPEC, params, jnp.asarray(fed), jnp.asarray(bts),
-                jnp.asarray(seq), k, v, active, jnp.zeros((B,)), z,
-                jnp.ones((B,)), jnp.zeros((B,), jnp.uint32), z,
-                n_steps=n_steps, n_logprobs=0)
-            o = np.asarray(o)
-            out.append(o[:2])
-            fed[:2], seq[:2] = o[:2, -1], seq[:2] + n_steps
-        return np.concatenate(out, axis=1), k
-
-    one, k1 = run([1] * 8)
-    eight, k8 = run([8])
-    np.testing.assert_array_equal(one, eight)
-    _close(k8.pools[1][:, :2], np.asarray(k1.pools[1][:, :2]), tol=1e-5)
+@pytest.mark.parametrize("case,kw", cases(
+    F, case("engine-preempt", preempt), case("engine-gates", gates)))
+def test_the_family_contract(case, kw, monkeypatch):
+    run(case, F, monkeypatch, **kw)
 
 
 def _kda_case(T_, beta_scale=2.0, seed=3, N=2, H=4, D=16):
@@ -231,8 +93,9 @@ KDA_CASES = {
 KDA_LOOSE = {"edge-decay": 1e-4, "parallel-keys": 5e-5}
 
 
+@functools.cache
 def _kda_named(name):
-    """A pack of 2 for ``kda_chunk_prefill``: (q, k, v, g, beta, s0, fresh
+    """Made once a worker: a pack of 2 for ``kda_chunk_prefill``: (q, k, v, g, beta, s0, fresh
     [2], trash [2], real [2]). ``fresh-beside-resumed``: member 0 starts
     from zero whatever its row holds; ``trash-member``: member 1 owns no
     row; ``padded``: tokens past ``real`` carry g = 0 and beta = 0;
@@ -260,6 +123,25 @@ def _kda_named(name):
     return q, k, v, g, beta, s0, fresh, trash, real
 
 
+# a decode path (``DYNAMO_PALLAS``, read at TRACE time) a jit: the chunk
+# form and the recurrence are traced once a shape a worker
+_CHUNK = {path: jax.jit(
+    lambda *a, **kw: attn_ops.kda_chunk_prefill(*a, **kw),
+    static_argnames=("layer",)) for path in ("0", "1")}
+_recurrence = jax.jit(attn_ops.kda_recurrence)
+
+
+@functools.cache
+def _kda_wanted(name):
+    """The recurrence a token at a time over each member's real tokens of
+    ``_kda_named(name)``: [(o, the state it leaves)]."""
+    q, k, v, g, beta, s0, fresh, trash, real = _kda_named(name)
+    return [_recurrence(
+        q[n, :r], k[n, :r], v[n, :r], g[n, :r], beta[n, :r],
+        s0[n] * (0.0 if fresh[n] or trash[n] else 1.0))
+        for n, r in enumerate(real)]
+
+
 def _chunk(q, k, v, g, beta, s0, fresh=None, trash=None):
     """``kda_chunk_prefill`` from and to rows 1.. of a pool whose row 0
     must come back as it was and whose last row is trash (a member of
@@ -269,7 +151,7 @@ def _chunk(q, k, v, g, beta, s0, fresh=None, trash=None):
     rows = np.arange(1, N + 1)
     if trash is not None:
         rows = np.where(trash, N + 1, rows)
-    o, new = attn_ops.kda_chunk_prefill(
+    o, new = _CHUNK[os.environ["DYNAMO_PALLAS"]](
         q, k, v, g, beta, pool, jnp.asarray(rows, jnp.int32),
         jnp.asarray(np.zeros(N, bool) if fresh is None else fresh), layer=0)
     np.testing.assert_array_equal(np.asarray(new[0, 0]), np.asarray(pool[0, 0]))
@@ -288,16 +170,13 @@ def test_kda_chunk_equals_the_token_recurrence(monkeypatch, pallas, case):
     q, k, v, g, beta, s0, fresh, trash, real = _kda_named(case)
     assert float(beta.max()) > 1.5
     o, s, _ = _chunk(q, k, v, g, beta, s0, fresh, trash)
-    for n in range(q.shape[0]):
+    for n, (want_o, want_s) in enumerate(_kda_wanted(case)):
         r = real[n]
-        want_o, want_s = attn_ops.kda_recurrence(
-            q[n, :r], k[n, :r], v[n, :r], g[n, :r], beta[n, :r],
-            s0[n] * (0.0 if fresh[n] or trash[n] else 1.0))
         if trash[n]:
             np.testing.assert_array_equal(np.asarray(s[n]), np.asarray(s0[n]))
             continue
-        _close(o[n, :r], np.asarray(want_o), tol=KDA_LOOSE.get(case, 2e-5))
-        _close(s[n], np.asarray(want_s), tol=KDA_LOOSE.get(case, 2e-5))
+        F.close(o[n, :r], np.asarray(want_o), tol=KDA_LOOSE.get(case, 2e-5))
+        F.close(s[n], np.asarray(want_s), tol=KDA_LOOSE.get(case, 2e-5))
 
 
 @pytest.mark.parametrize("case", ["both-kernels"] + list(KDA_CASES))
@@ -315,9 +194,9 @@ def test_kda_kernels_equal_their_xla_twins(monkeypatch, case):
             outs[pallas] = _chunk(q, k, v, g, beta, s0, fresh, trash)
         tol = KDA_LOOSE.get(case, 1e-5)
         for n, r in enumerate(real):
-            _close(outs["1"][0][n, :r], np.asarray(outs["0"][0][n, :r]), tol=tol)
+            F.close(outs["1"][0][n, :r], np.asarray(outs["0"][0][n, :r]), tol=tol)
         # every row but the trash row
-        _close(outs["1"][2][:, :-1], np.asarray(outs["0"][2][:, :-1]), tol=tol)
+        F.close(outs["1"][2][:, :-1], np.asarray(outs["0"][2][:, :-1]), tol=tol)
         return
     q, k, v, g, beta, s0 = _kda_case(128, seed=5)
     outs = {}
@@ -325,15 +204,14 @@ def test_kda_kernels_equal_their_xla_twins(monkeypatch, case):
         monkeypatch.setenv("DYNAMO_PALLAS", pallas)
         outs[pallas] = _chunk(q, k, v, g, beta, s0)[:2]
         # a fresh row starts from zero whatever the pool held
-        fresh = attn_ops.kda_chunk_prefill(
+        fresh = _CHUNK[pallas](
             q[:1], k[:1], v[:1], g[:1], beta[:1], s0[None, :2],
             jnp.zeros((1,), jnp.int32), jnp.ones((1,), bool), layer=0)
-        want = attn_ops.kda_recurrence(
-            q[0], k[0], v[0], g[0], beta[0], s0[0] * 0)
-        _close(fresh[0][0], np.asarray(want[0]), tol=2e-5)
-        _close(fresh[1][0, 0], np.asarray(want[1]), tol=2e-5)
+        want = _recurrence(q[0], k[0], v[0], g[0], beta[0], s0[0] * 0)
+        F.close(fresh[0][0], np.asarray(want[0]), tol=2e-5)
+        F.close(fresh[1][0, 0], np.asarray(want[1]), tol=2e-5)
     for a, b in zip(outs["0"], outs["1"]):
-        _close(a, np.asarray(b), tol=1e-5)
+        F.close(a, np.asarray(b), tol=1e-5)
 
     pool = jax.random.normal(jax.random.PRNGKey(9), (2, 6, 4, 16, 16))
     conv = jax.random.normal(jax.random.PRNGKey(8), (2, 6, 3, 3, 64))
@@ -350,9 +228,9 @@ def test_kda_kernels_equal_their_xla_twins(monkeypatch, case):
     live = np.asarray([1, 3])
     for x_, y in zip(steps["0"], steps["1"]):
         assert x_.shape == y.shape
-    _close(steps["0"][0][live], np.asarray(steps["1"][0][live]), tol=1e-5)
+    F.close(steps["0"][0][live], np.asarray(steps["1"][0][live]), tol=1e-5)
     for i in (1, 2):  # the pools: every row but the trash row
-        _close(steps["0"][i][:, :5], np.asarray(steps["1"][i][:, :5]), tol=1e-5)
+        F.close(steps["0"][i][:, :5], np.asarray(steps["1"][i][:, :5]), tol=1e-5)
     got_o, got_s, got_c = steps["1"]
     np.testing.assert_array_equal(np.asarray(got_s[0]), np.asarray(pool[0]))
     np.testing.assert_array_equal(
@@ -372,8 +250,8 @@ def test_kda_kernels_equal_their_xla_twins(monkeypatch, case):
     k1 = k1 / jnp.sqrt((k1 * k1).sum(-1, keepdims=True) + 1e-6)
     want_o, want_s = attn_ops.kda_recurrence(
         q1[None], k1[None], v1[None], g[0, :1], beta[0, :1], pool[1, 3])
-    _close(got_o[1], np.asarray(want_o[0]), tol=1e-5)
-    _close(got_s[1, 3], np.asarray(want_s), tol=1e-5)
+    F.close(got_o[1], np.asarray(want_o[0]), tol=1e-5)
+    F.close(got_s[1, 3], np.asarray(want_s), tol=1e-5)
 
 
 @pytest.mark.parametrize("case", kda_step_cases.CASES)
@@ -468,13 +346,13 @@ def test_the_shares_add_up(ref):
         alike = np.asarray(ref._layer(
             none, i, x, dict(lw, e_gate=lw["e_gate"][:0]), None))
         shares = [layer(i, 4, first)[0] for first in (0, 4)]
-        _close(alike + sum(s - alike for s in shares), whole, tol=1e-4)
+        F.close(alike + sum(s - alike for s in shares), whole, tol=1e-4)
     spec = ModelSpec.tiny_solar(
         held_experts=(4, 2), num_layers=2, layer_pattern=(0, 1))
     params = llama.init_params(spec, jax.random.PRNGKey(SEED))
-    got = llama.reference_forward(spec, params, jnp.asarray(toks[0]))
+    got = _whole(spec, params, jnp.asarray(toks[0]))
     want = ref.forward(cfg, SEED, toks, np.arange(10)[None].repeat(2, 0))
-    _close(got, np.asarray(want)[0])
+    F.close(got, np.asarray(want)[0])
 
 
 def test_a_checkpoint_in_the_published_layout_round_trips(tmp_path):
@@ -504,190 +382,6 @@ def test_a_checkpoint_in_the_published_layout_round_trips(tmp_path):
             "model.layers.3.mlp.gate.e_score_correction_bias",
             "model.layers.0.mlp.shared_experts.up_proj.weight"} <= names
     assert "model.layers.1.self_attn.g_proj.weight" not in names
-
-
-# ------------------------------------------------------------- the engine
-
-
-def _engine(**kw):
-    base = dict(
-        page_size=PAGE, num_pages=64, max_pages_per_seq=PAGES_PER_SEQ,
-        max_decode_slots=2, prefill_buckets=(16,), max_prefill_chunk_tokens=16,
-        decode_steps_per_dispatch=4, seed=SEED,
-    )
-    base.update(kw)
-    return InferenceEngine(SPEC, EngineConfig(**base))
-
-
-async def _greedy(engine, prompt, n, out=None, ctx=None):
-    out = [] if out is None else out
-    async for item in engine.generate(
-        {"token_ids": list(prompt), "sampling": {"temperature": 0.0},
-         "stop_conditions": {"max_tokens": n, "ignore_eos": True}},
-        ctx or Context(),
-    ):
-        assert item.get("finish_reason") != "error", item
-        out.extend(item.get("token_ids") or [])
-    return out
-
-
-_jit_reference = jax.jit(llama.reference_forward, static_argnums=0)
-
-
-def _greedy_reference(params, prompt, n):
-    seq = list(prompt)
-    for _ in range(n):
-        padded = np.zeros((64,), np.int32)
-        padded[: len(seq)] = seq
-        lg = _jit_reference(SPEC, params, jnp.asarray(padded))
-        seq.append(int(np.argmax(np.asarray(lg[len(seq) - 1]))))
-    return seq[len(prompt):]
-
-
-async def test_serves_through_the_engine_and_counts(monkeypatch):
-    """The toy model through the REAL engine (scheduler, a prompt of two
-    chunks, both kernels interpreted in bursts): the greedy stream is the
-    whole forward pass's own; the same prompt a second time gives the same
-    tokens and seals nothing (no page is reused under a prefix: it holds
-    no state); the rows go back; the counters read what hand arithmetic
-    gives."""
-    monkeypatch.setenv("DYNAMO_PALLAS", "1")
-    engine = _engine()
-    fam = engine.fam
-    assert isinstance(fam, GqaFamily) and fam.recurrent
-    assert not fam.supports_prefix_reuse and not engine.allocator.prefix_cache
-    prompt = [int(t) for t in np.arange(7, 7 + 21) % 96]  # two chunks
-    want = _greedy_reference(engine.params, prompt, 6)
-    assert await _greedy(engine, prompt, 6) == want
-    assert await _greedy(engine, prompt, 6) == want
-    assert engine.allocator._hash_page == {}
-    assert engine.allocator.evictable_pages == 0
-    assert engine.prefix_hit_tokens(prompt) == 0
-    assert engine.allocator.active_pages == 0
-    # two prompts of 16 + 5 tokens: a block of 64 holds each chunk; five
-    # decode steps a prompt served in bursts of 4, each counted whole
-    assert engine.kda["prefill_blocks"] == 4
-    assert engine.kda["decode_rows"] % 4 == 0 and engine.kda["decode_rows"] >= 16
-    await engine.close()
-    engine._metrics_publishes = 0
-    for _ in range(34):  # two refreshes bring the device's counters over
-        engine._publish_metrics()
-    c = engine.state_counters()
-    assert c == {"rows": 2, "rows_live": 0, "claims": 2, "row_missing": 0}
-    engine._flush_state_releases()
-    assert list(np.asarray(engine.k_pages.rows.owner[0])) == [0, 0, 0]
-    snap = engine.profile_snapshot()
-    assert snap["recurrent_state.rows"]["calls"] == 2
-    assert snap["kda.prefill_blocks"]["calls"] == 4
-    m = engine.moe_counters()
-    assert m["layers"] == 4 and m["prefill.assignments"] == 4 * 2 * 21 * 2
-
-
-@pytest.mark.parametrize("pipeline", [False, True], ids=["plain", "pipelined"])
-async def test_streams_share_the_engine(monkeypatch, pipeline):
-    """Three prompts on two slots, one of them chunked behind running
-    bursts: every stream is what it gets alone, pipelined or not, rows
-    are claimed and freed as slots turn over, none goes missing."""
-    monkeypatch.setenv("DYNAMO_PALLAS", "1")
-    prompts = [[3, 9, 27], [8, 64, 32, 5],
-               [int(t) for t in np.arange(5, 5 + 37) * 7 % 96]]
-    engine = _engine(pipeline_decode=pipeline, async_admissions=True)
-    want = [_greedy_reference(engine.params, p, n)
-            for p, n in zip(prompts, (12, 9, 6))]
-    outs = await asyncio.gather(*(
-        _greedy(engine, p, n) for p, n in zip(prompts, (12, 9, 6))))
-    assert outs == want
-    assert engine.allocator.active_pages == 0
-    await engine.close()
-    assert int(engine.k_pages.rows.stats[0, llama.STAT_MISSING]) == 0
-    assert int(engine.k_pages.rows.stats[0, llama.STAT_CLAIMS]) == 3
-
-
-async def test_preempt_and_resume_by_recomputation(monkeypatch):
-    """A batch stream preempted for an interactive one gives its row and
-    pages back and resumes by prefilling its prompt and its output so far
-    from an empty state: the tokens of an undisturbed run."""
-    monkeypatch.setenv("DYNAMO_PALLAS", "0")
-    prompt = [5, 11, 17, 23, 29]
-    engine = _engine(max_decode_slots=1, prefill_buckets=(16, 32, 64),
-                     max_prefill_chunk_tokens=64)
-    want = _greedy_reference(engine.params, prompt, 24)
-    got: list = []
-    batch = asyncio.create_task(_greedy(
-        engine, prompt, 24, out=got,
-        ctx=Context(headers={PRIORITY_HEADER: "batch"})))
-    while len(got) < 6:
-        await asyncio.sleep(0.002)
-    quick = await _greedy(engine, [2, 4, 6], 3)
-    assert quick == _greedy_reference(engine.params, [2, 4, 6], 3)
-    assert await batch == want
-    assert sum(engine.preemptions.values()) >= 1
-    assert engine.allocator.active_pages == 0
-    await engine.close()
-    assert int(engine.k_pages.rows.stats[0, llama.STAT_MISSING]) == 0
-
-
-def _fallbacks(*reasons):
-    from dynamo_tpu.ops import fallback
-
-    return [fallback._FALLBACKS.labels(r)._value.get() for r in reasons]
-
-
-async def test_every_gate_counts_its_reason():
-    """What moves, reuses or rolls back pages alone is off for a model
-    with recurrent layers, by the family's attributes; what is asked for
-    anyway joins the fallback series under its own reason."""
-    fam = get_family(SPEC)
-    assert fam.recurrent
-    for gate in ("ring_prefill", "spec_decode", "mesh", "prefix_reuse",
-                 "page_transfer", "multimodal"):
-        assert not getattr(fam, f"supports_{gate}"), gate
-    assert fam.supports_packed_prefill
-    plain = get_family(ModelSpec.tiny())
-    assert plain.supports_prefix_reuse and plain.supports_page_transfer
-    assert not plain.recurrent
-
-    names = ("recurrent_no_page_offload", "recurrent_no_spec_decode",
-             "recurrent_no_ring_prefill", "recurrent_no_page_transfer")
-    before = _fallbacks(*names)
-    from dynamo_tpu.kvbm import KvBlockManager, KvbmConfig
-
-    engine = InferenceEngine(
-        SPEC, EngineConfig(
-            page_size=PAGE, num_pages=64, max_pages_per_seq=PAGES_PER_SEQ,
-            max_decode_slots=2, prefill_buckets=(16,), spec_mode="ngram",
-            sp=2, seed=SEED,
-        ), kvbm=KvBlockManager(KvbmConfig(host_bytes=1 << 20)),
-    )
-    assert engine.kvbm is None and engine.offload is None
-    assert not engine._spec_on
-    assert [b - a for a, b in zip(before, _fallbacks(*names))] == [1, 1, 1, 0]
-    # a decode-side disaggregated request: the pull is refused, counted,
-    # and the stream is served by a local prefill of prompt + first token
-    out = []
-    async for item in engine.generate(
-        {"token_ids": [4, 8, 15, 16], "sampling": {"temperature": 0.0},
-         "stop_conditions": {"max_tokens": 4, "ignore_eos": True},
-         "disagg": {"mode": "decode", "kv_transfer": {
-             "first_token": 23, "address": "127.0.0.1:1", "handle": "x"}}},
-        Context(),
-    ):
-        assert item.get("finish_reason") != "error", item
-        out.extend(item.get("token_ids") or [])
-    assert out == _greedy_reference(engine.params, [4, 8, 15, 16, 23], 3)
-    assert _fallbacks(names[3])[0] - before[3] == 1
-    await engine.close()
-    # the programs with no recurrent form say so to a direct caller
-    k, v = _cache()
-    with pytest.raises(NotImplementedError, match="speculative verify"):
-        llama.verify_forward_impl(
-            SPEC, engine.params, jnp.zeros((1, 2), jnp.int32),
-            jnp.zeros((1, PAGES_PER_SEQ), jnp.int32), jnp.zeros((1,), jnp.int32),
-            k, v, jnp.ones((1,), jnp.int32))
-    with pytest.raises(ValueError, match="meshes"):
-        from dynamo_tpu.parallel.mesh import make_mesh
-
-        InferenceEngine(SPEC, EngineConfig(seed=SEED), mesh=make_mesh(tp=2, dp=1))
 
 
 def test_the_memory_guard_offers_packs_beside_a_long_table():

@@ -10,7 +10,6 @@ the loader's name map and the engine around it.
 """
 
 import dataclasses
-import importlib.util
 import json
 import os
 
@@ -18,14 +17,13 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
+from family_contract import (
+    CHUNKS, REPO, Family, _cache, _engine, _prefill, _programs, cases, run,
+)
 
-from dynamo_tpu.engine.config import EngineConfig, LayerKind, ModelSpec
-from dynamo_tpu.engine.core import InferenceEngine
+from dynamo_tpu.engine.config import LayerKind, ModelSpec
 from dynamo_tpu.models import llama
 from dynamo_tpu.models.family import GqaFamily, get_family
-from dynamo_tpu.runtime.context import Context
-
-REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
 # the reference reads the published keys; the program reads SPEC. Four
 # layers: a dense window layer, a window expert layer, a full (NoPE)
@@ -50,94 +48,55 @@ PAGE, PAGES_PER_SEQ, T = 4, 16, 40
 SEED = 13
 
 
-@pytest.fixture(scope="module")
-def ref():
-    spec = importlib.util.spec_from_file_location(
-        "gated_swa_moe",
-        os.path.join(REPO, "perfbench/references/gated_swa_moe.py"))
-    mod = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(mod)
-    return mod
-
-
-@pytest.fixture(scope="module")
-def model(ref):
-    params = llama.init_params(SPEC, jax.random.PRNGKey(SEED))
-    toks = np.asarray(
-        jax.random.randint(jax.random.PRNGKey(1), (3, T), 0, 96))
-    want = np.asarray(ref.forward(
-        CONFIG, SEED, toks, np.tile(np.arange(T), (3, 1))))
-    return params, toks, want
-
-
-def _cache(spec=SPEC):
-    return llama.init_cache(spec, 1 + 3 * PAGES_PER_SEQ, PAGE)
-
-
-def _table(row):
-    return jnp.arange(PAGES_PER_SEQ, dtype=jnp.int32) + 1 + row * PAGES_PER_SEQ
-
-
-def _close(got, want, tol=3e-4):
-    np.testing.assert_allclose(np.asarray(got), want, rtol=tol, atol=tol)
-
-
-def _fresh(fn):
-    """``fn`` under a name of its own: ``DYNAMO_PALLAS`` and a patched
-    helper are read at TRACE time, and ``jax.jit`` finds a function it has
-    traced before by its identity."""
-    return lambda *a, **kw: fn(*a, **kw)
-
-
-_TRACED: dict = {}
-
-
-def _programs():
-    """The four programs, traced once a decode path (``DYNAMO_PALLAS``)."""
-    path = os.environ.get("DYNAMO_PALLAS")
-    if path not in _TRACED:
-        _TRACED[path] = _jits()
-    return _TRACED[path]
-
-
-def _jits():
-    return (jax.jit(_fresh(llama.prefill_forward_impl), static_argnums=(0,)),
-            jax.jit(_fresh(llama.prefill_forward_batch_impl),
-                    static_argnums=(0,)),
-            jax.jit(_fresh(llama.decode_forward_impl), static_argnums=(0,)),
-            jax.jit(_fresh(llama.decode_steps_impl), static_argnums=(0,),
-                    static_argnames=("n_steps", "n_logprobs")))
-
-
 def _whole():
-    return jax.jit(_fresh(llama.reference_forward), static_argnums=0)
+    """The whole-sequence pass under a name of its own: a patched helper
+    is read at TRACE time, and ``jax.jit`` finds a function it has traced
+    before by its identity."""
+    return jax.jit(lambda *a: llama.reference_forward(*a), static_argnums=0)
 
 
-def _prefill(pf, params, toks, row, start, n, k, v, bucket=16, spec=SPEC):
-    padded = np.zeros((bucket,), np.int32)
-    padded[:n] = toks[row, start: start + n]
-    logits, k, v, _ = pf(
-        spec, params, jnp.asarray(padded), _table(row),
-        jnp.asarray(start, jnp.int32), k, v, jnp.asarray(n, jnp.int32),
-    )
-    return logits, k, v
+def _served(engine, snap, served, outs):
+    """A prompt of two chunks longer than the window, the second time over
+    a reused prefix: the window counters read what hand arithmetic gives
+    and reach ``/metrics`` through the collector; three expert layers,
+    top-2 of 8, all held."""
+    from dynamo_tpu.engine.telemetry import REGISTRY, EngineCollector
+
+    assert engine._prefill_walks == {"full": 0, "window": 8}
+    held, dead = (engine.kv["window_layer_tokens"],
+                  engine.kv["window_dead_tokens"])
+    # a slot of 22 tokens and more is live at every dispatched step: at
+    # least 14 of them past the window of 8, in three window layers
+    assert held > 0 and held % 3 == 0 and dead % 3 == 0
+    assert 14 / 22 <= dead / held < 1
+    collector = EngineCollector(engine)
+    collector.sample()
+    text = REGISTRY.exposition().decode()
+    line = next(
+        ln for ln in text.splitlines()
+        if ln.startswith("dynamo_engine_window_tokens_total{")
+        and f'engine="{collector.label}"' in ln and 'what="dead"' in ln)
+    assert float(line.rsplit(" ", 1)[1]) == dead
+    m = engine.moe_counters()
+    assert m["layers"] == 3
+    assert m["prefill.assignments_held"] == m["prefill.assignments"] > 0
+    assert m["decode.assignments_held"] == sum(
+        m[f"decode.expert.{i}"] for i in range(8))
 
 
-def _decode(df, params, toks, row, n, steps, k, v, spec=SPEC):
-    """Teacher-forced decode steps of ``row`` in slot 2 of three (slot 0
-    empty, slot 1 inactive); yields each step's logits."""
-    bts = np.zeros((3, PAGES_PER_SEQ), np.int32)
-    bts[2] = np.asarray(_table(row))
-    active = np.array([False, False, True])
-    out = []
-    for j in range(steps):
-        fed = np.zeros((3,), np.int32)
-        seq = np.ones((3,), np.int32)
-        fed[2], seq[2] = toks[row, n + j], n + j + 1
-        lg, k, v = df(spec, params, jnp.asarray(fed), jnp.asarray(bts),
-                      jnp.asarray(seq), k, v, jnp.asarray(active))
-        out.append(np.asarray(lg[2]))
-    return out, k, v
+# the family's row of the contract (tests/family_contract.py): prompts on
+# both sides of the toy window (a prompt of 5's decode steps cross its edge
+# at the 8th token), kernel and XLA twin; chunks that start past the window
+F = FAMILY = Family(
+    spec=SPEC, config=CONFIG, reference="gated_swa_moe",
+    seed=SEED, prompts=((5, "1"), (21, "1"), (21, "0")),
+    chunked={"three-chunks": CHUNKS["three-chunks"]}, bursts_paths=(None,),
+    engine_path=None, also={"serves": _served})
+
+
+@pytest.mark.parametrize("case,kw", cases(F))
+def test_the_family_contract(case, kw, monkeypatch):
+    run(case, F, monkeypatch, **kw)
 
 
 def test_the_kinds_the_pools_and_the_family():
@@ -152,7 +111,7 @@ def test_the_kinds_the_pools_and_the_family():
     assert [SPEC.kind(i).window for i in range(4)] == [8, 8, 0, 8]
     assert SPEC.sandwich_norm and SPEC.qk_norm and SPEC.attn_gate
     assert not SPEC.has_recurrent and SPEC.has_attn_extras
-    k, v = _cache()
+    k, v = _cache(F)
     pages = 1 + 3 * PAGES_PER_SEQ
     assert k.pools[WINDOW].shape == v.pools[WINDOW].shape == (
         3, pages, 2, PAGE, 16)
@@ -166,32 +125,6 @@ def test_the_kinds_the_pools_and_the_family():
     assert float(jnp.abs(layer[1]["post_mlp_norm"] - 1).max()) > 0.05
 
 
-@pytest.mark.parametrize("n,pallas", [(5, "1"), (21, "1"), (21, "0")], ids=[
-    "kernel-under-the-window", "kernel-past-it", "xla-twin-past-it"])
-def test_a_prompt_and_decode_steps_are_the_references(
-        model, monkeypatch, n, pallas):
-    """A prompt through the prefill program, then teacher-forced decode
-    steps through both kinds' pages: every position's logits are the
-    reference's whole forward pass, for a prompt shorter than the window
-    (whose decode steps cross its edge at the 8th token) and for one whose
-    window has left the row's first pages; through the fused kernel
-    (interpreted: the gate and the q and k norms over ``attn_window``) and
-    through its XLA twin."""
-    monkeypatch.setenv("DYNAMO_PALLAS", pallas)
-    params, toks, want = model
-    pf, _, df, _ = _programs()
-    k, v = _cache()
-    logits, k, v = _prefill(pf, params, toks, 1, 0, n, k, v, bucket=32)
-    _close(logits, want[1, n - 1])
-    got, k, v = _decode(df, params, toks, 1, n, 5, k, v)
-    for j, lg in enumerate(got):
-        _close(lg, want[1, n + j])
-    # the path asked for is the path traced
-    from dynamo_tpu.ops import attention
-
-    assert attention.use_pallas() == (pallas == "1")
-
-
 def test_a_lower_precision_than_stated_fails_the_tolerance(ref, model):
     """The same prefill in bfloat16 (weights, activations, pages) against
     the float32 reference misses the float32 tolerance by two orders and
@@ -199,90 +132,14 @@ def test_a_lower_precision_than_stated_fails_the_tolerance(ref, model):
     _, toks, want = model
     spec = dataclasses.replace(SPEC, dtype="bfloat16")
     params = llama.init_params(spec, jax.random.PRNGKey(SEED))
-    k, v = _cache(spec)
-    logits, k, v = _prefill(_jits()[0], params, toks, 1, 0, 21, k, v, 32, spec)
+    k, v = _cache(F, spec=spec)
+    logits, k, v = _prefill(F, _programs(F)[0], params, toks, 1, 0, 21, k, v, 32, spec)
     low = np.asarray(ref.forward(
         dict(CONFIG, torch_dtype="bfloat16"), SEED, toks,
         np.tile(np.arange(T), (3, 1))))[1, 20]
     lg = np.asarray(logits, np.float32)
     assert np.abs(lg - want[1, 20]).max() > 30 * 3e-4
     assert np.sqrt(np.mean((lg - low) ** 2)) < 0.1 * np.sqrt(np.mean(low ** 2))
-
-
-def test_a_prompt_of_three_chunks_and_a_ragged_pack(model):
-    """Chunks at ``start_pos`` > 0 (the second and third start past the
-    window of the first's tokens) end at the one-shot prefill's and the
-    reference's logits; rows of different lengths, an empty row and two
-    resumed chunks in packed calls keep apart."""
-    params, toks, want = model
-    pf, pb, _, _ = _programs()
-    k, v = _cache()
-    for start, n in ((0, 16), (16, 16), (32, 5)):
-        logits, k, v = _prefill(pf, params, toks, 0, start, n, k, v)
-    _close(logits, want[0, 36])
-
-    def pack(members, bucket=16):
-        nonlocal k, v
-        padded = np.zeros((2, bucket), np.int32)
-        bts = np.zeros((2, PAGES_PER_SEQ), np.int32)
-        starts, lens = np.zeros(2, np.int32), np.zeros(2, np.int32)
-        for i, (row, start, n) in enumerate(members):
-            padded[i, :n] = toks[row, start: start + n]
-            if n:
-                bts[i], starts[i], lens[i] = np.asarray(_table(row)), start, n
-        logits, k, v, _ = pb(
-            SPEC, params, jnp.asarray(padded), jnp.asarray(bts),
-            jnp.asarray(starts), k, v, jnp.asarray(lens))
-        return logits
-
-    logits = pack([(1, 0, 13), (1, 0, 0)])
-    _close(logits[0], want[1, 12])
-    logits = pack([(1, 0, 16), (2, 0, 8)])
-    _close(logits[0], want[1, 15])
-    _close(logits[1], want[2, 7])
-    logits = pack([(1, 16, 9), (2, 8, 16)])  # two resumed chunks
-    _close(logits[0], want[1, 24])
-    _close(logits[1], want[2, 23])
-
-
-def test_bursts_of_one_and_eight_agree_after_prefill(model):
-    """Eight greedy steps as one burst and as eight bursts of one after
-    two prefills, beside an empty slot: the same tokens, the reference's
-    own first choices, the same pages afterwards."""
-    params, toks, want = model
-    pf, _, _, ds = _programs()
-    B = 3
-    bts = np.zeros((B, PAGES_PER_SEQ), np.int32)
-    bts[0], bts[1] = np.asarray(_table(0)), np.asarray(_table(1))
-    active = jnp.asarray([True, True, False])
-    z = jnp.zeros((B,), jnp.int32)
-
-    def run(bursts):
-        k, v = _cache()
-        for row, n in ((0, 9), (1, 14)):
-            _, k, v = _prefill(pf, params, toks, row, 0, n, k, v)
-        fed = np.array([toks[0, 9], toks[1, 14], 0], np.int32)
-        seq = np.array([10, 15, 1], np.int32)
-        out = []
-        for n_steps in bursts:
-            o, k, v = ds(
-                SPEC, params, jnp.asarray(fed), jnp.asarray(bts),
-                jnp.asarray(seq), k, v, active, jnp.zeros((B,)), z,
-                jnp.ones((B,)), jnp.zeros((B,), jnp.uint32), z,
-                n_steps=n_steps, n_logprobs=0)
-            o = np.asarray(o)
-            out.append(o[:2])
-            fed[:2], seq[:2] = o[:2, -1], seq[:2] + n_steps
-        return np.concatenate(out, axis=1), k, v
-
-    one, k1, _ = run([1] * 8)
-    eight, k8, _ = run([8])
-    np.testing.assert_array_equal(one, eight)
-    assert one[0, 0] == int(np.argmax(want[0, 9]))
-    assert one[1, 0] == int(np.argmax(want[1, 14]))
-    for kind in (WINDOW, FULL):
-        _close(k8.pools[kind][:, 1:], np.asarray(k1.pools[kind][:, 1:]),
-               tol=1e-5)
 
 
 # each moves ONE thing of the program away from the published layer; the
@@ -326,7 +183,7 @@ def test_either_output_norm_dropped_fails(model, monkeypatch, gain):
             return llama._add(x, y)
         return real(spec, lp, x, y, name)
 
-    _close(_whole()(SPEC, params, jnp.asarray(toks[1])), want[1])
+    F.close(_whole()(SPEC, params, jnp.asarray(toks[1])), want[1])
     monkeypatch.setattr(llama, "_residual", dropped)
     got = np.asarray(_whole()(SPEC, params, jnp.asarray(toks[1])))
     assert np.abs(got - want[1]).max() > 30 * 3e-4
@@ -389,7 +246,7 @@ def test_the_shares_add_up(ref, model):
     assert np.abs(shared_once).max() > 1e-3
     # a toy router that the bias all but decides: not one share alone adds
     assert sum(np.abs(r).max() > 0 for r in routed) >= 2
-    _close(sum(routed) + shared_once, whole, tol=1e-4)
+    F.close(sum(routed) + shared_once, whole, tol=1e-4)
     # counted with every share instead, the shared expert is four times it
     assert np.abs(
         sum(share(first, True) for first in (0, 2, 4, 6)) - whole
@@ -401,14 +258,14 @@ def test_the_shares_add_up(ref, model):
     assert params["layers"][1]["moe"]["w_gate"].shape == (2, 64, 32)
     got = _whole()(spec, params, jnp.asarray(toks[0]))
     want = ref.forward(held, SEED, toks, np.tile(np.arange(T), (3, 1)))
-    _close(got, np.asarray(want)[0])
+    F.close(got, np.asarray(want)[0])
 
 
 def test_the_window_counters_against_a_hand_count():
     """``kv.window_layer_tokens`` / ``.window_dead_tokens`` over a
     hand-built burst: three window layers of window 8; a live slot's length
     grows by one a step; an inactive slot counts nothing."""
-    engine = _engine()
+    engine = _engine(F)
     assert engine._window_layers == {8: 3}
 
     def burst(seq_lens, n_burst=2):
@@ -541,84 +398,3 @@ def test_the_published_config_maps_to_the_spec():
         loader.spec_from_hf_config(dict(row["config"], score_func="softmax"))
     with pytest.raises(NotImplementedError):
         loader.spec_from_hf_config(dict(row["config"], n_group=4))
-
-
-# ------------------------------------------------------------- the engine
-
-
-def _engine(**kw):
-    base = dict(
-        page_size=PAGE, num_pages=64, max_pages_per_seq=PAGES_PER_SEQ,
-        max_decode_slots=2, prefill_buckets=(16,), max_prefill_chunk_tokens=16,
-        decode_steps_per_dispatch=4, seed=SEED,
-    )
-    base.update(kw)
-    return InferenceEngine(SPEC, EngineConfig(**base))
-
-
-async def _greedy(engine, prompt, n):
-    out = []
-    async for item in engine.generate(
-        {"token_ids": list(prompt), "sampling": {"temperature": 0.0},
-         "stop_conditions": {"max_tokens": n, "ignore_eos": True}},
-        Context(),
-    ):
-        assert item.get("finish_reason") != "error", item
-        out.extend(item.get("token_ids") or [])
-    return out
-
-
-_jit_reference = jax.jit(llama.reference_forward, static_argnums=0)
-
-
-def _greedy_reference(params, prompt, n):
-    seq = list(prompt)
-    for _ in range(n):
-        padded = np.zeros((64,), np.int32)
-        padded[: len(seq)] = seq
-        lg = _jit_reference(SPEC, params, jnp.asarray(padded))
-        seq.append(int(np.argmax(np.asarray(lg[len(seq) - 1]))))
-    return seq[len(prompt):]
-
-
-async def test_serves_through_the_engine_and_counts():
-    """The toy model through the REAL engine (scheduler, a prompt of two
-    chunks longer than the window, pipelined bursts): the greedy stream is
-    the whole forward pass's own, twice (the second time over a reused
-    prefix); the counters read what hand arithmetic gives and reach
-    ``/metrics`` through the collector."""
-    from dynamo_tpu.engine.telemetry import EngineCollector
-
-    engine = _engine(pipeline_decode=True)
-    assert engine._prefill_walks == {"full": 0, "window": 8}
-    prompt = [int(t) for t in np.arange(7, 7 + 21) % 96]  # two chunks
-    want = _greedy_reference(engine.params, prompt, 6)
-    assert await _greedy(engine, prompt, 6) == want
-    assert await _greedy(engine, prompt, 6) == want
-    assert engine.allocator.active_pages == 0
-    held, dead = (engine.kv["window_layer_tokens"],
-                  engine.kv["window_dead_tokens"])
-    # a slot of 22 tokens and more is live at every dispatched step: at
-    # least 14 of them past the window of 8, in three window layers
-    assert held > 0 and held % 3 == 0 and dead % 3 == 0
-    assert 14 / 22 <= dead / held < 1
-    collector = EngineCollector(engine)
-    collector.sample()
-    from dynamo_tpu.engine.telemetry import REGISTRY
-
-    text = REGISTRY.exposition().decode()
-    line = next(
-        ln for ln in text.splitlines()
-        if ln.startswith("dynamo_engine_window_tokens_total{")
-        and f'engine="{collector.label}"' in ln and 'what="dead"' in ln)
-    assert float(line.rsplit(" ", 1)[1]) == dead
-    await engine.close()
-    engine._metrics_publishes = 0
-    for _ in range(34):  # two refreshes bring the device's counters over
-        engine._publish_metrics()
-    m = engine.moe_counters()
-    # three expert layers, top-2 of 8, all held
-    assert m["layers"] == 3
-    assert m["prefill.assignments_held"] == m["prefill.assignments"] > 0
-    assert m["decode.assignments_held"] == sum(
-        m[f"decode.expert.{i}"] for i in range(8))
